@@ -1,32 +1,20 @@
-"""Version shims for the JAX APIs that drift across releases.
+"""The repo's single seam with JAX's version-sensitive APIs.
 
-The kernel surface (``ops/``, ``parallel/``, ``models/``) was written
-against a newer JAX than the one installed here, and the delta — four
-symbols, inventoried mechanically by the API-drift scanner
-(``python -m fmda_tpu lint``, ``artifacts/jax_api_drift.json``) — walled
-the Pallas kernels, ring attention, and sequence-parallel training off
-from tier-1 for eight PRs.  This module is the repo's single seam with
-that churn: each shim probes the installed API on first use and selects
-the available spelling, so the kernel code imports ONE stable name and
-never branches on ``jax.__version__``.
+The kernel surface (``ops/``, ``parallel/``, ``models/``) imports these
+five names from here and nowhere else, so a JAX upgrade that renames one
+is a one-file change.  Each resolves to the spelling of the one JAX this
+repository is installed with (0.9.0 — recorded in
+``artifacts/jax_api_drift.json``); there are no old-version branches.
 
 ==================  =======================================================
-shim                spellings it arbitrates
+name                resolves to
 ==================  =======================================================
-``CompilerParams``  ``pltpu.CompilerParams`` (new) vs
-                    ``pltpu.TPUCompilerParams`` (<= 0.4.x)
-``axis_size``       ``jax.lax.axis_size`` (new) vs ``lax.psum(1, axis)``
-                    — the unit-psum constant-folds to a static int, so
-                    ``range(axis_size(...))`` stays trace-time static
-``pcast``           ``jax.lax.pcast`` (new varying-manual-axes typing) vs
-                    identity — versions without the vma type system need
-                    no cast (run shard_map with the rep checker off)
-``shard_map``       ``jax.shard_map`` (new, ``check_vma=``) vs
-                    ``jax.experimental.shard_map.shard_map`` (old,
-                    ``check_rep=``); the kwarg is translated
-``cost_analysis``   ``lowered.compile().cost_analysis()`` (dict on new
-                    jax, ``[dict]`` on some 0.4.x, absent on older) —
-                    probed per call, normalised to ``dict | None``
+``CompilerParams``  ``jax.experimental.pallas.tpu.CompilerParams``
+``axis_size``       ``jax.lax.axis_size``
+``pcast``           ``jax.lax.pcast``
+``shard_map``       ``jax.shard_map``
+``cost_analysis``   ``lowered.compile().cost_analysis()`` on abstract
+                    arguments, as ``dict | None``
 ==================  =======================================================
 
 Everything resolves lazily (PEP 562): importing this module never
@@ -35,25 +23,24 @@ router's import path) can read :data:`SHIMMED_SYMBOLS` without paying
 for a backend.  The ``compat-required`` analyzer rule closes the loop
 statically — any direct use of a spelling listed in
 :data:`SHIMMED_SYMBOLS` inside ``ops/``/``parallel/``/``models/`` is a
-lint finding, so the shim cannot be bypassed as the surface grows, and
-the ``jax-api-drift`` rule is a zero-baseline hard gate, so a *fifth*
-drifted symbol fails lint the commit it appears.
+lint finding, and the ``jax-api-drift`` rule is a zero-baseline hard
+gate, so a renamed symbol fails lint the commit it appears.
 
 Upgrade workflow (docs/analysis.md "The compat workflow"): scanner
-inventory -> add/adjust the shim entry here -> port call sites to the
-shim -> the drift gate goes back to zero.
+inventory -> adjust the entry here -> the drift gate goes back to zero.
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, Sequence
 
-#: Every version-sensitive spelling this module arbitrates, mapped to
-#: the shim attribute that covers it.  This dict is the contract shared
-#: with :class:`fmda_tpu.analysis.compat_required.CompatRequiredRule`:
-#: a dotted reference listed here appearing anywhere on the kernel
-#: surface outside this module is a lint finding.  Importing it is
-#: jax-free by design (the analyzer runs on jax-free hosts).
+#: Every version-sensitive spelling — the installed one and the ones
+#: earlier releases used — mapped to the name here that covers it.  This
+#: dict is the contract shared with
+#: :class:`fmda_tpu.analysis.compat_required.CompatRequiredRule`: a dotted
+#: reference listed here appearing anywhere on the kernel surface outside
+#: this module is a lint finding.  Importing it is jax-free by design
+#: (the analyzer runs on jax-free hosts).
 SHIMMED_SYMBOLS: Dict[str, str] = {
     "jax.experimental.pallas.tpu.CompilerParams": "CompilerParams",
     "jax.experimental.pallas.tpu.TPUCompilerParams": "CompilerParams",
@@ -74,85 +61,28 @@ __all__ = [
 
 
 def _resolve_compiler_params() -> Any:
-    """``pallas_call(compiler_params=...)`` dataclass under either name.
-
-    Both spellings take the same ``dimension_semantics=`` field the
-    kernels pass; newer jax renamed the class, not the schema.
-    """
+    """The ``pallas_call(compiler_params=...)`` dataclass."""
     from jax.experimental.pallas import tpu as pltpu
 
-    new = getattr(pltpu, "CompilerParams", None)
-    if new is not None:
-        return new
-    return pltpu.TPUCompilerParams
+    return pltpu.CompilerParams
 
 
 def _resolve_axis_size() -> Callable[[str], int]:
     import jax
 
-    native = getattr(jax.lax, "axis_size", None)
-    if native is not None:
-        return native
-
-    def axis_size(axis_name) -> int:
-        """Size of a named mesh axis, inside shard_map/pmap bodies.
-
-        ``psum`` of the Python constant 1 constant-folds to the axis
-        size as a static int — the pre-``jax.lax.axis_size`` idiom — so
-        callers can keep using it in ``range(...)`` at trace time.
-        """
-        return jax.lax.psum(1, axis_name)
-
-    return axis_size
+    return jax.lax.axis_size
 
 
 def _resolve_pcast() -> Callable[..., Any]:
     import jax
 
-    native = getattr(jax.lax, "pcast", None)
-    if native is not None:
-        return native
-
-    def pcast(x, axes, to=None):
-        """Identity: this jax predates the varying-manual-axes type
-        system, so there is nothing to cast — values inside shard_map
-        are untyped w.r.t. replication (pair with ``check_vma=False``,
-        which the shimmed :func:`shard_map` maps to ``check_rep=False``).
-        """
-        del axes, to
-        return x
-
-    return pcast
+    return jax.lax.pcast
 
 
 def _resolve_shard_map() -> Callable[..., Any]:
     import jax
 
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-
-        def shard_map(f=None, **kwargs):
-            if f is None:  # bare-kwargs decorator form
-                return lambda fn: shard_map(fn, **kwargs)
-            return native(f, **kwargs)
-
-        return shard_map
-
-    from jax.experimental.shard_map import shard_map as old_shard_map
-
-    def shard_map(f=None, *, mesh, in_specs, out_specs, check_vma=True):
-        """Old-API shard_map with the new keyword surface: ``check_vma``
-        (the new name for the output-replication/varying checker)
-        translates to ``check_rep``."""
-        if f is None:
-            return lambda fn: shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma)
-        return old_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma)
-
-    return shard_map
+    return jax.shard_map
 
 
 def _resolve_cost_analysis() -> Callable[..., Any]:
@@ -162,11 +92,9 @@ def _resolve_cost_analysis() -> Callable[..., Any]:
     is re-lowered against **abstract** arguments (``ShapeDtypeStruct``
     per array leaf — the concrete buffers may already be donated and
     deleted by the time the compile ledger probes), compiled, and the
-    compiled object's ``cost_analysis`` is read.  Newer jax returns a
-    flat dict (``{"flops": ..., "bytes accessed": ...}``), some 0.4.x
-    builds wrap it in a one-element list, and older builds lack the
-    method entirely — all three normalise here, with ``None`` meaning
-    "this jax cannot cost programs" (the ledger counts, never raises).
+    compiled object's ``cost_analysis`` dict (``{"flops": ...,
+    "bytes accessed": ...}``) is returned; ``None`` when XLA reports no
+    costs for the program.
     """
     import jax
 
@@ -181,13 +109,7 @@ def _resolve_cost_analysis() -> Callable[..., Any]:
         kwargs = kwargs or {}
         a_args, a_kwargs = jax.tree_util.tree_map(_abstract,
                                                   (args, kwargs))
-        compiled = jitted.lower(*a_args, **a_kwargs).compile()
-        probe = getattr(compiled, "cost_analysis", None)
-        if probe is None:
-            return None
-        cost = probe()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else None
+        cost = jitted.lower(*a_args, **a_kwargs).compile().cost_analysis()
         return dict(cost) if cost else None
 
     return cost_analysis
@@ -203,8 +125,8 @@ _RESOLVERS: Dict[str, Callable[[], Any]] = {
 
 
 def __getattr__(name: str) -> Any:
-    """Probe the installed jax on first access and cache the winner in
-    the module dict (later lookups never re-enter here)."""
+    """Resolve on first access and cache in the module dict (later
+    lookups never re-enter here)."""
     resolver = _RESOLVERS.get(name)
     if resolver is None:
         raise AttributeError(

@@ -18,8 +18,11 @@ exists to buy):
 - the launcher blocks until membership is complete, so bootstrap joins
   never migrate anything.
 
-The launcher is router-role code: no jax (the workers own the
-accelerator math in their own processes).
+The launcher is router-role code: no jax (the workers own the model
+math in their own processes).  Every worker is forced onto the host
+platform (:data:`WORKER_PLATFORM`): a chip belongs to one process, so N
+local workers cannot share one — the multi-worker topology is a
+host-side topology until each worker is given its own device.
 """
 
 from __future__ import annotations
@@ -43,6 +46,10 @@ from fmda_tpu.fleet.router import FleetRouter
 from fmda_tpu.fleet.wire import BusServer
 
 log = logging.getLogger("fmda_tpu.fleet")
+
+#: The platform every spawned worker is forced onto (``--platform``);
+#: the ``--role local`` report states it beside the workers' stats.
+WORKER_PLATFORM = "cpu"
 
 
 def spawn_supported(python: str = sys.executable) -> bool:
@@ -253,7 +260,6 @@ def launch_local_fleet(
     max_linger_ms: Optional[float] = None,
     window: Optional[int] = None,
     trace_dir: Optional[str] = None,
-    platform: str = "cpu",
     wait_timeout_s: float = 180.0,
     python: str = sys.executable,
     log_dir: Optional[str] = None,
@@ -301,7 +307,7 @@ def launch_local_fleet(
             argv = [
                 python, "-m", "fmda_tpu", "serve-fleet",
                 "--role", "worker",
-                "--platform", platform,
+                "--platform", WORKER_PLATFORM,
                 "--worker-id", wid,
                 "--connect", address,
                 "--hidden", str(hidden),

@@ -23,9 +23,9 @@ the module must be importable on router-role analysis hosts):
   program: compiles, calls, compile seconds, and (where the installed
   jax supports ``cost_analysis``, probed through
   :mod:`fmda_tpu.compat`) per-program FLOPs/bytes-accessed.  Scrape
-  time derives ``device_mfu`` / arithmetic-intensity gauges against a
-  per-backend peak table — estimated peaks on CPU/interpret hosts so
-  tier-1 exercises the whole path, real peaks when a TPU appears.
+  time derives the arithmetic-intensity gauge and, for a device kind
+  with a published peak (:data:`DEVICE_PEAKS`), ``device_mfu``; any
+  other device gets no MFU gauge at all rather than an estimated one.
 - :class:`DeviceMemoryMonitor` — a cadence-gated sampler over
   ``jax.live_arrays()`` (plus ``device.memory_stats()`` where the
   backend exposes it) with per-owner attribution (pools register a
@@ -73,25 +73,13 @@ PROGRAM_SCHEMA = (
     "unexpected", "flops", "bytes_accessed",
 )
 
-#: per-backend peak FLOP/s for MFU accounting.  TPU/GPU entries are
-#: representative datasheet numbers (TPU v5e bf16; A100 bf16); the
-#: cpu/interpreter entries are deliberate *estimates* so the whole MFU
-#: path runs (and is tested) on CPU containers — the absolute value is
-#: wrong there and documented as such, the plumbing is what tier-1
-#: exercises.
-PEAK_FLOPS: Dict[str, float] = {
-    "tpu": 197e12,
-    "gpu": 312e12,
-    "cpu": 5e10,
-    "interpreter": 1e9,
-}
-
-#: per-backend peak memory bandwidth (bytes/s) for roofline position
-PEAK_BYTES_PER_S: Dict[str, float] = {
-    "tpu": 819e9,
-    "gpu": 2039e9,
-    "cpu": 2e10,
-    "interpreter": 1e9,
+#: Published per-chip peaks, keyed by jax ``device_kind``: (dense bf16
+#: FLOP/s, HBM bytes/s).  Source: Google Cloud TPU documentation, "TPU
+#: v5e" (197 TFLOP/s bf16, 819 GB/s).  The one table for the package and
+#: ``bench.py``.  A kind that is not here has no peak: utilization and
+#: roofline numbers are then absent (``None``), never estimated.
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
 }
 
 
@@ -330,9 +318,10 @@ class CompileLedger:
         # captures (device caches, parameter trees) — for process life
         self._functions: List["weakref.ref[TrackedFunction]"] = []
         self._backend: Optional[str] = None
+        self._device_kind: Optional[str] = None
         self._cost_probe_failures = 0
         self._mfu_prev: Optional[Tuple[float, float, float]] = None
-        self._mfu = 0.0
+        self._mfu: Optional[float] = None
         self._intensity = 0.0
 
     # -- registration --------------------------------------------------------
@@ -359,29 +348,35 @@ class CompileLedger:
         with self._lock:
             self._functions = []
             self._backend = None
+            self._device_kind = None
             self._cost_probe_failures = 0
             self._mfu_prev = None
-            self._mfu = 0.0
+            self._mfu = None
             self._intensity = 0.0
 
     # -- compile events ------------------------------------------------------
 
     def backend(self) -> str:
+        return self._device()[0]
+
+    def _device(self) -> Tuple[str, Optional[str]]:
+        """(platform, device_kind) of the default device, read once."""
         with self._lock:
             if self._backend is not None:
-                return self._backend
-        name = "unknown"
+                return self._backend, self._device_kind
+        name, kind = "unknown", None
         try:
             import jax
 
-            name = str(jax.default_backend())
+            dev = jax.devices()[0]
+            name, kind = str(dev.platform), str(dev.device_kind)
         except Exception:  # noqa: BLE001 — loss-free: a jax-free or
             # broken-runtime host still gets a ledger, just without a
-            # backend name (MFU reads 0 against the estimated peak)
+            # device name (and therefore without an MFU gauge)
             pass
         with self._lock:
-            self._backend = name
-        return name
+            self._backend, self._device_kind = name, kind
+        return name, kind
 
     def _on_compile(self, fn: TrackedFunction, sig: object, dt: float,
                     unexpected: bool, args: tuple, kwargs: dict, *,
@@ -450,8 +445,9 @@ class CompileLedger:
     def flops_done(self) -> float:
         return sum(f._totals()[3] for f in self.functions())
 
-    def mfu(self) -> float:
-        """Last scrape-interval MFU (0.0 until two scrapes land)."""
+    def mfu(self) -> Optional[float]:
+        """Last scrape-interval MFU; ``None`` until two scrapes land, and
+        always ``None`` on a device kind without a published peak."""
         with self._lock:
             return self._mfu
 
@@ -532,8 +528,8 @@ class CompileLedger:
                 "labels": {},
                 "value": self._cost_probe_failures,
             })
-        backend = self.backend()
-        peak = PEAK_FLOPS.get(backend, PEAK_FLOPS["cpu"])
+        backend, device_kind = self._device()
+        peaks = DEVICE_PEAKS.get(device_kind)
         now = time.monotonic()
         with self._lock:
             prev = self._mfu_prev
@@ -542,14 +538,16 @@ class CompileLedger:
                 elapsed = now - prev[0]
                 d_flops = max(0.0, flops_done - prev[1])
                 d_bytes = max(0.0, bytes_done - prev[2])
-                self._mfu = d_flops / elapsed / peak
+                if peaks is not None:
+                    self._mfu = d_flops / elapsed / peaks[0]
                 self._intensity = (d_flops / d_bytes) if d_bytes else 0.0
             mfu, intensity = self._mfu, self._intensity
-        gauges.append({
-            "name": "device_mfu",
-            "labels": {"backend": backend},
-            "value": mfu,
-        })
+        if mfu is not None:
+            gauges.append({
+                "name": "device_mfu",
+                "labels": {"backend": backend, "device_kind": device_kind},
+                "value": mfu,
+            })
         gauges.append({
             "name": "device_arithmetic_intensity",
             "labels": {"backend": backend},

@@ -146,16 +146,15 @@ def _recurrent_cell_ops(cell: str, use_pallas: bool = False) -> CellOps:
             ) if use_pallas else ssm_cell_step
             return step(xp, carry, w)
 
-        # Numerical caveat (measured, documented): the ssm tick is a
-        # pure elementwise chain with no matmul anchors after the input
-        # projection, so XLA's fusion/FMA choices can differ BETWEEN
-        # separately compiled programs by ~1 ulp at some shapes (seen
-        # at F=108 solo-core vs pool on CPU; the gru/lstm chains are
-        # pinned by their h@W_hh matmul and compile identically).
-        # Same-program contracts — migration export/import, drain/
-        # replay, chaos identity, every pool<->pool comparison — remain
-        # bit-exact; solo-vs-pool comparisons at untested shapes may
-        # sit at the last bit (the batched 1e-6 contract still holds).
+        # Numerical contract (docs/runtime.md "Numerical contract"): a
+        # solo core and the pool are two compiled programs.  Same-program
+        # comparisons — migration export/import, drain/replay, chaos
+        # identity, every pool<->pool comparison — are bit-exact, for
+        # every family.  Solo-vs-pool holds to a tolerance: 1 ulp apart
+        # on XLA-CPU already (gru and ssm at bucket 1, jax 0.9.0), 1e-6
+        # in the tests; on the TPU batched programs multiply at the
+        # MXU's default precision and batch-1 programs do not, which
+        # chip_smoke.py measures and bounds.
         return CellOps(gate_step, None, 3, 3, "carry")
     raise ValueError(
         "the carried-state streaming cores cover the recurrent families "

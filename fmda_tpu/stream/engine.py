@@ -830,6 +830,12 @@ class StreamEngine:
     # -- observability -------------------------------------------------------
 
     @property
+    def join_scheduler(self) -> str:
+        """``"native"`` when the C++ core runs the matching loop, else
+        ``"python"`` — what actually runs, after every fallback above."""
+        return "native" if self._core is not None else "python"
+
+    @property
     def stats(self) -> Dict[str, object]:
         """Counters plus the lag/watermark observability the reference
         sketched but never wired (spark_consumer.py:48-66's unused

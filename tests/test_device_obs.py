@@ -252,24 +252,13 @@ class _FakeJit:
         return type("L", (), {"compile": lambda self_: compiled})()
 
 
-def test_cost_analysis_probe_returns_dict_and_unwraps_lists():
+def test_cost_analysis_probe_returns_dict_or_none():
     cost = compat.cost_analysis(
         _FakeJit({"flops": 12.0, "bytes accessed": 34.0}),
         (jnp.ones((2, 3)),))
     assert cost == {"flops": 12.0, "bytes accessed": 34.0}
-    # some jax versions hand back a list of per-computation dicts
-    cost = compat.cost_analysis(
-        _FakeJit([{"flops": 5.0}]), (jnp.ones((2,)),))
-    assert cost == {"flops": 5.0}
-
-
-def test_cost_analysis_probe_none_when_method_missing():
-    class NoCost:
-        def lower(self, *a, **k):
-            compiled = object()  # no cost_analysis attribute
-            return type("L", (), {"compile": lambda self_: compiled})()
-
-    assert compat.cost_analysis(NoCost(), (jnp.ones((2,)),)) is None
+    # XLA reporting no costs for a program reads as None, not {}
+    assert compat.cost_analysis(_FakeJit({}), (jnp.ones((2,)),)) is None
 
 
 def test_cost_probe_failure_is_counted_never_raised():
@@ -469,6 +458,24 @@ def test_device_report_shape():
                         memory=DeviceMemoryMonitor())
     assert set(doc) == {"ledger", "memory", "kernel_fallbacks",
                         "recompiles_after_warmup", "mfu"}
+    # the cpu has no published peak: the MFU is absent, not estimated
+    assert doc["mfu"] is None
+
+
+def test_no_mfu_gauge_for_a_device_kind_without_a_published_peak():
+    led = CompileLedger(enabled=True)
+    for _ in range(2):  # two scrapes: the interval the gauge derives over
+        names = {g["name"] for g in led.families()["gauges"]}
+    assert "device_arithmetic_intensity" in names
+    assert "device_mfu" not in names
+    assert led.mfu() is None
+    # with a published peak the same scrape derives the gauge
+    led._backend, led._device_kind = "tpu", "TPU v5 lite"
+    led.families()
+    gauge = [g for g in led.families()["gauges"] if g["name"] == "device_mfu"]
+    assert gauge and gauge[0]["labels"] == {
+        "backend": "tpu", "device_kind": "TPU v5 lite"}
+    assert gauge[0]["value"] == led.mfu() == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -90,7 +90,7 @@ def test_local_topology_end_to_end_with_trace_merge(tmp_path):
 def test_worker_role_cli_requires_connect_args(capsys):
     from fmda_tpu.cli import main
 
-    rc = main(["serve-fleet", "--role", "worker", "--platform", "ambient"])
+    rc = main(["serve-fleet", "--role", "worker"])
     assert rc == 2
     assert "--worker-id" in capsys.readouterr().err
 

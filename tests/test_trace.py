@@ -258,7 +258,7 @@ def test_trace_cli_reports_slowest_breakdown(tracer, tmp_path, capsys):
     gw.drain()
     path = tmp_path / "trace.json"
     path.write_text(json.dumps(tracer.chrome()))
-    assert main(["trace", "--platform", "ambient", "--input", str(path),
+    assert main(["trace", "--input", str(path),
                  "--slowest", "1"]) == 0
     out = capsys.readouterr().out
     assert "root=tick" in out
