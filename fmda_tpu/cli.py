@@ -283,19 +283,35 @@ def _continuous_train(wh, cfg, *, checkpoint_dir, max_rounds, seed):
 def cmd_train(args) -> int:
     _select_backend(args)
     cfg = _config(args)
-    if args.continuous:
-        out = _continuous_train(
-            _warehouse(args.warehouse, cfg), cfg,
-            checkpoint_dir=_ckpt_dir(args, cfg),
-            max_rounds=args.max_rounds, seed=args.seed,
-        )
-        return 0 if out and out["rounds"] > 0 else 2
-    ckpt = _train(
-        _warehouse(args.warehouse, cfg), cfg, epochs=args.epochs,
-        batch_size=args.batch_size, checkpoint_dir=_ckpt_dir(args, cfg),
-        seed=args.seed,
-    )
-    return 0 if ckpt else 2
+    import contextlib
+
+    profile = contextlib.nullcontext()
+    if args.jax_profile:
+        # the trainer's host spans and named scopes are always compiled
+        # in; a capture is what makes them visible (docs/training.md
+        # "Profiling a run").  Whole-run capture: keep --epochs small.
+        from fmda_tpu.utils.tracing import device_trace
+
+        profile = device_trace(args.jax_profile)
+    with profile:
+        if args.continuous:
+            out = _continuous_train(
+                _warehouse(args.warehouse, cfg), cfg,
+                checkpoint_dir=_ckpt_dir(args, cfg),
+                max_rounds=args.max_rounds, seed=args.seed,
+            )
+            ok = bool(out and out["rounds"] > 0)
+        else:
+            ok = bool(_train(
+                _warehouse(args.warehouse, cfg), cfg, epochs=args.epochs,
+                batch_size=args.batch_size,
+                checkpoint_dir=_ckpt_dir(args, cfg), seed=args.seed,
+            ))
+    if args.jax_profile:
+        print(f"jax profile captured to {args.jax_profile} (tensorboard "
+              "--logdir, or benchmark/tools/span_report.py on its "
+              ".xplane.pb)", file=sys.stderr)
+    return 0 if ok else 2
 
 
 def _backtest(wh, cfg, ckpt: str, *, window: int, threshold: float) -> int:
@@ -2257,6 +2273,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max-rounds", type=int, default=None,
                    help="bound --continuous fine-tune rounds "
                         "(default: until the warehouse quiesces)")
+    p.add_argument("--jax-profile", default=None, metavar="DIR",
+                   help="capture a jax device profile of the run "
+                        "(TensorBoard/XProf): the step loop's host "
+                        "spans beside the compiled steps' named scopes")
     p.set_defaults(fn=cmd_train)
 
     p = sub.add_parser("backtest", parents=[common], help="score a checkpoint over history")

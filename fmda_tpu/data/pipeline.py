@@ -204,7 +204,6 @@ def prefetch_batches(
     place: Callable[[Batch], Batch],
     *,
     depth: int = 2,
-    stall_observer: Optional[Callable[[float], None]] = None,
 ) -> Iterator[Batch]:
     """Depth-N double-buffered input pipeline.
 
@@ -214,24 +213,24 @@ def prefetch_batches(
     (``jax.device_put`` dispatches async — the transfer also overlaps),
     and up to ``depth`` placed batches ride ahead of the consumer.
 
-    ``stall_observer(seconds)`` is called with the host-side wait per
-    pull — the time the step loop would have spent blocked on input
-    (exported as the ``train_input_stall_seconds`` histogram).  The
-    first ``depth`` pulls include pipeline warm-up by design, the same
-    way the first ``train_step_seconds`` bin carries the compile.
+    The refill runs in the consumer's ``next()``: the pull from the
+    composer's queue under the host span ``input_compose`` and the
+    placement under ``input_place`` (utils/tracing.py ``span``; both
+    nest inside the step loop's ``train_next_batch``).  The wait itself
+    is measured by the consumer, around that ``next()``
+    (``train_input_stall_seconds``, Trainer._run_batches); the first
+    ``depth`` pulls include pipeline warm-up by design.
 
     ``depth=0`` degrades to a synchronous place-per-batch loop with no
-    background thread (still observed) — the seed behavior.
+    background thread — the seed behavior.
     """
-    import time as _time
+    from fmda_tpu.utils.tracing import span
 
     if depth <= 0:
         def sync() -> Iterator[Batch]:
             for b in batches:
-                t0 = _time.perf_counter()
-                out = place(b)
-                if stall_observer is not None:
-                    stall_observer(_time.perf_counter() - t0)
+                with span("input_place"):
+                    out = place(b)
                 yield out
         return sync()
 
@@ -243,15 +242,13 @@ def prefetch_batches(
         exhausted = False
         while True:
             while not exhausted and len(queue) < depth:
-                t0 = _time.perf_counter()
-                try:
-                    b = next(it)
-                except StopIteration:
+                with span("input_compose"):
+                    b = next(it, None)
+                if b is None:
                     exhausted = True
                     break
-                queue.append(place(b))
-                if stall_observer is not None:
-                    stall_observer(_time.perf_counter() - t0)
+                with span("input_place"):
+                    queue.append(place(b))
             if not queue:
                 return
             yield queue.popleft()

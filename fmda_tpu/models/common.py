@@ -48,27 +48,28 @@ def pool_concat_logits(
     full windows and divides by the constant length); logits are always
     returned in float32.
     """
-    if mask is None:
-        max_pool = jnp.max(out_sum, axis=1)
-        avg_pool = jnp.sum(out_sum, axis=1) / jnp.asarray(
-            seq_len, dtype=compute_dtype
-        )
-    else:
-        m = mask[..., None].astype(compute_dtype)
-        neg = jnp.asarray(jnp.finfo(compute_dtype).min, compute_dtype)
-        max_pool = jnp.max(jnp.where(m > 0, out_sum, neg), axis=1)
-        denom = jnp.maximum(jnp.sum(m, axis=1), 1.0)
-        avg_pool = jnp.sum(out_sum * m, axis=1) / denom
+    with jax.named_scope("head"):
+        if mask is None:
+            max_pool = jnp.max(out_sum, axis=1)
+            avg_pool = jnp.sum(out_sum, axis=1) / jnp.asarray(
+                seq_len, dtype=compute_dtype
+            )
+        else:
+            m = mask[..., None].astype(compute_dtype)
+            neg = jnp.asarray(jnp.finfo(compute_dtype).min, compute_dtype)
+            max_pool = jnp.max(jnp.where(m > 0, out_sum, neg), axis=1)
+            denom = jnp.maximum(jnp.sum(m, axis=1), 1.0)
+            avg_pool = jnp.sum(out_sum * m, axis=1) / denom
 
-    concat = jnp.concatenate([last_hidden, max_pool, avg_pool], axis=-1)
-    scale = 1.0 / jnp.sqrt(3 * cfg.hidden_size)
-    logits = nn.Dense(
-        cfg.output_size,
-        name="linear",
-        kernel_init=_torch_uniform_init(scale),
-        bias_init=_torch_uniform_init(scale),
-    )(concat)
-    return logits.astype(jnp.float32)
+        concat = jnp.concatenate([last_hidden, max_pool, avg_pool], axis=-1)
+        scale = 1.0 / jnp.sqrt(3 * cfg.hidden_size)
+        logits = nn.Dense(
+            cfg.output_size,
+            name="linear",
+            kernel_init=_torch_uniform_init(scale),
+            bias_init=_torch_uniform_init(scale),
+        )(concat)
+        return logits.astype(jnp.float32)
 
 
 def ema_concat_logits(
@@ -85,15 +86,17 @@ def ema_concat_logits(
     serving).  Serve-side twin: ``fmda_tpu.serve.streaming
     .ema_head_logits`` reads the same ``linear`` params — concat order
     ``[h_last, ema_fast, ema_slow]`` is part of that contract."""
-    concat = jnp.concatenate([last_hidden, ema_fast, ema_slow], axis=-1)
-    scale = 1.0 / jnp.sqrt(3 * cfg.hidden_size)
-    logits = nn.Dense(
-        cfg.output_size,
-        name="linear",
-        kernel_init=_torch_uniform_init(scale),
-        bias_init=_torch_uniform_init(scale),
-    )(concat)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("head"):
+        concat = jnp.concatenate(
+            [last_hidden, ema_fast, ema_slow], axis=-1)
+        scale = 1.0 / jnp.sqrt(3 * cfg.hidden_size)
+        logits = nn.Dense(
+            cfg.output_size,
+            name="linear",
+            kernel_init=_torch_uniform_init(scale),
+            bias_init=_torch_uniform_init(scale),
+        )(concat)
+        return logits.astype(jnp.float32)
 
 
 def _torch_uniform_init(scale: float):

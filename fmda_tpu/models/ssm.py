@@ -214,10 +214,11 @@ class GatedSSM(nn.Module):
             rs = jax.nn.sigmoid(last_w_fwd.rho_s)
             af = jnp.where(m > 0, jnp.broadcast_to(rf, out_sum.shape), 1.0)
             as_ = jnp.where(m > 0, jnp.broadcast_to(rs, out_sum.shape), 1.0)
-            ema_fast = linear_scan_parallel(
-                af, (1.0 - af) * out_sum, ef0)[:, -1]
-            ema_slow = linear_scan_parallel(
-                as_, (1.0 - as_) * out_sum, es0)[:, -1]
+            with jax.named_scope("head"):
+                ema_fast = linear_scan_parallel(
+                    af, (1.0 - af) * out_sum, ef0)[:, -1]
+                ema_slow = linear_scan_parallel(
+                    as_, (1.0 - as_) * out_sum, es0)[:, -1]
             # the "last hidden" of a padded window reads the last VALID
             # forward step (+ the backward scan end, which already sits
             # at t=0 — the reversed scan crossed the padding first)

@@ -759,9 +759,14 @@ def tracked_jit(fn, *, name: str,
     signature (the pools pass the padded batch size); without it the
     signature is derived from leaf shapes, but only on compile events
     — the steady-state path never tree-flattens.  ``jit_kwargs`` pass
-    straight through (``donate_argnums``, shardings, ...)."""
+    straight through (``donate_argnums``, shardings, ...).
+
+    ``fn`` takes ``name`` as its ``__name__`` before it is jitted, so
+    that a device profile's ``XLA Modules`` line reads ``jit_<name>``:
+    one name in the trace, the compile ledger and the docs."""
     import jax
 
+    fn.__name__ = name
     if ledger is None:
         ledger = default_ledger()
     tracked = TrackedFunction(

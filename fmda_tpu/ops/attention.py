@@ -195,23 +195,27 @@ def mha(
     Returns (B, N, Tq, D) in q's dtype.
     """
     tq, tk = q.shape[-2], k.shape[-2]
-    if flash_dispatch(tq, tk, q.shape[-1], use_flash=use_flash,
-                      has_mask=mask is not None):
-        from fmda_tpu.ops import pallas_attention
+    # the attn family's place in the scope vocabulary: where the
+    # recurrent families have recurrence_fwd/_rev
+    with jax.named_scope("attention"):
+        if flash_dispatch(tq, tk, q.shape[-1], use_flash=use_flash,
+                          has_mask=mask is not None):
+            from fmda_tpu.ops import pallas_attention
 
-        return pallas_attention.flash_attention(q, k, v, causal=causal)
-    full_mask = None
-    if causal:
-        # suffix alignment: query i sits at global position tk - tq + i, so
-        # a short query block against a longer K/V history (streaming) sees
-        # its full past, not just the first i keys
-        q_pos = tk - tq + jnp.arange(tq)
-        full_mask = q_pos[:, None] >= jnp.arange(tk)[None, :]
-    if mask is not None:
-        full_mask = mask if full_mask is None else (full_mask & mask)
-    state = init_online_state(q.shape[0], q.shape[1], tq, q.shape[-1])
-    state = online_attention_block(state, q, k, v, full_mask)
-    return finalize_online_state(state, q.dtype)
+            return pallas_attention.flash_attention(q, k, v, causal=causal)
+        full_mask = None
+        if causal:
+            # suffix alignment: query i sits at global position
+            # tk - tq + i, so a short query block against a longer K/V
+            # history (streaming) sees its full past, not just the first
+            # i keys
+            q_pos = tk - tq + jnp.arange(tq)
+            full_mask = q_pos[:, None] >= jnp.arange(tk)[None, :]
+        if mask is not None:
+            full_mask = mask if full_mask is None else (full_mask & mask)
+        state = init_online_state(q.shape[0], q.shape[1], tq, q.shape[-1])
+        state = online_attention_block(state, q, k, v, full_mask)
+        return finalize_online_state(state, q.dtype)
 
 
 def split_heads(x: jax.Array, n_heads: int) -> jax.Array:

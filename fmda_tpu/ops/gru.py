@@ -41,7 +41,17 @@ class GRUWeights(NamedTuple):
 
 def input_projection(x: jax.Array, weights: GRUWeights) -> jax.Array:
     """All-timestep input projection: (B, T, F) -> (B, T, 3H)."""
-    return jnp.einsum("btf,gf->btg", x, weights.w_ih) + weights.b_ih
+    with jax.named_scope("input_projection"):
+        return jnp.einsum("btf,gf->btg", x, weights.w_ih) + weights.b_ih
+
+
+def recurrence_scope(reverse: bool):
+    """The named scope every family's recurrence runs under, whichever
+    implementation was selected (``lax.scan``, associative scan, Pallas
+    kernel): ``recurrence_fwd`` / ``recurrence_rev``.  Metadata only —
+    a profile's device operations carry it in their scope path
+    (docs/observability.md "Spans and scopes")."""
+    return jax.named_scope("recurrence_rev" if reverse else "recurrence_fwd")
 
 
 def gru_gates(
@@ -184,13 +194,17 @@ def gru_layer(
     scan_fn = select_scan_fn(
         use_pallas, mask,
         shape=(batch, x.shape[1], hidden), itemsize=x.dtype.itemsize)
-    if scan_fn is not gru_scan:
-        # The Pallas kernel pair already rematerialises: the backward
-        # kernel stores only the forward outputs (hs) and recomputes the
-        # gates in-VMEM per step, so `remat` is inherently satisfied.
-        return scan_fn(xp, h0, weights.w_hh, weights.b_hh, reverse=reverse)
-    if remat:
-        return jax.checkpoint(
-            functools.partial(gru_scan, reverse=reverse, mask=mask)
-        )(xp, h0, weights.w_hh, weights.b_hh)
-    return gru_scan(xp, h0, weights.w_hh, weights.b_hh, reverse=reverse, mask=mask)
+    with recurrence_scope(reverse):
+        if scan_fn is not gru_scan:
+            # The Pallas kernel pair already rematerialises: the backward
+            # kernel stores only the forward outputs (hs) and recomputes
+            # the gates in-VMEM per step, so `remat` is inherently
+            # satisfied.
+            return scan_fn(
+                xp, h0, weights.w_hh, weights.b_hh, reverse=reverse)
+        if remat:
+            return jax.checkpoint(
+                functools.partial(gru_scan, reverse=reverse, mask=mask)
+            )(xp, h0, weights.w_hh, weights.b_hh)
+        return gru_scan(
+            xp, h0, weights.w_hh, weights.b_hh, reverse=reverse, mask=mask)

@@ -28,6 +28,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from fmda_tpu.ops.gru import recurrence_scope
+
 
 class LSTMWeights(NamedTuple):
     """One direction's parameters, torch-layout."""
@@ -40,7 +42,8 @@ class LSTMWeights(NamedTuple):
 
 def lstm_input_projection(x: jax.Array, weights: LSTMWeights) -> jax.Array:
     """All-timestep input projection: (B, T, F) -> (B, T, 4H)."""
-    return jnp.einsum("btf,gf->btg", x, weights.w_ih) + weights.b_ih
+    with jax.named_scope("input_projection"):
+        return jnp.einsum("btf,gf->btg", x, weights.w_ih) + weights.b_ih
 
 
 def lstm_gates(
@@ -182,15 +185,18 @@ def lstm_layer(
     scan_fn = select_lstm_scan_fn(
         use_pallas, mask,
         shape=(batch, x.shape[1], hidden), itemsize=x.dtype.itemsize)
-    if scan_fn is not lstm_scan:
-        # the Pallas pair already rematerialises (backward recomputes the
-        # gates in-VMEM from hs/cs), so `remat` is inherently satisfied
-        return scan_fn(xp, h0, c0, weights.w_hh, weights.b_hh,
-                       reverse=reverse)
-    if remat:
-        return jax.checkpoint(
-            functools.partial(lstm_scan, reverse=reverse, mask=mask)
-        )(xp, h0, c0, weights.w_hh, weights.b_hh)
-    return lstm_scan(
-        xp, h0, c0, weights.w_hh, weights.b_hh, reverse=reverse, mask=mask
-    )
+    with recurrence_scope(reverse):
+        if scan_fn is not lstm_scan:
+            # the Pallas pair already rematerialises (backward recomputes
+            # the gates in-VMEM from hs/cs), so `remat` is inherently
+            # satisfied
+            return scan_fn(xp, h0, c0, weights.w_hh, weights.b_hh,
+                           reverse=reverse)
+        if remat:
+            return jax.checkpoint(
+                functools.partial(lstm_scan, reverse=reverse, mask=mask)
+            )(xp, h0, c0, weights.w_hh, weights.b_hh)
+        return lstm_scan(
+            xp, h0, c0, weights.w_hh, weights.b_hh, reverse=reverse,
+            mask=mask,
+        )

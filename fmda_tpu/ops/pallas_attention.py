@@ -170,6 +170,7 @@ def _fwd_impl(
     kernel = functools.partial(_fwd_kernel, causal=causal, n_k=n_blk)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bn, n_blk, n_blk),
         in_specs=[
             pl.BlockSpec((1, _BLOCK, d), lambda b, qi, ki: (b, qi, 0)),
@@ -335,6 +336,7 @@ def _bwd_impl(
     rspec = pl.BlockSpec((1, _BLOCK, 128), lambda b, ki, qi: (b, qi, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, n_q=n_blk),
+        name="flash_bwd_dkv",
         grid=(bn, n_blk, n_blk),
         in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
         out_specs=[
@@ -360,6 +362,7 @@ def _bwd_impl(
     rspec2 = pl.BlockSpec((1, _BLOCK, 128), lambda b, qi, ki: (b, qi, 0))
     (dq,) = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, n_k=n_blk),
+        name="flash_bwd_dq",
         grid=(bn, n_blk, n_blk),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
         out_specs=[qspec2],

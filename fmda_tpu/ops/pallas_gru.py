@@ -225,6 +225,7 @@ def _gru_scan_pallas_fwd_impl(
         _gru_step_kernel, block_t=block_t, reverse=reverse)
     hs_tm, h_last = pl.pallas_call(
         kernel,
+        name="gru_scan_fwd",
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_t, batch, 3 * hidden), time_map),
@@ -368,6 +369,7 @@ def _gru_scan_pallas_bwd_impl(
         _gru_bwd_kernel, block_t=block_t, reverse=reverse)
     dxp_tm, dh0, dwt, db = pl.pallas_call(
         kernel,
+        name="gru_scan_bwd",
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_t, batch, 3 * hidden), time_map),

@@ -152,6 +152,7 @@ def _lstm_fwd_impl(
         _lstm_step_kernel, block_t=block_t, reverse=reverse)
     hs_tm, cs_tm, h_last, c_last = pl.pallas_call(
         kernel,
+        name="lstm_scan_fwd",
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_t, batch, 4 * hidden), time_map),
@@ -319,6 +320,7 @@ def _lstm_bwd_impl(
         _lstm_bwd_kernel, block_t=block_t, reverse=reverse)
     dxp_tm, dh0, dc0, dwt, db = pl.pallas_call(
         kernel,
+        name="lstm_scan_bwd",
         grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec((block_t, batch, 4 * hidden), time_map),

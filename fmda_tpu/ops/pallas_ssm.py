@@ -105,6 +105,7 @@ def _ssm_step_pallas(
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     return pl.pallas_call(
         _ssm_step_kernel,
+        name="ssm_cell_step",
         out_shape=[out, out, out, out],
         in_specs=[vmem] * 8,
         out_specs=[vmem] * 4,

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from fmda_tpu.ops.dispatch import count_kernel_fallback
+from fmda_tpu.ops.gru import recurrence_scope
 
 
 class SSMWeights(NamedTuple):
@@ -85,7 +86,8 @@ def ssm_input_projection(x: jax.Array, weights: SSMWeights) -> jax.Array:
     """All-timestep input projection: (B, T, F) -> (B, T, 3H) — the one
     MXU-shaped matmul of the family, computed outside the recurrence
     exactly like the GRU/LSTM projection split."""
-    return jnp.einsum("btf,gf->btg", x, weights.w_ih) + weights.b_ih
+    with jax.named_scope("input_projection"):
+        return jnp.einsum("btf,gf->btg", x, weights.w_ih) + weights.b_ih
 
 
 def _split_gates(xp: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -140,9 +142,11 @@ def ssm_scan(
         h, c_new = ssm_cell_step(xp_t, c, w)
         return c_new, h
 
-    xs = jnp.swapaxes(xp, 0, 1)  # (T, B, 3H)
-    carry_last, hs = jax.lax.scan(step, tuple(carry), xs, reverse=reverse)
-    return carry_last, jnp.swapaxes(hs, 0, 1)
+    with recurrence_scope(reverse):
+        xs = jnp.swapaxes(xp, 0, 1)  # (T, B, 3H)
+        carry_last, hs = jax.lax.scan(
+            step, tuple(carry), xs, reverse=reverse)
+        return carry_last, jnp.swapaxes(hs, 0, 1)
 
 
 def linear_scan_parallel(
@@ -175,16 +179,17 @@ def ssm_scan_parallel(
     (hs, s_last) with hs (B, T, H).  Matches :func:`ssm_scan` to float
     tolerance (documented above), not bit — the associative tree
     reassociates the decay products."""
-    if reverse:
-        xp = jnp.flip(xp, axis=1)
-    zp, vp, gp = _split_gates(xp)
-    a = jax.nn.sigmoid(zp + w.a_base)
-    s = linear_scan_parallel(a, (1.0 - a) * vp, s0)
-    hs = s * jax.nn.silu(gp) + w.d * vp
-    s_last = s[:, -1]
-    if reverse:
-        hs = jnp.flip(hs, axis=1)
-    return hs, s_last
+    with recurrence_scope(reverse):
+        if reverse:
+            xp = jnp.flip(xp, axis=1)
+        zp, vp, gp = _split_gates(xp)
+        a = jax.nn.sigmoid(zp + w.a_base)
+        s = linear_scan_parallel(a, (1.0 - a) * vp, s0)
+        hs = s * jax.nn.silu(gp) + w.d * vp
+        s_last = s[:, -1]
+        if reverse:
+            hs = jnp.flip(hs, axis=1)
+        return hs, s_last
 
 
 def ema_pool_parallel(
@@ -194,10 +199,11 @@ def ema_pool_parallel(
     (``r = sigmoid(rho)``, per channel) over a window, in parallel mode.
     Returns (B, H) — the train-mode twin of the serving cache's
     ``ema_fast``/``ema_slow`` entries."""
-    r = jax.nn.sigmoid(rho)
-    a = jnp.broadcast_to(r, hs.shape)
-    e = linear_scan_parallel(a, (1.0 - r) * hs, ema0)
-    return e[:, -1]
+    with jax.named_scope("head"):
+        r = jax.nn.sigmoid(rho)
+        a = jnp.broadcast_to(r, hs.shape)
+        e = linear_scan_parallel(a, (1.0 - r) * hs, ema0)
+        return e[:, -1]
 
 
 def ssm_pallas_available() -> bool:

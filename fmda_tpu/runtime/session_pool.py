@@ -228,31 +228,44 @@ class SessionPool:
             may repeat freely — its scattered writes collide only with
             each other, in state nothing reads.
             """
-            x = ((rows - x_min[slots]) / x_range[slots]).astype(dtype)
-            pos_b = pos[slots]
-            carry_b = tuple(
-                tuple(c[slots] for c in layer) for layer in carry)
-            h_new, carry_new = advance_cells(params, cfg, gate_step, x,
-                                             carry_b)
+            # The named scopes are metadata on the compiled operations
+            # (docs/observability.md "Spans and scopes"): a profile's
+            # device time falls under normalize / recurrence /
+            # ring_update / head / state_writeback.
+            with jax.named_scope("normalize"):
+                x = ((rows - x_min[slots]) / x_range[slots]).astype(dtype)
+            with jax.named_scope("recurrence"):
+                pos_b = pos[slots]
+                carry_b = tuple(
+                    tuple(c[slots] for c in layer) for layer in carry)
+                h_new, carry_new = advance_cells(params, cfg, gate_step, x,
+                                                 carry_b)
             if self._head == "carry":
                 # ssm: pooling state rides the carry; the zero-width
                 # ring passes through untouched (kept for a uniform
                 # step signature/donation layout)
-                logits = ema_head_logits(params, h_new, carry_new[-1])
+                with jax.named_scope("head"):
+                    logits = ema_head_logits(params, h_new, carry_new[-1])
             else:
-                ring = ring.at[slots, pos_b % w].set(h_new)
-                ring_b = ring[slots]
-                # per-session valid trailing window: n_valid is (B, 1)
-                # here, a scalar in the solo carrier — same head either
-                # way
-                n_valid = jnp.minimum(pos_b + 1, w)[:, None]
-                logits = pooled_head_logits(params, h_new, ring_b, n_valid)
-            carry_out = tuple(
-                tuple(c.at[slots].set(cb)
-                      for c, cb in zip(carry[layer], carry_new[layer]))
-                for layer in range(cfg.n_layers))
-            pos = pos.at[slots].set(pos_b + 1)
-            return jax.nn.sigmoid(logits), carry_out, ring, pos
+                with jax.named_scope("ring_update"):
+                    ring = ring.at[slots, pos_b % w].set(h_new)
+                    ring_b = ring[slots]
+                with jax.named_scope("head"):
+                    # per-session valid trailing window: n_valid is
+                    # (B, 1) here, a scalar in the solo carrier — same
+                    # head either way
+                    n_valid = jnp.minimum(pos_b + 1, w)[:, None]
+                    logits = pooled_head_logits(
+                        params, h_new, ring_b, n_valid)
+            with jax.named_scope("state_writeback"):
+                carry_out = tuple(
+                    tuple(c.at[slots].set(cb)
+                          for c, cb in zip(carry[layer], carry_new[layer]))
+                    for layer in range(cfg.n_layers))
+                pos = pos.at[slots].set(pos_b + 1)
+            with jax.named_scope("head"):
+                probs = jax.nn.sigmoid(logits)
+            return probs, carry_out, ring, pos
 
         # carry/ring/pos are DONATED: the step advances the pooled state
         # in place (XLA aliases each donated input to its same-shape

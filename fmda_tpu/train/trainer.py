@@ -183,19 +183,21 @@ class Trainer:
 
         def grads_full(params, batch: Batch, dropout_rng):
             def loss_fn(params):
-                logits = model.apply(
-                    {"params": params},
-                    batch.x,
-                    deterministic=False,
-                    rngs={"dropout": dropout_rng},
-                )
-                loss = weighted_bce_with_logits(
-                    logits,
-                    batch.y,
-                    weight=weight,
-                    pos_weight=pos_weight,
-                    example_mask=batch.mask,
-                )
+                with jax.named_scope("forward"):
+                    logits = model.apply(
+                        {"params": params},
+                        batch.x,
+                        deterministic=False,
+                        rngs={"dropout": dropout_rng},
+                    )
+                with jax.named_scope("loss"):
+                    loss = weighted_bce_with_logits(
+                        logits,
+                        batch.y,
+                        weight=weight,
+                        pos_weight=pos_weight,
+                        example_mask=batch.mask,
+                    )
                 return loss, logits
 
             (loss, logits), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -219,19 +221,21 @@ class Trainer:
             )
 
             def sum_loss_fn(params, mb: Batch, mb_rng):
-                logits = model.apply(
-                    {"params": params},
-                    mb.x,
-                    deterministic=False,
-                    rngs={"dropout": mb_rng},
-                )
-                s, count = weighted_bce_sums(
-                    logits,
-                    mb.y,
-                    weight=weight,
-                    pos_weight=pos_weight,
-                    example_mask=mb.mask,
-                )
+                with jax.named_scope("forward"):
+                    logits = model.apply(
+                        {"params": params},
+                        mb.x,
+                        deterministic=False,
+                        rngs={"dropout": mb_rng},
+                    )
+                with jax.named_scope("loss"):
+                    s, count = weighted_bce_sums(
+                        logits,
+                        mb.y,
+                        weight=weight,
+                        pos_weight=pos_weight,
+                        example_mask=mb.mask,
+                    )
                 return s, (count, logits)
 
             def body(carry, xs):
@@ -263,24 +267,31 @@ class Trainer:
             return loss_sum / denom, logits, grads
 
         def step_fn(state: TrainState, batch: Batch, rng: jax.Array):
-            dropout_rng = jax.random.fold_in(rng, state.step)
+            with jax.named_scope("forward"):  # the forward's dropout key
+                dropout_rng = jax.random.fold_in(rng, state.step)
             if accum == 1:
                 loss, logits, grads = grads_full(
                     state.params, batch, dropout_rng)
             else:
                 loss, logits, grads = grads_accum(
                     state.params, batch, dropout_rng)
-            updates, opt_state = self.optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            params = optax.apply_updates(state.params, updates)
-            metrics = multilabel_metrics(
-                logits,
-                batch.y,
-                threshold=tc.prob_threshold,
-                beta=tc.fbeta_beta,
-                example_mask=batch.mask,
-            )
+            # named scopes are metadata on the compiled operations (the
+            # profile's device lines read them; docs/observability.md
+            # "Spans and scopes"): forward / loss inside the gradient,
+            # whose backward half JAX names transpose(jvp(forward))
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("metrics"):
+                metrics = multilabel_metrics(
+                    logits,
+                    batch.y,
+                    threshold=tc.prob_threshold,
+                    beta=tc.fbeta_beta,
+                    example_mask=batch.mask,
+                )
             new_state = TrainState(
                 params=params, opt_state=opt_state, step=state.step + 1
             )
@@ -299,21 +310,24 @@ class Trainer:
         model, tc = self.model, self.train_cfg
 
         def eval_fn(params, batch: Batch):
-            logits = model.apply({"params": params}, batch.x)
-            loss = weighted_bce_with_logits(
-                logits,
-                batch.y,
-                weight=self.weight,
-                pos_weight=self.pos_weight,
-                example_mask=batch.mask,
-            )
-            metrics = multilabel_metrics(
-                logits,
-                batch.y,
-                threshold=tc.prob_threshold,
-                beta=tc.fbeta_beta,
-                example_mask=batch.mask,
-            )
+            with jax.named_scope("forward"):
+                logits = model.apply({"params": params}, batch.x)
+            with jax.named_scope("loss"):
+                loss = weighted_bce_with_logits(
+                    logits,
+                    batch.y,
+                    weight=self.weight,
+                    pos_weight=self.pos_weight,
+                    example_mask=batch.mask,
+                )
+            with jax.named_scope("metrics"):
+                metrics = multilabel_metrics(
+                    logits,
+                    batch.y,
+                    threshold=tc.prob_threshold,
+                    beta=tc.fbeta_beta,
+                    example_mask=batch.mask,
+                )
             return loss, metrics
 
         jit_kwargs: Dict[str, Any] = {}
@@ -356,11 +370,9 @@ class Trainer:
         (dp batch sharding under a mesh; when the job spans processes
         each process's batches are its *local* shard of the global batch
         and are assembled in place), and up to ``train.prefetch_depth``
-        placed batches ride ahead of the step loop.  Host-side waits
-        surface as ``train_input_stall_seconds``."""
-        from fmda_tpu.obs.registry import default_registry
-
-        stall = default_registry().histogram("train_input_stall_seconds")
+        placed batches ride ahead of the step loop.  What the step loop
+        waits for it is measured where the loop pulls
+        (``_run_batches``: ``train_input_stall_seconds``)."""
         sharding = self._batch_sharding()
         if sharding is None:
             place = jax.device_put
@@ -377,11 +389,7 @@ class Trainer:
                     jax.device_put(b.mask, sharding),
                 )
         return prefetch_batches(
-            batches,
-            place,
-            depth=self.train_cfg.prefetch_depth,
-            stall_observer=stall.observe,
-        )
+            batches, place, depth=self.train_cfg.prefetch_depth)
 
     def _chunk_batches(
         self, dataset: ChunkDataset, chunk_idx: int
@@ -415,10 +423,16 @@ class Trainer:
                     and len(chunk_indices) <= self.train_cfg.cache_chunks)
         key = (id(dataset), tuple(chunk_indices))
         if cache_on:
+            from fmda_tpu.obs.registry import default_registry
+
             entry = self._placed_cache.get(key)
             # the entry pins its dataset, so a live hit can never be an
             # id()-reuse collision from a collected dataset
-            if entry is not None and entry[0] is dataset:
+            hit = entry is not None and entry[0] is dataset
+            default_registry().counter(
+                "train_placed_cache_total",
+                result="hit" if hit else "miss").inc()
+            if hit:
                 return self._run_batches(state, (entry[1],), rng, train)
 
         def host_batches() -> Iterable[Batch]:
@@ -452,15 +466,19 @@ class Trainer:
         import time as _time
 
         from fmda_tpu.obs.registry import default_registry
-        from fmda_tpu.utils.tracing import step_annotation
+        from fmda_tpu.utils.tracing import span, step_annotation
 
         phase = "train" if train else "eval"
-        # observability: host-side step dispatch wall clock (steps are
-        # async — this measures trace+dispatch, not device compute; the
-        # first step's compile dominates its bin, by design visible)
         reg = default_registry()
-        step_hist = reg.histogram("train_step_seconds", phase=phase)
         step_counter = reg.counter("train_steps_total", phase=phase)
+        stall = reg.histogram("train_input_stall_seconds")
+        clock = _time.perf_counter
+        # Host spans that tile one step, on the profiler's clock (a flag
+        # test each when nothing traces; docs/observability.md "Spans
+        # and scopes"): <phase>_next_batch, <phase>, <phase>_fold, and
+        # <phase>_pass_drain once a pass.  What is left uncovered is the
+        # loop's own Python.
+        next_name, fold_name = phase + "_next_batch", phase + "_fold"
         # Per-batch results are folded into running on-device accumulators
         # (async adds) — the host never blocks mid-pass and memory stays
         # O(1) instead of holding every batch's arrays live across an
@@ -468,23 +486,32 @@ class Trainer:
         acc = None
         step_no = 0
         for batches in batch_iterables:
-            for batch in batches:
-                # marks each step in a device profile when one is being
-                # captured (utils.tracing.device_trace); free otherwise
-                t0 = _time.perf_counter()
+            it = iter(batches)
+            while True:
+                # the input pipeline as the step loop meets it: the
+                # cached list, or the prefetch queue (whose compose and
+                # place spans nest in here)
+                t0 = clock()
+                with span(next_name):
+                    batch = next(it, None)
+                stall.observe(clock() - t0)
+                if batch is None:
+                    break
+                # the call into the jitted step: wrapper, dispatch and
+                # whatever donation waits for
                 with step_annotation(phase, step_no):
                     if train:
                         state, loss, metrics = self._train_step(
                             state, batch, rng)
                     else:
                         loss, metrics = self._eval_step(state.params, batch)
-                step_hist.observe(_time.perf_counter() - t0)
                 step_counter.inc()
                 step_no += 1
-                vals = (loss, metrics.accuracy, metrics.hamming,
-                        metrics.fbeta, metrics.confusion)
-                acc = vals if acc is None else jax.tree.map(
-                    jnp.add, acc, vals)
+                with span(fold_name):
+                    vals = (loss, metrics.accuracy, metrics.hamming,
+                            metrics.fbeta, metrics.confusion)
+                    acc = vals if acc is None else jax.tree.map(
+                        jnp.add, acc, vals)
         n_classes = self.model_cfg.output_size
         if acc is None:
             log.warning(
@@ -498,9 +525,11 @@ class Trainer:
                 EpochMetrics(nan, nan, nan, np.zeros(n_classes)),
                 np.zeros((n_classes, 2, 2), np.int64),
             )
-        loss_sum, acc_sum, ham_sum, fbeta_sum, confusion_total = (
-            jax.device_get(acc)
-        )
+        # the one place the host waits for the device
+        with span(phase + "_pass_drain"):
+            loss_sum, acc_sum, ham_sum, fbeta_sum, confusion_total = (
+                jax.device_get(acc)
+            )
         epoch = EpochMetrics(
             loss=float(loss_sum) / step_no,
             accuracy=float(acc_sum) / step_no,
@@ -570,6 +599,7 @@ class Trainer:
             self._warn_if_norm_drifted(dataset)
         history: Dict[str, List[EpochMetrics]] = {"train": [], "val": []}
         from fmda_tpu.obs.registry import default_registry
+        from fmda_tpu.utils.tracing import span
 
         reg = default_registry()
         epoch_hist = reg.histogram("train_epoch_seconds")
@@ -593,19 +623,20 @@ class Trainer:
                 nan = float("nan")
                 val_metrics = EpochMetrics(
                     nan, nan, nan, np.zeros(self.model_cfg.output_size))
-            history["val"].append(val_metrics)
-            epoch_hist.observe(_time.perf_counter() - t_epoch)
-            epoch_counter.inc()
-            log.info(
-                "epoch %d: train loss=%.4f acc=%.4f hamming=%.4f | "
-                "val acc=%.4f hamming=%.4f",
-                epoch + 1,
-                train_metrics.loss,
-                train_metrics.accuracy,
-                train_metrics.hamming,
-                val_metrics.accuracy,
-                val_metrics.hamming,
-            )
+            with span("fit_epoch_end"):
+                history["val"].append(val_metrics)
+                epoch_hist.observe(_time.perf_counter() - t_epoch)
+                epoch_counter.inc()
+                log.info(
+                    "epoch %d: train loss=%.4f acc=%.4f hamming=%.4f | "
+                    "val acc=%.4f hamming=%.4f",
+                    epoch + 1,
+                    train_metrics.loss,
+                    train_metrics.accuracy,
+                    train_metrics.hamming,
+                    val_metrics.accuracy,
+                    val_metrics.hamming,
+                )
         return state, history, dataset
 
     def fit_multi(

@@ -2,8 +2,12 @@
 
 The reference has no tracing (SURVEY.md §5) — its only timing is the
 sleep-budget measurement in producer.py:115/147-150.  Here every pipeline
-stage can be wrapped in a :class:`StageTimer`, and device-side regions use
-``jax.named_scope`` so they show up in the JAX profiler.
+stage can be wrapped in a :class:`StageTimer`; host regions that should
+stand beside the device's operations in a JAX profile use :func:`span`
+/ :func:`step_annotation` (the profiler's own clock; a flag test when
+nothing is being captured), and device-side regions call
+``jax.named_scope`` where the work is traced.  The names both kinds use
+are one vocabulary: docs/observability.md "Spans and scopes".
 """
 
 from __future__ import annotations
@@ -76,31 +80,34 @@ class StageTimer:
             )
 
 
-@contextlib.contextmanager
-def device_scope(name: str) -> Iterator[None]:
-    """Annotate a device-side region for the JAX profiler."""
+def span(name: str):
+    """A host span on the profiler's own clock — a
+    ``jax.profiler.TraceAnnotation`` to enter with ``with``.  It stands
+    on its thread's line of a captured profile beside the device's
+    operations; when no profile is being captured it costs a flag
+    test."""
     import jax  # deferred: keep stdlib-only users of this module jax-free
 
-    with jax.named_scope(name):
-        yield
+    return jax.profiler.TraceAnnotation(name)
+
+
+def step_annotation(name: str, step: int):
+    """Mark one step (a train step, a pool flush) in a captured profile:
+    the ``jax.profiler.StepTraceAnnotation`` itself, to enter with
+    ``with``.  Like :func:`span`, a flag test when nothing traces."""
+    import jax
+
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
 
 @contextlib.contextmanager
 def device_trace(log_dir: str) -> Iterator[None]:
     """Capture a JAX device profile (TensorBoard/XProf trace) of the
     enclosed region.  Wrap a few steps of a hot loop, not a whole run —
-    traces are large.  View with ``tensorboard --logdir <log_dir>``."""
+    traces are large.  View with ``tensorboard --logdir <log_dir>``, or
+    read the program's spans and scopes out of it with
+    ``python benchmark/tools/span_report.py <file.xplane.pb>``."""
     import jax
 
     with jax.profiler.trace(log_dir):
-        yield
-
-
-@contextlib.contextmanager
-def step_annotation(name: str, step: int) -> Iterator[None]:
-    """Mark one training step in an active device trace (no-op overhead
-    when no trace is being captured)."""
-    import jax
-
-    with jax.profiler.StepTraceAnnotation(name, step_num=step):
         yield

@@ -33,16 +33,22 @@ def _round_trip(value, binary):
     return out
 
 
-def _eq(a, b):
+def _eq(a, b, nan_payload=True):
     """Structural equality with NaN == NaN and exact float identity
-    (bit-for-bit: -0.0 != 0.0 matters on a bit-exact wire)."""
+    (bit-for-bit: -0.0 != 0.0 matters on a bit-exact wire).
+    ``nan_payload=False`` lets any NaN equal any NaN: JSON spells every
+    NaN ``NaN``, so the fallback cannot carry a payload's bits (the
+    fuzzer draws 0x7ff8000000000001 in about every other run)."""
     if isinstance(a, float) and isinstance(b, float):
+        if not nan_payload and math.isnan(a) and math.isnan(b):
+            return True
         return struct.pack("<d", a) == struct.pack("<d", b)
     if isinstance(a, dict) and isinstance(b, dict):
         return (a.keys() == b.keys()
-                and all(_eq(v, b[k]) for k, v in a.items()))
+                and all(_eq(v, b[k], nan_payload) for k, v in a.items()))
     if isinstance(a, list) and isinstance(b, list):
-        return len(a) == len(b) and all(_eq(x, y) for x, y in zip(a, b))
+        return len(a) == len(b) and all(
+            _eq(x, y, nan_payload) for x, y in zip(a, b))
     return type(a) is type(b) and a == b
 
 
@@ -76,7 +82,7 @@ def test_binary_round_trip_is_identity(value):
 @given(value=_VALUES)
 @settings(**SETTINGS)
 def test_json_fallback_round_trip_is_identity(value):
-    assert _eq(_round_trip(value, binary=False), value)
+    assert _eq(_round_trip(value, binary=False), value, nan_payload=False)
 
 
 @given(value=_VALUES)
