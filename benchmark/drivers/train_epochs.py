@@ -7,9 +7,16 @@ dataset=...)`` until ``--seconds`` have passed, each epoch with its
 validation pass and ended by the trainer's own device fetch — what
 epochs 2..25 of ``python -m fmda_tpu train`` are.
 
+A traced run then runs further epochs and traces ``trace_steps`` train
+steps of one of them, counted by the program's ``train_steps_total``
+and wholly inside one training pass where the pass is long enough
+(``harness/tracing.py`` ``StepSlice``): no eval step, drain or epoch
+end in the slice, at whatever speed the trainer runs.
+
 Traffic parameters: ``rows`` (length of the one-ticker corpus),
-``lead`` (bars ahead the labels look).  Batch, chunk and cache sizes are
-the configuration's ``framework.train``.
+``lead`` (bars ahead the labels look), ``trace_steps`` (train steps in a
+traced run's slice).  Batch, chunk and cache sizes are the
+configuration's ``framework.train``.
 """
 
 from __future__ import annotations
@@ -20,7 +27,7 @@ from typing import Dict
 import numpy as np
 
 from benchmark.harness.corpus import make_corpus
-from benchmark.harness.tracing import TailTracer, span
+from benchmark.harness.tracing import StepSlice, TailTracer, span
 
 END_TO_END = {"train_samples_per_s": "samples/s"}
 #: The trainer's validation loss (default matmul precision: one bf16 MXU
@@ -30,11 +37,6 @@ END_TO_END = {"train_samples_per_s": "samples/s"}
 #: a mean over 4e4 windows, so the errors average down; a forward pass in
 #: bf16 throughout moves it by more than 1e-2.
 EVAL_LOSS_RTOL = 3e-3
-#: A traced run traces this much of the epochs it runs after the window,
-#: starting ``TRACE_AFTER_S`` into them: a train step is some hundreds of
-#: device operations, so a short slice is already large.
-TRACE_SLICE_S = 1.0
-TRACE_AFTER_S = 0.5
 
 
 def valid_windows(dataset, chunk_indices, batch_size: int):
@@ -141,26 +143,33 @@ def run(ctx) -> Dict:
                    and trainer.unexpected_recompiles == 0)
     steps_per_epoch = train_steps + eval_steps
 
-    # more epochs, a slice of them traced (harness/tracing.py): the
-    # profiler starts and stops on a thread of its own, outside the window
+    # more epochs, some train steps of one of them traced: the profiler
+    # starts and stops on a thread of its own, outside the window
     tracer = TailTracer(ctx.trace, ctx.trace_dir)
+    tail = {}
     if ctx.trace:
-        import threading
+        counted = default_registry().counter("train_steps_total",
+                                             phase="train")
+        piece = StepSlice(tracer, lambda: counted.value,
+                          int(traffic["trace_steps"]), train_steps)
 
-        def trace_a_slice():
-            time.sleep(TRACE_AFTER_S)
-            tracer.start()
-            time.sleep(TRACE_SLICE_S)
-            tracer.stop()
-
-        thread = threading.Thread(target=trace_a_slice, daemon=True,
-                                  name="bench-tail-tracer")
-        thread.start()
-        while thread.is_alive():
+        def one_epoch():
+            nonlocal state
             with span("bench_epoch"):
                 state, _, _ = trainer.fit(
                     source, rng=rng, epochs=1, initial_state=state,
                     dataset=dataset)
+
+        tail_epochs = piece.drive(one_epoch)
+        tail = {
+            "tail_epochs": tail_epochs,
+            "trace_steps": piece.n_steps,
+            "traced_steps": piece.traced_steps,
+            "trace_slice_s": tracer.slice_s,
+            "trace_slice_in_one_pass": piece.in_one_pass,
+            "trace_slice_opened_at_step": piece.opened_at,
+            "trace_slice_closed_at_step": piece.closed_at,
+        }
     return {
         "attempted": epochs * steps_per_epoch,
         "failed": bad_epochs * steps_per_epoch,
@@ -185,5 +194,6 @@ def run(ctx) -> Dict:
             "window_elapsed_s": elapsed,
             "trace_start_cost_s": tracer.start_cost_s,
             "trace_stop_cost_s": tracer.stop_cost_s,
+            **tail,
         },
     }

@@ -2,10 +2,12 @@
 scopes: what the step loop's host thread did between steps, and which
 part of the compiled step the device's time went to.
 
-``trace_reduce`` keeps the benchmark's spans and three step annotations;
-this module keeps the vocabulary the program writes (docs/observability.md
-"Spans and scopes"): the host spans that tile one trainer step
-(:data:`PROGRAM_SPANS`), the module each device operation ran in
+``trace_reduce`` attributes the device's idle gaps to host spans by name,
+whatever thread wrote them; this module keeps the vocabulary the program
+writes (docs/observability.md "Spans and scopes"): the host spans that
+tile one trainer step (``trace_reduce.PROGRAM_SPANS``, the one tuple of
+their names), on the thread that runs the steps, the module each device
+operation ran in
 (``jit_train_step`` ...) and the ``jax.named_scope`` path it was traced
 under.  A program that writes none of them (an older commit) reduces to
 empty tables, and every reader built on this returns None.
@@ -40,18 +42,12 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from benchmark.harness import trace_reduce
 from benchmark.harness.trace_reduce import (
-    DEVICE_PLANE_PREFIX, OP_LINES, SLICE_SPAN, _clip, _gaps,
+    DEVICE_PLANE_PREFIX, OP_LINES, PROGRAM_SPANS, SLICE_SPAN, _clip, _gaps,
     _innermost_labels, _union)
 
 Span = Tuple[str, float, float]              # name, start_ns, dur_ns
 Op = Tuple[str, float, float, str, str]      # ... module, scope
 
-#: Host spans the program writes around the parts of one trainer step
-#: (``Trainer._run_batches``, ``Trainer.fit``, ``data.prefetch_batches``).
-PROGRAM_SPANS = (
-    "train_next_batch", "train", "train_fold", "train_pass_drain",
-    "eval_next_batch", "eval", "eval_fold", "eval_pass_drain",
-    "fit_epoch_end", "input_compose", "input_place")
 #: The trainer's step annotations; the thread that carries most of them
 #: is the step thread.
 STEP_SPANS = ("train", "eval")

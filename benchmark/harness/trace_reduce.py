@@ -34,11 +34,18 @@ SPAN_LINES = ("Steps", "XLA Modules", "XLA TraceMe", "Framework Ops",
 #: The slice the harness traced: opened after ``start_trace`` returned,
 #: closed before ``stop_trace`` is called.
 SLICE_SPAN = "bench_slice"
+#: Host spans the program writes around the parts of one trainer step
+#: (``Trainer._run_batches``, ``Trainer.fit``, ``data.prefetch_batches``);
+#: ``train``/``eval`` are its step annotations.
+PROGRAM_SPANS = (
+    "train_next_batch", "train", "train_fold", "train_pass_drain",
+    "eval_next_batch", "eval", "eval_fold", "eval_pass_drain",
+    "fit_epoch_end", "input_compose", "input_place")
 #: Host annotations gaps are attributed to.  ``bench_*`` come from the
 #: benchmark's own drivers; ``pool_flush`` is the gateway's
-#: StepTraceAnnotation; ``train``/``eval`` are the trainer's.
+#: StepTraceAnnotation; the rest are the trainer's.
 HOST_SPANS = ("bench_submit", "bench_pump", "bench_wait", "bench_round",
-              "bench_drain", "bench_epoch", "pool_flush", "train", "eval")
+              "bench_drain", "bench_epoch", "pool_flush") + PROGRAM_SPANS
 NO_SPAN = "(no host span)"
 #: The program's step annotations: one per pool flush / train step.
 STEP_SPANS = ("pool_flush", "train", "eval")

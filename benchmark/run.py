@@ -9,12 +9,14 @@ JSON object as the last line of standard output.  Everything else worth
 a number goes to standard error, one JSON object a line.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
-measures the same untraced window, then traces a few seconds more of the
-same traffic, and reports the cell's per-layer metrics and the breakdown
-(``harness/tracing.py``).  A cell of record needs a TPU and exits non-zero at once
-without one.  Any other cell (the rehearsal's tiny ones under
-``benchmark/selftest/``) runs on whatever is pinned, end to end, and then
-— not being on a TPU — exits non-zero without a result line.
+measures the same untraced window, then traces a little more of the
+same traffic (seconds of a serving cell's, a count of steps of a
+training cell's), and reports the cell's per-layer metrics and the
+breakdown (``harness/tracing.py``).  A cell of record needs a TPU and
+exits non-zero at once without one.  Any other cell (the rehearsal's
+tiny ones under ``benchmark/selftest/``) runs on whatever is pinned, end
+to end, and then — not being on a TPU — exits non-zero without a result
+line.
 """
 
 from __future__ import annotations
@@ -197,6 +199,10 @@ def main(argv=None) -> int:
                 "idle_share", "longest_gap_s", "n_gaps", "steps",
                 "n_device_planes")}})
     result["device"] = device
+    # what the run cost whoever waits for it, the reference check, the
+    # profiler's stop and the reductions of the trace included
+    ctx.say({"traced_run_s" if args.trace else "untraced_run_s":
+             time.time() - t_created})
 
     if not on_tpu:
         print(f"{cell.name}: rehearsal on {device['platform']} finished "

@@ -62,14 +62,40 @@ def test_a_traced_rehearsal_reports_layer_metrics_only():
     assert "flush_fill" not in result["metrics"]  # moves an open metric
 
 
-def test_a_traced_training_rehearsal_reports_the_train_names():
-    result = rehearsal_result(run_cell("selftest_ssm_train", trace=1))
+def notes_of(proc):
+    for line in proc.stderr.splitlines():
+        if line.startswith('{"notes"'):
+            return json.loads(line)["notes"]
+    raise AssertionError("no notes:\n" + proc.stderr[-3000:])
+
+
+@pytest.mark.parametrize("cell", ["selftest_gru_train", "selftest_ssm_train"])
+def test_a_traced_training_rehearsal_reports_the_train_names(cell):
+    proc = run_cell(cell, trace=1)
+    result = rehearsal_result(proc)
+    assert result["correct"] is True, proc.stderr[-3000:]
     assert "train_samples_per_s" not in result["metrics"]
     assert result["metrics"]["input_stall_share"]["value"] >= 0.0
-    # no peak to hold a rate against off the TPU, and the names that move
-    # a serving metric stay out of a training cell
-    assert "train_mfu" not in result["metrics"]
-    assert "device_idle_share" not in result["metrics"]
+    # what the step thread's spans give is read off the TPU too
+    for name in ("train_dispatch_us", "train_fold_us",
+                 "train_next_batch_us", "train_loop_self_us"):
+        assert result["metrics"][name]["value"] > 0.0
+    # no peak to hold a rate against off the TPU and no device plane in
+    # the trace, and the names that move a serving metric stay out of a
+    # training cell
+    for name in ("train_mfu", "train_step_dev_ms", "train_device_idle_share",
+                 "train_recurrence_dev_share", "device_idle_share"):
+        assert name not in result["metrics"]
+    # the slice is counted in steps; a 69-step pass has no room for the
+    # margins, and the run says so
+    notes = notes_of(proc)
+    assert notes["trace_steps"] == 6
+    assert 7 <= notes["traced_steps"] <= 69
+    assert notes["trace_slice_in_one_pass"] is False
+    assert 0.0 < notes["trace_slice_s"] < 2.0
+    assert notes["tail_epochs"] >= 1
+    assert notes["trace_stop_cost_s"] > 0.0
+    assert '{"traced_run_s"' in proc.stderr
 
 
 def test_the_kept_serving_cells_are_found_but_are_not_of_record():

@@ -140,3 +140,26 @@ def test_against_the_recorded_trace():
     total_idle = sum(t for _, t in r["idle_by_span"])
     assert total_idle <= (1 - r["busy_s"] / r["window_s"]) * r["window_s"] \
         * (1 + 1e-9)
+
+
+def test_gaps_of_a_training_trace_go_to_the_programs_spans(tmp_path):
+    """The five steps recorded on the v5e in PR 24 (three train, two
+    eval; ``test_program_spans.py`` reads the same file by span): the
+    device's idle time is named by the step loop's own spans, and only
+    what ``fit`` does around its two passes is left under no host span."""
+    import gzip
+
+    path = tmp_path / "train_steps.xplane.pb"
+    with gzip.open(os.path.join(FIXTURES, "train_steps.xplane.pb.gz")) as fh:
+        path.write_bytes(fh.read())
+    r = tr.reduce(tr.load(str(path)))
+    assert r["steps"] == {"pool_flush": 0, "train": 3, "eval": 2}
+    idle = dict(map(tuple, r["idle_by_span"]))
+    assert {"train_fold", "eval_fold", "train", "eval",
+            "train_pass_drain"} <= set(idle)
+    assert set(idle) <= set(tr.HOST_SPANS) | {tr.NO_SPAN}
+    named = sum(t for n, t in idle.items() if n != tr.NO_SPAN)
+    assert idle["train_fold"] > 0.25 * named
+    assert named > 3 * idle[tr.NO_SPAN]
+    assert sum(idle.values()) == pytest.approx(
+        r["window_s"] - r["busy_s"], rel=1e-6)
