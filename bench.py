@@ -186,19 +186,20 @@ def _bench_train_step(
     rng = jax.random.PRNGKey(1)
 
     for _ in range(warmup):
-        state, loss, _ = trainer._train_step(state, b, rng)
-    float(loss)
+        state, vals = trainer.single_step(state, b, rng)
+    float(vals.loss)
 
     # Slope timing (see _slope_time): two window sizes, each ended by a
     # host fetch; the constant per-window cost cancels in the difference.
     holder = {"state": state}
 
     def window_fn(n: int) -> float:
-        st = holder["state"]
+        # as the step loop runs it: the pass's totals ride through the step
+        st, totals = holder["state"], trainer.zero_totals()
         t0 = time.perf_counter()
         for _ in range(n):
-            st, loss_, _ = trainer._train_step(st, b, rng)
-        float(loss_)  # host fetch: the window's completion barrier
+            st, totals = trainer._train_step(st, totals, b, rng)
+        float(totals.loss)  # host fetch: the window's completion barrier
         holder["state"] = st
         return time.perf_counter() - t0
 
@@ -214,8 +215,8 @@ def _bench_train_step(
         with device_trace(profile_dir):
             for i in range(3):
                 with step_annotation("bench_train_step", i):
-                    state, loss, _ = trainer._train_step(state, b, rng)
-            float(loss)  # host fetch barrier
+                    state, vals = trainer.single_step(state, b, rng)
+            float(vals.loss)  # host fetch barrier
 
     dev = jax.devices()[0]
     if cell == "attn":
@@ -386,19 +387,19 @@ def phase_multiticker() -> dict:
     staged_dev = [jax.device_put(b) for b in staged[:3]]
 
     for b in staged_dev[:2]:
-        state, loss, _ = trainer._train_step(state, b, rng)
-    float(loss)
+        state, vals = trainer.single_step(state, b, rng)
+    float(vals.loss)
 
     # slope-timed device step over the staged batches
     holder = {"state": state}
 
     def window_fn(n: int) -> float:
-        st = holder["state"]
+        st, totals = holder["state"], trainer.zero_totals()
         t0 = time.perf_counter()
         for i in range(n):
-            st, loss_, _ = trainer._train_step(
-                st, staged_dev[i % len(staged_dev)], rng)
-        float(loss_)
+            st, totals = trainer._train_step(
+                st, totals, staged_dev[i % len(staged_dev)], rng)
+        float(totals.loss)
         holder["state"] = st
         return time.perf_counter() - t0
 
@@ -409,19 +410,20 @@ def phase_multiticker() -> dict:
     # max(compose, step), not their sum
     from fmda_tpu.data.pipeline import background_compose, prefetch_to_device
 
-    state = holder["state"]
+    state, totals = holder["state"], trainer.zero_totals()
     for b in prefetch_to_device(background_compose(
             mtd.mixed_batches(round0, per_ticker))):
-        state, loss, _ = trainer._train_step(state, b, rng)
-    float(loss)  # warm the overlapped path
+        state, totals = trainer._train_step(state, totals, b, rng)
+    float(totals.loss)  # warm the overlapped path
+    totals = trainer.zero_totals()
     t0 = time.perf_counter()
     pipeline_steps = 0
     for _ in range(3):
         for b in prefetch_to_device(background_compose(
                 mtd.mixed_batches(round0, per_ticker))):
-            state, loss, _ = trainer._train_step(state, b, rng)
+            state, totals = trainer._train_step(state, totals, b, rng)
             pipeline_steps += 1
-    float(loss)  # host fetch: completion barrier
+    float(totals.loss)  # host fetch: completion barrier
     pipeline_s = (time.perf_counter() - t0) / pipeline_steps
 
     dev = jax.devices()[0]

@@ -115,12 +115,14 @@ def main() -> None:
     # trained state stays alive for the checkpoint and backtests below
     st = _jax.tree.map(_jnp.copy, state)
     for b in staged[:2]:  # warmup (compiled already, but page everything in)
-        st, loss, _ = trainer._train_step(st, b, rng)
-    _jax.block_until_ready(loss)
+        st, vals = trainer.single_step(st, b, rng)
+    _jax.block_until_ready(vals)
+    # as fit_multi's loop runs it: the pass's totals ride through the step
+    totals = trainer.zero_totals()
     t_step = time.perf_counter()
     for b in staged:
-        st, loss, _ = trainer._train_step(st, b, rng)
-    _jax.block_until_ready(loss)
+        st, totals = trainer._train_step(st, totals, b, rng)
+    _jax.block_until_ready(totals)
     step_ms = (time.perf_counter() - t_step) / len(staged) * 1e3
     seq_s = train_cfg.batch_size / (step_ms / 1e3)
     print(f"fit_multi step: {step_ms:.1f} ms at B={train_cfg.batch_size} "

@@ -59,6 +59,24 @@ class EpochMetrics(NamedTuple):
     fbeta: np.ndarray  # (n_classes,)
 
 
+class StepTotals(NamedTuple):
+    """A pass's running sums of each step's loss and metrics, carried
+    through the compiled steps.  One step from :meth:`Trainer.zero_totals`
+    leaves that step's own values (``0 + v`` is ``v`` exactly)."""
+
+    loss: jax.Array
+    accuracy: jax.Array
+    hamming: jax.Array
+    fbeta: jax.Array  # (n_classes,)
+    confusion: jax.Array  # (n_classes, 2, 2) int32
+
+
+def _add_step(totals: StepTotals, loss, metrics) -> StepTotals:
+    """``totals + this step's values``, inside a compiled step (under its
+    ``metrics`` scope)."""
+    return jax.tree.map(jnp.add, totals, StepTotals(loss, *metrics))
+
+
 class Trainer:
     """Builds the model + optimizer and runs chunked epochs over a source."""
 
@@ -266,7 +284,8 @@ class Trainer:
             logits = logits_k.reshape((-1,) + logits_k.shape[2:])
             return loss_sum / denom, logits, grads
 
-        def step_fn(state: TrainState, batch: Batch, rng: jax.Array):
+        def step_fn(state: TrainState, totals: StepTotals, batch: Batch,
+                    rng: jax.Array):
             with jax.named_scope("forward"):  # the forward's dropout key
                 dropout_rng = jax.random.fold_in(rng, state.step)
             if accum == 1:
@@ -292,24 +311,26 @@ class Trainer:
                     beta=tc.fbeta_beta,
                     example_mask=batch.mask,
                 )
+                totals = _add_step(totals, loss, metrics)
             new_state = TrainState(
                 params=params, opt_state=opt_state, step=state.step + 1
             )
-            return new_state, loss, metrics
+            return new_state, totals
 
-        jit_kwargs: Dict[str, Any] = {"donate_argnums": (0,)}
+        jit_kwargs: Dict[str, Any] = {"donate_argnums": (0, 1)}
         shardings = self._step_shardings()
         if shardings is not None:
             replicated, batched = shardings
             jit_kwargs["in_shardings"] = (
-                replicated, Batch(batched, batched, batched), replicated)
-            jit_kwargs["out_shardings"] = (replicated, replicated, replicated)
+                replicated, replicated, Batch(batched, batched, batched),
+                replicated)
+            jit_kwargs["out_shardings"] = (replicated, replicated)
         return tracked_jit(step_fn, name="train_step", **jit_kwargs)
 
     def _build_eval_step(self):
         model, tc = self.model, self.train_cfg
 
-        def eval_fn(params, batch: Batch):
+        def eval_fn(params, totals: StepTotals, batch: Batch):
             with jax.named_scope("forward"):
                 logits = model.apply({"params": params}, batch.x)
             with jax.named_scope("loss"):
@@ -328,16 +349,41 @@ class Trainer:
                     beta=tc.fbeta_beta,
                     example_mask=batch.mask,
                 )
-            return loss, metrics
+                return _add_step(totals, loss, metrics)
 
-        jit_kwargs: Dict[str, Any] = {}
+        jit_kwargs: Dict[str, Any] = {"donate_argnums": (1,)}
         shardings = self._step_shardings()
         if shardings is not None:
             replicated, batched = shardings
             jit_kwargs["in_shardings"] = (
-                replicated, Batch(batched, batched, batched))
-            jit_kwargs["out_shardings"] = (replicated, replicated)
+                replicated, replicated, Batch(batched, batched, batched))
+            jit_kwargs["out_shardings"] = replicated
         return tracked_jit(eval_fn, name="eval_step", **jit_kwargs)
+
+    def zero_totals(self) -> StepTotals:
+        """A pass's accumulators at zero, placed as the compiled steps
+        return them (same dtypes, strong types and placement, so a pass's
+        first step runs the executable its second does)."""
+        n = self.model_cfg.output_size
+        zero = np.zeros((), np.float32)
+        totals = StepTotals(
+            zero, zero, zero, np.zeros((n,), np.float32),
+            np.zeros((n, 2, 2), np.int32))
+        if self.mesh is None:
+            return jax.device_put(totals)  # one placement for the five
+        return self._place_state(totals)
+
+    def single_step(
+        self, state: TrainState, batch: Batch,
+        rng: Optional[jax.Array] = None,
+    ) -> Tuple[TrainState, StepTotals]:
+        """One step's own loss and metrics, through the program the step
+        loop runs: a train step with ``rng`` (``state``'s buffers are
+        donated, as in the loop), an eval step without."""
+        if rng is None:
+            return state, self._eval_step(
+                state.params, self.zero_totals(), batch)
+        return self._train_step(state, self.zero_totals(), batch, rng)
 
     # -- compile accounting ---------------------------------------------------
 
@@ -479,11 +525,12 @@ class Trainer:
         # <phase>_pass_drain once a pass.  What is left uncovered is the
         # loop's own Python.
         next_name, fold_name = phase + "_next_batch", phase + "_fold"
-        # Per-batch results are folded into running on-device accumulators
-        # (async adds) — the host never blocks mid-pass and memory stays
+        # Each step's results are added to running on-device accumulators
+        # inside the compiled step itself — the host never blocks
+        # mid-pass, dispatches nothing but the step, and memory stays
         # O(1) instead of holding every batch's arrays live across an
         # epoch.  One device_get at the end drains the totals.
-        acc = None
+        totals = self.zero_totals()
         step_no = 0
         for batches in batch_iterables:
             it = iter(batches)
@@ -497,23 +544,23 @@ class Trainer:
                 stall.observe(clock() - t0)
                 if batch is None:
                     break
-                # the call into the jitted step: wrapper, dispatch and
-                # whatever donation waits for
+                # the call into the jitted step: wrapper, dispatch,
+                # whatever donation waits for, and letting go of the
+                # donated state
                 with step_annotation(phase, step_no):
                     if train:
-                        state, loss, metrics = self._train_step(
-                            state, batch, rng)
+                        state, out = self._train_step(
+                            state, totals, batch, rng)
                     else:
-                        loss, metrics = self._eval_step(state.params, batch)
+                        out = self._eval_step(state.params, totals, batch)
                 step_counter.inc()
                 step_no += 1
+                # what is left of the fold on the host: taking the new
+                # totals for the old (the sum is in the compiled step)
                 with span(fold_name):
-                    vals = (loss, metrics.accuracy, metrics.hamming,
-                            metrics.fbeta, metrics.confusion)
-                    acc = vals if acc is None else jax.tree.map(
-                        jnp.add, acc, vals)
+                    totals = out
         n_classes = self.model_cfg.output_size
-        if acc is None:
+        if step_no == 0:
             log.warning(
                 "pass produced no batches (source too short for "
                 "window=%d/chunk_size=%d, or empty chunk split) — metrics "
@@ -528,7 +575,7 @@ class Trainer:
         # the one place the host waits for the device
         with span(phase + "_pass_drain"):
             loss_sum, acc_sum, ham_sum, fbeta_sum, confusion_total = (
-                jax.device_get(acc)
+                jax.device_get(totals)
             )
         epoch = EpochMetrics(
             loss=float(loss_sum) / step_no,

@@ -121,8 +121,8 @@ def test_trainer_runs_attn_cell_and_loss_drops():
     rng = jax.random.PRNGKey(1)
     losses = []
     for _ in range(30):
-        state, loss, _ = trainer._train_step(state, b, rng)
-        losses.append(float(loss))
+        state, vals = trainer.single_step(state, b, rng)
+        losses.append(float(vals.loss))
     assert losses[-1] < losses[0]
 
 

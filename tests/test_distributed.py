@@ -92,8 +92,8 @@ state = trainer.init_state(jax.random.PRNGKey(0))
 local = Batch(x=x_global[lo:hi], y=y_global[lo:hi],
               mask=np.ones(2, np.float32))
 placed = next(iter(trainer._place_batches([local])))
-state, tr_loss, _ = trainer._train_step(state, placed, jax.random.PRNGKey(1))
-tr_loss = float(jax.device_get(tr_loss))
+state, vals = trainer.single_step(state, placed, jax.random.PRNGKey(1))
+tr_loss = float(jax.device_get(vals.loss))
 
 print(json.dumps({"pid": pid, "sp_loss": sp_loss, "trainer_loss": tr_loss,
                   "attn_loss": attn_loss}))

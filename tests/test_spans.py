@@ -197,23 +197,40 @@ def _scope_components(lowered):
     return module, parts
 
 
-@pytest.mark.parametrize("cell", sorted(FAMILY_SCOPES))
-@pytest.mark.parametrize("step", sorted(STEP_SCOPES))
-def test_compiled_steps_carry_the_scope_vocabulary(step, cell):
-    trainer = _trainer(cell=cell)
+def _lower(trainer, step):
     state = trainer.init_state(jax.random.PRNGKey(0))
+    totals = trainer.zero_totals()
     batch = Batch(jnp.zeros((64, 5, 6)), jnp.zeros((64, 4)),
                   jnp.ones((64,)))
     if step == "train_step":
-        lowered = trainer._train_step._jit.lower(
-            state, batch, jax.random.PRNGKey(1))
-    else:
-        lowered = trainer._eval_step._jit.lower(state.params, batch)
-    module, parts = _scope_components(lowered)
+        return trainer._train_step._jit.lower(
+            state, totals, batch, jax.random.PRNGKey(1))
+    return trainer._eval_step._jit.lower(state.params, totals, batch)
+
+
+@pytest.mark.parametrize("cell", sorted(FAMILY_SCOPES))
+@pytest.mark.parametrize("step", sorted(STEP_SCOPES))
+def test_compiled_steps_carry_the_scope_vocabulary(step, cell):
+    module, parts = _scope_components(_lower(_trainer(cell=cell), step))
     # one name in the trace, the compile ledger and the docs
     assert module == "jit_" + step
     for scope in STEP_SCOPES[step] + FAMILY_SCOPES[cell]:
         assert scope in parts, (scope, sorted(parts))
+
+
+@pytest.mark.parametrize("step", sorted(STEP_SCOPES))
+def test_pass_totals_are_added_under_the_metrics_scope(step):
+    """The fold is in the compiled step: five adds (loss, accuracy,
+    hamming, fbeta, confusion) directly under ``metrics``, so the
+    profile's device lines charge them to that scope."""
+    text = _lower(_trainer(), step).as_text(debug_info=True)
+    scoped = set(re.findall(
+        r'(#loc\d+) = loc\("jit\(%s\)/metrics/add"' % step, text))
+    # an add of a program argument (the carried total) and a value
+    added = [kind for kind, loc in re.findall(
+        r"stablehlo\.add %arg\d+, %\d+ : tensor<(\S+)> loc\((#loc\d+)\)",
+        text) if loc in scoped]
+    assert sorted(added) == ["4x2x2xi32", "4xf32", "f32", "f32", "f32"]
 
 
 @pytest.mark.parametrize("cell", ["gru", "ssm"])
