@@ -210,12 +210,27 @@ def _save_quality_profile(wh, cfg, ckpt, *, max_rows: int = 4096) -> None:
         print(f"drift reference profile not written: {e}", file=sys.stderr)
 
 
+def _token_source(path: str, cfg):
+    """``train --tokens``: a ``.npy`` file of token ids, one packed
+    stream, for the families trained on next-token prediction."""
+    import numpy as np
+
+    from fmda_tpu.data.source import TokenArraySource
+
+    return TokenArraySource(np.load(path), cfg.model.vocab_size)
+
+
 def _train(wh, cfg, *, epochs, batch_size, checkpoint_dir, seed):
     """Shared by ``train`` and ``demo``; returns the checkpoint path, or
-    None (after printing why) when training cannot run."""
+    None (after printing why) when training cannot run.  ``wh`` is the
+    warehouse, or a token source (``train --tokens``): what a family is
+    trained on is its task's business (train/tasks.py), and the source
+    brings class weights, normalisation and a drift profile only where
+    it is a feature table."""
     import dataclasses
 
     from fmda_tpu.train import Trainer, save_checkpoint
+    from fmda_tpu.train.tasks import task_class
     from fmda_tpu.train.trainer import imbalance_weights_from_source
     from fmda_tpu.utils.env import device_report
 
@@ -223,20 +238,25 @@ def _train(wh, cfg, *, epochs, batch_size, checkpoint_dir, seed):
         print("warehouse is empty — run ingest first", file=sys.stderr)
         return None
     fc = cfg.features
-    model_cfg = dataclasses.replace(cfg.model, n_features=len(wh.x_fields))
+    features = task_class(cfg.model).feature_windows
+    model_cfg = (dataclasses.replace(cfg.model, n_features=len(wh.x_fields))
+                 if features else cfg.model)
     # explicitly-passed CLI flags override the config file; absent flags
     # (None) leave the config's values in force
     overrides = {k: v for k, v in
                  dict(batch_size=batch_size, epochs=epochs, seed=seed).items()
                  if v is not None}
     train_cfg = dataclasses.replace(cfg.train, **overrides)
-    weight, pos_weight = imbalance_weights_from_source(wh)
+    weight, pos_weight = (imbalance_weights_from_source(wh) if features
+                          else (None, None))
     trainer = Trainer(model_cfg, train_cfg, weight=weight,
                       pos_weight=pos_weight)
     state, history, dataset = trainer.fit(
         wh, bid_levels=fc.bid_levels, ask_levels=fc.ask_levels)
-    ckpt = save_checkpoint(checkpoint_dir, state, dataset.final_norm_params)
-    _save_quality_profile(wh, cfg, ckpt)
+    ckpt = save_checkpoint(checkpoint_dir, state,
+                           trainer.task.norm_params(dataset))
+    if features:
+        _save_quality_profile(wh, cfg, ckpt)
     last = history["train"][-1]
     dev = device_report()
     print(f"trained {len(history['train'])} epochs: "
@@ -293,6 +313,11 @@ def cmd_train(args) -> int:
         from fmda_tpu.utils.tracing import device_trace
 
         profile = device_trace(args.jax_profile)
+    if bool(args.tokens) == bool(args.warehouse) or (
+            args.tokens and args.continuous):
+        print("train needs one of --warehouse and --tokens (--continuous "
+              "tails a warehouse)", file=sys.stderr)
+        return 2
     with profile:
         if args.continuous:
             out = _continuous_train(
@@ -302,8 +327,10 @@ def cmd_train(args) -> int:
             )
             ok = bool(out and out["rounds"] > 0)
         else:
+            source = (_token_source(args.tokens, cfg) if args.tokens
+                      else _warehouse(args.warehouse, cfg))
             ok = bool(_train(
-                _warehouse(args.warehouse, cfg), cfg, epochs=args.epochs,
+                source, cfg, epochs=args.epochs,
                 batch_size=args.batch_size,
                 checkpoint_dir=_ckpt_dir(args, cfg), seed=args.seed,
             ))
@@ -2257,8 +2284,12 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--checkpoint-every", type=int, default=None)
     p.set_defaults(fn=cmd_ingest)
 
-    p = sub.add_parser("train", parents=[common], help="train over a warehouse file")
-    p.add_argument("--warehouse", required=True)
+    p = sub.add_parser("train", parents=[common],
+                       help="train over a warehouse file, or a token file")
+    p.add_argument("--warehouse", default=None)
+    p.add_argument("--tokens", default=None, metavar="FILE.npy",
+                   help="train a token family (model.cell=decoder) over "
+                        "one packed stream of int token ids")
     p.add_argument("--checkpoint-dir", default=None,
                    help="override config train.checkpoint_dir")
     p.add_argument("--epochs", type=int, default=None,

@@ -357,11 +357,17 @@ class FeatureConfig:
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """BiGRU hyperparameters (ref: biGRU_model.py:32; notebook cell 29).
+    """Hyperparameters of every model family; ``cell`` names the family
+    and decides which of the fields below it reads.
 
-    ``n_features=None`` means "derive from the feature schema" — resolved by
-    :class:`FrameworkConfig` so the model width can never silently diverge
-    from what the data pipeline emits.
+    The window classifiers (``gru``, ``lstm``, ``attn``, ``ssm``) share
+    the reference's protocol (ref: biGRU_model.py:32; notebook cell 29):
+    float feature windows in, ``output_size`` labels out.
+    ``n_features=None`` means "derive from the feature schema" — resolved
+    by :class:`FrameworkConfig` so the model width can never silently
+    diverge from what the data pipeline emits.  The token ``decoder``
+    reads ``vocab_size`` instead, and the ``n_kv_heads`` .. ``loss_chunk``
+    group at the end (docs/training.md "The decoder family").
     """
 
     hidden_size: int = 32
@@ -422,9 +428,44 @@ class ModelConfig:
     #: opt in explicitly (ADVICE r1 — flip the default once the kernel
     #: has a TPU CI job).
     use_pallas: bool = False
-    #: Rematerialise the recurrence in backward (jax.checkpoint): trades
-    #: recompute FLOPs for HBM — enable for long-context windows.
+    #: Rematerialise in backward (jax.checkpoint): the recurrence of the
+    #: recurrent families, each whole block of ``attn`` and ``decoder`` —
+    #: trades recompute FLOPs for HBM; enable for long context.
     remat: bool = False
+    # -- cell="decoder": a causal token decoder with routed experts ------
+    #: Token ids the embedding and the untied head hold: the whole
+    #: vocabulary, or the slice of it one chip of a vocabulary-parallel
+    #: group holds (a sliced vocabulary is a smaller vocabulary: ids,
+    #: logits and loss are over the slice).
+    vocab_size: int = 0
+    #: Key/value heads; ``n_heads`` query heads share them in runs of
+    #: ``n_heads // n_kv_heads`` (grouped-query attention).
+    n_kv_heads: int = 0
+    #: Width of one attention head (queries are ``n_heads * head_dim``
+    #: wide, whatever ``hidden_size`` is).
+    head_dim: int = 0
+    #: One entry per layer (its length is the depth; ``n_layers`` is not
+    #: read): 1 = rotary positions on q and k and a causal window of
+    #: ``sliding_window`` keys, 0 = no positional encoding and the whole
+    #: causal past.
+    layer_layout: Tuple[int, ...] = ()
+    sliding_window: int = 4096
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
+    #: Width of the router: experts a token is routed over, on whatever
+    #: chip they live.
+    moe_experts: int = 0
+    #: Experts a token is sent to; their gates are renormalised to one.
+    moe_top_k: int = 0
+    #: Hidden width of one expert (``hidden -> moe_ffn_size -> hidden``).
+    moe_ffn_size: int = 0
+    #: ``(first, count)``: the experts this chip holds and computes
+    #: (ops/moe.py).  ``(0, moe_experts)`` is the uncut layer.
+    experts_held: Tuple[int, int] = (0, 0)
+    #: Tokens whose logits exist at a time in the loss: the cross-entropy
+    #: is summed chunk by chunk (recomputed in backward), the same sum as
+    #: over (tokens, vocab_size) at once.
+    loss_chunk: int = 1024
 
 
 @dataclass(frozen=True)

@@ -1,4 +1,5 @@
-from fmda_tpu.data.source import ArraySource, FeatureSource
+from fmda_tpu.data.source import (
+    ArraySource, FeatureSource, TokenArraySource, TokenSource)
 from fmda_tpu.data.windows import chunk_ranges, train_val_test_split, window_index_matrix
 from fmda_tpu.data.normalize import (
     NormParams,
@@ -9,6 +10,8 @@ from fmda_tpu.data.normalize import (
 )
 from fmda_tpu.data.pipeline import (
     ChunkDataset,
+    TokenBatches,
+    TokenDataset,
     WindowBatches,
     background_compose,
     prefetch_batches,
@@ -18,6 +21,8 @@ from fmda_tpu.data.pipeline import (
 __all__ = [
     "ArraySource",
     "FeatureSource",
+    "TokenArraySource",
+    "TokenSource",
     "chunk_ranges",
     "train_val_test_split",
     "window_index_matrix",
@@ -27,6 +32,8 @@ __all__ = [
     "save_norm_params",
     "load_norm_params",
     "ChunkDataset",
+    "TokenBatches",
+    "TokenDataset",
     "WindowBatches",
     "background_compose",
     "prefetch_batches",

@@ -12,6 +12,10 @@ The TPU-first re-design of the reference's SQL dataloader stack
   hits the same compiled executable — no recompiles, no dynamic shapes).
 - :func:`prefetch_to_device` double-buffers host batches into HBM so the
   device never waits on the host (the "infeed" half of SURVEY.md §7.2).
+- :class:`TokenDataset` / :class:`TokenBatches` are the same two roles
+  over a :class:`~fmda_tpu.data.source.TokenSource`: fixed-length id
+  sequences cut from a packed stream, the target the input shifted by
+  one, nothing normalised.
 """
 
 from __future__ import annotations
@@ -30,16 +34,17 @@ from typing import (
 import numpy as np
 
 from fmda_tpu.data.normalize import NormParams, chunk_norm_params, normalize
-from fmda_tpu.data.source import FeatureSource
+from fmda_tpu.data.source import FeatureSource, TokenSource
 from fmda_tpu.data.windows import chunk_ranges, train_val_test_split, window_index_matrix
 
 
 class Batch(NamedTuple):
-    """One fixed-shape training batch."""
+    """One fixed-shape training batch: of feature windows, or (the
+    shapes in brackets) of token sequences."""
 
-    x: np.ndarray  # (B, window, F) float32, normalized
-    y: np.ndarray  # (B, n_classes) float32
-    mask: np.ndarray  # (B,) float32 — 0 for padded examples
+    x: np.ndarray  # (B, window, F) float32, normalized  [(B, T) int32 ids]
+    y: np.ndarray  # (B, n_classes) float32  [(B, T) int32: x shifted by one]
+    mask: np.ndarray  # (B,) float32 — 0 for padded examples  [(B, T)]
 
 
 class ChunkDataset:
@@ -167,6 +172,70 @@ class WindowBatches:
                 yb = np.concatenate([yb, np.zeros((pad,) + yb.shape[1:], yb.dtype)])
             mask = np.zeros(bs, np.float32)
             mask[:valid] = 1.0
+            yield Batch(xb, yb, mask)
+
+
+class TokenDataset:
+    """Fixed-length sequences cut from a packed token stream, in chunks.
+
+    Sequence ``i`` reads tokens ``i*T .. i*T + T`` of the stream (``T`` =
+    ``window``): its input is the first ``T`` of them and its target the
+    last ``T``, so consecutive sequences share one token and every
+    position has a next token to predict.  Nothing is padded inside a
+    sequence and nothing is normalised; documents cross sequence
+    boundaries as the stream has them.  A chunk is
+    ``max(chunk_size // window, 1)`` consecutive sequences — the unit of
+    the train/validation/test split and of the placed-batch cache, as a
+    chunk of rows is for :class:`ChunkDataset`.
+    """
+
+    def __init__(self, source: TokenSource, chunk_size: int,
+                 window: int) -> None:
+        self.source = source
+        self.window = window
+        self.chunk_size = chunk_size
+        self.n_sequences = max(len(source) - 1, 0) // window
+        self.per_chunk = max(chunk_size // window, 1)
+
+    def __len__(self) -> int:
+        return -(-self.n_sequences // self.per_chunk)
+
+    def sequences(self, chunk_idx: int) -> Tuple[np.ndarray, np.ndarray]:
+        """``(inputs, targets)`` of one chunk, each (n, window) int32."""
+        first = chunk_idx * self.per_chunk
+        n = min(self.per_chunk, self.n_sequences - first)
+        t = self.window
+        ids = np.asarray(self.source.fetch_tokens(
+            first * t, (first + n) * t + 1), np.int32)
+        return ids[:-1].reshape(n, t), ids[1:].reshape(n, t)
+
+    def split(
+        self, val_size: float = 0.1, test_size: float = 0.1
+    ) -> Tuple[Sequence[int], Sequence[int], Sequence[int]]:
+        return train_val_test_split(len(self), val_size, test_size)
+
+
+class TokenBatches:
+    """Fixed-shape batches of one chunk's sequences; the last partial
+    batch is padded with all-masked sequences of id 0."""
+
+    def __init__(self, dataset: TokenDataset, chunk_idx: int,
+                 batch_size: int) -> None:
+        self.x, self.y = dataset.sequences(chunk_idx)
+        self.batch_size = batch_size
+
+    def __len__(self) -> int:
+        return -(-len(self.x) // self.batch_size)
+
+    def __iter__(self) -> Iterator[Batch]:
+        bs = self.batch_size
+        for start in range(0, len(self.x), bs):
+            xb, yb = self.x[start:start + bs], self.y[start:start + bs]
+            mask = np.zeros((bs, xb.shape[1]), np.float32)
+            mask[:len(xb)] = 1.0
+            if len(xb) < bs:
+                pad = np.zeros((bs - len(xb), xb.shape[1]), np.int32)
+                xb, yb = np.concatenate([xb, pad]), np.concatenate([yb, pad])
             yield Batch(xb, yb, mask)
 
 

@@ -1,4 +1,4 @@
-"""Pluggable feature sources for the data pipeline.
+"""Pluggable feature and token sources for the data pipeline.
 
 The reference hard-wires training to a live MariaDB cursor
 (sql_pytorch_dataloader.py:62-65, 227-236).  Here the pipeline reads through
@@ -72,3 +72,46 @@ class ArraySource:
 
     def fetch_targets(self, ids: Sequence[int]) -> np.ndarray:
         return self._y[self._to_index(ids)]
+
+
+class TokenSource(Protocol):
+    """A stream of token ids, for the families trained on next-token
+    prediction.  Positions are 0-based."""
+
+    @property
+    def vocab_size(self) -> int:
+        """Every id is in ``0 .. vocab_size - 1``."""
+        ...
+
+    def __len__(self) -> int:
+        """Number of tokens available."""
+        ...
+
+    def fetch_tokens(self, start: int, stop: int) -> np.ndarray:
+        """Ids of positions ``start .. stop - 1``, int32."""
+        ...
+
+
+class TokenArraySource:
+    """In-memory :class:`TokenSource` over one int array (documents
+    already joined by whatever end-of-document id the corpus uses)."""
+
+    def __init__(self, ids: np.ndarray, vocab_size: int) -> None:
+        ids = np.asarray(ids)
+        assert ids.ndim == 1 and np.issubdtype(ids.dtype, np.integer)
+        if ids.size and (ids.min() < 0 or ids.max() >= vocab_size):
+            raise ValueError(
+                f"token ids outside 0..{vocab_size - 1}: "
+                f"[{ids.min()}, {ids.max()}]")
+        self._ids = ids.astype(np.int32)
+        self._vocab_size = int(vocab_size)
+
+    @property
+    def vocab_size(self) -> int:
+        return self._vocab_size
+
+    def __len__(self) -> int:
+        return len(self._ids)
+
+    def fetch_tokens(self, start: int, stop: int) -> np.ndarray:
+        return self._ids[start:stop]

@@ -153,12 +153,35 @@ def flash_dispatch(
     """THE dispatch decision :func:`mha` makes — exposed so callers that
     *report* the executed path (bench.py's ``scan_path`` attribution)
     ask this function instead of re-implementing the gate and silently
-    drifting from it."""
+    drifting from it.  ``has_mask`` means an arbitrary mask array; the
+    causal triangle and a causal window are the kernel's own."""
     if not (use_flash and not has_mask and flash_available()):
         return False
     from fmda_tpu.ops import pallas_attention
 
     return pallas_attention.flash_supported(tq, tk, d_head)
+
+
+#: Query rows the non-kernel path scores at a time once the sequence is
+#: longer than this: the scores then cost (B, N, 512, Tk) float32 and
+#: never (B, N, Tq, Tk) — 7.5 GB at 28 heads x 8192 x 8192.
+FALLBACK_QUERY_BLOCK = 512
+
+
+def visibility_mask(
+    q_pos: jax.Array, k_pos: jax.Array, *, causal: bool,
+    window: Optional[int],
+) -> Optional[jax.Array]:
+    """(Tq, Tk) bool keep-mask from positions: key ``j`` is visible to
+    query ``i`` iff ``j <= i`` (causal) and ``i - j < window`` (a causal
+    window); None where everything is visible."""
+    if not causal and window is None:
+        return None
+    rel = q_pos[:, None] - k_pos[None, :]
+    keep = rel >= 0
+    if window is not None:
+        keep = keep & (rel < window)
+    return keep
 
 
 def mha(
@@ -167,34 +190,43 @@ def mha(
     v: jax.Array,
     *,
     causal: bool = False,
+    window: Optional[int] = None,
     mask: Optional[jax.Array] = None,
     use_flash: bool = False,
 ) -> jax.Array:
     """Single-device multi-head attention via the same online-softmax
-    primitive the ring path uses (one block = the whole key axis), so the
-    sharded and unsharded paths are the *same numerics* by construction.
+    primitive the ring path uses, so the sharded and unsharded paths are
+    the *same numerics* by construction.
 
     ``use_flash=True`` requests the fused Pallas flash kernel
     (:mod:`fmda_tpu.ops.pallas_attention`) on TPU backends — same math,
     but the (T, T) scores never leave VMEM instead of costing
-    (B, N, T, T) f32 of HBM traffic.  The flag is the attn family's
+    (B, N, T, T) f32 of HBM traffic.  The flag is the family's
     ``ModelConfig.use_pallas`` (same opt-in convention as the GRU/LSTM
     kernels: the default path stays the one exercised everywhere, and a
-    kernel regression can always be ruled out from config).  Anything
-    outside the kernel's envelope (masks, ragged Tq/Tk, T not a
-    multiple of 128, non-TPU backend) silently falls back to the jnp
-    path below.
+    kernel regression can always be ruled out from config).  The kernel
+    takes the causal triangle, a causal ``window`` and grouped-query
+    heads itself; anything outside its envelope (an arbitrary ``mask``
+    array, ragged Tq/Tk, T not a multiple of 128, non-TPU backend) takes
+    the jnp path below, which scores ``FALLBACK_QUERY_BLOCK`` query rows
+    at a time once the sequence is longer than that (each block
+    recomputed in backward), so no path materialises (B, N, Tq, Tk).
 
     Args:
-      q, k, v: (B, N, T, D).
+      q: (B, N, Tq, D).  k, v: (B, G, Tk, D) with ``N % G == 0`` — each
+        run of ``N / G`` consecutive query heads shares one key/value
+        head (G == N: plain multi-head attention).
       causal: apply a lower-triangular causal mask (needed for streaming
         serving where position t must not see the future).
+      window: a causal window — key j is visible to query i iff
+        ``0 <= i - j < window``; implies ``causal``.
       mask: optional extra mask, (Tq, Tk) or broadcastable (B, N, Tq, Tk).
       use_flash: opt into the fused kernel where supported.
 
     Returns (B, N, Tq, D) in q's dtype.
     """
     tq, tk = q.shape[-2], k.shape[-2]
+    causal = causal or window is not None
     # the attn family's place in the scope vocabulary: where the
     # recurrent families have recurrence_fwd/_rev
     with jax.named_scope("attention"):
@@ -202,20 +234,50 @@ def mha(
                           has_mask=mask is not None):
             from fmda_tpu.ops import pallas_attention
 
-            return pallas_attention.flash_attention(q, k, v, causal=causal)
-        full_mask = None
-        if causal:
-            # suffix alignment: query i sits at global position
-            # tk - tq + i, so a short query block against a longer K/V
-            # history (streaming) sees its full past, not just the first
-            # i keys
-            q_pos = tk - tq + jnp.arange(tq)
-            full_mask = q_pos[:, None] >= jnp.arange(tk)[None, :]
-        if mask is not None:
-            full_mask = mask if full_mask is None else (full_mask & mask)
-        state = init_online_state(q.shape[0], q.shape[1], tq, q.shape[-1])
-        state = online_attention_block(state, q, k, v, full_mask)
-        return finalize_online_state(state, q.dtype)
+            return pallas_attention.flash_attention(
+                q, k, v, causal=causal, window=window)
+        group = q.shape[1] // k.shape[1]
+        if group > 1:  # the kernel indexes; this path repeats
+            k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+        # suffix alignment: query i sits at global position tk - tq + i,
+        # so a short query block against a longer K/V history
+        # (streaming) sees its full past, not just the first i keys
+        q_pos = tk - tq + jnp.arange(tq)
+        k_pos = jnp.arange(tk)
+
+        def attend(q_blk, pos_blk, mask_blk):
+            full_mask = visibility_mask(
+                pos_blk, k_pos, causal=causal, window=window)
+            if mask_blk is not None:
+                full_mask = (mask_blk if full_mask is None
+                             else full_mask & mask_blk)
+            state = init_online_state(
+                q_blk.shape[0], q_blk.shape[1], q_blk.shape[2],
+                q_blk.shape[3])
+            state = online_attention_block(state, q_blk, k, v, full_mask)
+            return finalize_online_state(state, q.dtype)
+
+        blk = FALLBACK_QUERY_BLOCK
+        if tq <= blk or tq % blk != 0:
+            return attend(q, q_pos, mask)
+        # one block of query rows at a time, recomputed in backward
+        n_blk = tq // blk
+        q_blocks = jnp.moveaxis(
+            q.reshape(q.shape[:2] + (n_blk, blk, q.shape[-1])), 2, 0)
+        pos_blocks = q_pos.reshape(n_blk, blk)
+        if mask is None:
+            out = jax.lax.map(
+                lambda xs: jax.checkpoint(attend)(xs[0], xs[1], None),
+                (q_blocks, pos_blocks))
+        else:
+            rows = jnp.broadcast_to(
+                mask, jnp.broadcast_shapes(mask.shape, (tq, tk)))
+            mask_blocks = jnp.moveaxis(rows.reshape(
+                rows.shape[:-2] + (n_blk, blk, tk)), -3, 0)
+            out = jax.lax.map(
+                lambda xs: jax.checkpoint(attend)(*xs),
+                (q_blocks, pos_blocks, mask_blocks))
+        return jnp.moveaxis(out, 0, 2).reshape(q.shape)
 
 
 def split_heads(x: jax.Array, n_heads: int) -> jax.Array:

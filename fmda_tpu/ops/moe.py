@@ -1,0 +1,238 @@
+"""A routed-expert layer that is told which experts it holds.
+
+The usual cut of a mixture-of-experts model over chips is expert
+parallelism: every chip of a group routes every token over *all* the
+experts (the router keeps its published width), and computes the part of
+the layer's output that its own experts give.  :func:`expert_layer` is
+that part, for one chip::
+
+    p   = softmax(h @ W_r)                      over all E experts
+    S_t = top-k of p_t ;  g_te = p_te / sum_{e' in S_t} p_te'
+    m_t = sum_{e in S_t, e held} g_te * ( relu(u_t @ Wg_e) * (u_t @ Wu_e) ) @ Wd_e
+
+``experts_held = (first, count)`` names the held experts
+``first .. first + count - 1``; the gates are normalised over the whole
+top-k, held or not, so the parts of all the chips of a group add up to
+the uncut layer (tests/test_moe.py, the share test).  On one chip the
+layer runs without its exchange: nothing here stands in for the absent
+chips, and what their experts would have added is left out.
+
+No capacity factor and nothing dropped: every (token, expert) pair that
+lands on a held expert is computed.  Shapes stay static because the row
+layout is sized for the worst routing (all ``T * k`` pairs held) and the
+grouped products skip the tiles no pair fell into
+(:mod:`fmda_tpu.ops.pallas_moe`).  The steps, each under its scope
+(docs/observability.md "Spans and scopes"):
+
+- ``moe_route``: router product, softmax, top-k, gate normalisation;
+- ``moe_dispatch``: sort the held pairs by expert, pad each group to
+  whole row tiles, gather the token rows into that layout;
+- ``moe_experts``: the three grouped products and the ReGLU between;
+- ``moe_combine``: gather each token's rows back and sum them by gate.
+
+Both gathers have hand-written transposes that are gathers too (a row
+belongs to one pair, a pair to one row), so neither direction scatters
+wide rows.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+class Plan(NamedTuple):
+    """Where each held (token, expert) pair sits in the grouped row
+    layout, for one call of the layer.  ``R`` rows in tiles of ``tile``;
+    pairs are numbered ``token * k + slot``."""
+
+    row_pair: jax.Array     # (R,) int32: the pair a row carries (0: padding)
+    row_valid: jax.Array    # (R,) bool
+    pair_row: jax.Array     # (T, k) int32: the row of a pair (0: not held)
+    pair_held: jax.Array    # (T, k) bool
+    tile_expert: jax.Array  # (R / tile,) int32: held expert of a row tile
+    n_used: jax.Array       # (1,) int32: row tiles that hold a group
+    group_sizes: jax.Array  # (count,) int32: pairs on each held expert
+    dropped: jax.Array      # () int32: held pairs left without a row (0)
+
+
+def default_row_tile(n_pairs: int) -> int:
+    """Rows a tile: 256 at real sizes (an MXU-friendly product per grid
+    step), 16 where the whole call is smaller than that."""
+    return 256 if n_pairs >= 4096 else 16
+
+
+def layout_rows(n_pairs: int, count: int, tile: int) -> int:
+    """Rows of the grouped layout: every pair held, each of the ``count``
+    groups padded by up to a tile, an empty group keeping one."""
+    return (-(-n_pairs // tile) + count) * tile
+
+
+def route(h: jax.Array, w_router: jax.Array, top_k: int
+          ) -> Tuple[jax.Array, jax.Array]:
+    """``(gates (T, k) float32, experts (T, k) int32)``: softmax over
+    all experts in float32, the ``top_k`` largest, their probabilities
+    renormalised to sum to one."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(h, w_router.astype(h.dtype),
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top, experts = jax.lax.top_k(probs, top_k)
+        gates = top / jnp.sum(top, axis=-1, keepdims=True)
+        return gates, experts.astype(jnp.int32)
+
+
+def plan_dispatch(experts: jax.Array, experts_held: Tuple[int, int],
+                  tile: int) -> Plan:
+    """The row layout for this routing (integers only, no gradient)."""
+    first, count = experts_held
+    t, k = experts.shape
+    n_pairs = t * k
+    rows = layout_rows(n_pairs, count, tile)
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count)  # the rest sort behind
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes            # in the sorted pairs
+    tiles = jnp.maximum(-(-sizes // tile), 1)     # an empty group keeps one
+    tile_ends = jnp.cumsum(tiles)
+    row_starts = (tile_ends - tiles) * tile       # in the row layout
+    n_tiles = rows // tile
+    # the expert of each row tile; tiles past the last group repeat it
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right"),
+        count - 1).astype(jnp.int32)
+    # rows -> pairs
+    r = jnp.arange(rows, dtype=jnp.int32)
+    e_of_row = tile_expert[r // tile]
+    offset = r - row_starts[e_of_row]
+    row_valid = (offset < sizes[e_of_row]) & (r // tile < tile_ends[-1])
+    sorted_at = jnp.clip(starts[e_of_row] + offset, 0, n_pairs - 1)
+    row_pair = jnp.where(row_valid, order[sorted_at], 0)
+    # pairs -> rows: a pair's rank among the sorted pairs, then its row
+    rank = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
+        jnp.arange(n_pairs, dtype=jnp.int32), unique_indices=True)
+    e_of_pair = jnp.clip(local, 0, count - 1)
+    row_of_pair = row_starts[e_of_pair] + rank - starts[e_of_pair]
+    placed = held & (row_of_pair < rows)
+    return Plan(
+        row_pair=row_pair, row_valid=row_valid,
+        pair_row=jnp.where(placed, row_of_pair, 0).reshape(t, k),
+        pair_held=placed.reshape(t, k),
+        tile_expert=tile_expert,
+        n_used=tile_ends[-1:].astype(jnp.int32),
+        group_sizes=sizes,
+        dropped=jnp.sum(held & ~placed, dtype=jnp.int32))
+
+
+def _sum_rows_of_pairs(x: jax.Array, plan: Plan, weights: jax.Array
+                       ) -> jax.Array:
+    """``out[t] = sum over t's held pairs of weights[t, slot] * x[row of
+    the pair]``, (T, D) float32: one gather of (T, D) rows a slot."""
+    out = jnp.zeros((plan.pair_row.shape[0], x.shape[1]), jnp.float32)
+    for s in range(plan.pair_row.shape[1]):
+        w = jnp.where(plan.pair_held[:, s], weights[:, s], 0.0)
+        out = out + w[:, None] * x[plan.pair_row[:, s]].astype(jnp.float32)
+    return out
+
+
+@jax.custom_vjp
+def gather_rows(u: jax.Array, plan: Plan) -> jax.Array:
+    """``rows[r] = u[token of row r]`` (zeros on padding rows)."""
+    k = plan.pair_row.shape[1]
+    return jnp.where(plan.row_valid[:, None], u[plan.row_pair // k], 0)
+
+
+def _gather_rows_fwd(u, plan):
+    return gather_rows(u, plan), plan
+
+
+def _gather_rows_bwd(plan, d_rows):
+    ones = jnp.ones(plan.pair_row.shape, jnp.float32)
+    return _sum_rows_of_pairs(d_rows, plan, ones).astype(d_rows.dtype), None
+
+
+gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(y: jax.Array, gates: jax.Array, plan: Plan) -> jax.Array:
+    """``m[t] = sum over t's held pairs of gate * y[row of the pair]``,
+    float32 sum, in ``y``'s dtype."""
+    return _sum_rows_of_pairs(y, plan, gates).astype(y.dtype)
+
+
+def _combine_rows_fwd(y, gates, plan):
+    return combine_rows(y, gates, plan), (y, gates, plan)
+
+
+def _combine_rows_bwd(residuals, d_out):
+    y, gates, plan = residuals
+    k = gates.shape[1]
+    d32 = d_out.astype(jnp.float32)
+    row_gate = jnp.where(plan.row_valid,
+                         gates.reshape(-1)[plan.row_pair], 0.0)
+    d_y = (row_gate[:, None] * d32[plan.row_pair // k]).astype(y.dtype)
+    d_gates = jnp.stack([
+        jnp.where(plan.pair_held[:, s], jnp.sum(
+            d32 * y[plan.pair_row[:, s]].astype(jnp.float32), axis=-1), 0.0)
+        for s in range(k)], axis=1).astype(gates.dtype)
+    return d_y, d_gates, None
+
+
+combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def kernel_impl(use_pallas: bool) -> str:
+    """``"pallas"`` where the grouped-product kernels were asked for and
+    can run (a TPU backend), else ``"jnp"`` with the fallback counted."""
+    if not use_pallas:
+        return "jnp"
+    if jax.default_backend() == "tpu":
+        return "pallas"
+    from fmda_tpu.ops.dispatch import count_kernel_fallback
+
+    count_kernel_fallback("decoder", "backend")
+    return "jnp"
+
+
+def expert_layer(
+    u: jax.Array,
+    gates: jax.Array,
+    experts: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    experts_held: Tuple[int, int],
+    impl: str = "jnp",
+) -> Tuple[jax.Array, Plan]:
+    """The held experts' part of the layer's output, and the plan it was
+    computed under (whose ``group_sizes`` and ``dropped`` the caller
+    counts).
+
+    ``u`` (T, D) in the compute dtype; ``gates``/``experts`` (T, k) from
+    :func:`route`; ``w_gate``/``w_up`` (count, D, F) and ``w_down``
+    (count, F, D), float32, the held experts' matrices in order.
+    """
+    from fmda_tpu.ops.pallas_moe import grouped_matmul
+
+    t, k = experts.shape
+    tile = default_row_tile(t * k)
+    with jax.named_scope("moe_dispatch"):
+        plan = jax.tree.map(
+            jax.lax.stop_gradient,
+            plan_dispatch(experts, experts_held, tile))
+        rows = gather_rows(u, plan)
+    with jax.named_scope("moe_experts"):
+        tables = (plan.tile_expert, plan.n_used, tile, impl)
+        gate = grouped_matmul(rows, w_gate, *tables)
+        up = grouped_matmul(rows, w_up, *tables)
+        y = grouped_matmul(jax.nn.relu(gate) * up, w_down, *tables)
+    with jax.named_scope("moe_combine"):
+        return combine_rows(y, gates, plan), plan

@@ -42,17 +42,34 @@ the full array dim, and a (1, block) slab whose sublane dim is neither
 8-divisible nor full does not lower (same constraint that forced the GRU
 kernel time-major, ops/pallas_gru.py).
 
+Blocks are square, edge :func:`block_for` ``(T)``: 512 where T allows,
+else 256 or 128 — a grid step costs ~0.35 us whatever it computes, and a
+128 x 128 x D block is ~0.04 us of MXU work, so at T = 8192 the 128-wide
+grid is all overhead.  Two things ride inside the same kernels:
+
+- **grouped-query heads**: K/V may carry fewer heads than Q (``N`` query
+  heads on ``G`` key-value heads, ``N % G == 0``).  Nothing is repeated
+  in HBM: the K/V block index is the query head's index ``// (N / G)``;
+  the dK/dV sweep writes one float32 partial per query head and the
+  ``N / G`` partials of a group are summed outside.
+- **a causal window**: key ``j`` is visible to query ``i`` iff
+  ``0 <= i - j < window``.  Blocks wholly outside the band are skipped
+  (no MXU/VPU work) and their block index is clamped into the band, so
+  the pipeline re-references the block it already holds and fetches
+  nothing; blocks wholly inside the band skip the mask arithmetic.
+
 Support envelope (:func:`flash_supported`): self-attention with
-``Tq == Tk``, ``T % 128 == 0``, no arbitrary mask (causal is in-kernel),
-and D small enough that the per-block working set fits VMEM — in
-practice D <= 512.  Everything else falls back to the jnp path via
-:func:`fmda_tpu.ops.attention.mha`'s dispatch.
+``Tq == Tk``, ``T % 128 == 0``, no arbitrary mask (causal and the causal
+window are in-kernel; a window implies causal), and D small enough that
+the per-block working set fits VMEM — in practice D <= 512.  Everything
+else takes the jnp path via :func:`fmda_tpu.ops.attention.mha`'s
+dispatch.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,9 +78,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fmda_tpu.compat import CompilerParams
 
-#: Q/K block edge.  128 = MXU tile edge = Mosaic lane count; T must be a
-#: multiple (flash_supported gates on it).
+#: Smallest Q/K block edge.  128 = MXU tile edge = Mosaic lane count; T
+#: must be a multiple (flash_supported gates on it).
 _BLOCK = 128
+
+
+def block_for(seq_len: int) -> int:
+    """The square block edge the kernels use at this length: the largest
+    of 512, 256, 128 that divides it."""
+    for blk in (512, 256):
+        if seq_len % blk == 0:
+            return blk
+    return _BLOCK
 
 #: Finite stand-in for -inf in masked score slots: far below any real
 #: logit, but exp(finite - finite) stays a number (exp of ~-1e30 is 0.0
@@ -81,27 +107,73 @@ def flash_supported(q_len: int, k_len: int, d_head: int) -> bool:
     )
 
 
-def _causal_mask_block(qi, ki):
-    """(BLOCK, BLOCK) bool keep-mask for query block qi vs key block ki,
-    in global positions."""
-    q_pos = qi * _BLOCK + jax.lax.broadcasted_iota(
-        jnp.int32, (_BLOCK, _BLOCK), 0)
-    k_pos = ki * _BLOCK + jax.lax.broadcasted_iota(
-        jnp.int32, (_BLOCK, _BLOCK), 1)
-    return q_pos >= k_pos
+def _causal_mask_block(qi, ki, blk: int, window: Optional[int]):
+    """(blk, blk) bool keep-mask for query block qi vs key block ki, in
+    global positions: causal, and inside the window where there is one."""
+    q_pos = qi * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+    k_pos = ki * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = keep & (q_pos - k_pos < window)
+    return keep
+
+
+def _band_span(blk: int, window: Optional[int]) -> Optional[int]:
+    """How many key blocks behind its own a query block still sees
+    (None: all of them)."""
+    return None if window is None else (window + blk - 2) // blk
+
+
+def _in_band(qi, ki, span: Optional[int]):
+    """Block (qi, ki) holds at least one visible (query, key) pair."""
+    ok = ki <= qi
+    return ok if span is None else ok & (qi - ki <= span)
+
+
+def _interior(qi, ki, blk: int, window: Optional[int]):
+    """Every pair of block (qi, ki) is visible: no mask arithmetic."""
+    ok = ki < qi
+    if window is not None:
+        ok = ok & ((qi - ki) * blk + (blk - 1) < window)
+    return ok
+
+
+def _banded(causal: bool, qi, ki, blk, window, compute) -> None:
+    """Run ``compute(masked)`` for block (qi, ki): always and unmasked
+    without ``causal``; else only inside the band, masked on its edges."""
+    if not causal:
+        compute(False)
+        return
+    inside = _interior(qi, ki, blk, window)
+    pl.when(inside)(lambda: compute(False))
+    pl.when(_in_band(qi, ki, _band_span(blk, window)) & ~inside)(
+        lambda: compute(True))
+
+
+def _scores(q, k, qi, ki, *, blk, window, masked):
+    """Scaled scores of one block, masked slots at ``_NEG``."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if masked:
+        s = jnp.where(_causal_mask_block(qi, ki, blk, window), s, _NEG)
+    return s, scale
 
 
 def _fwd_kernel(
-    q_ref,  # (1, BLOCK, D)
-    k_ref,  # (1, BLOCK, D)
-    v_ref,  # (1, BLOCK, D)
-    o_ref,  # out (1, BLOCK, D)
-    lse_ref,  # out (1, BLOCK, 128) lane-replicated logsumexp
-    m_scr,  # VMEM (BLOCK, 128) f32
-    l_scr,  # VMEM (BLOCK, 128) f32
-    acc_scr,  # VMEM (BLOCK, D) f32
+    q_ref,  # (1, blk, D)
+    k_ref,  # (1, blk, D)
+    v_ref,  # (1, blk, D)
+    o_ref,  # out (1, blk, D)
+    lse_ref,  # out (1, blk, 128) lane-replicated logsumexp
+    m_scr,  # VMEM (blk, 128) f32
+    l_scr,  # VMEM (blk, 128) f32
+    acc_scr,  # VMEM (blk, D) f32
     *,
     causal: bool,
+    window: Optional[int],
+    blk: int,
     n_k: int,
 ):
     qi = pl.program_id(1)
@@ -113,26 +185,20 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr[:])
         acc_scr[:] = jnp.zeros_like(acc_scr[:])
 
-    def _compute():
+    def _compute(masked: bool):
         f32 = jnp.float32
-        q = q_ref[0]
-        k = k_ref[0]
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=f32
-        ) * scale
-        if causal:
-            s = jnp.where(_causal_mask_block(qi, ki), s, _NEG)
-
-        m_prev = m_scr[:, :1]  # (BLOCK, 1); lanes are replicated
+        s, _ = _scores(q_ref[0], k_ref[0], qi, ki, blk=blk, window=window,
+                       masked=masked)
+        m_prev = m_scr[:, :1]  # (blk, 1); lanes are replicated
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        # exactly zero where masked (s==_NEG - m_new underflows to 0
-        # anyway unless the whole row is masked and m_new==_NEG; this
-        # kills that)
-        p = jnp.where(s <= _NEG * 0.5, 0.0, p)
+        if masked:
+            # exactly zero where masked (s==_NEG - m_new underflows to 0
+            # anyway unless the whole row is masked and m_new==_NEG; this
+            # kills that)
+            p = jnp.where(s <= _NEG * 0.5, 0.0, p)
         l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
@@ -141,13 +207,10 @@ def _fwd_kernel(
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
-        # blocks strictly above the diagonal are fully masked: skip their
-        # MXU/VPU work entirely (round-4 advice: causal paid ~2x), the
-        # state update is a no-op there by construction
-        pl.when(ki <= qi)(_compute)
-    else:
-        _compute()
+    # blocks outside the band are fully masked: skip their MXU/VPU work
+    # entirely (round-4 advice: causal paid ~2x), the state update is a
+    # no-op there by construction
+    _banded(causal, qi, ki, blk, window, _compute)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -160,35 +223,65 @@ def _fwd_kernel(
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
+def _clamp_key_block(qi, ki, *, causal, blk, window):
+    """The key block a (qi, ki) grid step references: ``ki`` inside the
+    band, the band's nearest block outside it — an index the pipeline
+    already holds, so a skipped step fetches nothing."""
+    if not causal:
+        return ki
+    span = _band_span(blk, window)
+    lo = 0 if span is None else jnp.maximum(qi - span, 0)
+    return jnp.clip(ki, lo, qi)
+
+
+def _clamp_query_block(ki, qi, *, causal, blk, window, n_q):
+    """The dK/dV sweep's twin: the query block a (ki, qi) step
+    references."""
+    if not causal:
+        return qi
+    span = _band_span(blk, window)
+    hi = n_q - 1 if span is None else jnp.minimum(ki + span, n_q - 1)
+    return jnp.clip(qi, ki, hi)
+
+
 def _fwd_impl(
     q: jax.Array, k: jax.Array, v: jax.Array,
-    *, causal: bool, interpret: bool,
+    *, causal: bool, window: Optional[int], interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(BN, T, D) inputs -> (o (BN, T, D), lse (BN, T, 128))."""
+    """q (BN, T, D), k/v (BG, T, D) -> (o (BN, T, D), lse (BN, T, 128))."""
     bn, t, d = q.shape
-    n_blk = t // _BLOCK
-    kernel = functools.partial(_fwd_kernel, causal=causal, n_k=n_blk)
+    group = bn // k.shape[0]
+    blk = block_for(t)
+    n_blk = t // blk
+    kernel = functools.partial(
+        _fwd_kernel, causal=causal, window=window, blk=blk, n_k=n_blk)
+
+    def kv_index(b, qi, ki):
+        return (b // group,
+                _clamp_key_block(qi, ki, causal=causal, blk=blk,
+                                 window=window), 0)
+
     o, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",
         grid=(bn, n_blk, n_blk),
         in_specs=[
-            pl.BlockSpec((1, _BLOCK, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, _BLOCK, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, _BLOCK, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, blk, d), kv_index),
+            pl.BlockSpec((1, blk, d), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, _BLOCK, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, _BLOCK, 128), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, blk, 128), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bn, t, d), q.dtype),
             jax.ShapeDtypeStruct((bn, t, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((_BLOCK, 128), jnp.float32),
-            pltpu.VMEM((_BLOCK, 128), jnp.float32),
-            pltpu.VMEM((_BLOCK, d), jnp.float32),
+            pltpu.VMEM((blk, 128), jnp.float32),
+            pltpu.VMEM((blk, 128), jnp.float32),
+            pltpu.VMEM((blk, d), jnp.float32),
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
@@ -198,19 +291,37 @@ def _fwd_impl(
     return o, lse
 
 
+def _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+              *, blk, window, masked):
+    """The backward's shared recompute for one block: probabilities
+    ``p = exp(s - L)`` and ``ds = p * (do @ v^T - delta) * scale``."""
+    f32 = jnp.float32
+    s, scale = _scores(q_ref[0], k_ref[0], qi, ki, blk=blk, window=window,
+                       masked=masked)
+    p = jnp.exp(s - lse_ref[0][:, :1])
+    if masked:
+        p = jnp.where(s <= _NEG * 0.5, 0.0, p)
+    dp = jax.lax.dot_general(
+        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=f32)
+    return p, p * (dp - delta_ref[0][:, :1]) * scale
+
+
 def _dkv_kernel(
-    q_ref,  # (1, BLOCK, D) — query block qi
-    k_ref,  # (1, BLOCK, D) — the fixed key block ki
-    v_ref,  # (1, BLOCK, D)
-    do_ref,  # (1, BLOCK, D) — dO for query block qi
-    lse_ref,  # (1, BLOCK, 128)
-    delta_ref,  # (1, BLOCK, 128)
-    dk_ref,  # out (1, BLOCK, D)
-    dv_ref,  # out (1, BLOCK, D)
-    dk_scr,  # VMEM (BLOCK, D) f32
-    dv_scr,  # VMEM (BLOCK, D) f32
+    q_ref,  # (1, blk, D) — query block qi
+    k_ref,  # (1, blk, D) — the fixed key block ki
+    v_ref,  # (1, blk, D)
+    do_ref,  # (1, blk, D) — dO for query block qi
+    lse_ref,  # (1, blk, 128)
+    delta_ref,  # (1, blk, 128)
+    dk_ref,  # out (1, blk, D)
+    dv_ref,  # out (1, blk, D)
+    dk_scr,  # VMEM (blk, D) f32
+    dv_scr,  # VMEM (blk, D) f32
     *,
     causal: bool,
+    window: Optional[int],
+    blk: int,
     n_q: int,
 ):
     ki = pl.program_id(1)
@@ -221,41 +332,23 @@ def _dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr[:])
         dv_scr[:] = jnp.zeros_like(dv_scr[:])
 
-    def _compute():
+    def _compute(masked: bool):
         f32 = jnp.float32
-        q = q_ref[0]
-        k = k_ref[0]
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=f32
-        ) * scale
-        if causal:
-            s = jnp.where(_causal_mask_block(qi, ki), s, _NEG)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        p = jnp.where(s <= _NEG * 0.5, 0.0, p)
-
-        do = do_ref[0]
+        p, ds = _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          qi, ki, blk=blk, window=window, masked=masked)
         io_dtype = q_ref.dtype
         # dv += p^T @ do   (contract the query rows)
-        p_c = p.astype(io_dtype)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p_c, do, (((0,), (0,)), ((), ())), preferred_element_type=f32)
-        # ds = p * (do @ v^T - delta) * scale
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
+            p.astype(io_dtype), do_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=f32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
         # dk += ds^T @ q
         dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(io_dtype), q, (((0,), (0,)), ((), ())),
+            ds.astype(io_dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=f32)
 
-    if causal:
-        # query blocks above the diagonal contribute nothing to this
-        # K/V block's gradients — skip their matmuls
-        pl.when(qi >= ki)(_compute)
-    else:
-        _compute()
+    # query blocks outside the band contribute nothing to this K/V
+    # block's gradients — skip their matmuls
+    _banded(causal, qi, ki, blk, window, _compute)
 
     @pl.when(qi == n_q - 1)
     def _flush():
@@ -264,16 +357,18 @@ def _dkv_kernel(
 
 
 def _dq_kernel(
-    q_ref,  # (1, BLOCK, D) — the fixed query block qi
-    k_ref,  # (1, BLOCK, D) — key block ki
-    v_ref,  # (1, BLOCK, D)
-    do_ref,  # (1, BLOCK, D)
-    lse_ref,  # (1, BLOCK, 128)
-    delta_ref,  # (1, BLOCK, 128)
-    dq_ref,  # out (1, BLOCK, D)
-    dq_scr,  # VMEM (BLOCK, D) f32
+    q_ref,  # (1, blk, D) — the fixed query block qi
+    k_ref,  # (1, blk, D) — key block ki
+    v_ref,  # (1, blk, D)
+    do_ref,  # (1, blk, D)
+    lse_ref,  # (1, blk, 128)
+    delta_ref,  # (1, blk, 128)
+    dq_ref,  # out (1, blk, D)
+    dq_scr,  # VMEM (blk, D) f32
     *,
     causal: bool,
+    window: Optional[int],
+    blk: int,
     n_k: int,
 ):
     qi = pl.program_id(1)
@@ -283,33 +378,16 @@ def _dq_kernel(
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr[:])
 
-    def _compute():
-        f32 = jnp.float32
-        q = q_ref[0]
-        k = k_ref[0]
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=f32
-        ) * scale
-        if causal:
-            s = jnp.where(_causal_mask_block(qi, ki), s, _NEG)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        p = jnp.where(s <= _NEG * 0.5, 0.0, p)
-
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=f32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
+    def _compute(masked: bool):
+        _, ds = _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          qi, ki, blk=blk, window=window, masked=masked)
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(q_ref.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32)
+            ds.astype(q_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    if causal:
-        # key blocks past the diagonal are fully masked for this query
-        # block — no dq contribution, skip the matmuls
-        pl.when(ki <= qi)(_compute)
-    else:
-        _compute()
+    # key blocks outside the band are fully masked for this query
+    # block — no dq contribution, skip the matmuls
+    _banded(causal, qi, ki, blk, window, _compute)
 
     @pl.when(ki == n_k - 1)
     def _flush():
@@ -317,10 +395,14 @@ def _dq_kernel(
 
 
 def _bwd_impl(
-    q, k, v, o, lse, do, dlse=None, *, causal: bool, interpret: bool
+    q, k, v, o, lse, do, dlse=None, *, causal: bool,
+    window: Optional[int], interpret: bool,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     bn, t, d = q.shape
-    n_blk = t // _BLOCK
+    bg = k.shape[0]
+    group = bn // bg
+    blk = block_for(t)
+    n_blk = t // blk
     # delta = rowsum(do * o): cheap elementwise+reduce, plain XLA; ride
     # it in lane-replicated, matching lse's layout.  An lse cotangent
     # (the ring path differentiates through the per-block logsumexp)
@@ -330,44 +412,59 @@ def _bwd_impl(
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], (bn, t, 128))
+    clamp = dict(causal=causal, blk=blk, window=window)
 
-    qspec = pl.BlockSpec((1, _BLOCK, d), lambda b, ki, qi: (b, qi, 0))
-    kspec = pl.BlockSpec((1, _BLOCK, d), lambda b, ki, qi: (b, ki, 0))
-    rspec = pl.BlockSpec((1, _BLOCK, 128), lambda b, ki, qi: (b, qi, 0))
+    def q_rows(width):  # the dK/dV sweep's per-query-block operands
+        return pl.BlockSpec((1, blk, width), lambda b, ki, qi: (
+            b, _clamp_query_block(ki, qi, n_q=n_blk, **clamp), 0))
+
+    kspec = pl.BlockSpec((1, blk, d), lambda b, ki, qi: (b // group, ki, 0))
+    # one partial per query head; a group's partials are summed below, in
+    # float32 where there is more than one
+    part = q.dtype if group == 1 else jnp.float32
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, n_q=n_blk),
+        functools.partial(_dkv_kernel, causal=causal, window=window,
+                          blk=blk, n_q=n_blk),
         name="flash_bwd_dkv",
         grid=(bn, n_blk, n_blk),
-        in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
+        in_specs=[q_rows(d), kspec, kspec, q_rows(d), q_rows(128),
+                  q_rows(128)],
         out_specs=[
-            pl.BlockSpec((1, _BLOCK, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, _BLOCK, d), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, blk, d), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, blk, d), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bn, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bn, t, d), part),
+            jax.ShapeDtypeStruct((bn, t, d), part),
         ],
         scratch_shapes=[
-            pltpu.VMEM((_BLOCK, d), jnp.float32),
-            pltpu.VMEM((_BLOCK, d), jnp.float32),
+            pltpu.VMEM((blk, d), jnp.float32),
+            pltpu.VMEM((blk, d), jnp.float32),
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
+    if group > 1:
+        dk, dv = (x.reshape(bg, group, t, d).sum(axis=1).astype(k.dtype)
+                  for x in (dk, dv))
 
-    qspec2 = pl.BlockSpec((1, _BLOCK, d), lambda b, qi, ki: (b, qi, 0))
-    kspec2 = pl.BlockSpec((1, _BLOCK, d), lambda b, qi, ki: (b, ki, 0))
-    rspec2 = pl.BlockSpec((1, _BLOCK, 128), lambda b, qi, ki: (b, qi, 0))
+    def k_rows(b, qi, ki):
+        return (b // group, _clamp_key_block(qi, ki, **clamp), 0)
+
+    qspec2 = pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0))
+    kspec2 = pl.BlockSpec((1, blk, d), k_rows)
+    rspec2 = pl.BlockSpec((1, blk, 128), lambda b, qi, ki: (b, qi, 0))
     (dq,) = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, n_k=n_blk),
+        functools.partial(_dq_kernel, causal=causal, window=window,
+                          blk=blk, n_k=n_blk),
         name="flash_bwd_dq",
         grid=(bn, n_blk, n_blk),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
         out_specs=[qspec2],
         out_shape=[jax.ShapeDtypeStruct((bn, t, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((_BLOCK, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
@@ -376,22 +473,24 @@ def _bwd_impl(
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, interpret):
-    o, lse = _fwd_impl(q, k, v, causal=causal, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, window, interpret):
+    o, lse = _fwd_impl(q, k, v, causal=causal, window=window,
+                       interpret=interpret)
     return o, lse[..., 0]
 
 
-def _flash_fwd(q, k, v, causal, interpret):
-    o, lse = _fwd_impl(q, k, v, causal=causal, interpret=interpret)
+def _flash_fwd(q, k, v, causal, window, interpret):
+    o, lse = _fwd_impl(q, k, v, causal=causal, window=window,
+                       interpret=interpret)
     return (o, lse[..., 0]), (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, interpret, residuals, cts):
+def _flash_bwd(causal, window, interpret, residuals, cts):
     q, k, v, o, lse = residuals
     do, dlse = cts
     return _bwd_impl(q, k, v, o, lse, do, dlse, causal=causal,
-                     interpret=interpret)
+                     window=window, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -403,9 +502,11 @@ def flash_attention(
     v: jax.Array,
     *,
     causal: bool = False,
+    window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused-kernel multi-head attention, (B, N, T, D) -> (B, N, T, D).
+    """Fused-kernel multi-head attention, q (B, N, T, D) and k/v
+    (B, G, T, D) with ``N % G == 0`` -> (B, N, T, D).
 
     Numerics match :func:`fmda_tpu.ops.attention.mha` (same online
     softmax, f32 accumulation); parity is test-locked in interpret mode
@@ -414,7 +515,7 @@ def flash_attention(
     already checked :func:`flash_supported`.
     """
     out, _ = flash_attention_with_lse(
-        q, k, v, causal=causal, interpret=interpret)
+        q, k, v, causal=causal, window=window, interpret=interpret)
     return out
 
 
@@ -424,6 +525,7 @@ def flash_attention_with_lse(
     v: jax.Array,
     *,
     causal: bool = False,
+    window: Optional[int] = None,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused attention returning ``(o, lse)`` — o (B, N, T, D) in q's
@@ -437,12 +539,23 @@ def flash_attention_with_lse(
     blocks.  Differentiable in both outputs (the lse cotangent folds
     into the backward's delta term).  Fully-masked rows report
     ``lse = -1e30`` (the kernel's finite -inf sentinel) and ``o = 0``.
+    ``window`` (a causal window: key j visible to query i iff
+    ``0 <= i - j < window``) implies ``causal``.
     """
     b, n, t, d = q.shape
+    g = k.shape[1]
     if not flash_supported(q.shape[-2], k.shape[-2], d):
         raise ValueError(
             f"flash kernel unsupported for Tq={q.shape[-2]} "
             f"Tk={k.shape[-2]} D={d}; gate on flash_supported()")
-    fold = lambda x: x.reshape(b * n, t, d)
-    out, lse = _flash(fold(q), fold(k), fold(v), causal, interpret)
+    if n % g != 0 or v.shape[1] != g:
+        raise ValueError(
+            f"{n} query heads cannot share {g} key / {v.shape[1]} value "
+            "heads: the query heads must be a multiple of both")
+    causal = causal or window is not None
+    if window is not None and window >= t:
+        window = None  # the band is the whole causal triangle
+    out, lse = _flash(
+        q.reshape(b * n, t, d), k.reshape(b * g, t, d),
+        v.reshape(b * g, t, d), causal, window, interpret)
     return out.reshape(b, n, t, d), lse.reshape(b, n, t)

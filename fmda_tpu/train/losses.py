@@ -1,4 +1,5 @@
-"""Losses and class-imbalance weighting.
+"""Losses: the window classifiers' weighted BCE with its class-imbalance
+weighting, and the token decoder's next-token cross-entropy.
 
 The reference trains with ``BCEWithLogitsLoss(weight=[N/n_c],
 pos_weight=[(N-n_c)/n_c])`` (training notebook cells 13-16, 29).  The same
@@ -84,3 +85,50 @@ def weighted_bce_sums(
         return jnp.sum(per_elem), jnp.asarray(n, per_elem.dtype)
     m = example_mask.astype(per_elem.dtype)[:, None]
     return jnp.sum(per_elem * m), jnp.sum(m) * per_elem.shape[-1]
+
+
+def chunked_next_token_loss(
+    hidden: jax.Array,
+    head: jax.Array,
+    targets: jax.Array,
+    mask: jax.Array,
+    *,
+    chunk: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(loss_sum, n_tokens, n_correct)`` of softmax cross-entropy over
+    the vocabulary, taken ``chunk`` tokens at a time.
+
+    ``hidden`` (N, D) in the compute dtype, ``head`` (D, V) float32,
+    ``targets`` (N,) int32, ``mask`` (N,) — 0 for a token that does not
+    count.  The logits of one chunk exist at a time (``chunk * V``
+    float32, recomputed in backward) and never ``N * V``; the sum is the
+    one taken over all tokens at once, up to float re-association.  The
+    head is cast inside each chunk, so its cotangent accumulates over
+    the chunks in float32.  ``chunk`` is lowered to a divisor of N.
+    """
+    n = hidden.shape[0]
+    chunk = max(1, min(chunk, n))
+    while n % chunk:
+        chunk -= 1
+
+    @jax.checkpoint
+    def one(head, h, y, m):
+        with jax.named_scope("lm_head"):
+            logits = jnp.dot(h, head.astype(h.dtype),
+                             preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+        keep = m > 0
+        hit = keep & (jnp.argmax(logits, axis=-1) == y)
+        return (jnp.sum(jnp.where(keep, lse - picked, 0.0)),
+                jnp.sum(hit, dtype=jnp.int32))
+
+    def body(carry, xs):
+        s, c = one(head, *xs)
+        return (carry[0] + s, carry[1] + c), None
+
+    split = lambda a: a.reshape((n // chunk, chunk) + a.shape[1:])
+    (loss_sum, correct), _ = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
+        (split(hidden), split(targets), split(mask)))
+    return loss_sum, jnp.sum(mask > 0, dtype=jnp.int32), correct

@@ -1,0 +1,284 @@
+"""What a model family is trained on: its batches, its loss, the values
+each step adds to the pass's totals, and what a pass publishes.
+
+The trainer owns one step loop, one pair of compiled steps, the
+placed-batch cache and the checkpoints; everything that differs between
+a classifier of feature windows and a next-token decoder is asked of the
+family's *task* (:func:`task_for`):
+
+====================  ===================================================
+``init_params``       fresh parameters (the dummy input is the task's)
+``dataset``           chunks over the source, with the split
+``batches``           fixed-shape :class:`~fmda_tpu.data.pipeline.Batch`
+                      es of one chunk
+``forward`` / ``loss``  inside the compiled step, under its ``forward`` /
+                      ``loss`` scopes; ``loss_sums`` for accumulation
+``step_values``       this step's entries of the totals (``metrics``
+                      scope)
+``zero_totals``       the totals at zero, as host arrays
+``epoch_metrics``     the drained totals as :class:`EpochMetrics`
+``publish``           counters written once a pass, at the drain
+``feature_windows``   whether the source is a table of float features
+                      (class weights, a drift profile, ``n_features``)
+``norm_params``       the normalisation a checkpoint saves beside the
+                      parameters (None where inputs are not normalised)
+====================  ===================================================
+
+The totals ride through the compiled steps as PR 27 made them: a step
+gets the pass's totals and returns them with its own values added, so
+the host fetches nothing until the pass drains.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Iterable, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from fmda_tpu.config import ModelConfig, TrainConfig
+from fmda_tpu.data.pipeline import (
+    Batch, ChunkDataset, TokenBatches, TokenDataset, WindowBatches)
+from fmda_tpu.ops.metrics import multilabel_metrics
+from fmda_tpu.train.losses import (
+    chunked_next_token_loss, weighted_bce_sums, weighted_bce_with_logits)
+
+
+class EpochMetrics(NamedTuple):
+    """One pass's averages.  A classifier fills all four; the token task
+    reports next-token accuracy, ``hamming = 1 - accuracy`` and an empty
+    ``fbeta``."""
+
+    loss: float
+    accuracy: float
+    hamming: float
+    fbeta: np.ndarray  # (n_classes,)
+
+
+class StepTotals(NamedTuple):
+    """A classification pass's running sums of each step's loss and
+    metrics, carried through the compiled steps.  One step from
+    :meth:`Trainer.zero_totals` leaves that step's own values (``0 + v``
+    is ``v`` exactly)."""
+
+    loss: jax.Array
+    accuracy: jax.Array
+    hamming: jax.Array
+    fbeta: jax.Array  # (n_classes,)
+    confusion: jax.Array  # (n_classes, 2, 2) int32
+
+
+class TokenTotals(NamedTuple):
+    """A token pass's running sums: each step's mean loss, the tokens
+    that counted and those predicted right, and what the expert layers
+    counted (``expert_pairs[l, e]``: pairs layer ``l`` computed on held
+    expert ``e``)."""
+
+    loss: jax.Array          # () float32
+    tokens: jax.Array        # () int32
+    correct: jax.Array       # () int32
+    expert_pairs: jax.Array  # (layers, held experts) int32
+    dropped: jax.Array       # () int32
+
+
+class WindowClassification:
+    """Weighted BCE over the labels of a float feature window: the task
+    of ``gru``, ``lstm``, ``attn`` and ``ssm``."""
+
+    feature_windows = True
+
+    def __init__(self, model_cfg: ModelConfig, train_cfg: TrainConfig, *,
+                 weight=None, pos_weight=None) -> None:
+        self.model_cfg, self.train_cfg = model_cfg, train_cfg
+        self.weight, self.pos_weight = weight, pos_weight
+
+    # -- outside the compiled step ------------------------------------------
+
+    def init_params(self, model, rng: jax.Array) -> Any:
+        dummy = jnp.zeros(
+            (1, self.train_cfg.window, self.model_cfg.n_features),
+            jnp.float32)
+        return model.init({"params": rng}, dummy)["params"]
+
+    def dataset(self, source, *, bid_levels: int = 0, ask_levels: int = 0
+                ) -> ChunkDataset:
+        tc = self.train_cfg
+        return ChunkDataset(
+            source, tc.chunk_size, tc.window, bid_levels=bid_levels,
+            ask_levels=ask_levels, cache_chunks=tc.cache_chunks)
+
+    def batches(self, dataset: ChunkDataset, chunk_idx: int
+                ) -> Iterable[Batch]:
+        return WindowBatches(dataset, chunk_idx, self.train_cfg.batch_size)
+
+    def norm_params(self, dataset: ChunkDataset):
+        return dataset.final_norm_params
+
+    def zero_totals(self) -> StepTotals:
+        n = self.model_cfg.output_size
+        zero = np.zeros((), np.float32)
+        return StepTotals(zero, zero, zero, np.zeros((n,), np.float32),
+                          np.zeros((n, 2, 2), np.int32))
+
+    def epoch_metrics(self, totals: Optional[StepTotals], steps: int
+                      ) -> Tuple[EpochMetrics, np.ndarray]:
+        n = self.model_cfg.output_size
+        if totals is None:  # a pass with no batch
+            nan = float("nan")
+            return (EpochMetrics(nan, nan, nan, np.zeros(n)),
+                    np.zeros((n, 2, 2), np.int64))
+        loss_sum, acc_sum, ham_sum, fbeta_sum, confusion_total = totals
+        return EpochMetrics(
+            loss=float(loss_sum) / steps,
+            accuracy=float(acc_sum) / steps,
+            hamming=float(ham_sum) / steps,
+            fbeta=np.asarray(fbeta_sum) / steps,
+        ), np.asarray(confusion_total, np.int64)
+
+    def publish(self, totals: StepTotals, phase: str) -> None:
+        """Nothing beyond what the step loop counts itself."""
+
+    # -- inside the compiled step ---------------------------------------------
+
+    def forward(self, model, params, batch: Batch, rng: Optional[jax.Array]):
+        if rng is None:
+            return model.apply({"params": params}, batch.x)
+        return model.apply({"params": params}, batch.x, deterministic=False,
+                           rngs={"dropout": rng})
+
+    def loss(self, params, out, batch: Batch):
+        """``(mean loss, what step_values reads)``."""
+        return weighted_bce_with_logits(
+            out, batch.y, weight=self.weight, pos_weight=self.pos_weight,
+            example_mask=batch.mask), out
+
+    def loss_sums(self, params, out, batch: Batch):
+        """``(loss sum, count, aux)`` of one microbatch."""
+        s, count = weighted_bce_sums(
+            out, batch.y, weight=self.weight, pos_weight=self.pos_weight,
+            example_mask=batch.mask)
+        return s, count, out
+
+    def merge_micro(self, aux_k):
+        """The microbatches' logits as the full batch's."""
+        return aux_k.reshape((-1,) + aux_k.shape[2:])
+
+    def step_values(self, loss, aux, batch: Batch) -> StepTotals:
+        tc = self.train_cfg
+        metrics = multilabel_metrics(
+            aux, batch.y, threshold=tc.prob_threshold, beta=tc.fbeta_beta,
+            example_mask=batch.mask)
+        return StepTotals(loss, *metrics)
+
+
+class NextToken:
+    """Next-token cross-entropy over the held vocabulary, per-token
+    mask: the task of ``decoder``."""
+
+    feature_windows = False
+
+    def __init__(self, model_cfg: ModelConfig, train_cfg: TrainConfig, **_
+                 ) -> None:
+        self.model_cfg, self.train_cfg = model_cfg, train_cfg
+
+    # -- outside the compiled step ------------------------------------------
+
+    def init_params(self, model, rng: jax.Array) -> Any:
+        # eight positions: parameters do not depend on the length, and an
+        # eager init at the trained length would run every layer op by op
+        from fmda_tpu.obs.device import tracked_jit
+
+        dummy = jnp.zeros((1, 8), jnp.int32)
+        return tracked_jit(
+            lambda r: model.init({"params": r}, dummy)["params"],
+            name="decoder_init")(rng)
+
+    def dataset(self, source, **_) -> TokenDataset:
+        tc = self.train_cfg
+        if source.vocab_size > self.model_cfg.vocab_size:
+            raise ValueError(
+                f"the source's ids run to {source.vocab_size - 1}, the "
+                f"model holds {self.model_cfg.vocab_size}")
+        return TokenDataset(source, tc.chunk_size, tc.window)
+
+    def batches(self, dataset: TokenDataset, chunk_idx: int
+                ) -> Iterable[Batch]:
+        return TokenBatches(dataset, chunk_idx, self.train_cfg.batch_size)
+
+    def norm_params(self, dataset: TokenDataset) -> None:
+        """Token ids are not normalised."""
+        return None
+
+    def zero_totals(self) -> TokenTotals:
+        mc = self.model_cfg
+        zero = np.zeros((), np.int32)
+        return TokenTotals(
+            np.zeros((), np.float32), zero, zero,
+            np.zeros((len(mc.layer_layout), mc.experts_held[1]), np.int32),
+            zero)
+
+    def epoch_metrics(self, totals: Optional[TokenTotals], steps: int
+                      ) -> Tuple[EpochMetrics, np.ndarray]:
+        confusion = np.zeros((0, 2, 2), np.int64)
+        if totals is None:
+            nan = float("nan")
+            return EpochMetrics(nan, nan, nan, np.zeros(0)), confusion
+        accuracy = float(totals.correct) / max(int(totals.tokens), 1)
+        return EpochMetrics(
+            loss=float(totals.loss) / steps, accuracy=accuracy,
+            hamming=1.0 - accuracy, fbeta=np.zeros(0)), confusion
+
+    def publish(self, totals: TokenTotals, phase: str) -> None:
+        """The pass's token and routing counts, from the drained totals
+        (docs/observability.md "Spans and scopes")."""
+        from fmda_tpu.obs.registry import default_registry
+
+        reg = default_registry()
+        if phase == "train":
+            reg.counter("train_tokens_total").inc(int(totals.tokens))
+        reg.counter("moe_pairs_dropped_total").inc(int(totals.dropped))
+        for layer, pairs in enumerate(np.asarray(totals.expert_pairs)):
+            labels = dict(layer=str(layer), phase=phase)
+            reg.counter("moe_pairs_held_total", **labels).inc(
+                int(pairs.sum()))
+            reg.gauge("moe_expert_pairs_max", **labels).set(
+                int(pairs.max()))
+
+    # -- inside the compiled step ---------------------------------------------
+
+    def forward(self, model, params, batch: Batch, rng: Optional[jax.Array]):
+        del rng  # the family has no dropout
+        return model.apply({"params": params}, batch.x, method="features")
+
+    def loss_sums(self, params, out, batch: Batch):
+        hidden, stats = out
+        s, tokens, correct = chunked_next_token_loss(
+            hidden.reshape(-1, hidden.shape[-1]), params["head"],
+            batch.y.reshape(-1), batch.mask.reshape(-1),
+            chunk=self.model_cfg.loss_chunk)
+        return s, tokens.astype(jnp.float32), (tokens, correct, stats)
+
+    def loss(self, params, out, batch: Batch):
+        s, count, aux = self.loss_sums(params, out, batch)
+        return s / jnp.maximum(count, 1.0), aux
+
+    def merge_micro(self, aux_k):
+        """Counts add over the microbatches."""
+        return jax.tree.map(lambda a: jnp.sum(a, axis=0), aux_k)
+
+    def step_values(self, loss, aux, batch: Batch) -> TokenTotals:
+        tokens, correct, stats = aux
+        return TokenTotals(loss, tokens, correct, stats.expert_pairs,
+                           stats.dropped)
+
+
+def task_class(model_cfg: ModelConfig):
+    """The task of ``model_cfg.cell``'s family, as a class."""
+    return NextToken if model_cfg.cell == "decoder" else WindowClassification
+
+
+def task_for(model_cfg: ModelConfig, train_cfg: TrainConfig, *,
+             weight=None, pos_weight=None):
+    return task_class(model_cfg)(
+        model_cfg, train_cfg, weight=weight, pos_weight=pos_weight)

@@ -4,7 +4,10 @@ Replaces the reference's notebook training loop
 (biGRU_model_training.ipynb cells 11-39 + biGRU_model.py:162-286) with a
 proper API.  Same semantics — chunk-level contiguous split, per-chunk
 normalization, weighted BCE, Adam with global-norm clip 50, per-batch
-metrics averaged per epoch — but everything device-side:
+metrics averaged per epoch — but everything device-side, and with what
+is particular to a family (its batches, loss and per-step values) asked
+of the family's task (:mod:`fmda_tpu.train.tasks`), so that a
+next-token decoder trains through the same loop:
 
 - one compiled ``train_step``/``eval_step`` reused for every batch (fixed
   shapes via padded+masked batches — no per-batch Python/sklearn work);
@@ -17,7 +20,7 @@ metrics averaged per epoch — but everything device-side:
 from __future__ import annotations
 
 import logging
-from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import flax.struct
 import jax
@@ -26,21 +29,12 @@ import numpy as np
 import optax
 
 from fmda_tpu.config import ModelConfig, TrainConfig
-from fmda_tpu.data.pipeline import (
-    Batch,
-    ChunkDataset,
-    WindowBatches,
-    prefetch_batches,
-)
+from fmda_tpu.data.pipeline import Batch, ChunkDataset, prefetch_batches
 from fmda_tpu.data.source import FeatureSource
 from fmda_tpu.models import build_model
 from fmda_tpu.obs.device import tracked_jit
-from fmda_tpu.ops.metrics import multilabel_metrics
-from fmda_tpu.train.losses import (
-    class_weights,
-    weighted_bce_sums,
-    weighted_bce_with_logits,
-)
+from fmda_tpu.train.losses import class_weights
+from fmda_tpu.train.tasks import EpochMetrics, StepTotals, task_for
 
 log = logging.getLogger("fmda_tpu.train")
 
@@ -52,29 +46,10 @@ class TrainState:
     step: jax.Array
 
 
-class EpochMetrics(NamedTuple):
-    loss: float
-    accuracy: float
-    hamming: float
-    fbeta: np.ndarray  # (n_classes,)
-
-
-class StepTotals(NamedTuple):
-    """A pass's running sums of each step's loss and metrics, carried
-    through the compiled steps.  One step from :meth:`Trainer.zero_totals`
-    leaves that step's own values (``0 + v`` is ``v`` exactly)."""
-
-    loss: jax.Array
-    accuracy: jax.Array
-    hamming: jax.Array
-    fbeta: jax.Array  # (n_classes,)
-    confusion: jax.Array  # (n_classes, 2, 2) int32
-
-
-def _add_step(totals: StepTotals, loss, metrics) -> StepTotals:
+def _add_step(totals, values):
     """``totals + this step's values``, inside a compiled step (under its
     ``metrics`` scope)."""
-    return jax.tree.map(jnp.add, totals, StepTotals(loss, *metrics))
+    return jax.tree.map(jnp.add, totals, values)
 
 
 class Trainer:
@@ -99,6 +74,8 @@ class Trainer:
         )
         self.weight = None if weight is None else jnp.asarray(weight)
         self.pos_weight = None if pos_weight is None else jnp.asarray(pos_weight)
+        self.task = task_for(model_cfg, train_cfg, weight=self.weight,
+                             pos_weight=self.pos_weight)
         self.mesh = mesh
         self.dp_axis = dp_axis
         self._train_step = self._build_train_step()
@@ -111,14 +88,10 @@ class Trainer:
 
     def _init_state_local(self, rng: jax.Array) -> TrainState:
         """Fresh state on the default device (no mesh placement)."""
-        cfg = self.model_cfg
-        dummy = jnp.zeros(
-            (1, self.train_cfg.window, cfg.n_features), jnp.float32
-        )
-        variables = self.model.init({"params": rng}, dummy)
-        opt_state = self.optimizer.init(variables["params"])
+        params = self.task.init_params(self.model, rng)
+        opt_state = self.optimizer.init(params)
         return TrainState(
-            params=variables["params"],
+            params=params,
             opt_state=opt_state,
             step=jnp.zeros((), jnp.int32),
         )
@@ -151,9 +124,10 @@ class Trainer:
         # remembered so a subsequent fit() can detect that the data source
         # (and hence the recomputed normalization) changed since the save
         self._restored_norm = norm
-        # structure/dtype template only — no mesh placement of throwaway
-        # arrays; the restored state is placed once below
-        template = self._init_state_local(jax.random.PRNGKey(0))
+        # structure/dtype template only: shapes, never arrays (a second
+        # state beside the restored one is 8 GB at the decoder's widths)
+        template = jax.eval_shape(
+            self._init_state_local, jax.random.PRNGKey(0))
         params = jax.tree.map(
             lambda t, r: jnp.asarray(r, t.dtype), template.params,
             tree["params"],
@@ -195,33 +169,20 @@ class Trainer:
         )
 
     def _build_train_step(self):
-        model, tc = self.model, self.train_cfg
-        weight, pos_weight = self.weight, self.pos_weight
+        model, tc, task = self.model, self.train_cfg, self.task
         accum = tc.accum_steps
 
         def grads_full(params, batch: Batch, dropout_rng):
             def loss_fn(params):
                 with jax.named_scope("forward"):
-                    logits = model.apply(
-                        {"params": params},
-                        batch.x,
-                        deterministic=False,
-                        rngs={"dropout": dropout_rng},
-                    )
+                    out = task.forward(model, params, batch, dropout_rng)
                 with jax.named_scope("loss"):
-                    loss = weighted_bce_with_logits(
-                        logits,
-                        batch.y,
-                        weight=weight,
-                        pos_weight=pos_weight,
-                        example_mask=batch.mask,
-                    )
-                return loss, logits
+                    return task.loss(params, out, batch)
 
-            (loss, logits), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params
             )
-            return loss, logits, grads
+            return loss, aux, grads
 
         def grads_accum(params, batch: Batch, dropout_rng):
             # (B, ...) -> (K, B/K, ...): equal fixed-shape microbatches
@@ -240,21 +201,10 @@ class Trainer:
 
             def sum_loss_fn(params, mb: Batch, mb_rng):
                 with jax.named_scope("forward"):
-                    logits = model.apply(
-                        {"params": params},
-                        mb.x,
-                        deterministic=False,
-                        rngs={"dropout": mb_rng},
-                    )
+                    out = task.forward(model, params, mb, mb_rng)
                 with jax.named_scope("loss"):
-                    s, count = weighted_bce_sums(
-                        logits,
-                        mb.y,
-                        weight=weight,
-                        pos_weight=pos_weight,
-                        example_mask=mb.mask,
-                    )
-                return s, (count, logits)
+                    s, count, aux = task.loss_sums(params, out, mb)
+                return s, (count, aux)
 
             def body(carry, xs):
                 grad_sum, loss_sum, count_sum = carry
@@ -262,7 +212,7 @@ class Trainer:
                 # each microbatch gets its own dropout stream (folded on
                 # the microbatch index) — full/accumulated equivalence is
                 # stated at dropout 0.0
-                (s, (count, logits)), g = jax.value_and_grad(
+                (s, (count, aux)), g = jax.value_and_grad(
                     sum_loss_fn, has_aux=True
                 )(params, mb, jax.random.fold_in(dropout_rng, k))
                 carry = (
@@ -270,29 +220,29 @@ class Trainer:
                     loss_sum + s,
                     count_sum + count,
                 )
-                return carry, logits
+                return carry, aux
 
             zeros = jax.tree.map(jnp.zeros_like, params)
             init = (zeros, jnp.zeros((), jnp.float32),
                     jnp.zeros((), jnp.float32))
-            (grad_sum, loss_sum, count_sum), logits_k = jax.lax.scan(
+            (grad_sum, loss_sum, count_sum), aux_k = jax.lax.scan(
                 body, init, (micro, jnp.arange(accum))
             )
             denom = jnp.maximum(count_sum, 1.0)
             grads = jax.tree.map(lambda g: g / denom, grad_sum)
-            # metrics run on the full-batch logits, same as the K=1 path
-            logits = logits_k.reshape((-1,) + logits_k.shape[2:])
-            return loss_sum / denom, logits, grads
+            # metrics run on the full batch's values (a classifier's
+            # logits, concatenated), same as the K=1 path
+            return loss_sum / denom, task.merge_micro(aux_k), grads
 
-        def step_fn(state: TrainState, totals: StepTotals, batch: Batch,
+        def step_fn(state: TrainState, totals, batch: Batch,
                     rng: jax.Array):
             with jax.named_scope("forward"):  # the forward's dropout key
                 dropout_rng = jax.random.fold_in(rng, state.step)
             if accum == 1:
-                loss, logits, grads = grads_full(
+                loss, aux, grads = grads_full(
                     state.params, batch, dropout_rng)
             else:
-                loss, logits, grads = grads_accum(
+                loss, aux, grads = grads_accum(
                     state.params, batch, dropout_rng)
             # named scopes are metadata on the compiled operations (the
             # profile's device lines read them; docs/observability.md
@@ -304,14 +254,8 @@ class Trainer:
                 )
                 params = optax.apply_updates(state.params, updates)
             with jax.named_scope("metrics"):
-                metrics = multilabel_metrics(
-                    logits,
-                    batch.y,
-                    threshold=tc.prob_threshold,
-                    beta=tc.fbeta_beta,
-                    example_mask=batch.mask,
-                )
-                totals = _add_step(totals, loss, metrics)
+                totals = _add_step(
+                    totals, task.step_values(loss, aux, batch))
             new_state = TrainState(
                 params=params, opt_state=opt_state, step=state.step + 1
             )
@@ -328,28 +272,16 @@ class Trainer:
         return tracked_jit(step_fn, name="train_step", **jit_kwargs)
 
     def _build_eval_step(self):
-        model, tc = self.model, self.train_cfg
+        model, task = self.model, self.task
 
-        def eval_fn(params, totals: StepTotals, batch: Batch):
+        def eval_fn(params, totals, batch: Batch):
             with jax.named_scope("forward"):
-                logits = model.apply({"params": params}, batch.x)
+                out = task.forward(model, params, batch, None)
             with jax.named_scope("loss"):
-                loss = weighted_bce_with_logits(
-                    logits,
-                    batch.y,
-                    weight=self.weight,
-                    pos_weight=self.pos_weight,
-                    example_mask=batch.mask,
-                )
+                loss, aux = task.loss(params, out, batch)
             with jax.named_scope("metrics"):
-                metrics = multilabel_metrics(
-                    logits,
-                    batch.y,
-                    threshold=tc.prob_threshold,
-                    beta=tc.fbeta_beta,
-                    example_mask=batch.mask,
-                )
-                return _add_step(totals, loss, metrics)
+                return _add_step(
+                    totals, task.step_values(loss, aux, batch))
 
         jit_kwargs: Dict[str, Any] = {"donate_argnums": (1,)}
         shardings = self._step_shardings()
@@ -360,23 +292,20 @@ class Trainer:
             jit_kwargs["out_shardings"] = replicated
         return tracked_jit(eval_fn, name="eval_step", **jit_kwargs)
 
-    def zero_totals(self) -> StepTotals:
-        """A pass's accumulators at zero, placed as the compiled steps
-        return them (same dtypes, strong types and placement, so a pass's
-        first step runs the executable its second does)."""
-        n = self.model_cfg.output_size
-        zero = np.zeros((), np.float32)
-        totals = StepTotals(
-            zero, zero, zero, np.zeros((n,), np.float32),
-            np.zeros((n, 2, 2), np.int32))
+    def zero_totals(self):
+        """A pass's accumulators at zero (the task's: :class:`StepTotals`
+        for a classifier), placed as the compiled steps return them (same
+        dtypes, strong types and placement, so a pass's first step runs
+        the executable its second does)."""
+        totals = self.task.zero_totals()
         if self.mesh is None:
-            return jax.device_put(totals)  # one placement for the five
+            return jax.device_put(totals)  # one placement for all leaves
         return self._place_state(totals)
 
     def single_step(
         self, state: TrainState, batch: Batch,
         rng: Optional[jax.Array] = None,
-    ) -> Tuple[TrainState, StepTotals]:
+    ) -> Tuple[TrainState, Any]:
         """One step's own loss and metrics, through the program the step
         loop runs: a train step with ``rng`` (``state``'s buffers are
         donated, as in the loop), an eval step without."""
@@ -437,12 +366,8 @@ class Trainer:
         return prefetch_batches(
             batches, place, depth=self.train_cfg.prefetch_depth)
 
-    def _chunk_batches(
-        self, dataset: ChunkDataset, chunk_idx: int
-    ) -> Iterable[Batch]:
-        return self._place_batches(
-            WindowBatches(dataset, chunk_idx, self.train_cfg.batch_size)
-        )
+    def _chunk_batches(self, dataset, chunk_idx: int) -> Iterable[Batch]:
+        return self._place_batches(self.task.batches(dataset, chunk_idx))
 
     # -- epochs --------------------------------------------------------------
 
@@ -483,8 +408,7 @@ class Trainer:
 
         def host_batches() -> Iterable[Batch]:
             for idx in chunk_indices:
-                yield from WindowBatches(
-                    dataset, idx, self.train_cfg.batch_size)
+                yield from self.task.batches(dataset, idx)
 
         placed = self._place_batches(host_batches())
         if not cache_on:
@@ -559,31 +483,18 @@ class Trainer:
                 # totals for the old (the sum is in the compiled step)
                 with span(fold_name):
                     totals = out
-        n_classes = self.model_cfg.output_size
         if step_no == 0:
             log.warning(
                 "pass produced no batches (source too short for "
                 "window=%d/chunk_size=%d, or empty chunk split) — metrics "
                 "are NaN", self.train_cfg.window, self.train_cfg.chunk_size,
             )
-            nan = float("nan")
-            return (
-                state,
-                EpochMetrics(nan, nan, nan, np.zeros(n_classes)),
-                np.zeros((n_classes, 2, 2), np.int64),
-            )
+            return (state,) + self.task.epoch_metrics(None, 0)
         # the one place the host waits for the device
         with span(phase + "_pass_drain"):
-            loss_sum, acc_sum, ham_sum, fbeta_sum, confusion_total = (
-                jax.device_get(totals)
-            )
-        epoch = EpochMetrics(
-            loss=float(loss_sum) / step_no,
-            accuracy=float(acc_sum) / step_no,
-            hamming=float(ham_sum) / step_no,
-            fbeta=np.asarray(fbeta_sum) / step_no,
-        )
-        return state, epoch, np.asarray(confusion_total, np.int64)
+            drained = jax.device_get(totals)
+            self.task.publish(drained, phase)
+        return (state,) + self.task.epoch_metrics(drained, step_no)
 
     def _warn_if_norm_drifted(self, dataset: ChunkDataset) -> None:
         """Resume runs recompute normalization from the *current* source;
@@ -591,9 +502,9 @@ class Trainer:
         (last-chunk min/max) shift under the restored params — loud, not
         silent."""
         saved = getattr(self, "_restored_norm", None)
-        if saved is None:
+        now = None if saved is None else self.task.norm_params(dataset)
+        if now is None:
             return
-        now = dataset.final_norm_params
         if not (
             np.allclose(saved.x_min, now.x_min)
             and np.allclose(saved.x_max, now.x_max)
@@ -629,14 +540,8 @@ class Trainer:
         rng = jax.random.PRNGKey(tc.seed) if rng is None else rng
         init_rng, step_rng = jax.random.split(rng)
         if dataset is None:
-            dataset = ChunkDataset(
-                source,
-                tc.chunk_size,
-                tc.window,
-                bid_levels=bid_levels,
-                ask_levels=ask_levels,
-                cache_chunks=tc.cache_chunks,
-            )
+            dataset = self.task.dataset(
+                source, bid_levels=bid_levels, ask_levels=ask_levels)
         train_chunks, val_chunks, _ = dataset.split(tc.val_size, tc.test_size)
         state = (
             initial_state if initial_state is not None
@@ -667,9 +572,7 @@ class Trainer:
                 # continuous fine-tune rounds run val_size=0 (quality is
                 # judged by the shadow gate, not a holdout) — NaN metrics
                 # without the empty-pass warning
-                nan = float("nan")
-                val_metrics = EpochMetrics(
-                    nan, nan, nan, np.zeros(self.model_cfg.output_size))
+                val_metrics, _ = self.task.epoch_metrics(None, 0)
             with span("fit_epoch_end"):
                 history["val"].append(val_metrics)
                 epoch_hist.observe(_time.perf_counter() - t_epoch)

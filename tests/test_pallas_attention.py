@@ -229,3 +229,93 @@ def test_flash_on_tpu_device():
     for a, b in zip(g_pal, g_ref):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+# -- grouped-query heads and the causal window (PR 28) -------------------------
+
+
+def _masked_reference(q, k, v, window):
+    """Explicit-mask softmax attention with repeated K/V heads."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    t, d = q.shape[-2], q.shape[-1]
+    s = jnp.einsum("bnqd,bnkd->bnqk", q, k) / np.sqrt(d)
+    rel = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    keep = rel >= 0
+    if window is not None:
+        keep = keep & (rel < window)
+    s = jnp.where(keep, s, -jnp.inf)
+    return jnp.einsum("bnqk,bnkd->bnqd", jax.nn.softmax(s, -1), v)
+
+
+def _gqa_qkv(seq, heads=4, kv_heads=2, d_head=16, key=0):
+    ks = jax.random.split(jax.random.PRNGKey(key), 3)
+    return (jax.random.normal(ks[0], (1, heads, seq, d_head)),
+            jax.random.normal(ks[1], (1, kv_heads, seq, d_head)),
+            jax.random.normal(ks[2], (1, kv_heads, seq, d_head)))
+
+
+@pytest.mark.parametrize("seq,window", [
+    (1024, None),   # 512-wide blocks, causal, grouped heads
+    (1024, 300),    # the band ends inside a block
+    (1024, 512),    # the band ends on a block edge
+    (384, 100),     # 128-wide blocks, three of them
+])
+def test_gqa_window_kernel_matches_masked_attention_fwd_and_bwd(seq, window):
+    from fmda_tpu.ops.pallas_attention import block_for
+
+    assert block_for(1024) == 512 and block_for(384) == 128
+    q, k, v = _gqa_qkv(seq)
+    want = jax.value_and_grad(
+        lambda *a: jnp.sum(_masked_reference(*a, window) ** 2), (0, 1, 2))
+    got = jax.value_and_grad(
+        lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, window=window, interpret=True) ** 2), (0, 1, 2))
+    with jax.default_matmul_precision("highest"):
+        w, g = want(q, k, v), got(q, k, v)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+def test_window_at_least_the_sequence_is_plain_causal():
+    q, k, v = _gqa_qkv(256)
+    a = flash_attention(q, k, v, causal=True, interpret=True)
+    b = flash_attention(q, k, v, window=256, interpret=True)
+    np.testing.assert_array_equal(a, b)
+
+
+def test_query_heads_must_be_a_multiple_of_kv_heads():
+    q, k, v = _gqa_qkv(128, heads=4, kv_heads=3)
+    with pytest.raises(ValueError, match="query heads"):
+        flash_attention(q, k, v, interpret=True)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+def test_mha_fallback_is_blockwise_and_matches_with_mask_window_and_gqa(
+        window):
+    """Past FALLBACK_QUERY_BLOCK query rows the non-kernel path scores a
+    block of rows at a time; same numbers, with a causal window, grouped
+    heads and an arbitrary mask array."""
+    from fmda_tpu.ops.attention import FALLBACK_QUERY_BLOCK, mha
+
+    seq = 2 * FALLBACK_QUERY_BLOCK
+    q, k, v = _gqa_qkv(seq, d_head=8)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(lambda *a: jnp.sum(mha(
+            *a, causal=True, window=window) ** 2), (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(lambda *a: jnp.sum(_masked_reference(
+            *a, window) ** 2), (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+    # an arbitrary mask array rides through the blocks too
+    keep = jax.random.bernoulli(jax.random.PRNGKey(9), 0.7, (seq, seq))
+    keep = keep | jnp.eye(seq, dtype=bool)
+    lowered = jax.jit(lambda *a: mha(*a, mask=keep)).lower(q, k, v).as_text()
+    assert f"{seq}x{seq}xf32" not in lowered  # no (T, T) scores anywhere
+    with jax.default_matmul_precision("highest"):
+        a = mha(q, k, v, mask=keep)
+        kk, vv = jnp.repeat(k, 2, 1), jnp.repeat(v, 2, 1)
+        s = jnp.where(keep, jnp.einsum("bnqd,bnkd->bnqk", q, kk)
+                      / np.sqrt(8), -jnp.inf)
+        b = jnp.einsum("bnqk,bnkd->bnqd", jax.nn.softmax(s, -1), vv)
+    np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)

@@ -1,0 +1,71 @@
+"""The decoder family's kernels compiled for a TPU v5e at the published
+widths, without a chip: the TPU's compiler is installed here and
+compiles for a described topology (what interpret mode cannot show: a
+slice off the tiling, too much VMEM).  Nothing runs; no time, no result.
+One file, so one worker loads the TPU's library; the topology is
+described inside a fixture, never at import."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_kernels_compile_at_28_on_4_heads_of_128_by_8192(
+        one_chip, window):
+    from fmda_tpu.ops.pallas_attention import flash_attention
+
+    def step(q, k, v):
+        return jax.value_and_grad(lambda *a: flash_attention(
+            *a, causal=True, window=window).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(step).lower(
+        _shape(one_chip, (1, 28, 8192, 128), BF16),
+        _shape(one_chip, (1, 4, 8192, 128), BF16),
+        _shape(one_chip, (1, 4, 8192, 128), BF16)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert name in text
+
+
+@pytest.mark.parametrize("k,n", [(2560, 768), (768, 2560)])
+def test_grouped_product_kernels_compile_at_16_experts_of_2560_by_768(
+        one_chip, k, n):
+    from fmda_tpu.ops.moe import layout_rows
+    from fmda_tpu.ops.pallas_moe import grouped_matmul
+
+    tile = 256
+    rows = layout_rows(8192 * 6, 16, tile)
+
+    def step(x, w, tile_expert, n_used):
+        return jax.value_and_grad(lambda x, w: grouped_matmul(
+            x, w, tile_expert, n_used, tile, "pallas").astype(
+                jnp.float32).sum(), (0, 1))(x, w)
+
+    compiled = jax.jit(step).lower(
+        _shape(one_chip, (rows, k), BF16),
+        _shape(one_chip, (16, k, n), jnp.float32),
+        _shape(one_chip, (rows // tile,), jnp.int32),
+        _shape(one_chip, (1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text and "moe_tgmm" in text
