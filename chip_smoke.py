@@ -778,7 +778,9 @@ def four_chips() -> None:
         wh, bid_levels=cfg.features.bid_levels,
         ask_levels=cfg.features.ask_levels)
     check(int(state.step) == 10, f"step {int(state.step)}")
-    batch = next(iter(trainer._placed_cache.values()))[1][0]
+    # the cache holds what one call takes: a group of stacked batches,
+    # split over dp along each batch's own leading axis
+    batch = next(iter(trainer._placed_cache.values()))[1][0].batches
     check(batch.x.sharding.device_set == devices
           and len({s.index for s in batch.x.addressable_shards}) == 4,
           f"batch is not split over four devices: {batch.x.sharding}")

@@ -47,6 +47,17 @@ class Batch(NamedTuple):
     mask: np.ndarray  # (B,) float32 — 0 for padded examples  [(B, T)]
 
 
+class BatchGroup(NamedTuple):
+    """Consecutive batches of a pass, stacked for one call into the
+    compiled step (:func:`group_batches`)."""
+
+    #: every leaf of a batch with two leading axes ``(k, 1)``: the
+    #: group's size, and a unit axis that keeps the step axis out of the
+    #: device's tiles (:func:`group_batches`)
+    batches: Batch
+    n_live: int  # the first n_live are the pass's; the rest is padding
+
+
 class ChunkDataset:
     """Chunk ranges + per-chunk normalization stats over a source."""
 
@@ -237,6 +248,43 @@ class TokenBatches:
                 pad = np.zeros((bs - len(xb), xb.shape[1]), np.int32)
                 xb, yb = np.concatenate([xb, pad]), np.concatenate([yb, pad])
             yield Batch(xb, yb, mask)
+
+
+def group_batches(batches: Iterable[Batch], k: int
+                  ) -> Iterator[BatchGroup]:
+    """Stack each ``k`` consecutive host batches into a
+    :class:`BatchGroup`.
+
+    The order is the iterable's.  Where the batches do not divide by
+    ``k`` the last group is padded with zeros to the same shape and says
+    how many of its batches are live, so one compiled program serves a
+    pass of any length.
+
+    A leaf of shape ``s`` is stacked as ``(k, 1) + s``, not ``(k,) + s``.
+    A TPU lays an array out in tiles over the two axes that pad least,
+    and beside a batch axis of 256 a step axis of 16 is one of them: a
+    step's batch is then one row of every tile, and slicing it out cost
+    36.8 us of a 126 us train step (my chip run, PR 29; PERF.md section
+    6).  With the unit axis between them the tile takes that axis
+    instead, the step axis stays outermost on the device, and a step's
+    batch is one contiguous piece in the layout a batch placed alone
+    has."""
+    def stacked(pending: List[Batch]) -> BatchGroup:
+        def stack(*leaves):
+            out = np.zeros((k, 1) + leaves[0].shape, leaves[0].dtype)
+            for i, leaf in enumerate(leaves):
+                out[i, 0] = leaf
+            return out
+        return BatchGroup(Batch(*map(stack, *pending)), len(pending))
+
+    pending: List[Batch] = []
+    for batch in batches:
+        pending.append(batch)
+        if len(pending) == k:
+            yield stacked(pending)
+            pending = []
+    if pending:
+        yield stacked(pending)
 
 
 def prefetch_to_device(

@@ -129,12 +129,12 @@ def shard_train_inputs_multihost(
             place_replicated(mesh, opt_state))
 
 
-def place_local_batch(mesh: Mesh, batch, dp_axis: str = "dp"):
+def place_local_batch(mesh: Mesh, batch, spec: PartitionSpec):
     """Place a process-local training Batch onto the global dp sharding
-    (used by the Trainer when the job spans processes)."""
+    ``spec`` (``P(dp)``; ``P(None, None, dp)`` for a group of stacked batches;
+    used by the Trainer when the job spans processes)."""
     from fmda_tpu.data.pipeline import Batch
 
-    spec = PartitionSpec(dp_axis)
     return Batch(
         make_global_batch(mesh, batch.x, spec),
         make_global_batch(mesh, batch.y, spec),

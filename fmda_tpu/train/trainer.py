@@ -11,6 +11,8 @@ next-token decoder trains through the same loop:
 
 - one compiled ``train_step``/``eval_step`` reused for every batch (fixed
   shapes via padded+masked batches — no per-batch Python/sklearn work);
+- where a step is small beside what a call into it costs the host, one
+  call carries a *group* of consecutive steps (:func:`group_size`);
 - gradients, clipping, Adam, and all four metrics fused into the step;
 - optional data parallelism: pass a :class:`jax.sharding.Mesh` and the step
   shards the batch across the ``dp`` axis (XLA inserts the ICI all-reduce
@@ -19,6 +21,7 @@ next-token decoder trains through the same loop:
 
 from __future__ import annotations
 
+import itertools
 import logging
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -29,7 +32,9 @@ import numpy as np
 import optax
 
 from fmda_tpu.config import ModelConfig, TrainConfig
-from fmda_tpu.data.pipeline import Batch, ChunkDataset, prefetch_batches
+from fmda_tpu.data.pipeline import (
+    Batch, BatchGroup, ChunkDataset, background_compose, group_batches,
+    prefetch_batches)
 from fmda_tpu.data.source import FeatureSource
 from fmda_tpu.models import build_model
 from fmda_tpu.obs.device import tracked_jit
@@ -38,12 +43,56 @@ from fmda_tpu.train.tasks import EpochMetrics, StepTotals, task_for
 
 log = logging.getLogger("fmda_tpu.train")
 
+#: The most steps one call into a compiled step carries.
+MAX_GROUP_STEPS = 16
+#: The most bytes a group of batches may hold on the device: a pass keeps
+#: ``train.prefetch_depth`` of them ahead of the loop and pads its last
+#: one to the full group.
+GROUP_BYTES_CAP = 64 << 20
+#: A step that must move this many bytes (its state and one batch, each
+#: once) runs alone.  A call into a compiled step costs the host 0.15 ms
+#: (a bare host) to 0.55 ms (the chip machine's, PERF.md section 6), and
+#: reading 256 MiB of state and writing it back is 537 MB: 0.66 ms at a
+#: v5e's 819 GB/s.  From here on the device takes longer over a step
+#: than the host over the call, and a group would buy nothing.
+SOLO_STEP_BYTES = 256 << 20
+
+
+def group_size(state_bytes: int, batch_bytes: int) -> int:
+    """How many consecutive steps one call into the compiled step
+    carries, from the bytes a step must move at the least: 1 where the
+    step cannot be host-bound (``SOLO_STEP_BYTES``), else as many
+    batches as ``GROUP_BYTES_CAP`` holds, ``MAX_GROUP_STEPS`` at most.
+
+    The two regimes met so far lie orders of magnitude apart (a width-32
+    recurrent classifier: 0.6 MB of state, 3.4 MB a batch, 16; the
+    decoder: 7.88 GB of state, 1), so the constants decide nothing yet
+    and are no setting: a user who could tune them could only make a
+    pass slower or its tail group larger."""
+    if state_bytes + batch_bytes >= SOLO_STEP_BYTES:
+        return 1
+    return max(1, min(MAX_GROUP_STEPS,
+                      GROUP_BYTES_CAP // max(batch_bytes, 1)))
+
+
+def _tree_bytes(tree) -> int:
+    return sum(a.nbytes for a in jax.tree.leaves(tree))
+
 
 @flax.struct.dataclass
 class TrainState:
     params: Any
     opt_state: Any
     step: jax.Array
+
+
+def _batch_of(group: Batch, i) -> Batch:
+    """Batch ``i`` of a group (leaves ``(k, 1, ...)``:
+    :func:`~fmda_tpu.data.pipeline.group_batches`), inside a compiled
+    step."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)[0],
+        group)
 
 
 def _add_step(totals, values):
@@ -78,11 +127,15 @@ class Trainer:
                              pos_weight=self.pos_weight)
         self.mesh = mesh
         self.dp_axis = dp_axis
-        self._train_step = self._build_train_step()
-        self._eval_step = self._build_eval_step()
+        # each step kind as two programs of one body: the step alone
+        # (single_step, and the loop's where group_size says 1) and the
+        # step run over a group of batches in one call
+        self._train_step, self._train_group = self._build_train_step()
+        self._eval_step, self._eval_group = self._build_eval_step()
         # placed-batch cache: (id(dataset), chunk tuple) -> (dataset,
-        # [Batch]) — see _run_chunks; the dataset ref pins id() validity
-        self._placed_cache: Dict[Any, Tuple[Any, List[Batch]]] = {}
+        # [BatchGroup], or [Batch] where steps run alone) — see
+        # _run_chunks; the dataset ref pins id() validity
+        self._placed_cache: Dict[Any, Tuple[Any, List[Any]]] = {}
 
     # -- state ---------------------------------------------------------------
 
@@ -150,8 +203,18 @@ class Trainer:
 
         return batch_sharding(self.mesh, self.dp_axis)
 
-    def _step_shardings(self):
-        """(replicated, batch-dp) NamedShardings under a mesh, else None.
+    def _group_sharding(self):
+        """A group's batches keep their dp split, now along axis 2
+        (behind the step axis and the unit axis)."""
+        if self.mesh is None:
+            return None
+        return jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec(None, None, self.dp_axis))
+
+    def _jit_step(self, fn, name: str, *, grouped: bool):
+        """A step function as a tracked jit that donates what it returns
+        anew (the train step's state and totals, the eval step's
+        totals).
 
         With a mesh the compiled steps carry explicit in/out shardings:
         params/optimizer state replicated over every device, the batch
@@ -159,14 +222,23 @@ class Trainer:
         A 1-device mesh lowers to the identical program as the meshless
         jit — bit-identity is test-pinned (tests/test_train_parallel.py).
         """
-        if self.mesh is None:
-            return None
-        from fmda_tpu.parallel.mesh import batch_sharding, replicated_sharding
+        train = name == "train_step"
+        jit_kwargs: Dict[str, Any] = {
+            "donate_argnums": (0, 1) if train else (1,)}
+        if self.mesh is not None:
+            from fmda_tpu.parallel.mesh import replicated_sharding
 
-        return (
-            replicated_sharding(self.mesh),
-            batch_sharding(self.mesh, self.dp_axis),
-        )
+            replicated = replicated_sharding(self.mesh)
+            batched = (self._group_sharding() if grouped
+                       else self._batch_sharding())
+            # after the batch: a group's live count, a train step's key
+            rest = (replicated,) * (int(grouped) + int(train))
+            jit_kwargs["in_shardings"] = (
+                replicated, replicated, Batch(batched, batched, batched),
+                *rest)
+            jit_kwargs["out_shardings"] = (
+                (replicated, replicated) if train else replicated)
+        return tracked_jit(fn, name=name, **jit_kwargs)
 
     def _build_train_step(self):
         model, tc, task = self.model, self.train_cfg, self.task
@@ -261,15 +333,21 @@ class Trainer:
             )
             return new_state, totals
 
-        jit_kwargs: Dict[str, Any] = {"donate_argnums": (0, 1)}
-        shardings = self._step_shardings()
-        if shardings is not None:
-            replicated, batched = shardings
-            jit_kwargs["in_shardings"] = (
-                replicated, replicated, Batch(batched, batched, batched),
-                replicated)
-            jit_kwargs["out_shardings"] = (replicated, replicated)
-        return tracked_jit(step_fn, name="train_step", **jit_kwargs)
+        def group_fn(state: TrainState, totals, group: Batch, n_live,
+                     rng: jax.Array):
+            # the first n_live batches of the group, one step each, in
+            # order: state and totals are the carry, so the dropout key
+            # folds on the step counter as it does over single calls and
+            # the padding behind n_live is never read.  Nothing
+            # differentiates through the loop; the gradient is inside.
+            def body(i, carry):
+                return step_fn(*carry, _batch_of(group, i), rng)
+
+            return jax.lax.fori_loop(0, n_live, body, (state, totals))
+
+        return (
+            self._jit_step(step_fn, "train_step", grouped=False),
+            self._jit_step(group_fn, "train_step", grouped=True))
 
     def _build_eval_step(self):
         model, task = self.model, self.task
@@ -283,14 +361,15 @@ class Trainer:
                 return _add_step(
                     totals, task.step_values(loss, aux, batch))
 
-        jit_kwargs: Dict[str, Any] = {"donate_argnums": (1,)}
-        shardings = self._step_shardings()
-        if shardings is not None:
-            replicated, batched = shardings
-            jit_kwargs["in_shardings"] = (
-                replicated, replicated, Batch(batched, batched, batched))
-            jit_kwargs["out_shardings"] = replicated
-        return tracked_jit(eval_fn, name="eval_step", **jit_kwargs)
+        def group_fn(params, totals, group: Batch, n_live):
+            def body(i, totals):
+                return eval_fn(params, totals, _batch_of(group, i))
+
+            return jax.lax.fori_loop(0, n_live, body, totals)
+
+        return (
+            self._jit_step(eval_fn, "eval_step", grouped=False),
+            self._jit_step(group_fn, "eval_step", grouped=True))
 
     def zero_totals(self):
         """A pass's accumulators at zero (the task's: :class:`StepTotals`
@@ -316,30 +395,41 @@ class Trainer:
 
     # -- compile accounting ---------------------------------------------------
 
+    def _steps(self):
+        return (self._train_step, self._train_group,
+                self._eval_step, self._eval_group)
+
     def mark_warm(self) -> None:
         """Declare step warm-up over: any compile after this is counted
         as *unexpected* on the compile ledger (the contract the
         ``train_throughput`` bench phase and the continuous loop pin)."""
-        self._train_step.mark_warm()
-        self._eval_step.mark_warm()
+        for step in self._steps():
+            step.mark_warm()
 
     @property
     def unexpected_recompiles(self) -> int:
-        return (self._train_step.unexpected_recompiles
-                + self._eval_step.unexpected_recompiles)
+        return sum(step.unexpected_recompiles for step in self._steps())
 
     @property
     def compile_counts(self) -> Dict[str, Optional[int]]:
-        """Distinct compiled programs per step — the pin the
+        """Distinct compiled programs per step kind — the pin the
         ``train_throughput`` bench asserts (batches are always padded to
-        ``batch_size``, so each step compiles exactly once).  None when
-        jax's (private) cache probe is unavailable."""
-        return {"train_step": self._train_step.cache_size(),
-                "eval_step": self._eval_step.cache_size()}
+        ``batch_size`` and a pass's last group to the full group, so a
+        ``fit`` compiles each kind exactly once: the grouped program or
+        the single one, by :func:`group_size`; ``single_step`` beside a
+        grouped loop is a second).  None when jax's (private) cache
+        probe is unavailable."""
+        def both(single, group):
+            counts = (single.cache_size(), group.cache_size())
+            return None if None in counts else sum(counts)
+
+        return {"train_step": both(self._train_step, self._train_group),
+                "eval_step": both(self._eval_step, self._eval_group)}
 
     # -- batch plumbing ------------------------------------------------------
 
-    def _place_batches(self, batches: Iterable[Batch]) -> Iterable[Batch]:
+    def _place_batches(self, batches: Iterable[Batch],
+                       state: Optional[TrainState] = None) -> Iterable:
         """The overlapped input pipeline: host composition runs in a
         background thread, composed batches are transferred immediately
         (dp batch sharding under a mesh; when the job spans processes
@@ -347,26 +437,52 @@ class Trainer:
         and are assembled in place), and up to ``train.prefetch_depth``
         placed batches ride ahead of the step loop.  What the step loop
         waits for it is measured where the loop pulls
-        (``_run_batches``: ``train_input_stall_seconds``)."""
-        sharding = self._batch_sharding()
+        (``_run_batches``: ``train_input_stall_seconds``).
+
+        With the ``state`` a pass steps, the batches are grouped as the
+        step loop will run them: :func:`group_size` of the state's bytes
+        and the first batch's says how many consecutive host batches are
+        stacked and placed as one :class:`BatchGroup`, three transfers a
+        group; where it says 1, and without a state (what
+        ``single_step`` takes), each batch is placed alone.  Stacking
+        runs on the composer thread and the composition behind it on a
+        thread of its own: a stacked group is 54 MB of fresh host
+        pages, as dear as the window gather that fills it (PERF.md
+        section 6, PR 29)."""
+        depth = self.train_cfg.prefetch_depth
+        k = 1
+        if state is not None:
+            batches = iter(batches)
+            first = next(batches, None)
+            if first is None:
+                return iter(())
+            k = group_size(_tree_bytes(state), _tree_bytes(first))
+            batches = itertools.chain((first,), batches)
+        if k == 1:
+            return prefetch_batches(
+                batches, self._placer(self._batch_sharding()), depth=depth)
+        if depth > 0:
+            batches = background_compose(batches, depth=depth * k)
+        place = self._placer(self._group_sharding())
+        return prefetch_batches(
+            group_batches(batches, k),
+            lambda group: BatchGroup(place(group.batches), group.n_live),
+            depth=depth)
+
+    def _placer(self, sharding):
+        """``Batch -> placed Batch`` for a batch (or a group's stacked
+        batches) split over dp as ``sharding`` says."""
         if sharding is None:
-            place = jax.device_put
-        elif jax.process_count() > 1:
+            return jax.device_put  # one call for the three leaves
+        if jax.process_count() > 1:
             from fmda_tpu.parallel.distributed import place_local_batch
 
-            def place(b: Batch) -> Batch:
-                return place_local_batch(self.mesh, b, self.dp_axis)
-        else:
-            def place(b: Batch) -> Batch:
-                return Batch(
-                    jax.device_put(b.x, sharding),
-                    jax.device_put(b.y, sharding),
-                    jax.device_put(b.mask, sharding),
-                )
-        return prefetch_batches(
-            batches, place, depth=self.train_cfg.prefetch_depth)
+            return lambda b: place_local_batch(self.mesh, b, sharding.spec)
+        return lambda b: Batch(*(jax.device_put(a, sharding) for a in b))
 
     def _chunk_batches(self, dataset, chunk_idx: int) -> Iterable[Batch]:
+        """One chunk's batches, each placed alone: what ``single_step``
+        and the single programs take."""
         return self._place_batches(self.task.batches(dataset, chunk_idx))
 
     # -- epochs --------------------------------------------------------------
@@ -385,11 +501,12 @@ class Trainer:
         # thread while the device computes on chunk k's batches.
         #
         # With ``cache_chunks`` set, the PLACED batches of the first
-        # pass are kept and later epochs replay the device-side buffers
-        # directly — no re-gather, no re-pad, no re-transfer (batches
-        # are never donated, so reuse is safe; same arrays -> bit-
-        # identical epochs).  RAM bound: cache_chunks chunks of windows
-        # on the host (ChunkDataset) plus their placed batches.
+        # pass (groups of them, as they were placed) are kept and later
+        # epochs replay the device-side buffers directly — no re-gather,
+        # no re-pad, no re-transfer (batches are never donated, so reuse
+        # is safe; same arrays -> bit-identical epochs).  RAM bound:
+        # cache_chunks chunks of windows on the host (ChunkDataset) plus
+        # their placed batches.
         cache_on = (self.train_cfg.cache_chunks > 0
                     and len(chunk_indices) <= self.train_cfg.cache_chunks)
         key = (id(dataset), tuple(chunk_indices))
@@ -410,12 +527,12 @@ class Trainer:
             for idx in chunk_indices:
                 yield from self.task.batches(dataset, idx)
 
-        placed = self._place_batches(host_batches())
+        placed = self._place_batches(host_batches(), state)
         if not cache_on:
             return self._run_batches(state, (placed,), rng, train)
-        sink: List[Batch] = []
+        sink: List[Any] = []
 
-        def capturing() -> Iterable[Batch]:
+        def capturing() -> Iterable:
             for b in placed:
                 sink.append(b)
                 yield b
@@ -441,13 +558,14 @@ class Trainer:
         phase = "train" if train else "eval"
         reg = default_registry()
         step_counter = reg.counter("train_steps_total", phase=phase)
+        call_counter = reg.counter("train_step_calls_total", phase=phase)
         stall = reg.histogram("train_input_stall_seconds")
         clock = _time.perf_counter
-        # Host spans that tile one step, on the profiler's clock (a flag
-        # test each when nothing traces; docs/observability.md "Spans
-        # and scopes"): <phase>_next_batch, <phase>, <phase>_fold, and
-        # <phase>_pass_drain once a pass.  What is left uncovered is the
-        # loop's own Python.
+        # Host spans that tile one call into the compiled step, on the
+        # profiler's clock (a flag test each when nothing traces;
+        # docs/observability.md "Spans and scopes"): <phase>_next_batch,
+        # <phase>, <phase>_fold, and <phase>_pass_drain once a pass.
+        # What is left uncovered is the loop's own Python.
         next_name, fold_name = phase + "_next_batch", phase + "_fold"
         # Each step's results are added to running on-device accumulators
         # inside the compiled step itself — the host never blocks
@@ -461,24 +579,33 @@ class Trainer:
             while True:
                 # the input pipeline as the step loop meets it: the
                 # cached list, or the prefetch queue (whose compose and
-                # place spans nest in here)
+                # place spans nest in here); a pull is a group of
+                # batches where _place_batches made groups
                 t0 = clock()
                 with span(next_name):
-                    batch = next(it, None)
+                    item = next(it, None)
                 stall.observe(clock() - t0)
-                if batch is None:
+                if item is None:
                     break
                 # the call into the jitted step: wrapper, dispatch,
                 # whatever donation waits for, and letting go of the
-                # donated state
+                # donated state; the annotation carries the index of
+                # the call's first step
+                grouped = isinstance(item, BatchGroup)
+                batch, live = item if grouped else (item, 1)
+                count = (live,) if grouped else ()  # a group's argument
                 with step_annotation(phase, step_no):
                     if train:
-                        state, out = self._train_step(
-                            state, totals, batch, rng)
+                        step = (self._train_group if grouped
+                                else self._train_step)
+                        state, out = step(state, totals, batch, *count, rng)
                     else:
-                        out = self._eval_step(state.params, totals, batch)
-                step_counter.inc()
-                step_no += 1
+                        step = (self._eval_group if grouped
+                                else self._eval_step)
+                        out = step(state.params, totals, batch, *count)
+                step_counter.inc(live)
+                call_counter.inc()
+                step_no += live
                 # what is left of the fold on the host: taking the new
                 # totals for the old (the sum is in the compiled step)
                 with span(fold_name):
@@ -622,29 +749,32 @@ class Trainer:
         if mixed_batch_per_ticker:
             k = mixed_batch_per_ticker
 
-            def iters(chunks):
+            def host_batches(chunks):
                 # mixed composition is the expensive host stage (~12 ms
                 # per 800-row batch): _place_batches runs it in the
                 # composer thread and double-buffers the transfer
-                return (
-                    self._place_batches(mtd.mixed_batches(rc, k))
-                    for rc in mtd.rounds(chunks)
-                )
+                for rc in mtd.rounds(chunks):
+                    yield from mtd.mixed_batches(rc, k)
         else:
-            def iters(chunks):
-                return (
-                    self._place_batches(mtd.batches(t, c, tc.batch_size))
-                    for t, c in chunks
-                )
+            def host_batches(chunks):
+                for t, c in chunks:
+                    yield from mtd.batches(t, c, tc.batch_size)
+
+        def placed(chunks, state):
+            # one pipeline a pass, as _run_chunks has: a group then runs
+            # on across a chunk's end, in the pass's own order, and only
+            # the pass's last group is padded
+            return (self._place_batches(host_batches(chunks), state),)
+
         state = self.init_state(init_rng)
         history: Dict[str, List[EpochMetrics]] = {"train": [], "val": []}
         for epoch in range(epochs if epochs is not None else tc.epochs):
             state, train_metrics, _ = self._run_batches(
-                state, iters(train_chunks), step_rng, train=True,
+                state, placed(train_chunks, state), step_rng, train=True,
             )
             history["train"].append(train_metrics)
             _, val_metrics, _ = self._run_batches(
-                state, iters(val_chunks), None, train=False,
+                state, placed(val_chunks, state), None, train=False,
             )
             history["val"].append(val_metrics)
             log.info(

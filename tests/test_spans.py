@@ -3,10 +3,11 @@
 
 A tiny ``Trainer.fit`` runs under the JAX profiler on the CPU backend:
 its step thread must carry ``<phase>_next_batch`` → ``<phase>`` →
-``<phase>_fold`` once a step, in that order and never overlapping, one
+``<phase>_fold`` once a call into the compiled step (a group of up to
+16 steps at these sizes), in that order and never overlapping, one
 ``<phase>_pass_drain`` a pass, and nothing of the program's inside the
 ``train``/``eval`` annotation.  The scopes are read from the lowered
-text of each compiled step."""
+text of each compiled step, the single program's and the group's."""
 
 import glob
 import re
@@ -27,8 +28,11 @@ PROGRAM_SPANS = (
     "eval_next_batch", "eval", "eval_fold", "eval_pass_drain",
     "fit_epoch_end", "input_compose", "input_place",
 )
-#: steps a pass, by the sizes below (one batch a chunk)
-STEPS = {"train": 3, "eval": 2}
+#: steps a pass, by the sizes below (batches of 4: 8 + 10 + 10 over the
+#: train chunks, 10 + 10 over the validation chunks), and the calls that
+#: carry them (groups of 16: 16 + 12, 16 + 4)
+STEPS = {"train": 28, "eval": 20}
+CALLS = {"train": 2, "eval": 2}
 
 
 def _source(n=200, f=6, seed=0):
@@ -40,11 +44,11 @@ def _source(n=200, f=6, seed=0):
 
 def _trainer(cell="gru", **train):
     mc = ModelConfig(cell=cell, hidden_size=4, n_features=6, output_size=4)
-    # 6 chunks: 3 train, 2 validation, 1 test; a chunk's windows fit one
-    # batch of 64 -> three train steps and two eval steps a pass (four
-    # train steps with val_size=0.0)
+    # 6 chunks: 3 train, 2 validation, 1 test; a chunk's 32 to 40
+    # windows are eight to ten batches of 4 -> 28 train steps and 20
+    # eval steps a pass
     tc = TrainConfig(**{**dict(
-        batch_size=64, window=5, chunk_size=40, cache_chunks=16,
+        batch_size=4, window=5, chunk_size=40, cache_chunks=16,
         val_size=0.2, test_size=0.2), **train})
     return Trainer(mc, tc)
 
@@ -112,11 +116,11 @@ def _belongs(span, spans, phase):
 
 @pytest.mark.parametrize("cached", [False, True], ids=["uncached", "cached"])
 @pytest.mark.parametrize("phase", ["train", "eval"])
-def test_spans_tile_one_step_on_one_thread(traced_epochs, phase, cached):
+def test_spans_tile_one_call_on_one_thread(traced_epochs, phase, cached):
     spans = _one_pass(traced_epochs, phase, cached)
-    steps = STEPS[phase]
+    calls = CALLS[phase]
     top = [s for s in spans if not s[2].startswith("input_")]
-    want = [phase + "_next_batch", phase, phase + "_fold"] * steps + [
+    want = [phase + "_next_batch", phase, phase + "_fold"] * calls + [
         phase + "_next_batch", phase + "_pass_drain"]
     assert [n for _, _, n in top] == want
     # adjacent, never overlapping
@@ -134,7 +138,46 @@ def test_spans_tile_one_step_on_one_thread(traced_epochs, phase, cached):
         assert nested == []
     else:
         assert {n for _, _, n in nested} == {"input_compose", "input_place"}
-        assert sum(n == "input_place" for _, _, n in nested) == steps
+        # a group is placed whole: one placement a call
+        assert sum(n == "input_place" for _, _, n in nested) == calls
+
+
+@pytest.mark.parametrize("phase", ["train", "eval"])
+def test_a_call_counts_its_live_steps_and_itself_once(phase):
+    """``train_steps_total`` advances by the live steps of each call,
+    ``train_step_calls_total`` by one: a pass of n steps counts n and
+    ceil(n / 16), cached or not."""
+    trainer = _trainer()
+    source = _source()
+
+    def counts():
+        return (_counter("train_steps_total", phase=phase),
+                _counter("train_step_calls_total", phase=phase))
+
+    before = counts()
+    state, _, dataset = trainer.fit(source, epochs=1)
+    first = counts()
+    trainer.fit(source, epochs=1, initial_state=state, dataset=dataset)
+    second = counts()
+    want = (STEPS[phase], CALLS[phase])
+    assert CALLS[phase] == -(-STEPS[phase] // 16)
+    assert (first[0] - before[0], first[1] - before[1]) == want
+    assert (second[0] - first[0], second[1] - first[1]) == want
+
+
+def test_step_annotations_carry_the_index_of_their_first_step(monkeypatch):
+    from fmda_tpu.utils import tracing
+
+    seen = []
+    real = tracing.step_annotation
+
+    def spy(name, step):
+        seen.append((name, step))
+        return real(name, step)
+
+    monkeypatch.setattr(tracing, "step_annotation", spy)
+    _trainer().fit(_source(), epochs=1)
+    assert seen == [("train", 0), ("train", 16), ("eval", 0), ("eval", 16)]
 
 
 def test_placed_cache_counts_one_miss_then_hits():
@@ -156,8 +199,8 @@ def test_stall_is_observed_at_the_loops_pull_on_cached_passes_too():
     state, _, dataset = trainer.fit(source, epochs=1)
     before = stall.snapshot()["n"]
     trainer.fit(source, epochs=1, initial_state=state, dataset=dataset)
-    # one observation a pull: every step's, and the pull that ends a pass
-    assert stall.snapshot()["n"] - before == sum(STEPS.values()) + 2
+    # one observation a pull: every call's, and the pull that ends a pass
+    assert stall.snapshot()["n"] - before == sum(CALLS.values()) + 2
     names = {h["name"] for h in default_registry().snapshot()["histograms"]}
     assert "train_input_stall_seconds" in names
     assert "train_step_seconds" not in names
@@ -197,39 +240,52 @@ def _scope_components(lowered):
     return module, parts
 
 
-def _lower(trainer, step):
+def _lower(trainer, step, grouped=False):
+    """The single program of a step kind, or the one a group runs
+    through (what ``fit`` dispatches at these sizes)."""
     state = trainer.init_state(jax.random.PRNGKey(0))
     totals = trainer.zero_totals()
-    batch = Batch(jnp.zeros((64, 5, 6)), jnp.zeros((64, 4)),
-                  jnp.ones((64,)))
+    lead = (16, 1) if grouped else ()
+    batch = Batch(jnp.zeros(lead + (64, 5, 6)), jnp.zeros(lead + (64, 4)),
+                  jnp.ones(lead + (64,)))
+    live = (3,) if grouped else ()
     if step == "train_step":
-        return trainer._train_step._jit.lower(
-            state, totals, batch, jax.random.PRNGKey(1))
-    return trainer._eval_step._jit.lower(state.params, totals, batch)
+        fn = trainer._train_group if grouped else trainer._train_step
+        return fn._jit.lower(
+            state, totals, batch, *live, jax.random.PRNGKey(1))
+    fn = trainer._eval_group if grouped else trainer._eval_step
+    return fn._jit.lower(state.params, totals, batch, *live)
 
 
+@pytest.mark.parametrize("grouped", [False, True], ids=["single", "group"])
 @pytest.mark.parametrize("cell", sorted(FAMILY_SCOPES))
 @pytest.mark.parametrize("step", sorted(STEP_SCOPES))
-def test_compiled_steps_carry_the_scope_vocabulary(step, cell):
-    module, parts = _scope_components(_lower(_trainer(cell=cell), step))
+def test_compiled_steps_carry_the_scope_vocabulary(step, cell, grouped):
+    module, parts = _scope_components(
+        _lower(_trainer(cell=cell), step, grouped))
     # one name in the trace, the compile ledger and the docs
     assert module == "jit_" + step
     for scope in STEP_SCOPES[step] + FAMILY_SCOPES[cell]:
         assert scope in parts, (scope, sorted(parts))
 
 
+@pytest.mark.parametrize("grouped", [False, True], ids=["single", "group"])
 @pytest.mark.parametrize("step", sorted(STEP_SCOPES))
-def test_pass_totals_are_added_under_the_metrics_scope(step):
+def test_pass_totals_are_added_under_the_metrics_scope(step, grouped):
     """The fold is in the compiled step: five adds (loss, accuracy,
     hamming, fbeta, confusion) directly under ``metrics``, so the
-    profile's device lines charge them to that scope."""
-    text = _lower(_trainer(), step).as_text(debug_info=True)
+    profile's device lines charge them to that scope; in a group's
+    program the same five, once, in the loop's body."""
+    text = _lower(_trainer(), step, grouped).as_text(debug_info=True)
+    where = "while/body/" if grouped else ""
     scoped = set(re.findall(
-        r'(#loc\d+) = loc\("jit\(%s\)/metrics/add"' % step, text))
-    # an add of a program argument (the carried total) and a value
+        r'(#loc\d+) = loc\("jit\(%s\)/%smetrics/add"' % (step, where),
+        text))
+    # an add of a program argument (the carried total: the loop's carry
+    # in a group's program) and a value
     added = [kind for kind, loc in re.findall(
-        r"stablehlo\.add %arg\d+, %\d+ : tensor<(\S+)> loc\((#loc\d+)\)",
-        text) if loc in scoped]
+        r"stablehlo\.add %(?:arg|iterArg_)\d+, %\d+ : tensor<(\S+)> "
+        r"loc\((#loc\d+)\)", text) if loc in scoped]
     assert sorted(added) == ["4x2x2xi32", "4xf32", "f32", "f32", "f32"]
 
 

@@ -101,6 +101,72 @@ def test_pass_totals_are_bit_for_bit_the_fold_over_single_step(train):
                                       "eval_step": int(not train)}
 
 
+@pytest.mark.parametrize("train", [True, False])
+def test_grouped_token_pass_is_the_fold_over_single_step(train):
+    """A decoder this small is grouped like any other family (the rule
+    reads bytes, not names): seven batches ride one call, padded to 16,
+    and read what seven single steps read."""
+    trainer = Trainer(_model(), _train())
+    ds = trainer.task.dataset(_source())
+    host = [b for c in range(7) for b in trainer.task.batches(ds, c)]
+    state = trainer.init_state(jax.random.PRNGKey(0))
+    rng = jax.random.PRNGKey(1) if train else None
+    (group,) = trainer._place_batches(host, state)
+    assert group.n_live == 7 and group.batches.x.shape == (16, 1, 2, SEQ)
+
+    folded, st = None, _copy(state)
+    for b in trainer._place_batches(host):
+        st, vals = trainer.single_step(st, b, rng)
+        folded = vals if folded is None else jax.tree.map(
+            jnp.add, folded, vals)
+    want, _ = trainer.task.epoch_metrics(jax.device_get(folded), 7)
+    out, got, _ = trainer._run_batches(_copy(state), ([group],), rng, train)
+    assert got.loss == want.loss and got.accuracy == want.accuracy
+    same = jax.tree.map(np.array_equal, jax.device_get(out),
+                        jax.device_get(st))
+    assert all(jax.tree.leaves(same))
+
+
+#: sha256 (first 16 hex digits) of the lowered text of this file's tiny
+#: decoder's single train and eval programs at PR 29's parent (98d5033),
+#: jax 0.9.0.  Regenerate with the snippet in the test below after a
+#: deliberate change to the decoder, its task or the step function.
+PARENT_STEP_TEXT = ("abc7ef3cf54cbba3", "7b58db5aa61a8736")
+
+
+def test_a_solo_decoder_runs_the_parents_programs(monkeypatch):
+    """At the published widths the decoder's 7.88 GB of state put it past
+    ``SOLO_STEP_BYTES`` (tests/test_step_totals.py holds the rule to
+    that); with the threshold brought down to this tiny one, ``fit`` is
+    the parent's loop: one call a step through the single programs,
+    whose lowered text is the parent's character for character."""
+    import hashlib
+
+    from fmda_tpu.train import trainer as trainer_module
+
+    monkeypatch.setattr(trainer_module, "SOLO_STEP_BYTES", 1)
+    trainer = Trainer(_model(), _train())
+    state, _, ds = trainer.fit(_source(), epochs=1)
+    assert trainer._train_group.cache_size() == 0
+    assert trainer._eval_group.cache_size() == 0
+    assert trainer._train_step.cache_size() == 1
+    assert trainer._eval_step.cache_size() == 1
+    batch = next(iter(trainer._chunk_batches(ds, 0)))
+    totals = trainer.zero_totals()
+    lowered = (
+        trainer._train_step._jit.lower(
+            state, totals, batch, jax.random.PRNGKey(1)),
+        trainer._eval_step._jit.lower(state.params, totals, batch))
+    got = tuple(hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
+                for low in lowered)
+    assert got == PARENT_STEP_TEXT
+    # what the benchmark's decoder driver calls after the window are the
+    # programs the loop ran: nothing more compiles
+    trainer._eval_step(state.params, trainer.zero_totals(), batch)
+    trainer.single_step(state, batch, jax.random.PRNGKey(1))  # donates
+    assert trainer.compile_counts == {"train_step": 1, "eval_step": 1}
+
+
 def test_accumulated_microbatches_give_the_full_batch_step():
     full = Trainer(_model(), _train())
     micro = Trainer(_model(), _train(accum_steps=2))
