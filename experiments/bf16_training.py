@@ -8,8 +8,9 @@ bf16 compute — and publishes the side-by-side learning curves and test
 metrics, demonstrating the bf16 path is a drop-in for training quality,
 not just a kernel-lowering claim.
 
-On CPU, bf16 is emulated (slower, not faster — the speed claim belongs to
-the TPU bench phases); what this measures is *quality* parity.
+On CPU, bf16 is emulated (slower, not faster; bf16 speed on the chip is
+not measured: no cell of record trains in bf16); what this measures is
+*quality* parity.
 
     PYTHONPATH=/root/repo:$PYTHONPATH python experiments/bf16_training.py
 
@@ -94,8 +95,9 @@ def write_md(r: dict) -> None:
         "The flagship BiGRU trained twice on the same calibrated corpus"
         f" (seed {SEED}, {N_DAYS} days), seed, and protocol — f32 compute"
         " vs bf16 compute with f32 params/optimizer (the MXU-native mixed"
-        " precision).  Quality parity on CPU emulation; the bf16 *speed*"
-        " story is the TPU bench's `flagship_bf16` phase.  Reproduce:"
+        " precision).  Quality parity on CPU emulation; bf16 *speed* on"
+        " the chip is not measured (no cell of record trains in bf16)."
+        "  Reproduce:"
         " `python experiments/bf16_training.py`.",
         "",
         "| metric | float32 | bfloat16 |",

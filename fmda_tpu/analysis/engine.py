@@ -9,7 +9,7 @@ shared engine those checks now plug into:
 
 - :class:`ParsedModule` — one ``ast.parse`` + comment map per file,
   shared by every rule (the whole suite is one parse pass over the
-  package; the ``analysis_lint`` bench phase holds it to seconds);
+  package, seconds of tier-1: tests/test_analysis.py);
 - :class:`Rule` — per-module ``check()`` visitors plus a cross-module
   ``finish()`` hook for whole-program rules (topic cross-checks, the
   drift inventory);
@@ -291,8 +291,8 @@ class LintResult:
 
     @property
     def ok(self) -> bool:
-        # stale/forbidden entries gate too: the CLI, the bench phase,
-        # and the tier-1 test must agree — a paid-off debt left in the
+        # stale/forbidden entries gate too: the CLI and the tier-1
+        # test must agree — a paid-off debt left in the
         # baseline (or one smuggled under a zero-baseline rule) is a
         # red build everywhere, not a stderr whisper
         return (not self.new and not self.stale_baseline

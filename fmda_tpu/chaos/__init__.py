@@ -8,8 +8,8 @@ serving loops (one guarded branch when disabled — the tracer's
 discipline), :mod:`~fmda_tpu.chaos.wrap` wraps a bus or warehouse
 opt-in, and :mod:`~fmda_tpu.chaos.soak` drives the whole local
 multi-host topology under a plan, hard-gating the "counted degradation,
-never abort" contract end to end (the ``runtime_chaos_soak`` bench
-phase and ``serve-fleet --role local --chaos-plan``).
+never abort" contract end to end (tests/test_chaos.py and
+``serve-fleet --role local --chaos-plan``).
 
 Everything except the soak's worker subprocesses is router-role code:
 no jax on this import path.  Architecture: docs/chaos.md.

@@ -41,7 +41,8 @@ The report hard-gates the never-abort contract for the whole pipeline:
 Determinism: the message schedule is a pure function of ``seed``
 (:mod:`fmda_tpu.data.synthetic`), the plan is a pure function of its
 seed (:meth:`FaultPlan.generate`), and the driver holds no other
-randomness — a failing soak replays from ``FMDA_CHAOS_SEED``.
+randomness — a failing soak replays from its seed
+(``chaos-pipeline --seed``).
 
 Keep ``staleness_deadline_s`` below ``watermark_s + 2*join_tolerance_s``
 (660 s at the default feature config): past that, a tick waiting on a

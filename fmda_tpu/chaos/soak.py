@@ -19,8 +19,7 @@ the load runs:
 
 The report hard-gates the **never-abort contract**:
 
-- the function returning at all is gate zero (the bench phase's
-  subprocess exits 0);
+- the function returning at all is gate zero;
 - ``unaccounted_zero``: every submitted tick is either served or sits
   in exactly one loss counter (``results_missing`` +
   ``migration_buffer_shed`` + ``inflight_dropped_on_close``) — counted

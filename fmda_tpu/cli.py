@@ -1951,8 +1951,7 @@ def cmd_perf(args) -> int:
     roofline position, memory watermarks, kernel fallbacks, and the
     host profiler's hottest stacks.  Input is a running endpoint's
     ``/device`` (+ ``/profile``) or a saved device report — a
-    flight-recorder bundle's ``device.json`` or the bench phase's
-    ledger artifact."""
+    flight-recorder bundle's ``device.json``."""
     profile_text = None
     if args.endpoint:
         import urllib.error
@@ -1982,8 +1981,8 @@ def cmd_perf(args) -> int:
             return 2
     else:
         print("pass --endpoint HOST:PORT (a running /device endpoint) "
-              "or --input FILE (a flight-recorder bundle's device.json "
-              "or a bench ledger artifact)", file=sys.stderr)
+              "or --input FILE (a flight-recorder bundle's device.json)",
+              file=sys.stderr)
         return 2
     if args.profile:
         try:
@@ -1992,10 +1991,6 @@ def cmd_perf(args) -> int:
         except OSError as e:
             print(f"cannot read {args.profile}: {e}", file=sys.stderr)
             return 2
-    # a bare ledger dump (the bench artifact) renders like a report
-    # with only the ledger section
-    if "ledger" not in doc and "programs" in doc:
-        doc = {"ledger": doc}
     if args.json:
         if profile_text is not None:
             doc = {**doc, "profile_folded": profile_text}
@@ -2010,9 +2005,8 @@ def cmd_quality(args) -> int:
     quality"): per-weights-version live accuracy/F-beta off the
     label-join evaluator, drift scores vs the training-time reference
     profile, and the capture/join conservation ledger.  Input is a
-    running endpoint's ``/quality``, a flight-recorder bundle
-    directory (its ``quality.json``), or the bench
-    ``quality_overhead`` artifact."""
+    running endpoint's ``/quality`` or a flight-recorder bundle
+    directory (its ``quality.json``)."""
     if args.endpoint:
         import urllib.error
         import urllib.request
@@ -2033,17 +2027,9 @@ def cmd_quality(args) -> int:
         except (OSError, json.JSONDecodeError) as e:
             print(f"cannot read {path}: {e}", file=sys.stderr)
             return 2
-    elif args.artifact:
-        try:
-            with open(args.artifact) as fh:
-                doc = json.load(fh)
-        except (OSError, json.JSONDecodeError) as e:
-            print(f"cannot read {args.artifact}: {e}", file=sys.stderr)
-            return 2
     else:
-        print("pass --endpoint HOST:PORT (a running /quality endpoint), "
-              "--bundle DIR (a flight-recorder postmortem bundle), or "
-              "--artifact FILE (the bench quality_overhead artifact)",
+        print("pass --endpoint HOST:PORT (a running /quality endpoint) "
+              "or --bundle DIR (a flight-recorder postmortem bundle)",
               file=sys.stderr)
         return 2
     if args.json:
@@ -2054,14 +2040,6 @@ def cmd_quality(args) -> int:
 
 
 def _print_quality_report(doc: dict) -> None:
-    if "overhead_pct" in doc:
-        # the bench quality_overhead artifact, not an evaluator document
-        print(f"quality_overhead bench: overhead {doc['overhead_pct']:.2f}% "
-              f"(budget {doc.get('budget_pct')}%, "
-              f"quiet_host={doc.get('quiet_host')}, ok={doc.get('ok')})")
-        print(f"  joined {doc.get('joined')} over {doc.get('rounds')} rounds "
-              f"x {doc.get('sessions')} sessions")
-        return
     if not doc.get("enabled", True):
         print("quality evaluation disabled ([quality] enabled=false "
               "or no evaluator attached)")
@@ -2606,8 +2584,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "fleet telemetry endpoint")
     p.add_argument("--input", default=None, metavar="FILE",
                    help="saved device report JSON instead: a "
-                        "flight-recorder bundle's device.json or the "
-                        "bench device_obs_overhead ledger artifact")
+                        "flight-recorder bundle's device.json")
     p.add_argument("--profile", default=None, metavar="FILE",
                    help="folded-stack profile text to report hottest "
                         "stacks from (a bundle's profile.folded); "
@@ -2631,9 +2608,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--bundle", default=None, metavar="DIR",
                    help="read a flight-recorder postmortem bundle's "
                         "quality.json instead")
-    p.add_argument("--artifact", default=None, metavar="FILE",
-                   help="read a bench quality_overhead artifact "
-                        "(artifacts/quality_eval.json) instead")
     p.add_argument("--json", action="store_true",
                    help="machine-readable report (the /quality "
                         "document verbatim)")
@@ -2645,8 +2619,7 @@ def build_parser() -> argparse.ArgumentParser:
              "warehouse -> predictor under a seeded fault plan "
              "(docs/chaos.md); exit 1 iff a never-abort gate fails")
     p.add_argument("--seed", type=int, default=None,
-                   help="plan + market seed (default: [chaos] seed; "
-                        "FMDA_CHAOS_SEED drives the bench phase)")
+                   help="plan + market seed (default: [chaos] seed)")
     p.add_argument("--rounds", type=int, default=30,
                    help="virtual steps the plan schedules over")
     p.add_argument("--plan", default=None, metavar="FILE",

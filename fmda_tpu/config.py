@@ -424,9 +424,10 @@ class ModelConfig:
     #: (fmda_tpu.ops.pallas_gru.kernel_supported) — at MXU-wide hidden
     #: sizes the model auto-selects lax.scan, whose per-step matmul is
     #: MXU-shaped there anyway.  Default off: the flagship default path
-    #: must be the one exercised everywhere; bench.py and TPU-gated tests
-    #: opt in explicitly (ADVICE r1 — flip the default once the kernel
-    #: has a TPU CI job).
+    #: must be the one exercised everywhere; the decoder cell's config
+    #: (benchmark/configs/), ``chip_smoke.py`` and the TPU-gated tests
+    #: opt in explicitly.  No cell of record has yet shown a recurrent
+    #: kernel winning: ROADMAP S3, then D2 decides flag and kernels.
     use_pallas: bool = False
     #: Rematerialise in backward (jax.checkpoint): the recurrence of the
     #: recurrent families, each whole block of ``attn`` and ``decoder`` —
@@ -589,8 +590,8 @@ class EngineConfig:
 
 
 #: Fleet-runtime defaults shared by RuntimeConfig and the direct
-#: constructors (BatcherConfig, FleetGateway) so bench/test-style direct
-#: constructions can't drift from the config defaults.
+#: constructors (BatcherConfig, FleetGateway) so direct constructions
+#: (tests, the benchmark's drivers) can't drift from the config defaults.
 DEFAULT_BUCKET_SIZES: Tuple[int, ...] = (8, 32, 64, 128)
 DEFAULT_MAX_LINGER_S: float = 0.002
 DEFAULT_QUEUE_BOUND: int = 1024
@@ -631,9 +632,9 @@ class RuntimeConfig:
     #: regardless (bit-identical), and multi-chip serving is an explicit
     #: deployment decision.
     shard_pool: bool = False
-    #: Latency-SLO gate for `serve-fleet` and the `runtime_fleet_smoke`
-    #: bench phase: p99 of the submit→publish ("total") histogram must
-    #: stay under this bound (ms) on a quiet host.  None disables the
+    #: Latency-SLO gate for `serve-fleet`: p99 of the submit→publish
+    #: ("total") histogram must stay under this bound (ms), else the
+    #: command exits 1 (tests/test_runtime.py).  None disables the
     #: gate; `--slo-soft` reports the verdict without failing.
     slo_p99_ms: Optional[float] = None
 
@@ -749,7 +750,7 @@ class ObservabilityConfig:
     #: Module-level instrumentation with no Application handle (ingest
     #: transports, trainer step timings) reports to the process-default
     #: registry regardless; its cost is one lock-guarded update per
-    #: event, measured inside the noise floor (bench obs_overhead).
+    #: event (not measured on the chip machine's host).
     enabled: bool = True
     #: Serve ``/metrics``+``/healthz``+``/snapshot`` over HTTP.  Off by
     #: default so tests and one-shot CLI runs never bind a port; daemons
@@ -931,9 +932,9 @@ class TracingConfig:
     #: Master switch for the process tracer.
     enabled: bool = False
     #: Fraction of trace roots sampled in [0, 1].  1.0 traces every tick
-    #: (forensics runs); production fleets run ~0.01 — the
-    #: ``trace_overhead`` bench phase holds 1% sampling under the same
-    #: <2% hot-loop budget as the metrics plane.
+    #: (forensics runs); production fleets run ~0.01, where all but one
+    #: tick in a hundred take the disabled path's no-op singletons
+    #: (tests/test_trace.py; the cost on the chip's host: not measured).
     sample_rate: float = 1.0
     #: Span-ring capacity; overflow evicts the oldest spans, so a
     #: long-running daemon keeps the newest traces and bounded memory.
@@ -948,8 +949,8 @@ class ProfilingConfig:
 
     The compile ledger itself is on by default everywhere — a tracked
     jit call with the ledger enabled costs two cache-size reads and one
-    short lock window (``device_obs_overhead`` gates the whole plane
-    under 2% of the fleet hot loop).  ``cost_analysis`` re-lowers each
+    short lock window (part of ``train_dispatch_us`` in the training
+    cells, ``PERF.md`` §5).  ``cost_analysis`` re-lowers each
     program once per compile to read FLOPs/bytes, so it is a
     *deployment* default (serving hosts want MFU; unit tests do not
     want doubled compile time — the module-level default is off and

@@ -43,7 +43,7 @@ __all__ = [
 ]
 
 
-def __getattr__(name):  # PEP 562 — soak/bench entry points load lazily
+def __getattr__(name):  # PEP 562 — the soak entry points load lazily
     if name == "run_elastic_soak":
         from fmda_tpu.control.elastic import run_elastic_soak
 

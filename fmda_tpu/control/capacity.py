@@ -13,8 +13,9 @@ that shows the batching controller earning its keep on the same load.
 
 jax-free by injection: callers supply ``gateway_factory(n_sessions)``
 returning a :class:`~fmda_tpu.runtime.gateway.FleetGateway`-shaped
-object (the bench phase builds real pools; the schema tests inject a
-deterministic fake), so importing this module never touches the
+object (tests/test_control.py injects a deterministic fake; an
+operator passes a factory that builds real pools), so importing this
+module never touches the
 accelerator stack.
 """
 

@@ -15,7 +15,6 @@ from fmda_tpu.data.pipeline import (
     WindowBatches,
     background_compose,
     prefetch_batches,
-    prefetch_to_device,
 )
 
 __all__ = [
@@ -37,5 +36,4 @@ __all__ = [
     "WindowBatches",
     "background_compose",
     "prefetch_batches",
-    "prefetch_to_device",
 ]

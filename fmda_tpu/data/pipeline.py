@@ -10,8 +10,9 @@ The TPU-first re-design of the reference's SQL dataloader stack
   materialises every stride-1 window of a chunk, then yields fixed-shape
   batches (the last partial batch is zero-padded and masked, so every step
   hits the same compiled executable — no recompiles, no dynamic shapes).
-- :func:`prefetch_to_device` double-buffers host batches into HBM so the
-  device never waits on the host (the "infeed" half of SURVEY.md §7.2).
+- :func:`prefetch_batches` composes host batches in a daemon thread and
+  places them on the device ahead of the step loop, so the device never
+  waits on the host (the "infeed" half of SURVEY.md §7.2).
 - :class:`TokenDataset` / :class:`TokenBatches` are the same two roles
   over a :class:`~fmda_tpu.data.source.TokenSource`: fixed-length id
   sequences cut from a packed stream, the target the input shifted by
@@ -285,35 +286,6 @@ def group_batches(batches: Iterable[Batch], k: int
             pending = []
     if pending:
         yield stacked(pending)
-
-
-def prefetch_to_device(
-    batches: Iterable[Batch], buffer_size: int = 2
-) -> Iterator[Batch]:
-    """Move batches to the default device ahead of consumption.
-
-    A simple double-buffer: while the caller computes on batch ``i``, batch
-    ``i+1`` is already being transferred.  (jax.device_put is async — the
-    transfer overlaps with compute dispatch.)
-    """
-    import collections
-
-    import jax
-
-    queue: collections.deque = collections.deque()
-    it = iter(batches)
-    try:
-        for _ in range(buffer_size):
-            queue.append(jax.device_put(next(it)))
-    except StopIteration:
-        pass
-    while queue:
-        out = queue.popleft()
-        try:
-            queue.append(jax.device_put(next(it)))
-        except StopIteration:
-            pass
-        yield out
 
 
 def prefetch_batches(

@@ -1,7 +1,7 @@
 """Single-command local fleet topology: workers spawned, router inline.
 
 ``launch_local_fleet`` builds the whole multi-host topology on one
-machine for benches, tests, and demos, with each tier in its **own
+machine for tests, soaks and demos, with each tier in its **own
 process** (own GIL — a shared interpreter would serialize bus frame
 handling behind the load driver and flatten the scaling the topology
 exists to buy):
@@ -54,8 +54,8 @@ WORKER_PLATFORM = "cpu"
 
 def spawn_supported(python: str = sys.executable) -> bool:
     """Can this host spawn worker subprocesses at all?  (Sandboxed CI
-    hosts sometimes cannot — the multihost bench reports ``skipped``
-    instead of erroring there.)"""
+    hosts sometimes cannot — callers skip instead of erroring
+    there.)"""
     try:
         proc = subprocess.run(
             [python, "-c", "pass"], timeout=60,

@@ -165,8 +165,8 @@ class FleetWorker:
         if precompile:
             # one padding-only flush per bucket: every program the tick
             # path can need exists before the first real tick, so
-            # compile_count stays len(bucket_sizes) forever (the
-            # multihost bench gates on exactly this)
+            # compile_count stays len(bucket_sizes) forever
+            # (tests/test_multihost.py holds exactly this)
             feats = model_cfg.n_features
             for b in self.gateway.batcher.config.bucket_sizes:
                 self.pool.step(
@@ -265,8 +265,8 @@ class FleetWorker:
             "ticks_served": c.get("ticks_served", 0),
             "flushes": c.get("flushes", 0),
             "shed_oldest": c.get("shed_oldest", 0),
-            # rides the beat so the router (and the bench's zero-loss
-            # gate) can see a worker-side inbox overrun — the counter
+            # rides the beat so the router (and a zero-loss check on
+            # its report) can see a worker-side inbox overrun — the counter
             # lives in this process, not the router's
             "inbox_records_lost": c.get("inbox_records_lost", 0),
             "compile_count": self.pool.compile_count,

@@ -387,12 +387,12 @@ class FleetTelemetry:
             target=run, name="fmda-fleet-scrape", daemon=True)
         self._scrape_thread.start()
 
-    # -- in-process fold (single-process fleets, benches, tests) ------------
+    # -- in-process fold (single-process fleets, tests) ---------------------
 
     def collect_gateway(self, gateway, now: Optional[float] = None) -> None:
         """Fold an in-process :class:`FleetGateway`'s metrics + evaluate
-        — the single-process entry point (the ``obs_aggregate_overhead``
-        bench and the deterministic telemetry soak drive this)."""
+        — the single-process entry point (the deterministic telemetry
+        and quality soaks in tests/ drive this)."""
         now = self.clock() if now is None else now
         self._last_collect = now
         self.aggregator.observe_runtime(gateway.metrics, now=now)

@@ -36,16 +36,16 @@ the module must be importable on router-role analysis hosts):
 Cost discipline: a :class:`TrackedFunction` whose ledger is disabled
 is one attribute check + the underlying jit call — no allocation, no
 lock.  The enabled steady-state path (no compile) is two cache-size
-reads and one small lock window; the ``device_obs_overhead`` bench
-phase holds the whole plane (ledger + host profiler) under 2% of the
-fleet hot loop.  ``cost_analysis`` probing re-lowers the program once
+reads and one small lock window (in the trainer's step loop it is part
+of ``train_dispatch_us``, ``PERF.md`` §5; tests/test_device_obs.py holds
+that the plane changes no output).  ``cost_analysis`` probing re-lowers the program once
 per compile, so it defaults OFF at module level and ON in
 ``[profiling]`` config (serving hosts want the numbers; unit tests do
 not want doubled compile time).
 
 The ledger dump (:meth:`CompileLedger.dump`) has a pinned schema
 (``LEDGER_SCHEMA`` / ``PROGRAM_SCHEMA``, ``LEDGER_SCHEMA_VERSION``)
-— it is a bench artifact and a flight-recorder bundle member, so its
+— it is a flight-recorder bundle member and part of ``/device``, so its
 keys are load-bearing for tooling and asserted in tests.
 """
 
@@ -60,7 +60,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 #: bump when LEDGER_SCHEMA / PROGRAM_SCHEMA change shape
 LEDGER_SCHEMA_VERSION = 1
 
-#: exact key set of CompileLedger.dump() (pinned; bench artifact)
+#: exact key set of CompileLedger.dump() (pinned; bundle member)
 LEDGER_SCHEMA = (
     "schema_version", "backend", "compiles_total",
     "compile_seconds_total", "unexpected_recompiles_total",
@@ -75,9 +75,9 @@ PROGRAM_SCHEMA = (
 
 #: Published per-chip peaks, keyed by jax ``device_kind``: (dense bf16
 #: FLOP/s, HBM bytes/s).  Source: Google Cloud TPU documentation, "TPU
-#: v5e" (197 TFLOP/s bf16, 819 GB/s).  The one table for the package and
-#: ``bench.py``.  A kind that is not here has no peak: utilization and
-#: roofline numbers are then absent (``None``), never estimated.
+#: v5e" (197 TFLOP/s bf16, 819 GB/s).  The package's one table (the
+#: benchmark keeps a copy of its own).  A kind that is not here has no peak:
+#: utilization and roofline numbers are then absent (``None``), never estimated.
 DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
     "TPU v5 lite": (197e12, 819e9),
 }
@@ -455,7 +455,7 @@ class CompileLedger:
 
     def dump(self) -> Dict[str, object]:
         """The pinned-schema ledger document (LEDGER_SCHEMA keys;
-        bench artifact + flight-recorder bundle member)."""
+        ``/device`` + flight-recorder bundle member)."""
         functions = self.functions()
         programs: List[Dict[str, object]] = []
         for fn in functions:

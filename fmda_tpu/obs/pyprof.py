@@ -19,9 +19,8 @@ low-duty-cycle sampling profiler over ``sys._current_frames()``:
 
 Exported at ``/profile`` (text exposition) and bundled into
 flight-recorder postmortems as ``profile.folded``.  Cost: sampling is
-O(live threads × stack depth) per tick at 100 Hz default — the
-``device_obs_overhead`` bench phase gates the whole device-obs plane
-(this sampler included) under 2% of the fleet hot loop.
+O(live threads × stack depth) per tick at 100 Hz default (what that
+costs a serving loop on the chip machine's host: not measured).
 """
 
 from __future__ import annotations

@@ -20,9 +20,9 @@ Export surfaces consume :meth:`MetricsRegistry.snapshot`:
 exposition, the ``/snapshot`` endpoint and ``python -m fmda_tpu status``
 serve/print the JSON form.
 
-Instruments are cheap enough for hot loops (one lock acquisition per
-update; the ``obs_overhead`` bench phase holds the whole plane under 2%
-of ``engine.step``), and a registry constructed with ``enabled=False``
+Instruments are meant for hot loops (one lock acquisition per update;
+a wired replay lands the rows a bare one lands: tests/test_obs.py),
+and a registry constructed with ``enabled=False``
 hands out shared no-op instruments so a disabled plane costs one
 attribute call.
 """

@@ -2,7 +2,7 @@
 
 The metrics plane (:mod:`fmda_tpu.obs.registry`) answers "how fast is
 each stage on average"; this module answers "where did tick T spend its
-38 ms" — the tail forensics the ``FMDA_FLEET_SLO_P99_MS`` gate needs
+38 ms" — the tail forensics the ``serve-fleet --slo-p99-ms`` gate needs
 (docs/OPERATIONS.md §4d).  One tick's journey stitches into a single
 **trace** across ingest transport → bus publish → engine join →
 warehouse land → fleet gateway enqueue → batcher flush → pool dispatch/
@@ -28,8 +28,8 @@ transfer → result publish:
 Cost contract: **disabled tracing costs one branch** on every hot path
 (the obs ``_NullInstrument`` discipline — ``tracer.enabled`` is checked
 first and the no-op context manager / ``None`` ref are shared
-singletons, zero allocation); sampled tracing stays inside the existing
-<2% overhead budget (bench phase ``trace_overhead``).
+singletons, zero allocation: tests/test_trace.py); what sampled tracing
+costs a serving loop is not measured on the chip machine's host.
 
 Span clocks are ``time.perf_counter_ns`` throughout — monotonic and
 ns-resolution, so spans recorded on different threads of one process

@@ -151,8 +151,8 @@ def flash_dispatch(
     has_mask: bool = False,
 ) -> bool:
     """THE dispatch decision :func:`mha` makes — exposed so callers that
-    *report* the executed path (bench.py's ``scan_path`` attribution)
-    ask this function instead of re-implementing the gate and silently
+    *report* the executed path ask this function instead of
+    re-implementing the gate and silently
     drifting from it.  ``has_mask`` means an arbitrary mask array; the
     causal triangle and a causal window are the kernel's own."""
     if not (use_flash and not has_mask and flash_available()):

@@ -14,10 +14,10 @@ resident in a VMEM scratch buffer for the whole sequence and processes
   grid steps; smaller B or bf16 collapse it to one).  Per-grid-step
   DMA/barrier overhead — which dominates at small (B, H), where each
   step's matmul is microseconds — is amortized over block_t unrolled
-  in-kernel steps whose operands never leave VMEM (matched
-  kernel-vs-scan pairs live in the committed BENCH_TPU*.json
-  ``flagship_pallas``/``flagship_scan`` and ``kernel_sweep`` phases —
-  those artifacts, not this docstring, are the performance record);
+  in-kernel steps whose operands never leave VMEM (kernel against
+  ``lax.scan`` on the chip: not measured in any cell — ROADMAP S3;
+  ``PERF.md`` and the ledger, not this docstring, are the performance
+  record);
 - the sequence is laid out **time-major** ``(T, B, 3H)`` so each grid
   step's block is ``(block_t, B, 3H)`` — its last two dims span the
   array's full (B, 3H) plane, satisfying Mosaic's divisible-by-(8, 128)-
@@ -102,8 +102,8 @@ def kernel_supported(
     the whole sequence, so past H ~ 512 (f32) the backward's 6*H^2
     weight copies + 3*H^2 f32 dW accumulator alone outgrow the ~16 MB
     core budget and ``lax.scan`` — whose per-step matmul is MXU-shaped
-    at such H anyway — is the right path.  The crossover is measured on
-    hardware by ``bench.py --phase kernel_sweep``.
+    at such H anyway — is the right path.  The crossover itself is not
+    measured on the chip (``PERF.md`` §7, per-kernel roofline).
     """
     # time-varying blocks at K=1, double-buffered by Mosaic:
     # fwd: xp (1,B,3H) in + hs (1,B,H) out -> 8*B*H elems
@@ -134,8 +134,8 @@ def _default_block_t(
     cap = max(1, budget // max(per_step, 1))
     # unroll bound: past ~64 in-kernel steps the per-grid-step overhead is
     # already amortized away, while Mosaic compile time grows superlinearly
-    # with the unroll (a 256-step unroll at the longctx shape blew the
-    # bench's 900 s phase budget; 64 compiles in seconds)
+    # with the unroll (a 256-step unroll at a 4k-step shape compiled for
+    # over 900 s; 64 compiles in seconds)
     cap = min(cap, 64)
     best = 1
     for d in range(1, seq_len + 1):

@@ -209,7 +209,7 @@ def ema_pool_parallel(
 def ssm_pallas_available() -> bool:
     """True when the fused Pallas serve-step kernel can run compiled on
     this backend (interpret mode runs anywhere and is dispatched
-    explicitly by tests/bench)."""
+    explicitly by tests)."""
     try:
         from fmda_tpu.ops import pallas_ssm  # noqa: F401
     except ImportError:

@@ -4,9 +4,9 @@ Serves the *same* history source through the *same* gateway surface as
 :class:`~fmda_tpu.replay.driver.ReplayDriver`, but the way a live feed
 would: each round arrives on a wall-clock cadence, rows are submitted
 per-tick (no backfill coalescing), and flushes ride the batcher's own
-ready/linger logic.  The bench phase races the two — replay must beat
-this loop by a wide margin, because the cadence is exactly what replay
-deletes — and the identity tests compare their published probabilities
+ready/linger logic.  Replay deletes exactly that cadence (how much
+faster it is on the chip: not measured, no cell replays), and the
+identity tests (tests/test_replay.py) compare their published probabilities
 byte for byte (lockstep ``duty=1.0`` sources force identical flush
 composition, so float32 reduction order matches and equality is exact).
 

@@ -5,8 +5,10 @@ independent ticker sessions — each with its own price scale (per-session
 normalization stats) and its own random-walk feature stream — submitting
 rows round by round and pumping the gateway, exactly the traffic shape
 the fleet runtime exists for.  Used by ``python -m fmda_tpu serve-fleet``
-and by the ``runtime_fleet_smoke`` bench phase (the serving-trajectory
-baseline later PRs regress against).
+and the replay tier (``replay/driver.py``).  Its rounds are closed and in
+lockstep and its latencies start at ``submit``: a smoke, not a
+measurement (the serving cells in ``benchmark/cells.json`` are open-loop
+and time a tick from when it was due).
 """
 
 from __future__ import annotations
@@ -227,8 +229,7 @@ def run_predictor_load(
     """Publish predict-timestamp signals in bursts on the gateway's bus
     and poll the :class:`~fmda_tpu.runtime.predictor_pool
     .PredictorGateway` after each burst; returns throughput + per-stage
-    latency + loss counters (``serve-fleet --predictor`` and the
-    ``predictor_fleet_smoke`` bench phase)."""
+    latency + loss counters (``serve-fleet --predictor``)."""
     from fmda_tpu.config import TOPIC_PREDICT_TIMESTAMP
 
     load = load or PredictorLoadConfig()

@@ -401,8 +401,8 @@ class Trainer:
 
     def mark_warm(self) -> None:
         """Declare step warm-up over: any compile after this is counted
-        as *unexpected* on the compile ledger (the contract the
-        ``train_throughput`` bench phase and the continuous loop pin)."""
+        as *unexpected* on the compile ledger (the contract
+        tests/test_step_totals.py and the continuous loop's tests pin)."""
         for step in self._steps():
             step.mark_warm()
 
@@ -412,8 +412,8 @@ class Trainer:
 
     @property
     def compile_counts(self) -> Dict[str, Optional[int]]:
-        """Distinct compiled programs per step kind — the pin the
-        ``train_throughput`` bench asserts (batches are always padded to
+        """Distinct compiled programs per step kind — the pin
+        tests/test_step_totals.py asserts (batches are always padded to
         ``batch_size`` and a pass's last group to the full group, so a
         ``fit`` compiles each kind exactly once: the grouped program or
         the single one, by :func:`group_size`; ``single_step`` beside a

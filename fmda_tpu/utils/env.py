@@ -2,7 +2,7 @@
 the CPU-forced child environment.
 
 Every entry point that runs device code (``python -m fmda_tpu``,
-``bench.py`` phases, ``chip_smoke.py``, ``__graft_entry__``) decides its
+``benchmark/run.py``, ``chip_smoke.py``, ``__graft_entry__``) decides its
 platform with :func:`select_backend` and places its persistent compile
 cache with :func:`enable_compile_cache`; children that must stay off the
 accelerator (virtual-mesh runs, fleet workers) get

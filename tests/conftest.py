@@ -41,7 +41,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: excluded from tier-1 (long multi-process soaks; "
-        "run explicitly or via bench phases)")
+        "run explicitly: -m slow)")
 
 
 @pytest.fixture

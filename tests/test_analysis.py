@@ -655,8 +655,8 @@ def test_lock_rule_sees_through_match_statements():
 
 
 def test_lint_stale_baseline_entry_fails_the_gate(capsys, tmp_path):
-    # a paid-off debt left in the baseline exits 1 — the CLI, the bench
-    # phase, and the tier-1 test agree on `LintResult.ok`
+    # a paid-off debt left in the baseline exits 1 — the CLI and the
+    # tier-1 test agree on `LintResult.ok`
     from fmda_tpu import cli
 
     path = tmp_path / "baseline.json"

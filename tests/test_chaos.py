@@ -6,7 +6,7 @@ run is a reproduction recipe), the wrappers degrade components the way
 real transport failures do, the compiled-in injection points drive the
 REAL link-failure machinery in the router, and the configured-off state
 is indistinguishable from no chaos at all.  The full spawned-process
-soak is the slow-marked test at the bottom (bench: runtime_chaos_soak).
+soak is the slow-marked test at the bottom.
 """
 
 import json
@@ -317,7 +317,7 @@ def test_injected_worker_step_delay_uses_plan_sleep(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the full spawned-process soak (slow; bench: runtime_chaos_soak)
+# the full spawned-process soak (slow)
 # ---------------------------------------------------------------------------
 
 
@@ -342,8 +342,7 @@ def test_chaos_soak_never_abort_gates(cell):
     takeover rebuilding the registry from worker session reports, a
     control-bus outage — every gate must hold (zero uncounted losses,
     no orphaned session, post-chaos serving, clean sessions
-    bit-identical to an unfaulted replay).  The bench phase
-    ``runtime_chaos_soak`` runs the larger calibrated shape.
+    bit-identical to an unfaulted replay).
 
     Parametrized over the GRU reference AND the SSM cell family
     (ISSUE 14): the identity gates must stay green with the O(1)-cache

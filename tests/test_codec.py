@@ -229,6 +229,24 @@ def test_coalesce_preserves_order_with_interleaved_control():
     assert codec.coalesce_ticks([]) == []
 
 
+def test_publish_many_frame_of_coalesced_ticks_is_the_same_rows_in_both_formats():
+    """What a fleet tick pays: one ``publish_many`` frame whose ticks
+    were coalesced into blocks.  The binary frame and the JSON fallback
+    hand back the identical (B, F) float32 rows, bit for bit, and the
+    rows that went in."""
+    msgs = _tick_msgs(256, feats=108, pool=64)
+    rows = np.stack([m["row"] for m in msgs])
+    frame = {"op": "publish_many", "topic": "t",
+             "values": codec.coalesce_ticks(msgs)}
+    got = {}
+    for binary in (True, False):
+        out = _round_trip(frame, binary)
+        assert out["op"] == "publish_many" and out["topic"] == "t"
+        got[binary] = np.vstack(
+            [np.asarray(b["rows"], np.float32) for b in out["values"]])
+    assert got[True].tobytes() == got[False].tobytes() == rows.tobytes()
+
+
 # ------------------------------------------------------------ packed rows
 
 

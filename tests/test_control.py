@@ -678,6 +678,37 @@ def test_gateway_retune_swaps_linger_and_caps_buckets():
     assert gw.metrics.counters["retunes_applied"] == 2
 
 
+def test_retune_on_a_warm_pool_compiles_nothing():
+    """Linger and the bucket cap are host-side knobs: a pool precompiled
+    on its bucket set and declared warm serves through any retune with
+    the programs it had (the cap falls to a compiled bucket, never to a
+    new size)."""
+    gw, feats = _qos_gateway(queue_bound=64)
+    pool = gw.pool
+    for b in gw.batcher.config.bucket_sizes:
+        pool.step(np.full(b, pool.padding_slot, np.int32),
+                  np.zeros((b, feats), np.float32))
+    pool.mark_warm()
+    compiled = pool.compile_count
+    rng = np.random.default_rng(0)
+    for i in range(8):
+        gw.open_session(f"s{i}", tenant="gold" if i % 2 else "bronze")
+
+    def serve_round():
+        for i in range(8):
+            gw.submit(f"s{i}", rng.normal(size=feats).astype(np.float32))
+        return len(gw.drain())
+
+    served = serve_round()
+    for linger_ms, cap in ((0.0, 3), (5.0, 1), (0.5, None), (0.0, 7)):
+        gw.retune(max_linger_ms=linger_ms, bucket_cap=cap)
+        served += serve_round()
+    assert served == 8 * 5
+    assert gw.metrics.counters["retunes_applied"] == 4
+    assert pool.compile_count == compiled
+    assert pool.recompiles_after_warmup == 0
+
+
 # ---------------------------------------------------------------------------
 # loadgen tenant mixes
 # ---------------------------------------------------------------------------

@@ -76,7 +76,7 @@ def _slo_cfg(**over):
 
 
 def test_ledger_dump_schema_is_pinned():
-    """The dump document is a bench artifact and a flight-recorder
+    """The dump document is part of ``/device`` and a flight-recorder
     bundle member — its key set is part of the operational contract."""
     led = CompileLedger(enabled=True)
     f = tracked_jit(lambda x: x + 1.0, name="prog", ledger=led,
@@ -432,7 +432,8 @@ def test_profiler_overflow_folds_into_other_bucket():
 # ---------------------------------------------------------------------------
 
 
-def test_recorder_bundles_profile_and_device_report(tmp_path):
+def _bundle_with_device_report(tmp_path, reason):
+    """One program compiled once on its own ledger, frozen into a bundle."""
     led = CompileLedger(enabled=True)
     f = tracked_jit(lambda x: x + 1.0, name="prog", ledger=led,
                     signature_of=lambda x: int(x.shape[0]))
@@ -441,7 +442,11 @@ def test_recorder_bundles_profile_and_device_report(tmp_path):
         str(tmp_path), keep=2, min_interval_s=0.0,
         profile_fn=lambda: "MainThread;mod:fn 7\n",
         device_fn=lambda: device_report(ledger=led))
-    path = rec.trigger("slo-recompile")
+    return rec.trigger(reason)
+
+
+def test_recorder_bundles_profile_and_device_report(tmp_path):
+    path = _bundle_with_device_report(tmp_path, "slo-recompile")
     files = set(os.listdir(path))
     assert {"profile.folded", "device.json"} <= files
     assert HostProfiler.parse_folded(
@@ -476,6 +481,132 @@ def test_no_mfu_gauge_for_a_device_kind_without_a_published_peak():
     assert gauge and gauge[0]["labels"] == {
         "backend": "tpu", "device_kind": "TPU v5 lite"}
     assert gauge[0]["value"] == led.mfu() == 0.0
+
+
+class _FlopsOnly:
+    """A tracked function's face towards ``CompileLedger.families``."""
+
+    name = "prog"
+
+    def __init__(self):
+        self.flops = 0.0
+
+    def _totals(self):
+        return 0, 0.0, 0, self.flops, 0.0
+
+    def cache_size(self):
+        return 1
+
+    def snapshot(self):
+        return []
+
+
+def _mfu_gauge_after(monkeypatch, device_kind, flops, seconds):
+    """(gauge values, ``mfu()``) of a ledger on ``device_kind`` whose
+    programs did ``flops`` in the ``seconds`` between two scrapes."""
+    from fmda_tpu.obs import device as device_mod
+
+    led = CompileLedger(enabled=True)
+    led._backend, led._device_kind = "tpu", device_kind
+    fn = _FlopsOnly()
+    monkeypatch.setattr(led, "functions", lambda: [fn])
+    clock = FakeClock()
+    monkeypatch.setattr(device_mod.time, "monotonic", lambda: clock.t)
+    led.families()
+    clock.t += seconds
+    fn.flops = flops
+    gauges = [g["value"] for g in led.families()["gauges"]
+              if g["name"] == "device_mfu"]
+    return gauges, led.mfu()
+
+
+def test_the_peak_table_is_the_published_v5e_row_and_the_gauge_reads_it(
+        monkeypatch):
+    from fmda_tpu.obs.device import DEVICE_PEAKS
+
+    # keyed by jax device_kind; no cpu / interpreter / "tpu" backend rows
+    assert DEVICE_PEAKS == {"TPU v5 lite": (197e12, 819e9)}
+    # two seconds at the published peak
+    assert _mfu_gauge_after(
+        monkeypatch, "TPU v5 lite", 2.0 * 197e12, 2.0) == ([1.0], 1.0)
+
+
+def test_unknown_device_kind_has_no_mfu_not_an_assumed_one(monkeypatch):
+    from fmda_tpu.obs.device import DEVICE_PEAKS
+
+    for kind in ("TPU v9 imaginary", "cpu", "tpu", "", None):
+        assert DEVICE_PEAKS.get(kind) is None
+        assert _mfu_gauge_after(monkeypatch, kind, 1e12, 1.0) == ([], None)
+
+
+def test_cmd_perf_renders_a_bundles_device_report(tmp_path, capsys):
+    from fmda_tpu.cli import main
+
+    bundle = _bundle_with_device_report(tmp_path, "manual")
+    device_json = os.path.join(bundle, "device.json")
+    assert main(["perf", "--input", device_json, "--profile",
+                 os.path.join(bundle, "profile.folded")]) == 0
+    out = capsys.readouterr().out
+    assert "compiles 1" in out and "prog" in out
+    assert "hottest host stacks (7 samples)" in out
+    # --json passes the device report through, the profile beside it
+    assert main(["perf", "--input", device_json, "--json"]) == 0
+    doc = json.loads(capsys.readouterr().out)
+    assert tuple(sorted(doc["ledger"])) == tuple(sorted(LEDGER_SCHEMA))
+
+
+def test_cmd_perf_usage_names_only_the_inputs_that_exist(tmp_path, capsys):
+    from fmda_tpu.cli import main
+
+    assert main(["perf"]) == 2  # no input selected: usage error
+    err = capsys.readouterr().err
+    assert "--endpoint" in err and "--input" in err and "device.json" in err
+    assert "bench" not in err and "artifact" not in err
+    assert main(["perf", "--input", str(tmp_path / "absent.json")]) == 2
+    assert "cannot read" in capsys.readouterr().err
+
+
+def test_device_plane_on_a_warmed_pool_recompiles_nothing_and_changes_nothing():
+    """Steady serving under the whole device plane (ledger accounting a
+    call, the memory monitor's cadence check a step, the host profiler
+    sampling) adds no compile after warm-up, and the same steps with
+    the plane off give the same outputs and record nothing."""
+    from fmda_tpu.obs.device import default_ledger, default_memory_monitor
+
+    led, memory = default_ledger(), default_memory_monitor()
+    was = led.enabled, memory.enabled
+    led.reset()
+    led.enabled = memory.enabled = True
+    try:
+        cfg, params = _setup()
+        pool = SessionPool(cfg, params, capacity=4, window=4)
+        slots = np.arange(4, dtype=np.int32)
+        rows = np.random.default_rng(0).standard_normal(
+            (8, 4, 6)).astype(np.float32)
+        pool.step(np.full(4, pool.padding_slot, np.int32), rows[0])
+        pool.mark_warm()
+        profiler = HostProfiler()
+        profiler.start()
+        on = []
+        for r in rows:
+            on.append(np.asarray(pool.step(slots, r)))
+            memory.maybe_sample()
+        profiler.stop()
+        dump = led.dump()
+        assert dump["unexpected_recompiles_total"] == 0
+        assert pool.recompiles_after_warmup == 0
+        calls = sum(p["calls"] for p in dump["programs"])
+        assert calls >= len(rows) + 1
+
+        led.enabled = memory.enabled = False
+        twin = SessionPool(cfg, params, capacity=4, window=4)
+        off = [np.asarray(twin.step(slots, r)) for r in rows]
+        for a, b in zip(on, off):
+            np.testing.assert_array_equal(a, b)
+        assert sum(p["calls"] for p in led.dump()["programs"]) == calls
+    finally:
+        led.enabled, memory.enabled = was
+        led.reset()
 
 
 # ---------------------------------------------------------------------------

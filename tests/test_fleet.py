@@ -8,7 +8,7 @@ produces the bit-identical output sequence an unmigrated single-process
 gateway produces over the same ticks, with no drop, duplicate, or
 reorder.  The cross-process topology itself is exercised by
 ``test_multihost_topology`` (spawned workers, worker-hosted data
-buses) and the ``runtime_multihost_smoke`` bench phase.
+buses).
 """
 
 import numpy as np

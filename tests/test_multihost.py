@@ -6,8 +6,8 @@ router, and checks the acceptance surface end to end: every tick
 answered in per-session order, per-worker compile counts stable, and
 the per-process trace files stitching into single cross-process
 journeys via ``trace --merge`` on the topology's trace directory.
-Kept deliberately small (one worker, short load): the scaling
-measurement lives in the ``runtime_multihost_smoke`` bench phase.
+Kept deliberately small (one worker, short load): how the topology
+scales is not measured (local workers are forced onto the CPU).
 """
 
 import json

@@ -618,6 +618,52 @@ def test_transport_instrumentation_counts_retries_and_waits():
         sum(waits))
 
 
+def test_instrumented_engine_replay_lands_the_bare_replays_rows():
+    """The plane observes the hot loop and does not steer it: the same
+    session replayed in small steps with the registry wired (per-step
+    histogram, bus and warehouse counters, the engine's scrape-time
+    collector, a scrape mid-load) lands the rows a bare engine lands."""
+    from fmda_tpu.config import DEFAULT_TOPICS
+    from fmda_tpu.obs import engine_families
+    from fmda_tpu.stream import InProcessBus, StreamEngine, Warehouse
+
+    from test_stream import _session_messages
+
+    fc = _small_features(get_cot=False)
+    msgs = _session_messages(12)
+
+    def replay(reg):
+        bus = InProcessBus(DEFAULT_TOPICS)
+        wh = Warehouse(fc, WarehouseConfig(path=":memory:"))
+        eng = StreamEngine(bus, wh, fc, metrics=reg)
+        if reg is not None:
+            reg.register_collector("engine", lambda: engine_families(eng))
+            bus.bind_metrics(reg)
+            wh.bind_metrics(reg)
+        steps = 0
+        for i in range(0, len(msgs), 4):  # one tick's four messages a step
+            for topic, m in msgs[i:i + 4]:
+                bus.publish(topic, m)
+            eng.step()
+            steps += 1
+            if reg is not None and steps == 6:
+                reg.snapshot()
+        return wh.fetch(list(range(1, len(wh) + 1))), steps
+
+    bare, steps = replay(None)
+    reg = MetricsRegistry()
+    wired, _ = replay(reg)
+    assert bare.shape[0] == 12
+    np.testing.assert_array_equal(wired, bare)
+    snap = reg.snapshot()
+    step_hist = [h for h in snap["histograms"]
+                 if h["name"] == "engine_step_seconds"]
+    assert step_hist and step_hist[0]["count"] == steps
+    published = sum(c["value"] for c in snap["counters"]
+                    if c["name"] == "bus_published_total")
+    assert published >= len(msgs)
+
+
 def test_trainer_reports_step_and_epoch_timings():
     from fmda_tpu.data.synthetic import SyntheticMarketConfig, build_corpus
 

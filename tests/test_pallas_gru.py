@@ -147,7 +147,7 @@ def test_pallas_kernel_multiblock_parity(reverse, monkeypatch):
 
 # Each export costs ~4 s of Mosaic lowering on the one-core CI box, so
 # tier-1 runs a representative slice — both dtypes AND both directions
-# at the flagship shape, plus one lowering per remaining bench shape —
+# at the flagship shape, plus one lowering per remaining shape —
 # and the full 12-combo matrix stays available under `-m slow`.
 _LOWERING_CASES = [
     pytest.param(256, 30, 32, False, "float32", id="flagship-fwd-f32"),
@@ -171,7 +171,7 @@ _LOWERING_CASES = [
 
 @pytest.mark.parametrize("batch,seq,hidden,reverse,dtype", _LOWERING_CASES)
 def test_pallas_kernel_lowers_for_tpu(batch, seq, hidden, reverse, dtype):
-    """Mosaic TPU lowering of the full fwd+bwd kernel pair at every bench
+    """Mosaic TPU lowering of the full fwd+bwd kernel pair at every listed
     shape, both directions and compute dtypes, via jax.export — no TPU
     required.  This is what rejected the original batch-major (B, 1, 3H)
     block layout (sublane dim 1 < 8) and the mixed-dtype bf16 gate math."""

@@ -2,7 +2,7 @@
 
 Same three coverage layers as test_pallas_gru.py: interpret-mode parity
 (outputs and all gradients, both directions, nonzero initial state,
-forced multi-block), Mosaic TPU lowering via jax.export at the bench
+forced multi-block), Mosaic TPU lowering via jax.export at the listed
 shapes, and an on-device parity test gated on a reachable TPU.
 """
 
@@ -146,7 +146,7 @@ def test_pallas_lstm_bf16_numerics_close_to_scan(reverse):
 
 
 # ~4 s of Mosaic lowering per combo: tier-1 keeps one lowering per
-# bench shape (directions alternated); the full matrix runs under slow
+# shape (directions alternated); the full matrix runs under slow
 @pytest.mark.parametrize("batch,seq,hidden,reverse", [
     pytest.param(256, 30, 32, False, id="flagship-fwd"),
     pytest.param(16, 1024, 32, True, id="longctx-rev"),
@@ -156,7 +156,7 @@ def test_pallas_lstm_bf16_numerics_close_to_scan(reverse):
                  marks=pytest.mark.slow),
 ])
 def test_pallas_lstm_lowers_for_tpu(batch, seq, hidden, reverse):
-    """Mosaic TPU lowering of the fwd+bwd pair at the bench shapes via
+    """Mosaic TPU lowering of the fwd+bwd pair at the listed shapes via
     jax.export — no hardware required."""
     xp = jnp.zeros((batch, seq, 4 * hidden))
     h0 = jnp.zeros((batch, hidden))

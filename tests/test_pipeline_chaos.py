@@ -4,8 +4,7 @@ survival, and the pipeline soak's never-abort gates.
 
 The fast tier-1 surface runs everything in-process and deterministic
 (no jax, no subprocesses); the full calibrated soak with the jitted
-Predictor attached is the slow-marked test at the bottom (bench:
-``pipeline_chaos_soak``).
+Predictor attached is the slow-marked test at the bottom.
 """
 
 import json
@@ -502,7 +501,7 @@ def test_pipeline_plan_is_seeded_and_disjoint():
 
 
 # ---------------------------------------------------------------------------
-# the pipeline soak (fast deterministic shape; bench: pipeline_chaos_soak)
+# the pipeline soak (fast deterministic shape)
 # ---------------------------------------------------------------------------
 
 
@@ -544,9 +543,8 @@ def test_pipeline_soak_replays_identically_from_one_plan():
 
 @pytest.mark.slow
 def test_pipeline_soak_calibrated_with_predictor():
-    """The bench-calibrated shape: generated plan, jitted Predictor
-    attached, unfaulted-reference identity — the full
-    ``pipeline_chaos_soak`` contract."""
+    """The calibrated shape: generated plan, jitted Predictor
+    attached, unfaulted-reference identity — the full contract."""
     from fmda_tpu.chaos.pipeline import (
         generate_pipeline_plan, run_pipeline_soak)
 

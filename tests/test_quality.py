@@ -415,7 +415,7 @@ def test_status_quality_line_renders_from_snapshot(capsys):
     assert _quality_summary({"gauges": [], "counters": []}) == {}
 
 
-def test_cmd_quality_renders_bundle_and_bench_artifact(tmp_path, capsys):
+def test_cmd_quality_renders_bundle(tmp_path, capsys):
     from fmda_tpu.cli import main
 
     wh = _flat_warehouse(17)
@@ -430,18 +430,34 @@ def test_cmd_quality_renders_bundle_and_bench_artifact(tmp_path, capsys):
     assert "captured 1 = joined 1" in out
     assert "v2" in out
 
-    artifact = tmp_path / "quality_eval.json"
-    artifact.write_text(json.dumps({
-        "overhead_pct": 1.25, "budget_pct": 2.0, "quiet_host": True,
-        "ok": True, "joined": 219, "rounds": 29, "sessions": 8}))
-    assert main(["quality", "--artifact", str(artifact)]) == 0
-    out = capsys.readouterr().out
-    assert "overhead 1.25%" in out and "joined 219" in out
     # --json passes the document through verbatim
     assert main(["quality", "--bundle", str(bundle), "--json"]) == 0
     doc = json.loads(capsys.readouterr().out)
     assert doc["conservation"]["joined"] == 1
     assert main(["quality"]) == 2  # no input selected: usage error
+
+
+def test_cmd_quality_has_no_artifact_input(tmp_path, capsys):
+    """`quality` reads the evaluator's own document only: the flag
+    that read another program's file is a usage error from argparse."""
+    from fmda_tpu.cli import main
+
+    with pytest.raises(SystemExit) as exc:
+        main(["quality", "--artifact", str(tmp_path / "x.json")])
+    assert exc.value.code == 2
+    assert "unrecognized arguments: --artifact" in capsys.readouterr().err
+
+
+def test_cmd_quality_usage_names_only_the_inputs_that_exist(tmp_path, capsys):
+    from fmda_tpu.cli import main
+
+    assert main(["quality"]) == 2
+    err = capsys.readouterr().err
+    assert "--endpoint" in err and "--bundle" in err
+    assert "--artifact" not in err and "bench" not in err
+    # a bundle without the evaluator's document is a read error, not a report
+    assert main(["quality", "--bundle", str(tmp_path)]) == 2
+    assert "quality.json" in capsys.readouterr().err
 
 
 # ---------------------------------------------------------------------------

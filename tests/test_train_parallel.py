@@ -193,8 +193,8 @@ def test_cache_disabled_when_split_exceeds_budget(source):
 def test_dropped_trainer_is_collected(source):
     """The compile ledger registers weakly: deleting a Trainer frees its
     jit closures and placed device batches (the PR 20 leak fix — one
-    process constructing many Trainers, as the bench and the continuous
-    loop do, must not accrete dead trainers' device memory)."""
+    process constructing many Trainers, as a sweep or the continuous
+    loop does, must not accrete dead trainers' device memory)."""
     trainer = Trainer(_model_cfg(), _train_cfg(cache_chunks=16))
     trainer.fit(source, epochs=1)
     assert len(trainer._placed_cache) == 1
