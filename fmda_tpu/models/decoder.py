@@ -59,6 +59,7 @@ class RoutingStats(NamedTuple):
 
     expert_pairs: jax.Array  # (layers, held experts) int32
     dropped: jax.Array       # () int32: held pairs not computed (0)
+    row_tiles_used: jax.Array  # (layers,) int32: row tiles holding a group
 
 
 def _weight(module: nn.Module, name: str, shape: Tuple[int, ...],
@@ -140,7 +141,8 @@ class DecoderBlock(nn.Module):
             _weight(self, "w_up", (count, d, f)),
             _weight(self, "w_down", (count, f, d)),
             experts_held=(first, count), impl=kernel_impl(cfg.use_pallas))
-        return x + m.reshape(b, t, d), (plan.group_sizes, plan.dropped)
+        return x + m.reshape(b, t, d), (
+            plan.group_sizes, plan.dropped, plan.n_used[0])
 
 
 class MoEDecoder(nn.Module):
@@ -167,13 +169,14 @@ class MoEDecoder(nn.Module):
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = jnp.take(self.embed, ids, axis=0).astype(jnp.dtype(cfg.dtype))
-        sizes, dropped = [], jnp.zeros((), jnp.int32)
+        sizes, tiles, dropped = [], [], jnp.zeros((), jnp.int32)
         for block in self.blocks:
-            x, (layer_sizes, layer_dropped) = block(x)
+            x, (layer_sizes, layer_dropped, layer_tiles) = block(x)
             sizes.append(layer_sizes)
+            tiles.append(layer_tiles)
             dropped = dropped + layer_dropped
         x = rms_norm(x, self.ln_final, cfg.rms_norm_eps)
-        return x, RoutingStats(jnp.stack(sizes), dropped)
+        return x, RoutingStats(jnp.stack(sizes), dropped, jnp.stack(tiles))
 
     def __call__(self, ids: jax.Array, *, deterministic: bool = True
                  ) -> jax.Array:

@@ -32,7 +32,18 @@ grouped products skip the tiles no pair fell into
 
 Both gathers have hand-written transposes that are gathers too (a row
 belongs to one pair, a pair to one row), so neither direction scatters
-wide rows.
+wide rows.  The passes over the *row* layout (:func:`gather_rows`
+forward, :func:`combine_rows` backward) walk the first ``plan.n_used``
+row tiles, a few tiles a turn, and no further: the layout is sized for
+every pair landing here, the groups fill it from row 0 without a gap,
+and nothing reads a row past them (the grouped products skip those
+tiles, a held pair's row lies below ``n_used * tile``, an unheld pair's
+is row 0), so the rest of a row buffer is the zeros it was made of.  The combine's backward makes
+one such pass for both of its cotangents: ``d_y`` is the token's
+cotangent row times the gate, and the gate's own gradient is the dot of
+the same two rows, taken while both are at hand and gathered back to
+(T, k) as scalars.  The passes over *tokens* (``combine_rows`` forward,
+``gather_rows`` backward) still gather one (T, D) block a slot.
 """
 
 from __future__ import annotations
@@ -57,6 +68,11 @@ class Plan(NamedTuple):
     group_sizes: jax.Array  # (count,) int32: pairs on each held expert
     dropped: jax.Array      # () int32: held pairs left without a row (0)
 
+    @property
+    def tile(self) -> int:
+        """Rows a tile (static: the layout's rows over its tiles)."""
+        return self.row_pair.shape[0] // self.tile_expert.shape[0]
+
 
 def default_row_tile(n_pairs: int) -> int:
     """Rows a tile: 256 at real sizes (an MXU-friendly product per grid
@@ -68,6 +84,12 @@ def layout_rows(n_pairs: int, count: int, tile: int) -> int:
     """Rows of the grouped layout: every pair held, each of the ``count``
     groups padded by up to a tile, an empty group keeping one."""
     return (-(-n_pairs // tile) + count) * tile
+
+
+def layout_tiles(n_pairs: int, count: int) -> int:
+    """Row tiles of the layout a call of ``n_pairs`` pairs gets."""
+    tile = default_row_tile(n_pairs)
+    return layout_rows(n_pairs, count, tile) // tile
 
 
 def route(h: jax.Array, w_router: jax.Array, top_k: int
@@ -141,11 +163,47 @@ def _sum_rows_of_pairs(x: jax.Array, plan: Plan, weights: jax.Array
     return out
 
 
+#: Row tiles a turn of a row pass moves.  A turn is a handful of small
+#: operations (index slices, a gather, an in-place update) that cost the
+#: chip ~14 us however few rows they move: at the published shapes with
+#: 62 of 208 tiles used, ``gather_rows`` took 1.80 ms at one tile a turn
+#: (the unbounded pass: 1.93) and 1.36 ms at two, four, eight or sixteen
+#: (PERF.md section 6, PR 31).
+_TILES_A_TURN = 4
+
+
+def _over_used_rows(plan: Plan, body, init):
+    """``carry = body(at, n, carry)`` for turns of ``n`` rows from row
+    ``at`` that together cover the ``plan.n_used`` row tiles holding a
+    group, and at most ``_TILES_A_TURN - 1`` tiles behind them.  The
+    layout's last turn is moved back to end with the layout, so a turn
+    may visit rows again; a body writes a row from its index alone.  The
+    bound is the routing's, so this is a ``while``: it is only called
+    from the hand-written sides of a ``custom_vjp``, never
+    differentiated through."""
+    rows, tile = plan.row_pair.shape[0], plan.tile
+    n = min(_TILES_A_TURN * tile, rows)
+    turns = (plan.n_used[0] * tile + n - 1) // n
+    return jax.lax.fori_loop(
+        0, turns,
+        lambda i, carry: body(jnp.minimum(i * n, rows - n), n, carry), init)
+
+
 @jax.custom_vjp
 def gather_rows(u: jax.Array, plan: Plan) -> jax.Array:
-    """``rows[r] = u[token of row r]`` (zeros on padding rows)."""
+    """``rows[r] = u[token of row r]`` (zeros on padding rows, and on
+    the rows past the used tiles, which are not visited)."""
     k = plan.pair_row.shape[1]
-    return jnp.where(plan.row_valid[:, None], u[plan.row_pair // k], 0)
+
+    def one_turn(at, n, rows):
+        pair = jax.lax.dynamic_slice(plan.row_pair, (at,), (n,))
+        valid = jax.lax.dynamic_slice(plan.row_valid, (at,), (n,))
+        return jax.lax.dynamic_update_slice(
+            rows, jnp.where(valid[:, None], u[pair // k], 0), (at, 0))
+
+    return _over_used_rows(
+        plan, one_turn,
+        jnp.zeros((plan.row_pair.shape[0], u.shape[1]), u.dtype))
 
 
 def _gather_rows_fwd(u, plan):
@@ -174,15 +232,28 @@ def _combine_rows_fwd(y, gates, plan):
 def _combine_rows_bwd(residuals, d_out):
     y, gates, plan = residuals
     k = gates.shape[1]
-    d32 = d_out.astype(jnp.float32)
-    row_gate = jnp.where(plan.row_valid,
-                         gates.reshape(-1)[plan.row_pair], 0.0)
-    d_y = (row_gate[:, None] * d32[plan.row_pair // k]).astype(y.dtype)
-    d_gates = jnp.stack([
-        jnp.where(plan.pair_held[:, s], jnp.sum(
-            d32 * y[plan.pair_row[:, s]].astype(jnp.float32), axis=-1), 0.0)
-        for s in range(k)], axis=1).astype(gates.dtype)
-    return d_y, d_gates, None
+    flat_gates = gates.reshape(-1)
+
+    def one_turn(at, n, carry):
+        # g: the cotangent row of each row's token, float32; times the
+        # row's gate it is d_y, dotted with y's own row the gate's gradient
+        d_y, row_dot = carry
+        pair = jax.lax.dynamic_slice(plan.row_pair, (at,), (n,))
+        valid = jax.lax.dynamic_slice(plan.row_valid, (at,), (n,))
+        g = d_out[pair // k].astype(jnp.float32)
+        gate = jnp.where(valid, flat_gates[pair], 0.0)
+        y_rows = jax.lax.dynamic_slice(y, (at, 0), (n, y.shape[1]))
+        d_y = jax.lax.dynamic_update_slice(
+            d_y, (gate[:, None] * g).astype(y.dtype), (at, 0))
+        row_dot = jax.lax.dynamic_update_slice(
+            row_dot, jnp.sum(g * y_rows.astype(jnp.float32), axis=-1), (at,))
+        return d_y, row_dot
+
+    d_y, row_dot = _over_used_rows(
+        plan, one_turn,
+        (jnp.zeros_like(y), jnp.zeros((y.shape[0],), jnp.float32)))
+    d_gates = jnp.where(plan.pair_held, row_dot[plan.pair_row], 0.0)
+    return d_y, d_gates.astype(gates.dtype), None
 
 
 combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)

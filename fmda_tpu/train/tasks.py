@@ -73,13 +73,15 @@ class TokenTotals(NamedTuple):
     """A token pass's running sums: each step's mean loss, the tokens
     that counted and those predicted right, and what the expert layers
     counted (``expert_pairs[l, e]``: pairs layer ``l`` computed on held
-    expert ``e``)."""
+    expert ``e``; ``row_tiles_used[l]``: row tiles of its layout that
+    held a group, the ones its row passes visit)."""
 
     loss: jax.Array          # () float32
     tokens: jax.Array        # () int32
     correct: jax.Array       # () int32
     expert_pairs: jax.Array  # (layers, held experts) int32
     dropped: jax.Array       # () int32
+    row_tiles_used: jax.Array  # (layers,) int32
 
 
 class WindowClassification:
@@ -136,7 +138,7 @@ class WindowClassification:
             fbeta=np.asarray(fbeta_sum) / steps,
         ), np.asarray(confusion_total, np.int64)
 
-    def publish(self, totals: StepTotals, phase: str) -> None:
+    def publish(self, totals: StepTotals, phase: str, steps: int) -> None:
         """Nothing beyond what the step loop counts itself."""
 
     # -- inside the compiled step ---------------------------------------------
@@ -213,10 +215,11 @@ class NextToken:
     def zero_totals(self) -> TokenTotals:
         mc = self.model_cfg
         zero = np.zeros((), np.int32)
+        layers = len(mc.layer_layout)
         return TokenTotals(
             np.zeros((), np.float32), zero, zero,
-            np.zeros((len(mc.layer_layout), mc.experts_held[1]), np.int32),
-            zero)
+            np.zeros((layers, mc.experts_held[1]), np.int32), zero,
+            np.zeros((layers,), np.int32))
 
     def epoch_metrics(self, totals: Optional[TokenTotals], steps: int
                       ) -> Tuple[EpochMetrics, np.ndarray]:
@@ -229,12 +232,21 @@ class NextToken:
             loss=float(totals.loss) / steps, accuracy=accuracy,
             hamming=1.0 - accuracy, fbeta=np.zeros(0)), confusion
 
-    def publish(self, totals: TokenTotals, phase: str) -> None:
+    def publish(self, totals: TokenTotals, phase: str, steps: int) -> None:
         """The pass's token and routing counts, from the drained totals
-        (docs/observability.md "Spans and scopes")."""
+        of its ``steps`` steps (docs/observability.md "Spans and
+        scopes")."""
         from fmda_tpu.obs.registry import default_registry
+        from fmda_tpu.ops.moe import layout_tiles
 
         reg = default_registry()
+        tc, mc = self.train_cfg, self.model_cfg
+        # a forward pass lays its tokens out once a layer: the whole
+        # batch, or each microbatch of an accumulated train step
+        passes = tc.accum_steps if phase == "train" else 1
+        layout = steps * passes * layout_tiles(
+            tc.batch_size // passes * tc.window * mc.moe_top_k,
+            mc.experts_held[1])
         if phase == "train":
             reg.counter("train_tokens_total").inc(int(totals.tokens))
         reg.counter("moe_pairs_dropped_total").inc(int(totals.dropped))
@@ -244,6 +256,11 @@ class NextToken:
                 int(pairs.sum()))
             reg.gauge("moe_expert_pairs_max", **labels).set(
                 int(pairs.max()))
+            # used / layout: the share of the row layout the layer's row
+            # passes touched (1.0: every tile, as if they were unbounded)
+            reg.counter("moe_row_tiles_used_total", **labels).inc(
+                int(totals.row_tiles_used[layer]))
+            reg.counter("moe_row_tiles_layout_total", **labels).inc(layout)
 
     # -- inside the compiled step ---------------------------------------------
 
@@ -270,7 +287,7 @@ class NextToken:
     def step_values(self, loss, aux, batch: Batch) -> TokenTotals:
         tokens, correct, stats = aux
         return TokenTotals(loss, tokens, correct, stats.expert_pairs,
-                           stats.dropped)
+                           stats.dropped, stats.row_tiles_used)
 
 
 def task_class(model_cfg: ModelConfig):

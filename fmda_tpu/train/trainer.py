@@ -620,7 +620,7 @@ class Trainer:
         # the one place the host waits for the device
         with span(phase + "_pass_drain"):
             drained = jax.device_get(totals)
-            self.task.publish(drained, phase)
+            self.task.publish(drained, phase, step_no)
         return (state,) + self.task.epoch_metrics(drained, step_no)
 
     def _warn_if_norm_drifted(self, dataset: ChunkDataset) -> None:

@@ -24,18 +24,24 @@ def _weights(seed=0):
         w_down=0.2 * jax.random.normal(ks[5], (E, F, D)))
 
 
-def _dense(w, first, count, top_k=K):
-    """The layer as its equations read: a loop over the held experts."""
-    p = jax.nn.softmax(w["h"] @ w["router"], -1)
-    top, idx = jax.lax.top_k(p, top_k)
-    g = top / top.sum(-1, keepdims=True)
+def _dense_given(w, gates, experts, first, count):
+    """The layer as its equations read, for a routing that is given: a
+    loop over the held experts."""
     out = jnp.zeros_like(w["u"])
     for e in range(first, first + count):
-        ge = jnp.sum(jnp.where(idx == e, g, 0.0), -1)
+        ge = jnp.sum(jnp.where(experts == e, gates, 0.0), -1)
         out += ge[:, None] * (
             (jax.nn.relu(w["u"] @ w["w_gate"][e]) * (w["u"] @ w["w_up"][e]))
             @ w["w_down"][e])
     return out
+
+
+def _dense(w, first, count, top_k=K):
+    """... and with the routing computed as its equations read."""
+    p = jax.nn.softmax(w["h"] @ w["router"], -1)
+    top, idx = jax.lax.top_k(p, top_k)
+    return _dense_given(w, top / top.sum(-1, keepdims=True), idx, first,
+                        count)
 
 
 def _layer(w, first, count, impl="jnp", top_k=K):
@@ -153,3 +159,193 @@ def test_column_tiles_divide_and_fit():
     assert _column_tile(2560, 768) == 384
     assert _column_tile(768, 2560) == 1280
     assert _column_tile(32, 16) == 16
+
+
+# -- the row passes: bounded at n_used, one pass for both cotangents ---------
+
+def _routing(kind):
+    """``(experts (T, K), experts_held)`` for the routings a row pass must
+    be right under; ``K`` distinct experts a token."""
+    t = np.arange(T)
+    if kind == "even":
+        return np.stack([t % E, (t + 1) % E], 1), (2, 4)
+    if kind == "one_expert_takes_most":
+        return np.stack([np.full(T, 3), np.where(t % 8 == 0, 4, 7)], 1), (2, 4)
+    if kind == "an_expert_without_a_pair":
+        return np.stack([2 + t % 2, 5 + t % 3], 1), (2, 4)  # never expert 4
+    if kind == "every_pair_held":
+        # 17, 17, 33 and 61 pairs: 2 + 2 + 3 + 4 = 11 of the layout's 12
+        # tiles, the most a routing can use (the layout allows a spare
+        # tile a group, and the groups' remainders cannot all be one)
+        pairs = [(3, 2)] * 33 + [(3, 0)] * 14 + [(3, 1)] * 14 + [(0, 1)] * 3
+        return np.asarray(pairs), (0, 4)
+    if kind == "no_pair_held":
+        return np.stack([t % 2, 6 + t % 2], 1), (2, 4)
+    if kind == "the_last_turn_moved_back":
+        # six held experts with 17, 17, 17, 17, 17 and 43 pairs: 13 of 14
+        # tiles, 224 rows that turns of 64 do not divide, so the fourth
+        # turn starts at row 160 and visits rows 160-191 again
+        pairs = ([(6, 1)] * 9 + [(6, 2)] * 9 + [(6, 3)] * 9 + [(6, 4)] * 8
+                 + [(6, 5)] * 8 + [(1, 2)] * 4 + [(1, 3)] * 4 + [(2, 4)] * 4
+                 + [(3, 5)] * 4 + [(4, 5)] * 5)
+        return np.asarray(pairs), (1, 6)
+    raise ValueError(kind)
+
+
+ROUTINGS = ["even", "one_expert_takes_most", "an_expert_without_a_pair",
+            "every_pair_held", "no_pair_held", "the_last_turn_moved_back"]
+
+
+def _plan(kind):
+    experts, held = _routing(kind)
+    experts = jnp.asarray(experts, jnp.int32)
+    return experts, held, moe.plan_dispatch(
+        experts, held, moe.default_row_tile(T * K))
+
+
+def _plain_gather(u, plan):
+    return jnp.where(plan.row_valid[:, None], u[plan.row_pair // K], 0)
+
+
+def _plain_combine(y, gates, plan):
+    out = jnp.zeros((T, y.shape[1]), jnp.float32)
+    for s in range(K):
+        w = jnp.where(plan.pair_held[:, s], gates[:, s], 0.0)
+        out = out + w[:, None] * y[plan.pair_row[:, s]]
+    return out
+
+
+def test_the_routings_are_what_their_names_say():
+    tile = moe.default_row_tile(T * K)
+    sizes = {kind: np.asarray(_plan(kind)[2].group_sizes)
+             for kind in ROUTINGS}
+    used = {kind: int(_plan(kind)[2].n_used[0]) for kind in ROUTINGS}
+    n_tiles = moe.layout_tiles(T * K, 4)
+    assert sizes["one_expert_takes_most"][1] == T
+    assert sizes["an_expert_without_a_pair"][2] == 0
+    assert sizes["every_pair_held"].sum() == T * K
+    assert used["every_pair_held"] == n_tiles - 1 == 11
+    assert sizes["no_pair_held"].sum() == 0 and used["no_pair_held"] == 4
+    assert used["even"] < n_tiles
+    assert used["the_last_turn_moved_back"] == 13
+    assert moe.layout_tiles(T * K, 6) * tile == 224
+    assert 224 % (moe._TILES_A_TURN * tile) != 0
+    assert all(_plan(kind)[2].tile == tile for kind in ROUTINGS)
+
+
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_gather_rows_and_its_transpose_match_the_plain_gather(kind):
+    _, _, plan = _plan(kind)
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    u = jax.random.normal(ks[0], (T, D))
+    d_rows = jax.random.normal(ks[1], (plan.row_pair.shape[0], D))
+    got, vjp = jax.vjp(lambda u: moe.gather_rows(u, plan), u)
+    want, plain_vjp = jax.vjp(lambda u: _plain_gather(u, plan), u)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(vjp(d_rows)[0], plain_vjp(d_rows)[0],
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_combine_rows_and_its_backward_match_the_plain_sum(kind):
+    _, _, plan = _plan(kind)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    y = jax.random.normal(ks[0], (plan.row_pair.shape[0], D))
+    gates = jax.nn.softmax(jax.random.normal(ks[1], (T, K)), -1)
+    d_out = jax.random.normal(ks[2], (T, D))
+    got, vjp = jax.vjp(lambda y, g: moe.combine_rows(y, g, plan), y, gates)
+    want, plain_vjp = jax.vjp(lambda y, g: _plain_combine(y, g, plan),
+                              y, gates)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    (d_y, d_gates), (want_y, want_gates) = vjp(d_out), plain_vjp(d_out)
+    np.testing.assert_allclose(d_y, want_y, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(d_gates, want_gates, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "interpret"])
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_layer_gradients_match_the_dense_loop_under_a_given_routing(
+        kind, impl):
+    experts, (first, count), _ = _plan(kind)
+    w = _weights(6)
+    gates = jax.nn.softmax(w["h"][:, :K], -1)
+    held = slice(first, first + count)
+    keys = ["u", "w_gate", "w_up", "w_down"]
+
+    def layer(gates, *a):
+        p = dict(zip(keys, a))
+        return jnp.sum(moe.expert_layer(
+            p["u"], gates, experts, p["w_gate"][held], p["w_up"][held],
+            p["w_down"][held], experts_held=(first, count),
+            impl=impl)[0] ** 2)
+
+    def dense(gates, *a):
+        return jnp.sum(_dense_given(
+            dict(zip(keys, a)), gates, experts, first, count) ** 2)
+
+    args = [gates] + [w[k] for k in keys]
+    with jax.default_matmul_precision("highest"):
+        want = jax.value_and_grad(dense, range(len(args)))(*args)
+        got = jax.value_and_grad(layer, range(len(args)))(*args)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()) + 1e-7)
+
+
+@pytest.mark.parametrize("kind", ["even", "one_expert_takes_most",
+                                  "no_pair_held"])
+def test_rows_past_the_used_tiles_are_never_read(kind):
+    """Poison: NaN in every row past ``n_used * tile`` of what the row
+    passes are handed changes nothing, and nothing they return is NaN."""
+    _, _, plan = _plan(kind)
+    rows = plan.row_pair.shape[0]
+    past = (jnp.arange(rows) >= plan.n_used[0] * plan.tile)[:, None]
+    assert bool(past.any())
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    y = jax.random.normal(ks[0], (rows, D))
+    gates = jax.nn.softmax(jax.random.normal(ks[1], (T, K)), -1)
+    d_out = jax.random.normal(ks[2], (T, D))
+    d_rows = jax.random.normal(ks[3], (rows, D))
+
+    def both(y, d_rows):
+        out, vjp = jax.vjp(lambda y, g: moe.combine_rows(y, g, plan),
+                           y, gates)
+        d_u = jax.vjp(lambda u: moe.gather_rows(u, plan),
+                      jnp.zeros((T, D)))[1](d_rows)[0]
+        return (out,) + vjp(d_out) + (d_u,)
+
+    clean = both(y, d_rows)
+    poisoned = both(jnp.where(past, jnp.nan, y),
+                    jnp.where(past, jnp.nan, d_rows))
+    for a, b in zip(clean, poisoned):
+        assert bool(jnp.isfinite(b).all())
+        np.testing.assert_array_equal(a, b)
+    assert float(jnp.abs(jnp.where(past, clean[1], 0.0)).max()) == 0.0
+
+
+def test_one_compiled_program_serves_routings_of_different_n_used():
+    w = _weights(8)
+    gates = jax.nn.softmax(w["h"][:, :K], -1)
+
+    @jax.jit
+    def step(experts, gates, u):
+        def loss(gates, u):
+            m, plan = moe.expert_layer(
+                u, gates, experts, w["w_gate"][2:6], w["w_up"][2:6],
+                w["w_down"][2:6], experts_held=(2, 4))
+            return jnp.sum(m ** 2), plan.n_used[0]
+        (value, n_used), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(gates, u)
+        return value, n_used, grads
+
+    seen = []
+    for kind in ("even", "one_expert_takes_most"):
+        experts = jnp.asarray(_routing(kind)[0], jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            value, n_used, _ = step(experts, gates, w["u"])
+            want = jnp.sum(_dense_given(w, gates, experts, 2, 4) ** 2)
+        np.testing.assert_allclose(value, want, rtol=1e-4, atol=1e-6)
+        seen.append(int(n_used))
+    assert seen[0] != seen[1]
+    assert step._cache_size() == 1

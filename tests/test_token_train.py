@@ -101,6 +101,49 @@ def test_pass_totals_are_bit_for_bit_the_fold_over_single_step(train):
                                       "eval_step": int(not train)}
 
 
+@pytest.mark.parametrize("train,accum", [(True, 1), (False, 1), (True, 2)])
+def test_a_pass_publishes_the_row_tiles_its_layers_used(train, accum):
+    """``TokenTotals.row_tiles_used`` is the sum over a pass of each
+    layer's ``plan.n_used`` (told here from the pairs each held expert
+    got: a group fills whole tiles, an empty one keeps one), and the
+    drain publishes it beside the layout's tiles: whole batches, or a
+    train step's microbatches."""
+    from fmda_tpu.ops.moe import default_row_tile, layout_tiles
+
+    trainer = Trainer(_model(), _train(accum_steps=accum))
+    ds = trainer.task.dataset(_source())
+    batches = [b for c in (0, 1, 2) for b in trainer._chunk_batches(ds, c)]
+    state = trainer.init_state(jax.random.PRNGKey(0))
+    rng = jax.random.PRNGKey(1) if train else None
+    passes = accum if train else 1
+    pairs = 2 * SEQ * 2 // passes                  # a forward pass's
+    tile = default_row_tile(pairs)
+
+    want, st = np.zeros(2, np.int64), _copy(state)
+    for b in batches:
+        st, vals = trainer.single_step(st, b, rng)
+        if passes == 1:  # n_used from the group sizes, layer by layer
+            sizes = np.asarray(vals.expert_pairs)
+            np.testing.assert_array_equal(
+                vals.row_tiles_used,
+                np.maximum(-(-sizes // tile), 1).sum(axis=1))
+        want += np.asarray(vals.row_tiles_used)
+
+    phase = "train" if train else "eval"
+    counters = [[default_registry().counter(name, layer=str(layer),
+                                            phase=phase)
+                 for layer in (0, 1)]
+                for name in ("moe_row_tiles_used_total",
+                             "moe_row_tiles_layout_total")]
+    before = [[c.value for c in row] for row in counters]
+    trainer._run_batches(_copy(state), (batches,), rng, train)
+    used, layout = ([c.value - b for c, b in zip(row, was)]
+                    for row, was in zip(counters, before))
+    assert used == want.tolist()
+    assert layout == [len(batches) * passes * layout_tiles(pairs, 2)] * 2
+    assert all(0 < u <= total for u, total in zip(used, layout))
+
+
 @pytest.mark.parametrize("train", [True, False])
 def test_grouped_token_pass_is_the_fold_over_single_step(train):
     """A decoder this small is grouped like any other family (the rule
@@ -128,10 +171,12 @@ def test_grouped_token_pass_is_the_fold_over_single_step(train):
 
 
 #: sha256 (first 16 hex digits) of the lowered text of this file's tiny
-#: decoder's single train and eval programs at PR 29's parent (98d5033),
-#: jax 0.9.0.  Regenerate with the snippet in the test below after a
-#: deliberate change to the decoder, its task or the step function.
-PARENT_STEP_TEXT = ("abc7ef3cf54cbba3", "7b58db5aa61a8736")
+#: decoder's single train and eval programs, jax 0.9.0: PR 29's parent's
+#: (98d5033) until PR 31 changed the expert layer's row passes and the
+#: totals' leaves, PR 31's since.  Regenerate with the snippet in the
+#: test below after a deliberate change to the decoder, its task or the
+#: step function.
+PARENT_STEP_TEXT = ("3e53942d3a7820d2", "dab4756a66f47451")
 
 
 def test_a_solo_decoder_runs_the_parents_programs(monkeypatch):

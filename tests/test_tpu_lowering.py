@@ -69,3 +69,51 @@ def test_grouped_product_kernels_compile_at_16_experts_of_2560_by_768(
         _shape(one_chip, (1,), jnp.int32)).compile()
     text = compiled.as_text()
     assert "moe_gmm" in text and "moe_tgmm" in text
+
+
+def test_expert_layer_row_passes_compile_bounded_and_in_place(one_chip):
+    """The expert layer's forward and backward at T 8,192, k 6, D 2,560,
+    16 of 64 experts held: both row passes are ``while`` loops whose
+    53,248-row buffer is updated in place (never copied, in a turn or
+    around the loop), and the combine's backward gathers no (8192, 2560)
+    block: the gates' gradient comes off the row pass."""
+    import re
+
+    from fmda_tpu.ops import moe
+
+    t, k, d, f, count = 8192, 6, 2560, 768, 16
+    rows = moe.layout_rows(t * k, count, moe.default_row_tile(t * k))
+
+    def step(u, gates, experts, w_gate, w_up, w_down):
+        def loss(u, gates, w_gate, w_up, w_down):
+            m, _ = moe.expert_layer(
+                u, gates, experts, w_gate, w_up, w_down,
+                experts_held=(0, count), impl="pallas")
+            return m.astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, (0, 1, 2, 3, 4))(
+            u, gates, w_gate, w_up, w_down)
+
+    text = jax.jit(step).lower(
+        _shape(one_chip, (t, d), BF16),
+        _shape(one_chip, (t, k), jnp.float32),
+        _shape(one_chip, (t, k), jnp.int32),
+        _shape(one_chip, (count, d, f), jnp.float32),
+        _shape(one_chip, (count, d, f), jnp.float32),
+        _shape(one_chip, (count, f, d), jnp.float32)).compile().as_text()
+    buffer = rf"= bf16\[{rows},{d}\]\S* "
+    lines = text.splitlines()
+    # the row passes are loops, in both directions, and each turn updates
+    # the row buffer in place (the update's output aliases operand 0)
+    for loop in ("jvp(moe_dispatch)/while/body/",
+                 "transpose(jvp(moe_combine))/while/body/"):
+        updates = [line for line in lines if loop in line and re.search(
+            buffer + r"(dynamic-update-slice|fusion)\(", line)]
+        assert updates, loop
+        for line in updates:
+            assert '"aliasing_operands":{"lists":[{"indices":["0",' in line
+    # ... and nothing ever copies it
+    assert not [line for line in lines if re.search(buffer + r"copy\(", line)]
+    # no (T, D) gather is left in the combine's backward
+    assert not [line for line in lines
+                if "transpose(jvp(moe_combine))" in line and "gather" in line
+                and re.search(rf"= \w+\[{t},{d}\]", line)]
