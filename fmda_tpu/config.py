@@ -448,7 +448,11 @@ class ModelConfig:
     #: One entry per layer (its length is the depth; ``n_layers`` is not
     #: read): 1 = rotary positions on q and k and a causal window of
     #: ``sliding_window`` keys, 0 = no positional encoding and the whole
-    #: causal past.
+    #: causal past, 2 = a learned-sparse layer: rotary positions and an
+    #: RMSNorm per head on q and k, an indexer that picks ``indexer_topk``
+    #: keys of the causal past a query (ops/sparse_attention.py), and the
+    #: router placed *after* attention, reading the expert block's
+    #: normalised input.
     layer_layout: Tuple[int, ...] = ()
     sliding_window: int = 4096
     rope_theta: float = 10000.0
@@ -460,6 +464,14 @@ class ModelConfig:
     moe_top_k: int = 0
     #: Hidden width of one expert (``hidden -> moe_ffn_size -> hidden``).
     moe_ffn_size: int = 0
+    #: The gate's activation in an expert's gated product: "relu"
+    #: (ReGLU) or "silu" (SwiGLU).
+    hidden_act: str = "relu"
+    #: Layout 2's indexer: ``indexer_heads`` query heads of
+    #: ``indexer_head_dim`` on one key head, and the keys a query keeps.
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_topk: int = 0
     #: ``(first, count)``: the experts this chip holds and computes
     #: (ops/moe.py).  ``(0, moe_experts)`` is the uncut layer.
     experts_held: Tuple[int, int] = (0, 0)

@@ -8,7 +8,9 @@ that part, for one chip::
 
     p   = softmax(h @ W_r)                      over all E experts
     S_t = top-k of p_t ;  g_te = p_te / sum_{e' in S_t} p_te'
-    m_t = sum_{e in S_t, e held} g_te * ( relu(u_t @ Wg_e) * (u_t @ Wu_e) ) @ Wd_e
+    m_t = sum_{e in S_t, e held} g_te * ( act(u_t @ Wg_e) * (u_t @ Wu_e) ) @ Wd_e
+
+(``act``: relu, or silu where the model's configuration says so).
 
 ``experts_held = (first, count)`` names the held experts
 ``first .. first + count - 1``; the gates are normalised over the whole
@@ -27,7 +29,7 @@ grouped products skip the tiles no pair fell into
 - ``moe_route``: router product, softmax, top-k, gate normalisation;
 - ``moe_dispatch``: sort the held pairs by expert, pad each group to
   whole row tiles, gather the token rows into that layout;
-- ``moe_experts``: the three grouped products and the ReGLU between;
+- ``moe_experts``: the three grouped products and the gated unit between;
 - ``moe_combine``: gather each token's rows back and sum them by gate.
 
 Both gathers have hand-written transposes that are gathers too (a row
@@ -259,6 +261,10 @@ def _combine_rows_bwd(residuals, d_out):
 combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
+#: The gate's activation by its name in a model's configuration.
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
 def kernel_impl(use_pallas: bool) -> str:
     """``"pallas"`` where the grouped-product kernels were asked for and
     can run (a TPU backend), else ``"jnp"`` with the fallback counted."""
@@ -282,6 +288,7 @@ def expert_layer(
     *,
     experts_held: Tuple[int, int],
     impl: str = "jnp",
+    act: str = "relu",
 ) -> Tuple[jax.Array, Plan]:
     """The held experts' part of the layer's output, and the plan it was
     computed under (whose ``group_sizes`` and ``dropped`` the caller
@@ -289,7 +296,9 @@ def expert_layer(
 
     ``u`` (T, D) in the compute dtype; ``gates``/``experts`` (T, k) from
     :func:`route`; ``w_gate``/``w_up`` (count, D, F) and ``w_down``
-    (count, F, D), float32, the held experts' matrices in order.
+    (count, F, D), float32, the held experts' matrices in order.  ``act``
+    is the gate's activation, ``"relu"`` or ``"silu"`` (the grouped
+    products are the same).
     """
     from fmda_tpu.ops.pallas_moe import grouped_matmul
 
@@ -304,6 +313,6 @@ def expert_layer(
         tables = (plan.tile_expert, plan.n_used, tile, impl)
         gate = grouped_matmul(rows, w_gate, *tables)
         up = grouped_matmul(rows, w_up, *tables)
-        y = grouped_matmul(jax.nn.relu(gate) * up, w_down, *tables)
+        y = grouped_matmul(ACTIVATIONS[act](gate) * up, w_down, *tables)
     with jax.named_scope("moe_combine"):
         return combine_rows(y, gates, plan), plan

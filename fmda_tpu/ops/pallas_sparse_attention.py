@@ -1,0 +1,546 @@
+"""Pallas TPU kernels of learned-sparse attention: index scores, an exact
+per-query top-k as a mask, and attention over the picked keys alone.
+
+A learned-sparse layer (:mod:`fmda_tpu.ops.sparse_attention`) lets a
+small *indexer* choose, for every query, the ``topk`` keys of its causal
+past that the heads then attend over.  Three kernels, each under its own
+scope in the caller:
+
+- ``sparse_index`` — ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])``
+  for one chunk of query rows against all keys, blocks above the causal
+  diagonal skipped.  Grid ``(B, rows / bq, T / bk)``; per block one
+  ``(bq, Di) x (Di, bk)`` product an indexer head, float32 accumulation,
+  the relu, the weight and the sum over heads on the VPU.
+- ``sparse_select`` — for ``rows`` query rows at a time, the exact
+  ``min(t + 1, topk)`` largest scores of each row's causal prefix, ties
+  to the lower key, written as an int8 mask.  No sort: the scores become
+  order-preserving int32 keys in VMEM, the k-th largest key is found bit
+  by bit (32 counting passes over the prefix), the keys equal to it are
+  cut at the position that makes the count exact (one more counting pass
+  a bit of the position).  A pass is a compare and an add an element, and
+  it walks only the column tiles at or below the row block's diagonal.
+- ``sparse_fwd`` / ``sparse_bwd_dkv`` / ``sparse_bwd_dq`` — the flash
+  recurrence of :mod:`fmda_tpu.ops.pallas_attention` with the mask in
+  place of a rule of position.  The picks of a learned indexer fall
+  anywhere in the prefix, so no block below the diagonal is empty and
+  none is skipped: this is a dense causal pass that zeroes the pairs not
+  picked (4.3x the picked pairs' products at 16,384 tokens and 2,048
+  keys; PERF.md section 7 has what a gathered pass would cost instead).
+  The query heads of one key-value head ride one grid step together
+  (``(group, bq, D)`` query block), so a key, value and mask block is
+  fetched once a group, and the group's ``dk`` / ``dv`` accumulate in
+  one scratch.  ``lse`` and ``delta`` ride as ``(T, 128)`` tiles whose
+  lane ``l`` holds head ``l // (128 / group)``.
+
+One mask serves every head.  Support envelope
+(:func:`sparse_supported`): ``T`` a multiple of 128, ``group`` a divisor
+of 128, ``D <= 512``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from fmda_tpu.compat import CompilerParams
+from fmda_tpu.ops.sparse_attention import _INT_MIN, sortable_key
+
+_NEG = -1e30
+#: Rows of queries ``sparse_select`` ranks at a time (one int8 tile).
+SELECT_ROWS = 32
+#: Columns a counting pass reads at a time.
+SELECT_WIDTH = 2048
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _largest_dividing(n: int, candidates) -> int:
+    for c in candidates:
+        if n % c == 0:
+            return c
+    raise ValueError(f"{n} is not a multiple of {candidates[-1]}")
+
+
+def blocks_for(seq_len: int) -> Tuple[int, int]:
+    """``(query rows, keys)`` of a block of the attention and index
+    kernels at this length."""
+    return (_largest_dividing(seq_len, (256, 128)),
+            _largest_dividing(seq_len, (512, 256, 128)))
+
+
+def sparse_supported(seq_len: int, group: int, d_head: int) -> bool:
+    """Shape gate for the kernels (module docstring)."""
+    return (seq_len % 128 == 0 and group > 0 and 128 % group == 0
+            and d_head <= 512)
+
+
+def _last_key_block(row0, qi, bq: int, bk: int):
+    """The last key block holding a key visible to query block ``qi`` of
+    a chunk that begins at row ``row0``."""
+    return (row0 + (qi + 1) * bq - 1) // bk
+
+
+# ---------------------------------------------------------------------------
+# index scores
+# ---------------------------------------------------------------------------
+
+
+def _index_kernel(row0_ref, q_ref, k_ref, w_ref, o_ref, *, bq: int, bk: int):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki <= _last_key_block(row0_ref[0], qi, bq, bk))
+    def _compute():
+        k = k_ref[0]
+        w = w_ref[0]
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for j in range(q_ref.shape[1]):
+            s = jax.lax.dot_general(
+                q_ref[0, j], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = acc + w[:, j:j + 1] * jnp.maximum(s, 0.0)
+        o_ref[0] = acc
+
+
+def index_scores(q_idx: jax.Array, k_idx: jax.Array, w_idx: jax.Array,
+                 row0: jax.Array, *, interpret: bool = False) -> jax.Array:
+    """Index scores of a chunk of query rows: ``q_idx`` (B, Hi, C, Di)
+    and ``w_idx`` (B, C, Hi) float32 are the chunk's, ``k_idx`` (B, T, Di)
+    every key's, ``row0`` (1,) int32 the chunk's first row.  Returns
+    (B, C, T) float32; entries above the causal diagonal are not written
+    (the selection never reads them)."""
+    b, hi, c, di = q_idx.shape
+    t = k_idx.shape[1]
+    bq, bk = blocks_for(c)[0], blocks_for(t)[1]
+
+    def k_index(bi, qi, ki, r0):
+        return (bi, jnp.minimum(ki, _last_key_block(r0[0], qi, bq, bk)), 0)
+
+    return pl.pallas_call(
+        functools.partial(_index_kernel, bq=bq, bk=bk),
+        name="sparse_index",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, c // bq, t // bk),
+            in_specs=[
+                pl.BlockSpec((1, hi, bq, di),
+                             lambda bi, qi, ki, r0: (bi, 0, qi, 0)),
+                pl.BlockSpec((1, bk, di), k_index),
+                pl.BlockSpec((1, bq, hi),
+                             lambda bi, qi, ki, r0: (bi, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, bq, bk), lambda bi, qi, ki, r0: (bi, qi, ki)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, c, t), jnp.float32),
+        compiler_params=CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(row0, q_idx, k_idx, w_idx)
+
+
+# ---------------------------------------------------------------------------
+# selection
+# ---------------------------------------------------------------------------
+
+
+def _select_kernel(row0_ref, s_ref, m_ref, key_scr, *, topk: int,
+                   rows: int, width: int, seq_len: int):
+    r0 = row0_ref[0] + pl.program_id(1) * rows
+    row = r0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    want = jnp.minimum(row + 1, topk)
+    # column tiles that hold a key some row of the block may see
+    n_tiles = (r0 + rows + width - 1) // width
+    int_min = jnp.int32(_INT_MIN)
+
+    def tile(j):
+        c0 = pl.multiple_of(j * width, width)
+        col = c0 + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+        return c0, col
+
+    def fill(j, carry):
+        c0, col = tile(j)
+        key = sortable_key(s_ref[0, :, pl.ds(c0, width)])
+        key_scr[:, pl.ds(c0, width)] = jnp.where(col <= row, key, int_min)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, fill, 0)
+
+    def count(pred):
+        """Per row: the keys of the prefix for which ``pred(key, col)``."""
+        def body(j, acc):
+            c0, col = tile(j)
+            hit = pred(key_scr[:, pl.ds(c0, width)], col)
+            return acc + jnp.sum(hit.astype(jnp.int32), axis=-1,
+                                 keepdims=True)
+        return jax.lax.fori_loop(
+            0, n_tiles, body, jnp.zeros((rows, 1), jnp.int32))
+
+    # the want-th largest key, bit by bit from the top, in the offset
+    # binary whose unsigned order is the keys' signed order
+    def key_bit(i, prefix):
+        cand = prefix | jnp.left_shift(jnp.int32(1), 31 - i)
+        enough = count(lambda key, _: key >= (cand ^ int_min)) >= want
+        return jnp.where(enough, cand, prefix)
+
+    tau = jax.lax.fori_loop(
+        0, 32, key_bit, jnp.zeros((rows, 1), jnp.int32)) ^ int_min
+    # of the keys equal to it, the lowest `short` columns are taken: the
+    # column of the short-th, bit by bit
+    short = want - count(lambda key, _: key > tau)
+    bits = max(seq_len - 1, 1).bit_length()
+
+    def col_bit(i, prefix):
+        cand = prefix | jnp.left_shift(jnp.int32(1), bits - 1 - i)
+        below = count(lambda key, col: (key == tau) & (col < cand))
+        return jnp.where(below < short, cand, prefix)
+
+    last_tie = jax.lax.fori_loop(
+        0, bits, col_bit, jnp.zeros((rows, 1), jnp.int32))
+
+    def write(j, carry):
+        c0, col = tile(j)
+        key = key_scr[:, pl.ds(c0, width)]
+        keep = (key > tau) | ((key == tau) & (col <= last_tie))
+        m_ref[0, :, pl.ds(c0, width)] = jnp.where(keep, 1, 0).astype(
+            m_ref.dtype)
+        return carry
+
+    def clear(j, carry):
+        c0, _ = tile(j)
+        m_ref[0, :, pl.ds(c0, width)] = jnp.zeros((rows, width), m_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, write, 0)
+    jax.lax.fori_loop(n_tiles, seq_len // width, clear, 0)
+
+
+def select_topk(scores: jax.Array, row0: jax.Array, topk: int, *,
+                interpret: bool = False) -> jax.Array:
+    """``scores`` (B, C, T) float32 of the query rows ``row0 .. row0 + C
+    - 1`` -> (B, C, T) int8: 1 on the ``min(t + 1, topk)`` largest scores
+    of row ``t``'s keys ``s <= t`` (ties to the lower ``s``), 0
+    elsewhere."""
+    b, c, t = scores.shape
+    rows = min(SELECT_ROWS, c)
+    width = min(SELECT_WIDTH, t)
+    if c % rows or t % width:
+        raise ValueError(f"selection needs rows % {rows} == 0 and keys % "
+                         f"{width} == 0, got {c} x {t}")
+    spec = pl.BlockSpec((1, rows, t), lambda bi, ri, r0: (bi, ri, 0))
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, rows=rows,
+                          width=width, seq_len=t),
+        name="sparse_select",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, c // rows),
+            in_specs=[spec],
+            out_specs=spec,
+            scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, c, t), jnp.int8),
+        compiler_params=CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(row0, scores)
+
+
+# ---------------------------------------------------------------------------
+# attention over the picked keys
+# ---------------------------------------------------------------------------
+
+
+def _head_column(packed, h: int, group: int):
+    """(rows, 1): head ``h``'s value from a (rows, 128) tile whose lane
+    ``l`` holds head ``l // (128 / group)``."""
+    at = h * (128 // group)
+    return packed[:, at:at + 1]
+
+
+def _pack_heads(columns, rows: int):
+    """The inverse: ``group`` (rows, 1) columns -> one (rows, 128) tile."""
+    group = len(columns)
+    head_of_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 128), 1) // (128 // group)
+    out = jnp.zeros((rows, 128), jnp.float32)
+    for h, column in enumerate(columns):
+        out = jnp.where(head_of_lane == h, column, out)
+    return out
+
+
+def _masked_scores(q, k, keep):
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    return jnp.where(keep, s, _NEG), scale
+
+
+def _in_band(qi, ki, bq: int, bk: int):
+    """Block (qi, ki) holds a key at or below some query's position."""
+    return ki * bk < (qi + 1) * bq
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, bq: int, bk: int, n_k: int):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    group = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(_in_band(qi, ki, bq, bk))
+    def _compute():
+        keep = mask_ref[0].astype(jnp.int32) != 0
+        k, v = k_ref[0], v_ref[0]
+        for h in range(group):
+            s, _ = _masked_scores(q_ref[0, h], k, keep)
+            m_prev = m_scr[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # exactly zero off the picked keys, also where a row has
+            # seen none yet and m_new is still _NEG
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            l_new = l_scr[h][:, :1] * corr + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    @pl.when(ki == n_k - 1)
+    def _finalize():
+        columns = []
+        for h in range(group):
+            l = l_scr[h][:, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
+            columns.append(jnp.where(
+                l == 0.0, _NEG, m_scr[h][:, :1] + jnp.log(l_safe)))
+        lse_ref[0] = _pack_heads(columns, bq)
+
+
+def _p_and_ds(q, do, k, v, keep, lse, delta):
+    s, scale = _masked_scores(q, k, keep)
+    p = jnp.where(keep, jnp.exp(s - lse), 0.0)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * scale
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_scr, dv_scr, *, bq: int, bk: int,
+                n_q: int):
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    group = q_ref.shape[1]
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(_in_band(qi, ki, bq, bk))
+    def _compute():
+        keep = mask_ref[0].astype(jnp.int32) != 0
+        k, v = k_ref[0], v_ref[0]
+        lse, delta = lse_ref[0], delta_ref[0]
+        for h in range(group):
+            q, do = q_ref[0, h], do_ref[0, h]
+            p, ds = _p_and_ds(q, do, k, v, keep,
+                              _head_column(lse, h, group),
+                              _head_column(delta, h, group))
+            dv_scr[...] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[...] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(qi == n_q - 1)
+    def _flush():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dq_scr, *, bq: int, bk: int, n_k: int):
+    qi, ki = pl.program_id(1), pl.program_id(2)
+    group = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when(_in_band(qi, ki, bq, bk))
+    def _compute():
+        keep = mask_ref[0].astype(jnp.int32) != 0
+        k, v = k_ref[0], v_ref[0]
+        lse, delta = lse_ref[0], delta_ref[0]
+        for h in range(group):
+            _, ds = _p_and_ds(q_ref[0, h], do_ref[0, h], k, v, keep,
+                              _head_column(lse, h, group),
+                              _head_column(delta, h, group))
+            dq_scr[h] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(ki == n_k - 1)
+    def _flush():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _params():
+    return CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _fwd_impl(q, k, v, mask, *, blocks, interpret):
+    """q (BG, group, T, D), k/v (BG, T, D), mask (B, T, T) int8 ->
+    (o like q, lse (BG, T, 128) packed by head)."""
+    bg, group, t, d = q.shape
+    g = bg // mask.shape[0]
+    bq, bk = blocks
+    n_q, n_k = t // bq, t // bk
+
+    def k_block(qi, ki):  # a skipped step re-references the band's last
+        return jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+
+    q_spec = pl.BlockSpec((1, group, bq, d), lambda b, qi, ki: (b, 0, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, bk, d), lambda b, qi, ki: (b, k_block(qi, ki), 0))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, bq=bq, bk=bk, n_k=n_k),
+        name="sparse_fwd",
+        grid=(bg, n_q, n_k),
+        in_specs=[
+            q_spec, kv_spec, kv_spec,
+            pl.BlockSpec((1, bq, bk), lambda b, qi, ki: (
+                b // g, qi, k_block(qi, ki))),
+        ],
+        out_specs=[
+            q_spec,
+            pl.BlockSpec((1, bq, 128), lambda b, qi, ki: (b, qi, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bg, t, 128), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((group, bq, 128), jnp.float32),
+            pltpu.VMEM((group, bq, 128), jnp.float32),
+            pltpu.VMEM((group, bq, d), jnp.float32),
+        ],
+        compiler_params=_params(),
+        interpret=interpret,
+    )(q, k, v, mask)
+
+
+def _bwd_impl(q, k, v, mask, o, lse, do, *, blocks, interpret):
+    bg, group, t, d = q.shape
+    g = bg // mask.shape[0]
+    bq, bk = blocks
+    n_q, n_k = t // bq, t // bk
+    # delta = rowsum(do * o), packed by head like lse
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.repeat(delta.transpose(0, 2, 1), 128 // group, axis=-1)
+
+    def q_block(ki, qi):  # the dK/dV sweep's first query block in band
+        return jnp.maximum(qi, (ki * bk) // bq)
+
+    q_rows = pl.BlockSpec((1, group, bq, d), lambda b, ki, qi: (
+        b, 0, q_block(ki, qi), 0))
+    packed = pl.BlockSpec((1, bq, 128), lambda b, ki, qi: (
+        b, q_block(ki, qi), 0))
+    kv_fixed = pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, bq=bq, bk=bk, n_q=n_q),
+        name="sparse_bwd_dkv",
+        grid=(bg, n_k, n_q),
+        in_specs=[
+            q_rows, kv_fixed, kv_fixed,
+            pl.BlockSpec((1, bq, bk), lambda b, ki, qi: (
+                b // g, q_block(ki, qi), ki)),
+            q_rows, packed, packed,
+        ],
+        out_specs=[kv_fixed, kv_fixed],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_params(),
+        interpret=interpret,
+    )(q, k, v, mask, do, lse, delta)
+
+    def k_block(qi, ki):
+        return jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+
+    q_fixed = pl.BlockSpec((1, group, bq, d), lambda b, qi, ki: (b, 0, qi, 0))
+    packed2 = pl.BlockSpec((1, bq, 128), lambda b, qi, ki: (b, qi, 0))
+    kv_rows = pl.BlockSpec(
+        (1, bk, d), lambda b, qi, ki: (b, k_block(qi, ki), 0))
+    (dq,) = pl.pallas_call(
+        functools.partial(_dq_kernel, bq=bq, bk=bk, n_k=n_k),
+        name="sparse_bwd_dq",
+        grid=(bg, n_q, n_k),
+        in_specs=[
+            q_fixed, kv_rows, kv_rows,
+            pl.BlockSpec((1, bq, bk), lambda b, qi, ki: (
+                b // g, qi, k_block(qi, ki))),
+            q_fixed, packed2, packed2,
+        ],
+        out_specs=[q_fixed],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        scratch_shapes=[pltpu.VMEM((group, bq, d), jnp.float32)],
+        compiler_params=_params(),
+        interpret=interpret,
+    )(q, k, v, mask, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _sparse(q, k, v, mask, blocks, interpret):
+    return _fwd_impl(q, k, v, mask, blocks=blocks, interpret=interpret)[0]
+
+
+def _sparse_fwd(q, k, v, mask, blocks, interpret):
+    o, lse = _fwd_impl(q, k, v, mask, blocks=blocks, interpret=interpret)
+    return o, (q, k, v, mask, o, lse)
+
+
+def _sparse_bwd(blocks, interpret, residuals, do):
+    q, k, v, mask, o, lse = residuals
+    dq, dk, dv = _bwd_impl(q, k, v, mask, o, lse, do, blocks=blocks,
+                           interpret=interpret)
+    return dq, dk, dv, None
+
+
+_sparse.defvjp(_sparse_fwd, _sparse_bwd)
+
+
+def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     mask: jax.Array, *, blocks=None,
+                     interpret: bool = False) -> jax.Array:
+    """Attention of q (B, N, T, D) over the keys of k/v (B, G, T, D)
+    that ``mask`` (B, T, T) int8 marks, one mask for every head; rows of
+    the mask hold nothing above the causal diagonal.  Differentiable in
+    q, k, v."""
+    b, n, t, d = q.shape
+    g = k.shape[1]
+    if n % g or not sparse_supported(t, n // g, d):
+        raise ValueError(
+            f"sparse kernels unsupported for T={t} heads={n}/{g} D={d}; "
+            "gate on sparse_supported()")
+    out = _sparse(q.reshape(b * g, n // g, t, d), k.reshape(b * g, t, d),
+                  v.reshape(b * g, t, d), mask,
+                  tuple(blocks or blocks_for(t)), interpret)
+    return out.reshape(b, n, t, d)

@@ -40,6 +40,7 @@ import numpy as np
 from fmda_tpu.config import ModelConfig, TrainConfig
 from fmda_tpu.data.pipeline import (
     Batch, ChunkDataset, TokenBatches, TokenDataset, WindowBatches)
+from fmda_tpu.models.decoder import SPARSE_LAYOUT
 from fmda_tpu.ops.metrics import multilabel_metrics
 from fmda_tpu.train.losses import (
     chunked_next_token_loss, weighted_bce_sums, weighted_bce_with_logits)
@@ -82,6 +83,20 @@ class TokenTotals(NamedTuple):
     expert_pairs: jax.Array  # (layers, held experts) int32
     dropped: jax.Array       # () int32
     row_tiles_used: jax.Array  # (layers,) int32
+    #: The learned-sparse layers' selection (None in a model without
+    #: one): keys kept as (layers, 2) int32 ``[count >> 16, count &
+    #: 0xffff]`` (:func:`keys_kept_counts` joins them), and the query
+    #: rows they were kept for, (layers,) int32.
+    sparse_keys_kept: Optional[jax.Array] = None
+    sparse_query_rows: Optional[jax.Array] = None
+
+
+def keys_kept_counts(sparse_keys_kept) -> list:
+    """The keys each layer kept, as whole Python numbers, from a
+    :class:`TokenTotals`' split sums (a pass's count outgrows int32:
+    16,384 tokens keep 31 M keys a layer and sequence)."""
+    return [(int(hi) << 16) + int(lo)
+            for hi, lo in np.asarray(sparse_keys_kept, np.int64)]
 
 
 class WindowClassification:
@@ -216,10 +231,15 @@ class NextToken:
         mc = self.model_cfg
         zero = np.zeros((), np.int32)
         layers = len(mc.layer_layout)
-        return TokenTotals(
+        totals = TokenTotals(
             np.zeros((), np.float32), zero, zero,
             np.zeros((layers, mc.experts_held[1]), np.int32), zero,
             np.zeros((layers,), np.int32))
+        if SPARSE_LAYOUT in mc.layer_layout:
+            totals = totals._replace(
+                sparse_keys_kept=np.zeros((layers, 2), np.int32),
+                sparse_query_rows=np.zeros((layers,), np.int32))
+        return totals
 
     def epoch_metrics(self, totals: Optional[TokenTotals], steps: int
                       ) -> Tuple[EpochMetrics, np.ndarray]:
@@ -261,6 +281,18 @@ class NextToken:
             reg.counter("moe_row_tiles_used_total", **labels).inc(
                 int(totals.row_tiles_used[layer]))
             reg.counter("moe_row_tiles_layout_total", **labels).inc(layout)
+        if totals.sparse_keys_kept is None:
+            return
+        # a learned-sparse layer's selection: kept / (the rows' causal
+        # pairs) is the share of the triangle the heads attend over
+        kept = keys_kept_counts(totals.sparse_keys_kept)
+        for layer, layout in enumerate(mc.layer_layout):
+            if layout != SPARSE_LAYOUT:
+                continue
+            labels = dict(layer=str(layer), phase=phase)
+            reg.counter("sparse_keys_kept_total", **labels).inc(kept[layer])
+            reg.counter("sparse_query_rows_total", **labels).inc(
+                int(totals.sparse_query_rows[layer]))
 
     # -- inside the compiled step ---------------------------------------------
 
@@ -287,7 +319,8 @@ class NextToken:
     def step_values(self, loss, aux, batch: Batch) -> TokenTotals:
         tokens, correct, stats = aux
         return TokenTotals(loss, tokens, correct, stats.expert_pairs,
-                           stats.dropped, stats.row_tiles_used)
+                           stats.dropped, stats.row_tiles_used,
+                           stats.keys_kept, stats.query_rows)
 
 
 def task_class(model_cfg: ModelConfig):

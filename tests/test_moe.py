@@ -24,44 +24,53 @@ def _weights(seed=0):
         w_down=0.2 * jax.random.normal(ks[5], (E, F, D)))
 
 
-def _dense_given(w, gates, experts, first, count):
+def _dense_given(w, gates, experts, first, count, act="relu"):
     """The layer as its equations read, for a routing that is given: a
     loop over the held experts."""
+    fn = {"relu": jax.nn.relu, "silu": jax.nn.silu}[act]
     out = jnp.zeros_like(w["u"])
     for e in range(first, first + count):
         ge = jnp.sum(jnp.where(experts == e, gates, 0.0), -1)
         out += ge[:, None] * (
-            (jax.nn.relu(w["u"] @ w["w_gate"][e]) * (w["u"] @ w["w_up"][e]))
+            (fn(w["u"] @ w["w_gate"][e]) * (w["u"] @ w["w_up"][e]))
             @ w["w_down"][e])
     return out
 
 
-def _dense(w, first, count, top_k=K):
+def _dense(w, first, count, top_k=K, act="relu"):
     """... and with the routing computed as its equations read."""
     p = jax.nn.softmax(w["h"] @ w["router"], -1)
     top, idx = jax.lax.top_k(p, top_k)
     return _dense_given(w, top / top.sum(-1, keepdims=True), idx, first,
-                        count)
+                        count, act)
 
 
-def _layer(w, first, count, impl="jnp", top_k=K):
+def _layer(w, first, count, impl="jnp", top_k=K, act="relu"):
     gates, experts = moe.route(w["h"], w["router"], top_k)
     held = slice(first, first + count)
     return moe.expert_layer(
         w["u"], gates, experts, w["w_gate"][held], w["w_up"][held],
-        w["w_down"][held], experts_held=(first, count), impl=impl)
+        w["w_down"][held], experts_held=(first, count), impl=impl, act=act)
 
 
-def test_the_shares_of_four_chips_add_up_to_the_uncut_layer():
-    """The share test: four chips hold two experts each, every one routes
-    over all eight; what every chip computes alike (the router, the
-    gates' normalisation) is counted once, and the four partial outputs
-    add up to the uncut layer's."""
+@pytest.mark.parametrize("act", ["relu", "silu"])
+@pytest.mark.parametrize("shares", [4, 8])
+def test_the_shares_of_four_chips_add_up_to_the_uncut_layer(shares, act):
+    """The share test: the chips of a group (four, or eight) hold their
+    part of the eight experts each, every one routes over all eight;
+    what every chip computes alike (the router, the gates'
+    normalisation) is counted once, and the partial outputs add up to
+    the uncut layer's, whatever the gate's activation."""
     w = _weights()
+    each = E // shares
     with jax.default_matmul_precision("highest"):
-        whole = _dense(w, 0, E)
-        parts = [_layer(w, first, 2)[0] for first in (0, 2, 4, 6)]
-        uncut, plan = _layer(w, 0, E)
+        whole = _dense(w, 0, E, act=act)
+        parts = [_layer(w, first, each, act=act)[0]
+                 for first in range(0, E, each)]
+        uncut, plan = _layer(w, 0, E, act=act)
+    assert len(parts) == shares
+    if act == "silu":  # and the activation is really another layer
+        assert float(jnp.abs(whole - _dense(w, 0, E)).max()) > 1e-3
     np.testing.assert_allclose(sum(parts), whole, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(uncut, whole, rtol=1e-5, atol=1e-6)
     assert int(plan.group_sizes.sum()) == T * K  # every pair, once

@@ -48,6 +48,48 @@ def test_flash_kernels_compile_at_28_on_4_heads_of_128_by_8192(
         assert name in text
 
 
+def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
+        one_chip):
+    """Attention over picked keys, forward and backward, at the
+    learned-sparse configuration's widths: a (16384, 16384) int8 mask,
+    eight query heads a grid step."""
+    from fmda_tpu.ops.pallas_sparse_attention import sparse_attention
+
+    def step(q, k, v, mask):
+        return jax.value_and_grad(lambda q, k, v: sparse_attention(
+            q, k, v, mask).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(step).lower(
+        _shape(one_chip, (1, 32, 16384, 128), BF16),
+        _shape(one_chip, (1, 4, 16384, 128), BF16),
+        _shape(one_chip, (1, 4, 16384, 128), BF16),
+        _shape(one_chip, (1, 16384, 16384), jnp.int8)).compile()
+    text = compiled.as_text()
+    for name in ("sparse_fwd", "sparse_bwd_dkv", "sparse_bwd_dq"):
+        assert name in text
+
+
+def test_index_and_selection_kernels_compile_at_16_heads_of_64_by_16384(
+        one_chip):
+    """The indexer's scores and the counting top-2,048, a chunk of 2,048
+    query rows at a time inside one loop: the (16384, 16384) float32
+    scores never exist, the mask is int8."""
+    from fmda_tpu.ops.sparse_attention import select_keys
+
+    compiled = jax.jit(
+        lambda q, k, w: select_keys(q, k, w, 2048, use_kernels=True)).lower(
+        _shape(one_chip, (1, 16, 16384, 64), BF16),
+        _shape(one_chip, (1, 16384, 64), BF16),
+        _shape(one_chip, (1, 16384, 16), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "sparse_index" in text and "sparse_select" in text
+    assert "f32[1,2048,16384]" in text          # a chunk's scores
+    assert "f32[1,16384,16384]" not in text     # never the square
+    assert "s8[1,16384,16384]" in text or "s8[8,1,2048,16384]" in text
+    # one chunk of scores and the mask, not gigabytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("k,n", [(2560, 768), (768, 2560)])
 def test_grouped_product_kernels_compile_at_16_experts_of_2560_by_768(
         one_chip, k, n):
