@@ -42,7 +42,8 @@ expert layer computes the experts this chip holds
 (``cfg.experts_held``; :mod:`fmda_tpu.ops.moe`), attention runs through
 :func:`fmda_tpu.ops.attention.mha` (the fused kernel where
 ``cfg.use_pallas`` and the backend allow), and ``cfg.remat`` recomputes
-each block in backward.  Parameters are float32; products run in
+each block in backward but for what :data:`REPLAY_KEEPS` names.
+Parameters are float32; products run in
 ``cfg.dtype``; norms, softmaxes, rotary angles and the router's
 probabilities are float32.
 
@@ -61,10 +62,10 @@ import jax
 import jax.numpy as jnp
 
 from fmda_tpu.config import ModelConfig
-from fmda_tpu.ops.attention import mha
+from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT, mha
 from fmda_tpu.ops.moe import ACTIVATIONS, expert_layer, kernel_impl, route
 from fmda_tpu.ops.sparse_attention import (
-    kernels_dispatch, select_keys, sparse_mha)
+    PICKS, kernels_dispatch, select_keys, sparse_mha)
 
 #: Standard deviation of every weight matrix at init (the family's
 #: convention; norm scales start at one).
@@ -79,6 +80,17 @@ EMBED_INIT_STD = 1.0
 
 #: ``layer_layout``'s value for a learned-sparse layer.
 SPARSE_LAYOUT = 2
+
+#: What a block's recomputation (``cfg.remat``) keeps from the forward
+#: pass, by name; everything else it remakes from the block's input.
+#: These are what attention's backward reads and only a second run of
+#: the attention core (and, in a learned-sparse layer, of the indexer
+#: and the selection) could remake: the core's output (heads x head_dim
+#: wide, in the compute dtype), its rows' logsumexp (a float32 a head
+#: and row; the learned-sparse kernel's packed tile, 128 lanes a row and
+#: kv head) and a learned-sparse layer's picks (int8, T x T).  A layer
+#: puts under a name what it has: one list serves every layout.
+REPLAY_KEEPS = (CORE_OUT, CORE_LSE, PICKS)
 
 
 class RoutingStats(NamedTuple):
@@ -229,7 +241,12 @@ class MoEDecoder(nn.Module):
         d = cfg.hidden_size
         self.embed = _weight(self, "embed", (cfg.vocab_size, d),
                              EMBED_INIT_STD)
-        block_cls = nn.remat(DecoderBlock) if cfg.remat else DecoderBlock
+        block_cls = DecoderBlock
+        if cfg.remat:
+            block_cls = nn.remat(
+                DecoderBlock,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *REPLAY_KEEPS))
         self.blocks = [
             block_cls(cfg, int(layout), name=f"block_{i}")
             for i, layout in enumerate(cfg.layer_layout)]

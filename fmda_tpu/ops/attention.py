@@ -31,6 +31,16 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+#: The names an attention core gives what its backward reads and a
+#: recomputation would have to run the core again for: its output, and
+#: (the kernels alone) its rows' logsumexp.  A ``jax.checkpoint`` policy
+#: that saves these names (:data:`fmda_tpu.models.decoder.REPLAY_KEEPS`)
+#: replays a block without the core's forward; anywhere else the names
+#: are identities.
+CORE_OUT = "attention_core_out"
+CORE_LSE = "attention_core_lse"
 
 
 class OnlineSoftmaxState(NamedTuple):
@@ -259,7 +269,7 @@ def mha(
 
         blk = FALLBACK_QUERY_BLOCK
         if tq <= blk or tq % blk != 0:
-            return attend(q, q_pos, mask)
+            return checkpoint_name(attend(q, q_pos, mask), CORE_OUT)
         # one block of query rows at a time, recomputed in backward
         n_blk = tq // blk
         q_blocks = jnp.moveaxis(
@@ -277,7 +287,8 @@ def mha(
             out = jax.lax.map(
                 lambda xs: jax.checkpoint(attend)(*xs),
                 (q_blocks, pos_blocks, mask_blocks))
-        return jnp.moveaxis(out, 0, 2).reshape(q.shape)
+        return checkpoint_name(
+            jnp.moveaxis(out, 0, 2).reshape(q.shape), CORE_OUT)
 
 
 def split_heads(x: jax.Array, n_heads: int) -> jax.Array:

@@ -73,10 +73,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fmda_tpu.compat import CompilerParams
+from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT
 
 #: Smallest Q/K block edge.  128 = MXU tile edge = Mosaic lane count; T
 #: must be a multiple (flash_supported gates on it).
@@ -483,12 +485,20 @@ def _flash(q, k, v, causal, window, interpret):
 def _flash_fwd(q, k, v, causal, window, interpret):
     o, lse = _fwd_impl(q, k, v, causal=causal, window=window,
                        interpret=interpret)
-    return (o, lse[..., 0]), (q, k, v, o, lse)
+    # named here, on the arrays the residuals hold, so that a policy that
+    # saves the names replays a block without this kernel (a name on the
+    # caller's copy would keep a copy and still run it for the residuals).
+    # The kernel's lse tile is 128 equal lanes: its column is the
+    # residual, 1/128 of the bytes, and the backward rebuilds the tile.
+    o = checkpoint_name(o, CORE_OUT)
+    lse = checkpoint_name(lse[..., 0], CORE_LSE)
+    return (o, lse), (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, window, interpret, residuals, cts):
     q, k, v, o, lse = residuals
     do, dlse = cts
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
     return _bwd_impl(q, k, v, o, lse, do, dlse, causal=causal,
                      window=window, interpret=interpret)
 

@@ -44,10 +44,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fmda_tpu.compat import CompilerParams
+from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT
 from fmda_tpu.ops.sparse_attention import _INT_MIN, sortable_key
 
 _NEG = -1e30
@@ -514,6 +516,10 @@ def _sparse(q, k, v, mask, blocks, interpret):
 
 def _sparse_fwd(q, k, v, mask, blocks, interpret):
     o, lse = _fwd_impl(q, k, v, mask, blocks=blocks, interpret=interpret)
+    # named on the residuals' own arrays (pallas_attention._flash_fwd);
+    # lse is already packed by head, a lane a head and row
+    o = checkpoint_name(o, CORE_OUT)
+    lse = checkpoint_name(lse, CORE_LSE)
     return o, (q, k, v, mask, o, lse)
 
 

@@ -37,8 +37,14 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from fmda_tpu.ops.attention import FALLBACK_QUERY_BLOCK, mha
+
+#: The name :func:`select_keys` gives its mask: a recomputation whose
+#: policy saves it (:data:`fmda_tpu.models.decoder.REPLAY_KEEPS`) runs
+#: neither the indexer nor the selection a second time.
+PICKS = "attention_picks"
 
 #: Query rows whose scores against every key exist at a time on the
 #: kernel path: 2,048 x 16,384 float32 is 134 MB.
@@ -183,9 +189,11 @@ def select_keys(q_idx: jax.Array, k_idx: jax.Array, w_idx: jax.Array,
                            for x in (q_idx, k_idx, w_idx))
     w_idx = w_idx.astype(jnp.float32)
     if use_kernels or interpret:
-        return _select_kernels(q_idx, k_idx, w_idx, topk, interpret)
-    return jax.vmap(lambda q, k, w: _select_jnp(q, k, w, topk))(
-        q_idx, k_idx, w_idx)
+        picked, kept = _select_kernels(q_idx, k_idx, w_idx, topk, interpret)
+    else:
+        picked, kept = jax.vmap(
+            lambda q, k, w: _select_jnp(q, k, w, topk))(q_idx, k_idx, w_idx)
+    return checkpoint_name(picked, PICKS), kept
 
 
 def sparse_mha(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,

@@ -67,20 +67,40 @@ def test_logits_match_the_reference(held):
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("remat", [False, True])
-def test_loss_and_gradients_match_the_reference(remat):
-    cfg = small_cfg(remat=remat, experts_held=(4, 4))
-    model, params = _params(cfg)
-    x, y = _ids()
-    mask = jnp.ones(x.shape, jnp.float32).at[1, 40:].set(0.0)
-    batch = Batch(x, y, mask)
+def _program_loss_and_grads(cfg, params, batch):
+    model = build_model(cfg)
     task = NextToken(cfg, TrainConfig(batch_size=2, window=SEQ))
 
     def program_loss(p):
         return task.loss(p, task.forward(model, p, batch, None), batch)[0]
 
     with jax.default_matmul_precision("highest"):
-        got, got_grads = jax.jit(jax.value_and_grad(program_loss))(params)
+        return jax.jit(jax.value_and_grad(program_loss))(params)
+
+
+@pytest.mark.parametrize("remat", [False, True, "replay"])
+def test_loss_and_gradients_match_the_reference(remat):
+    """``"replay"``: the reference here is the program itself without
+    recomputation.  A block's replay keeps what it names and remakes the
+    rest from the block's input: loss and every gradient leaf are the
+    same bits with ``remat`` on and off."""
+    layout = (0, 1) if remat == "replay" else small_cfg().layer_layout
+    kw = dict(experts_held=(4, 4), layer_layout=layout)
+    cfg = small_cfg(remat=bool(remat), **kw)
+    _, params = _params(cfg)
+    x, y = _ids()
+    mask = jnp.ones(x.shape, jnp.float32).at[1, 40:].set(0.0)
+    batch = Batch(x, y, mask)
+    got, got_grads = _program_loss_and_grads(cfg, params, batch)
+    if remat == "replay":
+        want, want_grads = _program_loss_and_grads(
+            small_cfg(remat=False, **kw), params, batch)
+        np.testing.assert_array_equal(got, want)
+        for (path, g), w in zip(
+                jax.tree_util.tree_leaves_with_path(got_grads),
+                jax.tree.leaves(want_grads)):
+            np.testing.assert_array_equal(g, w, err_msg=str(path))
+        return
     want, want_grads = jax.jit(lambda p: ref.loss_and_grads(
         p, x, y, mask, cfg, remat=remat))(params)
     np.testing.assert_allclose(got, want, rtol=1e-5)

@@ -277,6 +277,49 @@ def test_gqa_window_kernel_matches_masked_attention_fwd_and_bwd(seq, window):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("seq,window", [(1024, None), (1024, 300),
+                                        (384, 100)])
+def test_the_kept_lse_is_a_column_and_both_outputs_gradients_hold(
+        seq, window):
+    """The backward's residual logsumexp is one float32 a head and row
+    (the kernel's 128 equal lanes are rebuilt from it), and the
+    gradients through both outputs, grouped heads and a window are the
+    blockwise path's with a plain logsumexp beside it."""
+    from fmda_tpu.ops.pallas_attention import flash_attention_with_lse
+
+    q, k, v = _gqa_qkv(seq)
+    heads = q.shape[1]
+
+    def ref_lse(q_, k_):
+        k_ = jnp.repeat(k_, heads // k_.shape[1], axis=1)
+        s = jnp.einsum("bnqd,bnkd->bnqk", q_, k_) / np.sqrt(q_.shape[-1])
+        rel = jnp.arange(seq)[:, None] - jnp.arange(seq)[None, :]
+        keep = (rel >= 0) if window is None else (rel >= 0) & (rel < window)
+        return jax.scipy.special.logsumexp(
+            jnp.where(keep, s, -jnp.inf), axis=-1)
+
+    def loss(o, lse):
+        return jnp.sum(o * jnp.cos(o)) + jnp.sum(jnp.sin(lse))
+
+    def want(q_, k_, v_):  # mha scores 512 query rows at a time here
+        return loss(mha(q_, k_, v_, causal=True, window=window),
+                    ref_lse(q_, k_))
+
+    def got(q_, k_, v_):
+        return loss(*flash_attention_with_lse(
+            q_, k_, v_, causal=True, window=window, interpret=True))
+
+    with jax.default_matmul_precision("highest"):
+        w = jax.value_and_grad(want, (0, 1, 2))(q, k, v)
+        g = jax.value_and_grad(got, (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+    # what the backward closes over: the rule's residuals
+    kept = [x.shape for x in jax.tree.leaves(jax.vjp(got, q, k, v)[1])]
+    assert (heads, seq) in kept            # the column
+    assert (heads, seq, 128) not in kept   # never the tile
+
+
 def test_window_at_least_the_sequence_is_plain_causal():
     q, k, v = _gqa_qkv(256)
     a = flash_attention(q, k, v, causal=True, interpret=True)

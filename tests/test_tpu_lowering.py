@@ -159,3 +159,68 @@ def test_expert_layer_row_passes_compile_bounded_and_in_place(one_chip):
     assert not [line for line in lines
                 if "transpose(jvp(moe_combine))" in line and "gather" in line
                 and re.search(rf"= \w+\[{t},{d}\]", line)]
+
+
+@pytest.mark.parametrize("layout,kernels", [
+    ((0, 1), ("flash_fwd",)),
+    ((2, 2), ("sparse_fwd", "sparse_select", "sparse_index")),
+])
+@pytest.mark.parametrize("keeps", ["names", "nothing"])
+def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
+        one_chip, monkeypatch, layout, kernels, keeps):
+    """The next-token loss's value and gradient through two recomputed
+    blocks, compiled for the chip on the kernel path: with the replay
+    keeping what it names (``decoder.REPLAY_KEEPS``) the program holds
+    one core forward a layer, and on a learned-sparse layer one selection
+    and one index-score kernel, none under ``rematted_computation``;
+    keeping nothing (the policy the family had before) it holds two."""
+    import re
+
+    from fmda_tpu.config import ModelConfig, TrainConfig
+    from fmda_tpu.data.pipeline import Batch
+    from fmda_tpu.models import build_model, decoder
+    from fmda_tpu.ops import attention
+    from fmda_tpu.train.tasks import NextToken
+
+    monkeypatch.setattr(attention, "flash_available", lambda: True)
+    monkeypatch.setattr(decoder, "kernel_impl", lambda use: "pallas")
+    monkeypatch.setattr(decoder, "kernels_dispatch",
+                        lambda t, g, d, use_kernels: t % 128 == 0)
+    if keeps == "nothing":
+        monkeypatch.setattr(decoder, "REPLAY_KEEPS", ())
+    t = 2048
+    cfg = ModelConfig(
+        cell="decoder", hidden_size=256, n_heads=8, n_kv_heads=2,
+        head_dim=128, vocab_size=512, layer_layout=layout,
+        sliding_window=256, moe_experts=8, moe_top_k=2, moe_ffn_size=128,
+        experts_held=(0, 4), hidden_act="silu", indexer_heads=4,
+        indexer_head_dim=64, indexer_topk=128, loss_chunk=256,
+        dtype="bfloat16", use_pallas=True, remat=True)
+    model = build_model(cfg)
+    task = NextToken(cfg, TrainConfig(batch_size=1, window=t))
+    params = jax.eval_shape(
+        lambda key: model.init({"params": key},
+                               jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+
+    def step(p, x, y, mask):
+        batch = Batch(x, y, mask)
+        return jax.value_and_grad(lambda p: task.loss(
+            p, task.forward(model, p, batch, None), batch)[0])(p)
+
+    text = jax.jit(step).lower(
+        jax.tree.map(lambda l: _shape(one_chip, l.shape, l.dtype), params),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.float32)).compile().as_text()
+    runs = 1 if keeps == "names" else 2
+    for name in kernels:
+        calls = re.findall(
+            rf"(?m)^\s*%{name}(?:\.\d+)? = .*custom-call\(.*$", text)
+        assert len(calls) == runs * len(layout), (name, len(calls))
+        replayed = [c for c in calls if "rematted_computation" in c]
+        assert len(replayed) == (runs - 1) * len(layout), name
+    # the backward kernels read what was kept: one pair a layer either way
+    bwd = "sparse_bwd_dq" if 2 in layout else "flash_bwd_dq"
+    assert len(re.findall(
+        rf"(?m)^\s*%{bwd}(?:\.\d+)? = .*custom-call\(", text)) == len(layout)
