@@ -452,7 +452,8 @@ class ModelConfig:
     #: RMSNorm per head on q and k, an indexer that picks ``indexer_topk``
     #: keys of the causal past a query (ops/sparse_attention.py), and the
     #: router placed *after* attention, reading the expert block's
-    #: normalised input.
+    #: normalised input; 3 = a state-space layer: no attention, a scan
+    #: over matrix-valued state (the ``ssm_*`` group below).
     layer_layout: Tuple[int, ...] = ()
     sliding_window: int = 4096
     rope_theta: float = 10000.0
@@ -479,6 +480,35 @@ class ModelConfig:
     #: is summed chunk by chunk (recomputed in backward), the same sum as
     #: over (tokens, vocab_size) at once.
     loss_chunk: int = 1024
+    #: ``layer_layout`` 3, a state-space layer in place of attention
+    #: (ops/ssd.py): ``ssm_heads`` heads of ``ssm_head_dim`` channels, each
+    #: carrying a ``(ssm_head_dim, ssm_state)`` matrix; one group of
+    #: ``ssm_state`` input and output coefficients shared by the heads; a
+    #: causal depthwise convolution of ``ssm_conv`` taps in front; the
+    #: scan taken ``ssm_chunk`` positions at a time.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 0
+    #: ``moe_experts == 0``: every layer's feed-forward is one dense gated
+    #: MLP ``hidden -> ffn_size -> hidden`` (``hidden_act`` on the gate),
+    #: with no router.
+    ffn_size: int = 0
+    #: The head is the embedding, transposed: one leaf, whose gradient
+    #: comes from both uses.
+    tie_embeddings: bool = False
+    #: Scalars a model's config states, each at the value that leaves the
+    #: layer as the other decoder configurations compute it: the embedding
+    #: rows are multiplied by ``embedding_multiplier``, each block's
+    #: mixer and feed-forward output by ``residual_multiplier`` before it
+    #: joins the stream, the attention scores by ``attention_multiplier``
+    #: (None: ``1 / sqrt(head_dim)``), and the logits are divided by
+    #: ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
 
 @dataclass(frozen=True)

@@ -37,7 +37,33 @@ to ``W_qI``, ``W_kI``, ``W_wI``, which keep their initial values (the
 mechanism's published recipe trains them by a separate alignment term;
 that term is not built, docs/training.md "The decoder family").
 
-Then a final RMSNorm and an untied head ``(hidden, vocab_size)``.  The
+A state-space layer (``layout`` 3) has no attention; its mixer carries a
+``(P, N)`` matrix a head through the sequence (``H`` = ``ssm_heads``,
+``P`` = ``ssm_head_dim``, ``N`` = ``ssm_state``, ``I = H * P``;
+:mod:`fmda_tpu.ops.ssd`)::
+
+    h  = RMSNorm(x)
+    [z | xBC | dt] = h @ W_in                       I | I + 2N | H
+    xBC = silu(conv_b + sum_{j<K} conv_w[:, j] * xBC[t-(K-1)+j])    causal, depthwise
+    [xs | B | C] = xBC                              (T, H, P) | (T, N) | (T, N)
+    d_t = softplus(dt_t + dt_bias) ;  A = -exp(A_log)
+    S_t = exp(d_t A) S_{t-1} + d_t * xs_t (x) B_t   per head, S_{-1} = 0, float32
+    y_t = S_t C_t + D * xs_t
+    x1 = x + r * (RMSNorm_I(y * silu(z)) @ W_out)   gate first, one norm over all I
+
+and where ``cfg.moe_experts`` is 0 every layer's feed-forward is one
+dense gated MLP, with no router::
+
+    u  = RMSNorm(x1) ;  x2 = x1 + r * ((act(u @ Wg) * (u @ Wu)) @ Wd)
+
+``r`` is ``cfg.residual_multiplier`` (on the attention output too);
+the embedding rows are multiplied by ``cfg.embedding_multiplier``, the
+attention scores by ``cfg.attention_multiplier`` in place of
+``1 / sqrt(head_dim)``, and the logits divided by ``cfg.logits_scaling``.
+At their defaults none of the four is an operation of the program.
+
+Then a final RMSNorm and a head ``(hidden, vocab_size)``: a leaf of its
+own, or with ``cfg.tie_embeddings`` the embedding transposed.  The
 expert layer computes the experts this chip holds
 (``cfg.experts_held``; :mod:`fmda_tpu.ops.moe`), attention runs through
 :func:`fmda_tpu.ops.attention.mha` (the fused kernel where
@@ -55,6 +81,7 @@ calls :meth:`MoEDecoder.features` and takes the loss over token chunks
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -66,6 +93,7 @@ from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT, mha
 from fmda_tpu.ops.moe import ACTIVATIONS, expert_layer, kernel_impl, route
 from fmda_tpu.ops.sparse_attention import (
     PICKS, kernels_dispatch, select_keys, sparse_mha)
+from fmda_tpu.ops.ssd import causal_conv, ssd_scan
 
 #: Standard deviation of every weight matrix at init (the family's
 #: convention; norm scales start at one).
@@ -80,6 +108,8 @@ EMBED_INIT_STD = 1.0
 
 #: ``layer_layout``'s value for a learned-sparse layer.
 SPARSE_LAYOUT = 2
+#: ... and for a state-space layer (ops/ssd.py).
+SSM_LAYOUT = 3
 
 #: What a block's recomputation (``cfg.remat``) keeps from the forward
 #: pass, by name; everything else it remakes from the block's input.
@@ -94,11 +124,12 @@ REPLAY_KEEPS = (CORE_OUT, CORE_LSE, PICKS)
 
 
 class RoutingStats(NamedTuple):
-    """What the expert layers counted in one forward pass."""
+    """What the layers counted in one forward pass.  The first three are
+    the expert layers', None in a model without experts."""
 
-    expert_pairs: jax.Array  # (layers, held experts) int32
-    dropped: jax.Array       # () int32: held pairs not computed (0)
-    row_tiles_used: jax.Array  # (layers,) int32: row tiles holding a group
+    expert_pairs: Optional[jax.Array]  # (layers, held experts) int32
+    dropped: Optional[jax.Array]       # () int32: held pairs not computed (0)
+    row_tiles_used: Optional[jax.Array]  # (layers,) int32: row tiles holding a group
     #: What the learned-sparse layers' selection counted, None in a model
     #: without one: the keys kept, as (layers, 2) int32 ``[count >> 16,
     #: count & 0xffff]`` summed over the batch's sequences (a sequence of
@@ -106,6 +137,11 @@ class RoutingStats(NamedTuple):
     #: int32), and the query rows they were kept for, (layers,) int32.
     keys_kept: Optional[jax.Array] = None
     query_rows: Optional[jax.Array] = None
+    #: What the state-space layers' scans walked, None in a model without
+    #: one: chunks and positions, (layers,) int32 each, 0 in a layer of
+    #: another kind.
+    ssd_chunks: Optional[jax.Array] = None
+    ssd_positions: Optional[jax.Array] = None
 
 
 def _weight(module: nn.Module, name: str, shape: Tuple[int, ...],
@@ -136,6 +172,74 @@ def rotary(x: jax.Array, theta: float) -> jax.Array:
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def _uniform(low: float, high: float, then=lambda v: v):
+    """An initializer: ``then`` of a uniform draw from ``[low, high)``."""
+    def init(key, shape, dtype=jnp.float32):
+        return then(jax.random.uniform(key, shape, dtype, low, high))
+    return init
+
+
+def _inverse_softplus(step: jax.Array) -> jax.Array:
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def _ssm_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
+    """A state-space layer's mixer (module docstring) on the normalised
+    stream ``h`` (B, T, hidden): its output (B, T, hidden) and the
+    ``(chunks, positions)`` its scan walked.  Parameters start where the
+    mechanism's published code starts them: rates ``-A`` uniform in 1..16,
+    step sizes log-uniform in 1e-3..1e-1 at a zero input, the skip at 1,
+    the taps uniform in +-1/sqrt(taps)."""
+    b, t, d = h.shape
+    heads, p, n, taps = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                         cfg.ssm_conv)
+    inner, dt, f32 = heads * p, h.dtype, jnp.float32
+    with jax.named_scope("ssm_in_proj"):
+        z, xbc, step = jnp.split(
+            jnp.dot(h, _weight(module, "w_in",
+                               (d, 2 * inner + 2 * n + heads)).astype(dt)),
+            [inner, 2 * inner + 2 * n], axis=-1)
+    with jax.named_scope("ssm_conv"):
+        bound = taps ** -0.5
+        xbc = jax.nn.silu(causal_conv(
+            xbc,
+            module.param("conv_w", _uniform(-bound, bound),
+                         (inner + 2 * n, taps), f32),
+            module.param("conv_b", _uniform(-bound, bound),
+                         (inner + 2 * n,), f32))).astype(dt)
+    xs, b_in, c_out = jnp.split(xbc, [inner, inner + n], axis=-1)
+    with jax.named_scope("ssd_scan"):
+        step = jax.nn.softplus(step.astype(f32) + module.param(
+            "dt_bias", _uniform(math.log(1e-3), math.log(1e-1),
+                                lambda v: _inverse_softplus(jnp.exp(v))),
+            (heads,), f32))
+        rate = -jnp.exp(module.param(
+            "a_log", _uniform(1.0, 16.0, jnp.log), (heads,), f32))
+        y, states = ssd_scan(
+            xs.reshape(b, t, heads, p), step, rate, b_in, c_out,
+            module.param("d_skip", nn.initializers.ones, (heads,), f32),
+            chunk=cfg.ssm_chunk, dtype=dt)
+    with jax.named_scope("ssm_gate_norm"):
+        gated = rms_norm(
+            y.reshape(b, t, inner) * jax.nn.silu(z.astype(f32)),
+            module.param("ln_gate", nn.initializers.ones, (inner,)),
+            cfg.rms_norm_eps).astype(dt)
+    with jax.named_scope("ssm_out_proj"):
+        out = jnp.dot(gated, _weight(module, "w_out", (inner, d))
+                      .astype(dt))
+    return out, (jnp.int32(b * states.shape[1]), jnp.int32(b * t))
+
+
+def _dense_mlp(module: nn.Module, cfg: ModelConfig, u: jax.Array):
+    """``(act(u @ Wg) * (u @ Wu)) @ Wd`` on the normalised stream."""
+    d, f, dt = u.shape[-1], cfg.ffn_size, u.dtype
+    with jax.named_scope("dense_mlp"):
+        gate = jnp.dot(u, _weight(module, "w_gate", (d, f)).astype(dt))
+        up = jnp.dot(u, _weight(module, "w_up", (d, f)).astype(dt))
+        return jnp.dot(ACTIVATIONS[cfg.hidden_act](gate) * up,
+                       _weight(module, "w_down", (f, d)).astype(dt))
+
+
 class DecoderBlock(nn.Module):
     """One layer (module docstring).  A module of its own so that
     ``nn.remat`` wraps it whole when ``cfg.remat``."""
@@ -148,26 +252,30 @@ class DecoderBlock(nn.Module):
         cfg = self.cfg
         b, t, d = x.shape
         n, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        first, count = cfg.experts_held
         dt = x.dtype
 
         sparse = self.layout == SPARSE_LAYOUT
+        has_experts = cfg.moe_experts > 0
         h = rms_norm(x, self.param("ln_attn", nn.initializers.ones, (d,)),
                      cfg.rms_norm_eps)
+
+        def joined(x, y):
+            """The stream with a mixer's or a feed-forward's output."""
+            if cfg.residual_multiplier == 1.0:
+                return x + y
+            return (x.astype(jnp.float32) + cfg.residual_multiplier
+                    * y.astype(jnp.float32)).astype(dt)
 
         def routed(y):
             return route(
                 y.reshape(b * t, d),
                 _weight(self, "router", (d, cfg.moe_experts)), cfg.moe_top_k)
 
-        kept = None
-        if not sparse:
-            # the router reads the attention block's normalised input: it
-            # is placed before attention, so its top-k is known a layer's
-            # attention ahead of the experts it feeds
-            gates, experts = routed(h)
+        def attention():
+            """The attention layouts' mixer: its output before it joins
+            the stream, and what a learned-sparse selection kept."""
+            kept = None
 
-        with jax.named_scope("attention"):
             def heads(name, n_heads, width=hd, src=h):
                 y = jnp.dot(src, _weight(self, name, (d, n_heads * width))
                             .astype(dt))
@@ -206,14 +314,36 @@ class DecoderBlock(nn.Module):
                     a = mha(q, k, v, causal=True,
                             window=(cfg.sliding_window if self.layout
                                     else None),
-                            use_flash=cfg.use_pallas)
+                            use_flash=cfg.use_pallas,
+                            scale=cfg.attention_multiplier)
             a = a.transpose(0, 2, 1, 3).reshape(b, t, n * hd)
-            x = x + jnp.dot(a, _weight(self, "wo", (n * hd, d)).astype(dt))
+            return jnp.dot(a, _weight(self, "wo", (n * hd, d)).astype(dt)), kept
 
-        u = rms_norm(x, self.param("ln_moe", nn.initializers.ones, (d,)),
+        kept = walked = None
+        if has_experts and not sparse:
+            # the router reads the attention block's normalised input: it
+            # is placed before attention, so its top-k is known a layer's
+            # attention ahead of the experts it feeds
+            gates, experts = routed(h)
+
+        if self.layout == SSM_LAYOUT:
+            with jax.named_scope("ssm_mixer"):
+                mixed, walked = _ssm_mixer(self, cfg, h)
+                x = joined(x, mixed)
+        else:
+            with jax.named_scope("attention"):
+                mixed, kept = attention()
+                x = joined(x, mixed)
+
+        u = rms_norm(x, self.param("ln_moe" if has_experts else "ln_mlp",
+                                   nn.initializers.ones, (d,)),
                      cfg.rms_norm_eps)
+        if not has_experts:
+            return joined(x, _dense_mlp(self, cfg, u)), (
+                None, None, None, kept, walked)
         if sparse:
             gates, experts = routed(u)
+        first, count = cfg.experts_held
         f = cfg.moe_ffn_size
         m, plan = expert_layer(
             u.reshape(b * t, d), gates, experts,
@@ -226,8 +356,8 @@ class DecoderBlock(nn.Module):
             # (batch, row blocks) counts -> the split sum RoutingStats holds
             kept = (jnp.stack([jnp.sum(kept >> 16), jnp.sum(kept & 0xFFFF)]),
                     jnp.int32(b * t))
-        return x + m.reshape(b, t, d), (
-            plan.group_sizes, plan.dropped, plan.n_used[0], kept)
+        return joined(x, m.reshape(b, t, d)), (
+            plan.group_sizes, plan.dropped, plan.n_used[0], kept, walked)
 
 
 class MoEDecoder(nn.Module):
@@ -239,8 +369,10 @@ class MoEDecoder(nn.Module):
         cfg = self.cfg
         check_decoder_config(cfg)
         d = cfg.hidden_size
+        # a tied embedding is a head too and starts at a head's scale
         self.embed = _weight(self, "embed", (cfg.vocab_size, d),
-                             EMBED_INIT_STD)
+                             INIT_STD if cfg.tie_embeddings
+                             else EMBED_INIT_STD)
         block_cls = DecoderBlock
         if cfg.remat:
             block_cls = nn.remat(
@@ -251,29 +383,43 @@ class MoEDecoder(nn.Module):
             block_cls(cfg, int(layout), name=f"block_{i}")
             for i, layout in enumerate(cfg.layer_layout)]
         self.ln_final = self.param("ln_final", nn.initializers.ones, (d,))
-        self.head = _weight(self, "head", (d, cfg.vocab_size))
+        if not cfg.tie_embeddings:
+            self.head = _weight(self, "head", (d, cfg.vocab_size))
 
     def features(self, ids: jax.Array) -> Tuple[jax.Array, RoutingStats]:
         """ids (B, T) int32 -> the final norm's output (B, T, hidden) in
-        the compute dtype, and what the expert layers counted."""
+        the compute dtype, and what the layers counted."""
         cfg = self.cfg
         with jax.named_scope("embed"):
-            x = jnp.take(self.embed, ids, axis=0).astype(jnp.dtype(cfg.dtype))
+            x = jnp.take(self.embed, ids, axis=0)
+            if cfg.embedding_multiplier != 1.0:
+                x = x * cfg.embedding_multiplier
+            x = x.astype(jnp.dtype(cfg.dtype))
+        has_experts = cfg.moe_experts > 0
         sizes, tiles, dropped = [], [], jnp.zeros((), jnp.int32)
         zero = (jnp.zeros((2,), jnp.int32), jnp.zeros((), jnp.int32))
-        kept = []
+        kept, walked = [], []
         for block in self.blocks:
-            x, (layer_sizes, layer_dropped, layer_tiles, layer_kept) = block(x)
-            sizes.append(layer_sizes)
-            tiles.append(layer_tiles)
-            dropped = dropped + layer_dropped
+            x, (layer_sizes, layer_dropped, layer_tiles, layer_kept,
+                layer_walked) = block(x)
+            if has_experts:
+                sizes.append(layer_sizes)
+                tiles.append(layer_tiles)
+                dropped = dropped + layer_dropped
             kept.append(layer_kept)
+            walked.append(layer_walked)
         x = rms_norm(x, self.ln_final, cfg.rms_norm_eps)
-        stats = RoutingStats(jnp.stack(sizes), dropped, jnp.stack(tiles))
+        stats = (RoutingStats(jnp.stack(sizes), dropped, jnp.stack(tiles))
+                 if has_experts else RoutingStats(None, None, None))
         if any(k is not None for k in kept):
             keys, rows = zip(*(zero if k is None else k for k in kept))
             stats = stats._replace(keys_kept=jnp.stack(keys),
                                    query_rows=jnp.stack(rows))
+        if any(w is not None for w in walked):
+            chunks, positions = zip(*((zero[1], zero[1]) if w is None else w
+                                      for w in walked))
+            stats = stats._replace(ssd_chunks=jnp.stack(chunks),
+                                   ssd_positions=jnp.stack(positions))
         return x, stats
 
     def __call__(self, ids: jax.Array, *, deterministic: bool = True
@@ -281,9 +427,13 @@ class MoEDecoder(nn.Module):
         """ids (B, T) -> logits (B, T, vocab_size) float32, whole."""
         del deterministic  # the family has no dropout
         x, _ = self.features(ids)
+        head = self.embed.T if self.cfg.tie_embeddings else self.head
         with jax.named_scope("lm_head"):
-            return jnp.dot(x, self.head.astype(x.dtype),
-                           preferred_element_type=jnp.float32)
+            logits = jnp.dot(x, head.astype(x.dtype),
+                             preferred_element_type=jnp.float32)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
+        return logits
 
 
 def check_decoder_config(cfg: ModelConfig) -> None:
@@ -291,19 +441,37 @@ def check_decoder_config(cfg: ModelConfig) -> None:
     inconsistent, naming the field."""
     first, count = cfg.experts_held
     sparse = SPARSE_LAYOUT in cfg.layer_layout
+    state_space = SSM_LAYOUT in cfg.layer_layout
+    dense = cfg.moe_experts == 0
+    why_ssm = " (layer_layout has a state-space layer)"
     problems = [name for name, ok in (
         ("vocab_size", cfg.vocab_size > 0),
         ("head_dim", cfg.head_dim > 0),
         ("n_kv_heads (must divide n_heads)",
          cfg.n_kv_heads > 0 and cfg.n_heads % cfg.n_kv_heads == 0),
-        ("layer_layout (one of 0/1/2 per layer)",
+        ("layer_layout (one of 0/1/2/3 per layer)",
          len(cfg.layer_layout) > 0
-         and all(v in (0, 1, SPARSE_LAYOUT) for v in cfg.layer_layout)),
+         and all(v in (0, 1, SPARSE_LAYOUT, SSM_LAYOUT)
+                 for v in cfg.layer_layout)),
+        ("ffn_size (moe_experts is 0: a dense gated MLP)",
+         not dense or cfg.ffn_size > 0),
         ("moe_experts / moe_top_k",
-         0 < cfg.moe_top_k <= cfg.moe_experts),
-        ("moe_ffn_size", cfg.moe_ffn_size > 0),
+         dense or 0 < cfg.moe_top_k <= cfg.moe_experts),
+        ("moe_ffn_size", dense or cfg.moe_ffn_size > 0),
         ("experts_held (first, count) inside moe_experts",
-         count > 0 and first >= 0 and first + count <= cfg.moe_experts),
+         dense or (count > 0 and first >= 0
+                   and first + count <= cfg.moe_experts)),
+        ("ssm_heads" + why_ssm, not state_space or cfg.ssm_heads > 0),
+        ("ssm_head_dim" + why_ssm, not state_space or cfg.ssm_head_dim > 0),
+        ("ssm_state" + why_ssm, not state_space or cfg.ssm_state > 0),
+        ("ssm_conv" + why_ssm, not state_space or cfg.ssm_conv > 0),
+        ("ssm_chunk" + why_ssm, not state_space or cfg.ssm_chunk > 0),
+        ("embedding_multiplier / residual_multiplier / logits_scaling "
+         "(not zero)",
+         cfg.embedding_multiplier != 0 and cfg.residual_multiplier != 0
+         and cfg.logits_scaling != 0),
+        ("attention_multiplier (None or positive)",
+         cfg.attention_multiplier is None or cfg.attention_multiplier > 0),
         ("head_dim (even, for rotary)", cfg.head_dim % 2 == 0),
         ("sliding_window", cfg.sliding_window > 0),
         ("hidden_act (one of %s)" % "/".join(sorted(ACTIVATIONS)),

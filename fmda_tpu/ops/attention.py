@@ -22,7 +22,7 @@ and ``o_n / l_n`` equals softmax(S) @ V exactly (in exact arithmetic) no
 matter how the key axis was blocked — which is precisely what lets the
 ring pass blocks around devices and still match the single-device result.
 All accumulation is float32 regardless of the I/O dtype; logits are scaled
-by 1/sqrt(d_head).
+by 1/sqrt(d_head), or by the ``scale`` a caller states.
 """
 
 from __future__ import annotations
@@ -72,15 +72,18 @@ def online_attention_block(
     k: jax.Array,  # (B, N, Tk, D)
     v: jax.Array,  # (B, N, Tk, D)
     mask: Optional[jax.Array] = None,  # (Tq, Tk) or (B, 1|N, Tq, Tk), True=keep
+    scale: Optional[float] = None,
 ) -> OnlineSoftmaxState:
     """Fold one K/V block into the running softmax state.
 
     The QK^T matmul runs on the MXU in the input dtype with f32
     accumulation; everything after is f32 VPU work.  Fully-masked rows are
     safe: the running max stays finite only once a row sees a real key, and
-    :func:`finalize_online_state` guards the l=0 case.
+    :func:`finalize_online_state` guards the l=0 case.  ``scale``
+    multiplies the scores; None is ``1 / sqrt(d_head)``.
     """
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
     s = jnp.einsum(
         "bnqd,bnkd->bnqk", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -203,6 +206,7 @@ def mha(
     window: Optional[int] = None,
     mask: Optional[jax.Array] = None,
     use_flash: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Single-device multi-head attention via the same online-softmax
     primitive the ring path uses, so the sharded and unsharded paths are
@@ -232,6 +236,8 @@ def mha(
         ``0 <= i - j < window``; implies ``causal``.
       mask: optional extra mask, (Tq, Tk) or broadcastable (B, N, Tq, Tk).
       use_flash: opt into the fused kernel where supported.
+      scale: what the scores are multiplied by before the softmax (a
+        model's stated attention multiplier); None is ``1 / sqrt(D)``.
 
     Returns (B, N, Tq, D) in q's dtype.
     """
@@ -245,7 +251,7 @@ def mha(
             from fmda_tpu.ops import pallas_attention
 
             return pallas_attention.flash_attention(
-                q, k, v, causal=causal, window=window)
+                q, k, v, causal=causal, window=window, scale=scale)
         group = q.shape[1] // k.shape[1]
         if group > 1:  # the kernel indexes; this path repeats
             k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
@@ -264,7 +270,8 @@ def mha(
             state = init_online_state(
                 q_blk.shape[0], q_blk.shape[1], q_blk.shape[2],
                 q_blk.shape[3])
-            state = online_attention_block(state, q_blk, k, v, full_mask)
+            state = online_attention_block(state, q_blk, k, v, full_mask,
+                                           scale)
             return finalize_online_state(state, q.dtype)
 
         blk = FALLBACK_QUERY_BLOCK

@@ -152,9 +152,11 @@ def _banded(causal: bool, qi, ki, blk, window, compute) -> None:
         lambda: compute(True))
 
 
-def _scores(q, k, qi, ki, *, blk, window, masked):
-    """Scaled scores of one block, masked slots at ``_NEG``."""
-    scale = 1.0 / (q.shape[-1] ** 0.5)
+def _scores(q, k, qi, ki, *, blk, window, masked, scale=None):
+    """Scaled scores of one block, masked slots at ``_NEG``; ``scale``
+    None is ``1 / sqrt(D)``."""
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -177,6 +179,7 @@ def _fwd_kernel(
     window: Optional[int],
     blk: int,
     n_k: int,
+    scale: Optional[float] = None,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -190,7 +193,7 @@ def _fwd_kernel(
     def _compute(masked: bool):
         f32 = jnp.float32
         s, _ = _scores(q_ref[0], k_ref[0], qi, ki, blk=blk, window=window,
-                       masked=masked)
+                       masked=masked, scale=scale)
         m_prev = m_scr[:, :1]  # (blk, 1); lanes are replicated
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -249,6 +252,7 @@ def _clamp_query_block(ki, qi, *, causal, blk, window, n_q):
 def _fwd_impl(
     q: jax.Array, k: jax.Array, v: jax.Array,
     *, causal: bool, window: Optional[int], interpret: bool,
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """q (BN, T, D), k/v (BG, T, D) -> (o (BN, T, D), lse (BN, T, 128))."""
     bn, t, d = q.shape
@@ -256,7 +260,8 @@ def _fwd_impl(
     blk = block_for(t)
     n_blk = t // blk
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, window=window, blk=blk, n_k=n_blk)
+        _fwd_kernel, causal=causal, window=window, blk=blk, n_k=n_blk,
+        **_stated(scale))
 
     def kv_index(b, qi, ki):
         return (b // group,
@@ -294,12 +299,12 @@ def _fwd_impl(
 
 
 def _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-              *, blk, window, masked):
+              *, blk, window, masked, scale=None):
     """The backward's shared recompute for one block: probabilities
     ``p = exp(s - L)`` and ``ds = p * (do @ v^T - delta) * scale``."""
     f32 = jnp.float32
     s, scale = _scores(q_ref[0], k_ref[0], qi, ki, blk=blk, window=window,
-                       masked=masked)
+                       masked=masked, scale=scale)
     p = jnp.exp(s - lse_ref[0][:, :1])
     if masked:
         p = jnp.where(s <= _NEG * 0.5, 0.0, p)
@@ -325,6 +330,7 @@ def _dkv_kernel(
     window: Optional[int],
     blk: int,
     n_q: int,
+    scale: Optional[float] = None,
 ):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
@@ -337,7 +343,8 @@ def _dkv_kernel(
     def _compute(masked: bool):
         f32 = jnp.float32
         p, ds = _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          qi, ki, blk=blk, window=window, masked=masked)
+                          qi, ki, blk=blk, window=window, masked=masked,
+                          scale=scale)
         io_dtype = q_ref.dtype
         # dv += p^T @ do   (contract the query rows)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
@@ -372,6 +379,7 @@ def _dq_kernel(
     window: Optional[int],
     blk: int,
     n_k: int,
+    scale: Optional[float] = None,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -382,7 +390,8 @@ def _dq_kernel(
 
     def _compute(masked: bool):
         _, ds = _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          qi, ki, blk=blk, window=window, masked=masked)
+                          qi, ki, blk=blk, window=window, masked=masked,
+                          scale=scale)
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
             ds.astype(q_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -398,7 +407,7 @@ def _dq_kernel(
 
 def _bwd_impl(
     q, k, v, o, lse, do, dlse=None, *, causal: bool,
-    window: Optional[int], interpret: bool,
+    window: Optional[int], interpret: bool, scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     bn, t, d = q.shape
     bg = k.shape[0]
@@ -426,7 +435,7 @@ def _bwd_impl(
     part = q.dtype if group == 1 else jnp.float32
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, window=window,
-                          blk=blk, n_q=n_blk),
+                          blk=blk, n_q=n_blk, **_stated(scale)),
         name="flash_bwd_dkv",
         grid=(bn, n_blk, n_blk),
         in_specs=[q_rows(d), kspec, kspec, q_rows(d), q_rows(128),
@@ -460,7 +469,7 @@ def _bwd_impl(
     rspec2 = pl.BlockSpec((1, blk, 128), lambda b, qi, ki: (b, qi, 0))
     (dq,) = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, window=window,
-                          blk=blk, n_k=n_blk),
+                          blk=blk, n_k=n_blk, **_stated(scale)),
         name="flash_bwd_dq",
         grid=(bn, n_blk, n_blk),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
@@ -475,16 +484,22 @@ def _bwd_impl(
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, window, interpret):
+def _stated(scale: Optional[float]) -> dict:
+    """The kernels' ``scale`` argument where a caller states one; nothing
+    where it is the default, so that such a kernel is the one it was."""
+    return {} if scale is None else {"scale": float(scale)}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, window, interpret, scale=None):
     o, lse = _fwd_impl(q, k, v, causal=causal, window=window,
-                       interpret=interpret)
+                       interpret=interpret, scale=scale)
     return o, lse[..., 0]
 
 
-def _flash_fwd(q, k, v, causal, window, interpret):
+def _flash_fwd(q, k, v, causal, window, interpret, scale=None):
     o, lse = _fwd_impl(q, k, v, causal=causal, window=window,
-                       interpret=interpret)
+                       interpret=interpret, scale=scale)
     # named here, on the arrays the residuals hold, so that a policy that
     # saves the names replays a block without this kernel (a name on the
     # caller's copy would keep a copy and still run it for the residuals).
@@ -495,12 +510,12 @@ def _flash_fwd(q, k, v, causal, window, interpret):
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, window, interpret, residuals, cts):
+def _flash_bwd(causal, window, interpret, scale, residuals, cts):
     q, k, v, o, lse = residuals
     do, dlse = cts
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
     return _bwd_impl(q, k, v, o, lse, do, dlse, causal=causal,
-                     window=window, interpret=interpret)
+                     window=window, interpret=interpret, scale=scale)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -514,6 +529,7 @@ def flash_attention(
     causal: bool = False,
     window: Optional[int] = None,
     interpret: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Fused-kernel multi-head attention, q (B, N, T, D) and k/v
     (B, G, T, D) with ``N % G == 0`` -> (B, N, T, D).
@@ -525,7 +541,8 @@ def flash_attention(
     already checked :func:`flash_supported`.
     """
     out, _ = flash_attention_with_lse(
-        q, k, v, causal=causal, window=window, interpret=interpret)
+        q, k, v, causal=causal, window=window, interpret=interpret,
+        scale=scale)
     return out
 
 
@@ -537,6 +554,7 @@ def flash_attention_with_lse(
     causal: bool = False,
     window: Optional[int] = None,
     interpret: bool = False,
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused attention returning ``(o, lse)`` — o (B, N, T, D) in q's
     dtype plus the per-row logsumexp (B, N, T) f32.
@@ -550,7 +568,8 @@ def flash_attention_with_lse(
     into the backward's delta term).  Fully-masked rows report
     ``lse = -1e30`` (the kernel's finite -inf sentinel) and ``o = 0``.
     ``window`` (a causal window: key j visible to query i iff
-    ``0 <= i - j < window``) implies ``causal``.
+    ``0 <= i - j < window``) implies ``causal``.  ``scale`` multiplies
+    the scores in place of ``1 / sqrt(D)`` (None).
     """
     b, n, t, d = q.shape
     g = k.shape[1]
@@ -567,5 +586,5 @@ def flash_attention_with_lse(
         window = None  # the band is the whole causal triangle
     out, lse = _flash(
         q.reshape(b * n, t, d), k.reshape(b * g, t, d),
-        v.reshape(b * g, t, d), causal, window, interpret)
+        v.reshape(b * g, t, d), causal, window, interpret, scale)
     return out.reshape(b, n, t, d), lse.reshape(b, n, t)

@@ -155,7 +155,10 @@ def linear_scan_parallel(
     """All prefixes of ``x_t = a_t * x_{t-1} + u_t`` over axis 1 via
     :func:`jax.lax.associative_scan` (log-depth tree, the training-mode
     layout).  ``a``/``u`` are (B, T, H); ``x0`` (B, H) folds a carried
-    initial state in exactly (``x_t`` gains ``prod(a_1..t) * x0``)."""
+    initial state in exactly (``x_t`` gains ``prod(a_1..t) * x0``).
+    ``u`` may carry further axes that ``a`` broadcasts over (``a`` (B, T,
+    H, 1) against ``u`` (B, T, H, M): one scalar decay a head over a
+    matrix-valued state, ops/ssd.py's carry over chunks)."""
 
     def combine(c1, c2):
         a1, u1 = c1

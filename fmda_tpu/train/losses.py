@@ -94,6 +94,7 @@ def chunked_next_token_loss(
     mask: jax.Array,
     *,
     chunk: int,
+    logits_scaling: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``(loss_sum, n_tokens, n_correct)`` of softmax cross-entropy over
     the vocabulary, taken ``chunk`` tokens at a time.
@@ -105,6 +106,7 @@ def chunked_next_token_loss(
     one taken over all tokens at once, up to float re-association.  The
     head is cast inside each chunk, so its cotangent accumulates over
     the chunks in float32.  ``chunk`` is lowered to a divisor of N.
+    The logits are divided by ``logits_scaling`` (1.0: not an operation).
     """
     n = hidden.shape[0]
     chunk = max(1, min(chunk, n))
@@ -116,6 +118,8 @@ def chunked_next_token_loss(
         with jax.named_scope("lm_head"):
             logits = jnp.dot(h, head.astype(h.dtype),
                              preferred_element_type=jnp.float32)
+            if logits_scaling != 1.0:
+                logits = logits / logits_scaling
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
         keep = m > 0

@@ -40,7 +40,7 @@ import numpy as np
 from fmda_tpu.config import ModelConfig, TrainConfig
 from fmda_tpu.data.pipeline import (
     Batch, ChunkDataset, TokenBatches, TokenDataset, WindowBatches)
-from fmda_tpu.models.decoder import SPARSE_LAYOUT
+from fmda_tpu.models.decoder import SPARSE_LAYOUT, SSM_LAYOUT
 from fmda_tpu.ops.metrics import multilabel_metrics
 from fmda_tpu.train.losses import (
     chunked_next_token_loss, weighted_bce_sums, weighted_bce_with_logits)
@@ -75,20 +75,25 @@ class TokenTotals(NamedTuple):
     that counted and those predicted right, and what the expert layers
     counted (``expert_pairs[l, e]``: pairs layer ``l`` computed on held
     expert ``e``; ``row_tiles_used[l]``: row tiles of its layout that
-    held a group, the ones its row passes visit)."""
+    held a group, the ones its row passes visit), None in a model
+    without experts."""
 
     loss: jax.Array          # () float32
     tokens: jax.Array        # () int32
     correct: jax.Array       # () int32
-    expert_pairs: jax.Array  # (layers, held experts) int32
-    dropped: jax.Array       # () int32
-    row_tiles_used: jax.Array  # (layers,) int32
+    expert_pairs: Optional[jax.Array]  # (layers, held experts) int32
+    dropped: Optional[jax.Array]       # () int32
+    row_tiles_used: Optional[jax.Array]  # (layers,) int32
     #: The learned-sparse layers' selection (None in a model without
     #: one): keys kept as (layers, 2) int32 ``[count >> 16, count &
     #: 0xffff]`` (:func:`keys_kept_counts` joins them), and the query
     #: rows they were kept for, (layers,) int32.
     sparse_keys_kept: Optional[jax.Array] = None
     sparse_query_rows: Optional[jax.Array] = None
+    #: The state-space layers' scans (None in a model without one): the
+    #: chunks and the positions each walked, (layers,) int32.
+    ssd_chunks: Optional[jax.Array] = None
+    ssd_positions: Optional[jax.Array] = None
 
 
 def keys_kept_counts(sparse_keys_kept) -> list:
@@ -231,14 +236,20 @@ class NextToken:
         mc = self.model_cfg
         zero = np.zeros((), np.int32)
         layers = len(mc.layer_layout)
+        per_layer = np.zeros((layers,), np.int32)
         totals = TokenTotals(
-            np.zeros((), np.float32), zero, zero,
-            np.zeros((layers, mc.experts_held[1]), np.int32), zero,
-            np.zeros((layers,), np.int32))
+            np.zeros((), np.float32), zero, zero, None, None, None)
+        if mc.moe_experts:
+            totals = totals._replace(
+                expert_pairs=np.zeros((layers, mc.experts_held[1]), np.int32),
+                dropped=zero, row_tiles_used=per_layer)
         if SPARSE_LAYOUT in mc.layer_layout:
             totals = totals._replace(
                 sparse_keys_kept=np.zeros((layers, 2), np.int32),
-                sparse_query_rows=np.zeros((layers,), np.int32))
+                sparse_query_rows=per_layer)
+        if SSM_LAYOUT in mc.layer_layout:
+            totals = totals._replace(ssd_chunks=per_layer,
+                                     ssd_positions=per_layer)
         return totals
 
     def epoch_metrics(self, totals: Optional[TokenTotals], steps: int
@@ -253,13 +264,26 @@ class NextToken:
             hamming=1.0 - accuracy, fbeta=np.zeros(0)), confusion
 
     def publish(self, totals: TokenTotals, phase: str, steps: int) -> None:
-        """The pass's token and routing counts, from the drained totals
-        of its ``steps`` steps (docs/observability.md "Spans and
-        scopes")."""
+        """The pass's token, routing and scan counts, from the drained
+        totals of its ``steps`` steps (docs/observability.md "Spans and
+        scopes").  A model without experts, learned-sparse layers or
+        state-space layers publishes none of that group's counters."""
         from fmda_tpu.obs.registry import default_registry
-        from fmda_tpu.ops.moe import layout_tiles
 
         reg = default_registry()
+        if phase == "train":
+            reg.counter("train_tokens_total").inc(int(totals.tokens))
+        if totals.expert_pairs is not None:
+            self._publish_routing(reg, totals, phase, steps)
+        if totals.sparse_keys_kept is not None:
+            self._publish_selection(reg, totals, phase)
+        if totals.ssd_chunks is not None:
+            self._publish_scans(reg, totals, phase)
+
+    def _publish_routing(self, reg, totals: TokenTotals, phase: str,
+                         steps: int) -> None:
+        from fmda_tpu.ops.moe import layout_tiles
+
         tc, mc = self.train_cfg, self.model_cfg
         # a forward pass lays its tokens out once a layer: the whole
         # batch, or each microbatch of an accumulated train step
@@ -267,8 +291,6 @@ class NextToken:
         layout = steps * passes * layout_tiles(
             tc.batch_size // passes * tc.window * mc.moe_top_k,
             mc.experts_held[1])
-        if phase == "train":
-            reg.counter("train_tokens_total").inc(int(totals.tokens))
         reg.counter("moe_pairs_dropped_total").inc(int(totals.dropped))
         for layer, pairs in enumerate(np.asarray(totals.expert_pairs)):
             labels = dict(layer=str(layer), phase=phase)
@@ -281,18 +303,35 @@ class NextToken:
             reg.counter("moe_row_tiles_used_total", **labels).inc(
                 int(totals.row_tiles_used[layer]))
             reg.counter("moe_row_tiles_layout_total", **labels).inc(layout)
-        if totals.sparse_keys_kept is None:
-            return
+
+    def _publish_selection(self, reg, totals: TokenTotals, phase: str
+                           ) -> None:
         # a learned-sparse layer's selection: kept / (the rows' causal
         # pairs) is the share of the triangle the heads attend over
         kept = keys_kept_counts(totals.sparse_keys_kept)
-        for layer, layout in enumerate(mc.layer_layout):
+        for layer, layout in enumerate(self.model_cfg.layer_layout):
             if layout != SPARSE_LAYOUT:
                 continue
             labels = dict(layer=str(layer), phase=phase)
             reg.counter("sparse_keys_kept_total", **labels).inc(kept[layer])
             reg.counter("sparse_query_rows_total", **labels).inc(
                 int(totals.sparse_query_rows[layer]))
+
+    def _publish_scans(self, reg, totals: TokenTotals, phase: str) -> None:
+        # what the state-space layers' scans walked, and the carried
+        # states one sequence leaves (a matrix a head and chunk, float32)
+        mc, tc = self.model_cfg, self.train_cfg
+        reg.gauge("ssd_state_bytes").set(
+            -(-tc.window // mc.ssm_chunk) * mc.ssm_heads * mc.ssm_head_dim
+            * mc.ssm_state * 4)
+        for layer, layout in enumerate(mc.layer_layout):
+            if layout != SSM_LAYOUT:
+                continue
+            labels = dict(layer=str(layer), phase=phase)
+            reg.counter("ssd_chunks_total", **labels).inc(
+                int(totals.ssd_chunks[layer]))
+            reg.counter("ssd_positions_total", **labels).inc(
+                int(totals.ssd_positions[layer]))
 
     # -- inside the compiled step ---------------------------------------------
 
@@ -302,10 +341,14 @@ class NextToken:
 
     def loss_sums(self, params, out, batch: Batch):
         hidden, stats = out
+        mc = self.model_cfg
+        # a tied head is the embedding, transposed: the leaf's gradient
+        # comes from both uses
+        head = params["embed"].T if mc.tie_embeddings else params["head"]
         s, tokens, correct = chunked_next_token_loss(
-            hidden.reshape(-1, hidden.shape[-1]), params["head"],
+            hidden.reshape(-1, hidden.shape[-1]), head,
             batch.y.reshape(-1), batch.mask.reshape(-1),
-            chunk=self.model_cfg.loss_chunk)
+            chunk=mc.loss_chunk, logits_scaling=mc.logits_scaling)
         return s, tokens.astype(jnp.float32), (tokens, correct, stats)
 
     def loss(self, params, out, batch: Batch):
@@ -320,7 +363,8 @@ class NextToken:
         tokens, correct, stats = aux
         return TokenTotals(loss, tokens, correct, stats.expert_pairs,
                            stats.dropped, stats.row_tiles_used,
-                           stats.keys_kept, stats.query_rows)
+                           stats.keys_kept, stats.query_rows,
+                           stats.ssd_chunks, stats.ssd_positions)
 
 
 def task_class(model_cfg: ModelConfig):

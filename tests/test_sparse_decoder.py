@@ -261,7 +261,7 @@ def test_a_pass_publishes_what_the_selection_counted():
     (dict(indexer_head_dim=7), "indexer_head_dim"),
     (dict(hidden_act="gelu"), "hidden_act"),
     (dict(n_heads=6, n_kv_heads=2, use_pallas=True), "divides 128"),
-    (dict(layer_layout=(2, 3)), "layer_layout"),
+    (dict(layer_layout=(2, 4)), "layer_layout"),
 ])
 def test_config_errors_name_the_field(over, named):
     with pytest.raises(ValueError, match=named):

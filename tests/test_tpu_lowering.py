@@ -224,3 +224,50 @@ def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
     bwd = "sparse_bwd_dq" if 2 in layout else "flash_bwd_dq"
     assert len(re.findall(
         rf"(?m)^\s*%{bwd}(?:\.\d+)? = .*custom-call\(", text)) == len(layout)
+
+
+def test_flash_kernels_compile_at_32_on_8_heads_of_64_with_a_stated_scale(
+        one_chip):
+    """The hybrid configuration's attention layer: heads of 64 (the
+    kernels had run 128 only), four query heads a key-value head, no
+    window, the scores multiplied by the model's 1/64 in place of
+    1/sqrt(64)."""
+    from fmda_tpu.ops.pallas_attention import flash_attention
+
+    def step(q, k, v):
+        return jax.value_and_grad(lambda *a: flash_attention(
+            *a, causal=True, scale=0.015625).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(step).lower(
+        _shape(one_chip, (1, 32, 8192, 64), BF16),
+        _shape(one_chip, (1, 8, 8192, 64), BF16),
+        _shape(one_chip, (1, 8, 8192, 64), BF16)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert name in text
+
+
+def test_the_chunked_scan_compiles_at_64_heads_of_64_on_state_128_by_8192(
+        one_chip):
+    """The state-space layer's scan, value and gradient, at the hybrid
+    configuration's widths: 32 chunks of 256 walked four at a time, so
+    that the program's temporaries stay far under what the decay
+    matrices of the whole sequence would take alone (537 MB in float32,
+    and as much again for their cotangent)."""
+    from fmda_tpu.ops.ssd import ssd_scan
+
+    t, h, p, n = 8192, 64, 64, 128
+
+    def step(xs, d, a, b, c, skip):
+        return jax.value_and_grad(
+            lambda *args: ssd_scan(*args, chunk=256, dtype=BF16)[0].sum(),
+            tuple(range(6)))(xs, d, a, b, c, skip)
+
+    compiled = jax.jit(step).lower(
+        _shape(one_chip, (1, t, h, p), BF16),
+        _shape(one_chip, (1, t, h), jnp.float32),
+        _shape(one_chip, (h,), jnp.float32),
+        _shape(one_chip, (1, t, n), BF16), _shape(one_chip, (1, t, n), BF16),
+        _shape(one_chip, (h,), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_200_000_000
