@@ -6,7 +6,8 @@ types — ``fmda_tpu.cli.main([...])`` and ``Application`` — at the published
 width (108 features, hidden 32, window 30, float32) with seeded random
 weights: the serving pool answers a fleet, the trainer takes ten steps and
 resumes from its checkpoint, the continuous-train loop hot-swaps the live
-pool, and the four Pallas kernels run compiled against their ``jnp``
+pool, and the five Pallas kernels (gru, lstm, ssm, flash attention, attention
+over picked keys) run compiled against their ``jnp``
 references.  Every check is the repo's own: counts that must balance,
 finite probabilities, agreement with a float32 reference.
 
@@ -598,6 +599,15 @@ def _kernel_errs(got, want, tol, suffix="") -> dict:
             f"grad{suffix}": (_err(got[1], want[1]), tol)}
 
 
+def _f32_kernel_errs(run, want, *args) -> dict:
+    """A float32 kernel as served and traced under `highest`."""
+    n = len(args)
+    errs = _kernel_errs(_value_and_grads(run, n)(*args), want, TOL_KERNEL_F32)
+    errs.update(_kernel_errs(_value_and_grads(run, n, "highest")(*args),
+                             want, TOL_KERNEL_F32_FULL, "_full_f32"))
+    return errs
+
+
 class _KernelTable:
     """Prints every comparison first and fails afterwards, so one chip
     run shows the whole table."""
@@ -707,14 +717,43 @@ def _flash_kernel(table: _KernelTable) -> None:
         # mha without use_flash is the jnp online-softmax reference
         want = _value_and_grads(
             lambda *a: A.mha(*a, causal=causal), 3, "highest")(q, k, v)
-        errs = _kernel_errs(
-            _value_and_grads(run, 3)(q, k, v), want, TOL_KERNEL_F32)
-        errs.update(_kernel_errs(
-            _value_and_grads(run, 3, "highest")(q, k, v),
-            want, TOL_KERNEL_F32_FULL, "_full_f32"))
         table.row(
             "flash_float32",
-            f"flash B={b} N={n} T={t} D={d} float32 causal={causal}", errs)
+            f"flash B={b} N={n} T={t} D={d} float32 causal={causal}",
+            _f32_kernel_errs(run, want, q, k, v))
+
+
+def _sparse_kernel(table: _KernelTable) -> None:
+    """Attention over picked keys, forward and gradient, with the block
+    pairs that follow from T (the forward's key block is the wider)."""
+    from fmda_tpu.ops import pallas_sparse_attention as kernels
+    from fmda_tpu.ops import sparse_attention as sa
+
+    b, n, g, t, d, topk = 1, 8, 2, 4096, 128, 512
+    check(kernels.sparse_supported(t, n // g, d),
+          "T=4096 is off the learned-sparse gate")
+    check(kernels.fwd_blocks_for(t)[1] > kernels.blocks_for(t)[1],
+          "the forward's key block is no wider than the backward's")
+    r = np.random.default_rng(0)
+
+    def arr(*shape):
+        return jnp.asarray(r.normal(size=shape), jnp.float32)
+
+    q, k, v = arr(b, n, t, d), arr(b, g, t, d), arr(b, g, t, d)
+    picked, kept = sa.select_keys(arr(b, 4, t, 32), arr(b, t, 32),
+                                  arr(b, t, 4), topk, use_kernels=True)
+    check(int(kept.sum()) == int(np.minimum(np.arange(t) + 1, topk).sum()),
+          "the selection kernels kept another count than min(t + 1, topk)")
+
+    def run(*a):
+        return kernels.sparse_attention(*a, picked)
+
+    # sparse_mha without use_kernels is the jnp path under the same mask
+    want = _value_and_grads(
+        lambda *a: sa.sparse_mha(*a, picked), 3, "highest")(q, k, v)
+    table.row("sparse_float32",
+              f"sparse B={b} N={n}/{g} T={t} D={d} top-{topk} float32",
+              _f32_kernel_errs(run, want, q, k, v))
 
 
 def kernels(tmp: str) -> None:
@@ -725,6 +764,7 @@ def kernels(tmp: str) -> None:
     _scan_kernels(table)
     _ssm_kernel(table)
     _flash_kernel(table)
+    _sparse_kernel(table)
 
     # the run that faulted in the one old capture: the float32 B=256
     # train step with the GRU kernel — ten steps, through `demo`

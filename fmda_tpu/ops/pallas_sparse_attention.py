@@ -32,6 +32,29 @@ scope in the caller:
   one scratch.  ``lse`` and ``delta`` ride as ``(T, 128)`` tiles whose
   lane ``l`` holds head ``l // (128 / group)``.
 
+Which kernel uses which blocks (``(query rows, keys)``; at 16,384 tokens
+in brackets).  ``sparse_index`` and the two backward kernels take
+:func:`blocks_for` [``(256, 512)``]: their ``lse`` and ``delta`` arrive
+finished, a block is products and element-wise work, and they run at
+~80 % of the MXU there.  ``sparse_fwd`` takes :func:`fwd_blocks_for`
+[``(256, 1024)``], a key block twice as wide, because what it pays a
+block and head is *per row, not per element*: the online softmax's lane
+reductions through the XLU (~10 ns a vreg of eight rows by the sweep's
+differences, and nothing else of the head's chain can start until the
+maximum is known), the
+``corr`` exponential, the rescale of ``acc``.  Three things keep that
+off the MXU's path, each exact (PERF.md section 6, PR 35, has the sweep:
+33.6 ms a run -> 15.4 at the learned-sparse cell's shape): the wide
+block halves the reductions a score; the row sum is not reduced at all
+until ``_finalize`` (``l`` is kept a lane tile wide, 128 partial sums a
+row, so a block folds its lane tiles into it on the VPU); and a head's
+``p v`` product is issued after the *next* head's scores and softmax, so
+one head's reductions sit under another's products.  A pair the mask
+drops scores ``-inf`` under a finite running maximum, so its ``exp`` is
+exactly 0 without a second select.  Wider still (2,048 keys) pays the
+causal band's overshoot (12.5 % against 5 %) for what the lane-wide sum
+has already taken.
+
 One mask serves every head.  Support envelope
 (:func:`sparse_supported`): ``T`` a multiple of 128, ``group`` a divisor
 of 128, ``D <= 512``.
@@ -68,10 +91,18 @@ def _largest_dividing(n: int, candidates) -> int:
 
 
 def blocks_for(seq_len: int) -> Tuple[int, int]:
-    """``(query rows, keys)`` of a block of the attention and index
-    kernels at this length."""
+    """``(query rows, keys)`` of a block of the index kernel and of
+    attention's backward kernels at this length."""
     return (_largest_dividing(seq_len, (256, 128)),
             _largest_dividing(seq_len, (512, 256, 128)))
+
+
+def fwd_blocks_for(seq_len: int) -> Tuple[int, int]:
+    """``(query rows, keys)`` of a block of attention's forward kernel:
+    the key block as wide as 1,024 where the length allows it (module
+    docstring), else what :func:`blocks_for` gives."""
+    return (blocks_for(seq_len)[0],
+            _largest_dividing(seq_len, (1024, 512, 256, 128)))
 
 
 def sparse_supported(seq_len: int, group: int, d_head: int) -> bool:
@@ -275,12 +306,22 @@ def _pack_heads(columns, rows: int):
     return out
 
 
-def _masked_scores(q, k, keep):
+def _masked_scores(q, k, keep, fill=_NEG):
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    return jnp.where(keep, s, _NEG), scale
+    return jnp.where(keep, s, fill), scale
+
+
+def _fold_lanes(x):
+    """(rows, n * 128) -> (rows, 128): the sum of the lane tiles,
+    pairwise, on the VPU."""
+    tiles = [x[:, i:i + 128] for i in range(0, x.shape[1], 128)]
+    while len(tiles) > 1:  # an odd tile out waits for the next round
+        tiles = [a + b for a, b in zip(tiles[::2], tiles[1::2])
+                 ] + tiles[len(tiles) & ~1:]
+    return tiles[0]
 
 
 def _in_band(qi, ki, bq: int, bk: int):
@@ -290,6 +331,10 @@ def _in_band(qi, ki, bq: int, bk: int):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, bq: int, bk: int, n_k: int):
+    """``m_scr[h]`` holds a row's running maximum on every lane,
+    ``l_scr[h]`` 128 partial sums a row (lane ``j``: the keys of lane
+    ``j`` of every lane tile so far), ``acc_scr[h]`` the unnormalised
+    output (module docstring)."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     group = q_ref.shape[1]
 
@@ -303,27 +348,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     def _compute():
         keep = mask_ref[0].astype(jnp.int32) != 0
         k, v = k_ref[0], v_ref[0]
-        for h in range(group):
-            s, _ = _masked_scores(q_ref[0, h], k, keep)
+
+        def softmax(h):
+            # -inf off the picked keys: under the finite m_new (_NEG
+            # where a row has seen no pick yet) exp gives exactly zero
+            s, _ = _masked_scores(q_ref[0, h], k, keep, -jnp.inf)
             m_prev = m_scr[h][:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
             corr = jnp.exp(m_prev - m_new)
-            # exactly zero off the picked keys, also where a row has
-            # seen none yet and m_new is still _NEG
-            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-            l_new = l_scr[h][:, :1] * corr + jnp.sum(
-                p, axis=-1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            l_scr[h] = l_scr[h] * corr + _fold_lanes(p)
             m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            return h, p.astype(v.dtype), corr
+
+        def accumulate(h, p, corr):
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        # a head's p v is issued behind the next head's scores and
+        # softmax: the compiler keeps this order, and the one head's
+        # reductions then sit under the other's products
+        behind = None
+        for h in range(group):
+            ahead = softmax(h)
+            if behind is not None:
+                accumulate(*behind)
+            behind = ahead
+        accumulate(*behind)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
         columns = []
         for h in range(group):
-            l = l_scr[h][:, :1]
+            l = jnp.sum(l_scr[h], axis=-1, keepdims=True)
             l_safe = jnp.where(l == 0.0, 1.0, l)
             o_ref[0, h] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
             columns.append(jnp.where(
@@ -511,11 +569,12 @@ def _bwd_impl(q, k, v, mask, o, lse, do, *, blocks, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _sparse(q, k, v, mask, blocks, interpret):
-    return _fwd_impl(q, k, v, mask, blocks=blocks, interpret=interpret)[0]
+    """``blocks``: the forward kernel's pair, the backward kernels'."""
+    return _fwd_impl(q, k, v, mask, blocks=blocks[0], interpret=interpret)[0]
 
 
 def _sparse_fwd(q, k, v, mask, blocks, interpret):
-    o, lse = _fwd_impl(q, k, v, mask, blocks=blocks, interpret=interpret)
+    o, lse = _fwd_impl(q, k, v, mask, blocks=blocks[0], interpret=interpret)
     # named on the residuals' own arrays (pallas_attention._flash_fwd);
     # lse is already packed by head, a lane a head and row
     o = checkpoint_name(o, CORE_OUT)
@@ -525,7 +584,7 @@ def _sparse_fwd(q, k, v, mask, blocks, interpret):
 
 def _sparse_bwd(blocks, interpret, residuals, do):
     q, k, v, mask, o, lse = residuals
-    dq, dk, dv = _bwd_impl(q, k, v, mask, o, lse, do, blocks=blocks,
+    dq, dk, dv = _bwd_impl(q, k, v, mask, o, lse, do, blocks=blocks[1],
                            interpret=interpret)
     return dq, dk, dv, None
 
@@ -534,19 +593,23 @@ _sparse.defvjp(_sparse_fwd, _sparse_bwd)
 
 
 def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     mask: jax.Array, *, blocks=None,
+                     mask: jax.Array, *, blocks=None, fwd_blocks=None,
                      interpret: bool = False) -> jax.Array:
     """Attention of q (B, N, T, D) over the keys of k/v (B, G, T, D)
     that ``mask`` (B, T, T) int8 marks, one mask for every head; rows of
     the mask hold nothing above the causal diagonal.  Differentiable in
-    q, k, v."""
+    q, k, v.  ``blocks`` and ``fwd_blocks`` are for the tests and a
+    sweep: the pairs follow from ``T`` (:func:`blocks_for` backward,
+    :func:`fwd_blocks_for` forward; a ``blocks`` given alone serves
+    both)."""
     b, n, t, d = q.shape
     g = k.shape[1]
     if n % g or not sparse_supported(t, n // g, d):
         raise ValueError(
             f"sparse kernels unsupported for T={t} heads={n}/{g} D={d}; "
             "gate on sparse_supported()")
+    pairs = (tuple(fwd_blocks or blocks or fwd_blocks_for(t)),
+             tuple(blocks or blocks_for(t)))
     out = _sparse(q.reshape(b * g, n // g, t, d), k.reshape(b * g, t, d),
-                  v.reshape(b * g, t, d), mask,
-                  tuple(blocks or blocks_for(t)), interpret)
+                  v.reshape(b * g, t, d), mask, pairs, interpret)
     return out.reshape(b, n, t, d)

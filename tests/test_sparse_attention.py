@@ -4,6 +4,8 @@ and its kernels under the Pallas interpreter): the selection is exactly
 attention; the kernels give what the ``jax.numpy`` path gives, forward
 and backward."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,14 +97,44 @@ def test_with_topk_at_least_t_the_layer_is_causal_attention():
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("t,topk,blocks", [
-    (128, 16, (32, 128)), (256, 24, (64, 128)), (512, 600, (128, 256))])
-def test_the_attention_kernels_match_the_jnp_path(t, topk, blocks):
+def _first_block_unpicked(picked, width=256):
+    """Every row past the first ``width`` keys loses its picks among
+    them (a row of the forward's first wide key block sees none: its
+    maximum stays the floor and its sum 0 until a later block), and
+    keeps its own position."""
+    t = picked.shape[-1]
+    row, col = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    return jnp.where((row >= width) & (col < width), 0, picked) | (
+        row == col).astype(picked.dtype)
+
+
+def _with_an_empty_row(picked, row=5):
+    """One query with no key at all: ``o`` 0, ``lse`` the floor, and no
+    gradient through it."""
+    return picked.at[:, row].set(0)
+
+
+@pytest.mark.parametrize("t,topk,blocks,fwd_blocks,edit", [
+    (128, 16, (32, 128), None, None),
+    (256, 24, (64, 128), None, None),
+    (512, 600, (128, 256), None, None),
+    # the forward's pair is its own: a key block wider than half the
+    # sequence over a backward of narrower ones
+    (512, 48, (128, 128), (128, 512), None),
+    (512, 600, (64, 256), (128, 512), None),
+    (1024, 96, None, None, None),          # the pairs that follow from T
+    (512, 48, (128, 128), (128, 256), _first_block_unpicked),
+    (512, 48, (64, 128), (128, 512), _with_an_empty_row),
+])
+def test_the_attention_kernels_match_the_jnp_path(t, topk, blocks,
+                                                  fwd_blocks, edit):
     """Forward, dQ, dK, dV of the masked flash kernels against ``mha``
     under the same mask; a query block shorter than a key block, a row
     whose first key blocks hold no pick, grouped heads."""
     q, k, w = _indexer(t, seed=3, ties=False)
     picked, _ = sa.select_keys(q, k, w, topk)
+    if edit is not None:
+        picked = edit(picked)
     qq, kk, vv = _qkv(t)
 
     def via(fn):
@@ -113,10 +145,45 @@ def test_the_attention_kernels_match_the_jnp_path(t, topk, blocks):
         want = via(lambda q, k, v: sa.sparse_mha(q, k, v, picked))(
             qq, kk, vv)
         got = via(lambda q, k, v: kernels.sparse_attention(
-            q, k, v, picked, blocks=blocks, interpret=True))(qq, kk, vv)
+            q, k, v, picked, blocks=blocks, fwd_blocks=fwd_blocks,
+            interpret=True))(qq, kk, vv)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(
             a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()) + 1e-6)
+
+
+@pytest.mark.parametrize("edit", [
+    None, functools.partial(_first_block_unpicked, width=1024),
+    _with_an_empty_row])
+def test_the_forwards_own_blocks_change_only_the_order_of_float32_sums(edit):
+    """``o`` and the packed ``lse`` tile of the forward's default pair
+    (a 1,024-key block, the row sum a lane tile wide until the end)
+    against the backward's pair in the same kernel: the same float32
+    mathematics, summed in another order."""
+    t = 2048
+    assert kernels.fwd_blocks_for(t) == (256, 1024)
+    assert kernels.blocks_for(t) == (256, 512)
+    assert kernels.fwd_blocks_for(512) == kernels.blocks_for(512)
+    assert kernels.fwd_blocks_for(1536) == kernels.blocks_for(1536)
+    q, k, w = _indexer(t, seed=4, ties=False)
+    picked, _ = sa.select_keys(q[:1], k[:1], w[:1], 96)
+    if edit is not None:
+        picked = edit(picked)
+    qq, kk, vv = _qkv(t)  # one sequence, as _fwd_impl takes it
+    qq, kk, vv = qq[0].reshape(G, N // G, t, D), kk[0], vv[0]
+    with jax.default_matmul_precision("highest"):
+        o, lse = kernels._fwd_impl(qq, kk, vv, picked,
+                                   blocks=kernels.fwd_blocks_for(t),
+                                   interpret=True)
+        o_ref, lse_ref = kernels._fwd_impl(qq, kk, vv, picked,
+                                           blocks=kernels.blocks_for(t),
+                                           interpret=True)
+    np.testing.assert_allclose(lse, lse_ref, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(o, o_ref, rtol=1e-5, atol=1e-6)
+    if edit is _with_an_empty_row:
+        assert not np.asarray(o)[:, :, 5].any()
+        assert (np.asarray(lse)[:, 5] == kernels._NEG).all()
 
 
 def test_the_kernels_gate_names_what_they_cannot_tile():

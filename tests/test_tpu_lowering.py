@@ -53,20 +53,53 @@ def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
     """Attention over picked keys, forward and backward, at the
     learned-sparse configuration's widths: a (16384, 16384) int8 mask,
     eight query heads a grid step."""
-    from fmda_tpu.ops.pallas_sparse_attention import sparse_attention
+    import re
+
+    from fmda_tpu.ops import pallas_sparse_attention as kernels
+
+    t = 16384
+    # the forward pays its row state once a 1,024-key block, the
+    # backward kernels keep their pair (the module's docstring says why)
+    assert kernels.fwd_blocks_for(t) == (256, 1024)
+    assert kernels.blocks_for(t) == (256, 512)
 
     def step(q, k, v, mask):
-        return jax.value_and_grad(lambda q, k, v: sparse_attention(
+        return jax.value_and_grad(lambda q, k, v: kernels.sparse_attention(
             q, k, v, mask).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
 
-    compiled = jax.jit(step).lower(
-        _shape(one_chip, (1, 32, 16384, 128), BF16),
-        _shape(one_chip, (1, 4, 16384, 128), BF16),
-        _shape(one_chip, (1, 4, 16384, 128), BF16),
-        _shape(one_chip, (1, 16384, 16384), jnp.int8)).compile()
+    args = (_shape(one_chip, (1, 32, t, 128), BF16),
+            _shape(one_chip, (1, 4, t, 128), BF16),
+            _shape(one_chip, (1, 4, t, 128), BF16),
+            _shape(one_chip, (1, t, t), jnp.int8))
+    # the grids as traced: (key-value heads, query blocks, key blocks),
+    # the dK/dV sweep with the two block axes the other way round
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(step)(*args).jaxpr)
+    assert grids == {"sparse_fwd": (4, 64, 16),
+                     "sparse_bwd_dkv": (4, 32, 64),
+                     "sparse_bwd_dq": (4, 64, 32)}
+    compiled = jax.jit(step).lower(*args).compile()
     text = compiled.as_text()
-    for name in ("sparse_fwd", "sparse_bwd_dkv", "sparse_bwd_dq"):
-        assert name in text
+    calls = {name: re.findall(
+        rf"(?m)^\s*%\S*{name}\S* = .*custom-call\(.*$", text)
+        for name in grids}
+    for name, lines in calls.items():
+        assert len(lines) == 1, name
+        # the kernel compiled inside the limit it asks for
+        assert f'"size":"{kernels._VMEM_LIMIT}"' in lines[0], name
+    # the temporaries stay where they were (134.3 MB: o's cotangent in
+    # float32 and the packed row statistics): no (T, T) float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 140_000_000
+    assert not re.search(rf"f32\[(1,)?{t},{t}\]", text)
 
 
 def test_index_and_selection_kernels_compile_at_16_heads_of_64_by_16384(
