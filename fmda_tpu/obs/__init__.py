@@ -37,7 +37,7 @@ from fmda_tpu.obs.device import (
     device_report,
     tracked_jit,
 )
-from fmda_tpu.obs.events import EventLog
+from fmda_tpu.obs.events import EventLog, default_epoch_log
 from fmda_tpu.obs.observability import (
     Observability,
     engine_families,
@@ -88,6 +88,7 @@ __all__ = [
     "TrackedFunction",
     "Tracer",
     "configure_tracing",
+    "default_epoch_log",
     "default_ledger",
     "default_memory_monitor",
     "default_profiler",

@@ -25,7 +25,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Dict, Optional, Tuple
 
-from fmda_tpu.obs.events import EventLog
+from fmda_tpu.obs.events import EventLog, default_epoch_log
 from fmda_tpu.obs.registry import (
     MetricsRegistry,
     Sample,
@@ -185,6 +185,10 @@ class Observability:
             capacity=self.config.events_capacity,
             path=self.config.events_path,
         )
+        # the trainer's one record an epoch (kind ``train.epoch``) is
+        # kept in the process's own ring; mirrored here it is on
+        # /events (latest instance wins, as for the ledger below)
+        default_epoch_log().mirror = self.events
         if self.registry.enabled:
             # the tracer's e2e_tick_seconds histogram + per-stage
             # attribution table ride every /snapshot and `status` (empty

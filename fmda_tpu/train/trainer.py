@@ -136,6 +136,9 @@ class Trainer:
         # [BatchGroup], or [Batch] where steps run alone) — see
         # _run_chunks; the dataset ref pins id() validity
         self._placed_cache: Dict[Any, Tuple[Any, List[Any]]] = {}
+        # epochs fit / fit_multi have finished: the index of the one
+        # being run, on its record and on its pass-level spans
+        self._epochs_run = 0
 
     # -- state ---------------------------------------------------------------
 
@@ -494,6 +497,7 @@ class Trainer:
         chunk_indices: Sequence[int],
         rng: Optional[jax.Array],
         train: bool,
+        account: Optional[Dict[str, Any]] = None,
     ) -> Tuple[TrainState, EpochMetrics, np.ndarray]:
         # one flat host generator over every chunk, behind one pipeline:
         # the window gather/normalization of chunk k+1 (cached after the
@@ -510,37 +514,47 @@ class Trainer:
         cache_on = (self.train_cfg.cache_chunks > 0
                     and len(chunk_indices) <= self.train_cfg.cache_chunks)
         key = (id(dataset), tuple(chunk_indices))
-        if cache_on:
-            from fmda_tpu.obs.registry import default_registry
-
-            entry = self._placed_cache.get(key)
-            # the entry pins its dataset, so a live hit can never be an
-            # id()-reuse collision from a collected dataset
-            hit = entry is not None and entry[0] is dataset
-            default_registry().counter(
-                "train_placed_cache_total",
-                result="hit" if hit else "miss").inc()
-            if hit:
-                return self._run_batches(state, (entry[1],), rng, train)
+        sink: Optional[List[Any]] = None  # a pass that is to be cached
 
         def host_batches() -> Iterable[Batch]:
             for idx in chunk_indices:
                 yield from self.task.batches(dataset, idx)
 
-        placed = self._place_batches(host_batches(), state)
-        if not cache_on:
-            return self._run_batches(state, (placed,), rng, train)
-        sink: List[Any] = []
+        def open_pass():
+            # the pass's input, opened by _run_batches under its
+            # <phase>_pass_open span
+            nonlocal sink
+            if cache_on:
+                from fmda_tpu.obs.registry import default_registry
 
-        def capturing() -> Iterable:
-            for b in placed:
-                sink.append(b)
-                yield b
+                entry = self._placed_cache.get(key)
+                # the entry pins its dataset, so a live hit can never be
+                # an id()-reuse collision from a collected dataset
+                hit = entry is not None and entry[0] is dataset
+                result = "hit" if hit else "miss"
+                default_registry().counter(
+                    "train_placed_cache_total", result=result).inc()
+                if account is not None:
+                    account["cache"] = result
+                if hit:
+                    return (entry[1],)
+            placed = self._place_batches(host_batches(), state)
+            if not cache_on:
+                return (placed,)
+            sink = []
 
-        out = self._run_batches(state, (capturing(),), rng, train)
-        self._placed_cache[key] = (dataset, sink)
-        while len(self._placed_cache) > 4:  # train + val + headroom
-            self._placed_cache.pop(next(iter(self._placed_cache)))
+            def capturing() -> Iterable:
+                for b in placed:
+                    sink.append(b)
+                    yield b
+
+            return (capturing(),)
+
+        out = self._run_batches(state, open_pass, rng, train, account)
+        if sink is not None:
+            self._placed_cache[key] = (dataset, sink)
+            while len(self._placed_cache) > 4:  # train + val + headroom
+                self._placed_cache.pop(next(iter(self._placed_cache)))
         return out
 
     def _run_batches(
@@ -549,7 +563,13 @@ class Trainer:
         batch_iterables,
         rng: Optional[jax.Array],
         train: bool,
+        account: Optional[Dict[str, Any]] = None,
     ) -> Tuple[TrainState, EpochMetrics, np.ndarray]:
+        """One pass over ``batch_iterables`` (the iterables, or a
+        callable that opens them: a pass of ``fit`` opens its input
+        under this pass's own ``<phase>_pass_open`` span).  Into
+        ``account`` go the pass's boundaries on the host clock and its
+        counts, read once a pass (fmda_tpu.train.epoch_account)."""
         import time as _time
 
         from fmda_tpu.obs.registry import default_registry
@@ -564,16 +584,24 @@ class Trainer:
         # Host spans that tile one call into the compiled step, on the
         # profiler's clock (a flag test each when nothing traces;
         # docs/observability.md "Spans and scopes"): <phase>_next_batch,
-        # <phase>, <phase>_fold, and <phase>_pass_drain once a pass.
-        # What is left uncovered is the loop's own Python.
+        # <phase>, <phase>_fold; and once a pass, around the loop,
+        # <phase>_pass_open, <phase>_pass_drain, <phase>_pass_publish,
+        # each with the epoch's index.  What is left uncovered is the
+        # loop's own Python.
         next_name, fold_name = phase + "_next_batch", phase + "_fold"
+        epoch = self._epochs_run
+        calls_before = call_counter.value
         # Each step's results are added to running on-device accumulators
         # inside the compiled step itself — the host never blocks
         # mid-pass, dispatches nothing but the step, and memory stays
         # O(1) instead of holding every batch's arrays live across an
         # epoch.  One device_get at the end drains the totals.
-        totals = self.zero_totals()
+        with span(phase + "_pass_open", epoch=epoch):
+            if callable(batch_iterables):
+                batch_iterables = batch_iterables()
+            totals = self.zero_totals()
         step_no = 0
+        t_run = clock()
         for batches in batch_iterables:
             it = iter(batches)
             while True:
@@ -616,12 +644,22 @@ class Trainer:
                 "window=%d/chunk_size=%d, or empty chunk split) — metrics "
                 "are NaN", self.train_cfg.window, self.train_cfg.chunk_size,
             )
-            return (state,) + self.task.epoch_metrics(None, 0)
-        # the one place the host waits for the device
-        with span(phase + "_pass_drain"):
-            drained = jax.device_get(totals)
-            self.task.publish(drained, phase, step_no)
-        return (state,) + self.task.epoch_metrics(drained, step_no)
+            drained = None
+        else:
+            # the one place the host waits for the device
+            with span(phase + "_pass_drain", epoch=epoch):
+                drained = jax.device_get(totals)
+        t_drained = clock()
+        with span(phase + "_pass_publish", epoch=epoch):
+            if drained is not None:
+                self.task.publish(drained, phase, step_no)
+            metrics = self.task.epoch_metrics(drained, step_no)
+        if account is not None:
+            account.update(
+                t_run=t_run, t_drained=t_drained, t_published=clock(),
+                steps=step_no,
+                calls=int(call_counter.value - calls_before))
+        return (state,) + metrics
 
     def _warn_if_norm_drifted(self, dataset: ChunkDataset) -> None:
         """Resume runs recompute normalization from the *current* source;
@@ -663,46 +701,55 @@ class Trainer:
         cache tier: host window gathers AND the placed device batches,
         which are keyed on dataset identity.
         """
-        tc = self.train_cfg
-        rng = jax.random.PRNGKey(tc.seed) if rng is None else rng
-        init_rng, step_rng = jax.random.split(rng)
-        if dataset is None:
-            dataset = self.task.dataset(
-                source, bid_levels=bid_levels, ask_levels=ask_levels)
-        train_chunks, val_chunks, _ = dataset.split(tc.val_size, tc.test_size)
-        state = (
-            initial_state if initial_state is not None
-            else self.init_state(init_rng)
-        )
-        if initial_state is not None:
-            self._warn_if_norm_drifted(dataset)
-        history: Dict[str, List[EpochMetrics]] = {"train": [], "val": []}
+        import time as _time
+
         from fmda_tpu.obs.registry import default_registry
         from fmda_tpu.utils.tracing import span
 
-        reg = default_registry()
-        epoch_hist = reg.histogram("train_epoch_seconds")
-        epoch_counter = reg.counter("train_epochs_total")
-        import time as _time
-
-        for epoch in range(epochs if epochs is not None else tc.epochs):
-            t_epoch = _time.perf_counter()
-            state, train_metrics, _ = self._run_chunks(
-                state, dataset, train_chunks, step_rng, train=True
+        # The epoch is accounted for whole (fmda_tpu.train.epoch_account):
+        # fit_setup, then each pass's open / run / publish, then
+        # fit_epoch_end, each part beginning on the clock read that ended
+        # the one before it; a call's first epoch begins here.
+        clock = _time.perf_counter
+        t_start = clock()
+        with span("fit_setup", epoch=self._epochs_run):
+            tc = self.train_cfg
+            rng = jax.random.PRNGKey(tc.seed) if rng is None else rng
+            init_rng, step_rng = jax.random.split(rng)
+            if dataset is None:
+                dataset = self.task.dataset(
+                    source, bid_levels=bid_levels, ask_levels=ask_levels)
+            train_chunks, val_chunks, _ = dataset.split(
+                tc.val_size, tc.test_size)
+            state = (
+                initial_state if initial_state is not None
+                else self.init_state(init_rng)
             )
+            if initial_state is not None:
+                self._warn_if_norm_drifted(dataset)
+            history: Dict[str, List[EpochMetrics]] = {"train": [], "val": []}
+            reg = default_registry()
+            epoch_hist = reg.histogram("train_epoch_seconds")
+            epoch_counter = reg.counter("train_epochs_total")
+            compiled = self._compiled_programs()
+        t_epoch = clock()
+        for epoch in range(epochs if epochs is not None else tc.epochs):
+            passes: Dict[str, Dict[str, Any]] = {"train": {}}
+            state, train_metrics, _ = self._run_chunks(
+                state, dataset, train_chunks, step_rng, True,
+                passes["train"])
             history["train"].append(train_metrics)
             if val_chunks:
+                passes["eval"] = {}
                 _, val_metrics, _ = self._run_chunks(
-                    state, dataset, val_chunks, None, train=False
-                )
+                    state, dataset, val_chunks, None, False, passes["eval"])
             else:
                 # continuous fine-tune rounds run val_size=0 (quality is
                 # judged by the shadow gate, not a holdout) — NaN metrics
                 # without the empty-pass warning
                 val_metrics, _ = self.task.epoch_metrics(None, 0)
-            with span("fit_epoch_end"):
+            with span("fit_epoch_end", epoch=self._epochs_run):
                 history["val"].append(val_metrics)
-                epoch_hist.observe(_time.perf_counter() - t_epoch)
                 epoch_counter.inc()
                 log.info(
                     "epoch %d: train loss=%.4f acc=%.4f hamming=%.4f | "
@@ -714,7 +761,38 @@ class Trainer:
                     val_metrics.accuracy,
                     val_metrics.hamming,
                 )
+                t_end, compiled = self._end_epoch(
+                    t_start, t_epoch, passes, compiled)
+                epoch_hist.observe(t_end - t_epoch)
+            t_start = t_epoch = t_end
         return state, history, dataset
+
+    def _compiled_programs(self) -> Optional[int]:
+        """Programs the tracked steps have compiled, all kinds together
+        (None without jax's cache probe: ``compile_counts``)."""
+        counts = list(self.compile_counts.values())
+        return None if None in counts else sum(counts)
+
+    def _end_epoch(self, t_start: float, t_epoch: float,
+                   passes: Dict[str, Dict[str, Any]],
+                   compiled_before: Optional[int]):
+        """The epoch's last clock read and its ``train.epoch`` record
+        (fmda_tpu.train.epoch_account); the next epoch's index.  Returns
+        the read and the compiled-program count, for the next epoch to
+        begin from."""
+        import time as _time
+
+        from fmda_tpu.train.epoch_account import emit_epoch
+
+        compiled = self._compiled_programs()
+        t_end = _time.perf_counter()
+        emit_epoch(
+            self._epochs_run, t_start, t_epoch, passes, t_end,
+            warm=self._train_step.warm,
+            compiles=(None if None in (compiled, compiled_before)
+                      else compiled - compiled_before))
+        self._epochs_run += 1
+        return t_end, compiled
 
     def fit_multi(
         self,
@@ -736,52 +814,69 @@ class Trainer:
         (``len(sources) * k`` rows/step — e.g. 50 x 16 = 800), so each
         gradient mixes all instruments and the device sees one big batch.
         """
+        import time as _time
+
         from fmda_tpu.train.multiticker import MultiTickerDataset
+        from fmda_tpu.utils.tracing import span
 
-        tc = self.train_cfg
-        rng = jax.random.PRNGKey(tc.seed) if rng is None else rng
-        init_rng, step_rng = jax.random.split(rng)
-        mtd = MultiTickerDataset(
-            sources, tc.chunk_size, tc.window,
-            bid_levels=bid_levels, ask_levels=ask_levels,
-        )
-        train_chunks, val_chunks, _ = mtd.splits(tc.val_size, tc.test_size)
-        if mixed_batch_per_ticker:
-            k = mixed_batch_per_ticker
-
-            def host_batches(chunks):
-                # mixed composition is the expensive host stage (~12 ms
-                # per 800-row batch): _place_batches runs it in the
-                # composer thread and double-buffers the transfer
-                for rc in mtd.rounds(chunks):
-                    yield from mtd.mixed_batches(rc, k)
-        else:
-            def host_batches(chunks):
-                for t, c in chunks:
-                    yield from mtd.batches(t, c, tc.batch_size)
-
-        def placed(chunks, state):
-            # one pipeline a pass, as _run_chunks has: a group then runs
-            # on across a chunk's end, in the pass's own order, and only
-            # the pass's last group is padded
-            return (self._place_batches(host_batches(chunks), state),)
-
-        state = self.init_state(init_rng)
-        history: Dict[str, List[EpochMetrics]] = {"train": [], "val": []}
-        for epoch in range(epochs if epochs is not None else tc.epochs):
-            state, train_metrics, _ = self._run_batches(
-                state, placed(train_chunks, state), step_rng, train=True,
+        clock = _time.perf_counter  # the epoch's account, as in fit
+        t_start = clock()
+        with span("fit_setup", epoch=self._epochs_run):
+            tc = self.train_cfg
+            rng = jax.random.PRNGKey(tc.seed) if rng is None else rng
+            init_rng, step_rng = jax.random.split(rng)
+            mtd = MultiTickerDataset(
+                sources, tc.chunk_size, tc.window,
+                bid_levels=bid_levels, ask_levels=ask_levels,
             )
+            train_chunks, val_chunks, _ = mtd.splits(
+                tc.val_size, tc.test_size)
+            if mixed_batch_per_ticker:
+                k = mixed_batch_per_ticker
+
+                def host_batches(chunks):
+                    # mixed composition is the expensive host stage (~12
+                    # ms per 800-row batch): _place_batches runs it in
+                    # the composer thread and double-buffers the transfer
+                    for rc in mtd.rounds(chunks):
+                        yield from mtd.mixed_batches(rc, k)
+            else:
+                def host_batches(chunks):
+                    for t, c in chunks:
+                        yield from mtd.batches(t, c, tc.batch_size)
+
+            def placed(chunks, state):
+                # one pipeline a pass, as _run_chunks has (opened under
+                # the pass's own span): a group then runs on across a
+                # chunk's end, in the pass's own order, and only the
+                # pass's last group is padded
+                return lambda: (
+                    self._place_batches(host_batches(chunks), state),)
+
+            state = self.init_state(init_rng)
+            history: Dict[str, List[EpochMetrics]] = {"train": [], "val": []}
+            compiled = self._compiled_programs()
+        t_epoch = clock()
+        for epoch in range(epochs if epochs is not None else tc.epochs):
+            passes: Dict[str, Dict[str, Any]] = {"train": {}, "eval": {}}
+            state, train_metrics, _ = self._run_batches(
+                state, placed(train_chunks, state), step_rng, True,
+                passes["train"])
             history["train"].append(train_metrics)
             _, val_metrics, _ = self._run_batches(
-                state, placed(val_chunks, state), None, train=False,
-            )
-            history["val"].append(val_metrics)
-            log.info(
-                "multi epoch %d: train loss=%.4f acc=%.4f | val acc=%.4f",
-                epoch + 1, train_metrics.loss, train_metrics.accuracy,
-                val_metrics.accuracy,
-            )
+                state, placed(val_chunks, state), None, False,
+                passes["eval"])
+            with span("fit_epoch_end", epoch=self._epochs_run):
+                history["val"].append(val_metrics)
+                log.info(
+                    "multi epoch %d: train loss=%.4f acc=%.4f | "
+                    "val acc=%.4f",
+                    epoch + 1, train_metrics.loss, train_metrics.accuracy,
+                    val_metrics.accuracy,
+                )
+                t_end, compiled = self._end_epoch(
+                    t_start, t_epoch, passes, compiled)
+            t_start = t_epoch = t_end
         return state, history, mtd
 
     def evaluate(

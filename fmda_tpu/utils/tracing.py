@@ -80,15 +80,17 @@ class StageTimer:
             )
 
 
-def span(name: str):
+def span(name: str, **args):
     """A host span on the profiler's own clock — a
     ``jax.profiler.TraceAnnotation`` to enter with ``with``.  It stands
     on its thread's line of a captured profile beside the device's
-    operations; when no profile is being captured it costs a flag
+    operations, with ``args`` as the event's arguments (the trainer's
+    pass-level spans carry ``epoch``, so the spans of one epoch share an
+    identifier); when no profile is being captured it costs a flag
     test."""
     import jax  # deferred: keep stdlib-only users of this module jax-free
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def step_annotation(name: str, step: int):
