@@ -19,24 +19,44 @@ scope in the caller:
   cut at the position that makes the count exact (one more counting pass
   a bit of the position).  A pass is a compare and an add an element, and
   it walks only the column tiles at or below the row block's diagonal.
-- ``sparse_fwd`` / ``sparse_bwd_dkv`` / ``sparse_bwd_dq`` — the flash
-  recurrence of :mod:`fmda_tpu.ops.pallas_attention` with the mask in
-  place of a rule of position.  The picks of a learned indexer fall
-  anywhere in the prefix, so no block below the diagonal is empty and
-  none is skipped: this is a dense causal pass that zeroes the pairs not
-  picked (4.3x the picked pairs' products at 16,384 tokens and 2,048
-  keys; PERF.md section 7 has what a gathered pass would cost instead).
-  The query heads of one key-value head ride one grid step together
+- ``sparse_fwd`` / ``sparse_bwd`` — the flash recurrence of
+  :mod:`fmda_tpu.ops.pallas_attention` with the mask in place of a rule
+  of position.  The picks of a learned indexer fall anywhere in the
+  prefix, so no block below the diagonal is empty and none is skipped:
+  this is a dense causal pass that zeroes the pairs not picked (4.3x the
+  picked pairs' products at 16,384 tokens and 2,048 keys; PERF.md
+  section 7 has what a gathered pass would cost instead).  The query
+  heads of one key-value head ride one grid step together
   (``(group, bq, D)`` query block), so a key, value and mask block is
-  fetched once a group, and the group's ``dk`` / ``dv`` accumulate in
-  one scratch.  ``lse`` and ``delta`` ride as ``(T, 128)`` tiles whose
-  lane ``l`` holds head ``l // (128 / group)``.
+  fetched once a group.  ``lse`` and ``delta`` ride as ``(T, 128)``
+  tiles whose lane ``l`` holds head ``l // (128 / group)``.
+
+One backward kernel, five products a block and head.  ``dk`` / ``dv``
+sum over query blocks and ``dq`` over key blocks, so one sweep keeps only
+one of them in a block-sized scratch, and a backward in two sweeps makes
+``q k^T``, ``do v^T``, the ``exp`` and the mask's select twice (seven
+products for five).  ``sparse_bwd`` walks query blocks outside and key
+blocks inside (grid ``(key-value heads, T / bq, T / bk)``): ``dq`` sits in
+a ``(group, bq, D)`` scratch for the query block's key blocks, and the
+group's ``dk`` and ``dv`` *for the whole sequence* sit in two ``(T, D)``
+float32 scratches, added to at the rows of key block ``ki`` query block
+by query block, head by head (the order a sweep over query blocks under
+a fixed key block would sum them in).  That fits because the heads of a
+group share one key-value head: 8 MB each at 16,384 x 128, where ``dq``
+resident for the sequence would be ``group`` times that (64 MB a
+key-value head, the whole of ``_VMEM_LIMIT``), and per-key-block partial
+``dq`` s summed afterwards would be 32 x 268 MB a layer in HBM.  Key
+block ``ki``'s rows are zeroed at the first query block that sees them
+and written, in the compute dtype, at the last (which sees every key
+block) into a ``(1, T, D)`` output block that goes back to HBM when the
+key-value head changes.  :func:`sparse_supported` holds the shape to what
+that keeps in VMEM at once.
 
 Which kernel uses which blocks (``(query rows, keys)``; at 16,384 tokens
-in brackets).  ``sparse_index`` and the two backward kernels take
-:func:`blocks_for` [``(256, 512)``]: their ``lse`` and ``delta`` arrive
-finished, a block is products and element-wise work, and they run at
-~80 % of the MXU there.  ``sparse_fwd`` takes :func:`fwd_blocks_for`
+in brackets).  ``sparse_index`` and ``sparse_bwd`` take
+:func:`blocks_for` [``(256, 512)``]: ``lse`` and ``delta`` arrive
+finished, a block is products and element-wise work, and the MXU paces
+it there.  ``sparse_fwd`` takes :func:`fwd_blocks_for`
 [``(256, 1024)``], a key block twice as wide, because what it pays a
 block and head is *per row, not per element*: the online softmax's lane
 reductions through the XLU (~10 ns a vreg of eight rows by the sweep's
@@ -57,7 +77,8 @@ has already taken.
 
 One mask serves every head.  Support envelope
 (:func:`sparse_supported`): ``T`` a multiple of 128, ``group`` a divisor
-of 128, ``D <= 512``.
+of 128, ``D <= 512``, and ``sparse_bwd``'s residents inside
+``_VMEM_LIMIT`` (16,384 x 128 at a group of 8 is, 32,768 x 128 is not).
 """
 
 from __future__ import annotations
@@ -92,7 +113,7 @@ def _largest_dividing(n: int, candidates) -> int:
 
 def blocks_for(seq_len: int) -> Tuple[int, int]:
     """``(query rows, keys)`` of a block of the index kernel and of
-    attention's backward kernels at this length."""
+    attention's backward kernel at this length."""
     return (_largest_dividing(seq_len, (256, 128)),
             _largest_dividing(seq_len, (512, 256, 128)))
 
@@ -105,10 +126,26 @@ def fwd_blocks_for(seq_len: int) -> Tuple[int, int]:
             _largest_dividing(seq_len, (1024, 512, 256, 128)))
 
 
+def _bwd_resident_bytes(seq_len: int, group: int, d_head: int) -> int:
+    """What ``sparse_bwd`` keeps in VMEM at once, by count, at four bytes
+    an element of q, k and v (the widest compute dtype; bfloat16 halves
+    the blocks, not the scratches)."""
+    bq, bk = blocks_for(seq_len)
+    whole = seq_len * d_head * 4          # a key-value head's dk or dv
+    q_block = group * bq * d_head * 4     # q, do, dq; the dq scratch
+    blocks_in = (2 * q_block + 2 * bk * d_head * 4 + bq * bk
+                 + 2 * bq * 128 * 4)      # q, do; k, v; mask; lse, delta
+    scores = 2 * bq * bk * 4              # a head's s and dp tiles
+    return (2 * whole + q_block           # the three scratches
+            + 2 * (2 * whole + q_block)   # output blocks, in flight twice
+            + 2 * blocks_in + scores)     # input blocks, in flight twice
+
+
 def sparse_supported(seq_len: int, group: int, d_head: int) -> bool:
     """Shape gate for the kernels (module docstring)."""
     return (seq_len % 128 == 0 and group > 0 and 128 % group == 0
-            and d_head <= 512)
+            and d_head <= 512
+            and _bwd_resident_bytes(seq_len, group, d_head) <= _VMEM_LIMIT)
 
 
 def _last_key_block(row0, qi, bq: int, bk: int):
@@ -329,6 +366,25 @@ def _in_band(qi, ki, bq: int, bk: int):
     return ki * bk < (qi + 1) * bq
 
 
+def _band_key_block(qi, ki, bq: int, bk: int):
+    """The key block grid step (qi, ki) references: its own in the band,
+    the band's last again on a skipped step (nothing is fetched)."""
+    return jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
+
+
+def _one_head_behind(group: int, first, second) -> None:
+    """``second(*first(h))`` for every head, with ``first(h + 1)`` issued
+    in between: the compiler keeps the order a kernel is written in, so
+    the one head's second stage runs under the next head's first."""
+    behind = None
+    for h in range(group):
+        ahead = first(h)
+        if behind is not None:
+            second(*behind)
+        behind = ahead
+    second(*behind)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, bq: int, bk: int, n_k: int):
     """``m_scr[h]`` holds a row's running maximum on every lane,
@@ -367,15 +423,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 preferred_element_type=jnp.float32)
 
         # a head's p v is issued behind the next head's scores and
-        # softmax: the compiler keeps this order, and the one head's
-        # reductions then sit under the other's products
-        behind = None
-        for h in range(group):
-            ahead = softmax(h)
-            if behind is not None:
-                accumulate(*behind)
-            behind = ahead
-        accumulate(*behind)
+        # softmax: the one head's reductions then sit under the other's
+        # products
+        _one_head_behind(group, softmax, accumulate)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -397,65 +447,60 @@ def _p_and_ds(q, do, k, v, keep, lse, delta):
     return p, p * (dp - delta) * scale
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, bq: int, bk: int,
-                n_q: int):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    group = q_ref.shape[1]
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    @pl.when(_in_band(qi, ki, bq, bk))
-    def _compute():
-        keep = mask_ref[0].astype(jnp.int32) != 0
-        k, v = k_ref[0], v_ref[0]
-        lse, delta = lse_ref[0], delta_ref[0]
-        for h in range(group):
-            q, do = q_ref[0, h], do_ref[0, h]
-            p, ds = _p_and_ds(q, do, k, v, keep,
-                              _head_column(lse, h, group),
-                              _head_column(delta, h, group))
-            dv_scr[...] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dk_scr[...] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-    @pl.when(qi == n_q - 1)
-    def _flush():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, bq: int, bk: int, n_k: int):
+def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, bq: int,
+                bk: int, n_q: int, n_k: int):
+    """``dq_scr`` holds the query block's ``dq`` across its key blocks;
+    ``dk_scr`` / ``dv_scr`` hold the key-value head's ``dk`` / ``dv``
+    for the whole sequence across the query blocks (module docstring)."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     group = q_ref.shape[1]
+    rows = pl.ds(pl.multiple_of(ki * bk, bk), bk)
 
     @pl.when(ki == 0)
-    def _init():
+    def _init_dq():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when(qi == (ki * bk) // bq)  # the first query block that sees ki
+    def _init_dkv():
+        dk_scr[rows] = dv_scr[rows] = jnp.zeros(
+            (bk, dk_scr.shape[1]), dk_scr.dtype)
 
     @pl.when(_in_band(qi, ki, bq, bk))
     def _compute():
         keep = mask_ref[0].astype(jnp.int32) != 0
         k, v = k_ref[0], v_ref[0]
         lse, delta = lse_ref[0], delta_ref[0]
-        for h in range(group):
-            _, ds = _p_and_ds(q_ref[0, h], do_ref[0, h], k, v, keep,
+
+        def scores(h):
+            p, ds = _p_and_ds(q_ref[0, h], do_ref[0, h], k, v, keep,
                               _head_column(lse, h, group),
                               _head_column(delta, h, group))
+            return h, p.astype(k.dtype), ds.astype(k.dtype)
+
+        def accumulate(h, p, ds):
+            dv_scr[rows] += jax.lax.dot_general(
+                p, do_ref[0, h], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[rows] += jax.lax.dot_general(
+                ds, q_ref[0, h], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
             dq_scr[h] += jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
+        # a head's three gradient products are issued behind the next
+        # head's two score products and element-wise work
+        _one_head_behind(group, scores, accumulate)
+
     @pl.when(ki == n_k - 1)
-    def _flush():
+    def _flush_dq():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+    @pl.when(qi == n_q - 1)  # the last query block sees every key block
+    def _flush_dkv():
+        dk_ref[0, rows] = dk_scr[rows].astype(dk_ref.dtype)
+        dv_ref[0, rows] = dv_scr[rows].astype(dv_ref.dtype)
 
 
 def _params():
@@ -472,9 +517,7 @@ def _fwd_impl(q, k, v, mask, *, blocks, interpret):
     bq, bk = blocks
     n_q, n_k = t // bq, t // bk
 
-    def k_block(qi, ki):  # a skipped step re-references the band's last
-        return jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
-
+    k_block = functools.partial(_band_key_block, bq=bq, bk=bk)
     q_spec = pl.BlockSpec((1, group, bq, d), lambda b, qi, ki: (b, 0, qi, 0))
     kv_spec = pl.BlockSpec(
         (1, bk, d), lambda b, qi, ki: (b, k_block(qi, ki), 0))
@@ -514,62 +557,38 @@ def _bwd_impl(q, k, v, mask, o, lse, do, *, blocks, interpret):
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.repeat(delta.transpose(0, 2, 1), 128 // group, axis=-1)
 
-    def q_block(ki, qi):  # the dK/dV sweep's first query block in band
-        return jnp.maximum(qi, (ki * bk) // bq)
-
-    q_rows = pl.BlockSpec((1, group, bq, d), lambda b, ki, qi: (
-        b, 0, q_block(ki, qi), 0))
-    packed = pl.BlockSpec((1, bq, 128), lambda b, ki, qi: (
-        b, q_block(ki, qi), 0))
-    kv_fixed = pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, bq=bq, bk=bk, n_q=n_q),
-        name="sparse_bwd_dkv",
-        grid=(bg, n_k, n_q),
-        in_specs=[
-            q_rows, kv_fixed, kv_fixed,
-            pl.BlockSpec((1, bq, bk), lambda b, ki, qi: (
-                b // g, q_block(ki, qi), ki)),
-            q_rows, packed, packed,
-        ],
-        out_specs=[kv_fixed, kv_fixed],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_params(),
-        interpret=interpret,
-    )(q, k, v, mask, do, lse, delta)
-
-    def k_block(qi, ki):
-        return jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
-
-    q_fixed = pl.BlockSpec((1, group, bq, d), lambda b, qi, ki: (b, 0, qi, 0))
-    packed2 = pl.BlockSpec((1, bq, 128), lambda b, qi, ki: (b, qi, 0))
-    kv_rows = pl.BlockSpec(
+    k_block = functools.partial(_band_key_block, bq=bq, bk=bk)
+    q_spec = pl.BlockSpec((1, group, bq, d), lambda b, qi, ki: (b, 0, qi, 0))
+    packed = pl.BlockSpec((1, bq, 128), lambda b, qi, ki: (b, qi, 0))
+    kv_spec = pl.BlockSpec(
         (1, bk, d), lambda b, qi, ki: (b, k_block(qi, ki), 0))
-    (dq,) = pl.pallas_call(
-        functools.partial(_dq_kernel, bq=bq, bk=bk, n_k=n_k),
-        name="sparse_bwd_dq",
+    # the head's dk / dv whole: written back when the head changes
+    kv_whole = pl.BlockSpec((1, t, d), lambda b, qi, ki: (b, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, bq=bq, bk=bk, n_q=n_q, n_k=n_k),
+        name="sparse_bwd",
         grid=(bg, n_q, n_k),
         in_specs=[
-            q_fixed, kv_rows, kv_rows,
+            q_spec, kv_spec, kv_spec,
             pl.BlockSpec((1, bq, bk), lambda b, qi, ki: (
                 b // g, qi, k_block(qi, ki))),
-            q_fixed, packed2, packed2,
+            q_spec, packed, packed,
         ],
-        out_specs=[q_fixed],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        scratch_shapes=[pltpu.VMEM((group, bq, d), jnp.float32)],
+        out_specs=[q_spec, kv_whole, kv_whole],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((group, bq, d), jnp.float32),
+                        pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((t, d), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
     )(q, k, v, mask, do, lse, delta)
-    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _sparse(q, k, v, mask, blocks, interpret):
-    """``blocks``: the forward kernel's pair, the backward kernels'."""
+    """``blocks``: the forward kernel's pair, the backward kernel's."""
     return _fwd_impl(q, k, v, mask, blocks=blocks[0], interpret=interpret)[0]
 
 

@@ -47,11 +47,11 @@ def _top_k_mask(q, k, w, topk):
     return out
 
 
-def _qkv(t, seed=1):
+def _qkv(t, seed=1, n=N, g=G):
     rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, N, t, D)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, G, t, D)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, G, t, D)), jnp.float32))
+    return (jnp.asarray(rng.normal(size=(B, n, t, D)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, g, t, D)), jnp.float32),
+            jnp.asarray(rng.normal(size=(B, g, t, D)), jnp.float32))
 
 
 @pytest.mark.parametrize("t,topk", [(40, 8), (128, 24), (1024, 96)])
@@ -114,28 +114,38 @@ def _with_an_empty_row(picked, row=5):
     return picked.at[:, row].set(0)
 
 
-@pytest.mark.parametrize("t,topk,blocks,fwd_blocks,edit", [
-    (128, 16, (32, 128), None, None),
-    (256, 24, (64, 128), None, None),
-    (512, 600, (128, 256), None, None),
+@pytest.mark.parametrize("t,topk,blocks,fwd_blocks,edit,group,kv_heads", [
+    (128, 16, (32, 128), None, None, 2, 2),
+    (256, 24, (64, 128), None, None, 2, 2),
+    (512, 600, (128, 256), None, None, 2, 2),
     # the forward's pair is its own: a key block wider than half the
     # sequence over a backward of narrower ones
-    (512, 48, (128, 128), (128, 512), None),
-    (512, 600, (64, 256), (128, 512), None),
-    (1024, 96, None, None, None),          # the pairs that follow from T
-    (512, 48, (128, 128), (128, 256), _first_block_unpicked),
-    (512, 48, (64, 128), (128, 512), _with_an_empty_row),
+    (512, 48, (128, 128), (128, 512), None, 2, 2),
+    (512, 600, (64, 256), (128, 512), None, 2, 2),
+    (1024, 96, None, None, None, 2, 2),    # the pairs that follow from T
+    (512, 48, (128, 128), (128, 256), _first_block_unpicked, 2, 2),
+    (512, 48, (64, 128), (128, 512), _with_an_empty_row, 2, 2),
+    # the one backward sweep: a key block's rows of the sequence-long
+    # dk / dv scratch added to by eight query blocks, eight heads each
+    (512, 48, (64, 128), None, None, 8, 1),
+    (256, 300, (32, 256), None, None, 8, 1),  # a sequence of one key block
+    # key-value heads back to back: a head's dk / dv rows hold only what
+    # that head put there (each key block zeroed where the head first
+    # sees it), an empty query row among them
+    (384, 32, (128, 128), None, _with_an_empty_row, 2, 4),
 ])
 def test_the_attention_kernels_match_the_jnp_path(t, topk, blocks,
-                                                  fwd_blocks, edit):
+                                                  fwd_blocks, edit, group,
+                                                  kv_heads):
     """Forward, dQ, dK, dV of the masked flash kernels against ``mha``
     under the same mask; a query block shorter than a key block, a row
-    whose first key blocks hold no pick, grouped heads."""
+    whose first key blocks hold no pick, grouped heads; the backward's
+    one sweep with dk / dv held for the whole sequence."""
     q, k, w = _indexer(t, seed=3, ties=False)
     picked, _ = sa.select_keys(q, k, w, topk)
     if edit is not None:
         picked = edit(picked)
-    qq, kk, vv = _qkv(t)
+    qq, kk, vv = _qkv(t, n=group * kv_heads, g=kv_heads)
 
     def via(fn):
         return jax.value_and_grad(
@@ -188,6 +198,12 @@ def test_the_forwards_own_blocks_change_only_the_order_of_float32_sums(edit):
 
 def test_the_kernels_gate_names_what_they_cannot_tile():
     assert kernels.sparse_supported(16384, 8, 128)
+    # the backward keeps a key-value head's dk and dv for the whole
+    # sequence in VMEM: 57.75 MiB by count at 16,384 x 128 with float32
+    # blocks, inside the kernels' limit, and 105.75 at 32,768
+    assert kernels._bwd_resident_bytes(16384, 8, 128) == 57.75 * 2 ** 20
+    assert not kernels.sparse_supported(32768, 8, 128)
+    assert kernels.sparse_supported(32768, 8, 64)        # half the rows' width
     assert not kernels.sparse_supported(16384, 3, 128)   # 128 % group
     assert not kernels.sparse_supported(1000, 8, 128)    # T % 128
     with pytest.raises(ValueError, match="sparse_supported"):

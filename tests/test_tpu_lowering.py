@@ -59,7 +59,7 @@ def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
 
     t = 16384
     # the forward pays its row state once a 1,024-key block, the
-    # backward kernels keep their pair (the module's docstring says why)
+    # backward kernel keeps its pair (the module's docstring says why)
     assert kernels.fwd_blocks_for(t) == (256, 1024)
     assert kernels.blocks_for(t) == (256, 512)
 
@@ -71,8 +71,8 @@ def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
             _shape(one_chip, (1, 4, t, 128), BF16),
             _shape(one_chip, (1, 4, t, 128), BF16),
             _shape(one_chip, (1, t, t), jnp.int8))
-    # the grids as traced: (key-value heads, query blocks, key blocks),
-    # the dK/dV sweep with the two block axes the other way round
+    # the grids as traced: (key-value heads, query blocks, key blocks);
+    # one backward sweep, dk / dv held in VMEM for the whole sequence
     grids = {}
 
     def walk(jaxpr):
@@ -84,9 +84,7 @@ def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
                 walk(sub)
 
     walk(jax.make_jaxpr(step)(*args).jaxpr)
-    assert grids == {"sparse_fwd": (4, 64, 16),
-                     "sparse_bwd_dkv": (4, 32, 64),
-                     "sparse_bwd_dq": (4, 64, 32)}
+    assert grids == {"sparse_fwd": (4, 64, 16), "sparse_bwd": (4, 64, 32)}
     compiled = jax.jit(step).lower(*args).compile()
     text = compiled.as_text()
     calls = {name: re.findall(
@@ -253,8 +251,8 @@ def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
         assert len(calls) == runs * len(layout), (name, len(calls))
         replayed = [c for c in calls if "rematted_computation" in c]
         assert len(replayed) == (runs - 1) * len(layout), name
-    # the backward kernels read what was kept: one pair a layer either way
-    bwd = "sparse_bwd_dq" if 2 in layout else "flash_bwd_dq"
+    # the backward kernels read what was kept: one run a layer either way
+    bwd = "sparse_bwd" if 2 in layout else "flash_bwd_dq"
     assert len(re.findall(
         rf"(?m)^\s*%{bwd}(?:\.\d+)? = .*custom-call\(", text)) == len(layout)
 
