@@ -17,21 +17,29 @@ length is far more than a chip holds (``(T, 64, 64, 128)`` float32 is
 chunks of ``chunk`` positions (``l = cumsum(d a)`` inside a chunk)::
 
     ssd_states  u_c  = sum_j exp(l_last - l_j) d_j xs_j (x) b_j      a chunk's own end state
-    ssd_carry   s_c  = exp(l_last) s_{c-1} + u_c                     over the chunks
-    ssd_intra   y_i  = sum_{j<=i} exp(l_i - l_j) (c_i . b_j) d_j xs_j   products on the MXU
+    ssd_carry   s_c  = exp(l_last) s_{c-1} + u_c                     chunk after chunk
+    ssd_intra   y_i  = sum_{j<=i} exp(l_i - l_j) d_j (c_i . b_j) xs_j   products on the MXU
     ssd_out     y_i += exp(l_i) s_{c-1} c_i                          the carried state, decayed
 
-The recurrence over chunk states is first-order with one scalar a head
-and chunk, the combine of :func:`fmda_tpu.ops.ssm.linear_scan_parallel`:
-the vector recurrence of the ``ssm`` family and this one share it.  No
-term divides by a decay: every exponent is a sum of non-positive terms,
-so a decay that underflows inside a chunk gives 0, not inf or nan.
+The chunks are walked once, in order: a ``lax.scan`` over groups of
+``CHUNK_GROUP`` chunks carries the ``(B, H, P, N)`` state from one group
+to the next, and a turn of it does all four parts for its group (the
+group's own end states, the recurrence over them unrolled, then the two
+output terms against the state before each chunk) and adds the skip.  So
+a chunk's state is written once, into the stacked ``states``, and ``y``
+once.  ``d_j`` rides on the float32 factor that multiplies an operand
+before it is rounded (``exp(l_last - l_j) d_j`` on ``xs_j`` in
+``ssd_states``, the decay matrix's column in ``ssd_intra``): ``d * xs``
+is never an array, and ``xs`` enters a group in the dtype it came in.
+No term divides by a decay: every exponent is a sum of non-positive
+terms, so a decay that underflows inside a chunk gives 0, not inf or nan.
 
 Cumulative decays, their exponentials and the carried state are float32;
-the products take operands in ``dtype`` and accumulate in float32.  The
-``(chunks, H, chunk, chunk)`` decay matrices are made a group of chunks
-at a time and made again in backward (``jax.checkpoint`` on the group),
-so neither pass holds them for the whole sequence.
+the products take operands in ``dtype`` (each rounded once) and
+accumulate in float32.  A turn runs under ``jax.checkpoint``: the
+``(group, H, chunk, chunk)`` decay matrices exist a group at a time and
+are made again in backward, which keeps of the walk the state at each
+group's edge.
 """
 
 from __future__ import annotations
@@ -41,11 +49,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from fmda_tpu.ops.ssm import linear_scan_parallel
-
-#: Chunks whose ``(H, chunk, chunk)`` decay matrices exist at a time: 4 x
-#: 64 x 256 x 256 float32 is 67 MB, where 32 chunks would be 537 MB and
-#: as much again in bfloat16 (PERF.md section 6, PR 34).
+#: Chunks a turn of the walk takes, and whose ``(H, chunk, chunk)`` decay
+#: matrices exist at a time: 4 x 64 x 256 x 256 float32 is 67 MB, where 32
+#: chunks would be 537 MB and as much again in bfloat16.  On the TPU the
+#: compiler makes them inside the products' fusions at 4 and at 8, and a
+#: step read the same at both; at 32 they are arrays (PERF.md section 6,
+#: PR 34 and PR 38).
 CHUNK_GROUP = 4
 
 
@@ -87,23 +96,12 @@ def _by_chunk(v: jax.Array, chunk: int) -> jax.Array:
     return v.reshape(v.shape[:1] + (-1, chunk) + v.shape[2:])
 
 
-def _in_groups(fn, args, n_chunks: int):
-    """``fn`` over the chunk axis (axis 1) of ``args``, ``CHUNK_GROUP``
-    chunks at a time, each group made again in backward."""
+def _group_size(n_chunks: int) -> int:
+    """The largest divisor of ``n_chunks`` that is at most ``CHUNK_GROUP``."""
     group = min(CHUNK_GROUP, n_chunks)
     while n_chunks % group:
         group -= 1
-    if group == n_chunks:
-        return fn(*args)
-
-    def grouped(v):  # (B, C, ...) -> (C / group, B, group, ...)
-        return jnp.moveaxis(v.reshape(
-            v.shape[:1] + (n_chunks // group, group) + v.shape[2:]), 1, 0)
-
-    out = jax.lax.map(lambda xs: jax.checkpoint(fn)(*xs),
-                      tuple(grouped(v) for v in args))
-    out = jnp.moveaxis(out, 0, 1)
-    return out.reshape(out.shape[:1] + (n_chunks,) + out.shape[3:])
+    return group
 
 
 def ssd_scan(xs, d, a, b, c, skip, *, chunk: int, dtype=jnp.float32
@@ -121,48 +119,67 @@ def ssd_scan(xs, d, a, b, c, skip, *, chunk: int, dtype=jnp.float32
         xs, d, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
                        for v in (xs, d, b, c))
     n_chunks = (t + pad) // chunk
+    group = _group_size(n_chunks)
     d = _by_chunk(d.astype(f32), chunk)                      # (B, C, Q, H)
     # l_i = sum_{r <= i} d_r a inside the chunk, never positive
     decay = jnp.cumsum(d * a, axis=2)
     x_c = _by_chunk(xs, chunk)                               # (B, C, Q, H, P)
-    dx = (d[..., None] * x_c.astype(f32))
     b_c, c_c = (_by_chunk(v, chunk).astype(dtype) for v in (b, c))
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
 
-    with jax.named_scope("ssd_states"):
-        to_end = jnp.exp(decay[:, :, -1:, :] - decay)        # (B, C, Q, H)
-        own = jnp.einsum("bcqhp,bcqn->bchpn",
-                         (to_end[..., None] * dx).astype(dtype), b_c,
-                         preferred_element_type=f32)
-    with jax.named_scope("ssd_carry"):
-        # s_c = exp(l_last) s_{c-1} + u_c: one scalar a head and chunk
-        through = jnp.exp(decay[:, :, -1, :])                # (B, C, H)
-        states = linear_scan_parallel(
-            through[..., None],
-            own.reshape(batch, n_chunks, h, p * n)).reshape(own.shape)
-        before = jnp.concatenate(
-            [jnp.zeros_like(states[:, :1]), states[:, :-1]], axis=1)
-
-    def outputs(decay, dx, b_c, c_c, before):
-        """``y`` of a group of chunks, (B, G, Q, H, P) float32."""
+    def walk(state, at):
+        """A group of chunks in order from the ``state`` (B, H, P, N)
+        before it: the state after it, the group's ``y`` (B, G, Q, H, P)
+        and the state after each of its chunks (B, G, H, P, N)."""
+        x, d, decay, b_c, c_c = at
+        x32 = x.astype(f32)
+        with jax.named_scope("ssd_states"):
+            # exp(l_last - l_j) d_j, the one float32 factor of xs_j
+            to_end = jnp.exp(decay[:, :, -1:, :] - decay) * d    # (B, G, Q, H)
+            own = jnp.einsum("bgqhp,bgqn->bghpn",
+                             (to_end[..., None] * x32).astype(dtype), b_c,
+                             preferred_element_type=f32)
+        with jax.named_scope("ssd_carry"):
+            # s_c = exp(l_last) s_{c-1} + u_c: one scalar a head and chunk
+            through = jnp.exp(decay[:, :, -1, :])                # (B, G, H)
+            before, after = [], []
+            for k in range(x.shape[1]):
+                before.append(state)
+                state = through[:, k, :, None, None] * state + own[:, k]
+                after.append(state)
+            before, after = jnp.stack(before, 1), jnp.stack(after, 1)
         with jax.named_scope("ssd_intra"):
-            q = decay.shape[2]
             scores = jnp.einsum("bgin,bgjn->bgij", c_c, b_c,
                                 preferred_element_type=f32)
-            # exp(l_i - l_j) for j <= i; the exponent is masked, not the
-            # result, so that no masked slot overflows
-            by_head = jnp.swapaxes(decay, 2, 3)              # (B, G, H, Q)
+            # exp(l_i - l_j) d_j for j <= i; the exponent is masked, not
+            # the result, so that no masked slot overflows
+            by_head = jnp.swapaxes(decay, 2, 3)                  # (B, G, H, Q)
             span = by_head[..., :, None] - by_head[..., None, :]
-            causal = jnp.tril(jnp.ones((q, q), bool))
             weights = (jnp.exp(jnp.where(causal, span, -jnp.inf))
-                       * scores[:, :, None])                # (B, G, H, i, j)
+                       * jnp.swapaxes(d, 2, 3)[..., None, :]
+                       * scores[:, :, None])                    # (B, G, H, i, j)
             y = jnp.einsum("bghij,bgjhp->bgihp", weights.astype(dtype),
-                           dx.astype(dtype), preferred_element_type=f32)
+                           x.astype(dtype), preferred_element_type=f32)
         with jax.named_scope("ssd_out"):
             y = y + jnp.exp(decay)[..., None] * jnp.einsum(
                 "bgin,bghpn->bgihp", c_c, before.astype(dtype),
                 preferred_element_type=f32)
-        return y
+        return state, (y + skip[:, None] * x32, after)
 
-    y = _in_groups(outputs, (decay, dx, b_c, c_c, before), n_chunks)
-    y = y.reshape(batch, t + pad, h, p)[:, :t]
-    return y + skip[:, None] * xs[:, :t].astype(f32), states
+    def grouped(v):  # (B, C, ...) -> (C / group, B, group, ...)
+        return jnp.moveaxis(v.reshape(
+            v.shape[:1] + (n_chunks // group, group) + v.shape[2:]), 1, 0)
+
+    def whole(v):  # (C / group, B, group, ...) -> (B, C, ...)
+        v = jnp.moveaxis(v, 0, 1)
+        return v.reshape(v.shape[:1] + (n_chunks,) + v.shape[3:])
+
+    start = jnp.zeros((batch, h, p, n), f32)
+    args = (x_c, d, decay, b_c, c_c)
+    if group == n_chunks:
+        _, (y, states) = walk(start, args)
+    else:
+        _, (y, states) = jax.lax.scan(
+            jax.checkpoint(walk), start, tuple(grouped(v) for v in args))
+        y, states = whole(y), whole(states)
+    return y.reshape(batch, t + pad, h, p)[:, :t], states

@@ -158,7 +158,7 @@ def linear_scan_parallel(
     initial state in exactly (``x_t`` gains ``prod(a_1..t) * x0``).
     ``u`` may carry further axes that ``a`` broadcasts over (``a`` (B, T,
     H, 1) against ``u`` (B, T, H, M): one scalar decay a head over a
-    matrix-valued state, ops/ssd.py's carry over chunks)."""
+    matrix-valued state)."""
 
     def combine(c1, c2):
         a1, u1 = c1

@@ -76,7 +76,8 @@ def _ids(seed=3, n=SEQ + 1, batch=2):
 
 
 def _program_loss(model, cfg, x, y, mask):
-    task = NextToken(cfg, TrainConfig(batch_size=x.shape[0], window=SEQ))
+    task = NextToken(cfg, TrainConfig(batch_size=x.shape[0],
+                                      window=x.shape[1]))
     batch = Batch(x, y, mask)
 
     def loss(p):
@@ -123,11 +124,15 @@ def test_logits_match_the_reference(layout):
         np.testing.assert_allclose(got[b], want, rtol=2e-4, atol=2e-5)
 
 
-def test_loss_and_every_leafs_gradient_match_the_reference():
-    cfg = small_cfg(remat=True)
+# three chunks, the last one short; twelve chunks, which the scan walks
+# as three groups of four carrying the state from one to the next; and
+# thirteen with the last one short, thirteen groups of one chunk
+@pytest.mark.parametrize("seq,chunk", [(SEQ, CHUNK), (96, 8), (100, 8)])
+def test_loss_and_every_leafs_gradient_match_the_reference(seq, chunk):
+    cfg = small_cfg(remat=True, ssm_chunk=chunk)
     model, params = _params(cfg)
-    x, y = _ids()
-    mask = jnp.ones(x.shape, jnp.float32).at[1, 30:].set(0.0)
+    x, y = _ids(n=seq + 1)
+    mask = jnp.ones(x.shape, jnp.float32).at[1, seq - 10:].set(0.0)
     got, got_grads = jax.jit(jax.value_and_grad(
         _program_loss(model, cfg, x, y, mask)))(params)
     want, want_grads = jax.jit(lambda p: ref.loss_and_grads(

@@ -3,6 +3,8 @@ written (ops/ssd.py), forward and gradient, and the causal depthwise
 convolution against shifted sums: small sizes, seeded inputs, float32
 on the CPU."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,11 +33,13 @@ def _close(got, want, tol):
 
 
 # lengths that are and are not a multiple of the chunk, one chunk alone,
-# more chunks than a group holds (the grouped walk), and steps so large
-# that a decay underflows inside a chunk
+# twelve chunks in three groups (the ordered walk), ten chunks (no
+# multiple of the group: five groups of two), a padded length of six
+# chunks in two groups of three, and steps so large that a decay
+# underflows inside a chunk
 @pytest.mark.parametrize("t,chunk,step_scale", [
     (32, 8, 1.0), (37, 8, 1.0), (8, 8, 1.0), (5, 8, 1.0), (96, 8, 1.0),
-    (64, 16, 60.0)])
+    (64, 16, 60.0), (80, 8, 1.0), (43, 8, 1.0)])
 def test_the_chunked_scan_is_the_recurrence_as_written(t, chunk, step_scale):
     args = _inputs(t, seed=t, step_scale=step_scale)
     with jax.default_matmul_precision("highest"):
@@ -50,7 +54,8 @@ def test_the_chunked_scan_is_the_recurrence_as_written(t, chunk, step_scale):
 
 
 @pytest.mark.parametrize("t,chunk,step_scale", [
-    (32, 8, 1.0), (37, 8, 1.0), (96, 8, 1.0), (64, 16, 60.0)])
+    (32, 8, 1.0), (37, 8, 1.0), (96, 8, 1.0), (64, 16, 60.0), (80, 8, 1.0),
+    (43, 8, 1.0)])
 def test_the_chunked_scans_gradient_is_the_recurrences(t, chunk, step_scale):
     args = _inputs(t, seed=t + 1, step_scale=step_scale)
 
@@ -66,18 +71,52 @@ def test_the_chunked_scans_gradient_is_the_recurrences(t, chunk, step_scale):
         _close(g, w, 1e-4)
 
 
-def test_the_carried_states_are_the_recurrences_states_at_chunk_ends():
-    t, chunk = 24, 8
+def _states_stepwise(xs, d, a, b, chunk):
+    """The recurrence's state after every ``chunk`` positions and after
+    the last one, (B, chunks, H, P, N), position by position."""
+    t = xs.shape[1]
+
+    def step(state, at):
+        x_t, d_t, b_t = at
+        state = (jnp.exp(d_t * a)[..., None, None] * state
+                 + jnp.einsum("bh,bhp,bn->bhpn", d_t, x_t, b_t))
+        return state, state
+
+    _, every = jax.lax.scan(
+        step, jnp.zeros((B, H, P, N)),
+        tuple(jnp.moveaxis(v, 1, 0) for v in (xs, d, b)))
+    ends = [i for i in range(t) if (i + 1) % chunk == 0 or i == t - 1]
+    return jnp.moveaxis(every[jnp.asarray(ends)], 0, 1)
+
+
+# one group, three groups of four, five groups of two, and a padded
+# length (the last chunk's state is the state after the last position)
+@pytest.mark.parametrize("t,chunk", [(24, 8), (96, 8), (80, 8), (43, 8)])
+def test_the_carried_states_are_the_recurrences_states_at_chunk_ends(
+        t, chunk):
     xs, d, a, b, c, skip = _inputs(t, seed=9)
     with jax.default_matmul_precision("highest"):
         _, states = ssd_scan(xs, d, a, b, c, skip, chunk=chunk)
-    state = np.zeros((B, H, P, N))
-    for i in range(t):
-        state = (np.exp(np.asarray(d[:, i] * a))[..., None, None] * state
-                 + np.einsum("bh,bhp,bn->bhpn", d[:, i], xs[:, i], b[:, i]))
-        if (i + 1) % chunk == 0:
-            np.testing.assert_allclose(states[:, i // chunk], state,
-                                       rtol=1e-4, atol=1e-5)
+        want = _states_stepwise(xs, d, a, b, chunk)
+    np.testing.assert_allclose(states, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("t,chunk", [(96, 8), (80, 8), (43, 8)])
+def test_the_carried_states_gradient_is_the_recurrences(t, chunk):
+    """What flows back through ``states`` alone, into the four inputs
+    that reach a state (``c`` and the skip do not), against the
+    recurrence's own states under autodiff."""
+    xs, d, a, b, c, skip = _inputs(t, seed=t + 2)
+
+    def through(fn):
+        return jax.grad(lambda *v: jnp.sum(jnp.sin(fn(*v))),
+                        argnums=(0, 1, 2, 3))(xs, d, a, b)
+
+    with jax.default_matmul_precision("highest"):
+        want = through(lambda *v: _states_stepwise(*v, chunk))
+        got = through(lambda *v: ssd_scan(*v, c, skip, chunk=chunk)[1])
+    for g, w in zip(got, want):
+        _close(g, w, 1e-4)
 
 
 def test_the_decay_matrices_exist_a_group_of_chunks_at_a_time(monkeypatch):
@@ -92,6 +131,62 @@ def test_the_decay_matrices_exist_a_group_of_chunks_at_a_time(monkeypatch):
     monkeypatch.setattr(ssd, "CHUNK_GROUP", 12)
     whole = jax.jit(lambda *a: ssd_scan(*a, chunk=8)[0]).lower(*args).as_text()
     assert f"tensor<{B}x12x{H}x8x8xf32>" in whole
+
+
+def _producers(text, shape):
+    """The operations of a lowered program whose result has ``shape``."""
+    made = set()
+    for line in text.splitlines():
+        m = re.search(r"= \"?(?:stablehlo\.|func\.)?([\w.]+)", line)
+        if not m or " = " not in line:
+            continue
+        results = line.rsplit("->", 1)[-1] if "->" in line else (
+            line.rsplit(" : ", 1)[-1])
+        if f"tensor<{shape}>" in results:
+            made.add(m.group(1))
+    return made
+
+
+def test_the_walk_holds_no_second_array_of_the_sequence_or_of_the_states():
+    """Twelve chunks in three groups, inputs in bfloat16 as the decoder
+    hands them over, value and gradient through ``y`` and ``states``:
+    outside a group nothing computes a float32 array of ``d * xs``'s
+    shape (by chunk, group-major or flat: ``y`` and its cotangent are
+    moved, never multiplied, added or converted), the chunk states exist
+    as the walk's stacked output and its cotangent alone (no shifted copy,
+    no padded halves of a log-depth scan), and the carried state is
+    float32."""
+    xs, d, a, b, c, skip = _inputs(96, seed=6)
+    half = jnp.bfloat16
+
+    def value(*args):
+        y, states = ssd_scan(*args, chunk=8, dtype=half)
+        return jnp.sum(jnp.sin(y)) + jnp.sum(jnp.sin(states))
+
+    text = jax.jit(jax.grad(value, argnums=tuple(range(6)))).lower(
+        xs.astype(half), d, a, b.astype(half), c.astype(half), skip
+    ).as_text()
+    moves = {"reshape", "transpose", "while", "dynamic_slice",
+             "dynamic_update_slice", "broadcast_in_dim", "constant"}
+    g = ssd.CHUNK_GROUP
+    groups = 12 // g
+    for shape in (f"{B}x12x8x{H}x{P}", f"{B}x{groups}x{g}x8x{H}x{P}",
+                  f"{groups}x{B}x{g}x8x{H}x{P}"):
+        assert _producers(text, f"{shape}xf32") <= moves, shape
+    # sin's derivative over y and over states is the one computation on
+    # an array of either size: the test's own
+    assert _producers(text, f"{B}x96x{H}x{P}xf32") <= moves | {
+        "sine", "cosine", "multiply"}
+    for shape in (f"{B}x12x{H}x{P}x{N}", f"{B}x{groups}x{g}x{H}x{P}x{N}",
+                  f"{groups}x{B}x{g}x{H}x{P}x{N}"):
+        extra = {"sine", "cosine", "multiply"} if shape.startswith(
+            f"{B}x12x") else set()
+        assert _producers(text, f"{shape}xf32") <= moves | extra, shape
+        assert not _producers(text, f"{shape}xbf16"), shape
+    for width in range(1, 12):  # no half, quarter ... of the chunk axis
+        assert not _producers(text, f"{B}x{width}x{H}x{P * N}xf32")
+    assert f"tensor<{B}x{H}x{P}x{N}xf32>" in text      # the carried state
+    assert f"tensor<{B}x{H}x{P}x{N}xbf16>" not in text
 
 
 def test_the_compute_dtype_rounds_the_products_operands_only():
