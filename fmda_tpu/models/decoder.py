@@ -93,7 +93,7 @@ from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT, mha
 from fmda_tpu.ops.moe import ACTIVATIONS, expert_layer, kernel_impl, route
 from fmda_tpu.ops.sparse_attention import (
     PICKS, kernels_dispatch, select_keys, sparse_mha)
-from fmda_tpu.ops.ssd import causal_conv, ssd_scan
+from fmda_tpu.ops.ssd import conv_silu, ssd_scan
 
 #: Standard deviation of every weight matrix at init (the family's
 #: convention; norm scales start at one).
@@ -201,12 +201,12 @@ def _ssm_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
             [inner, 2 * inner + 2 * n], axis=-1)
     with jax.named_scope("ssm_conv"):
         bound = taps ** -0.5
-        xbc = jax.nn.silu(causal_conv(
+        xbc = conv_silu(
             xbc,
             module.param("conv_w", _uniform(-bound, bound),
                          (inner + 2 * n, taps), f32),
             module.param("conv_b", _uniform(-bound, bound),
-                         (inner + 2 * n,), f32))).astype(dt)
+                         (inner + 2 * n,), f32), dtype=dt)
     xs, b_in, c_out = jnp.split(xbc, [inner, inner + n], axis=-1)
     with jax.named_scope("ssd_scan"):
         step = jax.nn.softplus(step.astype(f32) + module.param(

@@ -40,10 +40,28 @@ accumulate in float32.  A turn runs under ``jax.checkpoint``: the
 ``(group, H, chunk, chunk)`` decay matrices exist a group at a time and
 are made again in backward, which keeps of the walk the state at each
 group's edge.
+
+The convolution in front of it, as the mixer consumes it, is
+:func:`conv_silu`: ``silu(causal_conv(x, w, bias))`` rounded once to the
+mixer's dtype, on every backend one ``jnp`` form under a ``custom_vjp``.
+Forward, :func:`causal_conv`'s sums letter for letter with the padding
+done in ``x``'s dtype and each tap widened inside the sum: XLA makes one
+fusion of it that reads ``x`` once, in the dtype it came in, and writes
+the rounded result once, where ``silu(causal_conv(...))`` as written
+keeps a widened copy of the input in HBM.  Backward, written out in
+float32 from ``x``, ``w`` and ``bias`` alone: the pre-activation made
+again, ``dpre = ct * silu'(pre)`` (the one float32 array of the sequence
+that exists), ``dx`` the four shifted products of ``dpre`` added in
+float32 and rounded once, ``dw`` and ``dbias`` float32 sums; autodiff of
+the forward would round each tap's term of ``dx`` to ``x``'s dtype
+before adding them, and of ``causal_conv`` as written keeps a float32
+array a tap.  A Pallas kernel for the backward was built and timed and
+did not earn its place (PERF.md section 6, PR 39).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -69,6 +87,57 @@ def causal_conv(x: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
     for j in range(k):
         out = out + w[:, j] * padded[:, j:j + t]
     return out
+
+
+def _conv_taps(x, w, bias):
+    """:func:`causal_conv`'s sums and the padded input they read: the
+    padding in ``x``'s dtype, each tap widened inside the sum."""
+    k, t = w.shape[-1], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    out = bias.astype(jnp.float32)
+    for j in range(k):
+        out = out + w[:, j] * padded[:, j:j + t].astype(jnp.float32)
+    return out, padded
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv_silu(x, w, bias, dtype):
+    return jax.nn.silu(_conv_taps(x, w, bias)[0]).astype(dtype)
+
+
+def _conv_silu_fwd(x, w, bias, dtype):
+    return _conv_silu(x, w, bias, dtype), (x, w, bias)
+
+
+def _conv_silu_bwd(dtype, residuals, ct):
+    f32 = jnp.float32
+    x, w, bias = residuals
+    k, t = w.shape[-1], x.shape[1]
+    pre, padded = _conv_taps(x, w, bias)
+    sig = jax.nn.sigmoid(pre)
+    dpre = ct.astype(f32) * (sig * (1.0 + pre * (1.0 - sig)))
+    # dx[t] = sum_j w[:, j] * dpre[t + (K - 1) - j], zeros after the end
+    after = jnp.pad(dpre, ((0, 0), (0, k - 1), (0, 0)))
+    dx = w[:, 0] * after[:, k - 1:k - 1 + t]
+    for j in range(1, k):
+        dx = dx + w[:, j] * after[:, k - 1 - j:k - 1 - j + t]
+    dw = jnp.stack([(dpre * padded[:, j:j + t].astype(f32)).sum((0, 1))
+                    for j in range(k)], -1)
+    return (dx.astype(x.dtype), dw.astype(w.dtype),
+            dpre.sum((0, 1)).astype(bias.dtype))
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def conv_silu(x: jax.Array, w: jax.Array, bias: jax.Array, *, dtype
+              ) -> jax.Array:
+    """``silu(causal_conv(x, w, bias))`` rounded once to ``dtype``, the
+    mixer's convolution as it is consumed (module docstring): the same
+    float32 sums, ``x`` read in the dtype it came in, and a backward
+    that keeps ``x``, ``w`` and ``bias`` alone and adds ``dx``'s terms
+    in float32."""
+    return _conv_silu(x, w, bias, jnp.dtype(dtype))
 
 
 def ssd_scan_stepwise(xs, d, a, b, c, skip) -> jax.Array:

@@ -145,6 +145,45 @@ def test_loss_and_every_leafs_gradient_match_the_reference(seq, chunk):
         assert rel < 2e-4, (path, rel)
 
 
+def test_conv_silu_gives_the_logits_and_gradients_of_the_form_as_written(
+        monkeypatch):
+    """The mixer as it runs (``conv_silu``: the padding in the stream's
+    dtype, a backward written out in float32) against the mixer with
+    ``silu(causal_conv(...))`` as written and autodiff through it: the
+    logits bit for bit, the loss and every leaf's gradient inside the
+    limits the reference is held to above."""
+    from fmda_tpu.models import decoder
+    from fmda_tpu.ops import ssd
+
+    cfg = small_cfg(remat=True, ssm_head_dim=24, ssm_state=16)
+    model, params = _params(cfg)
+    x, y = _ids()
+    mask = jnp.ones(x.shape, jnp.float32).at[1, SEQ - 10:].set(0.0)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            logits = model.apply({"params": params}, x)
+        return logits, jax.value_and_grad(
+            _program_loss(model, cfg, x, y, mask))(params)
+
+    got_logits, (got, got_grads) = run()
+    calls = []
+
+    def as_written(x, w, bias, *, dtype):
+        calls.append(x.shape)
+        return jax.nn.silu(ssd.causal_conv(x, w, bias)).astype(dtype)
+
+    monkeypatch.setattr(decoder, "conv_silu", as_written)
+    want_logits, (want, want_grads) = run()
+    assert calls and all(shape == (2, SEQ, 128) for shape in calls)
+    np.testing.assert_array_equal(got_logits, want_logits)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_grads),
+                            jax.tree.leaves(want_grads)):
+        rel = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        assert rel < 2e-4, (path, rel)
+
+
 def test_the_references_layerwise_backward_is_the_whole_graphs():
     cfg = small_cfg()
     _, params = _params(cfg)

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from fmda_tpu.ops import ssd
-from fmda_tpu.ops.ssd import causal_conv, ssd_scan, ssd_scan_stepwise
+from fmda_tpu.ops.ssd import (
+    causal_conv, conv_silu, ssd_scan, ssd_scan_stepwise)
 
 B, H, P, N = 2, 3, 4, 5
 
@@ -210,29 +211,123 @@ def _shifted_sums(x, w, bias):
     return out
 
 
-def test_the_convolution_is_four_shifted_sums():
+def _as_written(x, w, bias, *, dtype):
+    return jax.nn.silu(causal_conv(x, w, bias)).astype(dtype)
+
+
+# the activated convolution as written (`causal_conv` + `silu`, the
+# reference) and as the mixer runs it (`conv_silu`): one result
+BOTH_FORMS = pytest.mark.parametrize(
+    "conv", [_as_written, conv_silu], ids=["as_written", "conv_silu"])
+
+
+@BOTH_FORMS
+def test_the_convolution_is_four_shifted_sums(conv):
     k = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(k[0], (2, 19, 6))
     w = jax.random.normal(k[1], (6, 4))
     bias = jax.random.normal(k[2], (6,))
+    want = _shifted_sums(x, w, bias)
     got = causal_conv(x, w, bias)
     assert got.dtype == jnp.float32 and got.shape == x.shape
-    np.testing.assert_allclose(got, _shifted_sums(x, w, bias), rtol=1e-5,
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    got = conv(x, w, bias, dtype=jnp.float32)
+    assert got.dtype == jnp.float32 and got.shape == x.shape
+    np.testing.assert_allclose(got, want / (1 + np.exp(-want)), rtol=1e-5,
                                atol=1e-6)
     # the first position sees its own tap and the bias alone
-    np.testing.assert_allclose(got[:, 0], bias + w[:, 3] * x[:, 0],
-                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        got[:, 0], jax.nn.silu(bias + w[:, 3] * x[:, 0]), rtol=1e-5,
+        atol=1e-6)
 
 
-def test_the_convolution_reads_no_future_position():
+@BOTH_FORMS
+def test_the_convolution_reads_no_future_position(conv):
     k = jax.random.split(jax.random.PRNGKey(1), 3)
     x = jax.random.normal(k[0], (1, 16, 5))
     w, bias = jax.random.normal(k[1], (5, 4)), jnp.zeros((5,))
-    base = causal_conv(x, w, bias)
-    moved = causal_conv(x.at[:, 9].add(1.0), w, bias)
+    base = conv(x, w, bias, dtype=jnp.float32)
+    moved = conv(x.at[:, 9].add(1.0), w, bias, dtype=jnp.float32)
     changed = np.flatnonzero(np.abs(np.asarray(moved - base)).max((0, 2)))
     assert changed.tolist() == [9, 10, 11, 12]
     # and the gradient of a position reaches back three, never forward
-    grad = jax.grad(lambda v: causal_conv(v, w, bias)[0, 9].sum())(x)
+    grad = jax.grad(
+        lambda v: conv(v, w, bias, dtype=jnp.float32)[0, 9].sum())(x)
     reached = np.flatnonzero(np.abs(np.asarray(grad)).max((0, 2)))
     assert reached.tolist() == [6, 7, 8, 9]
+
+
+@pytest.mark.parametrize("dtype,batch,t,channels", [
+    (jnp.float32, 1, 512, 256),
+    (jnp.bfloat16, 2, 512, 256),
+    (jnp.float32, 2, 24, 256),
+    (jnp.bfloat16, 1, 256, 256),
+    (jnp.float32, 1, 600, 256),
+    (jnp.bfloat16, 2, 600, 256),
+    (jnp.bfloat16, 1, 512, 4352),
+    (jnp.float32, 2, 300, 4352),
+])
+def test_conv_silu_is_the_convolution_as_written_and_its_gradient(
+        dtype, batch, t, channels):
+    """The value bit for bit (the same float32 sums in the same order,
+    one rounding), and ``dx``, ``dw``, ``dbias`` against ``jax.grad`` of
+    the form as written (``dx`` added in float32 and rounded once to
+    ``x``'s dtype on both)."""
+    f32 = jnp.float32
+    k = jax.random.split(jax.random.PRNGKey(t + channels), 4)
+    x = jax.random.normal(k[0], (batch, t, channels)).astype(dtype)
+    w = jax.random.uniform(k[1], (channels, 4), minval=-0.5, maxval=0.5)
+    bias = jax.random.uniform(k[2], (channels,), minval=-0.5, maxval=0.5)
+    ct = jax.random.normal(k[3], (batch, t, channels))
+
+    for out in (f32, dtype):
+        got = conv_silu(x, w, bias, dtype=out)
+        assert got.dtype == out
+        assert (got == _as_written(x, w, bias, dtype=out)).all()
+
+    def grads(conv):
+        return jax.grad(lambda *a: (
+            conv(*a, dtype=dtype).astype(f32) * ct).sum(), (0, 1, 2))(
+                x, w, bias)
+
+    for name, g, ref in zip(("dx", "dw", "dbias"), grads(conv_silu),
+                            grads(_as_written)):
+        assert g.dtype == ref.dtype and g.shape == ref.shape, name
+        tol = 1e-2 if (name == "dx" and dtype == jnp.bfloat16) else 2e-5
+        _close(g.astype(f32), ref.astype(f32), tol)
+
+
+def test_conv_silu_keeps_its_arguments_alone_and_adds_dx_in_float32():
+    """What backward holds of the forward is ``x``, ``w`` and ``bias``
+    (no float32 array of the sequence), and a bfloat16 ``dx`` is the
+    float32 sum of its four terms rounded once: as close to the float32
+    gradient as one rounding allows, where autodiff of the forward's own
+    sums (each term rounded to bfloat16, then added) is not."""
+    from fmda_tpu.ops.ssd import _conv_taps
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(k[0], (2, 64, 128)).astype(bf16)
+    w = jax.random.uniform(k[1], (128, 4), minval=-0.5, maxval=0.5)
+    bias = jax.random.uniform(k[2], (128,), minval=-0.5, maxval=0.5)
+    ct = jax.random.normal(k[3], x.shape)
+
+    _, vjp = jax.vjp(lambda *a: conv_silu(*a, dtype=bf16), x, w, bias)
+    kept = sorted((leaf.shape, leaf.dtype.name)
+                  for leaf in jax.tree.leaves(vjp) if hasattr(leaf, "shape"))
+    assert kept == sorted([(x.shape, "bfloat16"), (w.shape, "float32"),
+                           (bias.shape, "float32")])
+
+    def dx(conv, v):
+        return jax.grad(lambda v: (conv(v).astype(f32) * ct).sum())(v)
+
+    exact = dx(lambda v: _as_written(v, w, bias, dtype=f32), x.astype(f32))
+    got = dx(lambda v: conv_silu(v, w, bias, dtype=bf16), x)
+    naive = dx(lambda v: jax.nn.silu(_conv_taps(v, w, bias)[0]).astype(bf16),
+               x)
+    assert got.dtype == bf16 and naive.dtype == bf16
+    # autodiff of the form as written also adds in float32 and rounds once
+    rounded = dx(lambda v: _as_written(v, w, bias, dtype=bf16), x)
+    assert (got == rounded).mean() > 0.99
+    err = lambda g: float(jnp.abs(g.astype(f32) - exact).mean())
+    assert err(got) < 0.75 * err(naive)

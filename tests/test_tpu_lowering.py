@@ -302,3 +302,98 @@ def test_the_chunked_scan_compiles_at_64_heads_of_64_on_state_128_by_8192(
         _shape(one_chip, (1, t, n), BF16), _shape(one_chip, (1, t, n), BF16),
         _shape(one_chip, (h,), jnp.float32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1_200_000_000
+
+
+def _hbm_instructions(text):
+    """A compiled module's instructions outside its fused computations,
+    from the result type on: what exists as an array of its own."""
+    out, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            fused = "fused_computation" in line.split(" ", 1)[0]
+        elif not fused and " = " in line:
+            out.append(line.split(" = ", 1)[1])
+    return out
+
+
+def test_the_convolution_compiles_at_4352_channels_by_8192(one_chip):
+    """The state-space mixer's activated convolution at the hybrid
+    configuration's widths, bfloat16 in and out, 4 taps.  Forward is one
+    fusion that reads the input once and writes the result once: no
+    float32 array of the sequence (``silu(causal_conv(...))`` as written
+    keeps the widened input: 428 MB moved), no temporary.  With its
+    three cotangents one float32 array exists, ``dpre`` (as written:
+    the pre-activation's cotangent and one more a tap, 1.85 GB moved
+    beyond the forward)."""
+    import re
+
+    from fmda_tpu.ops.ssd import conv_silu
+
+    t, c = 8192, 4352
+    args = (_shape(one_chip, (1, t, c), BF16),
+            _shape(one_chip, (c, 4), jnp.float32),
+            _shape(one_chip, (c,), jnp.float32))
+
+    def value(x, w, bias):
+        return conv_silu(x, w, bias, dtype=BF16)
+
+    def step(x, w, bias, ct):
+        out, vjp = jax.vjp(value, x, w, bias)
+        return out, vjp(ct)
+
+    def sequence_arrays(compiled):
+        return [a for a in _hbm_instructions(compiled.as_text())
+                if re.match(r"\(?f32\[1,(819[25],4352|4352,819[25])\]", a)]
+
+    forward = jax.jit(value).lower(*args).compile()
+    assert not sequence_arrays(forward)
+    assert forward.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert forward.cost_analysis()["bytes accessed"] < 150e6
+    both = jax.jit(step).lower(*args, args[0]).compile()
+    assert len(sequence_arrays(both)) == 1
+    assert both.memory_analysis().temp_size_in_bytes < 150e6
+    assert both.cost_analysis()["bytes accessed"] < 600e6
+
+
+def test_a_hybrid_decoder_step_keeps_one_float32_array_a_convolution(
+        one_chip):
+    """The next-token loss's value and gradient through two recomputed
+    state-space layers, compiled for the chip: under ``ssm_conv`` no
+    float32 array of the convolution's channels in forward or replay,
+    and one a layer in backward (``dpre``)."""
+    import re
+
+    from fmda_tpu.config import ModelConfig, TrainConfig
+    from fmda_tpu.data.pipeline import Batch
+    from fmda_tpu.models import build_model
+    from fmda_tpu.train.tasks import NextToken
+
+    t, layout = 2048, (3, 3)
+    cfg = ModelConfig(
+        cell="decoder", hidden_size=256, n_heads=4, n_kv_heads=2,
+        head_dim=64, vocab_size=512, layer_layout=layout, moe_experts=0,
+        ffn_size=512, hidden_act="silu", ssm_heads=8, ssm_head_dim=64,
+        ssm_state=128, ssm_conv=4, ssm_chunk=256, tie_embeddings=True,
+        loss_chunk=256, dtype="bfloat16", use_pallas=True, remat=True)
+    model = build_model(cfg)
+    task = NextToken(cfg, TrainConfig(batch_size=1, window=t))
+    params = jax.eval_shape(
+        lambda key: model.init({"params": key},
+                               jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+
+    def step(p, x, y, mask):
+        batch = Batch(x, y, mask)
+        return jax.value_and_grad(lambda p: task.loss(
+            p, task.forward(model, p, batch, None), batch)[0])(p)
+
+    text = jax.jit(step).lower(
+        jax.tree.map(lambda l: _shape(one_chip, l.shape, l.dtype), params),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.float32)).compile().as_text()
+    # inner 512 + 2 x 128 channels
+    wide = [a for a in _hbm_instructions(text) if "ssm_conv" in a and re.match(
+        r"\(?f32\[1,(20(48|51),768|768,20(48|51))\]", a)]
+    assert len(wide) == len(layout), len(wide)
+    assert all("transpose(jvp" in a for a in wide)
