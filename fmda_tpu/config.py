@@ -453,7 +453,9 @@ class ModelConfig:
     #: keys of the causal past a query (ops/sparse_attention.py), and the
     #: router placed *after* attention, reading the expert block's
     #: normalised input; 3 = a state-space layer: no attention, a scan
-    #: over matrix-valued state (the ``ssm_*`` group below).
+    #: over matrix-valued state (the ``ssm_*`` group below); 4 = a latent-
+    #: attention layer (the ``q_lora_rank`` group below), in a model whose
+    #: layers are all of that kind.
     layer_layout: Tuple[int, ...] = ()
     sliding_window: int = 4096
     rope_theta: float = 10000.0
@@ -509,6 +511,57 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
+    #: ``layer_layout`` 4, latent attention (models/decoder.py): queries
+    #: through a ``q_lora_rank``-wide normalised latent, keys and values
+    #: through a ``kv_lora_rank``-wide one; a head's query and key are
+    #: ``qk_nope_head_dim`` wide without position plus ``qk_rope_head_dim``
+    #: rotary dims whose key is ONE head shared by all ``n_heads``; values
+    #: are ``v_head_dim`` wide.  ``head_dim`` / ``n_kv_heads`` are not
+    #: read by such a layer.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: YaRN's stretch of that layer's rotary frequencies (``rope_factor``
+    #: 1: plain rotary at ``rope_theta``): dims that turn more than
+    #: ``rope_beta_fast`` times over ``rope_original_max`` positions keep
+    #: their frequency, those under ``rope_beta_slow`` turns are slowed
+    #: by the factor, a ramp between; the scores are multiplied by
+    #: ``(0.1 ln(rope_factor) + 1)^2`` beside ``1 / sqrt(q width)``.
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_original_max: int = 0
+    #: With ``moe_experts > 0``: the first ``first_dense_layers`` layers'
+    #: feed-forward is the dense MLP of ``ffn_size`` instead of experts.
+    first_dense_layers: int = 0
+    #: Experts every token passes through beside its routed ones (one
+    #: gated MLP ``moe_shared_experts * moe_ffn_size`` wide, no gate).
+    moe_shared_experts: int = 0
+    #: The router's scores: "softmax" over all experts, or "sigmoid" of
+    #: each (ops/moe.py ``route``): the top-k then chosen on score +
+    #: a selection bias, gates from the unbiased scores, normalised over
+    #: the top-k, times ``moe_routed_scaling``.
+    moe_scoring: str = "softmax"
+    moe_routed_scaling: float = 1.0
+    #: > 0: the router carries a selection bias (E,), a parameter no
+    #: gradient reaches and the optimizer does not move; after each train
+    #: step ``bias_e += moe_bias_rate * sign(mean load - load_e)`` over
+    #: the step's pairs on all ``moe_experts`` (train/tasks.py).
+    moe_bias_rate: float = 0.0
+    #: Lanes of the residual stream (ops/hyper_connection.py): 1 is the
+    #: plain residual; ``n > 1`` carries ``(B, T, n, hidden)`` and wraps
+    #: each sublayer in learned pre / post / residual mixing, the
+    #: residual mix made doubly stochastic by ``hc_sinkhorn_iters`` turns
+    #: of Sinkhorn's iteration (``hc_eps`` beside each sum) from logits
+    #: clipped to +-``hc_res_clamp``.  A fresh block reads and writes
+    #: lane 0 and remixes by nearly the identity (models/latent_block.py
+    #: ``HC_OFFSET_INIT``).
+    hc_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
 
 
 @dataclass(frozen=True)

@@ -110,6 +110,8 @@ EMBED_INIT_STD = 1.0
 SPARSE_LAYOUT = 2
 #: ... and for a state-space layer (ops/ssd.py).
 SSM_LAYOUT = 3
+#: ... and for a latent-attention layer (models/latent_block.py).
+LATENT_LAYOUT = 4
 
 #: What a block's recomputation (``cfg.remat``) keeps from the forward
 #: pass, by name; everything else it remakes from the block's input.
@@ -142,6 +144,18 @@ class RoutingStats(NamedTuple):
     #: another kind.
     ssd_chunks: Optional[jax.Array] = None
     ssd_positions: Optional[jax.Array] = None
+    #: What the latent-attention layers counted, None in a model without
+    #: one: the pairs each of ALL the router's experts received, held
+    #: here or not, (layers, moe_experts) int32 (what the selection bias
+    #: steps on; a dense layer's row is 0); the largest size of a
+    #: selection bias, (layers,) float32; the largest distance of a row
+    #: or column sum of a residual mixing matrix from one, (layers,)
+    #: float32 (0 with one lane); the causal pairs each core scored,
+    #: (layers,) int32.
+    router_load: Optional[jax.Array] = None
+    router_bias_absmax: Optional[jax.Array] = None
+    hc_sum_error: Optional[jax.Array] = None
+    latent_pairs: Optional[jax.Array] = None
 
 
 def _weight(module: nn.Module, name: str, shape: Tuple[int, ...],
@@ -230,14 +244,19 @@ def _ssm_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
     return out, (jnp.int32(b * states.shape[1]), jnp.int32(b * t))
 
 
-def _dense_mlp(module: nn.Module, cfg: ModelConfig, u: jax.Array):
-    """``(act(u @ Wg) * (u @ Wu)) @ Wd`` on the normalised stream."""
-    d, f, dt = u.shape[-1], cfg.ffn_size, u.dtype
-    with jax.named_scope("dense_mlp"):
-        gate = jnp.dot(u, _weight(module, "w_gate", (d, f)).astype(dt))
-        up = jnp.dot(u, _weight(module, "w_up", (d, f)).astype(dt))
+def _dense_mlp(module: nn.Module, cfg: ModelConfig, u: jax.Array,
+               width: Optional[int] = None,
+               names: Tuple[str, str, str] = ("w_gate", "w_up", "w_down"),
+               scope: str = "dense_mlp"):
+    """``(act(u @ Wg) * (u @ Wu)) @ Wd`` on the normalised stream,
+    ``cfg.ffn_size`` wide unless a ``width`` is stated (a shared expert's,
+    under its own leaves' ``names`` and its own ``scope``)."""
+    d, f, dt = u.shape[-1], width or cfg.ffn_size, u.dtype
+    with jax.named_scope(scope):
+        gate = jnp.dot(u, _weight(module, names[0], (d, f)).astype(dt))
+        up = jnp.dot(u, _weight(module, names[1], (d, f)).astype(dt))
         return jnp.dot(ACTIVATIONS[cfg.hidden_act](gate) * up,
-                       _weight(module, "w_down", (f, d)).astype(dt))
+                       _weight(module, names[2], (f, d)).astype(dt))
 
 
 class DecoderBlock(nn.Module):
@@ -373,15 +392,26 @@ class MoEDecoder(nn.Module):
         self.embed = _weight(self, "embed", (cfg.vocab_size, d),
                              INIT_STD if cfg.tie_embeddings
                              else EMBED_INIT_STD)
-        block_cls = DecoderBlock
-        if cfg.remat:
-            block_cls = nn.remat(
-                DecoderBlock,
+        def replayed(block_cls):
+            if not cfg.remat:
+                return block_cls
+            return nn.remat(
+                block_cls,
                 policy=jax.checkpoint_policies.save_only_these_names(
                     *REPLAY_KEEPS))
-        self.blocks = [
-            block_cls(cfg, int(layout), name=f"block_{i}")
-            for i, layout in enumerate(cfg.layer_layout)]
+
+        if LATENT_LAYOUT in cfg.layer_layout:
+            from fmda_tpu.models.latent_block import LatentBlock
+
+            block_cls = replayed(LatentBlock)
+            self.blocks = [
+                block_cls(cfg, i < cfg.first_dense_layers, name=f"block_{i}")
+                for i in range(len(cfg.layer_layout))]
+        else:
+            block_cls = replayed(DecoderBlock)
+            self.blocks = [
+                block_cls(cfg, int(layout), name=f"block_{i}")
+                for i, layout in enumerate(cfg.layer_layout)]
         self.ln_final = self.param("ln_final", nn.initializers.ones, (d,))
         if not cfg.tie_embeddings:
             self.head = _weight(self, "head", (d, cfg.vocab_size))
@@ -398,16 +428,24 @@ class MoEDecoder(nn.Module):
         has_experts = cfg.moe_experts > 0
         sizes, tiles, dropped = [], [], jnp.zeros((), jnp.int32)
         zero = (jnp.zeros((2,), jnp.int32), jnp.zeros((), jnp.int32))
-        kept, walked = [], []
+        kept, walked, latent = [], [], []
+        if cfg.hc_streams > 1:
+            # every lane starts as the token's row
+            x = jnp.broadcast_to(
+                x[:, :, None, :], x.shape[:2] + (cfg.hc_streams, x.shape[-1]))
         for block in self.blocks:
             x, (layer_sizes, layer_dropped, layer_tiles, layer_kept,
-                layer_walked) = block(x)
+                layer_walked, *layer_latent) = block(x)
             if has_experts:
                 sizes.append(layer_sizes)
                 tiles.append(layer_tiles)
                 dropped = dropped + layer_dropped
             kept.append(layer_kept)
             walked.append(layer_walked)
+            latent.extend(layer_latent)
+        if cfg.hc_streams > 1:
+            with jax.named_scope("hyper_conn"):  # the lanes leave as one
+                x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
         x = rms_norm(x, self.ln_final, cfg.rms_norm_eps)
         stats = (RoutingStats(jnp.stack(sizes), dropped, jnp.stack(tiles))
                  if has_experts else RoutingStats(None, None, None))
@@ -420,6 +458,14 @@ class MoEDecoder(nn.Module):
                                       for w in walked))
             stats = stats._replace(ssd_chunks=jnp.stack(chunks),
                                    ssd_positions=jnp.stack(positions))
+        if latent:
+            load, absmax, sum_error, pairs = (
+                jnp.stack(v) for v in zip(*latent))
+            stats = stats._replace(
+                router_load=load if has_experts else None,
+                router_bias_absmax=absmax if has_experts else None,
+                hc_sum_error=sum_error if cfg.hc_streams > 1 else None,
+                latent_pairs=pairs)
         return x, stats
 
     def __call__(self, ids: jax.Array, *, deterministic: bool = True
@@ -443,18 +489,61 @@ def check_decoder_config(cfg: ModelConfig) -> None:
     sparse = SPARSE_LAYOUT in cfg.layer_layout
     state_space = SSM_LAYOUT in cfg.layer_layout
     dense = cfg.moe_experts == 0
+    latent = LATENT_LAYOUT in cfg.layer_layout
+    lanes = cfg.hc_streams > 1
+    sigmoid = cfg.moe_scoring == "sigmoid"
     why_ssm = " (layer_layout has a state-space layer)"
+    why_latent = " (layer_layout has a latent-attention layer)"
+    only_latent = " (a latent-attention model's)"
     problems = [name for name, ok in (
         ("vocab_size", cfg.vocab_size > 0),
-        ("head_dim", cfg.head_dim > 0),
-        ("n_kv_heads (must divide n_heads)",
-         cfg.n_kv_heads > 0 and cfg.n_heads % cfg.n_kv_heads == 0),
-        ("layer_layout (one of 0/1/2/3 per layer)",
+        ("head_dim", latent or cfg.head_dim > 0),
+        ("n_kv_heads (must divide n_heads)", latent or (
+            cfg.n_kv_heads > 0 and cfg.n_heads % cfg.n_kv_heads == 0)),
+        ("layer_layout (one of 0/1/2/3 per layer, or 4 in every layer)",
          len(cfg.layer_layout) > 0
-         and all(v in (0, 1, SPARSE_LAYOUT, SSM_LAYOUT)
-                 for v in cfg.layer_layout)),
-        ("ffn_size (moe_experts is 0: a dense gated MLP)",
-         not dense or cfg.ffn_size > 0),
+         and (all(v == LATENT_LAYOUT for v in cfg.layer_layout) if latent
+              else all(v in (0, 1, SPARSE_LAYOUT, SSM_LAYOUT)
+                       for v in cfg.layer_layout))),
+        ("ffn_size (moe_experts is 0 or first_dense_layers is not: a "
+         "dense gated MLP)",
+         not (dense or cfg.first_dense_layers > 0) or cfg.ffn_size > 0),
+        ("q_lora_rank" + why_latent, not latent or cfg.q_lora_rank > 0),
+        ("kv_lora_rank" + why_latent, not latent or cfg.kv_lora_rank > 0),
+        ("qk_nope_head_dim" + why_latent,
+         not latent or cfg.qk_nope_head_dim > 0),
+        ("qk_rope_head_dim (even, for rotary)" + why_latent,
+         not latent or (cfg.qk_rope_head_dim > 0
+                        and cfg.qk_rope_head_dim % 2 == 0)),
+        ("v_head_dim" + why_latent, not latent or cfg.v_head_dim > 0),
+        ("rope_factor (at least 1) / rope_original_max (positive where "
+         "the factor stretches)",
+         cfg.rope_factor >= 1 and (cfg.rope_factor == 1
+                                   or cfg.rope_original_max > 0)),
+        ("attention_multiplier / residual_multiplier (a latent-attention "
+         "layer states its own score scale and joins the stream unscaled)",
+         not latent or (cfg.attention_multiplier is None
+                        and cfg.residual_multiplier == 1.0)),
+        ("first_dense_layers (0 .. the depth; more than 0 with experts"
+         + only_latent + ")",
+         0 <= cfg.first_dense_layers <= len(cfg.layer_layout)
+         and (cfg.first_dense_layers == 0 or (latent and not dense))),
+        ("moe_shared_experts (more than 0 with experts" + only_latent + ")",
+         cfg.moe_shared_experts >= 0
+         and (cfg.moe_shared_experts == 0 or (latent and not dense))),
+        ("moe_scoring (softmax, or sigmoid" + only_latent + ")",
+         cfg.moe_scoring == "softmax" or (sigmoid and latent)),
+        ("moe_routed_scaling (positive; other than 1 with sigmoid scores)",
+         cfg.moe_routed_scaling > 0
+         and (cfg.moe_routed_scaling == 1.0 or sigmoid)),
+        ("moe_bias_rate (0, or positive with sigmoid scores)",
+         cfg.moe_bias_rate == 0 or (cfg.moe_bias_rate > 0 and sigmoid)),
+        ("hc_streams (1, or more lanes" + only_latent + ")",
+         cfg.hc_streams == 1 or (lanes and latent)),
+        ("hc_sinkhorn_iters (hc_streams is more than 1)",
+         not lanes or cfg.hc_sinkhorn_iters > 0),
+        ("hc_eps / hc_res_clamp (positive; hc_streams is more than 1)",
+         not lanes or (cfg.hc_eps > 0 and cfg.hc_res_clamp > 0)),
         ("moe_experts / moe_top_k",
          dense or 0 < cfg.moe_top_k <= cfg.moe_experts),
         ("moe_ffn_size", dense or cfg.moe_ffn_size > 0),

@@ -227,9 +227,11 @@ def mha(
     recomputed in backward), so no path materialises (B, N, Tq, Tk).
 
     Args:
-      q: (B, N, Tq, D).  k, v: (B, G, Tk, D) with ``N % G == 0`` — each
-        run of ``N / G`` consecutive query heads shares one key/value
-        head (G == N: plain multi-head attention).
+      q: (B, N, Tq, D).  k: (B, G, Tk, D), v: (B, G, Tk, Dv) with
+        ``N % G == 0`` — each run of ``N / G`` consecutive query heads
+        shares one key/value head (G == N: plain multi-head attention).
+        Values may be narrower or wider than queries and keys
+        (``Dv != D``: latent attention scores 192 wide, reads 128).
       causal: apply a lower-triangular causal mask (needed for streaming
         serving where position t must not see the future).
       window: a causal window — key j is visible to query i iff
@@ -239,7 +241,7 @@ def mha(
       scale: what the scores are multiplied by before the softmax (a
         model's stated attention multiplier); None is ``1 / sqrt(D)``.
 
-    Returns (B, N, Tq, D) in q's dtype.
+    Returns (B, N, Tq, Dv) in q's dtype.
     """
     tq, tk = q.shape[-2], k.shape[-2]
     causal = causal or window is not None
@@ -269,7 +271,7 @@ def mha(
                              else full_mask & mask_blk)
             state = init_online_state(
                 q_blk.shape[0], q_blk.shape[1], q_blk.shape[2],
-                q_blk.shape[3])
+                v.shape[3])
             state = online_attention_block(state, q_blk, k, v, full_mask,
                                            scale)
             return finalize_online_state(state, q.dtype)
@@ -295,7 +297,8 @@ def mha(
                 lambda xs: jax.checkpoint(attend)(*xs),
                 (q_blocks, pos_blocks, mask_blocks))
         return checkpoint_name(
-            jnp.moveaxis(out, 0, 2).reshape(q.shape), CORE_OUT)
+            jnp.moveaxis(out, 0, 2).reshape(q.shape[:3] + v.shape[3:]),
+            CORE_OUT)
 
 
 def split_heads(x: jax.Array, n_heads: int) -> jax.Array:

@@ -94,18 +94,42 @@ def layout_tiles(n_pairs: int, count: int) -> int:
     return layout_rows(n_pairs, count, tile) // tile
 
 
-def route(h: jax.Array, w_router: jax.Array, top_k: int
-          ) -> Tuple[jax.Array, jax.Array]:
+def route(h: jax.Array, w_router: jax.Array, top_k: int, *,
+          scoring: str = "softmax", bias: jax.Array = None,
+          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """``(gates (T, k) float32, experts (T, k) int32)``: softmax over
     all experts in float32, the ``top_k`` largest, their probabilities
-    renormalised to sum to one."""
+    renormalised to sum to one.
+
+    ``scoring="sigmoid"``: each expert's score is its own sigmoid; the
+    ``top_k`` are chosen on ``score + bias`` (``bias`` (E,): a selection
+    bias no gradient reaches, None for none), the gates are the chosen
+    experts' *unbiased* scores over their sum, times ``scale``.  Ties go
+    to the lower expert, as :func:`jax.lax.top_k` breaks them."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(h, w_router.astype(h.dtype),
                          preferred_element_type=jnp.float32)
+        if scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            chosen_on = scores if bias is None else (
+                scores + jax.lax.stop_gradient(bias.astype(jnp.float32)))
+            _, experts = jax.lax.top_k(chosen_on, top_k)
+            top = jnp.take_along_axis(scores, experts, axis=-1)
+            gates = scale * top / jnp.sum(top, axis=-1, keepdims=True)
+            return gates, experts.astype(jnp.int32)
         probs = jax.nn.softmax(logits, axis=-1)
         top, experts = jax.lax.top_k(probs, top_k)
         gates = top / jnp.sum(top, axis=-1, keepdims=True)
         return gates, experts.astype(jnp.int32)
+
+
+def router_load(experts: jax.Array, n_experts: int) -> jax.Array:
+    """Pairs each of the router's ``n_experts`` outputs received, held
+    here or not: (E,) int32 from ``experts`` (T, k)."""
+    return jnp.sum(
+        experts.reshape(-1)[:, None]
+        == jnp.arange(n_experts, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32)
 
 
 def plan_dispatch(experts: jax.Array, experts_held: Tuple[int, int],

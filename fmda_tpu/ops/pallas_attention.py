@@ -61,8 +61,10 @@ grid is all overhead.  Two things ride inside the same kernels:
 Support envelope (:func:`flash_supported`): self-attention with
 ``Tq == Tk``, ``T % 128 == 0``, no arbitrary mask (causal and the causal
 window are in-kernel; a window implies causal), and D small enough that
-the per-block working set fits VMEM — in practice D <= 512.  Everything
-else takes the jnp path via :func:`fmda_tpu.ops.attention.mha`'s
+the per-block working set fits VMEM — in practice D <= 512.  Values
+may have a width of their own (``Dv``, latent attention's 128 beside
+scores over 192): ``p @ v``, ``o``, ``do`` and ``dv`` are then ``Dv``
+wide and nothing is padded.  Everything else takes the jnp path via :func:`fmda_tpu.ops.attention.mha`'s
 dispatch.
 """
 
@@ -168,12 +170,12 @@ def _scores(q, k, qi, ki, *, blk, window, masked, scale=None):
 def _fwd_kernel(
     q_ref,  # (1, blk, D)
     k_ref,  # (1, blk, D)
-    v_ref,  # (1, blk, D)
-    o_ref,  # out (1, blk, D)
+    v_ref,  # (1, blk, Dv)
+    o_ref,  # out (1, blk, Dv)
     lse_ref,  # out (1, blk, 128) lane-replicated logsumexp
     m_scr,  # VMEM (blk, 128) f32
     l_scr,  # VMEM (blk, 128) f32
-    acc_scr,  # VMEM (blk, D) f32
+    acc_scr,  # VMEM (blk, Dv) f32
     *,
     causal: bool,
     window: Optional[int],
@@ -254,8 +256,10 @@ def _fwd_impl(
     *, causal: bool, window: Optional[int], interpret: bool,
     scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """q (BN, T, D), k/v (BG, T, D) -> (o (BN, T, D), lse (BN, T, 128))."""
+    """q (BN, T, D), k (BG, T, D), v (BG, T, Dv) -> (o (BN, T, Dv), lse
+    (BN, T, 128))."""
     bn, t, d = q.shape
+    dv = v.shape[-1]
     group = bn // k.shape[0]
     blk = block_for(t)
     n_blk = t // blk
@@ -275,20 +279,20 @@ def _fwd_impl(
         in_specs=[
             pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, blk, d), kv_index),
-            pl.BlockSpec((1, blk, d), kv_index),
+            pl.BlockSpec((1, blk, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, blk, dv), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, blk, 128), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bn, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bn, t, 128), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk, 128), jnp.float32),
             pltpu.VMEM((blk, 128), jnp.float32),
-            pltpu.VMEM((blk, d), jnp.float32),
+            pltpu.VMEM((blk, dv), jnp.float32),
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
@@ -317,14 +321,14 @@ def _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
 def _dkv_kernel(
     q_ref,  # (1, blk, D) — query block qi
     k_ref,  # (1, blk, D) — the fixed key block ki
-    v_ref,  # (1, blk, D)
-    do_ref,  # (1, blk, D) — dO for query block qi
+    v_ref,  # (1, blk, Dv)
+    do_ref,  # (1, blk, Dv) — dO for query block qi
     lse_ref,  # (1, blk, 128)
     delta_ref,  # (1, blk, 128)
     dk_ref,  # out (1, blk, D)
-    dv_ref,  # out (1, blk, D)
+    dv_ref,  # out (1, blk, Dv)
     dk_scr,  # VMEM (blk, D) f32
-    dv_scr,  # VMEM (blk, D) f32
+    dv_scr,  # VMEM (blk, Dv) f32
     *,
     causal: bool,
     window: Optional[int],
@@ -368,8 +372,8 @@ def _dkv_kernel(
 def _dq_kernel(
     q_ref,  # (1, blk, D) — the fixed query block qi
     k_ref,  # (1, blk, D) — key block ki
-    v_ref,  # (1, blk, D)
-    do_ref,  # (1, blk, D)
+    v_ref,  # (1, blk, Dv)
+    do_ref,  # (1, blk, Dv)
     lse_ref,  # (1, blk, 128)
     delta_ref,  # (1, blk, 128)
     dq_ref,  # out (1, blk, D)
@@ -410,6 +414,7 @@ def _bwd_impl(
     window: Optional[int], interpret: bool, scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     bn, t, d = q.shape
+    dv = v.shape[-1]
     bg = k.shape[0]
     group = bn // bg
     blk = block_for(t)
@@ -429,28 +434,31 @@ def _bwd_impl(
         return pl.BlockSpec((1, blk, width), lambda b, ki, qi: (
             b, _clamp_query_block(ki, qi, n_q=n_blk, **clamp), 0))
 
-    kspec = pl.BlockSpec((1, blk, d), lambda b, ki, qi: (b // group, ki, 0))
+    def kspec(width):  # the fixed key block's operands
+        return pl.BlockSpec((1, blk, width),
+                            lambda b, ki, qi: (b // group, ki, 0))
+
     # one partial per query head; a group's partials are summed below, in
     # float32 where there is more than one
     part = q.dtype if group == 1 else jnp.float32
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, window=window,
                           blk=blk, n_q=n_blk, **_stated(scale)),
         name="flash_bwd_dkv",
         grid=(bn, n_blk, n_blk),
-        in_specs=[q_rows(d), kspec, kspec, q_rows(d), q_rows(128),
+        in_specs=[q_rows(d), kspec(d), kspec(dv), q_rows(dv), q_rows(128),
                   q_rows(128)],
         out_specs=[
             pl.BlockSpec((1, blk, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, blk, d), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, blk, dv), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bn, t, d), part),
-            jax.ShapeDtypeStruct((bn, t, d), part),
+            jax.ShapeDtypeStruct((bn, t, dv), part),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk, d), jnp.float32),
-            pltpu.VMEM((blk, d), jnp.float32),
+            pltpu.VMEM((blk, dv), jnp.float32),
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
@@ -458,21 +466,23 @@ def _bwd_impl(
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     if group > 1:
-        dk, dv = (x.reshape(bg, group, t, d).sum(axis=1).astype(k.dtype)
-                  for x in (dk, dv))
+        dk, dv_ = (x.reshape(bg, group, t, x.shape[-1]).sum(axis=1)
+                   .astype(k.dtype) for x in (dk, dv_))
 
     def k_rows(b, qi, ki):
         return (b // group, _clamp_key_block(qi, ki, **clamp), 0)
 
     qspec2 = pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0))
+    dospec2 = pl.BlockSpec((1, blk, dv), lambda b, qi, ki: (b, qi, 0))
     kspec2 = pl.BlockSpec((1, blk, d), k_rows)
+    vspec2 = pl.BlockSpec((1, blk, dv), k_rows)
     rspec2 = pl.BlockSpec((1, blk, 128), lambda b, qi, ki: (b, qi, 0))
     (dq,) = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, window=window,
                           blk=blk, n_k=n_blk, **_stated(scale)),
         name="flash_bwd_dq",
         grid=(bn, n_blk, n_blk),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
+        in_specs=[qspec2, kspec2, vspec2, dospec2, rspec2, rspec2],
         out_specs=[qspec2],
         out_shape=[jax.ShapeDtypeStruct((bn, t, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
@@ -481,7 +491,7 @@ def _bwd_impl(
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 def _stated(scale: Optional[float]) -> dict:
@@ -556,8 +566,10 @@ def flash_attention_with_lse(
     interpret: bool = False,
     scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Fused attention returning ``(o, lse)`` — o (B, N, T, D) in q's
-    dtype plus the per-row logsumexp (B, N, T) f32.
+    """Fused attention returning ``(o, lse)`` — o (B, N, T, Dv) in q's
+    dtype plus the per-row logsumexp (B, N, T) f32.  Values may have a
+    width of their own (v (B, G, T, Dv), ``Dv != D``): scores are taken
+    over D, the output and its gradient are Dv wide.
 
     The lse is what makes the output *mergeable*: two attention results
     over disjoint key segments combine exactly via
@@ -586,5 +598,5 @@ def flash_attention_with_lse(
         window = None  # the band is the whole causal triangle
     out, lse = _flash(
         q.reshape(b * n, t, d), k.reshape(b * g, t, d),
-        v.reshape(b * g, t, d), causal, window, interpret, scale)
-    return out.reshape(b, n, t, d), lse.reshape(b, n, t)
+        v.reshape(b * g, t, v.shape[-1]), causal, window, interpret, scale)
+    return out.reshape(b, n, t, v.shape[-1]), lse.reshape(b, n, t)

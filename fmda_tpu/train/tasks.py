@@ -18,6 +18,10 @@ family's *task* (:func:`task_for`):
 ``zero_totals``       the totals at zero, as host arrays
 ``epoch_metrics``     the drained totals as :class:`EpochMetrics`
 ``publish``           counters written once a pass, at the drain
+``fold``              (optional) the totals with a step's values, where
+                      not every entry is a sum
+``after_update``      (optional) the step rule of parameters the
+                      optimizer does not move
 ``feature_windows``   whether the source is a table of float features
                       (class weights, a drift profile, ``n_features``)
 ``norm_params``       the normalisation a checkpoint saves beside the
@@ -40,7 +44,8 @@ import numpy as np
 from fmda_tpu.config import ModelConfig, TrainConfig
 from fmda_tpu.data.pipeline import (
     Batch, ChunkDataset, TokenBatches, TokenDataset, WindowBatches)
-from fmda_tpu.models.decoder import SPARSE_LAYOUT, SSM_LAYOUT
+from fmda_tpu.models.decoder import (
+    LATENT_LAYOUT, SPARSE_LAYOUT, SSM_LAYOUT)
 from fmda_tpu.ops.metrics import multilabel_metrics
 from fmda_tpu.train.losses import (
     chunked_next_token_loss, weighted_bce_sums, weighted_bce_with_logits)
@@ -94,6 +99,22 @@ class TokenTotals(NamedTuple):
     #: chunks and the positions each walked, (layers,) int32.
     ssd_chunks: Optional[jax.Array] = None
     ssd_positions: Optional[jax.Array] = None
+    #: The latent-attention layers (None in a model without one; models/
+    #: decoder.py ``RoutingStats``): pairs on each of all the router's
+    #: experts, (layers, moe_experts) int32; the causal pairs the cores
+    #: scored, (layers,) int32; and two that a pass folds by ``max``, not
+    #: by sum (:data:`FOLDED_BY_MAX`): the largest size of a selection
+    #: bias and the largest distance of a residual mixing matrix's row or
+    #: column sum from one, (layers,) float32.
+    router_load: Optional[jax.Array] = None
+    latent_pairs: Optional[jax.Array] = None
+    router_bias_absmax: Optional[jax.Array] = None
+    hc_sum_error: Optional[jax.Array] = None
+
+
+#: The :class:`TokenTotals` fields whose pass value is the largest of the
+#: steps' values.
+FOLDED_BY_MAX = ("router_bias_absmax", "hc_sum_error")
 
 
 def keys_kept_counts(sparse_keys_kept) -> list:
@@ -250,6 +271,15 @@ class NextToken:
         if SSM_LAYOUT in mc.layer_layout:
             totals = totals._replace(ssd_chunks=per_layer,
                                      ssd_positions=per_layer)
+        if LATENT_LAYOUT in mc.layer_layout:
+            gauge = np.zeros((layers,), np.float32)
+            totals = totals._replace(latent_pairs=per_layer)
+            if mc.moe_experts:
+                totals = totals._replace(
+                    router_load=np.zeros((layers, mc.moe_experts), np.int32),
+                    router_bias_absmax=gauge)
+            if mc.hc_streams > 1:
+                totals = totals._replace(hc_sum_error=gauge)
         return totals
 
     def epoch_metrics(self, totals: Optional[TokenTotals], steps: int
@@ -279,6 +309,8 @@ class NextToken:
             self._publish_selection(reg, totals, phase)
         if totals.ssd_chunks is not None:
             self._publish_scans(reg, totals, phase)
+        if totals.latent_pairs is not None:
+            self._publish_latent(reg, totals, phase)
 
     def _publish_routing(self, reg, totals: TokenTotals, phase: str,
                          steps: int) -> None:
@@ -293,6 +325,8 @@ class NextToken:
             mc.experts_held[1])
         reg.counter("moe_pairs_dropped_total").inc(int(totals.dropped))
         for layer, pairs in enumerate(np.asarray(totals.expert_pairs)):
+            if layer < mc.first_dense_layers:
+                continue  # a dense layer of a model with experts
             labels = dict(layer=str(layer), phase=phase)
             reg.counter("moe_pairs_held_total", **labels).inc(
                 int(pairs.sum()))
@@ -333,7 +367,58 @@ class NextToken:
             reg.counter("ssd_positions_total", **labels).inc(
                 int(totals.ssd_positions[layer]))
 
+    def _publish_latent(self, reg, totals: TokenTotals, phase: str) -> None:
+        # the latent-attention layers: the causal pairs scored; the load
+        # on ALL the router's experts (what the selection bias steps on)
+        # and the bias's size; how far a residual mixing matrix's sums
+        # strayed from one
+        mc = self.model_cfg
+        for layer in range(len(mc.layer_layout)):
+            labels = dict(layer=str(layer), phase=phase)
+            reg.counter("attention_latent_pairs_total", **labels).inc(
+                int(totals.latent_pairs[layer]))
+            if totals.hc_sum_error is not None:
+                reg.gauge("hc_res_sum_error_max", **labels).set(
+                    float(totals.hc_sum_error[layer]))
+            if totals.router_load is None or layer < mc.first_dense_layers:
+                continue
+            reg.gauge("moe_router_bias_absmax", **labels).set(
+                float(totals.router_bias_absmax[layer]))
+            for expert, pairs in enumerate(
+                    np.asarray(totals.router_load[layer])):
+                reg.counter("moe_router_load_total", expert=str(expert),
+                            **labels).inc(int(pairs))
+
     # -- inside the compiled step ---------------------------------------------
+
+    def fold(self, totals: TokenTotals, values: TokenTotals) -> TokenTotals:
+        """The pass's totals with one step's values: sums, but for the
+        fields of :data:`FOLDED_BY_MAX`."""
+        worst = {name: jnp.maximum(getattr(totals, name),
+                                   getattr(values, name))
+                 for name in FOLDED_BY_MAX
+                 if getattr(values, name) is not None}
+        return jax.tree.map(jnp.add, totals, values)._replace(**worst)
+
+    def after_update(self, params, aux):
+        """The parameters after the optimizer's update and the step rule
+        of what it does not move: each expert layer's selection bias goes
+        up by ``moe_bias_rate`` where the step sent its expert fewer pairs
+        than the mean over all experts, and down where more."""
+        stats = aux[2]
+        if stats.router_load is None or not self.model_cfg.moe_bias_rate:
+            return params
+        rate = self.model_cfg.moe_bias_rate
+        params = dict(params)
+        for layer, load in enumerate(stats.router_load):
+            block = params.get(f"block_{layer}", {})
+            if "router_bias" not in block:
+                continue
+            load = load.astype(jnp.float32)
+            params[f"block_{layer}"] = dict(
+                block, router_bias=block["router_bias"] + rate * jnp.sign(
+                    jnp.mean(load) - load))
+        return params
 
     def forward(self, model, params, batch: Batch, rng: Optional[jax.Array]):
         del rng  # the family has no dropout
@@ -356,15 +441,24 @@ class NextToken:
         return s / jnp.maximum(count, 1.0), aux
 
     def merge_micro(self, aux_k):
-        """Counts add over the microbatches."""
-        return jax.tree.map(lambda a: jnp.sum(a, axis=0), aux_k)
+        """Counts add over the microbatches; the largest of what a pass
+        folds by ``max`` (:data:`FOLDED_BY_MAX`, under the layers' own
+        names in the routing statistics)."""
+        tokens, correct, stats = jax.tree.map(
+            lambda a: jnp.sum(a, axis=0), aux_k)
+        worst = {name: jnp.max(getattr(aux_k[2], name), axis=0)
+                 for name in FOLDED_BY_MAX
+                 if getattr(stats, name) is not None}
+        return tokens, correct, stats._replace(**worst)
 
     def step_values(self, loss, aux, batch: Batch) -> TokenTotals:
         tokens, correct, stats = aux
         return TokenTotals(loss, tokens, correct, stats.expert_pairs,
                            stats.dropped, stats.row_tiles_used,
                            stats.keys_kept, stats.query_rows,
-                           stats.ssd_chunks, stats.ssd_positions)
+                           stats.ssd_chunks, stats.ssd_positions,
+                           stats.router_load, stats.latent_pairs,
+                           stats.router_bias_absmax, stats.hc_sum_error)
 
 
 def task_class(model_cfg: ModelConfig):

@@ -246,6 +246,7 @@ class Trainer:
     def _build_train_step(self):
         model, tc, task = self.model, self.train_cfg, self.task
         accum = tc.accum_steps
+        fold = getattr(task, "fold", _add_step)
 
         def grads_full(params, batch: Batch, dropout_rng):
             def loss_fn(params):
@@ -328,9 +329,10 @@ class Trainer:
                     grads, state.opt_state, state.params
                 )
                 params = optax.apply_updates(state.params, updates)
+                if hasattr(task, "after_update"):
+                    params = task.after_update(params, aux)
             with jax.named_scope("metrics"):
-                totals = _add_step(
-                    totals, task.step_values(loss, aux, batch))
+                totals = fold(totals, task.step_values(loss, aux, batch))
             new_state = TrainState(
                 params=params, opt_state=opt_state, step=state.step + 1
             )
@@ -354,6 +356,7 @@ class Trainer:
 
     def _build_eval_step(self):
         model, task = self.model, self.task
+        fold = getattr(task, "fold", _add_step)
 
         def eval_fn(params, totals, batch: Batch):
             with jax.named_scope("forward"):
@@ -361,8 +364,7 @@ class Trainer:
             with jax.named_scope("loss"):
                 loss, aux = task.loss(params, out, batch)
             with jax.named_scope("metrics"):
-                return _add_step(
-                    totals, task.step_values(loss, aux, batch))
+                return fold(totals, task.step_values(loss, aux, batch))
 
         def group_fn(params, totals, group: Batch, n_live):
             def body(i, totals):

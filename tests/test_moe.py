@@ -358,3 +358,56 @@ def test_one_compiled_program_serves_routings_of_different_n_used():
         seen.append(int(n_used))
     assert seen[0] != seen[1]
     assert step._cache_size() == 1
+
+
+@pytest.mark.parametrize("with_bias", [False, True])
+def test_sigmoid_routing_is_the_written_out_top_k_with_ties(with_bias):
+    """``route(scoring="sigmoid")`` against a top-k written out by hand:
+    each expert's score its own sigmoid, the k largest of score + bias
+    chosen one at a time, a tie going to the lower expert, the gates the
+    chosen experts' *unbiased* scores over their sum, times the scale.
+    Columns 1, 4 and 6 of the router are equal, so every token has a
+    three-way tie somewhere in its order, and the bias breaks or makes
+    others."""
+    w = _weights(3)
+    router = np.array(w["router"])
+    router[:, 4] = router[:, 6] = router[:, 1]
+    h = np.asarray(w["h"])
+    bias = np.array([0.0, 0.3, -0.2, 0.0, 0.3, 0.1, 0.0, -0.4],
+                    np.float32) if with_bias else None
+    gates, experts = moe.route(
+        jnp.asarray(h), jnp.asarray(router), 3, scoring="sigmoid",
+        bias=None if bias is None else jnp.asarray(bias), scale=2.0)
+    scores = np.asarray(jax.nn.sigmoid(jnp.asarray(h) @ jnp.asarray(router)))
+    chosen_on = scores + (0.0 if bias is None else bias)
+    for t in range(T):
+        left, picked = chosen_on[t].copy(), []
+        for _ in range(3):
+            best = int(np.flatnonzero(left == left.max())[0])  # the lowest
+            picked.append(best)
+            left[best] = -np.inf
+        assert list(np.asarray(experts[t])) == picked, t
+        want = 2.0 * scores[t, picked] / scores[t, picked].sum()
+        np.testing.assert_allclose(gates[t], want, rtol=1e-6)
+    tied = np.isin(np.asarray(experts), (1, 4, 6)).sum(axis=1)
+    assert (tied >= 2).any()  # ties were among the chosen
+    np.testing.assert_allclose(np.asarray(gates).sum(-1), 2.0, rtol=1e-6)
+    # the load over ALL the experts, held or not
+    load = moe.router_load(experts, E)
+    assert load.shape == (E,) and int(load.sum()) == T * 3
+    assert int(load[2]) == int((np.asarray(experts) == 2).sum())
+
+
+def test_the_selection_bias_gets_no_gradient_and_the_router_does():
+    w = _weights(1)
+    bias = jnp.linspace(-0.1, 0.1, E)
+
+    def total(router, bias):
+        gates, _ = moe.route(w["h"], router, K, scoring="sigmoid", bias=bias,
+                             scale=2.0)
+        return jnp.sum(gates * jnp.arange(1.0, K + 1))
+
+    g_router, g_bias = jax.grad(total, (0, 1))(w["router"], bias)
+    assert not np.asarray(g_bias).any()
+    assert np.abs(np.asarray(g_router)).max() > 0
+

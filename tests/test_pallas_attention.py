@@ -362,3 +362,69 @@ def test_mha_fallback_is_blockwise_and_matches_with_mask_window_and_gqa(
                       / np.sqrt(8), -jnp.inf)
         b = jnp.einsum("bnqk,bnkd->bnqd", jax.nn.softmax(s, -1), vv)
     np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "kernel"])
+@pytest.mark.parametrize("seq", [384, 1024])
+def test_the_latent_core_is_plain_attention_on_the_joined_queries_and_keys(
+        seq, kernel):
+    """Latent attention's core: a score is a 16-wide product per head
+    plus an 8-wide product against ONE rotary key shared by all the
+    heads, values are 12 wide.  Through ``mha``'s jnp path and through
+    the kernels (interpreter), with their value width of its own, it is
+    plain causal attention written out on the joined 24-wide queries
+    and keys, forward and every gradient, the shared key's summed over
+    the heads."""
+    heads, dn, dr, dv, scale = 4, 16, 8, 12, 0.27
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    qn = jax.random.normal(ks[0], (1, heads, seq, dn))
+    qr = jax.random.normal(ks[1], (1, heads, seq, dr))
+    kn = jax.random.normal(ks[2], (1, heads, seq, dn))
+    kr = jax.random.normal(ks[3], (1, 1, seq, dr))  # one head
+    v = jax.random.normal(ks[4], (1, heads, seq, dv))
+
+    def joined(qn, qr, kn, kr):
+        return (jnp.concatenate([qn, qr], -1), jnp.concatenate(
+            [kn, jnp.broadcast_to(kr, (1, heads, seq, dr))], -1))
+
+    def core(qn, qr, kn, kr, v):
+        q, k = joined(qn, qr, kn, kr)
+        if kernel:
+            return flash_attention(q, k, v, causal=True, scale=scale,
+                                   interpret=True)
+        return mha(q, k, v, causal=True, scale=scale)
+
+    def written_out(qn, qr, kn, kr, v):
+        s = (jnp.einsum("bnqd,bnkd->bnqk", qn, kn)
+             + jnp.einsum("bnqd,bkd->bnqk", qr, kr[:, 0])) * scale
+        keep = jnp.arange(seq)[:, None] >= jnp.arange(seq)[None, :]
+        p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bnqk,bnkd->bnqd", p, v)
+
+    args = (qn, qr, kn, kr, v)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(
+            lambda *a: jnp.sum(core(*a) ** 2), (0, 1, 2, 3, 4))(*args)
+        want = jax.value_and_grad(
+            lambda *a: jnp.sum(written_out(*a) ** 2), (0, 1, 2, 3, 4))(*args)
+        assert core(*args).shape == (1, heads, seq, dv)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4)
+    assert got[1][3].shape == (1, 1, seq, dr)
+
+
+def test_grouped_heads_take_a_value_width_of_their_own():
+    """N query heads on G key-value heads with values wider than the
+    scores' width: the kernels against the jnp path, both gradients."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (2, 4, 256, 16))
+    k = jax.random.normal(ks[1], (2, 2, 256, 16))
+    v = jax.random.normal(ks[2], (2, 2, 256, 40))
+    run = lambda fn: jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a) ** 2), (0, 1, 2))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        got = run(lambda *a: flash_attention(*a, causal=True, window=100,
+                                             interpret=True))
+        want = run(lambda *a: mha(*a, causal=True, window=100))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)

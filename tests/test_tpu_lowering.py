@@ -397,3 +397,82 @@ def test_a_hybrid_decoder_step_keeps_one_float32_array_a_convolution(
         r"\(?f32\[1,(20(48|51),768|768,20(48|51))\]", a)]
     assert len(wide) == len(layout), len(wide)
     assert all("transpose(jvp" in a for a in wide)
+
+
+def test_flash_kernels_compile_at_32_heads_of_192_on_values_of_128_by_4096(
+        one_chip):
+    """Latent attention's core at the published widths: scores over 192
+    (128 + the 64 rotary dims), values 128 wide, a stated scale.  Nothing
+    is padded: the kernels' operands in the compiled text are 192 and
+    128 wide."""
+    from fmda_tpu.ops.pallas_attention import flash_attention
+
+    def step(q, k, v):
+        return jax.value_and_grad(lambda *a: flash_attention(
+            *a, causal=True, scale=0.1447).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(step).lower(
+        _shape(one_chip, (1, 32, 4096, 192), BF16),
+        _shape(one_chip, (1, 32, 4096, 192), BF16),
+        _shape(one_chip, (1, 32, 4096, 128), BF16)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert name in text
+    assert "bf16[32,4096,256]" not in text and "bf16[1,32,4096,256]" not in text
+
+
+@pytest.mark.parametrize("keeps", ["names", "nothing"])
+def test_a_recomputed_latent_decoder_step_runs_the_core_once_a_layer(
+        one_chip, monkeypatch, keeps):
+    """The latent-attention decoder's loss and gradient through three
+    recomputed blocks (a dense one, two with experts and a shared
+    expert), four lanes, compiled for the chip on the kernel path: the
+    replay keeps the core's output and row statistics under the names
+    every layout uses, so the program holds one ``flash_fwd`` a layer."""
+    import re
+
+    from fmda_tpu.config import ModelConfig, TrainConfig
+    from fmda_tpu.data.pipeline import Batch
+    from fmda_tpu.models import build_model, decoder, latent_block
+    from fmda_tpu.ops import attention
+    from fmda_tpu.train.tasks import NextToken
+
+    monkeypatch.setattr(attention, "flash_available", lambda: True)
+    monkeypatch.setattr(latent_block, "kernel_impl", lambda use: "pallas")
+    if keeps == "nothing":
+        monkeypatch.setattr(decoder, "REPLAY_KEEPS", ())
+    t = 1024
+    cfg = ModelConfig(
+        cell="decoder", hidden_size=256, n_heads=4, vocab_size=512,
+        layer_layout=(4, 4, 4), q_lora_rank=128, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_factor=64.0, rope_original_max=4096, moe_experts=8,
+        moe_top_k=2, moe_ffn_size=128, experts_held=(0, 4),
+        hidden_act="silu", ffn_size=512, first_dense_layers=1,
+        moe_shared_experts=1, moe_scoring="sigmoid", moe_routed_scaling=2.0,
+        moe_bias_rate=1e-3, hc_streams=4, loss_chunk=256, dtype="bfloat16",
+        use_pallas=True, remat=True)
+    model = build_model(cfg)
+    task = NextToken(cfg, TrainConfig(batch_size=1, window=t))
+    params = jax.eval_shape(
+        lambda key: model.init({"params": key},
+                               jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+
+    def step(p, x, y, mask):
+        batch = Batch(x, y, mask)
+        return jax.value_and_grad(lambda p: task.loss(
+            p, task.forward(model, p, batch, None), batch)[0])(p)
+
+    text = jax.jit(step).lower(
+        jax.tree.map(lambda l: _shape(one_chip, l.shape, l.dtype), params),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.float32)).compile().as_text()
+    runs = 1 if keeps == "names" else 2
+    calls = re.findall(
+        r"(?m)^\s*%flash_fwd(?:\.\d+)? = .*custom-call\(.*$", text)
+    assert len(calls) == runs * 3, len(calls)
+    assert len(re.findall(
+        r"(?m)^\s*%flash_bwd_dq(?:\.\d+)? = .*custom-call\(", text)) == 3
