@@ -139,14 +139,17 @@ class LatentBlock(nn.Module):
     cfg: ModelConfig
     dense: bool = False
 
-    def _mixing(self, name: str, x: jax.Array) -> hc.Coefficients:
+    def _mixed(self, name: str, x: jax.Array, fn, impl: str):
+        """``hc.around`` with this sublayer's mixing parameters: the
+        stream after ``fn`` inside its mixing, what else ``fn``
+        returns, and ``Hres``."""
         cfg = self.cfg
         n, d = x.shape[2], x.shape[3]
         const = nn.initializers.constant
         gain = const(HC_GAIN_INIT)
         b_pre, b_post, b_res = _lane_offsets(HC_OFFSET_INIT, n)
-        return hc.coefficients(
-            x,
+        return hc.around(
+            fn, x,
             _weight(self, f"hc_{name}_p_pre", (n * d, n)),
             _weight(self, f"hc_{name}_p_post", (n * d, n)),
             _weight(self, f"hc_{name}_p_res", (n * d, n * n)),
@@ -156,7 +159,7 @@ class LatentBlock(nn.Module):
              self.param(f"hc_{name}_b_post", const(b_post), (n,)),
              self.param(f"hc_{name}_b_res", const(b_res), (n, n))),
             norm_eps=cfg.rms_norm_eps, iters=cfg.hc_sinkhorn_iters,
-            eps=cfg.hc_eps, clamp=cfg.hc_res_clamp)
+            eps=cfg.hc_eps, clamp=cfg.hc_res_clamp, impl=impl)
 
     def _attention(self, h: jax.Array) -> jax.Array:
         cfg = self.cfg
@@ -244,21 +247,25 @@ class LatentBlock(nn.Module):
         lanes = cfg.hc_streams > 1
         d, ones = x.shape[-1], nn.initializers.ones
         sum_errors = []
+        if lanes:  # what differentiates the mixing
+            hc_impl = hc.backward_impl(
+                kernel_impl(cfg.use_pallas), d, x.shape[1])
 
         def sublayer(x, name, ln, fn):
             """``x`` after the sublayer ``fn`` (normalised stream ->
             output, anything else it returns), and that else."""
             scale = self.param(ln, ones, (d,))
+
+            def normed(u):
+                return fn(rms_norm(u, scale, cfg.rms_norm_eps))
+
             if not lanes:
-                y, out = fn(rms_norm(x, scale, cfg.rms_norm_eps))
+                y, out = normed(x)
                 return x + y, out
+            x, out, res = self._mixed(name, x, normed, hc_impl)
             with jax.named_scope("hyper_conn"):
-                mix = self._mixing(name, x)
-                u = hc.read(x, mix.pre)
-            y, out = fn(rms_norm(u, scale, cfg.rms_norm_eps))
-            with jax.named_scope("hyper_conn"):
-                sum_errors.append(hc.sum_error(mix.res))
-                return hc.write(x, y, mix.post, mix.res), out
+                sum_errors.append(hc.sum_error(res))
+            return x, out
 
         b, t = x.shape[:2]
         x, _ = sublayer(x, "attn", "ln_attn",

@@ -476,3 +476,50 @@ def test_a_recomputed_latent_decoder_step_runs_the_core_once_a_layer(
     assert len(calls) == runs * 3, len(calls)
     assert len(re.findall(
         r"(?m)^\s*%flash_bwd_dq(?:\.\d+)? = .*custom-call\(", text)) == 3
+
+
+def test_the_hyper_connections_backward_compiles_at_4_lanes_of_3584_by_4096(
+        one_chip):
+    """Two sublayers' mixing around a stand-in sublayer, value and
+    gradient, compiled for the chip at the latent cell's widths: the
+    three backward kernels once a sublayer, the stream handed to them
+    as it lies (tokens last: the only copies of the stream's size are
+    the program's own argument and result, which arrive and leave in
+    the default layout), and no float32 array of the stream's size
+    anywhere in the backward."""
+    import re
+
+    from fmda_tpu.ops import hyper_connection as hc
+
+    t, n, d = 4096, 4, 3584
+    kw = dict(norm_eps=1e-6, iters=20, eps=1e-6, clamp=30.0, impl="pallas")
+
+    def loss(x, mixing, ws):
+        for (p_pre, p_post, p_res, a, b), w in zip(mixing, ws):
+            x, _, _ = hc.around(
+                lambda u, w=w: (
+                    jnp.dot(jax.nn.silu(u), w.astype(u.dtype)), None),
+                x, p_pre, p_post, p_res, a, b, **kw)
+        return jnp.sum(jnp.sum(x.astype(jnp.float32), axis=2) ** 2)
+
+    f32 = jnp.float32
+    scalar = _shape(one_chip, (), f32)
+    one = (_shape(one_chip, (n * d, n), f32), _shape(one_chip, (n * d, n), f32),
+           _shape(one_chip, (n * d, n * n), f32), (scalar,) * 3,
+           (_shape(one_chip, (n,), f32), _shape(one_chip, (n,), f32),
+            _shape(one_chip, (n, n), f32)))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        _shape(one_chip, (1, t, n, d), BF16), [one, one],
+        [_shape(one_chip, (d, d), f32)] * 2).compile().as_text()
+    for name in ("hc_bwd_leave", "hc_bwd_pre", "hc_bwd_enter"):
+        assert len(re.findall(
+            rf"(?m)^\s*%{name}(?:\.\d+)? = .*custom-call\(", text)) == 2, name
+    wide = re.compile(r"^\(?f32\[(1,4096,4,3584|1,4,3584,4096|1,4096,14336"
+                      r"|4096,14336)\]")
+    moved = re.compile(r"^bf16\[(1,4096,4,3584|1,4,3584,4096)\]\S* "
+                       r"(copy|transpose)\(")
+    arrays = _hbm_instructions(text)
+    backward = [i for i in arrays if "transpose(jvp" in i]
+    assert backward
+    assert not [i[:120] for i in backward if wide.match(i)]
+    assert len([i for i in arrays if moved.match(i)]) == 2
