@@ -1,62 +1,112 @@
-"""Fused Pallas TPU flash-attention kernel for the attn model family.
+"""Fused Pallas TPU flash-attention kernels: every attention core of the
+decoder and attn families but the learned-sparse one.
 
 The pure-jnp path (:func:`fmda_tpu.ops.attention.mha`) materialises the
-(B, N, T, T) score matrix in HBM — at the long-context shape (B=16, N=4,
-T=1024) that is ~256 MB of f32 traffic per layer per direction, and HBM
-bandwidth, not the MXU, bounds the step.  This kernel is the standard
-flash-attention restructuring of the SAME online-softmax recurrence the
-module documents (ops/attention.py docstring; the ring path folds K/V
-blocks with identical math, parallel/ring_attention.py:45-82): scores
-only ever exist as a (128, 128) block in VMEM.
+(B, N, T, T) score matrix in HBM, and HBM bandwidth, not the MXU, then
+bounds the step.  These kernels are the standard flash-attention
+restructuring of the SAME online-softmax recurrence the module documents
+(ops/attention.py docstring; the ring path folds K/V blocks with
+identical math, parallel/ring_attention.py): scores only ever exist as
+one block in VMEM.  Three kernels: ``flash_fwd``, ``flash_bwd_dkv`` and
+``flash_bwd_dq``.
 
-Forward — grid ``(B*N, T/128, T/128)`` (``dimension_semantics``
-arbitrary: steps run sequentially, so VMEM scratch legitimately carries
-the online state across the K axis)::
+Forward — grid ``(key-value heads x steps a group, T / bq, T / bk)``,
+``dimension_semantics`` arbitrary: steps run in order, so VMEM scratch
+carries a query block's row state across its key blocks.  One grid step
+takes the query heads that share a key-value head together (q and o
+blocks ``(1, heads, bq, D)``), so a key and value block is fetched once
+a group and not once a head; a group of one is the same kernel.  Per
+head ``h`` and block, the row state a head each in three scratches::
 
-    s    = (q_blk @ k_blk^T) * scale           # MXU, f32 accumulate
-    m'   = max(m, rowmax(s))
-    corr = exp(m - m')
-    p    = exp(s - m')                          # VPU, f32
-    l    = l * corr + rowsum(p)
-    acc  = acc * corr + p @ v_blk               # MXU
-    at last K block:  o = acc / l,  L = m + log l
+    s      = (q[h] @ k^T) * scale, -inf where masked   # MXU, f32
+    m'     = max(m[h], rowmax(s))                      # the one XLU reduction
+    p      = exp(s - m');  corr = exp(m[h] - m')       # f32
+    l[h]   = l[h] * corr + fold_lanes(p)               # 128 partial sums a row
+    acc[h] = acc[h] * corr + p @ v                     # MXU
+    at the last key block:  o = acc / sum(l),  L = m + log sum(l)
 
-``L`` (the per-row logsumexp) is the only residual beyond the inputs and
-``o`` — the backward recomputes ``p = exp(s - L)`` blockwise instead of
-storing probabilities (the same fused-remat trade as the GRU/LSTM kernel
-pairs, ops/pallas_gru.py).  Backward runs as two kernels over the same
-block structure, the textbook split:
+What a block and head pay *per row, not per element* is what kept the
+forward at a quarter of the MXU's rate while the backward kernels, which
+get ``L`` finished, ran at twice that a product: the lane reductions
+through the XLU (nothing of a head's chain can start until its maximum
+is known), the ``corr`` exponential, the rescale of ``acc``.  Four
+things keep that off the MXU's path, each exact (PERF.md section 6,
+PR 44, has the sweep at the three decoder cells' shapes; PR 35 made the
+same moves in ``sparse_fwd``, whose ``_fold_lanes`` and
+``_one_head_behind`` are used here):
 
-- **dK/dV sweep** — grid ``(B*N, T/128 [k], T/128 [q])``: for a fixed
+- **the row sum stays a lane tile wide**: ``l`` holds 128 partial sums
+  a row, a block folds its lane tiles into them on the VPU, and the one
+  cross-lane sum happens in ``_finalize``;
+- **masked scores are ``-inf`` under a finite running maximum** (``m``
+  starts at ``_NEG``), so their ``exp`` is exactly 0 with no second
+  select; a row whose block holds no visible key (a window's low edge)
+  leaves ``m``, ``l`` and ``acc`` as they were, and a row that never
+  sees a key reports ``lse = _NEG``, ``o = 0``;
+- **a key block of 1,024** where ``T % 1024 == 0``
+  (:func:`fwd_blocks_for`; the query block stays :func:`block_for`'s):
+  half the reductions a score.  The band is then walked in ``(bq, bk)``
+  blocks (:func:`_fwd_visible`, :func:`_fwd_keep`,
+  :func:`_fwd_key_block`), every in-band block under one mask that all
+  the step's heads share;
+- **a head's ``p v`` is issued behind the next head's scores and
+  softmax**, so one head's reduction sits under another's products.
+
+What a shape does not get of this is counted at trace time
+(``ops.dispatch.kernel_fallbacks()``): ``attention:narrow_key_block``
+where 1,024 does not divide the length and the key block falls back to
+the square one, ``attention:group_in_parts`` where a grid step does not
+hold the group (:func:`heads_a_step`: half of the stated VMEM limit, at
+most eight heads) and the group is walked in parts, its keys fetched
+once a part.
+
+Tried and dropped, with the chip's numbers in PERF.md section 6 (PR 43's
+sweep, PR 44's for the head loop): a second, unmasked body for interior
+blocks (2 % of the kernel for twice the compile and the program text);
+a 256-row query block; a 512-key block with grouped heads; and every
+way of unrolling fewer heads, which would cut the kernel's program text
+(1.38 MB at a group of 7, all of a step's heads being straight-line
+code) — a ``fori_loop`` over the heads through a VMEM scratch, the
+heads rolled two or three a turn with the overlap kept inside a turn
+(+9 to +14 % of the kernel: nothing overlaps across a loop's turns), at
+most four heads a step with the group walked in two steps (+20 %).  The
+text costs a warm set-up nothing that its faster first epoch does not
+give back.
+
+``L`` (the per-row logsumexp) leaves the kernel as a ``(rows, 128)``
+tile of equal lanes; its column is the only residual beyond the inputs
+and ``o`` — the backward recomputes ``p = exp(s - L)`` blockwise instead
+of storing probabilities.  Backward runs as two kernels over square
+blocks of :func:`block_for` ``(T)`` (512 where T allows, else 256 or
+128: a grid step costs ~0.35 us whatever it computes), one query head a
+grid step, the textbook split:
+
+- **dK/dV sweep** — grid ``(B*N, T/blk [k], T/blk [q])``: for a fixed
   K/V block, walk the query blocks; ``dv += p^T @ do``,
   ``ds = p * (do @ v^T - delta) * scale``, ``dk += ds^T @ q``.
-- **dQ sweep** — grid ``(B*N, T/128 [q], T/128 [k])``: for a fixed Q
+- **dQ sweep** — grid ``(B*N, T/blk [q], T/blk [k])``: for a fixed Q
   block, walk the key blocks; ``dq += ds @ k``.
 
 ``delta = rowsum(do * o)`` is cheap elementwise work computed outside in
-plain XLA.  Masking uses a large-negative finite constant (not -inf) so
-fully-masked causal blocks stay NaN-free; masked probabilities are
-forced to exactly zero.  m/l/L/delta ride as 128-lane-replicated
-``(rows, 128)`` tiles — Mosaic's tiling wants the last dim to be 128 or
-the full array dim, and a (1, block) slab whose sublane dim is neither
-8-divisible nor full does not lower (same constraint that forced the GRU
-kernel time-major, ops/pallas_gru.py).
+plain XLA.  The backward masks with the large-negative finite ``_NEG``
+and forces masked probabilities to exactly zero.  m/L/delta ride as
+128-lane-replicated ``(rows, 128)`` tiles — Mosaic's tiling wants the
+last dim to be 128 or the full array dim, and a (1, block) slab whose
+sublane dim is neither 8-divisible nor full does not lower.
 
-Blocks are square, edge :func:`block_for` ``(T)``: 512 where T allows,
-else 256 or 128 — a grid step costs ~0.35 us whatever it computes, and a
-128 x 128 x D block is ~0.04 us of MXU work, so at T = 8192 the 128-wide
-grid is all overhead.  Two things ride inside the same kernels:
+Two things ride inside all three kernels:
 
 - **grouped-query heads**: K/V may carry fewer heads than Q (``N`` query
   heads on ``G`` key-value heads, ``N % G == 0``).  Nothing is repeated
-  in HBM: the K/V block index is the query head's index ``// (N / G)``;
-  the dK/dV sweep writes one float32 partial per query head and the
+  in HBM: the K/V block index follows from the query heads' index; the
+  dK/dV sweep writes one float32 partial per query head and the
   ``N / G`` partials of a group are summed outside.
 - **a causal window**: key ``j`` is visible to query ``i`` iff
   ``0 <= i - j < window``.  Blocks wholly outside the band are skipped
   (no MXU/VPU work) and their block index is clamped into the band, so
   the pipeline re-references the block it already holds and fetches
-  nothing; blocks wholly inside the band skip the mask arithmetic.
+  nothing; in the backward, blocks wholly inside the band skip the mask
+  arithmetic.
 
 Support envelope (:func:`flash_supported`): self-attention with
 ``Tq == Tk``, ``T % 128 == 0``, no arbitrary mask (causal and the causal
@@ -64,8 +114,8 @@ window are in-kernel; a window implies causal), and D small enough that
 the per-block working set fits VMEM — in practice D <= 512.  Values
 may have a width of their own (``Dv``, latent attention's 128 beside
 scores over 192): ``p @ v``, ``o``, ``do`` and ``dv`` are then ``Dv``
-wide and nothing is padded.  Everything else takes the jnp path via :func:`fmda_tpu.ops.attention.mha`'s
-dispatch.
+wide and nothing is padded.  Everything else takes the jnp path via
+:func:`fmda_tpu.ops.attention.mha`'s dispatch.
 """
 
 from __future__ import annotations
@@ -81,6 +131,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fmda_tpu.compat import CompilerParams
 from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT
+from fmda_tpu.ops.dispatch import count_kernel_fallback
+from fmda_tpu.ops.pallas_sparse_attention import (
+    _fold_lanes, _one_head_behind)
 
 #: Smallest Q/K block edge.  128 = MXU tile edge = Mosaic lane count; T
 #: must be a multiple (flash_supported gates on it).
@@ -95,10 +148,11 @@ def block_for(seq_len: int) -> int:
             return blk
     return _BLOCK
 
-#: Finite stand-in for -inf in masked score slots: far below any real
-#: logit, but exp(finite - finite) stays a number (exp of ~-1e30 is 0.0
-#: in f32 anyway); masked probabilities are additionally forced to 0 so
-#: a fully-masked row cannot poison the state with exp(0)=1.
+#: Finite stand-in for -inf: the forward's running maximum starts here
+#: (its masked scores are a true -inf beneath it) and a row that sees no
+#: key reports it as ``lse``; the backward's masked score slots hold it
+#: (exp(finite - finite) stays a number) and their probabilities are
+#: forced to 0, so a fully-masked row cannot give exp(0) = 1.
 _NEG = -1e30
 
 
@@ -167,67 +221,217 @@ def _scores(q, k, qi, ki, *, blk, window, masked, scale=None):
     return s, scale
 
 
+# ---------------------------------------------------------------------------
+# forward: (bq, bk) blocks, a key-value head's query heads a grid step
+# ---------------------------------------------------------------------------
+
+
+def fwd_blocks_for(seq_len: int) -> Tuple[int, int]:
+    """``(query rows, keys)`` of a block of the forward kernel: the key
+    block 1,024 wide where the length allows it (module docstring), else
+    the square :func:`block_for` block."""
+    blk = block_for(seq_len)
+    return blk, (1024 if seq_len % 1024 == 0 else blk)
+
+
+#: What ``flash_fwd`` may hold in VMEM (a v5e has 128 MiB; the default
+#: scoped limit, 16 MiB, is under the seven heads of a (512, 1024) step).
+_FWD_VMEM_LIMIT = 64 * 1024 * 1024
+#: Query heads of one grid step at most: each is unrolled in the kernel.
+_MAX_HEADS_A_STEP = 8
+
+
+def heads_a_step(group: int, bq: int, d: int, dv: int, itemsize: int) -> int:
+    """How many of a key-value head's ``group`` query heads one forward
+    grid step takes: all of them where that is at most
+    ``_MAX_HEADS_A_STEP`` and their blocks (q and o in flight twice, the
+    lse tile likewise, the three scratches) fit half of
+    ``_FWD_VMEM_LIMIT`` beside the key, value and score tiles; else the
+    largest divisor of the group that does."""
+    a_head = bq * (2 * (d + dv) * itemsize + 2 * 128 * 4
+                   + (2 * 128 + dv) * 4)
+    most = max(1, min(_MAX_HEADS_A_STEP, _FWD_VMEM_LIMIT // 2 // a_head))
+    return max(h for h in range(1, group + 1)
+               if group % h == 0 and h <= most)
+
+
+def _fwd_visible(qi, ki, bq: int, bk: int, window: Optional[int]):
+    """:func:`_in_band` for ``(bq, bk)`` blocks: block (qi, ki) holds at
+    least one visible (query, key) pair, i.e. its greatest ``query - key``
+    is causal and its least is inside the window."""
+    ok = (qi + 1) * bq - 1 - ki * bk >= 0
+    if window is not None:
+        ok = ok & (qi * bq - (ki + 1) * bk + 1 < window)
+    return ok
+
+
+def _fwd_keep(qi, ki, bq: int, bk: int, window: Optional[int]):
+    """:func:`_causal_mask_block` for ``(bq, bk)`` blocks: the bool
+    keep-mask of block (qi, ki), causal, and inside the window where
+    there is one."""
+    rel = (qi * bq - ki * bk
+           + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+           - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    keep = rel >= 0
+    if window is not None:
+        keep = keep & (rel < window)
+    return keep
+
+
+def _fwd_key_block(qi, ki, bq: int, bk: int, window: Optional[int]):
+    """:func:`_clamp_key_block` for ``(bq, bk)`` blocks: ``ki`` inside
+    the band, the band's nearest block outside it."""
+    lo = 0 if window is None else jnp.maximum(
+        qi * bq - window + 1, 0) // bk
+    return jnp.clip(ki, lo, ((qi + 1) * bq - 1) // bk)
+
+
 def _fwd_kernel(
-    q_ref,  # (1, blk, D)
-    k_ref,  # (1, blk, D)
-    v_ref,  # (1, blk, Dv)
-    o_ref,  # out (1, blk, Dv)
-    lse_ref,  # out (1, blk, 128) lane-replicated logsumexp
-    m_scr,  # VMEM (blk, 128) f32
-    l_scr,  # VMEM (blk, 128) f32
-    acc_scr,  # VMEM (blk, Dv) f32
+    q_ref,  # (1, heads, bq, D): the query heads of this grid step
+    k_ref,  # (1, bk, D): their one key-value head
+    v_ref,  # (1, bk, Dv)
+    o_ref,  # out (1, heads, bq, Dv)
+    lse_ref,  # out (1, heads, bq, 128) lane-replicated logsumexp
+    m_scr,  # VMEM (heads, bq, 128) f32: a row's running maximum, every lane
+    l_scr,  # VMEM (heads, bq, 128) f32: 128 partial sums a row
+    acc_scr,  # VMEM (heads, bq, Dv) f32: the unnormalised output
     *,
     causal: bool,
     window: Optional[int],
-    blk: int,
+    bq: int,
+    bk: int,
     n_k: int,
     scale: Optional[float] = None,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    heads = q_ref.shape[1]
+    if scale is None:
+        scale = 1.0 / (q_ref.shape[-1] ** 0.5)
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr[:], _NEG)
-        l_scr[:] = jnp.zeros_like(l_scr[:])
-        acc_scr[:] = jnp.zeros_like(acc_scr[:])
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _compute(masked: bool):
-        f32 = jnp.float32
-        s, _ = _scores(q_ref[0], k_ref[0], qi, ki, blk=blk, window=window,
-                       masked=masked, scale=scale)
-        m_prev = m_scr[:, :1]  # (blk, 1); lanes are replicated
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if masked:
-            # exactly zero where masked (s==_NEG - m_new underflows to 0
-            # anyway unless the whole row is masked and m_new==_NEG; this
-            # kills that)
-            p = jnp.where(s <= _NEG * 0.5, 0.0, p)
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=f32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    def _compute():
+        k, v = k_ref[0], v_ref[0]
+        # one mask a grid step, for every head of it
+        keep = _fwd_keep(qi, ki, bq, bk, window) if causal else None
+
+        def softmax(h):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if causal:
+                # -inf where masked: under the finite m_new (_NEG where a
+                # row has seen no key yet) exp gives exactly zero, and
+                # such a row's m, l and acc stay as they were
+                s = jnp.where(keep, s, -jnp.inf)
+            m_prev = m_scr[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + _fold_lanes(p)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            return h, p.astype(v.dtype), corr
+
+        def accumulate(h, p, corr):
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        # a head's p v is issued behind the next head's scores and
+        # softmax: the one head's reductions then sit under the other's
+        # products
+        _one_head_behind(heads, softmax, accumulate)
 
     # blocks outside the band are fully masked: skip their MXU/VPU work
-    # entirely (round-4 advice: causal paid ~2x), the state update is a
-    # no-op there by construction
-    _banded(causal, qi, ki, blk, window, _compute)
+    # entirely, the state update is a no-op there by construction
+    if causal:
+        pl.when(_fwd_visible(qi, ki, bq, bk, window))(_compute)
+    else:
+        _compute()
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # logsumexp residual; fully-masked rows keep _NEG (p recomputes
-        # to 0 in backward)
-        lse = jnp.where(l == 0.0, _NEG, m_scr[:, :1] + jnp.log(l_safe))
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        for h in range(heads):
+            # the one cross-lane sum of a row; a row that saw no key
+            # reports o = 0, lse = _NEG (p recomputes to 0 in the backward)
+            l = jnp.sum(l_scr[h], axis=-1, keepdims=True)
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc_scr[h] / l_safe).astype(o_ref.dtype)
+            lse = jnp.where(l == 0.0, _NEG,
+                            m_scr[h][:, :1] + jnp.log(l_safe))
+            lse_ref[0, h] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "window", "interpret", "scale"))
+def _fwd_impl(
+    q: jax.Array, k: jax.Array, v: jax.Array,
+    *, causal: bool, window: Optional[int], interpret: bool,
+    scale: Optional[float] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """q (BN, T, D), k (BG, T, D), v (BG, T, Dv) -> (o (BN, T, Dv), lse
+    (BN, T, 128)).  A ``jax.jit`` of its own, so that the layers of a
+    step share one traced and lowered kernel body."""
+    bn, t, d = q.shape
+    dv = v.shape[-1]
+    group = bn // k.shape[0]
+    bq, bk = fwd_blocks_for(t)
+    heads = heads_a_step(group, bq, d, dv, q.dtype.itemsize)
+    # what a shape did not get of the mechanism, said once a trace
+    if bk == bq:
+        count_kernel_fallback("attention", "narrow_key_block")
+    if heads < group:
+        count_kernel_fallback("attention", "group_in_parts")
+    steps = bn // heads
+    kernel = functools.partial(
+        _fwd_kernel, causal=causal, window=window, bq=bq, bk=bk,
+        n_k=t // bk, **_stated(scale))
+
+    def q_rows(width):
+        return pl.BlockSpec((1, heads, bq, width),
+                            lambda b, qi, ki: (b, 0, qi, 0))
+
+    def kv_index(b, qi, ki):
+        if causal:
+            ki = _fwd_key_block(qi, ki, bq, bk, window)
+        return ((b * heads) // group, ki, 0)
+
+    o, lse = pl.pallas_call(
+        kernel,
+        name="flash_fwd",
+        grid=(steps, t // bq, t // bk),
+        in_specs=[
+            q_rows(d),
+            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((1, bk, dv), kv_index),
+        ],
+        out_specs=[q_rows(dv), q_rows(128)],
+        out_shape=[
+            jax.ShapeDtypeStruct((steps, heads, t, dv), q.dtype),
+            jax.ShapeDtypeStruct((steps, heads, t, 128), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((heads, bq, 128), jnp.float32),
+            pltpu.VMEM((heads, bq, 128), jnp.float32),
+            pltpu.VMEM((heads, bq, dv), jnp.float32),
+        ],
+        compiler_params=CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_FWD_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+    )(q.reshape(steps, heads, t, d), k, v)
+    return o.reshape(bn, t, dv), lse.reshape(bn, t, 128)
+
+
+# ---------------------------------------------------------------------------
+# backward: square blocks, one query head a grid step
+# ---------------------------------------------------------------------------
 
 
 def _clamp_key_block(qi, ki, *, causal, blk, window):
@@ -249,57 +453,6 @@ def _clamp_query_block(ki, qi, *, causal, blk, window, n_q):
     span = _band_span(blk, window)
     hi = n_q - 1 if span is None else jnp.minimum(ki + span, n_q - 1)
     return jnp.clip(qi, ki, hi)
-
-
-def _fwd_impl(
-    q: jax.Array, k: jax.Array, v: jax.Array,
-    *, causal: bool, window: Optional[int], interpret: bool,
-    scale: Optional[float] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """q (BN, T, D), k (BG, T, D), v (BG, T, Dv) -> (o (BN, T, Dv), lse
-    (BN, T, 128))."""
-    bn, t, d = q.shape
-    dv = v.shape[-1]
-    group = bn // k.shape[0]
-    blk = block_for(t)
-    n_blk = t // blk
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, window=window, blk=blk, n_k=n_blk,
-        **_stated(scale))
-
-    def kv_index(b, qi, ki):
-        return (b // group,
-                _clamp_key_block(qi, ki, causal=causal, blk=blk,
-                                 window=window), 0)
-
-    o, lse = pl.pallas_call(
-        kernel,
-        name="flash_fwd",
-        grid=(bn, n_blk, n_blk),
-        in_specs=[
-            pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, blk, d), kv_index),
-            pl.BlockSpec((1, blk, dv), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk, dv), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, blk, 128), lambda b, qi, ki: (b, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bn, t, dv), q.dtype),
-            jax.ShapeDtypeStruct((bn, t, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk, 128), jnp.float32),
-            pltpu.VMEM((blk, 128), jnp.float32),
-            pltpu.VMEM((blk, dv), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q, k, v)
-    return o, lse
 
 
 def _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,

@@ -6,6 +6,8 @@ f32 and bf16), Mosaic TPU lowering via jax.export without hardware, and
 an on-device parity test gated on a reachable TPU.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -159,10 +161,18 @@ def test_mha_dispatch_stays_on_jnp_path_off_tpu():
     assert out.shape == q.shape
 
 
-def test_mosaic_lowering_via_export():
+def _grouped(q, k, v, group):
+    """The same queries on ``heads / group`` key-value heads."""
+    return q, k[:, ::group], v[:, ::group]
+
+
+@pytest.mark.parametrize("group", [1, 2])
+def test_mosaic_lowering_via_export(group):
     """The kernel lowers through the real Mosaic TPU pass (no hardware
-    needed): value + grad, both causal settings, both dtypes."""
-    q, k, v = _qkv(batch=1, heads=2, seq=2 * _BLOCK, d_head=8)
+    needed): value + grad, both causal settings, both dtypes, one query
+    head and two a forward grid step."""
+    q, k, v = _grouped(
+        *_qkv(batch=1, heads=2, seq=2 * _BLOCK, d_head=8), group)
 
     for causal in (False, True):
         for dtype in (jnp.float32, jnp.bfloat16):
@@ -180,13 +190,15 @@ def test_mosaic_lowering_via_export():
             assert "tpu" in exported.platforms
 
 
-def test_mosaic_lowering_with_lse_via_export():
+@pytest.mark.parametrize("group", [1, 2])
+def test_mosaic_lowering_with_lse_via_export(group):
     """The ring fold's kernel program — (o, lse) outputs with gradients
     through BOTH (the dlse-folded backward) — lowers through the real
     Mosaic TPU pass."""
     from fmda_tpu.ops.pallas_attention import flash_attention_with_lse
 
-    q, k, v = _qkv(batch=1, heads=2, seq=2 * _BLOCK, d_head=8)
+    q, k, v = _grouped(
+        *_qkv(batch=1, heads=2, seq=2 * _BLOCK, d_head=8), group)
 
     for causal in (False, True):
         def train_like(q_, k_, v_, _c=causal):
@@ -428,3 +440,182 @@ def test_grouped_heads_take_a_value_width_of_their_own():
         want = run(lambda *a: mha(*a, causal=True, window=100))
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+# -- the forward's wide key block and grouped grid step (PR 44) ----------------
+
+
+def _reference_with_lse(q, k, v, *, causal, window, scale):
+    """Explicit-mask softmax attention and its row logsumexp, K/V heads
+    repeated; a row that sees no key gives o = 0 and lse = -1e30."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    t = q.shape[-2]
+    s = jnp.einsum("bnqd,bnkd->bnqk", q, k) * (
+        scale if scale is not None else 1.0 / np.sqrt(q.shape[-1]))
+    if causal or window is not None:
+        rel = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+        keep = rel >= 0
+        if window is not None:
+            keep = keep & (rel < window)
+        s = jnp.where(keep, s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(jnp.isfinite(m), jnp.exp(s - jnp.where(
+        jnp.isfinite(m), m, 0.0)), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bnqk,bnkd->bnqd", p / jnp.where(l == 0, 1.0, l), v)
+    lse = jnp.where(l == 0, -1e30, jnp.where(jnp.isfinite(m), m, 0.0)
+                    + jnp.log(jnp.where(l == 0, 1.0, l)))[..., 0]
+    return o, lse
+
+
+def _both_outputs(fn, q, k, v):
+    """Outputs and the gradients of a loss through both of them."""
+    def loss(q_, k_, v_):
+        o, lse = fn(q_, k_, v_)
+        return jnp.sum(o * jnp.cos(o)) + jnp.sum(jnp.sin(lse)), (o, lse)
+
+    with jax.default_matmul_precision("highest"):
+        (_, outs), grads = jax.value_and_grad(
+            loss, (0, 1, 2), has_aux=True)(q, k, v)
+    return outs + grads
+
+
+#: heads, kv heads, D, Dv, causal, window, scale: the three cells' head
+#: layouts at 2,048 tokens, where the forward's key block is 1,024 wide
+WIDE_BLOCK_CASES = {
+    "group7_d128_window_shorter": (7, 1, 128, 128, True, 512, None),
+    "group7_d128_window_equal": (7, 1, 128, 128, True, 1024, None),
+    "group7_d128_window_no_multiple": (7, 1, 128, 128, True, 1300, None),
+    "group4_d64_causal": (8, 2, 64, 64, True, None, None),
+    "group1_scores192_values128_scale": (2, 2, 192, 128, True, None, 0.0721),
+    "not_causal_lse_cotangent": (4, 2, 64, 64, False, None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_BLOCK_CASES))
+def test_wide_key_block_and_grouped_step_match_masked_attention(case):
+    """Forward, ``lse`` and the gradients through both outputs where the
+    forward runs ``(512, 1024)`` blocks with a key-value head's query
+    heads in one grid step (interpreter)."""
+    from fmda_tpu.ops.pallas_attention import (
+        flash_attention_with_lse, fwd_blocks_for)
+
+    heads, kv_heads, d, dv, causal, window, scale = WIDE_BLOCK_CASES[case]
+    seq = 2048
+    assert fwd_blocks_for(seq) == (512, 1024)
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (1, heads, seq, d))
+    k = jax.random.normal(ks[1], (1, kv_heads, seq, d))
+    v = jax.random.normal(ks[2], (1, kv_heads, seq, dv))
+    kw = dict(causal=causal, window=window, scale=scale)
+    got = _both_outputs(lambda *a: flash_attention_with_lse(
+        *a, interpret=True, **kw), q, k, v)
+    want = _both_outputs(
+        lambda *a: _reference_with_lse(*a, **kw), q, k, v)
+    for a, b, name in zip(got, want, ("o", "lse", "dq", "dk", "dv")):
+        np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4,
+                                   err_msg=f"{case}: {name}")
+
+
+@pytest.mark.parametrize("window", [100, 0])
+def test_rows_whose_block_holds_no_visible_key_leave_the_state_alone(
+        window):
+    """Masked scores are ``-inf`` under a finite running maximum.  At a
+    window of 100 a query block's first in-band key block (1,024 wide)
+    holds no key most of its rows can see: those rows' state must pass
+    through it untouched.  At a window of 0 no row sees any key: every
+    row reports ``lse == -1e30`` and ``o == 0``, and the gradients are
+    finite (zero)."""
+    from fmda_tpu.ops.pallas_attention import flash_attention_with_lse
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, 4, 2048, 16))
+    k = jax.random.normal(ks[1], (1, 2, 2048, 16))
+    v = jax.random.normal(ks[2], (1, 2, 2048, 16))
+    got = _both_outputs(lambda *a: flash_attention_with_lse(
+        *a, window=window, interpret=True), q, k, v)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in got)
+    if window == 0:
+        o, lse, *grads = got
+        np.testing.assert_array_equal(lse, np.full(lse.shape, -1e30, "f4"))
+        for x in (o, *grads):
+            np.testing.assert_array_equal(x, np.zeros(x.shape, "f4"))
+        return
+    want = _both_outputs(lambda *a: _reference_with_lse(
+        *a, causal=True, window=window, scale=None), q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("seq,pair", [
+    (8192, (512, 1024)), (4096, (512, 1024)), (2048, (512, 1024)),
+    (1536, (512, 512)),   # 1,024 does not divide it: the square block
+    (1024, (512, 1024)), (768, (256, 256)), (384, (128, 128)),
+])
+def test_forward_block_pair_follows_the_length(seq, pair):
+    from fmda_tpu.ops.pallas_attention import block_for, fwd_blocks_for
+
+    assert fwd_blocks_for(seq) == pair
+    assert pair[0] == block_for(seq)  # the backward's, and the query's
+
+
+def test_flash_supported_is_what_it_was():
+    """The gate knows nothing of the forward's blocks or heads a step."""
+    for t in (128, 384, 1024, 1536, 8192, 100, 8200):
+        for tk in (t, 2 * t):
+            for d in (8, 64, 192, 512, 513):
+                assert flash_supported(t, tk, d) == (
+                    t == tk and t % 128 == 0 and d <= 512), (t, tk, d)
+
+
+@pytest.mark.parametrize("group,d,dv,itemsize,heads", [
+    (7, 128, 128, 2, 7),    # smallthinker_train_8k: the whole group
+    (4, 64, 64, 2, 4),      # granite_h_train_8k
+    (1, 192, 128, 2, 1),    # xing_train_4k, the attn family, ring steps
+    (32, 128, 128, 2, 8),   # one key-value head for all: eight at a time
+    (12, 128, 128, 2, 6),   # the largest divisor under the cap
+    (8, 512, 512, 4, 4),    # wide float32 heads: what VMEM holds
+])
+def test_heads_a_forward_step(group, d, dv, itemsize, heads):
+    from fmda_tpu.ops.pallas_attention import heads_a_step
+
+    assert heads_a_step(group, 512, d, dv, itemsize) == heads
+
+
+def test_a_group_wider_than_a_step_is_walked_in_parts():
+    """Sixteen query heads on one key-value head: two grid steps of
+    eight, each on the same keys."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(ks[0], (1, 16, 256, 16))
+    k = jax.random.normal(ks[1], (1, 1, 256, 16))
+    v = jax.random.normal(ks[2], (1, 1, 256, 16))
+    with jax.default_matmul_precision("highest"):
+        got = flash_attention(q, k, v, causal=True, interpret=True)
+        want = _masked_reference(q, k, v, None)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,kv_heads,seq,counted", [
+    (7, 1, 2048, {}),                                   # group 7, wide block
+    (2, 2, 1024, {}),                                   # group 1
+    (4, 2, 384, {"attention:narrow_key_block": 1}),     # square blocks of 128
+    (16, 1, 256, {"attention:narrow_key_block": 1,
+                  "attention:group_in_parts": 1}),      # two steps of eight
+])
+def test_trace_time_counters_say_what_a_shape_did_not_get(
+        heads, kv_heads, seq, counted):
+    """``attention:narrow_key_block`` where 1,024 does not divide the
+    length, ``attention:group_in_parts`` where a grid step does not hold
+    the group: one tick a traced kernel, nothing at the cells' layouts."""
+    from fmda_tpu.ops.dispatch import kernel_fallbacks, reset_kernel_fallbacks
+    from fmda_tpu.ops.pallas_attention import _fwd_impl
+
+    reset_kernel_fallbacks()
+    shape = lambda n: jax.ShapeDtypeStruct((n, seq, 16), jnp.float32)
+    # a fresh trace: eval_shape of the undecorated function
+    jax.eval_shape(functools.partial(
+        _fwd_impl.__wrapped__, causal=True, window=None, interpret=True),
+        shape(heads), shape(kv_heads), shape(kv_heads))
+    assert kernel_fallbacks() == counted
+    reset_kernel_fallbacks()

@@ -48,6 +48,34 @@ def test_flash_kernels_compile_at_28_on_4_heads_of_128_by_8192(
         assert name in text
 
 
+@pytest.mark.parametrize("group,seq,window,counted", [
+    (2, 4096, 1024, ()),      # two heads a grid step on (512, 1024) blocks
+    (1, 4096, None, ()),      # a group of one: the same kernel, no overlap
+    (2, 1536, None, ("attention:narrow_key_block",)),  # square blocks of 512
+    (16, 2048, 512, ("attention:group_in_parts",)),    # two steps of eight
+])
+def test_the_forward_alone_compiles_by_group_and_says_what_it_did_not_get(
+        one_chip, group, seq, window, counted):
+    """``flash_fwd`` for a described v5e at a group of 2 and of 1 (the
+    three cells' groups of 7, 4 and 1 compile with their backward in the
+    tests around this one), and the two trace-time counters: a length
+    that 1,024 does not divide and a group wider than a grid step are
+    said in ``kernel_fallbacks()``, the cells' shapes say nothing."""
+    from fmda_tpu.ops.dispatch import kernel_fallbacks, reset_kernel_fallbacks
+    from fmda_tpu.ops.pallas_attention import flash_attention_with_lse
+
+    reset_kernel_fallbacks()
+    heads = 2 * group
+    compiled = jax.jit(lambda *a: flash_attention_with_lse(
+        *a, causal=True, window=window)).lower(
+        _shape(one_chip, (1, heads, seq, 128), BF16),
+        _shape(one_chip, (1, 2, seq, 128), BF16),
+        _shape(one_chip, (1, 2, seq, 128), BF16)).compile()
+    assert "flash_fwd" in compiled.as_text()
+    assert tuple(sorted(kernel_fallbacks())) == counted
+    reset_kernel_fallbacks()
+
+
 def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
         one_chip):
     """Attention over picked keys, forward and backward, at the
