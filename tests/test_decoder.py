@@ -204,3 +204,118 @@ def test_the_chunked_loss_is_the_whole_loss():
     assert int(tokens) == 90
     assert int(correct) == int(jnp.sum(
         (jnp.argmax(hidden @ head, -1) == y) & (mask > 0)))
+
+
+# -- the guard of a refactor: five kinds' programs, pinned -------------------
+
+_EXPERTS = dict(moe_experts=4, moe_top_k=2, moe_ffn_size=16,
+                experts_held=(1, 2))
+_LATENT = dict(
+    _EXPERTS, layer_layout=(4, 4, 4), rms_norm_eps=1e-5, q_lora_rank=24,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
+    rope_theta=10000.0, rope_factor=64.0, rope_original_max=64,
+    hidden_act="silu", ffn_size=48, first_dense_layers=1,
+    moe_shared_experts=1, moe_scoring="sigmoid", moe_routed_scaling=2.0)
+#: Tiny copies of the four accepted decoder configurations (bfloat16,
+#: recomputed blocks, as their files state) and a latent one with a plain
+#: residual: what each states beyond the two-layer base below.
+PINNED_KINDS = {
+    "routed": _EXPERTS,
+    "learned_sparse": dict(
+        _EXPERTS, layer_layout=(2, 2), hidden_act="silu", indexer_heads=2,
+        indexer_head_dim=8, indexer_topk=8, rope_theta=1e7),
+    "hybrid": dict(
+        layer_layout=(3, 0, 3), rms_norm_eps=1e-5, ffn_size=48,
+        hidden_act="silu", ssm_heads=4, ssm_head_dim=16, ssm_state=8,
+        ssm_conv=4, ssm_chunk=16, tie_embeddings=True,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.25, logits_scaling=8.0),
+    "latent": dict(_LATENT, moe_bias_rate=1e-3, hc_streams=4),
+    "latent_plain": _LATENT,
+}
+#: sha256 (first 16 hex digits), jax 0.9.0, taken on PR 45's parent
+#: (b804e58) by this very code, of: the lowered text of the single train
+#: and eval programs; the sorted set of their operations' scope paths
+#: (the ``"jit(...)/..."`` names of ``as_text(debug_info=True)``, no file
+#: or line: every ``jax.named_scope`` and module name with its nesting,
+#: less the ``block_1._method`` components flax writes for a module's
+#: method, which name no scope the code opens and no reader asks for);
+#: the parameter tree at ``PRNGKey(0)`` (paths, shapes, dtypes, bytes).
+#: The first three kinds' text hashes are PR 34's and PR 41's parents'.
+#: A pin that moves means the change altered the program:
+#: regenerate (``_program_pins(kind)``) only after a deliberate change to
+#: these layers, their task or the step function.
+PINNED = {
+    "routed": dict(
+        params="733593a24ee9f9e9",
+        train_text="76a140ee75bf588c", train_scopes="ee0a831e3dffa8a1",
+        eval_text="5699bc3b43b95178", eval_scopes="abd3c87e847b2834"),
+    "learned_sparse": dict(
+        params="c338591f00479041",
+        train_text="f9fc79c631ca6670", train_scopes="16477f079b833fea",
+        eval_text="8b169ac148cd8b1c", eval_scopes="fb8ad303eccc0d7e"),
+    "hybrid": dict(
+        params="7170fa193506754b",
+        train_text="c9f228243c080be9", train_scopes="627737dbb0728fbd",
+        eval_text="c949e67b3ed09b92", eval_scopes="65a0fce208c4d6ab"),
+    "latent": dict(
+        params="43dbdb7823a39232",
+        train_text="db71a66c10d37741", train_scopes="b00d2db6086a442e",
+        eval_text="55a5e5ccb8daad7d", eval_scopes="3f052a1b1b05a258"),
+    "latent_plain": dict(
+        params="881b57f6db59149d",
+        train_text="eae356acde159301", train_scopes="566a40a3b3816364",
+        eval_text="4dbdd7bc48e5f019", eval_scopes="09c0dcb75690bf72"),
+}
+
+
+def _sha(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _program_pins(kind):
+    import re
+
+    from fmda_tpu.data.source import TokenArraySource
+    from fmda_tpu.train.trainer import Trainer
+
+    seq, vocab = 32, 64
+    mc = ModelConfig(**{**dict(
+        cell="decoder", hidden_size=32, n_heads=4, n_kv_heads=2, head_dim=8,
+        vocab_size=vocab, layer_layout=(0, 1), sliding_window=8,
+        loss_chunk=16, dtype="bfloat16", remat=True), **PINNED_KINDS[kind]})
+    tc = TrainConfig(batch_size=2, window=seq, chunk_size=2 * seq,
+                     learning_rate=1e-2, clip=1.0, val_size=0.1,
+                     test_size=0.1, cache_chunks=16, seed=0)
+    rng = np.random.default_rng(0)
+    ids = np.minimum(rng.zipf(1.3, size=21 * seq + 1) - 1, vocab - 1)
+    trainer = Trainer(mc, tc)
+    dataset = trainer.task.dataset(TokenArraySource(ids, vocab))
+    state = trainer.init_state(jax.random.PRNGKey(0))
+    batch = next(iter(trainer._chunk_batches(dataset, 0)))
+    totals = trainer.zero_totals()
+    pins = {"params": _sha("\n".join(
+        "%s %s %s %s" % (jax.tree_util.keystr(path), leaf.shape, leaf.dtype,
+                         _sha(np.asarray(leaf).tobytes().hex()))
+        for path, leaf in jax.tree_util.tree_leaves_with_path(state.params)))}
+    for step, lowered in (
+            ("train", trainer._train_step._jit.lower(
+                state, totals, batch, jax.random.PRNGKey(1))),
+            ("eval", trainer._eval_step._jit.lower(
+                state.params, totals, batch))):
+        pins[step + "_text"] = _sha(lowered.as_text())
+        pins[step + "_scopes"] = _sha("\n".join(sorted({
+            re.sub(r"/block_\d+\.\w+", "", path) for path in re.findall(
+                r'"(jit\([^"]*)"', lowered.as_text(debug_info=True))})))
+    return pins
+
+
+@pytest.mark.parametrize("kind", sorted(PINNED_KINDS))
+def test_the_accepted_configurations_steps_are_the_parents(
+        monkeypatch, kind):
+    from fmda_tpu.train import trainer as trainer_module
+
+    monkeypatch.setattr(trainer_module, "SOLO_STEP_BYTES", 1)
+    assert _program_pins(kind) == PINNED[kind]
