@@ -556,7 +556,7 @@ class ModelConfig:
     #: residual mix made doubly stochastic by ``hc_sinkhorn_iters`` turns
     #: of Sinkhorn's iteration (``hc_eps`` beside each sum) from logits
     #: clipped to +-``hc_res_clamp``.  A fresh block reads and writes
-    #: lane 0 and remixes by nearly the identity (models/latent_block.py
+    #: lane 0 and remixes by nearly the identity (models/decoder.py
     #: ``HC_OFFSET_INIT``).
     hc_streams: int = 1
     hc_sinkhorn_iters: int = 20

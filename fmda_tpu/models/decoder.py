@@ -2,8 +2,13 @@
 attention — the token family, ``ModelConfig(cell="decoder")``.
 
 Every other family classifies a float feature window; this one predicts
-the next token of an id sequence.  One layer (x: residual stream,
-``(T, hidden)``; ``layout`` from ``cfg.layer_layout``)::
+the next token of an id sequence.  A layer is a mixer, a feed-forward and
+a residual, each written once: :class:`DecoderBlock` runs ``mixer
+sublayer -> feed-forward sublayer``, the mixer from :data:`KINDS` (the
+one table that gives ``cfg.layer_layout``'s integers a meaning), the
+feed-forward :func:`feed_forward`, and what a layer counts declared by
+name (:func:`model_counts`).  A layer of kind 0 or 1 (x: residual
+stream, ``(T, hidden)``)::
 
     h  = RMSNorm(x)
     p  = softmax(h @ W_r)                     router, placed BEFORE attention
@@ -16,8 +21,9 @@ the next token of an id sequence.  One layer (x: residual stream,
     m  = sum_{e in S, held} g_e * (relu(u @ Wg_e) * (u @ Wu_e)) @ Wd_e
     x2 = x1 + m
 
-A learned-sparse layer (``layout`` 2) decides the keys by a score it
-learns instead of by position, and routes after attention::
+(kind 0: full attention; kind 1: window attention.)  A learned-sparse
+layer (``layout`` 2) decides the keys by a score it learns instead of by
+position, and routes after attention::
 
     h  = RMSNorm(x)
     q, k, v = h @ W_q, h @ W_k, h @ W_v
@@ -73,6 +79,48 @@ Parameters are float32; products run in
 ``cfg.dtype``; norms, softmaxes, rotary angles and the router's
 probabilities are float32.
 
+A latent-attention layer (``layout`` 4; a model has them in every layer
+or in none) keeps ``n`` heads of ``dn`` = ``qk_nope_head_dim`` unrotated
+and ``dr`` = ``qk_rope_head_dim`` rotated dims, values ``dv`` =
+``v_head_dim`` wide, behind two low-rank products::
+
+    cq = RMSNorm(h @ wq_a)  (q_lora_rank) ;  [qn | qr] = cq @ wq_b              n x (dn | dr)
+    [ckv | kr] = h @ wkv_a  (kv_lora_rank | dr) ;  [kn | v] = RMSNorm(ckv) @ wkv_b   n x (dn | dv)
+    qr, kr rotary over dr dims at YaRN's frequencies; kr is ONE head for all n
+    s[t, j] = (qn_t . kn_j + qr_t . kr_j) * (dn + dr)^-1/2 * m^2 ,  m = 0.1 ln(rope_factor) + 1
+    a = causal softmax(s) v ;  out = a @ wo                                    (n * dv -> hidden)
+
+The core runs through :func:`~fmda_tpu.ops.attention.mha` on ``[qn |
+qr]`` and ``[kn | kr]`` (the shared rotary key repeated over the heads,
+``dr / (dn + dr)`` of the keys' bytes) with values ``dv`` wide: the
+flash kernels take a value width of their own, nothing is padded.  Such
+a model may state three more things.  Its first
+``cfg.first_dense_layers`` layers take the dense MLP though the rest
+have experts.  Its expert layers may score by sigmoid, choose on a
+biased score and add a shared expert (``E`` = ``moe_experts``)::
+
+    sc = sigmoid(u @ router)  (E) ;  S = top-k of (sc + router_bias)
+    g_e = moe_routed_scaling * sc_e / sum_{e' in S} sc_e'
+    m = act(u ws_gate) * (u ws_up) @ ws_down  +  sum_{e in S, held} g_e expert_e(u)
+
+(``router_bias`` has no gradient; the trainer's task moves it after each
+step from the step's load over all ``E`` experts,
+:meth:`fmda_tpu.train.tasks.NextToken.after_update`).  And its residual
+may run in ``n`` = ``cfg.hc_streams`` lanes: at one lane each sublayer
+``F`` is the plain pre-norm residual ``x + r * F(RMSNorm(x))`` every
+kind has; at ``n > 1`` it is wrapped by learned mixing
+(:mod:`fmda_tpu.ops.hyper_connection`: ``Hpre`` reads the lanes into one
+stream, ``Hpost`` writes ``F``'s output back, ``Hres`` remixes the lanes,
+doubly stochastic)::
+
+    u = sum_i Hpre[i] X[i] ;  y = F(RMSNorm(u)) ;  X'[i] = sum_j Hres[i, j] X[j] + Hpost[i] y
+
+Scopes (docs/observability.md "Spans and scopes"): ``attention`` holds
+the cores' and ``mla_proj`` (latent attention's products, norms and
+rotary); ``ssm_mixer`` a state-space mixer's five; ``hyper_conn`` the
+lanes' ``hc_coeff``, ``hc_pre``, ``hc_post_res``; ``moe_shared``; the
+expert layer's and the dense MLP's own.
+
 ``__call__`` returns the logits whole (small sizes, tests).  Training
 calls :meth:`MoEDecoder.features` and takes the loss over token chunks
 (:func:`fmda_tpu.train.losses.chunked_next_token_loss`), so the
@@ -82,15 +130,18 @@ calls :meth:`MoEDecoder.features` and takes the loss over token chunks
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional, Tuple
+from contextlib import nullcontext
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from fmda_tpu.config import ModelConfig
 from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT, mha
-from fmda_tpu.ops.moe import ACTIVATIONS, expert_layer, kernel_impl, route
+from fmda_tpu.ops.moe import (
+    ACTIVATIONS, expert_layer, kernel_impl, route, router_load)
 from fmda_tpu.ops.sparse_attention import (
     PICKS, kernels_dispatch, select_keys, sparse_mha)
 from fmda_tpu.ops.ssd import conv_silu, ssd_scan
@@ -104,13 +155,21 @@ INIT_STD = 0.02
 #: deeper router sees one row a sequence long, and routing collapses
 #: onto ``moe_top_k`` experts (seen on the chip, PERF.md section 6, PR 28).
 EMBED_INIT_STD = 1.0
+#: Initial value of the lanes' three gains ``a_pre``, ``a_post``,
+#: ``a_res``: the coefficients start nearly static (their offsets), the
+#: input's part small beside them.
+HC_GAIN_INIT = 0.01
+#: ... and the size of the offsets' initial values (:func:`_lane_mixed`):
+#: a lane is read at sigmoid(+-6) = 0.9975 / 0.0025 and the remix is the
+#: identity to exp(-12).
+HC_OFFSET_INIT = 6.0
 
-
-#: ``layer_layout``'s value for a learned-sparse layer.
+#: ``layer_layout``'s value for a learned-sparse layer (:data:`KINDS` has
+#: every value's meaning; these three are names other files hold).
 SPARSE_LAYOUT = 2
-#: ... and for a state-space layer (ops/ssd.py).
+#: ... for a state-space layer (ops/ssd.py).
 SSM_LAYOUT = 3
-#: ... and for a latent-attention layer (models/latent_block.py).
+#: ... and for a latent-attention layer.
 LATENT_LAYOUT = 4
 
 #: What a block's recomputation (``cfg.remat``) keeps from the forward
@@ -123,39 +182,6 @@ LATENT_LAYOUT = 4
 #: kv head) and a learned-sparse layer's picks (int8, T x T).  A layer
 #: puts under a name what it has: one list serves every layout.
 REPLAY_KEEPS = (CORE_OUT, CORE_LSE, PICKS)
-
-
-class RoutingStats(NamedTuple):
-    """What the layers counted in one forward pass.  The first three are
-    the expert layers', None in a model without experts."""
-
-    expert_pairs: Optional[jax.Array]  # (layers, held experts) int32
-    dropped: Optional[jax.Array]       # () int32: held pairs not computed (0)
-    row_tiles_used: Optional[jax.Array]  # (layers,) int32: row tiles holding a group
-    #: What the learned-sparse layers' selection counted, None in a model
-    #: without one: the keys kept, as (layers, 2) int32 ``[count >> 16,
-    #: count & 0xffff]`` summed over the batch's sequences (a sequence of
-    #: 16,384 tokens keeps 31 M keys a layer: a pass's sum outgrows
-    #: int32), and the query rows they were kept for, (layers,) int32.
-    keys_kept: Optional[jax.Array] = None
-    query_rows: Optional[jax.Array] = None
-    #: What the state-space layers' scans walked, None in a model without
-    #: one: chunks and positions, (layers,) int32 each, 0 in a layer of
-    #: another kind.
-    ssd_chunks: Optional[jax.Array] = None
-    ssd_positions: Optional[jax.Array] = None
-    #: What the latent-attention layers counted, None in a model without
-    #: one: the pairs each of ALL the router's experts received, held
-    #: here or not, (layers, moe_experts) int32 (what the selection bias
-    #: steps on; a dense layer's row is 0); the largest size of a
-    #: selection bias, (layers,) float32; the largest distance of a row
-    #: or column sum of a residual mixing matrix from one, (layers,)
-    #: float32 (0 with one lane); the causal pairs each core scored,
-    #: (layers,) int32.
-    router_load: Optional[jax.Array] = None
-    router_bias_absmax: Optional[jax.Array] = None
-    hc_sum_error: Optional[jax.Array] = None
-    latent_pairs: Optional[jax.Array] = None
 
 
 def _weight(module: nn.Module, name: str, shape: Tuple[int, ...],
@@ -171,19 +197,25 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding over all of the last axis of
-    ``(B, heads, T, head_dim)``, positions ``0 .. T-1``, the half-split
-    convention: dims ``i`` and ``i + head_dim/2`` rotate together by
-    ``pos * theta^(-2i/head_dim)``."""
+def rotary_at(x: jax.Array, inv_freq) -> jax.Array:
+    """Rotary over all of the last axis of ``(B, heads, T, d)`` at the
+    given ``d / 2`` frequencies, positions ``0 .. T-1``, the half-split
+    convention: dims ``i`` and ``i + d/2`` rotate together by ``pos *
+    inv_freq[i]``; float32 angles, ``x``'s dtype."""
     t, d = x.shape[-2], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(
+        inv_freq, jnp.float32)[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """:func:`rotary_at` the plain frequencies ``theta^(-2i/head_dim)``."""
+    d = x.shape[-1]
+    return rotary_at(x, theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))
 
 
 def _uniform(low: float, high: float, then=lambda v: v):
@@ -197,10 +229,148 @@ def _inverse_softplus(step: jax.Array) -> jax.Array:
     return step + jnp.log(-jnp.expm1(-step))
 
 
+class Count(NamedTuple):
+    """One thing a layer counts in a forward pass, declared once: in
+    :data:`EXPERT_COUNTS` (an expert layer's) or in the row of
+    :data:`KINDS` whose layers count it."""
+
+    dtype: Any
+    shape: Callable[[ModelConfig], Tuple[int, ...]] = lambda cfg: ()
+    #: Which of the layers that could count it do.
+    where: Callable[[ModelConfig, int], bool] = lambda cfg, layer: True
+    #: A pass's value from its steps' (and a step's from its
+    #: microbatches'): ``"sum"`` or ``"max"``.
+    fold: str = "sum"
+    #: Whether the model keeps a value a layer (axis 0) or adds them up.
+    stacked: bool = True
+    #: What makes the layer's value of the array its kernels counted.
+    settle: Optional[Callable[[jax.Array], jax.Array]] = None
+
+
+class ModelCount(NamedTuple):
+    """A :class:`Count` over one configuration's layers."""
+
+    count: Count
+    shape: Tuple[int, ...]   # (layers,) + one layer's, or one layer's
+    layers: Tuple[int, ...]  # the layers that count it
+
+
+@jax.tree_util.register_pytree_node_class
+class Counts(dict):
+    """A layer's counts by name, a pytree whose leaves keep the order
+    they were put in (a dict's are sorted by name): the order of a
+    compiled block's outputs."""
+
+    def tree_flatten(self):
+        return tuple(self.values()), tuple(self.keys())
+
+    @classmethod
+    def tree_unflatten(cls, names, values):
+        return cls(zip(names, values))
+
+
+def _has_experts(cfg: ModelConfig, layer: int) -> bool:
+    return cfg.moe_experts > 0 and layer >= cfg.first_dense_layers
+
+
+def _split_sum(kept: jax.Array) -> jax.Array:
+    """(batch, row blocks) int32 counts -> (2,) int32 ``[sum of count >>
+    16, sum of count & 0xffff]``: a sequence of 16,384 tokens keeps 31 M
+    keys a layer, and a pass's sum outgrows int32
+    (:func:`fmda_tpu.train.tasks.keys_kept_counts` joins the halves)."""
+    return jnp.stack([jnp.sum(kept >> 16), jnp.sum(kept & 0xFFFF)])
+
+
+#: What an expert layer counts (:func:`feed_forward`): the pairs it
+#: computed on each held expert, the held pairs it did not compute (0),
+#: and the row tiles of its layout that held a group.
+EXPERT_COUNTS: Dict[str, Count] = {
+    "expert_pairs": Count(jnp.int32, lambda cfg: (cfg.experts_held[1],),
+                          _has_experts),
+    "dropped": Count(jnp.int32, where=_has_experts, stacked=False),
+    "row_tiles_used": Count(jnp.int32, where=_has_experts,
+                            settle=lambda n_used: n_used[0]),
+}
+
+
+def _settled(counts: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """Each of ``counts`` in its declared form."""
+    return {name: COUNTS[name].settle(value) if COUNTS[name].settle
+            else value for name, value in counts.items()}
+
+
+def _heads(module: nn.Module, src: jax.Array, name: str, n_heads: int,
+           width: int) -> jax.Array:
+    """``src @ W`` as ``(B, n_heads, T, width)``."""
+    b, t, d = src.shape
+    y = jnp.dot(src, _weight(module, name, (d, n_heads * width))
+                .astype(src.dtype))
+    return y.reshape(b, t, n_heads, width).transpose(0, 2, 1, 3)
+
+
+def _merged(module: nn.Module, a: jax.Array, d: int) -> jax.Array:
+    """``(B, heads, T, width)`` through the output product ``wo``."""
+    b, n, t, width = a.shape
+    a = a.transpose(0, 2, 1, 3).reshape(b, t, n * width)
+    return jnp.dot(a, _weight(module, "wo", (n * width, d)).astype(a.dtype))
+
+
+def _attention_mixer(window: bool):
+    """Full attention (no positional encoding, every key ``j <= i``), or
+    with ``window`` rotary on q, k and keys ``0 <= i - j <
+    cfg.sliding_window``."""
+    def mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
+        n, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        q, k, v = (_heads(module, h, "wq", n, hd),
+                   _heads(module, h, "wk", g, hd),
+                   _heads(module, h, "wv", g, hd))
+        if window:
+            with jax.named_scope("rope"):
+                q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+        with jax.named_scope("attention_window" if window
+                             else "attention_full"):
+            a = mha(q, k, v, causal=True,
+                    window=cfg.sliding_window if window else None,
+                    use_flash=cfg.use_pallas, scale=cfg.attention_multiplier)
+        return _merged(module, a, h.shape[-1]), {}
+    return mixer
+
+
+def _sparse_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
+    """Attention over the keys a learned indexer picks (module
+    docstring), and what the selection kept."""
+    b, t, d = h.shape
+    n, g, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, h.dtype
+    q, k, v = (_heads(module, h, "wq", n, hd), _heads(module, h, "wk", g, hd),
+               _heads(module, h, "wv", g, hd))
+    q, k = (rms_norm(y, module.param(name, nn.initializers.ones, (hd,)),
+                     cfg.rms_norm_eps)
+            for y, name in ((q, "q_norm"), (k, "k_norm")))
+    with jax.named_scope("rope"):
+        q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+    use_kernels = kernels_dispatch(t, n // g, hd, use_kernels=cfg.use_pallas)
+    with jax.named_scope("attention_indexer"):
+        # no gradient reaches the indexer (module docstring)
+        h_idx = jax.lax.stop_gradient(h)
+        hi, di = cfg.indexer_heads, cfg.indexer_head_dim
+        q_idx = rotary(_heads(module, h_idx, "wq_idx", hi, di),
+                       cfg.rope_theta)
+        k_idx = rotary(_heads(module, h_idx, "wk_idx", 1, di),
+                       cfg.rope_theta)[:, 0]
+        w_idx = jnp.dot(h_idx, _weight(module, "ww_idx", (d, hi)).astype(dt),
+                        preferred_element_type=jnp.float32)
+    picked, kept = select_keys(q_idx, k_idx, w_idx, cfg.indexer_topk,
+                               use_kernels=use_kernels)
+    module.sow("intermediates", "picked", picked)
+    a = sparse_mha(q, k, v, picked, use_kernels=use_kernels)
+    return _merged(module, a, d), {
+        "sparse_keys_kept": kept, "sparse_query_rows": jnp.int32(b * t)}
+
+
 def _ssm_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
     """A state-space layer's mixer (module docstring) on the normalised
-    stream ``h`` (B, T, hidden): its output (B, T, hidden) and the
-    ``(chunks, positions)`` its scan walked.  Parameters start where the
+    stream ``h`` (B, T, hidden): its output (B, T, hidden) and the chunks
+    and positions its scan walked.  Parameters start where the
     mechanism's published code starts them: rates ``-A`` uniform in 1..16,
     step sizes log-uniform in 1e-3..1e-1 at a zero input, the skip at 1,
     the taps uniform in +-1/sqrt(taps)."""
@@ -241,7 +411,200 @@ def _ssm_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
     with jax.named_scope("ssm_out_proj"):
         out = jnp.dot(gated, _weight(module, "w_out", (inner, d))
                       .astype(dt))
-    return out, (jnp.int32(b * states.shape[1]), jnp.int32(b * t))
+    return out, {"ssd_chunks": jnp.int32(b * states.shape[1]),
+                 "ssd_positions": jnp.int32(b * t)}
+
+
+def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
+    """The rotary frequencies of the ``qk_rope_head_dim`` rotary dims,
+    (dr / 2,) float32: ``theta^(-2i/dr)``, stretched by YaRN where
+    ``cfg.rope_factor > 1`` (dims turning more than ``rope_beta_fast``
+    times over ``rope_original_max`` positions keep theirs, those under
+    ``rope_beta_slow`` turns are divided by the factor, a linear ramp
+    over the dims between)."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if cfg.rope_factor <= 1.0:
+        return plain.astype(np.float32)
+
+    def dim_turning(turns: float) -> float:
+        return dim * math.log(cfg.rope_original_max / (turns * 2 * math.pi)
+                              ) / (2 * math.log(base))
+
+    low = max(math.floor(dim_turning(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(dim_turning(cfg.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / cfg.rope_factor * ramp + plain * (1 - ramp)
+            ).astype(np.float32)
+
+
+def score_scale(cfg: ModelConfig) -> float:
+    """What the latent core's scores are multiplied by."""
+    m = 0.1 * math.log(cfg.rope_factor) + 1.0 if cfg.rope_factor > 1 else 1.0
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _latent_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
+    """Latent attention (module docstring), and the causal pairs its core
+    scored."""
+    b, t, d = h.shape
+    n, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    dt, eps = h.dtype, cfg.rms_norm_eps
+    ones = nn.initializers.ones
+
+    def heads(y, width):
+        return y.reshape(b, t, n, width).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("attention"):
+        with jax.named_scope("mla_proj"):
+            cq = rms_norm(
+                jnp.dot(h, _weight(module, "wq_a", (d, cfg.q_lora_rank))
+                        .astype(dt)),
+                module.param("q_norm", ones, (cfg.q_lora_rank,)), eps)
+            q = heads(jnp.dot(cq, _weight(
+                module, "wq_b", (cfg.q_lora_rank, n * (dn + dr)))
+                .astype(dt)), dn + dr)
+            ckv, kr = jnp.split(
+                jnp.dot(h, _weight(module, "wkv_a",
+                                   (d, cfg.kv_lora_rank + dr)).astype(dt)),
+                [cfg.kv_lora_rank], axis=-1)
+            kv = heads(jnp.dot(
+                rms_norm(ckv, module.param("kv_norm", ones,
+                                           (cfg.kv_lora_rank,)), eps),
+                _weight(module, "wkv_b", (cfg.kv_lora_rank, n * (dn + dv)))
+                .astype(dt)), dn + dv)
+            kn, v = kv[..., :dn], kv[..., dn:]
+            with jax.named_scope("rope"):
+                inv_freq = yarn_inv_freq(cfg)
+                qr = rotary_at(q[..., dn:], inv_freq)
+                kr = rotary_at(kr[:, None], inv_freq)  # one head
+            q = jnp.concatenate([q[..., :dn], qr], axis=-1)
+            k = jnp.concatenate(
+                [kn, jnp.broadcast_to(kr, (b, n, t, dr))], axis=-1)
+        with jax.named_scope("attention_latent"):
+            a = mha(q, k, v, causal=True, use_flash=cfg.use_pallas,
+                    scale=score_scale(cfg))
+        with jax.named_scope("mla_proj"):
+            out = _merged(module, a, d)
+    return out, {"latent_pairs": jnp.int32(b * (t * (t + 1) // 2))}
+
+
+# -- the kinds: the one place layer_layout's integers are given meaning -------
+
+
+class Kind(NamedTuple):
+    """One value of ``layer_layout``: its mixer, and what else a block
+    has to know of it."""
+
+    #: ``(module, cfg, h) -> (output, counts)``: the block's module (the
+    #: parameters' owner) and the normalised stream (B, T, hidden) -> the
+    #: output before it joins the stream, and what it counted by name.
+    mixer: Callable[[nn.Module, ModelConfig, jax.Array],
+                    Tuple[jax.Array, Dict[str, jax.Array]]]
+    #: The scope the mixer runs and joins the stream under; None where
+    #: the mixer names its own and the join is outside it.
+    scope: Optional[str]
+    #: Where an expert layer's router reads.  ``"mixer"``: the mixer's
+    #: normalised input (placed before attention, its top-k is known a
+    #: layer's attention ahead of the experts it feeds).  ``"feed_forward"``:
+    #: the feed-forward's, which the block flattens for the router apart
+    #: from the experts.  None: the same values, the rows the experts
+    #: compute on (one ``reshape`` fewer; each kind keeps its parent's text).
+    router_reads: Optional[str]
+    #: What its layers count beside an expert layer's own, in the order
+    #: a model stacks them.
+    counts: Dict[str, Count] = {}
+    #: ``cfg -> [(field and what it must be, whether it is)]``: its rows
+    #: of :func:`check_decoder_config`.
+    rules: Callable[[ModelConfig], list] = lambda cfg: []
+
+
+def _sparse_rules(cfg: ModelConfig) -> list:
+    why = "layer_layout has a learned-sparse layer"
+    return [
+        (f"indexer_topk ({why})", cfg.indexer_topk > 0),
+        (f"indexer_heads ({why})", cfg.indexer_heads > 0),
+        (f"indexer_head_dim (even, for rotary; {why})",
+         cfg.indexer_head_dim > 0 and cfg.indexer_head_dim % 2 == 0),
+        ("n_heads / n_kv_heads / head_dim (a learned-sparse layer's "
+         "kernels take a group that divides 128 and heads of at most 512)",
+         not (cfg.use_pallas and cfg.n_kv_heads > 0)
+         or (128 % max(cfg.n_heads // cfg.n_kv_heads, 1) == 0
+             and cfg.head_dim <= 512))]
+
+
+def _ssm_rules(cfg: ModelConfig) -> list:
+    return [(f"{name} (layer_layout has a state-space layer)",
+             getattr(cfg, name) > 0)
+            for name in ("ssm_heads", "ssm_head_dim", "ssm_state",
+                         "ssm_conv", "ssm_chunk")]
+
+
+def _latent_rules(cfg: ModelConfig) -> list:
+    why = " (layer_layout has a latent-attention layer)"
+    return [(name + why, getattr(cfg, name) > 0) for name in (
+        "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "v_head_dim")] + [
+        ("qk_rope_head_dim (even, for rotary)" + why,
+         cfg.qk_rope_head_dim > 0 and cfg.qk_rope_head_dim % 2 == 0),
+        ("attention_multiplier / residual_multiplier (a latent-attention "
+         "layer states its own score scale and joins the stream unscaled)",
+         cfg.attention_multiplier is None and cfg.residual_multiplier == 1.0)]
+
+
+KINDS: Dict[int, Kind] = {
+    0: Kind(_attention_mixer(window=False), "attention", "mixer"),
+    1: Kind(_attention_mixer(window=True), "attention", "mixer"),
+    SPARSE_LAYOUT: Kind(_sparse_mixer, "attention", "feed_forward", {
+        # the keys kept, as two halves, and the query rows they were kept
+        # for
+        "sparse_keys_kept": Count(jnp.int32, lambda cfg: (2,),
+                                  settle=_split_sum),
+        "sparse_query_rows": Count(jnp.int32)}, _sparse_rules),
+    SSM_LAYOUT: Kind(_ssm_mixer, "ssm_mixer", "mixer", {
+        # what the scan walked
+        "ssd_chunks": Count(jnp.int32),
+        "ssd_positions": Count(jnp.int32)}, _ssm_rules),
+    LATENT_LAYOUT: Kind(_latent_mixer, None, None, {
+        # such a model's expert layers: the pairs each of ALL the router's
+        # experts received, held here or not (what the selection bias
+        # steps on), and the largest size of a selection bias; its lanes:
+        # the largest distance of a row or column sum of a residual
+        # mixing matrix from one; its cores: the causal pairs each scored
+        "router_load": Count(jnp.int32, lambda cfg: (cfg.moe_experts,),
+                             _has_experts),
+        "router_bias_absmax": Count(jnp.float32, where=_has_experts,
+                                    fold="max"),
+        "hc_sum_error": Count(
+            jnp.float32, where=lambda cfg, layer: cfg.hc_streams > 1,
+            fold="max"),
+        "latent_pairs": Count(jnp.int32)}, _latent_rules),
+}
+
+#: Every declared count by name, whatever the configuration.
+COUNTS: Dict[str, Count] = {**EXPERT_COUNTS, **{
+    name: count for kind in KINDS.values()
+    for name, count in kind.counts.items()}}
+
+
+def model_counts(cfg: ModelConfig) -> Dict[str, ModelCount]:
+    """THE declaration: the counts a forward pass of ``cfg``'s model
+    returns, by name, in the order :meth:`MoEDecoder.features` makes
+    them.  The model fills and stacks by it; the task (train/tasks.py
+    ``NextToken``) zeroes, folds and publishes by it.  No model is traced."""
+    depth = range(len(cfg.layer_layout))
+    groups = [(EXPERT_COUNTS, list(depth))] + [
+        (KINDS[code].counts, [i for i in depth if cfg.layer_layout[i] == code])
+        for code in sorted(set(cfg.layer_layout)) if code in KINDS]
+    declared = {}
+    for counts, candidates in groups:
+        for name, count in counts.items():
+            layers = tuple(i for i in candidates if count.where(cfg, i))
+            if layers:
+                stack = (len(depth),) if count.stacked else ()
+                declared[name] = ModelCount(
+                    count, stack + tuple(count.shape(cfg)), layers)
+    return declared
 
 
 def _dense_mlp(module: nn.Module, cfg: ModelConfig, u: jax.Array,
@@ -259,124 +622,172 @@ def _dense_mlp(module: nn.Module, cfg: ModelConfig, u: jax.Array,
                        _weight(module, names[2], (f, d)).astype(dt))
 
 
+def routing(module: nn.Module, cfg: ModelConfig, y: jax.Array):
+    """``(gates, experts)`` of the stream or rows ``y`` (..., hidden):
+    the model's one router call, as ``cfg`` scores, biases and scales."""
+    bias = None
+    if cfg.moe_bias_rate > 0:
+        bias = module.param("router_bias", nn.initializers.zeros,
+                            (cfg.moe_experts,), jnp.float32)
+    d = y.shape[-1]
+    return route(
+        y.reshape(-1, d), _weight(module, "router", (d, cfg.moe_experts)),
+        cfg.moe_top_k, scoring=cfg.moe_scoring, bias=bias,
+        scale=cfg.moe_routed_scaling), bias
+
+
+def feed_forward(module: nn.Module, cfg: ModelConfig, u: jax.Array, *,
+                 dense: bool, routed=None, counted: Dict[str, jax.Array],
+                 load: bool = False):
+    """A layer's feed-forward on the normalised stream ``u`` (B, T,
+    hidden): the dense gated MLP (``dense``, or a model without experts),
+    or the router, the held experts and ``cfg.moe_shared_experts`` shared
+    ones.  ``routed``: :func:`routing`'s answer where the block asked
+    already.  Returns the output and all the layer counted: ``counted``
+    (the mixer's, each now in its declared form), :data:`EXPERT_COUNTS`
+    and, with ``load``, the load on all experts and the bias's size."""
+    if dense or not cfg.moe_experts:
+        return _dense_mlp(module, cfg, u), _settled(counted)
+    b, t, d = u.shape
+    f = cfg.moe_ffn_size
+    first, count = cfg.experts_held
+    flat = u.reshape(b * t, d)
+    (gates, experts), bias = routed or routing(module, cfg, flat)
+    m, plan = expert_layer(
+        flat, gates, experts,
+        _weight(module, "w_gate", (count, d, f)),
+        _weight(module, "w_up", (count, d, f)),
+        _weight(module, "w_down", (count, f, d)),
+        experts_held=(first, count), impl=kernel_impl(cfg.use_pallas),
+        act=cfg.hidden_act)
+    if cfg.moe_shared_experts:
+        shared = _dense_mlp(
+            module, cfg, flat, cfg.moe_shared_experts * f,
+            ("ws_gate", "ws_up", "ws_down"), "moe_shared")
+        with jax.named_scope("moe_shared"):
+            m = (m.astype(jnp.float32) + shared.astype(jnp.float32)
+                 ).astype(u.dtype)
+    counts = dict(_settled(counted), expert_pairs=plan.group_sizes,
+                  dropped=plan.dropped, row_tiles_used=plan.n_used)
+    if load:
+        counts["router_bias_absmax"] = (
+            jnp.zeros((), jnp.float32) if bias is None
+            else jnp.max(jnp.abs(bias)))
+    out = m.reshape(b, t, d)
+    if load:
+        counts["router_load"] = router_load(experts, cfg.moe_experts)
+    return out, counts
+
+
+def _lane_mixed(module: nn.Module, cfg: ModelConfig, name: str,
+                x: jax.Array, fn, impl: str):
+    """``hc.around`` with the sublayer ``name``'s mixing parameters: the
+    lanes after ``fn`` inside its mixing, ``fn``'s counts, and ``Hres``.
+    The offsets start so that it reads lane 0 (``Hpre`` near one-hot),
+    writes it with weight one (``2 sigmoid(0)``), remixes by the identity."""
+    n, d = x.shape[2], x.shape[3]
+    const, size = nn.initializers.constant, HC_OFFSET_INIT
+    gain, first = const(HC_GAIN_INIT), np.arange(n) == 0
+    b_pre, b_post, b_res = (
+        np.where(mask, high, -size).astype(np.float32) for mask, high in (
+            (first, size), (first, 0.0), (np.eye(n, dtype=bool), size)))
+    from fmda_tpu.ops import hyper_connection as hc
+
+    return hc.around(
+        fn, x,
+        _weight(module, f"hc_{name}_p_pre", (n * d, n)),
+        _weight(module, f"hc_{name}_p_post", (n * d, n)),
+        _weight(module, f"hc_{name}_p_res", (n * d, n * n)),
+        tuple(module.param(f"hc_{name}_a_{k}", gain, (), jnp.float32)
+              for k in ("pre", "post", "res")),
+        (module.param(f"hc_{name}_b_pre", const(b_pre), (n,)),
+         module.param(f"hc_{name}_b_post", const(b_post), (n,)),
+         module.param(f"hc_{name}_b_res", const(b_res), (n, n))),
+        norm_eps=cfg.rms_norm_eps, iters=cfg.hc_sinkhorn_iters,
+        eps=cfg.hc_eps, clamp=cfg.hc_res_clamp, impl=impl)
+
+
 class DecoderBlock(nn.Module):
-    """One layer (module docstring).  A module of its own so that
-    ``nn.remat`` wraps it whole when ``cfg.remat``."""
+    """One layer: the mixer of ``KINDS[layout]`` and the feed-forward (the
+    dense MLP where ``dense``), each a pre-norm sublayer of the residual.
+    Returns the stream and what the layer counted, by name.  A module of
+    its own so that ``nn.remat`` wraps it whole when ``cfg.remat``."""
 
     cfg: ModelConfig
     layout: int
+    dense: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array):
-        cfg = self.cfg
-        b, t, d = x.shape
-        n, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        dt = x.dtype
+        cfg, kind = self.cfg, KINDS[self.layout]
+        d, dt = x.shape[-1], x.dtype
+        lanes = cfg.hc_streams > 1
+        experts = cfg.moe_experts > 0 and not self.dense
+        sum_errors = []
+        if lanes:
+            # imported where a model has lanes: its kernels' module would
+            # cost every other model a second of start-up
+            from fmda_tpu.ops import hyper_connection as hc
 
-        sparse = self.layout == SPARSE_LAYOUT
-        has_experts = cfg.moe_experts > 0
-        h = rms_norm(x, self.param("ln_attn", nn.initializers.ones, (d,)),
-                     cfg.rms_norm_eps)
+            hc_impl = hc.backward_impl(  # what differentiates the mixing
+                kernel_impl(cfg.use_pallas), d, x.shape[1])
 
-        def joined(x, y):
-            """The stream with a mixer's or a feed-forward's output."""
-            if cfg.residual_multiplier == 1.0:
-                return x + y
-            return (x.astype(jnp.float32) + cfg.residual_multiplier
-                    * y.astype(jnp.float32)).astype(dt)
+        def sublayer(x, name, ln, fn, scope=None):
+            """``x`` after the sublayer ``fn`` (normalised stream ->
+            output, what it counted), and those counts: ``x + r * fn(
+            RMSNorm(x))`` at one lane, inside the lanes' mixing at more."""
+            scale = self.param(ln, nn.initializers.ones, (d,))
 
-        def routed(y):
-            return route(
-                y.reshape(b * t, d),
-                _weight(self, "router", (d, cfg.moe_experts)), cfg.moe_top_k)
+            def normed(u):
+                return fn(rms_norm(u, scale, cfg.rms_norm_eps))
 
-        def attention():
-            """The attention layouts' mixer: its output before it joins
-            the stream, and what a learned-sparse selection kept."""
-            kept = None
+            if lanes:
+                x, counts, res = _lane_mixed(self, cfg, name, x, normed,
+                                             hc_impl)
+                with jax.named_scope("hyper_conn"):
+                    sum_errors.append(hc.sum_error(res))
+                return x, counts
+            y, counts = normed(x)
+            with jax.named_scope(scope) if scope else nullcontext():
+                if cfg.residual_multiplier == 1.0:
+                    return x + y, counts
+                return (x.astype(jnp.float32) + cfg.residual_multiplier
+                        * y.astype(jnp.float32)).astype(dt), counts
 
-            def heads(name, n_heads, width=hd, src=h):
-                y = jnp.dot(src, _weight(self, name, (d, n_heads * width))
-                            .astype(dt))
-                return y.reshape(b, t, n_heads, width).transpose(0, 2, 1, 3)
+        routed = None
 
-            q, k, v = heads("wq", n), heads("wk", g), heads("wv", g)
-            if sparse:
-                q, k = (rms_norm(y, self.param(name, nn.initializers.ones,
-                                               (hd,)), cfg.rms_norm_eps)
-                        for y, name in ((q, "q_norm"), (k, "k_norm")))
-            if self.layout:
-                with jax.named_scope("rope"):
-                    q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
-            if sparse:
-                use_kernels = kernels_dispatch(
-                    t, n // g, hd, use_kernels=cfg.use_pallas)
-                with jax.named_scope("attention_indexer"):
-                    # no gradient reaches the indexer (module docstring)
-                    h_idx = jax.lax.stop_gradient(h)
-                    hi, di = cfg.indexer_heads, cfg.indexer_head_dim
-                    q_idx = rotary(heads("wq_idx", hi, di, h_idx),
-                                   cfg.rope_theta)
-                    k_idx = rotary(heads("wk_idx", 1, di, h_idx),
-                                   cfg.rope_theta)[:, 0]
-                    w_idx = jnp.dot(
-                        h_idx, _weight(self, "ww_idx", (d, hi)).astype(dt),
-                        preferred_element_type=jnp.float32)
-                picked, kept = select_keys(
-                    q_idx, k_idx, w_idx, cfg.indexer_topk,
-                    use_kernels=use_kernels)
-                self.sow("intermediates", "picked", picked)
-                a = sparse_mha(q, k, v, picked, use_kernels=use_kernels)
-            else:
-                with jax.named_scope("attention_window" if self.layout
-                                     else "attention_full"):
-                    a = mha(q, k, v, causal=True,
-                            window=(cfg.sliding_window if self.layout
-                                    else None),
-                            use_flash=cfg.use_pallas,
-                            scale=cfg.attention_multiplier)
-            a = a.transpose(0, 2, 1, 3).reshape(b, t, n * hd)
-            return jnp.dot(a, _weight(self, "wo", (n * hd, d)).astype(dt)), kept
+        def mix(h):
+            nonlocal routed
+            if experts and kind.router_reads == "mixer":
+                routed = routing(self, cfg, h)
+            with jax.named_scope(kind.scope) if kind.scope else nullcontext():
+                return kind.mixer(self, cfg, h)
 
-        kept = walked = None
-        if has_experts and not sparse:
-            # the router reads the attention block's normalised input: it
-            # is placed before attention, so its top-k is known a layer's
-            # attention ahead of the experts it feeds
-            gates, experts = routed(h)
+        def feed(u):
+            nonlocal routed
+            if experts and kind.router_reads == "feed_forward":
+                routed = routing(self, cfg, u)
+            return feed_forward(
+                self, cfg, u, dense=self.dense, routed=routed,
+                counted=counted, load="router_load" in kind.counts)
 
-        if self.layout == SSM_LAYOUT:
-            with jax.named_scope("ssm_mixer"):
-                mixed, walked = _ssm_mixer(self, cfg, h)
-                x = joined(x, mixed)
-        else:
-            with jax.named_scope("attention"):
-                mixed, kept = attention()
-                x = joined(x, mixed)
-
-        u = rms_norm(x, self.param("ln_moe" if has_experts else "ln_mlp",
-                                   nn.initializers.ones, (d,)),
-                     cfg.rms_norm_eps)
-        if not has_experts:
-            return joined(x, _dense_mlp(self, cfg, u)), (
-                None, None, None, kept, walked)
-        if sparse:
-            gates, experts = routed(u)
-        first, count = cfg.experts_held
-        f = cfg.moe_ffn_size
-        m, plan = expert_layer(
-            u.reshape(b * t, d), gates, experts,
-            _weight(self, "w_gate", (count, d, f)),
-            _weight(self, "w_up", (count, d, f)),
-            _weight(self, "w_down", (count, f, d)),
-            experts_held=(first, count), impl=kernel_impl(cfg.use_pallas),
-            act=cfg.hidden_act)
-        if sparse:
-            # (batch, row blocks) counts -> the split sum RoutingStats holds
-            kept = (jnp.stack([jnp.sum(kept >> 16), jnp.sum(kept & 0xFFFF)]),
-                    jnp.int32(b * t))
-        return joined(x, m.reshape(b, t, d)), (
-            plan.group_sizes, plan.dropped, plan.n_used[0], kept, walked)
+        x, counted = sublayer(x, "attn", "ln_attn", mix, kind.scope)
+        x, counts = sublayer(x, "ffn", "ln_moe" if experts else "ln_mlp",
+                             feed)
+        counts.update(_settled({name: counts[name] for name in EXPERT_COUNTS
+                                if name in counts}))
+        # a layer answers for what the model declares of the feed-forward
+        # and of its own kind: zeros where it did not count (a dense layer
+        # of a model with experts)
+        declared = model_counts(cfg)
+        for name, count in {**EXPERT_COUNTS, **kind.counts}.items():
+            if name in declared and name not in counts:
+                counts[name] = jnp.zeros(count.shape(cfg), count.dtype)
+        if sum_errors:
+            counts["hc_sum_error"] = jax.lax.stop_gradient(
+                jnp.max(jnp.stack(sum_errors)))
+        return x, Counts((name, counts[name]) for name in declared
+                         if name in counts)
 
 
 class MoEDecoder(nn.Module):
@@ -392,81 +803,54 @@ class MoEDecoder(nn.Module):
         self.embed = _weight(self, "embed", (cfg.vocab_size, d),
                              INIT_STD if cfg.tie_embeddings
                              else EMBED_INIT_STD)
-        def replayed(block_cls):
-            if not cfg.remat:
-                return block_cls
-            return nn.remat(
-                block_cls,
+        block_cls = DecoderBlock
+        if cfg.remat:
+            block_cls = nn.remat(
+                DecoderBlock,
                 policy=jax.checkpoint_policies.save_only_these_names(
                     *REPLAY_KEEPS))
-
-        if LATENT_LAYOUT in cfg.layer_layout:
-            from fmda_tpu.models.latent_block import LatentBlock
-
-            block_cls = replayed(LatentBlock)
-            self.blocks = [
-                block_cls(cfg, i < cfg.first_dense_layers, name=f"block_{i}")
-                for i in range(len(cfg.layer_layout))]
-        else:
-            block_cls = replayed(DecoderBlock)
-            self.blocks = [
-                block_cls(cfg, int(layout), name=f"block_{i}")
-                for i, layout in enumerate(cfg.layer_layout)]
+        self.blocks = [
+            block_cls(cfg, int(layout), i < cfg.first_dense_layers,
+                      name=f"block_{i}")
+            for i, layout in enumerate(cfg.layer_layout)]
         self.ln_final = self.param("ln_final", nn.initializers.ones, (d,))
         if not cfg.tie_embeddings:
             self.head = _weight(self, "head", (d, cfg.vocab_size))
 
-    def features(self, ids: jax.Array) -> Tuple[jax.Array, RoutingStats]:
+    def features(self, ids: jax.Array
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """ids (B, T) int32 -> the final norm's output (B, T, hidden) in
-        the compute dtype, and what the layers counted."""
+        the compute dtype, and what the layers counted: an array a name
+        of :func:`model_counts`, in its order and shapes."""
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = jnp.take(self.embed, ids, axis=0)
             if cfg.embedding_multiplier != 1.0:
                 x = x * cfg.embedding_multiplier
             x = x.astype(jnp.dtype(cfg.dtype))
-        has_experts = cfg.moe_experts > 0
-        sizes, tiles, dropped = [], [], jnp.zeros((), jnp.int32)
-        zero = (jnp.zeros((2,), jnp.int32), jnp.zeros((), jnp.int32))
-        kept, walked, latent = [], [], []
+        declared = {name: c.count for name, c in model_counts(cfg).items()}
+        # a count's values a layer, or their running sum where they add up
+        kept = {name: [] if count.stacked
+                else [jnp.zeros(count.shape(cfg), count.dtype)]
+                for name, count in declared.items()}
         if cfg.hc_streams > 1:
             # every lane starts as the token's row
             x = jnp.broadcast_to(
                 x[:, :, None, :], x.shape[:2] + (cfg.hc_streams, x.shape[-1]))
         for block in self.blocks:
-            x, (layer_sizes, layer_dropped, layer_tiles, layer_kept,
-                layer_walked, *layer_latent) = block(x)
-            if has_experts:
-                sizes.append(layer_sizes)
-                tiles.append(layer_tiles)
-                dropped = dropped + layer_dropped
-            kept.append(layer_kept)
-            walked.append(layer_walked)
-            latent.extend(layer_latent)
+            x, counts = block(x)
+            for name, count in declared.items():
+                # zeros from a layer of another kind than the one counting
+                value = counts[name] if name in counts else jnp.zeros(
+                    count.shape(cfg), count.dtype)
+                kept[name] = (kept[name] + [value] if count.stacked
+                              else [kept[name][0] + value])
         if cfg.hc_streams > 1:
             with jax.named_scope("hyper_conn"):  # the lanes leave as one
                 x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
         x = rms_norm(x, self.ln_final, cfg.rms_norm_eps)
-        stats = (RoutingStats(jnp.stack(sizes), dropped, jnp.stack(tiles))
-                 if has_experts else RoutingStats(None, None, None))
-        if any(k is not None for k in kept):
-            keys, rows = zip(*(zero if k is None else k for k in kept))
-            stats = stats._replace(keys_kept=jnp.stack(keys),
-                                   query_rows=jnp.stack(rows))
-        if any(w is not None for w in walked):
-            chunks, positions = zip(*((zero[1], zero[1]) if w is None else w
-                                      for w in walked))
-            stats = stats._replace(ssd_chunks=jnp.stack(chunks),
-                                   ssd_positions=jnp.stack(positions))
-        if latent:
-            load, absmax, sum_error, pairs = (
-                jnp.stack(v) for v in zip(*latent))
-            stats = stats._replace(
-                router_load=load if has_experts else None,
-                router_bias_absmax=absmax if has_experts else None,
-                hc_sum_error=sum_error if cfg.hc_streams > 1 else None,
-                latent_pairs=pairs)
-        return x, stats
+        return x, {name: jnp.stack(values) if declared[name].stacked
+                   else values[0] for name, values in kept.items()}
 
     def __call__(self, ids: jax.Array, *, deterministic: bool = True
                  ) -> jax.Array:
@@ -482,48 +866,15 @@ class MoEDecoder(nn.Module):
         return logits
 
 
-def check_decoder_config(cfg: ModelConfig) -> None:
-    """Refuse a decoder configuration that leaves a size unset or
-    inconsistent, naming the field."""
+def _feed_forward_rules(cfg: ModelConfig, latent: bool) -> list:
     first, count = cfg.experts_held
-    sparse = SPARSE_LAYOUT in cfg.layer_layout
-    state_space = SSM_LAYOUT in cfg.layer_layout
     dense = cfg.moe_experts == 0
-    latent = LATENT_LAYOUT in cfg.layer_layout
-    lanes = cfg.hc_streams > 1
     sigmoid = cfg.moe_scoring == "sigmoid"
-    why_ssm = " (layer_layout has a state-space layer)"
-    why_latent = " (layer_layout has a latent-attention layer)"
     only_latent = " (a latent-attention model's)"
-    problems = [name for name, ok in (
-        ("vocab_size", cfg.vocab_size > 0),
-        ("head_dim", latent or cfg.head_dim > 0),
-        ("n_kv_heads (must divide n_heads)", latent or (
-            cfg.n_kv_heads > 0 and cfg.n_heads % cfg.n_kv_heads == 0)),
-        ("layer_layout (one of 0/1/2/3 per layer, or 4 in every layer)",
-         len(cfg.layer_layout) > 0
-         and (all(v == LATENT_LAYOUT for v in cfg.layer_layout) if latent
-              else all(v in (0, 1, SPARSE_LAYOUT, SSM_LAYOUT)
-                       for v in cfg.layer_layout))),
+    return [
         ("ffn_size (moe_experts is 0 or first_dense_layers is not: a "
          "dense gated MLP)",
          not (dense or cfg.first_dense_layers > 0) or cfg.ffn_size > 0),
-        ("q_lora_rank" + why_latent, not latent or cfg.q_lora_rank > 0),
-        ("kv_lora_rank" + why_latent, not latent or cfg.kv_lora_rank > 0),
-        ("qk_nope_head_dim" + why_latent,
-         not latent or cfg.qk_nope_head_dim > 0),
-        ("qk_rope_head_dim (even, for rotary)" + why_latent,
-         not latent or (cfg.qk_rope_head_dim > 0
-                        and cfg.qk_rope_head_dim % 2 == 0)),
-        ("v_head_dim" + why_latent, not latent or cfg.v_head_dim > 0),
-        ("rope_factor (at least 1) / rope_original_max (positive where "
-         "the factor stretches)",
-         cfg.rope_factor >= 1 and (cfg.rope_factor == 1
-                                   or cfg.rope_original_max > 0)),
-        ("attention_multiplier / residual_multiplier (a latent-attention "
-         "layer states its own score scale and joins the stream unscaled)",
-         not latent or (cfg.attention_multiplier is None
-                        and cfg.residual_multiplier == 1.0)),
         ("first_dense_layers (0 .. the depth; more than 0 with experts"
          + only_latent + ")",
          0 <= cfg.first_dense_layers <= len(cfg.layer_layout)
@@ -538,47 +889,56 @@ def check_decoder_config(cfg: ModelConfig) -> None:
          and (cfg.moe_routed_scaling == 1.0 or sigmoid)),
         ("moe_bias_rate (0, or positive with sigmoid scores)",
          cfg.moe_bias_rate == 0 or (cfg.moe_bias_rate > 0 and sigmoid)),
-        ("hc_streams (1, or more lanes" + only_latent + ")",
-         cfg.hc_streams == 1 or (lanes and latent)),
-        ("hc_sinkhorn_iters (hc_streams is more than 1)",
-         not lanes or cfg.hc_sinkhorn_iters > 0),
-        ("hc_eps / hc_res_clamp (positive; hc_streams is more than 1)",
-         not lanes or (cfg.hc_eps > 0 and cfg.hc_res_clamp > 0)),
         ("moe_experts / moe_top_k",
          dense or 0 < cfg.moe_top_k <= cfg.moe_experts),
         ("moe_ffn_size", dense or cfg.moe_ffn_size > 0),
         ("experts_held (first, count) inside moe_experts",
          dense or (count > 0 and first >= 0
                    and first + count <= cfg.moe_experts)),
-        ("ssm_heads" + why_ssm, not state_space or cfg.ssm_heads > 0),
-        ("ssm_head_dim" + why_ssm, not state_space or cfg.ssm_head_dim > 0),
-        ("ssm_state" + why_ssm, not state_space or cfg.ssm_state > 0),
-        ("ssm_conv" + why_ssm, not state_space or cfg.ssm_conv > 0),
-        ("ssm_chunk" + why_ssm, not state_space or cfg.ssm_chunk > 0),
-        ("embedding_multiplier / residual_multiplier / logits_scaling "
-         "(not zero)",
-         cfg.embedding_multiplier != 0 and cfg.residual_multiplier != 0
-         and cfg.logits_scaling != 0),
+        ("hidden_act (one of %s)" % "/".join(sorted(ACTIVATIONS)),
+         cfg.hidden_act in ACTIVATIONS)]
+
+
+def check_decoder_config(cfg: ModelConfig) -> None:
+    """Refuse a decoder configuration that leaves a size unset or
+    inconsistent, naming the field: the rows every model answers (the
+    residual's among them), those of each kind of layer it has
+    (:data:`KINDS`) and the feed-forward's."""
+    latent = LATENT_LAYOUT in cfg.layer_layout
+    lanes = cfg.hc_streams > 1
+    present = [KINDS[v] for v in sorted(set(cfg.layer_layout)) if v in KINDS]
+    rules = [
+        ("vocab_size", cfg.vocab_size > 0),
+        # a latent-attention layer states its heads' widths itself
+        ("head_dim", latent or cfg.head_dim > 0),
+        ("n_kv_heads (must divide n_heads)", latent or (
+            cfg.n_kv_heads > 0 and cfg.n_heads % cfg.n_kv_heads == 0)),
+        ("layer_layout (one of 0/1/2/3 per layer, or 4 in every layer)",
+         len(cfg.layer_layout) > 0
+         and all(v in KINDS for v in cfg.layer_layout)
+         and (not latent
+              or all(v == LATENT_LAYOUT for v in cfg.layer_layout))),
+        ("rope_factor (at least 1) / rope_original_max (positive where "
+         "the factor stretches)",
+         cfg.rope_factor >= 1 and (cfg.rope_factor == 1
+                                   or cfg.rope_original_max > 0)),
         ("attention_multiplier (None or positive)",
          cfg.attention_multiplier is None or cfg.attention_multiplier > 0),
         ("head_dim (even, for rotary)", cfg.head_dim % 2 == 0),
         ("sliding_window", cfg.sliding_window > 0),
-        ("hidden_act (one of %s)" % "/".join(sorted(ACTIVATIONS)),
-         cfg.hidden_act in ACTIVATIONS),
-        ("indexer_topk (layer_layout has a learned-sparse layer)",
-         not sparse or cfg.indexer_topk > 0),
-        ("indexer_heads (layer_layout has a learned-sparse layer)",
-         not sparse or cfg.indexer_heads > 0),
-        ("indexer_head_dim (even, for rotary; layer_layout has a "
-         "learned-sparse layer)",
-         not sparse or (cfg.indexer_head_dim > 0
-                        and cfg.indexer_head_dim % 2 == 0)),
-        ("n_heads / n_kv_heads / head_dim (a learned-sparse layer's "
-         "kernels take a group that divides 128 and heads of at most 512)",
-         not (sparse and cfg.use_pallas and cfg.n_kv_heads > 0)
-         or (128 % max(cfg.n_heads // cfg.n_kv_heads, 1) == 0
-             and cfg.head_dim <= 512)),
-    ) if not ok]
+        ("hc_streams (1, or more lanes (a latent-attention model's))",
+         cfg.hc_streams == 1 or (lanes and latent)),
+        ("hc_sinkhorn_iters (hc_streams is more than 1)",
+         not lanes or cfg.hc_sinkhorn_iters > 0),
+        ("hc_eps / hc_res_clamp (positive; hc_streams is more than 1)",
+         not lanes or (cfg.hc_eps > 0 and cfg.hc_res_clamp > 0)),
+        ("embedding_multiplier / residual_multiplier / logits_scaling "
+         "(not zero)",
+         cfg.embedding_multiplier != 0 and cfg.residual_multiplier != 0
+         and cfg.logits_scaling != 0),
+    ] + [rule for kind in present for rule in kind.rules(cfg)
+         ] + _feed_forward_rules(cfg, latent)
+    problems = list(dict.fromkeys(name for name, ok in rules if not ok))
     if problems:
         raise ValueError(
             "ModelConfig(cell='decoder') needs: " + "; ".join(problems))

@@ -44,8 +44,7 @@ import numpy as np
 from fmda_tpu.config import ModelConfig, TrainConfig
 from fmda_tpu.data.pipeline import (
     Batch, ChunkDataset, TokenBatches, TokenDataset, WindowBatches)
-from fmda_tpu.models.decoder import (
-    LATENT_LAYOUT, SPARSE_LAYOUT, SSM_LAYOUT)
+from fmda_tpu.models.decoder import COUNTS, model_counts
 from fmda_tpu.ops.metrics import multilabel_metrics
 from fmda_tpu.train.losses import (
     chunked_next_token_loss, weighted_bce_sums, weighted_bce_with_logits)
@@ -76,45 +75,47 @@ class StepTotals(NamedTuple):
 
 
 class TokenTotals(NamedTuple):
-    """A token pass's running sums: each step's mean loss, the tokens
-    that counted and those predicted right, and what the expert layers
-    counted (``expert_pairs[l, e]``: pairs layer ``l`` computed on held
-    expert ``e``; ``row_tiles_used[l]``: row tiles of its layout that
-    held a group, the ones its row passes visit), None in a model
-    without experts."""
+    """A token pass's running totals: each step's mean loss, the tokens
+    that counted and those predicted right, and what the layers counted,
+    an attribute a name of :data:`fmda_tpu.models.decoder.COUNTS` (which
+    says what each is), None where the model does not count it: the
+    expert layers' three, a learned-sparse selection's two, a state-space
+    scan's two, a latent-attention model's four (two of them folded by
+    ``max``, not by sum: :data:`FOLDED_BY_MAX`)."""
 
     loss: jax.Array          # () float32
     tokens: jax.Array        # () int32
     correct: jax.Array       # () int32
-    expert_pairs: Optional[jax.Array]  # (layers, held experts) int32
-    dropped: Optional[jax.Array]       # () int32
-    row_tiles_used: Optional[jax.Array]  # (layers,) int32
-    #: The learned-sparse layers' selection (None in a model without
-    #: one): keys kept as (layers, 2) int32 ``[count >> 16, count &
-    #: 0xffff]`` (:func:`keys_kept_counts` joins them), and the query
-    #: rows they were kept for, (layers,) int32.
-    sparse_keys_kept: Optional[jax.Array] = None
-    sparse_query_rows: Optional[jax.Array] = None
-    #: The state-space layers' scans (None in a model without one): the
-    #: chunks and the positions each walked, (layers,) int32.
-    ssd_chunks: Optional[jax.Array] = None
-    ssd_positions: Optional[jax.Array] = None
-    #: The latent-attention layers (None in a model without one; models/
-    #: decoder.py ``RoutingStats``): pairs on each of all the router's
-    #: experts, (layers, moe_experts) int32; the causal pairs the cores
-    #: scored, (layers,) int32; and two that a pass folds by ``max``, not
-    #: by sum (:data:`FOLDED_BY_MAX`): the largest size of a selection
-    #: bias and the largest distance of a residual mixing matrix's row or
-    #: column sum from one, (layers,) float32.
-    router_load: Optional[jax.Array] = None
-    latent_pairs: Optional[jax.Array] = None
-    router_bias_absmax: Optional[jax.Array] = None
-    hc_sum_error: Optional[jax.Array] = None
+    expert_pairs: Optional[jax.Array] = None  # (layers, held experts) int32
+    dropped: Optional[jax.Array] = None       # () int32
+    row_tiles_used: Optional[jax.Array] = None  # (layers,) int32
+    sparse_keys_kept: Optional[jax.Array] = None   # (layers, 2) int32
+    sparse_query_rows: Optional[jax.Array] = None  # (layers,) int32
+    ssd_chunks: Optional[jax.Array] = None     # (layers,) int32
+    ssd_positions: Optional[jax.Array] = None  # (layers,) int32
+    router_load: Optional[jax.Array] = None    # (layers, moe_experts) int32
+    latent_pairs: Optional[jax.Array] = None   # (layers,) int32
+    router_bias_absmax: Optional[jax.Array] = None  # (layers,) float32
+    hc_sum_error: Optional[jax.Array] = None        # (layers,) float32
 
 
 #: The :class:`TokenTotals` fields whose pass value is the largest of the
 #: steps' values.
-FOLDED_BY_MAX = ("router_bias_absmax", "hc_sum_error")
+FOLDED_BY_MAX = tuple(
+    name for name, count in COUNTS.items() if count.fold == "max")
+
+
+#: A count published as it stands, and its series: a counter, or a gauge
+#: where a pass folds it by ``max``.
+PUBLISHED = {
+    "row_tiles_used": "moe_row_tiles_used_total",
+    "sparse_query_rows": "sparse_query_rows_total",
+    "ssd_chunks": "ssd_chunks_total",
+    "ssd_positions": "ssd_positions_total",
+    "latent_pairs": "attention_latent_pairs_total",
+    "hc_sum_error": "hc_res_sum_error_max",
+    "router_bias_absmax": "moe_router_bias_absmax",
+}
 
 
 def keys_kept_counts(sparse_keys_kept) -> list:
@@ -254,33 +255,10 @@ class NextToken:
         return None
 
     def zero_totals(self) -> TokenTotals:
-        mc = self.model_cfg
         zero = np.zeros((), np.int32)
-        layers = len(mc.layer_layout)
-        per_layer = np.zeros((layers,), np.int32)
-        totals = TokenTotals(
-            np.zeros((), np.float32), zero, zero, None, None, None)
-        if mc.moe_experts:
-            totals = totals._replace(
-                expert_pairs=np.zeros((layers, mc.experts_held[1]), np.int32),
-                dropped=zero, row_tiles_used=per_layer)
-        if SPARSE_LAYOUT in mc.layer_layout:
-            totals = totals._replace(
-                sparse_keys_kept=np.zeros((layers, 2), np.int32),
-                sparse_query_rows=per_layer)
-        if SSM_LAYOUT in mc.layer_layout:
-            totals = totals._replace(ssd_chunks=per_layer,
-                                     ssd_positions=per_layer)
-        if LATENT_LAYOUT in mc.layer_layout:
-            gauge = np.zeros((layers,), np.float32)
-            totals = totals._replace(latent_pairs=per_layer)
-            if mc.moe_experts:
-                totals = totals._replace(
-                    router_load=np.zeros((layers, mc.moe_experts), np.int32),
-                    router_bias_absmax=gauge)
-            if mc.hc_streams > 1:
-                totals = totals._replace(hc_sum_error=gauge)
-        return totals
+        return TokenTotals(np.zeros((), np.float32), zero, zero, **{
+            name: np.zeros(c.shape, c.count.dtype)
+            for name, c in model_counts(self.model_cfg).items()})
 
     def epoch_metrics(self, totals: Optional[TokenTotals], steps: int
                       ) -> Tuple[EpochMetrics, np.ndarray]:
@@ -294,98 +272,60 @@ class NextToken:
             hamming=1.0 - accuracy, fbeta=np.zeros(0)), confusion
 
     def publish(self, totals: TokenTotals, phase: str, steps: int) -> None:
-        """The pass's token, routing and scan counts, from the drained
-        totals of its ``steps`` steps (docs/observability.md "Spans and
-        scopes").  A model without experts, learned-sparse layers or
-        state-space layers publishes none of that group's counters."""
+        """The pass's token count and what :func:`model_counts` declares
+        its layers count, from the drained totals of its ``steps`` steps,
+        a series a layer (docs/observability.md "Spans and scopes")."""
         from fmda_tpu.obs.registry import default_registry
-
-        reg = default_registry()
-        if phase == "train":
-            reg.counter("train_tokens_total").inc(int(totals.tokens))
-        if totals.expert_pairs is not None:
-            self._publish_routing(reg, totals, phase, steps)
-        if totals.sparse_keys_kept is not None:
-            self._publish_selection(reg, totals, phase)
-        if totals.ssd_chunks is not None:
-            self._publish_scans(reg, totals, phase)
-        if totals.latent_pairs is not None:
-            self._publish_latent(reg, totals, phase)
-
-    def _publish_routing(self, reg, totals: TokenTotals, phase: str,
-                         steps: int) -> None:
         from fmda_tpu.ops.moe import layout_tiles
 
-        tc, mc = self.train_cfg, self.model_cfg
-        # a forward pass lays its tokens out once a layer: the whole
-        # batch, or each microbatch of an accumulated train step
-        passes = tc.accum_steps if phase == "train" else 1
-        layout = steps * passes * layout_tiles(
-            tc.batch_size // passes * tc.window * mc.moe_top_k,
-            mc.experts_held[1])
-        reg.counter("moe_pairs_dropped_total").inc(int(totals.dropped))
-        for layer, pairs in enumerate(np.asarray(totals.expert_pairs)):
-            if layer < mc.first_dense_layers:
-                continue  # a dense layer of a model with experts
-            labels = dict(layer=str(layer), phase=phase)
+        reg, tc, mc = default_registry(), self.train_cfg, self.model_cfg
+        declared = model_counts(mc)
+        if phase == "train":
+            reg.counter("train_tokens_total").inc(int(totals.tokens))
+
+        def by_layer(name):
+            """``(labels, value)`` of each layer that counts ``name``."""
+            if name not in declared:
+                return []
+            values = np.asarray(getattr(totals, name))
+            return [(dict(layer=str(layer), phase=phase), values[layer])
+                    for layer in declared[name].layers]
+
+        for name, metric in PUBLISHED.items():
+            for labels, value in by_layer(name):
+                if name in FOLDED_BY_MAX:
+                    reg.gauge(metric, **labels).set(float(value))
+                else:
+                    reg.counter(metric, **labels).inc(int(value))
+        if "expert_pairs" in declared:
+            reg.counter("moe_pairs_dropped_total").inc(int(totals.dropped))
+            # a forward pass lays its tokens out once a layer: the whole
+            # batch, or each microbatch of an accumulated train step
+            passes = tc.accum_steps if phase == "train" else 1
+            layout = steps * passes * layout_tiles(
+                tc.batch_size // passes * tc.window * mc.moe_top_k,
+                mc.experts_held[1])
+        for labels, pairs in by_layer("expert_pairs"):
             reg.counter("moe_pairs_held_total", **labels).inc(
                 int(pairs.sum()))
-            reg.gauge("moe_expert_pairs_max", **labels).set(
-                int(pairs.max()))
-            # used / layout: the share of the row layout the layer's row
-            # passes touched (1.0: every tile, as if they were unbounded)
-            reg.counter("moe_row_tiles_used_total", **labels).inc(
-                int(totals.row_tiles_used[layer]))
+            reg.gauge("moe_expert_pairs_max", **labels).set(int(pairs.max()))
+            # moe_row_tiles_used_total / this: the share of the row layout
+            # the layer's row passes touched (1.0: every tile, as if they
+            # were unbounded)
             reg.counter("moe_row_tiles_layout_total", **labels).inc(layout)
-
-    def _publish_selection(self, reg, totals: TokenTotals, phase: str
-                           ) -> None:
         # a learned-sparse layer's selection: kept / (the rows' causal
         # pairs) is the share of the triangle the heads attend over
-        kept = keys_kept_counts(totals.sparse_keys_kept)
-        for layer, layout in enumerate(self.model_cfg.layer_layout):
-            if layout != SPARSE_LAYOUT:
-                continue
-            labels = dict(layer=str(layer), phase=phase)
-            reg.counter("sparse_keys_kept_total", **labels).inc(kept[layer])
-            reg.counter("sparse_query_rows_total", **labels).inc(
-                int(totals.sparse_query_rows[layer]))
-
-    def _publish_scans(self, reg, totals: TokenTotals, phase: str) -> None:
-        # what the state-space layers' scans walked, and the carried
-        # states one sequence leaves (a matrix a head and chunk, float32)
-        mc, tc = self.model_cfg, self.train_cfg
-        reg.gauge("ssd_state_bytes").set(
-            -(-tc.window // mc.ssm_chunk) * mc.ssm_heads * mc.ssm_head_dim
-            * mc.ssm_state * 4)
-        for layer, layout in enumerate(mc.layer_layout):
-            if layout != SSM_LAYOUT:
-                continue
-            labels = dict(layer=str(layer), phase=phase)
-            reg.counter("ssd_chunks_total", **labels).inc(
-                int(totals.ssd_chunks[layer]))
-            reg.counter("ssd_positions_total", **labels).inc(
-                int(totals.ssd_positions[layer]))
-
-    def _publish_latent(self, reg, totals: TokenTotals, phase: str) -> None:
-        # the latent-attention layers: the causal pairs scored; the load
-        # on ALL the router's experts (what the selection bias steps on)
-        # and the bias's size; how far a residual mixing matrix's sums
-        # strayed from one
-        mc = self.model_cfg
-        for layer in range(len(mc.layer_layout)):
-            labels = dict(layer=str(layer), phase=phase)
-            reg.counter("attention_latent_pairs_total", **labels).inc(
-                int(totals.latent_pairs[layer]))
-            if totals.hc_sum_error is not None:
-                reg.gauge("hc_res_sum_error_max", **labels).set(
-                    float(totals.hc_sum_error[layer]))
-            if totals.router_load is None or layer < mc.first_dense_layers:
-                continue
-            reg.gauge("moe_router_bias_absmax", **labels).set(
-                float(totals.router_bias_absmax[layer]))
-            for expert, pairs in enumerate(
-                    np.asarray(totals.router_load[layer])):
+        for labels, halves in by_layer("sparse_keys_kept"):
+            reg.counter("sparse_keys_kept_total", **labels).inc(
+                keys_kept_counts([halves])[0])
+        if "ssd_chunks" in declared:
+            # the carried states one sequence leaves (a matrix a head and
+            # chunk, float32)
+            reg.gauge("ssd_state_bytes").set(
+                -(-tc.window // mc.ssm_chunk) * mc.ssm_heads
+                * mc.ssm_head_dim * mc.ssm_state * 4)
+        for labels, load in by_layer("router_load"):
+            for expert, pairs in enumerate(load):
                 reg.counter("moe_router_load_total", expert=str(expert),
                             **labels).inc(int(pairs))
 
@@ -405,12 +345,11 @@ class NextToken:
         of what it does not move: each expert layer's selection bias goes
         up by ``moe_bias_rate`` where the step sent its expert fewer pairs
         than the mean over all experts, and down where more."""
-        stats = aux[2]
-        if stats.router_load is None or not self.model_cfg.moe_bias_rate:
+        counts, rate = aux[2], self.model_cfg.moe_bias_rate
+        if "router_load" not in counts or not rate:
             return params
-        rate = self.model_cfg.moe_bias_rate
         params = dict(params)
-        for layer, load in enumerate(stats.router_load):
+        for layer, load in enumerate(counts["router_load"]):
             block = params.get(f"block_{layer}", {})
             if "router_bias" not in block:
                 continue
@@ -442,23 +381,16 @@ class NextToken:
 
     def merge_micro(self, aux_k):
         """Counts add over the microbatches; the largest of what a pass
-        folds by ``max`` (:data:`FOLDED_BY_MAX`, under the layers' own
-        names in the routing statistics)."""
-        tokens, correct, stats = jax.tree.map(
+        folds by ``max`` (:data:`FOLDED_BY_MAX`)."""
+        tokens, correct, counts = jax.tree.map(
             lambda a: jnp.sum(a, axis=0), aux_k)
-        worst = {name: jnp.max(getattr(aux_k[2], name), axis=0)
-                 for name in FOLDED_BY_MAX
-                 if getattr(stats, name) is not None}
-        return tokens, correct, stats._replace(**worst)
+        worst = {name: jnp.max(aux_k[2][name], axis=0)
+                 for name in FOLDED_BY_MAX if name in counts}
+        return tokens, correct, {**counts, **worst}
 
     def step_values(self, loss, aux, batch: Batch) -> TokenTotals:
-        tokens, correct, stats = aux
-        return TokenTotals(loss, tokens, correct, stats.expert_pairs,
-                           stats.dropped, stats.row_tiles_used,
-                           stats.keys_kept, stats.query_rows,
-                           stats.ssd_chunks, stats.ssd_positions,
-                           stats.router_load, stats.latent_pairs,
-                           stats.router_bias_absmax, stats.hc_sum_error)
+        tokens, correct, counts = aux
+        return TokenTotals(loss, tokens, correct, **counts)
 
 
 def task_class(model_cfg: ModelConfig):

@@ -120,8 +120,8 @@ def test_expert_pair_counts_match_the_reference_routing():
             {"params": p}, x, method="features"))(params, x)
     _, want = jax.jit(lambda p, ids: ref.hidden_states(p, ids, cfg))(
         params, x[0])
-    np.testing.assert_array_equal(stats.expert_pairs, want)
-    assert int(stats.dropped) == 0
+    np.testing.assert_array_equal(stats["expert_pairs"], want)
+    assert int(stats["dropped"]) == 0
 
 
 def test_window_layers_agree_inside_the_window_and_differ_past_it():
@@ -269,6 +269,14 @@ PINNED = {
 }
 
 
+def _tiny(kind, **over):
+    return ModelConfig(**{**dict(
+        cell="decoder", hidden_size=32, n_heads=4, n_kv_heads=2, head_dim=8,
+        vocab_size=64, layer_layout=(0, 1), sliding_window=8,
+        loss_chunk=16, dtype="bfloat16", remat=True),
+        **PINNED_KINDS[kind], **over})
+
+
 def _sha(text: str) -> str:
     import hashlib
 
@@ -282,16 +290,12 @@ def _program_pins(kind):
     from fmda_tpu.train.trainer import Trainer
 
     seq, vocab = 32, 64
-    mc = ModelConfig(**{**dict(
-        cell="decoder", hidden_size=32, n_heads=4, n_kv_heads=2, head_dim=8,
-        vocab_size=vocab, layer_layout=(0, 1), sliding_window=8,
-        loss_chunk=16, dtype="bfloat16", remat=True), **PINNED_KINDS[kind]})
     tc = TrainConfig(batch_size=2, window=seq, chunk_size=2 * seq,
                      learning_rate=1e-2, clip=1.0, val_size=0.1,
                      test_size=0.1, cache_chunks=16, seed=0)
     rng = np.random.default_rng(0)
     ids = np.minimum(rng.zipf(1.3, size=21 * seq + 1) - 1, vocab - 1)
-    trainer = Trainer(mc, tc)
+    trainer = Trainer(_tiny(kind), tc)
     dataset = trainer.task.dataset(TokenArraySource(ids, vocab))
     state = trainer.init_state(jax.random.PRNGKey(0))
     batch = next(iter(trainer._chunk_batches(dataset, 0)))
@@ -319,3 +323,118 @@ def test_the_accepted_configurations_steps_are_the_parents(
 
     monkeypatch.setattr(trainer_module, "SOLO_STEP_BYTES", 1)
     assert _program_pins(kind) == PINNED[kind]
+
+
+# -- the seam: one declaration of what the layers count ----------------------
+
+
+@pytest.mark.parametrize("kind", sorted(PINNED_KINDS))
+def test_zero_totals_is_the_tree_a_step_returns(kind):
+    """``zero_totals`` makes its shapes from the configuration; a traced
+    step makes them from the model: the same tree, leaf for leaf."""
+    cfg = _tiny(kind)
+    task = NextToken(cfg, TrainConfig(batch_size=2, window=32))
+    model = build_model(cfg)
+    ids = jnp.zeros((2, 32), jnp.int32)
+    batch = Batch(ids, ids, jnp.ones((2, 32), jnp.float32))
+
+    def one_step(key):
+        params = model.init({"params": key}, ids[:1, :8])["params"]
+        loss, aux = task.loss(
+            params, task.forward(model, params, batch, None), batch)
+        return task.step_values(loss, aux, batch)
+
+    stepped = jax.eval_shape(one_step, jax.random.PRNGKey(0))
+    zeros = task.zero_totals()
+    assert jax.tree.structure(stepped) == jax.tree.structure(zeros)
+    for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(stepped),
+            jax.tree.leaves(zeros)):
+        assert (got.shape, got.dtype) == (want.shape, want.dtype), path
+
+
+@pytest.mark.parametrize("kind", sorted(PINNED_KINDS))
+def test_the_totals_hold_what_the_declaration_lists_and_nothing_else(kind):
+    from fmda_tpu.models.decoder import COUNTS, model_counts
+
+    cfg = _tiny(kind)
+    totals = NextToken(
+        cfg, TrainConfig(batch_size=2, window=32)).zero_totals()
+    declared = model_counts(cfg)
+    assert set(declared) <= set(COUNTS) <= set(totals._fields)
+    for name in COUNTS:
+        assert (getattr(totals, name) is not None) == (name in declared), name
+    want = {
+        "routed": ["expert_pairs", "dropped", "row_tiles_used"],
+        "learned_sparse": ["expert_pairs", "dropped", "row_tiles_used",
+                           "sparse_keys_kept", "sparse_query_rows"],
+        "hybrid": ["ssd_chunks", "ssd_positions"],
+        "latent": ["expert_pairs", "dropped", "row_tiles_used",
+                   "router_load", "router_bias_absmax", "hc_sum_error",
+                   "latent_pairs"],
+        "latent_plain": ["expert_pairs", "dropped", "row_tiles_used",
+                         "router_load", "router_bias_absmax",
+                         "latent_pairs"]}[kind]
+    assert list(declared) == want  # the order features stacks them in
+    # which layers count: the dense first layer of a latent model has no
+    # experts; a state-space count comes from the state-space layers
+    if kind.startswith("latent"):
+        assert declared["expert_pairs"].layers == (1, 2)
+        assert declared["latent_pairs"].layers == (0, 1, 2)
+    if kind == "hybrid":
+        assert declared["ssd_chunks"].layers == (0, 2)
+
+
+@pytest.mark.parametrize("through", ["fold", "merge_micro"])
+def test_a_count_folds_as_it_is_declared(through):
+    """Every count of a latent model (it has both folds) through the
+    pass's fold and the microbatches' merge: ``max`` where declared,
+    sums elsewhere."""
+    from fmda_tpu.models.decoder import model_counts
+    from fmda_tpu.train.tasks import FOLDED_BY_MAX, TokenTotals
+
+    cfg = _tiny("latent")
+    task = NextToken(cfg, TrainConfig(batch_size=2, window=32))
+    declared = model_counts(cfg)
+    assert set(FOLDED_BY_MAX) == {"router_bias_absmax", "hc_sum_error"}
+    rng = np.random.default_rng(0)
+    a, b = ({name: jnp.asarray(rng.integers(1, 9, c.shape), c.count.dtype)
+             for name, c in declared.items()} for _ in range(2))
+    if through == "fold":
+        one = jnp.ones((), jnp.int32)
+        got = task.fold(TokenTotals(jnp.float32(1.0), one, one, **a),
+                        TokenTotals(jnp.float32(2.0), one, one, **b))
+        assert float(got.loss) == 3.0 and int(got.tokens) == 2
+        got = {name: getattr(got, name) for name in declared}
+    else:
+        two = jnp.ones((2,), jnp.int32)
+        stacked = {name: jnp.stack([a[name], b[name]]) for name in declared}
+        tokens, _, got = task.merge_micro((two, two, stacked))
+        assert int(tokens) == 2
+    for name, c in declared.items():
+        want = (jnp.maximum if c.count.fold == "max" else jnp.add)(
+            a[name], b[name])
+        np.testing.assert_array_equal(got[name], want, err_msg=name)
+        assert (c.count.fold == "max") == (name in FOLDED_BY_MAX)
+
+
+@pytest.mark.parametrize("kind,over,message", [
+    ("routed", dict(moe_ffn_size=0), "moe_ffn_size"),
+    ("learned_sparse", dict(indexer_topk=0),
+     "indexer_topk (layer_layout has a learned-sparse layer)"),
+    ("hybrid", dict(ssm_state=0),
+     "ssm_state (layer_layout has a state-space layer)"),
+    ("latent", dict(kv_lora_rank=0),
+     "kv_lora_rank (layer_layout has a latent-attention layer)"),
+    ("latent_plain", dict(hc_streams=4, hc_sinkhorn_iters=0),
+     "hc_sinkhorn_iters (hc_streams is more than 1)"),
+    ("routed", dict(hc_streams=2),
+     "hc_streams (1, or more lanes (a latent-attention model's))"),
+    ("latent", dict(layer_layout=(4, 4, 0)),
+     "layer_layout (one of 0/1/2/3 per layer, or 4 in every layer)"),
+])
+def test_one_missing_field_is_refused_in_the_parents_words(
+        kind, over, message):
+    with pytest.raises(ValueError) as err:
+        check_decoder_config(_tiny(kind, **over))
+    assert str(err.value) == "ModelConfig(cell='decoder') needs: " + message

@@ -194,7 +194,7 @@ def test_a_recomputed_block_on_the_kernels_is_the_block_under_autodiff(
     mixing left to autodiff: the output's gradient to the stream and to
     every parameter."""
     from fmda_tpu.config import ModelConfig
-    from fmda_tpu.models import latent_block
+    from fmda_tpu.models import decoder
 
     cfg = ModelConfig(
         cell="decoder", hidden_size=HIDDEN, n_heads=2, vocab_size=64,
@@ -205,14 +205,14 @@ def test_a_recomputed_block_on_the_kernels_is_the_block_under_autodiff(
     rng = np.random.default_rng(1)
     lanes = jnp.asarray(rng.normal(size=(1, 128, 4, HIDDEN)), jnp.float32)
     weight = jnp.asarray(rng.normal(size=lanes.shape), jnp.float32)
-    block = nn.remat(latent_block.LatentBlock)(cfg, True)
+    block = nn.remat(decoder.DecoderBlock)(cfg, 4, True)
     params = block.init({"params": jax.random.PRNGKey(0)}, lanes)["params"]
     params = jax.tree.map(  # off the fresh block's saturated mixing
         lambda p: p + 0.3 * jnp.asarray(rng.normal(size=p.shape), p.dtype),
         params)
 
     def grads(impl):
-        monkeypatch.setattr(latent_block, "kernel_impl", lambda use: impl)
+        monkeypatch.setattr(decoder, "kernel_impl", lambda use: impl)
         with jax.default_matmul_precision("highest"):
             return jax.grad(lambda p, x: jnp.sum(
                 block.apply({"params": p}, x)[0] * weight),

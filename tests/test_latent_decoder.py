@@ -1,5 +1,5 @@
 """The latent-attention decoder with a hyper-connected residual stream
-(``layer_layout`` 4, models/latent_block.py) against its plain reference
+(``layer_layout`` 4, models/decoder.py) against its plain reference
 (benchmark/reference/latent_decoder.py), on the CPU at small widths and
 seeded weights: logits, loss, every leaf's gradient, one optimizer step
 and the selection bias's step; the share test; Sinkhorn's turns; a fresh
@@ -24,9 +24,9 @@ from fmda_tpu.config import ModelConfig, TrainConfig  # noqa: E402
 from fmda_tpu.data.pipeline import Batch  # noqa: E402
 from fmda_tpu.data.source import TokenArraySource  # noqa: E402
 from fmda_tpu.models import build_model  # noqa: E402
-from fmda_tpu.models.decoder import check_decoder_config  # noqa: E402
-from fmda_tpu.models.latent_block import (  # noqa: E402
-    LatentBlock, score_scale, yarn_inv_freq)
+from fmda_tpu.models.decoder import (  # noqa: E402
+    DecoderBlock, check_decoder_config, feed_forward, score_scale,
+    yarn_inv_freq)
 from fmda_tpu.ops import hyper_connection as hc  # noqa: E402
 from fmda_tpu.train.tasks import NextToken  # noqa: E402
 
@@ -188,19 +188,19 @@ def test_loss_counts_and_every_leafs_gradient_match_the_reference(remat):
         errors.append(err)
     # these parameters' mixing is far from a fresh block's: its sums are
     # off one by what 20 turns leave, the same here and there
-    np.testing.assert_allclose(stats.hc_sum_error, np.max(errors, axis=0),
+    np.testing.assert_allclose(stats["hc_sum_error"], np.max(errors, axis=0),
                                rtol=1e-3)
-    assert 1e-4 < float(stats.hc_sum_error.max()) < 2e-2
-    np.testing.assert_array_equal(stats.expert_pairs, pairs)
-    np.testing.assert_array_equal(stats.router_load, load)
-    assert stats.router_load[1].sum() == 2 * SEQ * cfg.moe_top_k
-    assert not np.asarray(stats.router_load[0]).any()  # the dense layer
-    assert int(stats.dropped) == 0
+    assert 1e-4 < float(stats["hc_sum_error"].max()) < 2e-2
+    np.testing.assert_array_equal(stats["expert_pairs"], pairs)
+    np.testing.assert_array_equal(stats["router_load"], load)
+    assert stats["router_load"][1].sum() == 2 * SEQ * cfg.moe_top_k
+    assert not np.asarray(stats["router_load"][0]).any()  # the dense layer
+    assert int(stats["dropped"]) == 0
     np.testing.assert_array_equal(
-        stats.latent_pairs, [2 * SEQ * (SEQ + 1) // 2] * 3)
+        stats["latent_pairs"], [2 * SEQ * (SEQ + 1) // 2] * 3)
     # the held experts are 2..4 of the router's eight
-    np.testing.assert_array_equal(stats.expert_pairs,
-                                  stats.router_load[:, 2:5])
+    np.testing.assert_array_equal(stats["expert_pairs"],
+                                  stats["router_load"][:, 2:5])
 
 
 @pytest.mark.parametrize("clip", [1e9, 0.05])
@@ -251,12 +251,15 @@ def test_one_step_is_the_references_adam_step_and_bias_step(clip):
                                    err_msg=name)
 
 
-class _OnlyExperts(LatentBlock):
-    """A block's expert feed-forward alone, on a normalised stream."""
+class _OnlyExperts(nn.Module):
+    """A layer's expert feed-forward alone, on a normalised stream."""
+
+    cfg: ModelConfig
 
     @nn.compact
     def __call__(self, u):
-        return self._experts(u)
+        return feed_forward(self, self.cfg, u, dense=False, counted={},
+                            load=True)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -356,18 +359,18 @@ def test_a_fresh_hyper_connection_is_nearly_the_plain_residual():
     under 1 % of theirs."""
     cfg = small_cfg(first_dense_layers=0)
     plain_cfg = small_cfg(first_dense_layers=0, hc_streams=1)
-    block, plain = LatentBlock(cfg), LatentBlock(plain_cfg)
+    block, plain = DecoderBlock(cfg, 4), DecoderBlock(plain_cfg, 4)
     rng = np.random.default_rng(2)
     lanes = jnp.asarray(rng.normal(size=(1, SEQ, 4, 32)), jnp.float32)
     params = block.init({"params": jax.random.PRNGKey(0)}, lanes)["params"]
     shared = {k: v for k, v in params.items() if not k.startswith("hc_")}
     with jax.default_matmul_precision("highest"):
-        got, (_, _, _, _, _, stats) = block.apply({"params": params}, lanes)
+        got, counts = block.apply({"params": params}, lanes)
         want, _ = plain.apply({"params": shared}, lanes[:, :, 0])
     norm = lambda a: float(jnp.linalg.norm(a))
     assert norm(got[:, :, 0] - want) < 0.015 * norm(want)
     assert norm(got[:, :, 1:] - lanes[:, :, 1:]) < 0.01 * norm(lanes[:, :, 1:])
-    assert float(stats.hc_sum_error) < 1e-5
+    assert float(counts["hc_sum_error"]) < 1e-5
 
 
 def test_yarn_stretches_the_slow_dims_and_keeps_the_fast_ones():

@@ -99,8 +99,8 @@ def test_the_selection_is_the_references_and_counted():
         assert dist.program_only.tolist() == dist.reference_only.tolist() \
             == [0, 0]
     _, stats = model.apply({"params": params}, x, method="features")
-    assert keys_kept_counts(stats.keys_kept) == [2 * KEPT] * 2
-    assert stats.query_rows.tolist() == [2 * SEQ] * 2
+    assert keys_kept_counts(stats["sparse_keys_kept"]) == [2 * KEPT] * 2
+    assert stats["sparse_query_rows"].tolist() == [2 * SEQ] * 2
 
 
 def _program_loss_and_grads(cfg, params, batch):

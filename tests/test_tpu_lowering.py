@@ -462,12 +462,12 @@ def test_a_recomputed_latent_decoder_step_runs_the_core_once_a_layer(
 
     from fmda_tpu.config import ModelConfig, TrainConfig
     from fmda_tpu.data.pipeline import Batch
-    from fmda_tpu.models import build_model, decoder, latent_block
+    from fmda_tpu.models import build_model, decoder
     from fmda_tpu.ops import attention
     from fmda_tpu.train.tasks import NextToken
 
     monkeypatch.setattr(attention, "flash_available", lambda: True)
-    monkeypatch.setattr(latent_block, "kernel_impl", lambda use: "pallas")
+    monkeypatch.setattr(decoder, "kernel_impl", lambda use: "pallas")
     if keeps == "nothing":
         monkeypatch.setattr(decoder, "REPLAY_KEEPS", ())
     t = 1024
