@@ -512,7 +512,8 @@ class ModelConfig:
     attention_multiplier: Optional[float] = None
     logits_scaling: float = 1.0
     #: ``layer_layout`` 4, latent attention (models/decoder.py): queries
-    #: through a ``q_lora_rank``-wide normalised latent, keys and values
+    #: through a ``q_lora_rank``-wide normalised latent (0: one direct
+    #: product, no latent and no norm), keys and values
     #: through a ``kv_lora_rank``-wide one; a head's query and key are
     #: ``qk_nope_head_dim`` wide without position plus ``qk_rope_head_dim``
     #: rotary dims whose key is ONE head shared by all ``n_heads``; values
@@ -550,6 +551,13 @@ class ModelConfig:
     #: step ``bias_e += moe_bias_rate * sign(mean load - load_e)`` over
     #: the step's pairs on all ``moe_experts`` (train/tasks.py).
     moe_bias_rate: float = 0.0
+    #: > 0: each expert layer adds a balance term to what training
+    #: differentiates, a sequence at a time (models/decoder.py, "The
+    #: declared loss term"): ``alpha * sum_e f_e P_e`` over all
+    #: ``moe_experts``, ``f_e`` the share of the sequence's pairs expert
+    #: ``e`` was chosen for (times ``E / K``), ``P_e`` its mean normalised
+    #: score.  0: no term, and no operation of the program changes.
+    moe_seq_aux_alpha: float = 0.0
     #: Lanes of the residual stream (ops/hyper_connection.py): 1 is the
     #: plain residual; ``n > 1`` carries ``(B, T, n, hidden)`` and wraps
     #: each sublayer in learned pre / post / residual mixing, the

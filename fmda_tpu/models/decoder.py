@@ -85,6 +85,7 @@ and ``dr`` = ``qk_rope_head_dim`` rotated dims, values ``dv`` =
 ``v_head_dim`` wide, behind two low-rank products::
 
     cq = RMSNorm(h @ wq_a)  (q_lora_rank) ;  [qn | qr] = cq @ wq_b              n x (dn | dr)
+    (q_lora_rank 0, a direct query:  [qn | qr] = h @ wq, no latent and no norm)
     [ckv | kr] = h @ wkv_a  (kv_lora_rank | dr) ;  [kn | v] = RMSNorm(ckv) @ wkv_b   n x (dn | dv)
     qr, kr rotary over dr dims at YaRN's frequencies; kr is ONE head for all n
     s[t, j] = (qn_t . kn_j + qr_t . kr_j) * (dn + dr)^-1/2 * m^2 ,  m = 0.1 ln(rope_factor) + 1
@@ -105,7 +106,33 @@ biased score and add a shared expert (``E`` = ``moe_experts``)::
 
 (``router_bias`` has no gradient; the trainer's task moves it after each
 step from the step's load over all ``E`` experts,
-:meth:`fmda_tpu.train.tasks.NextToken.after_update`).  And its residual
+:meth:`fmda_tpu.train.tasks.NextToken.after_update`).  Such an expert
+layer may add a term to what training differentiates, the first LOSS
+TERM A LAYER DECLARES (:data:`TERMS`; ``cfg.moe_seq_aux_alpha`` > 0):
+the router's balance term of each SEQUENCE of ``T`` tokens, over all
+``E`` experts, held here or not (``K`` = ``moe_top_k``)::
+
+    s'[t, e] = sc[t, e] / sum_e' sc[t, e']         P_e = mean_t s'[t, e]
+    f_e = E / (K T) * #{t : e in S_t}              (S_t as chosen; a count, no gradient)
+    L_bal = alpha * sum_e f_e P_e                  (alpha at an even router)
+    objective = mean next-token loss + mean over the step's sequences of sum over expert layers of L_bal
+
+(:func:`fmda_tpu.ops.moe.seq_balance_term`; validation and test losses
+are the next-token loss alone).  With a direct query, a plain residual,
+``first_dense_layers`` 1, two shared experts and this term the block is
+Moonlight-16B-A3B's (``x`` the stream ``(T, 2048)``)::
+
+    h  = RMSNorm(x) ;  [qn | qr] = h @ wq                  16 heads x (128 | 64)
+    [ckv | kr] = h @ wkv_a (512 | 64) ;  [kn | v] = RMSNorm(ckv) @ wkv_b      16 x (128 | 128)
+    qr, kr rotary over 64 dims, theta 50,000, no stretch; kr ONE head
+    s[t, j] = (qn_t . kn_j + qr_t . kr_j) * 192^-1/2 ;  a = causal softmax(s) v ;  x1 = x + a @ wo
+    u  = RMSNorm(x1)
+    layer 0:     x2 = x1 + (silu(u Wg) * (u Wu)) Wd                       11264 wide
+    layers 1..:  sc = sigmoid(u @ router) (64) ;  S = top-6 of (sc + router_bias)
+                 g_e = 2.446 * sc_e / sum_{e' in S} sc_e'
+                 x2 = x1 + shared(u) + sum_{e in S, held} g_e expert_e(u)    shared: 2 x 1408 wide
+
+And its residual
 may run in ``n`` = ``cfg.hc_streams`` lanes: at one lane each sublayer
 ``F`` is the plain pre-norm residual ``x + r * F(RMSNorm(x))`` every
 kind has; at ``n > 1`` it is wrapped by learned mixing
@@ -118,8 +145,9 @@ doubly stochastic)::
 Scopes (docs/observability.md "Spans and scopes"): ``attention`` holds
 the cores' and ``mla_proj`` (latent attention's products, norms and
 rotary); ``ssm_mixer`` a state-space mixer's five; ``hyper_conn`` the
-lanes' ``hc_coeff``, ``hc_pre``, ``hc_post_res``; ``moe_shared``; the
-expert layer's and the dense MLP's own.
+lanes' ``hc_coeff``, ``hc_pre``, ``hc_post_res``; ``moe_shared``;
+``moe_seq_aux`` the balance term; the expert layer's and the dense MLP's
+own.
 
 ``__call__`` returns the logits whole (small sizes, tests).  Training
 calls :meth:`MoEDecoder.features` and takes the loss over token chunks
@@ -141,7 +169,8 @@ import numpy as np
 from fmda_tpu.config import ModelConfig
 from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT, mha
 from fmda_tpu.ops.moe import (
-    ACTIVATIONS, expert_layer, kernel_impl, route, router_load)
+    ACTIVATIONS, expert_layer, kernel_impl, route, router_load,
+    seq_balance_term)
 from fmda_tpu.ops.sparse_attention import (
     PICKS, kernels_dispatch, select_keys, sparse_mha)
 from fmda_tpu.ops.ssd import conv_silu, ssd_scan
@@ -458,13 +487,17 @@ def _latent_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
 
     with jax.named_scope("attention"):
         with jax.named_scope("mla_proj"):
-            cq = rms_norm(
-                jnp.dot(h, _weight(module, "wq_a", (d, cfg.q_lora_rank))
-                        .astype(dt)),
-                module.param("q_norm", ones, (cfg.q_lora_rank,)), eps)
-            q = heads(jnp.dot(cq, _weight(
-                module, "wq_b", (cfg.q_lora_rank, n * (dn + dr)))
-                .astype(dt)), dn + dr)
+            if cfg.q_lora_rank:
+                cq = rms_norm(
+                    jnp.dot(h, _weight(module, "wq_a", (d, cfg.q_lora_rank))
+                            .astype(dt)),
+                    module.param("q_norm", ones, (cfg.q_lora_rank,)), eps)
+                q = heads(jnp.dot(cq, _weight(
+                    module, "wq_b", (cfg.q_lora_rank, n * (dn + dr)))
+                    .astype(dt)), dn + dr)
+            else:  # a direct query: one product, no latent and no norm
+                q = heads(jnp.dot(h, _weight(
+                    module, "wq", (d, n * (dn + dr))).astype(dt)), dn + dr)
             ckv, kr = jnp.split(
                 jnp.dot(h, _weight(module, "wkv_a",
                                    (d, cfg.kv_lora_rank + dr)).astype(dt)),
@@ -544,7 +577,9 @@ def _ssm_rules(cfg: ModelConfig) -> list:
 def _latent_rules(cfg: ModelConfig) -> list:
     why = " (layer_layout has a latent-attention layer)"
     return [(name + why, getattr(cfg, name) > 0) for name in (
-        "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "v_head_dim")] + [
+        "kv_lora_rank", "qk_nope_head_dim", "v_head_dim")] + [
+        ("q_lora_rank (0: a direct query; or the latent's width)" + why,
+         cfg.q_lora_rank >= 0),
         ("qk_rope_head_dim (even, for rotary)" + why,
          cfg.qk_rope_head_dim > 0 and cfg.qk_rope_head_dim % 2 == 0),
         ("attention_multiplier / residual_multiplier (a latent-attention "
@@ -607,6 +642,31 @@ def model_counts(cfg: ModelConfig) -> Dict[str, ModelCount]:
     return declared
 
 
+#: The loss terms a layer may add to what training differentiates beside
+#: the next-token loss, by name: which layers have it.  A layer returns a
+#: term's value a sequence, (B,) float32, WITH its gradient path (a count
+#: has none); the model stacks them a layer, (layers, B), among what
+#: :meth:`MoEDecoder.features` returns; the task (train/tasks.py
+#: ``NextToken``) adds them to the objective, folds and publishes their
+#: values like counts, and leaves them out of a validation pass's loss.
+TERMS: Dict[str, Callable[[ModelConfig, int], bool]] = {
+    # an expert layer's balance term a sequence (ops/moe.py
+    # seq_balance_term), already times cfg.moe_seq_aux_alpha
+    "seq_aux_loss": lambda cfg, layer: (
+        cfg.moe_seq_aux_alpha > 0 and _has_experts(cfg, layer)),
+}
+
+
+def model_terms(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
+    """The loss terms ``cfg``'s model declares, by name: the layers that
+    have each (:data:`TERMS`; beside :func:`model_counts`, and like it
+    without tracing a model)."""
+    depth = range(len(cfg.layer_layout))
+    found = {name: tuple(i for i in depth if where(cfg, i))
+             for name, where in TERMS.items()}
+    return {name: layers for name, layers in found.items() if layers}
+
+
 def _dense_mlp(module: nn.Module, cfg: ModelConfig, u: jax.Array,
                width: Optional[int] = None,
                names: Tuple[str, str, str] = ("w_gate", "w_up", "w_down"),
@@ -623,8 +683,10 @@ def _dense_mlp(module: nn.Module, cfg: ModelConfig, u: jax.Array,
 
 
 def routing(module: nn.Module, cfg: ModelConfig, y: jax.Array):
-    """``(gates, experts)`` of the stream or rows ``y`` (..., hidden):
-    the model's one router call, as ``cfg`` scores, biases and scales."""
+    """``(gates, experts)`` of the stream or rows ``y`` (..., hidden),
+    and the selection bias: the model's one router call, as ``cfg``
+    scores, biases and scales; with the scores of all experts third where
+    the model declares the balance term that reads them."""
     bias = None
     if cfg.moe_bias_rate > 0:
         bias = module.param("router_bias", nn.initializers.zeros,
@@ -633,7 +695,8 @@ def routing(module: nn.Module, cfg: ModelConfig, y: jax.Array):
     return route(
         y.reshape(-1, d), _weight(module, "router", (d, cfg.moe_experts)),
         cfg.moe_top_k, scoring=cfg.moe_scoring, bias=bias,
-        scale=cfg.moe_routed_scaling), bias
+        scale=cfg.moe_routed_scaling,
+        with_scores=cfg.moe_seq_aux_alpha > 0), bias
 
 
 def feed_forward(module: nn.Module, cfg: ModelConfig, u: jax.Array, *,
@@ -645,14 +708,16 @@ def feed_forward(module: nn.Module, cfg: ModelConfig, u: jax.Array, *,
     ones.  ``routed``: :func:`routing`'s answer where the block asked
     already.  Returns the output and all the layer counted: ``counted``
     (the mixer's, each now in its declared form), :data:`EXPERT_COUNTS`
-    and, with ``load``, the load on all experts and the bias's size."""
+    and, with ``load``, the load on all experts and the bias's size;
+    among them, under its name, the balance term a sequence where the
+    model declares it (:data:`TERMS`: a value with a gradient path)."""
     if dense or not cfg.moe_experts:
         return _dense_mlp(module, cfg, u), _settled(counted)
     b, t, d = u.shape
     f = cfg.moe_ffn_size
     first, count = cfg.experts_held
     flat = u.reshape(b * t, d)
-    (gates, experts), bias = routed or routing(module, cfg, flat)
+    (gates, experts, *scores), bias = routed or routing(module, cfg, flat)
     m, plan = expert_layer(
         flat, gates, experts,
         _weight(module, "w_gate", (count, d, f)),
@@ -676,6 +741,9 @@ def feed_forward(module: nn.Module, cfg: ModelConfig, u: jax.Array, *,
     out = m.reshape(b, t, d)
     if load:
         counts["router_load"] = router_load(experts, cfg.moe_experts)
+    if scores:
+        counts["seq_aux_loss"] = seq_balance_term(
+            scores[0], experts, b, cfg.moe_seq_aux_alpha)
     return out, counts
 
 
@@ -786,8 +854,13 @@ class DecoderBlock(nn.Module):
         if sum_errors:
             counts["hc_sum_error"] = jax.lax.stop_gradient(
                 jnp.max(jnp.stack(sum_errors)))
-        return x, Counts((name, counts[name]) for name in declared
-                         if name in counts)
+        # ... and for the loss terms it declares: a dense layer of a model
+        # whose expert layers have one adds nothing, a sequence
+        terms = [(name, counts[name] if name in counts
+                  else jnp.zeros(x.shape[:1], jnp.float32))
+                 for name in model_terms(cfg)]
+        return x, Counts([(name, counts[name]) for name in declared
+                          if name in counts] + terms)
 
 
 class MoEDecoder(nn.Module):
@@ -821,7 +894,9 @@ class MoEDecoder(nn.Module):
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """ids (B, T) int32 -> the final norm's output (B, T, hidden) in
         the compute dtype, and what the layers counted: an array a name
-        of :func:`model_counts`, in its order and shapes."""
+        of :func:`model_counts`, in its order and shapes; then each loss
+        term of :func:`model_terms`, (layers, B) float32 with its
+        gradient path."""
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = jnp.take(self.embed, ids, axis=0)
@@ -833,6 +908,7 @@ class MoEDecoder(nn.Module):
         kept = {name: [] if count.stacked
                 else [jnp.zeros(count.shape(cfg), count.dtype)]
                 for name, count in declared.items()}
+        terms = {name: [] for name in model_terms(cfg)}
         if cfg.hc_streams > 1:
             # every lane starts as the token's row
             x = jnp.broadcast_to(
@@ -845,12 +921,16 @@ class MoEDecoder(nn.Module):
                     count.shape(cfg), count.dtype)
                 kept[name] = (kept[name] + [value] if count.stacked
                               else [kept[name][0] + value])
+            for name in terms:
+                terms[name].append(counts[name])
         if cfg.hc_streams > 1:
             with jax.named_scope("hyper_conn"):  # the lanes leave as one
                 x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
         x = rms_norm(x, self.ln_final, cfg.rms_norm_eps)
-        return x, {name: jnp.stack(values) if declared[name].stacked
-                   else values[0] for name, values in kept.items()}
+        return x, {
+            **{name: jnp.stack(values) if declared[name].stacked
+               else values[0] for name, values in kept.items()},
+            **{name: jnp.stack(values) for name, values in terms.items()}}
 
     def __call__(self, ids: jax.Array, *, deterministic: bool = True
                  ) -> jax.Array:
@@ -889,6 +969,11 @@ def _feed_forward_rules(cfg: ModelConfig, latent: bool) -> list:
          and (cfg.moe_routed_scaling == 1.0 or sigmoid)),
         ("moe_bias_rate (0, or positive with sigmoid scores)",
          cfg.moe_bias_rate == 0 or (cfg.moe_bias_rate > 0 and sigmoid)),
+        ("moe_seq_aux_alpha (0, or positive with experts under a plain "
+         "residual" + only_latent + ")",
+         cfg.moe_seq_aux_alpha == 0 or (
+             cfg.moe_seq_aux_alpha > 0 and latent and not dense
+             and cfg.hc_streams == 1)),
         ("moe_experts / moe_top_k",
          dense or 0 < cfg.moe_top_k <= cfg.moe_experts),
         ("moe_ffn_size", dense or cfg.moe_ffn_size > 0),

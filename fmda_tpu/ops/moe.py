@@ -26,7 +26,9 @@ grouped products skip the tiles no pair fell into
 (:mod:`fmda_tpu.ops.pallas_moe`).  The steps, each under its scope
 (docs/observability.md "Spans and scopes"):
 
-- ``moe_route``: router product, softmax, top-k, gate normalisation;
+- ``moe_route``: router product, softmax, top-k, gate normalisation
+  (and, where the model declares it, ``moe_seq_aux``: the router's
+  per-sequence balance term, :func:`seq_balance_term`);
 - ``moe_dispatch``: sort the held pairs by expert, pad each group to
   whole row tiles, gather the token rows into that layout;
 - ``moe_experts``: the three grouped products and the gated unit between;
@@ -96,7 +98,7 @@ def layout_tiles(n_pairs: int, count: int) -> int:
 
 def route(h: jax.Array, w_router: jax.Array, top_k: int, *,
           scoring: str = "softmax", bias: jax.Array = None,
-          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+          scale: float = 1.0, with_scores: bool = False):
     """``(gates (T, k) float32, experts (T, k) int32)``: softmax over
     all experts in float32, the ``top_k`` largest, their probabilities
     renormalised to sum to one.
@@ -105,7 +107,11 @@ def route(h: jax.Array, w_router: jax.Array, top_k: int, *,
     ``top_k`` are chosen on ``score + bias`` (``bias`` (E,): a selection
     bias no gradient reaches, None for none), the gates are the chosen
     experts' *unbiased* scores over their sum, times ``scale``.  Ties go
-    to the lower expert, as :func:`jax.lax.top_k` breaks them."""
+    to the lower expert, as :func:`jax.lax.top_k` breaks them.
+
+    ``with_scores``: a third answer, the scores of all experts (T, E)
+    float32 the choice was made from (unbiased: what
+    :func:`seq_balance_term` reads)."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(h, w_router.astype(h.dtype),
                          preferred_element_type=jnp.float32)
@@ -116,11 +122,40 @@ def route(h: jax.Array, w_router: jax.Array, top_k: int, *,
             _, experts = jax.lax.top_k(chosen_on, top_k)
             top = jnp.take_along_axis(scores, experts, axis=-1)
             gates = scale * top / jnp.sum(top, axis=-1, keepdims=True)
-            return gates, experts.astype(jnp.int32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top, experts = jax.lax.top_k(probs, top_k)
-        gates = top / jnp.sum(top, axis=-1, keepdims=True)
-        return gates, experts.astype(jnp.int32)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+            top, experts = jax.lax.top_k(scores, top_k)
+            gates = top / jnp.sum(top, axis=-1, keepdims=True)
+        experts = experts.astype(jnp.int32)
+        return (gates, experts, scores) if with_scores else (gates, experts)
+
+
+def seq_balance_term(scores: jax.Array, experts: jax.Array, n_seq: int,
+                     alpha: float) -> jax.Array:
+    """The router's balance term of each of ``n_seq`` sequences, (n_seq,)
+    float32, from :func:`route`'s ``scores`` (n_seq * T, E) and
+    ``experts`` (n_seq * T, k), over ALL ``E`` experts, held or not::
+
+        s'[t, e] = scores[t, e] / sum_e' scores[t, e']   P_e = mean_t s'[t, e]
+        f_e = E / (k T) * #{t : e chosen for t}
+        alpha * sum_e f_e P_e                            (alpha at an even router)
+
+    ``f`` is a count (the choice as made, on the biased score): the
+    gradient reaches the scores through ``P`` alone.  Under the scope
+    ``moe_seq_aux``, beside ``moe_route``."""
+    with jax.named_scope("moe_seq_aux"):
+        n_experts, top_k = scores.shape[-1], experts.shape[-1]
+        chosen = jnp.sum(
+            experts.reshape(n_seq, -1)[:, :, None]
+            == jnp.arange(n_experts, dtype=jnp.int32)[None, None, :],
+            axis=1, dtype=jnp.int32)                           # (n_seq, E)
+        per_seq = scores.shape[0] // n_seq
+        share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        mean_share = jnp.mean(
+            share.reshape(n_seq, per_seq, n_experts), axis=1)
+        often = chosen.astype(jnp.float32) * (
+            n_experts / (top_k * per_seq))
+        return alpha * jnp.sum(often * mean_share, axis=-1)
 
 
 def router_load(experts: jax.Array, n_experts: int) -> jax.Array:

@@ -26,7 +26,10 @@ Record fields (kind ``train.epoch`` in
   first pull), ``run_s`` (the loop and its drain: first pull to the
   return of ``device_get``), ``publish_s`` (``task.publish`` and
   ``epoch_metrics``), ``steps``, ``calls``, ``cache`` (``"hit"`` /
-  ``"miss"`` of the placed-batch cache, None where none was asked);
+  ``"miss"`` of the placed-batch cache, None where none was asked),
+  and what the task's ``publish`` returned for the pass (a token task
+  whose layers declare loss terms: each term's sum over its layers, a
+  step, e.g. ``seq_aux_loss``);
 - ``epoch_end_s``: history, ``train_epoch_seconds``, the log line;
 - ``warm``: ``mark_warm`` had been called when the epoch ended;
 - ``compiles``: programs the trainer's tracked steps compiled during
@@ -73,6 +76,7 @@ def emit_epoch(
             "steps": account["steps"],
             "calls": account["calls"],
             "cache": account.get("cache"),
+            **(account.get("published") or {}),
         }
         t = account["t_published"]
     record.update(epoch_end_s=t_end - t, total_s=t_end - t_start,

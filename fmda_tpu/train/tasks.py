@@ -20,6 +20,9 @@ family's *task* (:func:`task_for`):
 ``publish``           counters written once a pass, at the drain
 ``fold``              (optional) the totals with a step's values, where
                       not every entry is a sum
+``eval_loss``         (optional) ``loss`` as a validation or test pass
+                      takes it, where the training objective has terms a
+                      layer declares and such a pass leaves out
 ``after_update``      (optional) the step rule of parameters the
                       optimizer does not move
 ``feature_windows``   whether the source is a table of float features
@@ -44,7 +47,7 @@ import numpy as np
 from fmda_tpu.config import ModelConfig, TrainConfig
 from fmda_tpu.data.pipeline import (
     Batch, ChunkDataset, TokenBatches, TokenDataset, WindowBatches)
-from fmda_tpu.models.decoder import COUNTS, model_counts
+from fmda_tpu.models.decoder import COUNTS, model_counts, model_terms
 from fmda_tpu.ops.metrics import multilabel_metrics
 from fmda_tpu.train.losses import (
     chunked_next_token_loss, weighted_bce_sums, weighted_bce_with_logits)
@@ -81,7 +84,10 @@ class TokenTotals(NamedTuple):
     says what each is), None where the model does not count it: the
     expert layers' three, a learned-sparse selection's two, a state-space
     scan's two, a latent-attention model's four (two of them folded by
-    ``max``, not by sum: :data:`FOLDED_BY_MAX`)."""
+    ``max``, not by sum: :data:`FOLDED_BY_MAX`).  Last, the loss terms the
+    layers declare (:func:`fmda_tpu.models.decoder.model_terms`): each
+    step's value a layer, the mean over the step's sequences, as the
+    objective took it; ``loss`` stays the next-token loss."""
 
     loss: jax.Array          # () float32
     tokens: jax.Array        # () int32
@@ -97,6 +103,7 @@ class TokenTotals(NamedTuple):
     latent_pairs: Optional[jax.Array] = None   # (layers,) int32
     router_bias_absmax: Optional[jax.Array] = None  # (layers,) float32
     hc_sum_error: Optional[jax.Array] = None        # (layers,) float32
+    seq_aux_loss: Optional[jax.Array] = None        # (layers,) float32
 
 
 #: The :class:`TokenTotals` fields whose pass value is the largest of the
@@ -116,6 +123,9 @@ PUBLISHED = {
     "hc_sum_error": "hc_res_sum_error_max",
     "router_bias_absmax": "moe_router_bias_absmax",
 }
+
+#: A declared loss term's gauge: its mean a step over the pass, a layer.
+PUBLISHED_TERMS = {"seq_aux_loss": "moe_seq_aux_loss"}
 
 
 def keys_kept_counts(sparse_keys_kept) -> list:
@@ -256,9 +266,12 @@ class NextToken:
 
     def zero_totals(self) -> TokenTotals:
         zero = np.zeros((), np.int32)
+        depth = len(self.model_cfg.layer_layout)
         return TokenTotals(np.zeros((), np.float32), zero, zero, **{
             name: np.zeros(c.shape, c.count.dtype)
-            for name, c in model_counts(self.model_cfg).items()})
+            for name, c in model_counts(self.model_cfg).items()}, **{
+            name: np.zeros((depth,), np.float32)
+            for name in model_terms(self.model_cfg)})
 
     def epoch_metrics(self, totals: Optional[TokenTotals], steps: int
                       ) -> Tuple[EpochMetrics, np.ndarray]:
@@ -271,10 +284,14 @@ class NextToken:
             loss=float(totals.loss) / steps, accuracy=accuracy,
             hamming=1.0 - accuracy, fbeta=np.zeros(0)), confusion
 
-    def publish(self, totals: TokenTotals, phase: str, steps: int) -> None:
+    def publish(self, totals: TokenTotals, phase: str, steps: int
+                ) -> Optional[dict]:
         """The pass's token count and what :func:`model_counts` declares
         its layers count, from the drained totals of its ``steps`` steps,
-        a series a layer (docs/observability.md "Spans and scopes")."""
+        a series a layer (docs/observability.md "Spans and scopes"); and
+        each declared loss term's mean a step, a gauge a layer.  Returns
+        what of it the epoch's ``train.epoch`` record carries for the
+        pass: each term summed over its layers, a step (None: nothing)."""
         from fmda_tpu.obs.registry import default_registry
         from fmda_tpu.ops.moe import layout_tiles
 
@@ -328,6 +345,14 @@ class NextToken:
             for expert, pairs in enumerate(load):
                 reg.counter("moe_router_load_total", expert=str(expert),
                             **labels).inc(int(pairs))
+        record = {}
+        for name, layers in model_terms(mc).items():
+            a_step = np.asarray(getattr(totals, name), np.float64) / steps
+            for layer in layers:
+                reg.gauge(PUBLISHED_TERMS[name], layer=str(layer),
+                          phase=phase).set(float(a_step[layer]))
+            record[name] = float(a_step.sum())
+        return record or None
 
     # -- inside the compiled step ---------------------------------------------
 
@@ -363,7 +388,15 @@ class NextToken:
         del rng  # the family has no dropout
         return model.apply({"params": params}, batch.x, method="features")
 
-    def loss_sums(self, params, out, batch: Batch):
+    def loss_sums(self, params, out, batch: Batch, terms: bool = True):
+        """``(what is differentiated, as a sum; its count; aux)``: the
+        next-token loss's sum over the counted tokens, and with ``terms``
+        each loss term the layers declare
+        (:func:`fmda_tpu.models.decoder.model_terms`), a sequence's value
+        times the tokens it counts: over the count, the mean over the
+        step's sequences (every sequence of a token source counts all of
+        its tokens or, as padding, none).  ``aux``'s stats then carry,
+        as sums, each term a layer and the next-token loss alone."""
         hidden, stats = out
         mc = self.model_cfg
         # a tied head is the embedding, transposed: the leaf's gradient
@@ -373,11 +406,26 @@ class NextToken:
             hidden.reshape(-1, hidden.shape[-1]), head,
             batch.y.reshape(-1), batch.mask.reshape(-1),
             chunk=mc.loss_chunk, logits_scaling=mc.logits_scaling)
+        declared = model_terms(mc)
+        if declared:
+            with jax.named_scope("loss_terms"):
+                counted = jnp.sum(batch.mask, axis=-1)  # (B,): a sequence's
+                weighed = {name: jnp.sum(stats[name] * counted, axis=-1)
+                           for name in declared}        # (layers,) each
+                stats = {**stats, **weighed, "next_token_sum": s}
+                if terms:
+                    s = s + sum(jnp.sum(v) for v in weighed.values())
         return s, tokens.astype(jnp.float32), (tokens, correct, stats)
 
-    def loss(self, params, out, batch: Batch):
-        s, count, aux = self.loss_sums(params, out, batch)
+    def loss(self, params, out, batch: Batch, terms: bool = True):
+        s, count, aux = self.loss_sums(params, out, batch, terms)
         return s / jnp.maximum(count, 1.0), aux
+
+    def eval_loss(self, params, out, batch: Batch):
+        """The next-token loss alone: a validation or test pass leaves
+        the declared terms out of its loss (their values are still
+        folded and published, by phase)."""
+        return self.loss(params, out, batch, terms=False)
 
     def merge_micro(self, aux_k):
         """Counts add over the microbatches; the largest of what a pass
@@ -390,6 +438,14 @@ class NextToken:
 
     def step_values(self, loss, aux, batch: Batch) -> TokenTotals:
         tokens, correct, counts = aux
+        if "next_token_sum" in counts:
+            # the reported loss stays the next-token loss; the declared
+            # terms go beside it, each the step's mean a layer
+            counts = dict(counts)
+            n = jnp.maximum(tokens.astype(jnp.float32), 1.0)
+            loss = counts.pop("next_token_sum") / n
+            for name in model_terms(self.model_cfg):
+                counts[name] = counts[name] / n
         return TokenTotals(loss, tokens, correct, **counts)
 
 

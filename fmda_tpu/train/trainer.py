@@ -357,12 +357,14 @@ class Trainer:
     def _build_eval_step(self):
         model, task = self.model, self.task
         fold = getattr(task, "fold", _add_step)
+        # the training objective less the terms an evaluation leaves out
+        eval_loss = getattr(task, "eval_loss", task.loss)
 
         def eval_fn(params, totals, batch: Batch):
             with jax.named_scope("forward"):
                 out = task.forward(model, params, batch, None)
             with jax.named_scope("loss"):
-                loss, aux = task.loss(params, out, batch)
+                loss, aux = eval_loss(params, out, batch)
             with jax.named_scope("metrics"):
                 return fold(totals, task.step_values(loss, aux, batch))
 
@@ -653,14 +655,16 @@ class Trainer:
                 drained = jax.device_get(totals)
         t_drained = clock()
         with span(phase + "_pass_publish", epoch=epoch):
+            published = None
             if drained is not None:
-                self.task.publish(drained, phase, step_no)
+                published = self.task.publish(drained, phase, step_no)
             metrics = self.task.epoch_metrics(drained, step_no)
         if account is not None:
             account.update(
                 t_run=t_run, t_drained=t_drained, t_published=clock(),
                 steps=step_no,
-                calls=int(call_counter.value - calls_before))
+                calls=int(call_counter.value - calls_before),
+                published=published)
         return (state,) + metrics
 
     def _warn_if_norm_drifted(self, dataset: ChunkDataset) -> None:

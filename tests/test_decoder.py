@@ -206,7 +206,7 @@ def test_the_chunked_loss_is_the_whole_loss():
         (jnp.argmax(hidden @ head, -1) == y) & (mask > 0)))
 
 
-# -- the guard of a refactor: five kinds' programs, pinned -------------------
+# -- the guard of a refactor: six kinds' programs, pinned --------------------
 
 _EXPERTS = dict(moe_experts=4, moe_top_k=2, moe_ffn_size=16,
                 experts_held=(1, 2))
@@ -216,9 +216,12 @@ _LATENT = dict(
     rope_theta=10000.0, rope_factor=64.0, rope_original_max=64,
     hidden_act="silu", ffn_size=48, first_dense_layers=1,
     moe_shared_experts=1, moe_scoring="sigmoid", moe_routed_scaling=2.0)
-#: Tiny copies of the four accepted decoder configurations (bfloat16,
-#: recomputed blocks, as their files state) and a latent one with a plain
-#: residual: what each states beyond the two-layer base below.
+#: Tiny copies of the five accepted decoder configurations (bfloat16,
+#: recomputed blocks, as their files state: the fifth, ``latent_direct``,
+#: is latent attention with a direct query, two shared experts, a
+#: selection bias and the router's per-sequence balance term) and a
+#: latent one with a plain residual: what each states beyond the
+#: two-layer base below.
 PINNED_KINDS = {
     "routed": _EXPERTS,
     "learned_sparse": dict(
@@ -232,6 +235,10 @@ PINNED_KINDS = {
         attention_multiplier=0.25, logits_scaling=8.0),
     "latent": dict(_LATENT, moe_bias_rate=1e-3, hc_streams=4),
     "latent_plain": _LATENT,
+    "latent_direct": dict(
+        _LATENT, q_lora_rank=0, rope_theta=50000.0, rope_factor=1.0,
+        rope_original_max=0, moe_shared_experts=2, moe_routed_scaling=2.446,
+        moe_bias_rate=1e-3, moe_seq_aux_alpha=1e-3),
 }
 #: sha256 (first 16 hex digits), jax 0.9.0, taken on PR 45's parent
 #: (b804e58) by this very code, of: the lowered text of the single train
@@ -241,7 +248,8 @@ PINNED_KINDS = {
 #: less the ``block_1._method`` components flax writes for a module's
 #: method, which name no scope the code opens and no reader asks for);
 #: the parameter tree at ``PRNGKey(0)`` (paths, shapes, dtypes, bytes).
-#: The first three kinds' text hashes are PR 34's and PR 41's parents'.
+#: The first three kinds' text hashes are PR 34's and PR 41's parents';
+#: ``latent_direct``'s are PR 46's own (the kind did not run before it).
 #: A pin that moves means the change altered the program:
 #: regenerate (``_program_pins(kind)``) only after a deliberate change to
 #: these layers, their task or the step function.
@@ -266,6 +274,10 @@ PINNED = {
         params="881b57f6db59149d",
         train_text="eae356acde159301", train_scopes="566a40a3b3816364",
         eval_text="4dbdd7bc48e5f019", eval_scopes="09c0dcb75690bf72"),
+    "latent_direct": dict(
+        params="5f93cbac51dae9f8",
+        train_text="ee45915933e4ee56", train_scopes="821054b0ff3ee6ee",
+        eval_text="c3255018e5649468", eval_scopes="090b7a1ba162fd35"),
 }
 
 
@@ -374,7 +386,10 @@ def test_the_totals_hold_what_the_declaration_lists_and_nothing_else(kind):
                    "latent_pairs"],
         "latent_plain": ["expert_pairs", "dropped", "row_tiles_used",
                          "router_load", "router_bias_absmax",
-                         "latent_pairs"]}[kind]
+                         "latent_pairs"],
+        "latent_direct": ["expert_pairs", "dropped", "row_tiles_used",
+                          "router_load", "router_bias_absmax",
+                          "latent_pairs"]}[kind]
     assert list(declared) == want  # the order features stacks them in
     # which layers count: the dense first layer of a latent model has no
     # experts; a state-space count comes from the state-space layers
@@ -383,6 +398,16 @@ def test_the_totals_hold_what_the_declaration_lists_and_nothing_else(kind):
         assert declared["latent_pairs"].layers == (0, 1, 2)
     if kind == "hybrid":
         assert declared["ssd_chunks"].layers == (0, 2)
+    # ... and the loss terms the layers declare, beside the counts: the
+    # expert layers' balance term where the configuration states one
+    from fmda_tpu.models.decoder import TERMS, model_terms
+
+    assert not set(TERMS) & set(COUNTS) and set(TERMS) <= set(totals._fields)
+    assert model_terms(cfg) == (
+        {"seq_aux_loss": (1, 2)} if kind == "latent_direct" else {})
+    for name in TERMS:
+        assert (getattr(totals, name) is not None) == (
+            name in model_terms(cfg)), name
 
 
 @pytest.mark.parametrize("through", ["fold", "merge_micro"])
@@ -432,6 +457,15 @@ def test_a_count_folds_as_it_is_declared(through):
      "hc_streams (1, or more lanes (a latent-attention model's))"),
     ("latent", dict(layer_layout=(4, 4, 0)),
      "layer_layout (one of 0/1/2/3 per layer, or 4 in every layer)"),
+    ("latent_direct", dict(hc_streams=4),
+     "moe_seq_aux_alpha (0, or positive with experts under a plain "
+     "residual (a latent-attention model's))"),
+    ("routed", dict(moe_seq_aux_alpha=1e-3),
+     "moe_seq_aux_alpha (0, or positive with experts under a plain "
+     "residual (a latent-attention model's))"),
+    ("latent_direct", dict(q_lora_rank=-1),
+     "q_lora_rank (0: a direct query; or the latent's width) (layer_layout "
+     "has a latent-attention layer)"),
 ])
 def test_one_missing_field_is_refused_in_the_parents_words(
         kind, over, message):

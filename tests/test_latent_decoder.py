@@ -441,7 +441,7 @@ def test_a_pass_publishes_the_latent_layers_counters():
 
 
 @pytest.mark.parametrize("over,named", [
-    (dict(q_lora_rank=0), "q_lora_rank"),
+    (dict(q_lora_rank=-1), "q_lora_rank"),  # 0 is a direct query
     (dict(kv_lora_rank=0), "kv_lora_rank"),
     (dict(qk_nope_head_dim=0), "qk_nope_head_dim"),
     (dict(qk_rope_head_dim=7), "qk_rope_head_dim"),
