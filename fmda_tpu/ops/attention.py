@@ -162,17 +162,19 @@ def flash_dispatch(
     *,
     use_flash: bool,
     has_mask: bool = False,
+    d_value: Optional[int] = None,
 ) -> bool:
     """THE dispatch decision :func:`mha` makes — exposed so callers that
     *report* the executed path ask this function instead of
     re-implementing the gate and silently
     drifting from it.  ``has_mask`` means an arbitrary mask array; the
-    causal triangle and a causal window are the kernel's own."""
+    causal triangle and a causal window are the kernel's own.
+    ``d_value`` is the values' width where it is not ``d_head``."""
     if not (use_flash and not has_mask and flash_available()):
         return False
     from fmda_tpu.ops import pallas_attention
 
-    return pallas_attention.flash_supported(tq, tk, d_head)
+    return pallas_attention.flash_supported(tq, tk, d_head, d_value)
 
 
 #: Query rows the non-kernel path scores at a time once the sequence is
@@ -249,7 +251,7 @@ def mha(
     # recurrent families have recurrence_fwd/_rev
     with jax.named_scope("attention"):
         if flash_dispatch(tq, tk, q.shape[-1], use_flash=use_flash,
-                          has_mask=mask is not None):
+                          has_mask=mask is not None, d_value=v.shape[-1]):
             from fmda_tpu.ops import pallas_attention
 
             return pallas_attention.flash_attention(

@@ -7,8 +7,7 @@ bounds the step.  These kernels are the standard flash-attention
 restructuring of the SAME online-softmax recurrence the module documents
 (ops/attention.py docstring; the ring path folds K/V blocks with
 identical math, parallel/ring_attention.py): scores only ever exist as
-one block in VMEM.  Three kernels: ``flash_fwd``, ``flash_bwd_dkv`` and
-``flash_bwd_dq``.
+one block in VMEM.  Two kernels: ``flash_fwd`` and ``flash_bwd``.
 
 Forward — grid ``(key-value heads x steps a group, T / bq, T / bk)``,
 ``dimension_semantics`` arbitrary: steps run in order, so VMEM scratch
@@ -26,8 +25,8 @@ head ``h`` and block, the row state a head each in three scratches::
     at the last key block:  o = acc / sum(l),  L = m + log sum(l)
 
 What a block and head pay *per row, not per element* is what kept the
-forward at a quarter of the MXU's rate while the backward kernels, which
-get ``L`` finished, ran at twice that a product: the lane reductions
+forward at a quarter of the MXU's rate while the backward, which gets
+``L`` finished, ran at twice that a product: the lane reductions
 through the XLU (nothing of a head's chain can start until its maximum
 is known), the ``corr`` exponential, the rescale of ``acc``.  Four
 things keep that off the MXU's path, each exact (PERF.md section 6,
@@ -46,8 +45,8 @@ same moves in ``sparse_fwd``, whose ``_fold_lanes`` and
 - **a key block of 1,024** where ``T % 1024 == 0``
   (:func:`fwd_blocks_for`; the query block stays :func:`block_for`'s):
   half the reductions a score.  The band is then walked in ``(bq, bk)``
-  blocks (:func:`_fwd_visible`, :func:`_fwd_keep`,
-  :func:`_fwd_key_block`), every in-band block under one mask that all
+  blocks (:func:`_visible`, :func:`_keep`,
+  :func:`_key_block`), every in-band block under one mask that all
   the step's heads share;
 - **a head's ``p v`` is issued behind the next head's scores and
   softmax**, so one head's reduction sits under another's products.
@@ -73,45 +72,83 @@ most four heads a step with the group walked in two steps (+20 %).  The
 text costs a warm set-up nothing that its faster first epoch does not
 give back.
 
-``L`` (the per-row logsumexp) leaves the kernel as a ``(rows, 128)``
+``L`` (the per-row logsumexp) leaves the forward as a ``(rows, 128)``
 tile of equal lanes; its column is the only residual beyond the inputs
 and ``o`` — the backward recomputes ``p = exp(s - L)`` blockwise instead
-of storing probabilities.  Backward runs as two kernels over square
-blocks of :func:`block_for` ``(T)`` (512 where T allows, else 256 or
-128: a grid step costs ~0.35 us whatever it computes), one query head a
-grid step, the textbook split:
+of storing probabilities.
 
-- **dK/dV sweep** — grid ``(B*N, T/blk [k], T/blk [q])``: for a fixed
-  K/V block, walk the query blocks; ``dv += p^T @ do``,
-  ``ds = p * (do @ v^T - delta) * scale``, ``dk += ds^T @ q``.
-- **dQ sweep** — grid ``(B*N, T/blk [q], T/blk [k])``: for a fixed Q
-  block, walk the key blocks; ``dq += ds @ k``.
+Backward — one kernel, five products a block and head, shaped as
+``sparse_bwd`` is (:mod:`fmda_tpu.ops.pallas_sparse_attention`; PERF.md
+section 6, PR 37 and PR 47).  ``dk`` / ``dv`` sum over query blocks and
+``dq`` over key blocks, so one sweep keeps only one of them in a
+block-sized scratch, and a backward in two sweeps makes ``q k^T``,
+``do v^T``, the ``exp`` and the mask twice (seven products for five).
+``flash_bwd`` walks query blocks outside and key blocks inside, as the
+forward does: grid ``(key-value heads x steps a group, T / blk,
+T / blk)`` over square :func:`block_for` blocks (512 where T allows,
+else 256 or 128: a grid step costs ~0.35 us whatever it computes), the
+query heads of a key-value head in one grid step.
+``dq`` sits in a ``(heads, blk, D)`` float32 scratch for the query
+block's key blocks; the key-value head's ``dk`` and ``dv``
+*for the whole sequence* sit in a ``(T, D)`` and a ``(T, Dv)`` float32
+scratch, added to at the rows of key block ``ki`` query block by query
+block, head by head: the group's sum happens there, in float32, and
+nothing a query head wide leaves the kernel.  Key block ``ki``'s rows
+are zeroed at the first query block that sees them and written, in the
+compute dtype, at the last query block (whose grid steps pass every key
+block) into ``(1, T, D)`` / ``(1, T, Dv)`` output blocks that go back to
+HBM when the key-value head changes.  Per head ``h`` and block, with a
+key a row (the scores are taken transposed, so that the two products
+that contract the query rows need no transpose and only ``dq``'s does;
+``L`` and ``delta`` then lie along the lanes and ride as they are kept,
+one float32 a head and row, no 128-lane tile)::
 
-``delta = rowsum(do * o)`` is cheap elementwise work computed outside in
-plain XLA.  The backward masks with the large-negative finite ``_NEG``
-and forces masked probabilities to exactly zero.  m/L/delta ride as
-128-lane-replicated ``(rows, 128)`` tiles — Mosaic's tiling wants the
-last dim to be 128 or the full array dim, and a (1, block) slab whose
-sublane dim is neither 8-divisible nor full does not lower.
+    s^T  = (k @ q[h]^T) * scale, -inf where masked       # MXU, f32
+    p^T  = exp(s^T - L[h])                               # exactly 0 if masked
+    ds^T = p^T * (v @ do[h]^T - delta[h]) * scale        # MXU, f32
+    dv[rows] += p^T @ do[h];  dk[rows] += ds^T @ q[h]    # MXU
+    dq[h]    += ds @ k                                   # MXU
 
-Two things ride inside all three kernels:
+``delta = rowsum(do * o)`` (less the ``lse`` cotangent where there is
+one) is cheap elementwise work computed outside in plain XLA.  The
+step's heads are unrolled one after the other: all five of a head's
+products are the MXU's and there is no reduction to hide, so issuing a
+head's gradient products behind the next head's scores (the forward's
+``_one_head_behind``) reads nothing here, and a ``fori_loop`` over the
+heads costs 6 % of the kernel for a third of its program text (PERF.md
+section 6, PR 47, has the sweeps).  On square blocks a group of one sums
+every gradient's blocks in the order the two-sweep backward did: dQ, dK
+and dV are that backward's bit for bit; a group above one differs by the
+order of the group's float32 sum alone.
+
+What fits is read from the shape (:func:`_bwd_resident_bytes`: the two
+scratches, the output blocks in flight twice, a head's query-side blocks
+times the heads, against ``_VMEM_LIMIT``): 27.9 MiB at seven bfloat16
+heads of 128 by 8,192 tokens, 26.1 at one head of 192 on values of 128.
+:func:`bwd_heads_a_step` walks a group that does not fit in parts, every
+part adding into the one scratch (``attention:group_in_parts``); a
+length whose scratches alone pass the limit (between 16k and 32k keys at
+width 128) is outside the envelope: :func:`flash_supported` refuses it,
+counts ``attention:backward_not_resident``, and ``mha`` takes its
+blockwise path.
+
+Two things ride inside both kernels:
 
 - **grouped-query heads**: K/V may carry fewer heads than Q (``N`` query
   heads on ``G`` key-value heads, ``N % G == 0``).  Nothing is repeated
-  in HBM: the K/V block index follows from the query heads' index; the
-  dK/dV sweep writes one float32 partial per query head and the
-  ``N / G`` partials of a group are summed outside.
+  in HBM: the K/V block index follows from the query heads' index.
 - **a causal window**: key ``j`` is visible to query ``i`` iff
   ``0 <= i - j < window``.  Blocks wholly outside the band are skipped
   (no MXU/VPU work) and their block index is clamped into the band, so
   the pipeline re-references the block it already holds and fetches
   nothing; in the backward, blocks wholly inside the band skip the mask
-  arithmetic.
+  arithmetic (:func:`_interior`).
 
 Support envelope (:func:`flash_supported`): self-attention with
 ``Tq == Tk``, ``T % 128 == 0``, no arbitrary mask (causal and the causal
-window are in-kernel; a window implies causal), and D small enough that
-the per-block working set fits VMEM — in practice D <= 512.  Values
+window are in-kernel; a window implies causal), D small enough that
+the per-block working set fits VMEM — in practice D <= 512 — and a
+sequence whose ``dk`` and ``dv`` the backward can hold.  Values
 may have a width of their own (``Dv``, latent attention's 128 beside
 scores over 192): ``p @ v``, ``o``, ``do`` and ``dv`` are then ``Dv``
 wide and nothing is padded.  Everything else takes the jnp path via
@@ -149,76 +186,79 @@ def block_for(seq_len: int) -> int:
     return _BLOCK
 
 #: Finite stand-in for -inf: the forward's running maximum starts here
-#: (its masked scores are a true -inf beneath it) and a row that sees no
-#: key reports it as ``lse``; the backward's masked score slots hold it
-#: (exp(finite - finite) stays a number) and their probabilities are
-#: forced to 0, so a fully-masked row cannot give exp(0) = 1.
+#: and a row that sees no key reports it as ``lse``; masked scores are a
+#: true -inf beneath it in both kernels, so their ``exp`` is exactly 0.
 _NEG = -1e30
 
-
-def flash_supported(q_len: int, k_len: int, d_head: int) -> bool:
-    """Shape gate for the fused kernel (see module docstring)."""
-    return (
-        q_len == k_len
-        and q_len % _BLOCK == 0
-        and d_head <= 512
-    )
+#: What a kernel may hold in VMEM (a v5e has 128 MiB; the default scoped
+#: limit, 16 MiB, is under the seven heads of a (512, 1024) forward step
+#: and under the backward's two whole-sequence scratches at 8,192 x 128).
+_VMEM_LIMIT = 64 * 1024 * 1024
+#: Query heads of one grid step at most: each is unrolled in the kernel.
+_MAX_HEADS_A_STEP = 8
 
 
-def _causal_mask_block(qi, ki, blk: int, window: Optional[int]):
-    """(blk, blk) bool keep-mask for query block qi vs key block ki, in
-    global positions: causal, and inside the window where there is one."""
-    q_pos = qi * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
-    k_pos = ki * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
-    keep = q_pos >= k_pos
+def flash_supported(q_len: int, k_len: int, d_head: int,
+                    d_value: Optional[int] = None) -> bool:
+    """Shape gate for the fused kernels (see module docstring).  A length
+    whose backward residents (one query head a step, four-byte elements)
+    pass ``_VMEM_LIMIT`` is refused and counted,
+    ``attention:backward_not_resident``."""
+    if not (q_len == k_len and q_len % _BLOCK == 0 and d_head <= 512):
+        return False
+    if _bwd_resident_bytes(
+            q_len, 1, d_head, d_value or d_head, 4) > _VMEM_LIMIT:
+        count_kernel_fallback("attention", "backward_not_resident")
+        return False
+    return True
+
+
+# ---------------------------------------------------------------------------
+# the causal band in (bq, bk) blocks, for both kernels
+# ---------------------------------------------------------------------------
+
+
+def _visible(qi, ki, bq: int, bk: int, window: Optional[int]):
+    """Block (qi, ki) holds at least one visible (query, key) pair: its
+    greatest ``query - key`` is causal and its least is inside the
+    window."""
+    ok = (qi + 1) * bq - 1 - ki * bk >= 0
     if window is not None:
-        keep = keep & (q_pos - k_pos < window)
-    return keep
-
-
-def _band_span(blk: int, window: Optional[int]) -> Optional[int]:
-    """How many key blocks behind its own a query block still sees
-    (None: all of them)."""
-    return None if window is None else (window + blk - 2) // blk
-
-
-def _in_band(qi, ki, span: Optional[int]):
-    """Block (qi, ki) holds at least one visible (query, key) pair."""
-    ok = ki <= qi
-    return ok if span is None else ok & (qi - ki <= span)
-
-
-def _interior(qi, ki, blk: int, window: Optional[int]):
-    """Every pair of block (qi, ki) is visible: no mask arithmetic."""
-    ok = ki < qi
-    if window is not None:
-        ok = ok & ((qi - ki) * blk + (blk - 1) < window)
+        ok = ok & (qi * bq - (ki + 1) * bk + 1 < window)
     return ok
 
 
-def _banded(causal: bool, qi, ki, blk, window, compute) -> None:
-    """Run ``compute(masked)`` for block (qi, ki): always and unmasked
-    without ``causal``; else only inside the band, masked on its edges."""
-    if not causal:
-        compute(False)
-        return
-    inside = _interior(qi, ki, blk, window)
-    pl.when(inside)(lambda: compute(False))
-    pl.when(_in_band(qi, ki, _band_span(blk, window)) & ~inside)(
-        lambda: compute(True))
+def _interior(qi, ki, bq: int, bk: int, window: Optional[int]):
+    """Every pair of block (qi, ki) is visible: its least ``query - key``
+    is causal and its greatest inside the window.  No mask arithmetic."""
+    ok = qi * bq - (ki + 1) * bk + 1 >= 0
+    if window is not None:
+        ok = ok & ((qi + 1) * bq - 1 - ki * bk < window)
+    return ok
 
 
-def _scores(q, k, qi, ki, *, blk, window, masked, scale=None):
-    """Scaled scores of one block, masked slots at ``_NEG``; ``scale``
-    None is ``1 / sqrt(D)``."""
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if masked:
-        s = jnp.where(_causal_mask_block(qi, ki, blk, window), s, _NEG)
-    return s, scale
+def _keep(qi, ki, bq: int, bk: int, window: Optional[int],
+          by_key: bool = False):
+    """The bool keep-mask of block (qi, ki), in global positions: causal,
+    and inside the window where there is one.  ``(bq, bk)``, a query a
+    row; ``by_key`` gives it transposed, ``(bk, bq)``, a key a row."""
+    shape, q_axis = ((bk, bq), 1) if by_key else ((bq, bk), 0)
+    rel = (qi * bq - ki * bk
+           + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+           - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+    keep = rel >= 0
+    if window is not None:
+        keep = keep & (rel < window)
+    return keep
+
+
+def _key_block(qi, ki, bq: int, bk: int, window: Optional[int]):
+    """The key block a (qi, ki) grid step references: ``ki`` inside the
+    band, the band's nearest block outside it — an index the pipeline
+    already holds, so a skipped step fetches nothing."""
+    lo = 0 if window is None else jnp.maximum(
+        qi * bq - window + 1, 0) // bk
+    return jnp.clip(ki, lo, ((qi + 1) * bq - 1) // bk)
 
 
 # ---------------------------------------------------------------------------
@@ -234,56 +274,18 @@ def fwd_blocks_for(seq_len: int) -> Tuple[int, int]:
     return blk, (1024 if seq_len % 1024 == 0 else blk)
 
 
-#: What ``flash_fwd`` may hold in VMEM (a v5e has 128 MiB; the default
-#: scoped limit, 16 MiB, is under the seven heads of a (512, 1024) step).
-_FWD_VMEM_LIMIT = 64 * 1024 * 1024
-#: Query heads of one grid step at most: each is unrolled in the kernel.
-_MAX_HEADS_A_STEP = 8
-
-
 def heads_a_step(group: int, bq: int, d: int, dv: int, itemsize: int) -> int:
     """How many of a key-value head's ``group`` query heads one forward
     grid step takes: all of them where that is at most
     ``_MAX_HEADS_A_STEP`` and their blocks (q and o in flight twice, the
     lse tile likewise, the three scratches) fit half of
-    ``_FWD_VMEM_LIMIT`` beside the key, value and score tiles; else the
+    ``_VMEM_LIMIT`` beside the key, value and score tiles; else the
     largest divisor of the group that does."""
     a_head = bq * (2 * (d + dv) * itemsize + 2 * 128 * 4
                    + (2 * 128 + dv) * 4)
-    most = max(1, min(_MAX_HEADS_A_STEP, _FWD_VMEM_LIMIT // 2 // a_head))
+    most = max(1, min(_MAX_HEADS_A_STEP, _VMEM_LIMIT // 2 // a_head))
     return max(h for h in range(1, group + 1)
                if group % h == 0 and h <= most)
-
-
-def _fwd_visible(qi, ki, bq: int, bk: int, window: Optional[int]):
-    """:func:`_in_band` for ``(bq, bk)`` blocks: block (qi, ki) holds at
-    least one visible (query, key) pair, i.e. its greatest ``query - key``
-    is causal and its least is inside the window."""
-    ok = (qi + 1) * bq - 1 - ki * bk >= 0
-    if window is not None:
-        ok = ok & (qi * bq - (ki + 1) * bk + 1 < window)
-    return ok
-
-
-def _fwd_keep(qi, ki, bq: int, bk: int, window: Optional[int]):
-    """:func:`_causal_mask_block` for ``(bq, bk)`` blocks: the bool
-    keep-mask of block (qi, ki), causal, and inside the window where
-    there is one."""
-    rel = (qi * bq - ki * bk
-           + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-           - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
-    keep = rel >= 0
-    if window is not None:
-        keep = keep & (rel < window)
-    return keep
-
-
-def _fwd_key_block(qi, ki, bq: int, bk: int, window: Optional[int]):
-    """:func:`_clamp_key_block` for ``(bq, bk)`` blocks: ``ki`` inside
-    the band, the band's nearest block outside it."""
-    lo = 0 if window is None else jnp.maximum(
-        qi * bq - window + 1, 0) // bk
-    return jnp.clip(ki, lo, ((qi + 1) * bq - 1) // bk)
 
 
 def _fwd_kernel(
@@ -318,7 +320,7 @@ def _fwd_kernel(
     def _compute():
         k, v = k_ref[0], v_ref[0]
         # one mask a grid step, for every head of it
-        keep = _fwd_keep(qi, ki, bq, bk, window) if causal else None
+        keep = _keep(qi, ki, bq, bk, window) if causal else None
 
         def softmax(h):
             s = jax.lax.dot_general(
@@ -350,7 +352,7 @@ def _fwd_kernel(
     # blocks outside the band are fully masked: skip their MXU/VPU work
     # entirely, the state update is a no-op there by construction
     if causal:
-        pl.when(_fwd_visible(qi, ki, bq, bk, window))(_compute)
+        pl.when(_visible(qi, ki, bq, bk, window))(_compute)
     else:
         _compute()
 
@@ -398,7 +400,7 @@ def _fwd_impl(
 
     def kv_index(b, qi, ki):
         if causal:
-            ki = _fwd_key_block(qi, ki, bq, bk, window)
+            ki = _key_block(qi, ki, bq, bk, window)
         return ((b * heads) // group, ki, 0)
 
     o, lse = pl.pallas_call(
@@ -422,7 +424,7 @@ def _fwd_impl(
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_FWD_VMEM_LIMIT,
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
     )(q.reshape(steps, heads, t, d), k, v)
@@ -430,221 +432,202 @@ def _fwd_impl(
 
 
 # ---------------------------------------------------------------------------
-# backward: square blocks, one query head a grid step
+# backward: one sweep, a key-value head's dk and dv resident for the sequence
 # ---------------------------------------------------------------------------
 
 
-def _clamp_key_block(qi, ki, *, causal, blk, window):
-    """The key block a (qi, ki) grid step references: ``ki`` inside the
-    band, the band's nearest block outside it — an index the pipeline
-    already holds, so a skipped step fetches nothing."""
-    if not causal:
-        return ki
-    span = _band_span(blk, window)
-    lo = 0 if span is None else jnp.maximum(qi - span, 0)
-    return jnp.clip(ki, lo, qi)
+def _bwd_resident_bytes(seq_len: int, heads: int, d: int, dv: int,
+                        itemsize: int) -> int:
+    """What ``flash_bwd`` keeps in VMEM at once with ``heads`` query heads
+    a grid step, by count: the key-value head's ``dk`` and ``dv`` for the
+    whole sequence (float32 scratches, and the output blocks in flight
+    twice), a head's query-side blocks, the key and value blocks and a
+    head's score tiles."""
+    blk = block_for(seq_len)
+    whole = seq_len * (d + dv)
+    a_head = blk * (2 * (d + dv) * itemsize    # q, do, in flight twice
+                    + 2 * d * itemsize + d * 4  # dq twice, its scratch
+                    + 2 * 2 * 8 * 4)            # the lse and delta rows
+    return (whole * 4 + 2 * whole * itemsize + heads * a_head
+            + 2 * blk * (d + dv) * itemsize    # k, v, in flight twice
+            + 4 * blk * blk * 4)               # s, p, dp, ds of a head
 
 
-def _clamp_query_block(ki, qi, *, causal, blk, window, n_q):
-    """The dK/dV sweep's twin: the query block a (ki, qi) step
-    references."""
-    if not causal:
-        return qi
-    span = _band_span(blk, window)
-    hi = n_q - 1 if span is None else jnp.minimum(ki + span, n_q - 1)
-    return jnp.clip(qi, ki, hi)
+def bwd_heads_a_step(group: int, seq_len: int, d: int, dv: int,
+                     itemsize: int) -> int:
+    """:func:`heads_a_step` for the backward: the largest divisor of the
+    group, eight at most, whose residents (:func:`_bwd_resident_bytes`)
+    fit ``_VMEM_LIMIT``; one where nothing fits (:func:`flash_supported`
+    refuses such a length)."""
+    return max([h for h in range(1, min(group, _MAX_HEADS_A_STEP) + 1)
+                if group % h == 0 and _bwd_resident_bytes(
+                    seq_len, h, d, dv, itemsize) <= _VMEM_LIMIT] or [1])
 
 
-def _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-              *, blk, window, masked, scale=None):
-    """The backward's shared recompute for one block: probabilities
-    ``p = exp(s - L)`` and ``ds = p * (do @ v^T - delta) * scale``."""
-    f32 = jnp.float32
-    s, scale = _scores(q_ref[0], k_ref[0], qi, ki, blk=blk, window=window,
-                       masked=masked, scale=scale)
-    p = jnp.exp(s - lse_ref[0][:, :1])
-    if masked:
-        p = jnp.where(s <= _NEG * 0.5, 0.0, p)
-    dp = jax.lax.dot_general(
-        do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=f32)
-    return p, p * (dp - delta_ref[0][:, :1]) * scale
-
-
-def _dkv_kernel(
-    q_ref,  # (1, blk, D) — query block qi
-    k_ref,  # (1, blk, D) — the fixed key block ki
+def _bwd_kernel(
+    q_ref,  # (1, heads, blk, D): the query heads of this grid step
+    k_ref,  # (1, blk, D): their one key-value head
     v_ref,  # (1, blk, Dv)
-    do_ref,  # (1, blk, Dv) — dO for query block qi
-    lse_ref,  # (1, blk, 128)
-    delta_ref,  # (1, blk, 128)
-    dk_ref,  # out (1, blk, D)
-    dv_ref,  # out (1, blk, Dv)
-    dk_scr,  # VMEM (blk, D) f32
-    dv_scr,  # VMEM (blk, Dv) f32
+    do_ref,  # (1, heads, blk, Dv)
+    lse_ref,  # (1, heads, 1, blk): a head's rows side by side
+    delta_ref,  # (1, heads, 1, blk)
+    dq_ref,  # out (1, heads, blk, D)
+    dk_ref,  # out (1, T, D): the key-value head's, whole
+    dv_ref,  # out (1, T, Dv)
+    dq_scr,  # VMEM (heads, blk, D) f32: across the query block's key blocks
+    dk_scr,  # VMEM (T, D) f32: across the head's query blocks and heads
+    dv_scr,  # VMEM (T, Dv) f32
     *,
     causal: bool,
     window: Optional[int],
     blk: int,
-    n_q: int,
+    n_blk: int,
+    parts: int,
     scale: Optional[float] = None,
 ):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr[:])
-        dv_scr[:] = jnp.zeros_like(dv_scr[:])
-
-    def _compute(masked: bool):
-        f32 = jnp.float32
-        p, ds = _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          qi, ki, blk=blk, window=window, masked=masked,
-                          scale=scale)
-        io_dtype = q_ref.dtype
-        # dv += p^T @ do   (contract the query rows)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(io_dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=f32)
-        # dk += ds^T @ q
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(io_dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=f32)
-
-    # query blocks outside the band contribute nothing to this K/V
-    # block's gradients — skip their matmuls
-    _banded(causal, qi, ki, blk, window, _compute)
-
-    @pl.when(qi == n_q - 1)
-    def _flush():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _dq_kernel(
-    q_ref,  # (1, blk, D) — the fixed query block qi
-    k_ref,  # (1, blk, D) — key block ki
-    v_ref,  # (1, blk, Dv)
-    do_ref,  # (1, blk, Dv)
-    lse_ref,  # (1, blk, 128)
-    delta_ref,  # (1, blk, 128)
-    dq_ref,  # out (1, blk, D)
-    dq_scr,  # VMEM (blk, D) f32
-    *,
-    causal: bool,
-    window: Optional[int],
-    blk: int,
-    n_k: int,
-    scale: Optional[float] = None,
-):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[1]
+    if scale is None:
+        scale = 1.0 / (q_ref.shape[-1] ** 0.5)
+    rows = pl.ds(pl.multiple_of(ki * blk, blk), blk)
+    # a group walked in parts adds every part into the one scratch
+    first_part = True if parts == 1 else step % parts == 0
+    last_part = True if parts == 1 else step % parts == parts - 1
 
     @pl.when(ki == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr[:])
+    def _init_dq():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    # the first query block that sees key block ki zeroes its rows
+    @pl.when((qi == (ki if causal else 0)) & first_part)
+    def _init_dkv():
+        dk_scr[rows] = jnp.zeros((blk, dk_scr.shape[1]), dk_scr.dtype)
+        dv_scr[rows] = jnp.zeros((blk, dv_scr.shape[1]), dv_scr.dtype)
 
     def _compute(masked: bool):
-        _, ds = _p_and_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          qi, ki, blk=blk, window=window, masked=masked,
-                          scale=scale)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(q_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        k, v = k_ref[0], v_ref[0]
+        # one mask a grid step, for every head of it
+        keep = _keep(qi, ki, blk, blk, window, by_key=True) if masked else None
 
-    # key blocks outside the band are fully masked for this query
-    # block — no dq contribution, skip the matmuls
-    _banded(causal, qi, ki, blk, window, _compute)
+        for h in range(heads):
+            # (blk, blk), a key a row: s^T = k q^T and dp^T = v do^T
+            s = jax.lax.dot_general(
+                k, q_ref[0, h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if masked:
+                # -inf where masked: under the finite lse (_NEG where a
+                # row saw no key) exp gives exactly zero, and so is ds
+                s = jnp.where(keep, s, -jnp.inf)
+            p = jnp.exp(s - lse_ref[0, h])
+            dp = jax.lax.dot_general(
+                v, do_ref[0, h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, h]) * scale).astype(k.dtype)
+            # dv += p^T do and dk += ds^T q as they stand; dq += ds k
+            # contracts the keys, the one transposed operand of the five
+            dv_scr[rows] += jax.lax.dot_general(
+                p.astype(k.dtype), do_ref[0, h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[rows] += jax.lax.dot_general(
+                ds, q_ref[0, h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq_scr[h] += jax.lax.dot_general(
+                ds, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(ki == n_k - 1)
-    def _flush():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+    # blocks outside the band give nothing to any gradient: no MXU or
+    # VPU work; blocks wholly inside it skip the mask
+    if causal:
+        inside = _interior(qi, ki, blk, blk, window)
+        pl.when(inside)(lambda: _compute(False))
+        pl.when(_visible(qi, ki, blk, blk, window) & ~inside)(
+            lambda: _compute(True))
+    else:
+        _compute(False)
+
+    @pl.when(ki == n_blk - 1)
+    def _flush_dq():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+    # the last query block's grid steps pass every key block
+    @pl.when((qi == n_blk - 1) & last_part)
+    def _flush_dkv():
+        dk_ref[0, rows] = dk_scr[rows].astype(dk_ref.dtype)
+        dv_ref[0, rows] = dv_scr[rows].astype(dv_ref.dtype)
 
 
+@functools.partial(
+    jax.jit, static_argnames=("causal", "window", "interpret", "scale"))
 def _bwd_impl(
     q, k, v, o, lse, do, dlse=None, *, causal: bool,
     window: Optional[int], interpret: bool, scale: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """q, do (BN, T, .), k, v (BG, T, .), lse (BN, T) -> dq, dk, dv in
+    the inputs' dtypes.  A ``jax.jit`` of its own, as the forward's."""
     bn, t, d = q.shape
     dv = v.shape[-1]
-    bg = k.shape[0]
-    group = bn // bg
+    group = bn // k.shape[0]
     blk = block_for(t)
-    n_blk = t // blk
-    # delta = rowsum(do * o): cheap elementwise+reduce, plain XLA; ride
-    # it in lane-replicated, matching lse's layout.  An lse cotangent
-    # (the ring path differentiates through the per-block logsumexp)
-    # folds in for free: d lse_i / d s_ij = p_ij, so
+    heads = bwd_heads_a_step(group, t, d, dv, q.dtype.itemsize)
+    if heads < group:
+        count_kernel_fallback("attention", "group_in_parts")
+    steps, parts = bn // heads, group // heads
+    # delta = rowsum(do * o): cheap elementwise+reduce, plain XLA.  An
+    # lse cotangent (the ring path differentiates through the per-block
+    # logsumexp) folds in for free: d lse_i / d s_ij = p_ij, so
     # ds = p * (dp - delta + dlse) * scale — i.e. delta -= dlse.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    delta = jnp.broadcast_to(delta[..., None], (bn, t, 128))
-    clamp = dict(causal=causal, blk=blk, window=window)
 
-    def q_rows(width):  # the dK/dV sweep's per-query-block operands
-        return pl.BlockSpec((1, blk, width), lambda b, ki, qi: (
-            b, _clamp_query_block(ki, qi, n_q=n_blk, **clamp), 0))
+    def q_rows(width):
+        return pl.BlockSpec((1, heads, blk, width),
+                            lambda b, qi, ki: (b, 0, qi, 0))
 
-    def kspec(width):  # the fixed key block's operands
-        return pl.BlockSpec((1, blk, width),
-                            lambda b, ki, qi: (b // group, ki, 0))
+    # lse and delta ride as they are kept, one float32 a head and row: a
+    # head's (1, blk) block lies along the lanes of the transposed scores
+    a_row = pl.BlockSpec((1, heads, 1, blk), lambda b, qi, ki: (b, 0, 0, qi))
 
-    # one partial per query head; a group's partials are summed below, in
-    # float32 where there is more than one
-    part = q.dtype if group == 1 else jnp.float32
-    dk, dv_ = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, window=window,
-                          blk=blk, n_q=n_blk, **_stated(scale)),
-        name="flash_bwd_dkv",
-        grid=(bn, n_blk, n_blk),
-        in_specs=[q_rows(d), kspec(d), kspec(dv), q_rows(dv), q_rows(128),
-                  q_rows(128)],
-        out_specs=[
-            pl.BlockSpec((1, blk, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, blk, dv), lambda b, ki, qi: (b, ki, 0)),
+    def kv_index(b, qi, ki):
+        if causal:
+            ki = _key_block(qi, ki, blk, blk, window)
+        return ((b * heads) // group, ki, 0)
+
+    def kv_whole(width):  # goes back to HBM when the head changes
+        return pl.BlockSpec((1, t, width),
+                            lambda b, qi, ki: ((b * heads) // group, 0, 0))
+
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, causal=causal, window=window, blk=blk,
+            n_blk=t // blk, parts=parts, **_stated(scale)),
+        name="flash_bwd",
+        grid=(steps, t // blk, t // blk),
+        in_specs=[
+            q_rows(d),
+            pl.BlockSpec((1, blk, d), kv_index),
+            pl.BlockSpec((1, blk, dv), kv_index),
+            q_rows(dv), a_row, a_row,
         ],
+        out_specs=[q_rows(d), kv_whole(d), kv_whole(dv)],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, t, d), part),
-            jax.ShapeDtypeStruct((bn, t, dv), part),
+            jax.ShapeDtypeStruct((steps, heads, t, d), q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk, d), jnp.float32),
-            pltpu.VMEM((blk, dv), jnp.float32),
+            pltpu.VMEM((heads, blk, d), jnp.float32),
+            pltpu.VMEM((t, d), jnp.float32),
+            pltpu.VMEM((t, dv), jnp.float32),
         ],
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    if group > 1:
-        dk, dv_ = (x.reshape(bg, group, t, x.shape[-1]).sum(axis=1)
-                   .astype(k.dtype) for x in (dk, dv_))
-
-    def k_rows(b, qi, ki):
-        return (b // group, _clamp_key_block(qi, ki, **clamp), 0)
-
-    qspec2 = pl.BlockSpec((1, blk, d), lambda b, qi, ki: (b, qi, 0))
-    dospec2 = pl.BlockSpec((1, blk, dv), lambda b, qi, ki: (b, qi, 0))
-    kspec2 = pl.BlockSpec((1, blk, d), k_rows)
-    vspec2 = pl.BlockSpec((1, blk, dv), k_rows)
-    rspec2 = pl.BlockSpec((1, blk, 128), lambda b, qi, ki: (b, qi, 0))
-    (dq,) = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, window=window,
-                          blk=blk, n_k=n_blk, **_stated(scale)),
-        name="flash_bwd_dq",
-        grid=(bn, n_blk, n_blk),
-        in_specs=[qspec2, kspec2, vspec2, dospec2, rspec2, rspec2],
-        out_specs=[qspec2],
-        out_shape=[jax.ShapeDtypeStruct((bn, t, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv_
+    )(q.reshape(steps, heads, t, d), k, v, do.reshape(steps, heads, t, dv),
+      lse.reshape(steps, heads, 1, t), delta.reshape(steps, heads, 1, t))
+    return dq.reshape(bn, t, d), dk, dv_
 
 
 def _stated(scale: Optional[float]) -> dict:
@@ -676,7 +659,6 @@ def _flash_fwd(q, k, v, causal, window, interpret, scale=None):
 def _flash_bwd(causal, window, interpret, scale, residuals, cts):
     q, k, v, o, lse = residuals
     do, dlse = cts
-    lse = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
     return _bwd_impl(q, k, v, o, lse, do, dlse, causal=causal,
                      window=window, interpret=interpret, scale=scale)
 
@@ -738,7 +720,7 @@ def flash_attention_with_lse(
     """
     b, n, t, d = q.shape
     g = k.shape[1]
-    if not flash_supported(q.shape[-2], k.shape[-2], d):
+    if not flash_supported(q.shape[-2], k.shape[-2], d, v.shape[-1]):
         raise ValueError(
             f"flash kernel unsupported for Tq={q.shape[-2]} "
             f"Tk={k.shape[-2]} D={d}; gate on flash_supported()")

@@ -560,13 +560,40 @@ def test_forward_block_pair_follows_the_length(seq, pair):
     assert pair[0] == block_for(seq)  # the backward's, and the query's
 
 
-def test_flash_supported_is_what_it_was():
-    """The gate knows nothing of the forward's blocks or heads a step."""
-    for t in (128, 384, 1024, 1536, 8192, 100, 8200):
+@pytest.mark.parametrize("seq,heads,d,dv,itemsize,mib,ok", [
+    (8192, 7, 128, 128, 2, 27.9375, True),     # smallthinker_train_8k
+    (8192, 1, 192, 128, 2, 26.0625, True),     # moonlight_train_8k
+    (4096, 1, 192, 128, 2, 16.0625, True),     # xing_train_4k
+    (8192, 4, 64, 64, 2, 14.5, True),      # granite_h_train_8k
+    (16384, 1, 128, 128, 4, 54.8125, True),   # the gate's count: inside
+    (20480, 1, 128, 128, 4, 66.8125, False),  # and past the 64 MiB stated
+    (32768, 1, 128, 128, 2, 69.5625, False),   # bfloat16 does not save it
+])
+def test_backward_residents_by_count_and_the_bound_they_set(
+        seq, heads, d, dv, itemsize, mib, ok):
+    """What ``flash_bwd`` keeps in VMEM, counted from the shape: the four
+    cells' sizes, and the two sides of the bound ``flash_supported``
+    learns from the count (one head a step, four-byte elements), said in
+    ``attention:backward_not_resident`` where it refuses."""
+    from fmda_tpu.ops import pallas_attention as pa
+    from fmda_tpu.ops.dispatch import kernel_fallbacks, reset_kernel_fallbacks
+
+    resident = pa._bwd_resident_bytes(seq, heads, d, dv, itemsize)
+    assert resident == mib * 2 ** 20
+    assert (resident <= pa._VMEM_LIMIT) == ok
+    reset_kernel_fallbacks()
+    assert flash_supported(seq, seq, d, dv) == (
+        pa._bwd_resident_bytes(seq, 1, d, dv, 4) <= pa._VMEM_LIMIT)
+    assert kernel_fallbacks() == (
+        {} if seq <= 16384 else {"attention:backward_not_resident": 1})
+    reset_kernel_fallbacks()
+    # the rest of the gate is what it was, and counts nothing
+    for t in (128, 384, 1536, 100, 8200):
         for tk in (t, 2 * t):
-            for d in (8, 64, 192, 512, 513):
-                assert flash_supported(t, tk, d) == (
-                    t == tk and t % 128 == 0 and d <= 512), (t, tk, d)
+            for width in (8, 64, 192, 512, 513):
+                assert flash_supported(t, tk, width) == (
+                    t == tk and t % 128 == 0 and width <= 512), (t, tk, width)
+    assert kernel_fallbacks() == {}
 
 
 @pytest.mark.parametrize("group,d,dv,itemsize,heads", [
@@ -617,5 +644,87 @@ def test_trace_time_counters_say_what_a_shape_did_not_get(
     jax.eval_shape(functools.partial(
         _fwd_impl.__wrapped__, causal=True, window=None, interpret=True),
         shape(heads), shape(kv_heads), shape(kv_heads))
+    assert kernel_fallbacks() == counted
+    reset_kernel_fallbacks()
+
+
+# -- the backward in one sweep (PR 47) -----------------------------------------
+
+#: heads, kv heads, seq, D, Dv, causal, window
+ONE_SWEEP_CASES = {
+    # three query blocks of 128 add seven heads each into a key block's rows
+    "group7_three_query_blocks": (7, 1, 384, 16, 16, True, None),
+    "group7_not_causal": (7, 1, 384, 16, 16, False, None),
+    # one block: zeroed, added to and written in the same grid step
+    "one_block_group1": (2, 2, 128, 16, 16, True, None),
+    "one_block_group4_not_causal": (4, 1, 128, 16, 16, False, None),
+    # a key block's rows are zeroed by its own query block and the later
+    # query blocks, past the window's low edge, never add to them
+    "window_inside_a_block": (4, 2, 512, 16, 16, True, 60),
+    "window_of_a_block_and_a_half": (4, 2, 512, 16, 16, True, 192),
+    # dk is D wide, dv Dv, both summed over a group
+    "group4_values_wider": (4, 1, 256, 16, 40, True, None),
+    "group4_values_narrower_window": (8, 2, 256, 24, 8, True, 100),
+    # sixteen heads on one key-value head: two grid steps of eight add
+    # into the one scratch, the second writes it
+    "group16_in_two_parts": (16, 1, 256, 16, 16, True, None),
+    "group32_in_four_parts_two_kv_heads": (32, 2, 128, 8, 8, True, 50),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_SWEEP_CASES))
+def test_one_sweep_backward_matches_masked_attention(case):
+    """dQ, dK and dV of ``flash_bwd`` (interpreter) through both outputs
+    against explicit-mask attention with repeated key-value heads."""
+    from fmda_tpu.ops.pallas_attention import flash_attention_with_lse
+
+    heads, kv_heads, seq, d, dv, causal, window = ONE_SWEEP_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    q = jax.random.normal(ks[0], (2, heads, seq, d))
+    k = jax.random.normal(ks[1], (2, kv_heads, seq, d))
+    v = jax.random.normal(ks[2], (2, kv_heads, seq, dv))
+    kw = dict(causal=causal, window=window, scale=None)
+    got = _both_outputs(lambda *a: flash_attention_with_lse(
+        *a, interpret=True, **kw), q, k, v)
+    want = _both_outputs(
+        lambda *a: _reference_with_lse(*a, **kw), q, k, v)
+    for a, b, name in zip(got, want, ("o", "lse", "dq", "dk", "dv")):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
+                                   err_msg=f"{case}: {name}")
+
+
+@pytest.mark.parametrize("group,seq,d,dv,itemsize,heads", [
+    (7, 8192, 128, 128, 2, 7),     # smallthinker_train_8k: the whole group
+    (4, 8192, 64, 64, 2, 4),       # granite_h_train_8k
+    (1, 8192, 192, 128, 2, 1),     # moonlight_train_8k; xing_train_4k at 4,096
+    (16, 2048, 128, 128, 2, 8),    # eight unrolled heads at most
+    (12, 2048, 128, 128, 2, 6),    # the largest divisor under the cap
+    (8, 2048, 512, 512, 4, 4),     # wide float32 heads: what VMEM holds
+    (8, 16384, 128, 128, 4, 4),    # near the bound the scratches leave less
+])
+def test_heads_a_backward_step(group, seq, d, dv, itemsize, heads):
+    from fmda_tpu.ops.pallas_attention import bwd_heads_a_step
+
+    assert bwd_heads_a_step(group, seq, d, dv, itemsize) == heads
+
+
+@pytest.mark.parametrize("heads,kv_heads,seq,counted", [
+    (7, 1, 2048, {}),                                   # group 7
+    (2, 2, 1024, {}),                                   # group 1
+    (4, 2, 384, {}),                                    # square blocks anyway
+    (16, 1, 256, {"attention:group_in_parts": 1}),      # two steps of eight
+])
+def test_the_backward_says_when_a_group_is_walked_in_parts(
+        heads, kv_heads, seq, counted):
+    from fmda_tpu.ops.dispatch import kernel_fallbacks, reset_kernel_fallbacks
+    from fmda_tpu.ops.pallas_attention import _bwd_impl
+
+    reset_kernel_fallbacks()
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    jax.eval_shape(functools.partial(
+        _bwd_impl.__wrapped__, causal=True, window=None, interpret=True),
+        shape(heads, seq, 16), shape(kv_heads, seq, 16),
+        shape(kv_heads, seq, 16), shape(heads, seq, 16), shape(heads, seq),
+        shape(heads, seq, 16))
     assert kernel_fallbacks() == counted
     reset_kernel_fallbacks()

@@ -29,6 +29,22 @@ def _shape(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` in ``fn``'s trace, by its name."""
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
 @pytest.mark.parametrize("window", [None, 4096])
 def test_flash_kernels_compile_at_28_on_4_heads_of_128_by_8192(
         one_chip, window):
@@ -44,7 +60,7 @@ def test_flash_kernels_compile_at_28_on_4_heads_of_128_by_8192(
         _shape(one_chip, (1, 4, 8192, 128), BF16),
         _shape(one_chip, (1, 4, 8192, 128), BF16)).compile()
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    for name in ("flash_fwd", "flash_bwd"):
         assert name in text
 
 
@@ -76,6 +92,62 @@ def test_the_forward_alone_compiles_by_group_and_says_what_it_did_not_get(
     reset_kernel_fallbacks()
 
 
+@pytest.mark.parametrize("heads,kv_heads,seq,d,dv,window,grids", [
+    # smallthinker_train_8k, its full and its window layers
+    (28, 4, 8192, 128, 128, None, {"flash_fwd": (4, 16, 8),
+                                   "flash_bwd": (4, 16, 16)}),
+    (28, 4, 8192, 128, 128, 4096, {"flash_fwd": (4, 16, 8),
+                                   "flash_bwd": (4, 16, 16)}),
+    # moonlight_train_8k and xing_train_4k: a group of one
+    (16, 16, 8192, 192, 128, None, {"flash_fwd": (16, 16, 8),
+                                    "flash_bwd": (16, 16, 16)}),
+    (32, 32, 4096, 192, 128, None, {"flash_fwd": (32, 8, 4),
+                                    "flash_bwd": (32, 8, 8)}),
+    # granite_h_train_8k
+    (32, 8, 8192, 64, 64, None, {"flash_fwd": (8, 16, 8),
+                                 "flash_bwd": (8, 16, 16)}),
+])
+def test_the_backward_is_one_sweep_at_the_four_cells_shapes(
+        one_chip, heads, kv_heads, seq, d, dv, window, grids):
+    """``flash_bwd`` at each cell's head layout: the grid as traced
+    (key-value heads, query blocks, key blocks), one custom call a kernel
+    inside the VMEM limit it states, a key-value head's ``dk`` and ``dv``
+    summed over its group in the kernel's scratch — no float32 partial a
+    query head in the compiled text — and nothing counted."""
+    import re
+
+    from fmda_tpu.ops import pallas_attention as kernels
+    from fmda_tpu.ops.dispatch import kernel_fallbacks, reset_kernel_fallbacks
+
+    reset_kernel_fallbacks()
+
+    def step(q, k, v):
+        return jax.value_and_grad(lambda *a: kernels.flash_attention(
+            *a, causal=True, window=window).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)
+
+    args = (_shape(one_chip, (1, heads, seq, d), BF16),
+            _shape(one_chip, (1, kv_heads, seq, d), BF16),
+            _shape(one_chip, (1, kv_heads, seq, dv), BF16))
+    assert _pallas_grids(step, *args) == grids
+    text = jax.jit(step).lower(*args).compile().as_text()
+    for name in grids:
+        lines = re.findall(
+            rf"(?m)^\s*%\S*{name}\S* = .*custom-call\(.*$", text)
+        assert len(lines) == 1, name
+        assert f'"size":"{kernels._VMEM_LIMIT}"' in lines[0], name
+    # what the backward writes: dq a query head, dk and dv a key-value
+    # head, all in the compute dtype (the parent's dK/dV sweep wrote
+    # f32[heads, T, D] partials that XLA summed outside)
+    outputs = re.search(
+        r"(?m)^\s*%\S*flash_bwd\S* = (.*?)custom-call\(", text).group(1)
+    assert "f32[" not in outputs and outputs.count("bf16[") == 3, outputs
+    assert f"bf16[{kv_heads},{seq},{d}]" in outputs
+    assert f"bf16[{kv_heads},{seq},{dv}]" in outputs
+    assert kernel_fallbacks() == {}
+    reset_kernel_fallbacks()
+
+
 def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
         one_chip):
     """Attention over picked keys, forward and backward, at the
@@ -101,17 +173,7 @@ def test_sparse_attention_kernels_compile_at_32_on_4_heads_of_128_by_16384(
             _shape(one_chip, (1, t, t), jnp.int8))
     # the grids as traced: (key-value heads, query blocks, key blocks);
     # one backward sweep, dk / dv held in VMEM for the whole sequence
-    grids = {}
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                grids[eqn.params["name"]] = tuple(
-                    eqn.params["grid_mapping"].grid)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(step)(*args).jaxpr)
+    grids = _pallas_grids(step, *args)
     assert grids == {"sparse_fwd": (4, 64, 16), "sparse_bwd": (4, 64, 32)}
     compiled = jax.jit(step).lower(*args).compile()
     text = compiled.as_text()
@@ -280,7 +342,7 @@ def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
         replayed = [c for c in calls if "rematted_computation" in c]
         assert len(replayed) == (runs - 1) * len(layout), name
     # the backward kernels read what was kept: one run a layer either way
-    bwd = "sparse_bwd" if 2 in layout else "flash_bwd_dq"
+    bwd = "sparse_bwd" if 2 in layout else "flash_bwd"
     assert len(re.findall(
         rf"(?m)^\s*%{bwd}(?:\.\d+)? = .*custom-call\(", text)) == len(layout)
 
@@ -303,7 +365,7 @@ def test_flash_kernels_compile_at_32_on_8_heads_of_64_with_a_stated_scale(
         _shape(one_chip, (1, 8, 8192, 64), BF16),
         _shape(one_chip, (1, 8, 8192, 64), BF16)).compile()
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    for name in ("flash_fwd", "flash_bwd"):
         assert name in text
 
 
@@ -445,7 +507,7 @@ def test_flash_kernels_compile_at_32_heads_of_192_on_values_of_128_by_4096(
         _shape(one_chip, (1, 32, 4096, 192), BF16),
         _shape(one_chip, (1, 32, 4096, 128), BF16)).compile()
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+    for name in ("flash_fwd", "flash_bwd"):
         assert name in text
     assert "bf16[32,4096,256]" not in text and "bf16[1,32,4096,256]" not in text
 
@@ -503,7 +565,7 @@ def test_a_recomputed_latent_decoder_step_runs_the_core_once_a_layer(
         r"(?m)^\s*%flash_fwd(?:\.\d+)? = .*custom-call\(.*$", text)
     assert len(calls) == runs * 3, len(calls)
     assert len(re.findall(
-        r"(?m)^\s*%flash_bwd_dq(?:\.\d+)? = .*custom-call\(", text)) == 3
+        r"(?m)^\s*%flash_bwd(?:\.\d+)? = .*custom-call\(", text)) == 3
 
 
 def test_the_hyper_connections_backward_compiles_at_4_lanes_of_3584_by_4096(
