@@ -169,7 +169,7 @@ import numpy as np
 from fmda_tpu.config import ModelConfig
 from fmda_tpu.ops.attention import CORE_LSE, CORE_OUT, mha
 from fmda_tpu.ops.moe import (
-    ACTIVATIONS, expert_layer, kernel_impl, route, router_load,
+    ACTIVATIONS, EXPERT_OUT, expert_layer, kernel_impl, route, router_load,
     seq_balance_term)
 from fmda_tpu.ops.sparse_attention import (
     PICKS, kernels_dispatch, select_keys, sparse_mha)
@@ -202,15 +202,15 @@ SSM_LAYOUT = 3
 LATENT_LAYOUT = 4
 
 #: What a block's recomputation (``cfg.remat``) keeps from the forward
-#: pass, by name; everything else it remakes from the block's input.
-#: These are what attention's backward reads and only a second run of
-#: the attention core (and, in a learned-sparse layer, of the indexer
-#: and the selection) could remake: the core's output (heads x head_dim
-#: wide, in the compute dtype), its rows' logsumexp (a float32 a head
-#: and row; the learned-sparse kernel's packed tile, 128 lanes a row and
-#: kv head) and a learned-sparse layer's picks (int8, T x T).  A layer
-#: puts under a name what it has: one list serves every layout.
-REPLAY_KEEPS = (CORE_OUT, CORE_LSE, PICKS)
+#: pass, by name; everything else it remakes from the block's input:
+#: what attention's backward reads and only a second run of the core (in
+#: a learned-sparse layer, of the indexer and the selection) could remake
+#: (the core's output, heads x head_dim wide in the compute dtype; its
+#: rows' logsumexp, a float32 a head and row or the learned-sparse
+#: kernel's packed tile; a learned-sparse layer's picks, int8, T x T), and
+#: the expert layer's output where the lanes' mixing reads it in backward.
+#: A layer puts under a name what it has: one list serves every layout.
+REPLAY_KEEPS = (CORE_OUT, CORE_LSE, PICKS, EXPERT_OUT)
 
 
 def _weight(module: nn.Module, name: str, shape: Tuple[int, ...],
@@ -312,13 +312,13 @@ def _split_sum(kept: jax.Array) -> jax.Array:
 
 #: What an expert layer counts (:func:`feed_forward`): the pairs it
 #: computed on each held expert, the held pairs it did not compute (0),
-#: and the row tiles of its layout that held a group.
+#: the row tiles that held a group and the rounds of its layout they took.
 EXPERT_COUNTS: Dict[str, Count] = {
     "expert_pairs": Count(jnp.int32, lambda cfg: (cfg.experts_held[1],),
                           _has_experts),
     "dropped": Count(jnp.int32, where=_has_experts, stacked=False),
-    "row_tiles_used": Count(jnp.int32, where=_has_experts,
-                            settle=lambda n_used: n_used[0]),
+    "row_tiles_used": Count(jnp.int32, where=_has_experts),
+    "layout_rounds": Count(jnp.int32, where=_has_experts),
 }
 
 
@@ -718,13 +718,13 @@ def feed_forward(module: nn.Module, cfg: ModelConfig, u: jax.Array, *,
     first, count = cfg.experts_held
     flat = u.reshape(b * t, d)
     (gates, experts, *scores), bias = routed or routing(module, cfg, flat)
-    m, plan = expert_layer(
+    m, laid = expert_layer(
         flat, gates, experts,
         _weight(module, "w_gate", (count, d, f)),
         _weight(module, "w_up", (count, d, f)),
         _weight(module, "w_down", (count, f, d)),
         experts_held=(first, count), impl=kernel_impl(cfg.use_pallas),
-        act=cfg.hidden_act)
+        act=cfg.hidden_act, n_experts=cfg.moe_experts)
     if cfg.moe_shared_experts:
         shared = _dense_mlp(
             module, cfg, flat, cfg.moe_shared_experts * f,
@@ -732,8 +732,8 @@ def feed_forward(module: nn.Module, cfg: ModelConfig, u: jax.Array, *,
         with jax.named_scope("moe_shared"):
             m = (m.astype(jnp.float32) + shared.astype(jnp.float32)
                  ).astype(u.dtype)
-    counts = dict(_settled(counted), expert_pairs=plan.group_sizes,
-                  dropped=plan.dropped, row_tiles_used=plan.n_used)
+    # EXPERT_COUNTS, under their names
+    counts = dict(_settled(counted), **laid._asdict())
     if load:
         counts["router_bias_absmax"] = (
             jnp.zeros((), jnp.float32) if bias is None
@@ -842,11 +842,11 @@ class DecoderBlock(nn.Module):
         x, counted = sublayer(x, "attn", "ln_attn", mix, kind.scope)
         x, counts = sublayer(x, "ffn", "ln_moe" if experts else "ln_mlp",
                              feed)
-        counts.update(_settled({name: counts[name] for name in EXPERT_COUNTS
-                                if name in counts}))
         # a layer answers for what the model declares of the feed-forward
-        # and of its own kind: zeros where it did not count (a dense layer
-        # of a model with experts)
+        # and of its own kind, each in its declared form (feed_forward
+        # settles the mixer's, the expert layer's come as declared): zeros
+        # where it did not count (a dense layer of a model with experts)
+        # -- of the declared shape, so that the layers' values stack
         declared = model_counts(cfg)
         for name, count in {**EXPERT_COUNTS, **kind.counts}.items():
             if name in declared and name not in counts:

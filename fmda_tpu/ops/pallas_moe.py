@@ -5,9 +5,9 @@ pairs that landed on held experts out as *rows grouped by expert*, each
 group padded to whole row tiles, so that a tile of ``tile`` rows belongs
 to exactly one expert.  Two tables ride ahead of the grid as scalar
 prefetch: ``tile_expert[i]``, the held expert of row tile ``i``, and
-``n_used``, how many tiles hold rows at all — the layout is sized for the
-worst routing (every pair on a held expert), and the tiles past
-``n_used`` are skipped.
+``n_used``, how many tiles hold rows at all — the layout is sized for
+twice the even share of a call's pairs (and filled in rounds: a grid is
+one round's), and the tiles past ``n_used`` are skipped.
 
 - ``moe_gmm`` — ``y[tile i] = x[tile i] @ w[tile_expert[i]]`` (or
   ``@ w[...]^T``: the same kernel gives the backward's ``dx``).  Grid

@@ -81,10 +81,8 @@ class TokenTotals(NamedTuple):
     """A token pass's running totals: each step's mean loss, the tokens
     that counted and those predicted right, and what the layers counted,
     an attribute a name of :data:`fmda_tpu.models.decoder.COUNTS` (which
-    says what each is), None where the model does not count it: the
-    expert layers' three, a learned-sparse selection's two, a state-space
-    scan's two, a latent-attention model's four (two of them folded by
-    ``max``, not by sum: :data:`FOLDED_BY_MAX`).  Last, the loss terms the
+    says what each is; two are folded by ``max``: :data:`FOLDED_BY_MAX`),
+    None where the model does not count it.  Last, the loss terms the
     layers declare (:func:`fmda_tpu.models.decoder.model_terms`): each
     step's value a layer, the mean over the step's sequences, as the
     objective took it; ``loss`` stays the next-token loss."""
@@ -95,6 +93,7 @@ class TokenTotals(NamedTuple):
     expert_pairs: Optional[jax.Array] = None  # (layers, held experts) int32
     dropped: Optional[jax.Array] = None       # () int32
     row_tiles_used: Optional[jax.Array] = None  # (layers,) int32
+    layout_rounds: Optional[jax.Array] = None   # (layers,) int32
     sparse_keys_kept: Optional[jax.Array] = None   # (layers, 2) int32
     sparse_query_rows: Optional[jax.Array] = None  # (layers,) int32
     ssd_chunks: Optional[jax.Array] = None     # (layers,) int32
@@ -116,6 +115,7 @@ FOLDED_BY_MAX = tuple(
 #: where a pass folds it by ``max``.
 PUBLISHED = {
     "row_tiles_used": "moe_row_tiles_used_total",
+    "layout_rounds": "moe_layout_rounds_total",
     "sparse_query_rows": "sparse_query_rows_total",
     "ssd_chunks": "ssd_chunks_total",
     "ssd_positions": "ssd_positions_total",
@@ -316,20 +316,20 @@ class NextToken:
                     reg.counter(metric, **labels).inc(int(value))
         if "expert_pairs" in declared:
             reg.counter("moe_pairs_dropped_total").inc(int(totals.dropped))
-            # a forward pass lays its tokens out once a layer: the whole
-            # batch, or each microbatch of an accumulated train step
-            passes = tc.accum_steps if phase == "train" else 1
-            layout = steps * passes * layout_tiles(
-                tc.batch_size // passes * tc.window * mc.moe_top_k,
-                mc.experts_held[1])
         for labels, pairs in by_layer("expert_pairs"):
             reg.counter("moe_pairs_held_total", **labels).inc(
                 int(pairs.sum()))
             reg.gauge("moe_expert_pairs_max", **labels).set(int(pairs.max()))
-            # moe_row_tiles_used_total / this: the share of the row layout
-            # the layer's row passes touched (1.0: every tile, as if they
-            # were unbounded)
-            reg.counter("moe_row_tiles_layout_total", **labels).inc(layout)
+        # a layer's calls a step: one, or one a microbatch it accumulates
+        passes = tc.accum_steps if phase == "train" else 1
+        for labels, rounds in by_layer("layout_rounds"):
+            # rounds over calls: 1.0 where every call fitted the layout once;
+            # tiles used over tiles laid out: the share that held a group
+            reg.counter("moe_layer_calls_total", **labels).inc(steps * passes)
+            reg.counter("moe_row_tiles_layout_total", **labels).inc(
+                int(rounds) * layout_tiles(
+                    tc.batch_size // passes * tc.window * mc.moe_top_k,
+                    mc.experts_held[1], mc.moe_experts))
         # a learned-sparse layer's selection: kept / (the rows' causal
         # pairs) is the share of the triangle the heads attend over
         for labels, halves in by_layer("sparse_keys_kept"):

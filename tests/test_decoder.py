@@ -248,36 +248,41 @@ PINNED_KINDS = {
 #: less the ``block_1._method`` components flax writes for a module's
 #: method, which name no scope the code opens and no reader asks for);
 #: the parameter tree at ``PRNGKey(0)`` (paths, shapes, dtypes, bytes).
-#: The first three kinds' text hashes are PR 34's and PR 41's parents';
-#: ``latent_direct``'s are PR 46's own (the kind did not run before it).
+#: ``hybrid``'s hashes and all six ``params`` are still those; the five
+#: kinds with an expert layer have PR 48's own text and scopes (the
+#: layer's rounds in a ``while`` under one ``custom_vjp``, each direction
+#: a ``jax.jit`` of its own: its scopes stand under ``jit(...)/while/
+#: body``, the backward's under ``jvp(moe_*)`` and ``transpose(jvp(
+#: moe_*))`` there; the output named for the replay; a fourth count,
+#: ``layout_rounds``).
 #: A pin that moves means the change altered the program:
 #: regenerate (``_program_pins(kind)``) only after a deliberate change to
 #: these layers, their task or the step function.
 PINNED = {
     "routed": dict(
         params="733593a24ee9f9e9",
-        train_text="76a140ee75bf588c", train_scopes="ee0a831e3dffa8a1",
-        eval_text="5699bc3b43b95178", eval_scopes="abd3c87e847b2834"),
+        train_text="bb42a182719ea2b2", train_scopes="8540add247c1a98a",
+        eval_text="9d04e135fe4b39c9", eval_scopes="79063c661c74d701"),
     "learned_sparse": dict(
         params="c338591f00479041",
-        train_text="f9fc79c631ca6670", train_scopes="16477f079b833fea",
-        eval_text="8b169ac148cd8b1c", eval_scopes="fb8ad303eccc0d7e"),
+        train_text="441f68d9f4812eb1", train_scopes="43f38c17e1f961c3",
+        eval_text="e4a838a7f34577e5", eval_scopes="81ebb71f65af29da"),
     "hybrid": dict(
         params="7170fa193506754b",
         train_text="c9f228243c080be9", train_scopes="627737dbb0728fbd",
         eval_text="c949e67b3ed09b92", eval_scopes="65a0fce208c4d6ab"),
     "latent": dict(
         params="43dbdb7823a39232",
-        train_text="db71a66c10d37741", train_scopes="b00d2db6086a442e",
-        eval_text="55a5e5ccb8daad7d", eval_scopes="3f052a1b1b05a258"),
+        train_text="48fd7a8239d36c6a", train_scopes="c0bbc76b37228339",
+        eval_text="2e98a77a3d84e2bc", eval_scopes="73af4a412538784b"),
     "latent_plain": dict(
         params="881b57f6db59149d",
-        train_text="eae356acde159301", train_scopes="566a40a3b3816364",
-        eval_text="4dbdd7bc48e5f019", eval_scopes="09c0dcb75690bf72"),
+        train_text="b20fd15e2abb0aac", train_scopes="beec7de9265d76cf",
+        eval_text="8e8ad003a11364ce", eval_scopes="c55711daca10cd61"),
     "latent_direct": dict(
         params="5f93cbac51dae9f8",
-        train_text="ee45915933e4ee56", train_scopes="821054b0ff3ee6ee",
-        eval_text="c3255018e5649468", eval_scopes="090b7a1ba162fd35"),
+        train_text="4a4bfdc2b411fb48", train_scopes="01019bca8d4c5459",
+        eval_text="d2fc6ab77e156cfa", eval_scopes="c14b381fe91fc917"),
 }
 
 
@@ -376,20 +381,17 @@ def test_the_totals_hold_what_the_declaration_lists_and_nothing_else(kind):
     assert set(declared) <= set(COUNTS) <= set(totals._fields)
     for name in COUNTS:
         assert (getattr(totals, name) is not None) == (name in declared), name
+    experts = ["expert_pairs", "dropped", "row_tiles_used", "layout_rounds"]
     want = {
-        "routed": ["expert_pairs", "dropped", "row_tiles_used"],
-        "learned_sparse": ["expert_pairs", "dropped", "row_tiles_used",
-                           "sparse_keys_kept", "sparse_query_rows"],
+        "routed": experts,
+        "learned_sparse": experts + ["sparse_keys_kept", "sparse_query_rows"],
         "hybrid": ["ssd_chunks", "ssd_positions"],
-        "latent": ["expert_pairs", "dropped", "row_tiles_used",
-                   "router_load", "router_bias_absmax", "hc_sum_error",
-                   "latent_pairs"],
-        "latent_plain": ["expert_pairs", "dropped", "row_tiles_used",
-                         "router_load", "router_bias_absmax",
-                         "latent_pairs"],
-        "latent_direct": ["expert_pairs", "dropped", "row_tiles_used",
-                          "router_load", "router_bias_absmax",
-                          "latent_pairs"]}[kind]
+        "latent": experts + ["router_load", "router_bias_absmax",
+                             "hc_sum_error", "latent_pairs"],
+        "latent_plain": experts + ["router_load", "router_bias_absmax",
+                                   "latent_pairs"],
+        "latent_direct": experts + ["router_load", "router_bias_absmax",
+                                    "latent_pairs"]}[kind]
     assert list(declared) == want  # the order features stacks them in
     # which layers count: the dense first layer of a latent model has no
     # experts; a state-space count comes from the state-space layers

@@ -50,7 +50,8 @@ def _layer(w, first, count, impl="jnp", top_k=K, act="relu"):
     held = slice(first, first + count)
     return moe.expert_layer(
         w["u"], gates, experts, w["w_gate"][held], w["w_up"][held],
-        w["w_down"][held], experts_held=(first, count), impl=impl, act=act)
+        w["w_down"][held], experts_held=(first, count), n_experts=E,
+        impl=impl, act=act)
 
 
 @pytest.mark.parametrize("act", ["relu", "silu"])
@@ -67,13 +68,13 @@ def test_the_shares_of_four_chips_add_up_to_the_uncut_layer(shares, act):
         whole = _dense(w, 0, E, act=act)
         parts = [_layer(w, first, each, act=act)[0]
                  for first in range(0, E, each)]
-        uncut, plan = _layer(w, 0, E, act=act)
+        uncut, laid = _layer(w, 0, E, act=act)
     assert len(parts) == shares
     if act == "silu":  # and the activation is really another layer
         assert float(jnp.abs(whole - _dense(w, 0, E)).max()) > 1e-3
     np.testing.assert_allclose(sum(parts), whole, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(uncut, whole, rtol=1e-5, atol=1e-6)
-    assert int(plan.group_sizes.sum()) == T * K  # every pair, once
+    assert int(laid.expert_pairs.sum()) == T * K  # every pair, once
 
 
 @pytest.mark.parametrize("impl", ["jnp", "interpret"])
@@ -103,11 +104,11 @@ def test_no_pair_dropped_when_one_expert_takes_most_tokens(impl):
     w["router"] = w["router"] - 1.0
     w["router"] = w["router"].at[:, 3].set(5.0)
     with jax.default_matmul_precision("highest"):
-        out, plan = _layer(w, 2, 4, impl)
+        out, laid = _layer(w, 2, 4, impl)
         want = _dense(w, 2, 4)
-    assert int(plan.group_sizes[1]) == T       # expert 3 = held index 1
-    assert int(plan.dropped) == 0
-    assert int(plan.n_used[0]) >= T // moe.default_row_tile(T * K)
+    assert int(laid.expert_pairs[1]) == T      # expert 3 = held index 1
+    assert int(laid.dropped) == 0
+    assert int(laid.row_tiles_used) >= T // moe.default_row_tile(T * K)
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
 
 
@@ -115,7 +116,7 @@ def test_the_plan_places_every_held_pair_in_its_experts_rows():
     experts = jnp.asarray(
         np.random.default_rng(0).integers(0, E, size=(T, K)), jnp.int32)
     tile = 16
-    plan = moe.plan_dispatch(experts, (2, 4), tile)
+    plan = moe.plan_dispatch(experts, (2, 4), E, tile)
     held = (experts >= 2) & (experts < 6)
     np.testing.assert_array_equal(plan.pair_held, held)
     assert int(plan.row_valid.sum()) == int(held.sum())
@@ -126,7 +127,7 @@ def test_the_plan_places_every_held_pair_in_its_experts_rows():
     back = np.asarray(plan.row_pair)[rows]                  # and back again
     np.testing.assert_array_equal(
         back, np.flatnonzero(np.asarray(held).reshape(-1)))
-    assert plan.row_pair.shape[0] == moe.layout_rows(T * K, 4, tile)
+    assert plan.row_pair.shape[0] == moe.layout_rows(T * K, 4, E, tile)
 
 
 def test_an_expert_with_no_pair_gets_a_zero_gradient_not_garbage():
@@ -209,7 +210,7 @@ def _plan(kind):
     experts, held = _routing(kind)
     experts = jnp.asarray(experts, jnp.int32)
     return experts, held, moe.plan_dispatch(
-        experts, held, moe.default_row_tile(T * K))
+        experts, held, E, moe.default_row_tile(T * K))
 
 
 def _plain_gather(u, plan):
@@ -229,7 +230,7 @@ def test_the_routings_are_what_their_names_say():
     sizes = {kind: np.asarray(_plan(kind)[2].group_sizes)
              for kind in ROUTINGS}
     used = {kind: int(_plan(kind)[2].n_used[0]) for kind in ROUTINGS}
-    n_tiles = moe.layout_tiles(T * K, 4)
+    n_tiles = moe.layout_tiles(T * K, 4, E)
     assert sizes["one_expert_takes_most"][1] == T
     assert sizes["an_expert_without_a_pair"][2] == 0
     assert sizes["every_pair_held"].sum() == T * K
@@ -237,7 +238,7 @@ def test_the_routings_are_what_their_names_say():
     assert sizes["no_pair_held"].sum() == 0 and used["no_pair_held"] == 4
     assert used["even"] < n_tiles
     assert used["the_last_turn_moved_back"] == 13
-    assert moe.layout_tiles(T * K, 6) * tile == 224
+    assert moe.layout_tiles(T * K, 6, E) * tile == 224
     assert 224 % (moe._TILES_A_TURN * tile) != 0
     assert all(_plan(kind)[2].tile == tile for kind in ROUTINGS)
 
@@ -285,7 +286,7 @@ def test_layer_gradients_match_the_dense_loop_under_a_given_routing(
         p = dict(zip(keys, a))
         return jnp.sum(moe.expert_layer(
             p["u"], gates, experts, p["w_gate"][held], p["w_up"][held],
-            p["w_down"][held], experts_held=(first, count),
+            p["w_down"][held], experts_held=(first, count), n_experts=E,
             impl=impl)[0] ** 2)
 
     def dense(gates, *a):
@@ -333,31 +334,137 @@ def test_rows_past_the_used_tiles_are_never_read(kind):
     assert float(jnp.abs(jnp.where(past, clean[1], 0.0)).max()) == 0.0
 
 
-def test_one_compiled_program_serves_routings_of_different_n_used():
+@pytest.mark.parametrize("held,rounds", [((2, 4), [1, 1]), ((3, 2), [1, 2])])
+def test_one_compiled_program_serves_routings_of_different_n_used(
+        held, rounds):
+    """... and of different numbers of rounds: a chip with two of the
+    eight experts lays 64 of the 128 pairs out a round, and the second
+    routing brings it 72."""
     w = _weights(8)
     gates = jax.nn.softmax(w["h"][:, :K], -1)
+    first, count = held
+    mine = slice(first, first + count)
 
     @jax.jit
     def step(experts, gates, u):
         def loss(gates, u):
-            m, plan = moe.expert_layer(
-                u, gates, experts, w["w_gate"][2:6], w["w_up"][2:6],
-                w["w_down"][2:6], experts_held=(2, 4))
-            return jnp.sum(m ** 2), plan.n_used[0]
-        (value, n_used), grads = jax.value_and_grad(
+            m, laid = moe.expert_layer(
+                u, gates, experts, w["w_gate"][mine], w["w_up"][mine],
+                w["w_down"][mine], experts_held=held, n_experts=E)
+            return jnp.sum(m ** 2), laid
+        (value, laid), grads = jax.value_and_grad(
             loss, (0, 1), has_aux=True)(gates, u)
-        return value, n_used, grads
+        return value, laid, grads
 
     seen = []
     for kind in ("even", "one_expert_takes_most"):
         experts = jnp.asarray(_routing(kind)[0], jnp.int32)
         with jax.default_matmul_precision("highest"):
-            value, n_used, _ = step(experts, gates, w["u"])
-            want = jnp.sum(_dense_given(w, gates, experts, 2, 4) ** 2)
+            value, laid, _ = step(experts, gates, w["u"])
+            want = jnp.sum(_dense_given(w, gates, experts, *held) ** 2)
         np.testing.assert_allclose(value, want, rtol=1e-4, atol=1e-6)
-        seen.append(int(n_used))
-    assert seen[0] != seen[1]
+        seen.append((int(laid.row_tiles_used), int(laid.layout_rounds)))
+    assert seen[0][0] != seen[1][0]
+    assert [r for _, r in seen] == rounds
     assert step._cache_size() == 1
+
+
+# -- the layout: twice the even share, in rounds ------------------------------
+
+def test_the_layout_is_twice_the_even_share_and_the_uncut_layers_is_whole():
+    """The four expert cells' shapes (pairs a call, experts held, the
+    router's width), and a chip that holds every expert or half of them:
+    the layout of every pair, as it was."""
+    assert [moe.layout_tiles(*cell) for cell in (
+        (16384 * 8, 16, 128), (8192 * 6, 8, 64), (4096 * 4, 8, 64),
+        (8192 * 6, 16, 64))] == [144, 56, 24, 112]
+    for pairs, count in ((8192 * 6, 16), (16384 * 8, 16), (T * K, 4)):
+        whole = -(-pairs // moe.default_row_tile(pairs)) + count
+        assert moe.layout_tiles(pairs, count, count) == whole
+        assert moe.layout_tiles(pairs, count, 2 * count) == whole
+    assert moe.round_pairs(T * K, 2, E, 16) == 64
+    assert moe.round_pairs(100, 2, 3, 16) == 112  # whole tiles, all pairs
+
+
+def _given(experts, held, seed=9):
+    """Inputs of a layer under a routing that is given, and the dense
+    loop's output on them."""
+    w = _weights(seed)
+    gates = jax.nn.softmax(w["h"][:, :K], -1)
+
+    def dense(u, gates, w_gate, w_up, w_down):
+        return _dense_given(
+            dict(u=u, w_gate=w_gate, w_up=w_up, w_down=w_down), gates,
+            experts, *held)
+
+    return (w["u"], gates, w["w_gate"], w["w_up"], w["w_down"]), dense
+
+
+def _layer_and_gradients(experts, held, n_experts, impl):
+    """Output, what was laid out, and the gradients of the output's
+    squared sum in all five inputs."""
+    args, _ = _given(experts, held)
+    mine = slice(held[0], sum(held))
+
+    def loss(u, gates, w_gate, w_up, w_down):
+        m, laid = moe.expert_layer(
+            u, gates, experts, w_gate[mine], w_up[mine], w_down[mine],
+            experts_held=held, n_experts=n_experts, impl=impl)
+        return jnp.sum(m ** 2), (m, laid)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (m, laid)), grads = jax.value_and_grad(
+            loss, range(5), has_aux=True)(*args)
+    return m, laid, grads
+
+
+@pytest.mark.parametrize("impl", ["jnp", "interpret"])
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_one_round_is_the_layout_of_every_pair_bit_for_bit(kind, impl):
+    """The smallest layout that holds a routing in one round (the widest
+    router of which this chip's share is still enough) against the
+    layout of every pair (``n_experts = count``: a share of one): the
+    same tiles with the same contents, fewer empty ones behind them, so
+    the output and all five gradients are equal to the bit."""
+    experts, held, _ = _plan(kind)
+    held_pairs = int(((experts >= held[0]) & (experts < sum(held))).sum())
+    tile = moe.default_row_tile(T * K)
+    widest = max(n for n in range(held[1], 65) if moe.round_pairs(
+        T * K, held[1], n, tile) >= held_pairs)
+    small = _layer_and_gradients(experts, held, widest, impl)
+    whole = _layer_and_gradients(experts, held, held[1], impl)
+    assert int(small[1].layout_rounds) == int(whole[1].layout_rounds) == 1
+    assert int(small[1].dropped) == 0
+    if held_pairs <= T * K - tile:  # ... and the layout is smaller
+        assert moe.layout_tiles(T * K, held[1], widest) < moe.layout_tiles(
+            T * K, held[1], held[1])
+    for a, b in zip(jax.tree.leaves(small), jax.tree.leaves(whole)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "interpret"])
+def test_a_routing_that_overflows_the_layout_takes_rounds_and_is_exact(impl):
+    """``one_expert_takes_most`` on a chip that holds experts 3 and 4 of
+    eight: 72 held pairs where a round lays 64 out, so the layer runs
+    two rounds, drops nothing, and its output and gradients are the
+    dense loop's; on a chip that holds expert 3 alone (32 a round), two
+    rounds of the one group cut in halves."""
+    experts = jnp.asarray(_routing("one_expert_takes_most")[0], jnp.int32)
+    for held in ((3, 2), (3, 1)):
+        m, laid, grads = _layer_and_gradients(experts, held, E, impl)
+        assert int(laid.layout_rounds) == 2
+        assert int(laid.dropped) == 0
+        assert int(laid.expert_pairs[0]) == T
+        args, dense = _given(experts, held)
+        with jax.default_matmul_precision("highest"):
+            want = dense(*args)
+            want_grads = jax.grad(
+                lambda *a: jnp.sum(dense(*a) ** 2), range(5))(*args)
+        np.testing.assert_allclose(m, want, rtol=1e-5, atol=1e-6)
+        for a, b in zip(grads, want_grads):
+            assert bool(jnp.isfinite(a).all())
+            np.testing.assert_allclose(
+                a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()) + 1e-7)
 
 
 @pytest.mark.parametrize("with_bias", [False, True])

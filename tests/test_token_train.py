@@ -104,10 +104,11 @@ def test_pass_totals_are_bit_for_bit_the_fold_over_single_step(train):
 @pytest.mark.parametrize("train,accum", [(True, 1), (False, 1), (True, 2)])
 def test_a_pass_publishes_the_row_tiles_its_layers_used(train, accum):
     """``TokenTotals.row_tiles_used`` is the sum over a pass of each
-    layer's ``plan.n_used`` (told here from the pairs each held expert
+    layer's used row tiles (told here from the pairs each held expert
     got: a group fills whole tiles, an empty one keeps one), and the
-    drain publishes it beside the layout's tiles: whole batches, or a
-    train step's microbatches."""
+    drain publishes it beside the tiles laid out, the rounds of the
+    layout and the layer's calls: whole batches, or a train step's
+    microbatches, each one round here."""
     from fmda_tpu.ops.moe import default_row_tile, layout_tiles
 
     trainer = Trainer(_model(), _train(accum_steps=accum))
@@ -134,13 +135,17 @@ def test_a_pass_publishes_the_row_tiles_its_layers_used(train, accum):
                                             phase=phase)
                  for layer in (0, 1)]
                 for name in ("moe_row_tiles_used_total",
-                             "moe_row_tiles_layout_total")]
+                             "moe_row_tiles_layout_total",
+                             "moe_layout_rounds_total",
+                             "moe_layer_calls_total")]
     before = [[c.value for c in row] for row in counters]
     trainer._run_batches(_copy(state), (batches,), rng, train)
-    used, layout = ([c.value - b for c, b in zip(row, was)]
-                    for row, was in zip(counters, before))
+    used, layout, rounds, calls = (
+        [c.value - b for c, b in zip(row, was)]
+        for row, was in zip(counters, before))
     assert used == want.tolist()
-    assert layout == [len(batches) * passes * layout_tiles(pairs, 2)] * 2
+    assert rounds == calls == [len(batches) * passes] * 2
+    assert layout == [calls[0] * layout_tiles(pairs, 2, 4)] * 2
     assert all(0 < u <= total for u, total in zip(used, layout))
 
 
@@ -173,10 +178,12 @@ def test_grouped_token_pass_is_the_fold_over_single_step(train):
 #: sha256 (first 16 hex digits) of the lowered text of this file's tiny
 #: decoder's single train and eval programs, jax 0.9.0: PR 29's parent's
 #: (98d5033) until PR 31 changed the expert layer's row passes and the
-#: totals' leaves, PR 31's since.  Regenerate with the snippet in the
-#: test below after a deliberate change to the decoder, its task or the
-#: step function.
-PARENT_STEP_TEXT = ("3e53942d3a7820d2", "dab4756a66f47451")
+#: totals' leaves, PR 31's until PR 48 put the layer's rounds in a
+#: ``while`` under one ``custom_vjp`` (each direction a ``jax.jit``),
+#: named its output and gave the totals ``layout_rounds``; PR 48's since.  Regenerate with the snippet
+#: in the test below after a deliberate change to the decoder, its task
+#: or the step function.
+PARENT_STEP_TEXT = ("f298a8cc86996da6", "9a879c738316528e")
 
 
 def test_a_solo_decoder_runs_the_parents_programs(monkeypatch):

@@ -218,7 +218,7 @@ def test_grouped_product_kernels_compile_at_16_experts_of_2560_by_768(
     from fmda_tpu.ops.pallas_moe import grouped_matmul
 
     tile = 256
-    rows = layout_rows(8192 * 6, 16, tile)
+    rows = layout_rows(8192 * 6, 16, 64, tile)
 
     def step(x, w, tile_expert, n_used):
         return jax.value_and_grad(lambda x, w: grouped_matmul(
@@ -236,22 +236,26 @@ def test_grouped_product_kernels_compile_at_16_experts_of_2560_by_768(
 
 def test_expert_layer_row_passes_compile_bounded_and_in_place(one_chip):
     """The expert layer's forward and backward at T 8,192, k 6, D 2,560,
-    16 of 64 experts held: both row passes are ``while`` loops whose
-    53,248-row buffer is updated in place (never copied, in a turn or
-    around the loop), and the combine's backward gathers no (8192, 2560)
-    block: the gates' gradient comes off the row pass."""
+    16 of 64 experts held: both row passes (the gather forward and in
+    backward's second making of the round, the combine's backward) are
+    ``while`` loops inside the loop over rounds, whose 28,672-row buffer
+    (twice the even share: 112 tiles, 208 for every pair) is updated in
+    place (never copied, in a turn or around either loop), and the
+    combine's backward gathers no (8192, 2560) block: the gates'
+    gradient comes off the row pass."""
     import re
 
     from fmda_tpu.ops import moe
 
     t, k, d, f, count = 8192, 6, 2560, 768, 16
-    rows = moe.layout_rows(t * k, count, moe.default_row_tile(t * k))
+    rows = moe.layout_rows(t * k, count, 64, moe.default_row_tile(t * k))
+    assert rows == 28672
 
     def step(u, gates, experts, w_gate, w_up, w_down):
         def loss(u, gates, w_gate, w_up, w_down):
             m, _ = moe.expert_layer(
                 u, gates, experts, w_gate, w_up, w_down,
-                experts_held=(0, count), impl="pallas")
+                experts_held=(0, count), n_experts=64, impl="pallas")
             return m.astype(jnp.float32).sum()
         return jax.value_and_grad(loss, (0, 1, 2, 3, 4))(
             u, gates, w_gate, w_up, w_down)
@@ -267,8 +271,9 @@ def test_expert_layer_row_passes_compile_bounded_and_in_place(one_chip):
     lines = text.splitlines()
     # the row passes are loops, in both directions, and each turn updates
     # the row buffer in place (the update's output aliases operand 0)
-    for loop in ("jvp(moe_dispatch)/while/body/",
-                 "transpose(jvp(moe_combine))/while/body/"):
+    for loop in ("while/body/moe_dispatch/while/body/",
+                 "while/body/jvp(moe_dispatch)/while/body/",
+                 "while/body/transpose(jvp(moe_combine))/while/body/"):
         updates = [line for line in lines if loop in line and re.search(
             buffer + r"(dynamic-update-slice|fusion)\(", line)]
         assert updates, loop
@@ -280,6 +285,15 @@ def test_expert_layer_row_passes_compile_bounded_and_in_place(one_chip):
     assert not [line for line in lines
                 if "transpose(jvp(moe_combine))" in line and "gather" in line
                 and re.search(rf"= \w+\[{t},{d}\]", line)]
+
+
+def _kernel_runs(text):
+    """The grouped-product kernels' custom calls in a compiled program."""
+    import re
+
+    return {name: len(re.findall(
+        rf"(?m)^\s*%{name}(?:\.\d+)? = .*custom-call\(", text))
+        for name in ("moe_gmm", "moe_tgmm")}
 
 
 @pytest.mark.parametrize("layout,kernels", [
@@ -345,6 +359,11 @@ def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
     bwd = "sparse_bwd" if 2 in layout else "flash_bwd"
     assert len(re.findall(
         rf"(?m)^\s*%{bwd}(?:\.\d+)? = .*custom-call\(", text)) == len(layout)
+    # the expert layer's forward rule keeps its inputs alone: behind a
+    # plain residual the replay's forward is dead, and the three products
+    # run forward, once more in backward, and transposed, whatever is kept
+    assert _kernel_runs(text) == {"moe_gmm": 9 * len(layout),
+                                  "moe_tgmm": 3 * len(layout)}
 
 
 def test_flash_kernels_compile_at_32_on_8_heads_of_64_with_a_stated_scale(
@@ -566,6 +585,11 @@ def test_a_recomputed_latent_decoder_step_runs_the_core_once_a_layer(
     assert len(calls) == runs * 3, len(calls)
     assert len(re.findall(
         r"(?m)^\s*%flash_bwd(?:\.\d+)? = .*custom-call\(", text)) == 3
+    # the lanes' mixing reads an expert layer's output in backward (the
+    # write weights' gradient): kept by name, else the replay runs the
+    # forward's three products a third time
+    assert _kernel_runs(text) == {"moe_gmm": (6 + 3 * runs) * 2,
+                                  "moe_tgmm": 3 * 2}
 
 
 def test_the_hyper_connections_backward_compiles_at_4_lanes_of_3584_by_4096(
