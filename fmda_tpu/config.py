@@ -454,8 +454,10 @@ class ModelConfig:
     #: router placed *after* attention, reading the expert block's
     #: normalised input; 3 = a state-space layer: no attention, a scan
     #: over matrix-valued state (the ``ssm_*`` group below); 4 = a latent-
-    #: attention layer (the ``q_lora_rank`` group below), in a model whose
-    #: layers are all of that kind.
+    #: attention layer (the ``q_lora_rank`` group below); 5 = a delta-rule
+    #: layer with a decay a channel (the ``kda_*`` group below).  Kinds 4
+    #: and 5 state their heads' widths themselves and may share a model,
+    #: in any order; they do not mix with kinds 0..3.
     layer_layout: Tuple[int, ...] = ()
     sliding_window: int = 4096
     rope_theta: float = 10000.0
@@ -493,6 +495,22 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_conv: int = 0
     ssm_chunk: int = 0
+    #: ``layer_layout`` 5, a delta-rule layer in place of attention
+    #: (ops/kda.py): ``kda_heads`` heads, each carrying a ``(kda_head_dim,
+    #: kda_head_dim)`` float32 state that every position decays by a
+    #: vector (a factor a key channel) and corrects by a rank-one step;
+    #: queries, keys and values ``kda_head_dim`` wide behind a causal
+    #: depthwise convolution of ``kda_conv`` taps each; the decay and the
+    #: output gate through low-rank pairs ``kda_head_dim`` wide; the walk
+    #: taken ``kda_chunk`` positions at a time (a size the configuration
+    #: states, as ``ssm_chunk``; how many chunks a turn of the walk takes
+    #: and the rows of a sub-block are set from the chip's timings and are
+    #: ops/kda.py's ``CHUNK_GROUP`` and ``SUB_ROWS``).  No positional
+    #: encoding.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    kda_chunk: int = 0
     #: ``moe_experts == 0``: every layer's feed-forward is one dense gated
     #: MLP ``hidden -> ffn_size -> hidden`` (``hidden_act`` on the gate),
     #: with no router.
@@ -518,7 +536,11 @@ class ModelConfig:
     #: ``qk_nope_head_dim`` wide without position plus ``qk_rope_head_dim``
     #: rotary dims whose key is ONE head shared by all ``n_heads``; values
     #: are ``v_head_dim`` wide.  ``head_dim`` / ``n_kv_heads`` are not
-    #: read by such a layer.
+    #: read by such a layer.  ``mla_use_nope``: the model states no
+    #: position in these layers: the ``qk_rope_head_dim`` dims of the
+    #: query and the one shared key stay, unrotated (``rope_theta`` and
+    #: the ``rope_*`` group below are then not read).
+    mla_use_nope: bool = False
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0

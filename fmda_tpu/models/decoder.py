@@ -79,8 +79,8 @@ Parameters are float32; products run in
 ``cfg.dtype``; norms, softmaxes, rotary angles and the router's
 probabilities are float32.
 
-A latent-attention layer (``layout`` 4; a model has them in every layer
-or in none) keeps ``n`` heads of ``dn`` = ``qk_nope_head_dim`` unrotated
+A latent-attention layer (``layout`` 4) keeps ``n`` heads of ``dn`` =
+``qk_nope_head_dim`` unrotated
 and ``dr`` = ``qk_rope_head_dim`` rotated dims, values ``dv`` =
 ``v_head_dim`` wide, behind two low-rank products::
 
@@ -88,6 +88,7 @@ and ``dr`` = ``qk_rope_head_dim`` rotated dims, values ``dv`` =
     (q_lora_rank 0, a direct query:  [qn | qr] = h @ wq, no latent and no norm)
     [ckv | kr] = h @ wkv_a  (kv_lora_rank | dr) ;  [kn | v] = RMSNorm(ckv) @ wkv_b   n x (dn | dv)
     qr, kr rotary over dr dims at YaRN's frequencies; kr is ONE head for all n
+    (``cfg.mla_use_nope``: the model states no position here; qr and kr stay, unrotated)
     s[t, j] = (qn_t . kn_j + qr_t . kr_j) * (dn + dr)^-1/2 * m^2 ,  m = 0.1 ln(rope_factor) + 1
     a = causal softmax(s) v ;  out = a @ wo                                    (n * dv -> hidden)
 
@@ -132,7 +133,38 @@ Moonlight-16B-A3B's (``x`` the stream ``(T, 2048)``)::
                  g_e = 2.446 * sc_e / sum_{e' in S} sc_e'
                  x2 = x1 + shared(u) + sum_{e in S, held} g_e expert_e(u)    shared: 2 x 1408 wide
 
-And its residual
+A delta-rule layer with a decay a channel (``layout`` 5, Kimi Delta
+Attention; :mod:`fmda_tpu.ops.kda`) has no attention and no positional
+encoding; its mixer carries a ``(dk, dk)`` float32 state a head through
+the sequence (``H`` = ``kda_heads``, ``dk`` = ``kda_head_dim``, the
+convolutions ``kda_conv`` taps, causal, depthwise, no bias)::
+
+    h  = RMSNorm(x)
+    q  = L2norm_head(conv_silu(h @ wq)) ;  k = L2norm_head(conv_silu(h @ wk)) ;  v = conv_silu(h @ wv)    (T, H, dk) each
+    g  = -exp(a_log)[head] * softplus((h @ wf_a) @ wf_b + dt_bias)     (T, H, dk) float32, <= 0: the log-decay A CHANNEL
+    b  = sigmoid(h @ wb)                                               (T, H)
+    S_t = Diag(exp(g_t)) S_{t-1} ;  S_t += b_t k_t (v_t - S_t^T k_t)^T           S_{-1} = 0
+    o_t = S_t^T q_t * dk^-1/2
+    x1 = x + (RMSNorm_head(o) * sigmoid((h @ wg_a) @ wg_b)) @ wo       the low-rank pairs dk wide; the norm over a head's dk
+
+Layers of kinds 4 and 5 state their heads' widths themselves and may
+share a model (they do not mix with kinds 0..3), each with the
+feed-forward described above: an expert layer under either has the same
+router, bias, shared expert and load.  With ``mla_use_nope``, a direct
+query, ``first_dense_layers`` 1, one shared expert and three delta-rule
+layers to one latent layer the block is Kimi-Linear-48B-A3B's (``x`` the
+stream ``(T, 2304)``)::
+
+    layers 1, 2, 3, 5, ... (of four, the first three):  the delta-rule mixer, 32 heads of 128 | 128, 4 taps
+    layers 4, 8, ...:  [qn | qr] = h @ wq (32 x (128 | 64)) ;  [ckv | kr] = h @ wkv_a (512 | 64)
+                 [kn | v] = RMSNorm(ckv) @ wkv_b ;  NO rotary ;  s = (qn . kn + qr . kr) * 192^-1/2 ;  causal softmax
+    u  = RMSNorm(x1)
+    layer 1:     x2 = x1 + (silu(u Wg) * (u Wu)) Wd                       9216 wide
+    layers 2..:  sc = sigmoid(u @ router) (256) ;  S = top-8 of (sc + router_bias)
+                 g_e = 2.446 * sc_e / sum_{e' in S} sc_e'
+                 x2 = x1 + shared(u) + sum_{e in S, held} g_e expert_e(u)    shared and experts 1024 wide
+
+A latent-attention model's residual
 may run in ``n`` = ``cfg.hc_streams`` lanes: at one lane each sublayer
 ``F`` is the plain pre-norm residual ``x + r * F(RMSNorm(x))`` every
 kind has; at ``n > 1`` it is wrapped by learned mixing
@@ -144,7 +176,10 @@ doubly stochastic)::
 
 Scopes (docs/observability.md "Spans and scopes"): ``attention`` holds
 the cores' and ``mla_proj`` (latent attention's products, norms and
-rotary); ``ssm_mixer`` a state-space mixer's five; ``hyper_conn`` the
+rotary); ``ssm_mixer`` a state-space mixer's five; ``kda_mixer`` a
+delta-rule mixer's ``kda_proj``, ``kda_conv``, ``kda_gates``,
+``kda_scan`` (the walk's ``kda_intra``, ``kda_solve``, ``kda_carry``,
+``kda_out``) and ``kda_out_norm``; ``hyper_conn`` the
 lanes' ``hc_coeff``, ``hc_pre``, ``hc_post_res``; ``moe_shared``;
 ``moe_seq_aux`` the balance term; the expert layer's and the dense MLP's
 own.
@@ -173,6 +208,7 @@ from fmda_tpu.ops.moe import (
     seq_balance_term)
 from fmda_tpu.ops.sparse_attention import (
     PICKS, kernels_dispatch, select_keys, sparse_mha)
+from fmda_tpu.ops.kda import kda_scan
 from fmda_tpu.ops.ssd import conv_silu, ssd_scan
 
 #: Standard deviation of every weight matrix at init (the family's
@@ -198,8 +234,13 @@ HC_OFFSET_INIT = 6.0
 SPARSE_LAYOUT = 2
 #: ... for a state-space layer (ops/ssd.py).
 SSM_LAYOUT = 3
-#: ... and for a latent-attention layer.
+#: ... for a latent-attention layer.
 LATENT_LAYOUT = 4
+#: ... and for a delta-rule layer with a decay a channel (ops/kda.py).
+KDA_LAYOUT = 5
+#: What is added to a head's sum of squares before the root, where a
+#: delta-rule layer takes its queries and keys to unit length.
+L2_NORM_EPS = 1e-6
 
 #: What a block's recomputation (``cfg.remat``) keeps from the forward
 #: pass, by name; everything else it remakes from the block's input:
@@ -319,6 +360,22 @@ EXPERT_COUNTS: Dict[str, Count] = {
     "dropped": Count(jnp.int32, where=_has_experts, stacked=False),
     "row_tiles_used": Count(jnp.int32, where=_has_experts),
     "layout_rounds": Count(jnp.int32, where=_has_experts),
+}
+
+
+def _sigmoid_router(cfg: ModelConfig, layer: int) -> bool:
+    return _has_experts(cfg, layer) and cfg.moe_scoring == "sigmoid"
+
+
+#: What an expert layer whose router scores by sigmoid counts beside
+#: that, whatever its mixer: the pairs each of ALL the router's experts
+#: received, held here or not (what the selection bias steps on), and the
+#: largest size of a selection bias.
+ROUTER_COUNTS: Dict[str, Count] = {
+    "router_load": Count(jnp.int32, lambda cfg: (cfg.moe_experts,),
+                         _sigmoid_router),
+    "router_bias_absmax": Count(jnp.float32, where=_sigmoid_router,
+                                fold="max"),
 }
 
 
@@ -444,6 +501,67 @@ def _ssm_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
                  "ssd_positions": jnp.int32(b * t)}
 
 
+def _kda_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
+    """A delta-rule layer's mixer (module docstring) on the normalised
+    stream ``h`` (B, T, hidden): its output (B, T, hidden), the chunks and
+    positions its walk took and the largest cumulative log-decay inside a
+    chunk.  Parameters start where the mechanism's published code starts
+    them: rates ``exp(a_log)`` uniform in 1..16 a head, step sizes
+    log-uniform in 1e-3..1e-1 a channel at a zero input, the taps uniform
+    in +-1/sqrt(taps)."""
+    b, t, d = h.shape
+    heads, hd, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+    inner, dt, f32 = heads * hd, h.dtype, jnp.float32
+
+    def product(x, name, width, **kw):
+        return jnp.dot(x, _weight(module, name, (x.shape[-1], width))
+                       .astype(dt), **kw)
+
+    def by_head(x):
+        return x.reshape(b, t, heads, hd)
+
+    def unit_length(x):
+        x32 = x.astype(f32)
+        return (x32 * jax.lax.rsqrt(jnp.sum(
+            jnp.square(x32), -1, keepdims=True) + L2_NORM_EPS)).astype(dt)
+
+    with jax.named_scope("kda_proj"):
+        q, k, v = (product(h, name, inner) for name in ("wq", "wk", "wv"))
+        decay = product(product(h, "wf_a", hd), "wf_b", inner,
+                        preferred_element_type=f32)
+        beta = product(h, "wb", heads, preferred_element_type=f32)
+        gate = product(product(h, "wg_a", hd), "wg_b", inner,
+                       preferred_element_type=f32)
+    with jax.named_scope("kda_conv"):
+        bound = taps ** -0.5
+        q, k, v = (by_head(conv_silu(
+            x, module.param(name, _uniform(-bound, bound), (inner, taps),
+                            f32), jnp.zeros((inner,), f32), dtype=dt))
+            for x, name in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+    with jax.named_scope("kda_gates"):
+        q, k = unit_length(q), unit_length(k)
+        step = jax.nn.softplus(by_head(decay + module.param(
+            "dt_bias", _uniform(math.log(1e-3), math.log(1e-1),
+                                lambda u: _inverse_softplus(jnp.exp(u))),
+            (inner,), f32)))
+        log_decay = -jnp.exp(module.param(
+            "a_log", _uniform(1.0, 16.0, jnp.log), (heads,), f32)
+        )[:, None] * step
+        beta = jax.nn.sigmoid(beta)
+    with jax.named_scope("kda_scan"):
+        o, _, absmax = kda_scan(q, k, v, log_decay, beta,
+                                chunk=cfg.kda_chunk, dtype=dt)
+    with jax.named_scope("kda_out_norm"):
+        o = (rms_norm(o, module.param("o_norm", nn.initializers.ones, (hd,)),
+                      cfg.rms_norm_eps)
+             * jax.nn.sigmoid(by_head(gate))).astype(dt)
+    with jax.named_scope("kda_proj"):
+        out = product(o.reshape(b, t, inner), "wo", d)
+    return out, {"kda_chunks": jnp.int32(b * -(-t // cfg.kda_chunk)),
+                 "kda_positions": jnp.int32(b * t),
+                 "kda_log_decay_absmax": absmax}
+
+
 def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
     """The rotary frequencies of the ``qk_rope_head_dim`` rotary dims,
     (dr / 2,) float32: ``theta^(-2i/dr)``, stretched by YaRN where
@@ -508,11 +626,14 @@ def _latent_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
                 _weight(module, "wkv_b", (cfg.kv_lora_rank, n * (dn + dv)))
                 .astype(dt)), dn + dv)
             kn, v = kv[..., :dn], kv[..., dn:]
-            with jax.named_scope("rope"):
-                inv_freq = yarn_inv_freq(cfg)
-                qr = rotary_at(q[..., dn:], inv_freq)
-                kr = rotary_at(kr[:, None], inv_freq)  # one head
-            q = jnp.concatenate([q[..., :dn], qr], axis=-1)
+            if cfg.mla_use_nope:  # no position: the dr dims stay as made
+                kr = kr[:, None]
+            else:
+                with jax.named_scope("rope"):
+                    inv_freq = yarn_inv_freq(cfg)
+                    qr = rotary_at(q[..., dn:], inv_freq)
+                    kr = rotary_at(kr[:, None], inv_freq)  # one head
+                q = jnp.concatenate([q[..., :dn], qr], axis=-1)
             k = jnp.concatenate(
                 [kn, jnp.broadcast_to(kr, (b, n, t, dr))], axis=-1)
         with jax.named_scope("attention_latent"):
@@ -587,6 +708,13 @@ def _latent_rules(cfg: ModelConfig) -> list:
          cfg.attention_multiplier is None and cfg.residual_multiplier == 1.0)]
 
 
+def _kda_rules(cfg: ModelConfig) -> list:
+    return [(f"{name} (layer_layout has a delta-rule layer)",
+             getattr(cfg, name) > 0)
+            for name in ("kda_heads", "kda_head_dim", "kda_conv",
+                         "kda_chunk")]
+
+
 KINDS: Dict[int, Kind] = {
     0: Kind(_attention_mixer(window=False), "attention", "mixer"),
     1: Kind(_attention_mixer(window=True), "attention", "mixer"),
@@ -601,23 +729,25 @@ KINDS: Dict[int, Kind] = {
         "ssd_chunks": Count(jnp.int32),
         "ssd_positions": Count(jnp.int32)}, _ssm_rules),
     LATENT_LAYOUT: Kind(_latent_mixer, None, None, {
-        # such a model's expert layers: the pairs each of ALL the router's
-        # experts received, held here or not (what the selection bias
-        # steps on), and the largest size of a selection bias; its lanes:
-        # the largest distance of a row or column sum of a residual
-        # mixing matrix from one; its cores: the causal pairs each scored
-        "router_load": Count(jnp.int32, lambda cfg: (cfg.moe_experts,),
-                             _has_experts),
-        "router_bias_absmax": Count(jnp.float32, where=_has_experts,
-                                    fold="max"),
+        # such a model's lanes: the largest distance of a row or column
+        # sum of a residual mixing matrix from one; its cores: the causal
+        # pairs each scored
         "hc_sum_error": Count(
             jnp.float32, where=lambda cfg, layer: cfg.hc_streams > 1,
             fold="max"),
         "latent_pairs": Count(jnp.int32)}, _latent_rules),
+    KDA_LAYOUT: Kind(_kda_mixer, "kda_mixer", None, {
+        # what the walk took, and the largest |G| inside a chunk: how far
+        # past float32's 87 an exponent the chunked form never takes
+        # (ops/kda.py) would have gone
+        "kda_chunks": Count(jnp.int32),
+        "kda_positions": Count(jnp.int32),
+        "kda_log_decay_absmax": Count(jnp.float32, fold="max")},
+        _kda_rules),
 }
 
 #: Every declared count by name, whatever the configuration.
-COUNTS: Dict[str, Count] = {**EXPERT_COUNTS, **{
+COUNTS: Dict[str, Count] = {**EXPERT_COUNTS, **ROUTER_COUNTS, **{
     name: count for kind in KINDS.values()
     for name, count in kind.counts.items()}}
 
@@ -628,7 +758,7 @@ def model_counts(cfg: ModelConfig) -> Dict[str, ModelCount]:
     them.  The model fills and stacks by it; the task (train/tasks.py
     ``NextToken``) zeroes, folds and publishes by it.  No model is traced."""
     depth = range(len(cfg.layer_layout))
-    groups = [(EXPERT_COUNTS, list(depth))] + [
+    groups = [(EXPERT_COUNTS, list(depth)), (ROUTER_COUNTS, list(depth))] + [
         (KINDS[code].counts, [i for i in depth if cfg.layer_layout[i] == code])
         for code in sorted(set(cfg.layer_layout)) if code in KINDS]
     declared = {}
@@ -708,7 +838,8 @@ def feed_forward(module: nn.Module, cfg: ModelConfig, u: jax.Array, *,
     ones.  ``routed``: :func:`routing`'s answer where the block asked
     already.  Returns the output and all the layer counted: ``counted``
     (the mixer's, each now in its declared form), :data:`EXPERT_COUNTS`
-    and, with ``load``, the load on all experts and the bias's size;
+    and, with ``load`` (:data:`ROUTER_COUNTS`), the load on all experts
+    and the bias's size;
     among them, under its name, the balance term a sequence where the
     model declares it (:data:`TERMS`: a value with a gradient path)."""
     if dense or not cfg.moe_experts:
@@ -788,6 +919,7 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array):
         cfg, kind = self.cfg, KINDS[self.layout]
+        declared = model_counts(cfg)
         d, dt = x.shape[-1], x.dtype
         lanes = cfg.hc_streams > 1
         experts = cfg.moe_experts > 0 and not self.dense
@@ -837,7 +969,7 @@ class DecoderBlock(nn.Module):
                 routed = routing(self, cfg, u)
             return feed_forward(
                 self, cfg, u, dense=self.dense, routed=routed,
-                counted=counted, load="router_load" in kind.counts)
+                counted=counted, load="router_load" in declared)
 
         x, counted = sublayer(x, "attn", "ln_attn", mix, kind.scope)
         x, counts = sublayer(x, "ffn", "ln_moe" if experts else "ln_mlp",
@@ -847,8 +979,8 @@ class DecoderBlock(nn.Module):
         # settles the mixer's, the expert layer's come as declared): zeros
         # where it did not count (a dense layer of a model with experts)
         # -- of the declared shape, so that the layers' values stack
-        declared = model_counts(cfg)
-        for name, count in {**EXPERT_COUNTS, **kind.counts}.items():
+        for name, count in {**EXPERT_COUNTS, **ROUTER_COUNTS,
+                            **kind.counts}.items():
             if name in declared and name not in counts:
                 counts[name] = jnp.zeros(count.shape(cfg), count.dtype)
         if sum_errors:
@@ -946,33 +1078,35 @@ class MoEDecoder(nn.Module):
         return logits
 
 
-def _feed_forward_rules(cfg: ModelConfig, latent: bool) -> list:
+def _feed_forward_rules(cfg: ModelConfig, self_sized: bool) -> list:
+    """``self_sized``: the model's layers are of kinds 4 and 5, whose
+    feed-forward may state what the rows marked so state."""
     first, count = cfg.experts_held
     dense = cfg.moe_experts == 0
     sigmoid = cfg.moe_scoring == "sigmoid"
-    only_latent = " (a latent-attention model's)"
+    only_those = " (a model of layers of kinds 4 and 5)"
     return [
         ("ffn_size (moe_experts is 0 or first_dense_layers is not: a "
          "dense gated MLP)",
          not (dense or cfg.first_dense_layers > 0) or cfg.ffn_size > 0),
         ("first_dense_layers (0 .. the depth; more than 0 with experts"
-         + only_latent + ")",
+         + only_those + ")",
          0 <= cfg.first_dense_layers <= len(cfg.layer_layout)
-         and (cfg.first_dense_layers == 0 or (latent and not dense))),
-        ("moe_shared_experts (more than 0 with experts" + only_latent + ")",
+         and (cfg.first_dense_layers == 0 or (self_sized and not dense))),
+        ("moe_shared_experts (more than 0 with experts" + only_those + ")",
          cfg.moe_shared_experts >= 0
-         and (cfg.moe_shared_experts == 0 or (latent and not dense))),
-        ("moe_scoring (softmax, or sigmoid" + only_latent + ")",
-         cfg.moe_scoring == "softmax" or (sigmoid and latent)),
+         and (cfg.moe_shared_experts == 0 or (self_sized and not dense))),
+        ("moe_scoring (softmax, or sigmoid" + only_those + ")",
+         cfg.moe_scoring == "softmax" or (sigmoid and self_sized)),
         ("moe_routed_scaling (positive; other than 1 with sigmoid scores)",
          cfg.moe_routed_scaling > 0
          and (cfg.moe_routed_scaling == 1.0 or sigmoid)),
         ("moe_bias_rate (0, or positive with sigmoid scores)",
          cfg.moe_bias_rate == 0 or (cfg.moe_bias_rate > 0 and sigmoid)),
         ("moe_seq_aux_alpha (0, or positive with experts under a plain "
-         "residual" + only_latent + ")",
+         "residual" + only_those + ")",
          cfg.moe_seq_aux_alpha == 0 or (
-             cfg.moe_seq_aux_alpha > 0 and latent and not dense
+             cfg.moe_seq_aux_alpha > 0 and self_sized and not dense
              and cfg.hc_streams == 1)),
         ("moe_experts / moe_top_k",
          dense or 0 < cfg.moe_top_k <= cfg.moe_experts),
@@ -989,20 +1123,20 @@ def check_decoder_config(cfg: ModelConfig) -> None:
     inconsistent, naming the field: the rows every model answers (the
     residual's among them), those of each kind of layer it has
     (:data:`KINDS`) and the feed-forward's."""
-    latent = LATENT_LAYOUT in cfg.layer_layout
+    # layers of kinds 4 and 5 state their heads' widths themselves
+    own_widths = {LATENT_LAYOUT, KDA_LAYOUT}
+    self_sized = bool(own_widths & set(cfg.layer_layout))
     lanes = cfg.hc_streams > 1
     present = [KINDS[v] for v in sorted(set(cfg.layer_layout)) if v in KINDS]
     rules = [
         ("vocab_size", cfg.vocab_size > 0),
-        # a latent-attention layer states its heads' widths itself
-        ("head_dim", latent or cfg.head_dim > 0),
-        ("n_kv_heads (must divide n_heads)", latent or (
+        ("head_dim", self_sized or cfg.head_dim > 0),
+        ("n_kv_heads (must divide n_heads)", self_sized or (
             cfg.n_kv_heads > 0 and cfg.n_heads % cfg.n_kv_heads == 0)),
-        ("layer_layout (one of 0/1/2/3 per layer, or 4 in every layer)",
+        ("layer_layout (one of 0/1/2/3 per layer, or of 4/5 in every layer)",
          len(cfg.layer_layout) > 0
          and all(v in KINDS for v in cfg.layer_layout)
-         and (not latent
-              or all(v == LATENT_LAYOUT for v in cfg.layer_layout))),
+         and (not self_sized or set(cfg.layer_layout) <= own_widths)),
         ("rope_factor (at least 1) / rope_original_max (positive where "
          "the factor stretches)",
          cfg.rope_factor >= 1 and (cfg.rope_factor == 1
@@ -1012,7 +1146,8 @@ def check_decoder_config(cfg: ModelConfig) -> None:
         ("head_dim (even, for rotary)", cfg.head_dim % 2 == 0),
         ("sliding_window", cfg.sliding_window > 0),
         ("hc_streams (1, or more lanes (a latent-attention model's))",
-         cfg.hc_streams == 1 or (lanes and latent)),
+         cfg.hc_streams == 1 or (
+             lanes and self_sized and KDA_LAYOUT not in cfg.layer_layout)),
         ("hc_sinkhorn_iters (hc_streams is more than 1)",
          not lanes or cfg.hc_sinkhorn_iters > 0),
         ("hc_eps / hc_res_clamp (positive; hc_streams is more than 1)",
@@ -1022,7 +1157,7 @@ def check_decoder_config(cfg: ModelConfig) -> None:
          cfg.embedding_multiplier != 0 and cfg.residual_multiplier != 0
          and cfg.logits_scaling != 0),
     ] + [rule for kind in present for rule in kind.rules(cfg)
-         ] + _feed_forward_rules(cfg, latent)
+         ] + _feed_forward_rules(cfg, self_sized)
     problems = list(dict.fromkeys(name for name, ok in rules if not ok))
     if problems:
         raise ValueError(

@@ -1,0 +1,223 @@
+"""The delta rule with a decay a channel, in chunks (Kimi Delta
+Attention): the sequence mixer of a ``decoder`` layer of ``layer_layout``
+5 (models/decoder.py).
+
+One head carries a ``(K, V)`` state (``q``, ``k``: ``(T, H, K)``; ``v``:
+``(T, H, V)``; ``g``: ``(T, H, K)`` float32 log-decays, never positive;
+``b``: ``(T, H)`` in 0..1).  Every position decays the state by a vector,
+a factor a key channel, and then corrects it by a rank-one step towards
+``v_t`` along ``k_t``::
+
+    S_t = Diag(exp(g_t)) S_{t-1} ;  S_t += b_t k_t (v_t - S_t^T k_t)^T      S_{-1} = 0
+    o_t = scale * S_t^T q_t
+
+:func:`kda_stepwise` is that recurrence as written, a ``lax.scan`` over
+positions in float32: the form the tests hold the chunked one to.  It
+keeps a ``(B, H, K, V)`` state a position in backward (17 GB at 8,192
+positions of 32 heads of 128 x 128), so training runs :func:`kda_scan`,
+the same numbers in chunks of ``chunk`` positions.  With ``G_i`` the
+sum of ``g`` from a chunk's first position to its ``i``-th and ``S``
+the state entering the chunk::
+
+    kda_intra   A_ij = sum_c k_ic k_jc exp(G_ic - G_jc)   (j < i) ;  B_ij the same with q_i (j <= i)
+    kda_solve   (I + Diag(b) A) [u0 | w] = Diag(b) [V | K * exp(G)]      unit lower triangular
+    kda_carry   u = u0 - w S ;  S' = Diag(exp(G_last)) S + (K * exp(G_last - G))^T u
+    kda_out     o = scale * ((q * exp(G)) S + B u)
+
+(``u_i`` is the correction position ``i`` writes: ``b_i (v_i - S_i^T
+k_i)`` of the recurrence; the solve gives all of a chunk's at once, and
+splits off what the entering state adds, so that everything but
+``kda_carry`` and ``kda_out`` is independent of the walk.)  **Every
+exponent is a difference** ``G_i - G_j`` **with** ``j <= i``**, never
+positive, and no term divides by a decay**: at the published
+initialisation ``g`` reaches -1.6 a position, a decay underflows inside a
+chunk of 64, and a form that multiplies by ``exp(-G)`` gives inf times 0.
+A decay a channel makes the pairwise factor of ``A`` a ``(chunk, chunk,
+K)`` tensor; it is made only where it must be.  A chunk is taken in
+sub-blocks of :data:`SUB_ROWS` rows: for ``i`` in a sub-block whose first
+row is ``r`` and ``j`` before ``r``, ``exp(G_i - G_j) = exp(G_i - G_r)
+exp(G_r - G_j)``, both factors at most one, so the off-diagonal
+sub-blocks are plain products on the MXU and the ``(SUB_ROWS, SUB_ROWS,
+K)`` tensor exists on the diagonal sub-blocks alone.
+
+The chunks are walked once, in order, as ``ops/ssd.py`` walks its own: a
+``lax.scan`` over groups of :data:`CHUNK_GROUP` chunks carries the state,
+a turn does all four parts for its group (the carry unrolled over the
+group's chunks) under ``jax.checkpoint``, so that backward keeps of the
+walk the state at each group's edge and makes the rest again a group at
+a time.  ``G``, its exponentials, the solve and the carried state are
+float32; the products take operands in ``dtype`` (each rounded once,
+after its float32 decay factor) and accumulate in float32.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+#: Chunks a turn of the walk takes (512 positions at a chunk of 64: what
+#: exists a group at a time is then 8 MB a float32 array of 32 heads of
+#: 128).  On the chip, value and gradient of one layer's walk at 8,192
+#: positions read 77.6 ms at 8, 83.3 at 16 and 100.6 at 32 (PERF.md
+#: section 6, PR 49).
+CHUNK_GROUP = 8
+#: Rows of a sub-block: the pairwise decays exist as a tensor on the
+#: ``(SUB_ROWS, SUB_ROWS)`` diagonal sub-blocks of a chunk only.
+SUB_ROWS = 16
+
+
+def kda_stepwise(q, k, v, g, b, *, scale=None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The recurrence as written, one position at a time, float32:
+    ``q`` / ``k`` / ``g`` (B, T, H, K), ``v`` (B, T, H, V), ``b``
+    (B, T, H) -> ``(o (B, T, H, V), the state after the last position
+    (B, H, K, V))``."""
+    f32 = jnp.float32
+    q, k, v, g, b = (x.astype(f32) for x in (q, k, v, g, b))
+    batch, _, h, dk = q.shape
+    scale = dk ** -0.5 if scale is None else scale
+
+    def step(state, at):
+        q_t, k_t, v_t, g_t, b_t = at
+        state = jnp.exp(g_t)[..., None] * state
+        miss = v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t)
+        state = state + jnp.einsum("bh,bhk,bhv->bhkv", b_t, k_t, miss)
+        return state, scale * jnp.einsum("bhkv,bhk->bhv", state, q_t)
+
+    state, o = jax.lax.scan(
+        step, jnp.zeros((batch, h, dk, v.shape[-1]), f32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, b)))
+    return jnp.moveaxis(o, 0, 1), state
+
+
+def _largest_divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``most``."""
+    d = min(most, n)
+    while n % d:
+        d -= 1
+    return d
+
+
+def _block_diagonal(blocks: jax.Array) -> jax.Array:
+    """(..., s, R, R) -> (..., s R, s R) with the blocks on the diagonal."""
+    s, r = blocks.shape[-3], blocks.shape[-1]
+    full = jnp.einsum("...sij,st->...sitj", blocks,
+                      jnp.eye(s, dtype=blocks.dtype))
+    return full.reshape(blocks.shape[:-3] + (s * r, s * r))
+
+
+def _pairwise(q, k, gc, sub: int, dtype):
+    """``(A, B)`` of the module docstring for a group of chunks: ``q``
+    (already scaled), ``k``, ``gc`` (B, G, H, C, K) float32 -> two
+    (B, G, H, C, C) float32, ``A`` strictly lower triangular and ``B``
+    lower triangular."""
+    f32 = jnp.float32
+    chunk = q.shape[-2]
+    s = chunk // sub
+    by_sub = lambda x: x.reshape(x.shape[:-2] + (s, sub) + x.shape[-1:])
+    qs, ks, gs = by_sub(q), by_sub(k), by_sub(gc)
+    # rows of a sub-block against the positions before it: through the
+    # sub-block's first row r, exp(G_i - G_r) exp(G_r - G_j), both <= 1
+    first = gs[..., :1, :]                                # (.., s, 1, K)
+    within = jnp.exp(gs - first)
+    before = (jnp.arange(chunk)[None, :]
+              < (jnp.arange(s) * sub)[:, None])           # (s, C): j < r
+    reach = first - gc[..., None, :, :]                   # (.., s, C, K)
+    # the exponent is masked, not the result: no masked slot overflows
+    k_right = (k[..., None, :, :] * jnp.exp(
+        jnp.where(before[..., None], reach, -jnp.inf))).astype(dtype)
+    a, b = (jnp.einsum("...srk,...sjk->...srj", (x * within).astype(dtype),
+                       k_right, preferred_element_type=f32
+                       ).reshape(x.shape[:-3] + (chunk, chunk))
+            for x in (ks, qs))
+    # ... and against its own rows: the one place the pairwise decays are
+    # a tensor, (.., s, R, R, K)
+    lower = jnp.tril(jnp.ones((sub, sub), bool))          # j <= i
+    span = gs[..., :, None, :] - gs[..., None, :, :]
+    decayed = ks[..., None, :, :] * jnp.exp(
+        jnp.where(lower[..., None], span, -jnp.inf))
+    a = a + _block_diagonal(jnp.sum(ks[..., :, None, :] * decayed, -1))
+    b = b + _block_diagonal(jnp.sum(qs[..., :, None, :] * decayed, -1))
+    return a * jnp.tril(jnp.ones((chunk, chunk), f32), -1), b
+
+
+def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`kda_stepwise` in chunks of ``chunk`` positions (module
+    docstring): ``(o (B, T, H, V) float32, the state after the last
+    position (B, H, K, V) float32, the largest |G| inside a chunk ()
+    float32)``.  A length that is no multiple of ``chunk`` is padded with
+    positions that neither decay nor correct the state (``g`` and ``b``
+    zero)."""
+    f32 = jnp.float32
+    batch, t, h, dk = q.shape
+    dv = v.shape[-1]
+    scale = dk ** -0.5 if scale is None else scale
+    pad = -t % chunk
+    if pad:
+        q, k, v, g, b = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, b))
+    n_chunks = (t + pad) // chunk
+    group = _largest_divisor(n_chunks, CHUNK_GROUP)
+    sub = _largest_divisor(chunk, SUB_ROWS)
+    eye = jnp.eye(chunk, dtype=f32)
+
+    def walk(state, at):
+        """A group of chunks in order from the ``state`` (B, H, K, V)
+        before it: the state after it, the group's ``o`` (B, G, H, C, V)
+        and the largest |G| among its chunks."""
+        q, k, v, g, b = at                                # (B, G, H, C, .)
+        q32, k32 = q.astype(f32) * scale, k.astype(f32)
+        b = b.astype(f32)[..., None]
+        gc = jnp.cumsum(g.astype(f32), axis=-2)           # never positive
+        from_start = jnp.exp(gc)
+        with jax.named_scope("kda_intra"):
+            a_kk, a_qk = _pairwise(q32, k32, gc, sub, dtype)
+        with jax.named_scope("kda_solve"):
+            # what each position writes, but for the entering state's part
+            solved = jax.scipy.linalg.solve_triangular(
+                eye + b * a_kk,
+                b * jnp.concatenate([v.astype(f32), k32 * from_start], -1),
+                lower=True, unit_diagonal=True)
+            u0, w = solved[..., :dv], solved[..., dv:].astype(dtype)
+        q_in = (q32 * from_start).astype(dtype)
+        to_end = (k32 * jnp.exp(gc[..., -1:, :] - gc)).astype(dtype)
+        through = jnp.exp(gc[..., -1, :])                 # (B, G, H, K)
+        a_qk = a_qk.astype(dtype)
+        out = []
+        for c in range(q.shape[1]):
+            before = state.astype(dtype)
+            with jax.named_scope("kda_carry"):
+                u = u0[:, c] - jnp.einsum(
+                    "bhck,bhkv->bhcv", w[:, c], before,
+                    preferred_element_type=f32)
+                u_n = u.astype(dtype)
+                state = through[:, c, :, :, None] * state + jnp.einsum(
+                    "bhck,bhcv->bhkv", to_end[:, c], u_n,
+                    preferred_element_type=f32)
+            with jax.named_scope("kda_out"):
+                out.append(
+                    jnp.einsum("bhck,bhkv->bhcv", q_in[:, c], before,
+                               preferred_element_type=f32)
+                    + jnp.einsum("bhcj,bhjv->bhcv", a_qk[:, c], u_n,
+                                 preferred_element_type=f32))
+        return state, (jnp.stack(out, 1), jnp.max(jnp.abs(gc[..., -1, :])))
+
+    def grouped(x):  # (B, T, H, ...) -> (T / (G C), B, G, H, C, ...)
+        x = x.reshape((batch, n_chunks // group, group, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.swapaxes(x, 3, 4), 1, 0)
+
+    start = jnp.zeros((batch, h, dk, dv), f32)
+    args = tuple(grouped(x) for x in (q, k, v, g, b))
+    if group == n_chunks:
+        state, (o, absmax) = walk(start, tuple(x[0] for x in args))
+        o = o[None]
+    else:
+        state, (o, absmax) = jax.lax.scan(jax.checkpoint(walk), start, args)
+    # (turns, B, G, H, C, V) -> (B, T, H, V)
+    o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 3, 4).reshape(
+        batch, t + pad, h, dv)[:, :t]
+    return o, state, jax.lax.stop_gradient(jnp.max(absmax))

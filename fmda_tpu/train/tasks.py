@@ -81,7 +81,7 @@ class TokenTotals(NamedTuple):
     """A token pass's running totals: each step's mean loss, the tokens
     that counted and those predicted right, and what the layers counted,
     an attribute a name of :data:`fmda_tpu.models.decoder.COUNTS` (which
-    says what each is; two are folded by ``max``: :data:`FOLDED_BY_MAX`),
+    says what each is; three are folded by ``max``: :data:`FOLDED_BY_MAX`),
     None where the model does not count it.  Last, the loss terms the
     layers declare (:func:`fmda_tpu.models.decoder.model_terms`): each
     step's value a layer, the mean over the step's sequences, as the
@@ -102,6 +102,9 @@ class TokenTotals(NamedTuple):
     latent_pairs: Optional[jax.Array] = None   # (layers,) int32
     router_bias_absmax: Optional[jax.Array] = None  # (layers,) float32
     hc_sum_error: Optional[jax.Array] = None        # (layers,) float32
+    kda_chunks: Optional[jax.Array] = None     # (layers,) int32
+    kda_positions: Optional[jax.Array] = None  # (layers,) int32
+    kda_log_decay_absmax: Optional[jax.Array] = None  # (layers,) float32
     seq_aux_loss: Optional[jax.Array] = None        # (layers,) float32
 
 
@@ -122,6 +125,9 @@ PUBLISHED = {
     "latent_pairs": "attention_latent_pairs_total",
     "hc_sum_error": "hc_res_sum_error_max",
     "router_bias_absmax": "moe_router_bias_absmax",
+    "kda_chunks": "kda_chunks_total",
+    "kda_positions": "kda_positions_total",
+    "kda_log_decay_absmax": "kda_log_decay_absmax",
 }
 
 #: A declared loss term's gauge: its mean a step over the pass, a layer.

@@ -423,7 +423,8 @@ def test_a_count_folds_as_it_is_declared(through):
     cfg = _tiny("latent")
     task = NextToken(cfg, TrainConfig(batch_size=2, window=32))
     declared = model_counts(cfg)
-    assert set(FOLDED_BY_MAX) == {"router_bias_absmax", "hc_sum_error"}
+    assert set(FOLDED_BY_MAX) == {"router_bias_absmax", "hc_sum_error",
+                                  "kda_log_decay_absmax"}
     rng = np.random.default_rng(0)
     a, b = ({name: jnp.asarray(rng.integers(1, 9, c.shape), c.count.dtype)
              for name, c in declared.items()} for _ in range(2))
@@ -458,13 +459,13 @@ def test_a_count_folds_as_it_is_declared(through):
     ("routed", dict(hc_streams=2),
      "hc_streams (1, or more lanes (a latent-attention model's))"),
     ("latent", dict(layer_layout=(4, 4, 0)),
-     "layer_layout (one of 0/1/2/3 per layer, or 4 in every layer)"),
+     "layer_layout (one of 0/1/2/3 per layer, or of 4/5 in every layer)"),
     ("latent_direct", dict(hc_streams=4),
      "moe_seq_aux_alpha (0, or positive with experts under a plain "
-     "residual (a latent-attention model's))"),
+     "residual (a model of layers of kinds 4 and 5))"),
     ("routed", dict(moe_seq_aux_alpha=1e-3),
      "moe_seq_aux_alpha (0, or positive with experts under a plain "
-     "residual (a latent-attention model's))"),
+     "residual (a model of layers of kinds 4 and 5))"),
     ("latent_direct", dict(q_lora_rank=-1),
      "q_lora_rank (0: a direct query; or the latent's width) (layer_layout "
      "has a latent-attention layer)"),

@@ -1,0 +1,150 @@
+"""The chunked delta rule with a decay a channel against the recurrence as
+written (ops/kda.py): outputs, the final state and every gradient, at
+small sizes and seeded inputs, float32 on the CPU."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fmda_tpu.ops import kda
+from fmda_tpu.ops.kda import kda_scan, kda_stepwise
+
+B, H, K, V = 2, 3, 8, 6
+
+
+def _inputs(t, seed=0, decay=1.0, beta=None):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k = (jax.random.normal(key, (B, t, H, K)) for key in keys[:2])
+    q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
+    v = jax.random.normal(keys[2], (B, t, H, V))
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (B, t, H, K))) * decay
+    b = jax.nn.sigmoid(jax.random.normal(keys[4], (B, t, H)))
+    return q, k, v, g, b if beta is None else jnp.full_like(b, beta)
+
+
+def _close(got, want, tol):
+    scale = float(jnp.abs(want).max()) + 1e-12
+    assert float(jnp.abs(got - want).max()) <= tol * scale
+
+
+# a length that is no multiple of the chunk (two chunks of two sub-blocks
+# each), one chunk alone, several groups (48 chunks in six groups of
+# eight), a decay that underflows inside a chunk, no decay (the plain
+# delta rule), and no correction (the state only decays)
+CASES = {
+    "ragged": (37, 32, dict()),
+    "one_chunk": (8, 8, dict()),
+    "six_groups": (384, 8, dict(decay=0.1)),
+    "underflow": (64, 16, dict(decay=60.0)),
+    "no_decay": (48, 16, dict(decay=0.0)),
+    "no_correction": (48, 16, dict(beta=0.0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_chunked_walk_is_the_recurrence_as_written(case):
+    t, chunk, kw = CASES[case]
+    args = _inputs(t, seed=t, **kw)
+
+    def through(fn):
+        def value(*a):
+            o, state, *absmax = fn(*a)
+            return (jnp.sum(jnp.sin(o)) + jnp.sum(jnp.cos(state)),
+                    (o, state, absmax))
+        return jax.jit(jax.value_and_grad(
+            value, argnums=tuple(range(5)), has_aux=True))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (want_o, want_s, _)), want = through(kda_stepwise)
+        (_, (got_o, got_s, (absmax,))), got = through(
+            lambda *a: kda_scan(*a, chunk=chunk))
+    assert got_o.shape == want_o.shape == (B, t, H, V)
+    assert got_s.shape == want_s.shape == (B, H, K, V)
+    _close(got_o, want_o, 2e-5)
+    _close(got_s, want_s, 2e-5)
+    for g, w in zip(got, want):
+        assert bool(jnp.isfinite(g).all())  # every value finite
+        _close(g, w, 1e-4)
+    # the largest |G| inside a chunk: the sum of a chunk's log-decays
+    g = args[3]
+    pad = -t % chunk
+    by_chunk = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+        B, -1, chunk, H, K)
+    assert float(absmax) == pytest.approx(
+        float(jnp.abs(by_chunk.sum(2)).max()), rel=1e-5)
+    if case == "underflow":  # past float32's 87: exp(-G) would be inf
+        assert float(absmax) > 200.0
+        assert float(jnp.exp(-absmax)) == 0.0
+    if case == "no_decay":
+        assert float(absmax) == 0.0
+    if case == "no_correction":  # nothing was ever written
+        assert not bool(jnp.any(got_s)) and not bool(jnp.any(got_o))
+
+
+def test_the_pairwise_decays_exist_a_group_of_chunks_at_a_time(monkeypatch):
+    """Forty-eight chunks are walked six groups of eight, each made
+    again in backward: no (chunks, H, chunk, chunk) array of the whole
+    sequence is in the program, and the (rows, rows, K) tensor is a
+    sub-block's."""
+    args = _inputs(384, seed=2)
+    grad = jax.jit(jax.grad(lambda *a: kda_scan(*a, chunk=8)[0].sum()))
+    text = grad.lower(*args).as_text()
+    assert f"tensor<{B}x48x{H}x8x8xf32>" not in text
+    assert f"tensor<{B}x{kda.CHUNK_GROUP}x{H}x8x8xf32>" in text
+    assert f"tensor<{B}x{kda.CHUNK_GROUP}x{H}x1x8x8x{K}xf32>" in text
+    monkeypatch.setattr(kda, "SUB_ROWS", 4)
+    halves = jax.jit(lambda *a: kda_scan(*a, chunk=8)[0]).lower(
+        *args).as_text()
+    assert f"tensor<{B}x{kda.CHUNK_GROUP}x{H}x2x4x4x{K}xf32>" in halves
+    assert f"x8x8x{K}xf32>" not in halves
+
+
+def test_a_state_or_log_decays_rounded_to_bfloat16_are_refused():
+    """The configuration guarantees a float32 state, float32 cumulative
+    log-decays and a float32 solve, and a run on the chip cannot tell
+    (PERF.md section 7: behind bfloat16 products the rounding reads as
+    the program's own distance).  Held here: the walk's types are read
+    from its traced program at ``dtype`` bfloat16, and the tolerance
+    the cases above hold the walk to is a hundred times under what a
+    recurrence with its state and ``g`` rounded to bfloat16 reads."""
+    from benchmark.reference.kda_decoder import _delta_recurrence
+
+    t, chunk, kw = CASES["six_groups"]
+    q, k, v, g, b = _inputs(t, seed=t, **kw)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = jax.jit(
+            lambda *a: kda_stepwise(*a, scale=1.0))(q, k, v, g, b)
+        one = tuple(x[0] for x in (q, k, v, g, b))
+        plain, rounded = (
+            jax.jit(lambda *a: _delta_recurrence(
+                *a, remat=False, state_as=state_as))(*one)
+            for state_as in (None, "bfloat16"))
+    scale = float(jnp.abs(want_o[0]).max())
+    assert float(jnp.abs(plain - want_o[0]).max()) <= 2e-5 * scale
+    assert float(jnp.abs(rounded - want_o[0]).max()) >= 2e-3 * scale
+
+    narrow = tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) + (g, b)
+    jaxpr = jax.make_jaxpr(lambda *a: kda_scan(
+        *a, chunk=chunk, dtype=jnp.bfloat16))(*narrow)
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    found = {"scan": [], "cumsum": [], "triangular_solve": []}
+    for eqn in equations(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        assert name != "reduce_precision"
+        if name == "scan":  # the walk's: its one carry is the state
+            n = eqn.params["num_carry"]
+            consts = eqn.params["num_consts"]
+            found[name] += [x.aval for x in eqn.invars[consts:consts + n]]
+        elif name in found:
+            found[name] += [x.aval for x in eqn.invars]
+    assert [(a.shape, a.dtype) for a in found["scan"]] == [
+        ((B, H, K, V), jnp.float32)]
+    for name in ("cumsum", "triangular_solve"):
+        assert found[name] and all(
+            a.dtype == jnp.float32 for a in found[name]), name

@@ -413,6 +413,29 @@ def test_the_chunked_scan_compiles_at_64_heads_of_64_on_state_128_by_8192(
     assert compiled.memory_analysis().temp_size_in_bytes < 1_200_000_000
 
 
+def test_the_delta_rule_walk_compiles_at_32_heads_of_128_by_8192(one_chip):
+    """The delta rule with a decay a channel, value and gradient, at
+    Kimi-Linear's widths: 128 chunks of 64 walked eight at a time, the
+    chunk's triangular solve and the pairwise decays' ``(16, 16, 128)``
+    sub-blocks among what the TPU's compiler has to take; the program's
+    temporaries stay under what the pairwise decays of the whole sequence
+    would take alone (2.1 GB in float32 on the diagonal sub-blocks)."""
+    from fmda_tpu.ops.kda import kda_scan
+
+    t, h, k = 8192, 32, 128
+
+    def step(q, key, v, g, b):
+        return jax.value_and_grad(
+            lambda *args: kda_scan(*args, chunk=64, dtype=BF16)[0].sum(),
+            tuple(range(5)))(q, key, v, g, b)
+
+    wide = _shape(one_chip, (1, t, h, k), BF16)
+    compiled = jax.jit(step).lower(
+        wide, wide, wide, _shape(one_chip, (1, t, h, k), jnp.float32),
+        _shape(one_chip, (1, t, h), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_200_000_000
+
+
 def _hbm_instructions(text):
     """A compiled module's instructions outside its fused computations,
     from the result type on: what exists as an array of its own."""
