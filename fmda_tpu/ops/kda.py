@@ -27,7 +27,20 @@ the state entering the chunk::
 (``u_i`` is the correction position ``i`` writes: ``b_i (v_i - S_i^T
 k_i)`` of the recurrence; the solve gives all of a chunk's at once, and
 splits off what the entering state adds, so that everything but
-``kda_carry`` and ``kda_out`` is independent of the walk.)  **Every
+``kda_carry`` and ``kda_out`` is independent of the walk.)  **The solve
+is float32 products, and substitution, not a power series.**  With ``L
+= I + Diag(b) A`` the inverse is made by halving, ``inv([[L11, 0],
+[L21, L22]]) = [[X, 0], [-Y L21 X, Y]]`` with ``X = inv(L11)``, ``Y =
+inv(L22)``, from diagonal blocks of :data:`SOLVE_ROWS` rows upward, and
+``[u0 | w]`` is one product with it on the MXU
+(:func:`_unit_lower_solve`; backward is two more); the halving's own
+products, of blocks of 4 to 32 rows, run on the vector unit with the
+group's systems on the minor axis.  ``(I + N)^-1 = (I - N)(I + N^2)(I
++ N^4)...`` is the same inverse in exact arithmetic and is used inside a
+block of four rows only: where neighbouring keys agree the powers of
+``N`` grow binomially before they cancel, and float32 loses the answer
+(the numbers are at :data:`SOLVE_ROWS`); substitution keeps every
+intermediate at the size of the answer.  **Every
 exponent is a difference** ``G_i - G_j`` **with** ``j <= i``**, never
 positive, and no term divides by a decay**: at the published
 initialisation ``g`` reaches -1.6 a position, a decay underflows inside a
@@ -66,6 +79,14 @@ CHUNK_GROUP = 8
 #: Rows of a sub-block: the pairwise decays exist as a tensor on the
 #: ``(SUB_ROWS, SUB_ROWS)`` diagonal sub-blocks of a chunk only.
 SUB_ROWS = 16
+#: Rows of the diagonal blocks the chunk's solve inverts by powers of
+#: their strictly lower part, ``(I - N)(I + N^2)``; above them blocks are
+#: merged by substitution.  Where a chunk's keys all but agree (``k_0 +
+#: 0.05 noise``, ``b`` 1: float32 against a float64 solve of 64 rows, CPU
+#: run) powers over all 64 rows read 7e+10, over blocks of 16 rows
+#: 4.8e-4, of 8 rows 1.0e-6, of 4 or 2 rows 4e-7, XLA's solve 3.3e-7:
+#: ``N^8`` of 16 such rows reaches 6,435 before the series cancels it.
+SOLVE_ROWS = 4
 
 
 def kda_stepwise(q, k, v, g, b, *, scale=None
@@ -143,6 +164,82 @@ def _pairwise(q, k, gc, sub: int, dtype):
     return a * jnp.tril(jnp.ones((chunk, chunk), f32), -1), b
 
 
+def _product(x, y):
+    """``x @ y`` of float32 operands at float32's precision: the chip's
+    default for them is one bfloat16 pass, so every pass is asked for."""
+    return jnp.matmul(x, y, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def _by_system(x, y):
+    """``(i, j, N) x (j, k, N) -> (i, k, N)``: a product a system with the
+    systems on the minor axis, multiplied and summed on the vector unit
+    (a block of 4 to 32 rows fills a sixteenth of an MXU tile or less;
+    here every lane works whatever the block).  On the chip one layer's
+    walk at 8,192 positions reads 8.39 ms forward and 44.30 with its
+    gradient this way, 11.21 and 49.14 with every level as two masked
+    ``(64, 64)`` products on the MXU, 18.96 and 77.58 with XLA's
+    triangular solve (PERF.md section 6, PR 50)."""
+    return jnp.sum(x[:, :, None, :] * y[None, :, :, :], axis=1)
+
+
+def _halved(n):
+    """``(I + n)^-1`` for ``n`` (rows, rows, N) strictly lower triangular,
+    ``rows`` a power of two times :data:`SOLVE_ROWS` at most: both
+    halves' inverses in one call, side by side on the minor axis, then
+    the block under them."""
+    rows, _, systems = n.shape
+    if rows <= SOLVE_ROWS:
+        eye = jnp.eye(rows, dtype=n.dtype)[..., None]
+        # exact: a strictly lower block of four rows has a zero fourth power
+        return _by_system(eye - n, eye + _by_system(n, n))
+    half = rows // 2
+    both = _halved(jnp.concatenate([n[:half, :half], n[half:, half:]], -1))
+    upper, lower = both[..., :systems], both[..., systems:]
+    below = -_by_system(lower, _by_system(n[half:, :half], upper))
+    return jnp.concatenate([
+        jnp.concatenate([upper, jnp.zeros_like(upper)], 1),
+        jnp.concatenate([below, lower], 1)], 0)
+
+
+def _unit_lower_inverse(n):
+    """``(I + n)^-1`` for ``n`` (..., C, C) float32, strictly lower
+    triangular, by halving (module docstring; :func:`_halved`), with the
+    systems moved to the minor axis for it and ``C`` padded to a power of
+    two times :data:`SOLVE_ROWS` with rows of the identity."""
+    rows = n.shape[-1]
+    size = SOLVE_ROWS
+    while size < rows:
+        size *= 2
+    by_system = jnp.moveaxis(n.reshape((-1, rows, rows)), 0, -1)
+    by_system = jnp.pad(by_system, ((0, size - rows),) * 2 + ((0, 0),))
+    inv = _halved(by_system)[:rows, :rows]
+    return jnp.moveaxis(inv, -1, 0).reshape(n.shape)
+
+
+@jax.custom_vjp
+def _unit_lower_solve(n, r):
+    """``x`` of ``(I + n) x = r``: :func:`_unit_lower_inverse` and one
+    product.  Backward is two products from the inverse and ``x``, which
+    forward keeps, and not the transform of the halving."""
+    return _unit_lower_solve_fwd(n, r)[0]
+
+
+def _unit_lower_solve_fwd(n, r):
+    inv = _unit_lower_inverse(n)
+    x = _product(inv, r)
+    return x, (inv, x)
+
+
+def _unit_lower_solve_bwd(kept, dx):
+    inv, x = kept
+    dr = _product(jnp.swapaxes(inv, -1, -2), dx)
+    return -jnp.tril(_product(dr, jnp.swapaxes(x, -1, -2)), -1), dr
+
+
+_unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
+
+
 def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """:func:`kda_stepwise` in chunks of ``chunk`` positions (module
@@ -163,7 +260,6 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None
     n_chunks = (t + pad) // chunk
     group = _largest_divisor(n_chunks, CHUNK_GROUP)
     sub = _largest_divisor(chunk, SUB_ROWS)
-    eye = jnp.eye(chunk, dtype=f32)
 
     def walk(state, at):
         """A group of chunks in order from the ``state`` (B, H, K, V)
@@ -178,10 +274,9 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None
             a_kk, a_qk = _pairwise(q32, k32, gc, sub, dtype)
         with jax.named_scope("kda_solve"):
             # what each position writes, but for the entering state's part
-            solved = jax.scipy.linalg.solve_triangular(
-                eye + b * a_kk,
-                b * jnp.concatenate([v.astype(f32), k32 * from_start], -1),
-                lower=True, unit_diagonal=True)
+            solved = _unit_lower_solve(
+                b * a_kk,
+                b * jnp.concatenate([v.astype(f32), k32 * from_start], -1))
             u0, w = solved[..., :dv], solved[..., dv:].astype(dtype)
         q_in = (q32 * from_start).astype(dtype)
         to_end = (k32 * jnp.exp(gc[..., -1:, :] - gc)).astype(dtype)

@@ -12,9 +12,12 @@ from fmda_tpu.ops.kda import kda_scan, kda_stepwise
 B, H, K, V = 2, 3, 8, 6
 
 
-def _inputs(t, seed=0, decay=1.0, beta=None):
+def _inputs(t, seed=0, decay=1.0, beta=None, key_noise=None):
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     q, k = (jax.random.normal(key, (B, t, H, K)) for key in keys[:2])
+    if key_noise is not None:  # every position's key near one key a head
+        k = jax.random.normal(jax.random.fold_in(keys[1], 1),
+                              (B, 1, H, K)) + key_noise * k
     q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
     v = jax.random.normal(keys[2], (B, t, H, V))
     g = -jax.nn.softplus(jax.random.normal(keys[3], (B, t, H, K))) * decay
@@ -30,7 +33,12 @@ def _close(got, want, tol):
 # a length that is no multiple of the chunk (two chunks of two sub-blocks
 # each), one chunk alone, several groups (48 chunks in six groups of
 # eight), a decay that underflows inside a chunk, no decay (the plain
-# delta rule), and no correction (the state only decays)
+# delta rule), no correction (the state only decays), and one chunk of 64
+# whose keys all but agree (neighbouring tokens' do, in a trained layer):
+# the chunk's unit-lower matrix is then near all ones under its diagonal,
+# the powers of that part grow binomially before they cancel, and an
+# inverse by doublings over 16 rows reads 4.8e-4 here where a solve by
+# substitution reads 3e-7 (ops/kda.py SOLVE_ROWS)
 CASES = {
     "ragged": (37, 32, dict()),
     "one_chunk": (8, 8, dict()),
@@ -38,6 +46,7 @@ CASES = {
     "underflow": (64, 16, dict(decay=60.0)),
     "no_decay": (48, 16, dict(decay=0.0)),
     "no_correction": (48, 16, dict(beta=0.0)),
+    "keys_alike": (64, 64, dict(decay=0.0, beta=1.0, key_noise=0.05)),
 }
 
 
@@ -127,24 +136,87 @@ def test_a_state_or_log_decays_rounded_to_bfloat16_are_refused():
     jaxpr = jax.make_jaxpr(lambda *a: kda_scan(
         *a, chunk=chunk, dtype=jnp.bfloat16))(*narrow)
 
-    def equations(jaxpr):
+    def equations(jaxpr, scopes=""):
+        # an inner program's name stacks start at the equation that
+        # calls it (the solve's products are inside its custom_vjp)
         for eqn in jaxpr.eqns:
-            yield eqn
+            under = f"{scopes}/{eqn.source_info.name_stack}"
+            yield under, eqn
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from equations(sub)
+                yield from equations(sub, under)
 
-    found = {"scan": [], "cumsum": [], "triangular_solve": []}
-    for eqn in equations(jaxpr.jaxpr):
+    # the solve: under the ``kda_solve`` scope every product on the MXU
+    # asks for every pass, and those of the halving on the vector unit
+    # multiply and sum float32
+    found = {"scan": [], "cumsum": [], "kda_solve": [], "halving": []}
+    for under, eqn in equations(jaxpr.jaxpr):
         name = eqn.primitive.name
-        assert name != "reduce_precision"
+        assert name not in ("reduce_precision", "triangular_solve")
         if name == "scan":  # the walk's: its one carry is the state
             n = eqn.params["num_carry"]
             consts = eqn.params["num_consts"]
             found[name] += [x.aval for x in eqn.invars[consts:consts + n]]
-        elif name in found:
+        elif name == "cumsum":
             found[name] += [x.aval for x in eqn.invars]
+        elif name == "dot_general" and "kda_solve" in under:
+            assert eqn.params["precision"] is not None and all(
+                p == jax.lax.Precision.HIGHEST
+                for p in eqn.params["precision"])
+            found["kda_solve"] += [x.aval for x in eqn.invars + eqn.outvars]
+        elif name in ("mul", "reduce_sum") and "kda_solve" in under:
+            found["halving"] += [x.aval for x in eqn.invars + eqn.outvars]
     assert [(a.shape, a.dtype) for a in found["scan"]] == [
         ((B, H, K, V), jnp.float32)]
-    for name in ("cumsum", "triangular_solve"):
+    for name in ("cumsum", "kda_solve", "halving"):
         assert found[name] and all(
             a.dtype == jnp.float32 for a in found[name]), name
+
+
+def _strictly_lower(kind):
+    """``Diag(b) A`` of a chunk of 64, (B, H, 64, 64) float32: of the
+    ``keys_alike`` case (no decay, so ``A`` is the keys' products), or
+    random."""
+    if kind == "random":
+        return jnp.tril(jax.random.normal(
+            jax.random.PRNGKey(7), (B, H, 64, 64)), -1) / 8.0
+    t, _, kw = CASES["keys_alike"]
+    _, k, _, _, b = _inputs(t, seed=t, **kw)
+    a = jnp.einsum("bihk,bjhk->bhij", k, k)
+    return jnp.moveaxis(b, 1, 2)[..., None] * jnp.tril(a, -1)
+
+
+@pytest.mark.parametrize("kind", ["keys_alike", "random"])
+def test_the_chunks_inverse_is_the_inverse(kind):
+    strict = _strictly_lower(kind)
+    l = jnp.eye(64) + strict
+    inv = jax.jit(kda._unit_lower_inverse)(strict)
+    assert inv.shape == l.shape and inv.dtype == jnp.float32
+    assert not bool(jnp.any(jnp.triu(inv, 1)))      # lower triangular
+    assert float(jnp.abs(jnp.diagonal(inv, axis1=-2, axis2=-1) - 1).max()) \
+        == 0.0                                       # of unit diagonal
+    with jax.default_matmul_precision("highest"):
+        assert float(jnp.abs(inv @ l - jnp.eye(64)).max()) <= 1e-5
+
+
+@pytest.mark.parametrize("kind", ["keys_alike", "random"])
+def test_the_solves_gradients_are_the_triangular_solves(kind):
+    """``_unit_lower_solve``'s own rule (two products from what forward
+    kept) against ``jax.grad`` through XLA's ``triangular_solve``."""
+    strict = _strictly_lower(kind)
+    r = jax.random.normal(jax.random.PRNGKey(8), (B, H, 64, 2 * V))
+
+    def through(solve):
+        def value(strict, r):
+            x = solve(jnp.tril(strict, -1), r)
+            return jnp.sum(jnp.sin(x)), x
+        return jax.jit(jax.value_and_grad(value, (0, 1), has_aux=True))(
+            strict, r)
+
+    with jax.default_matmul_precision("highest"):
+        (_, want_x), want = through(
+            lambda n, r: jax.scipy.linalg.solve_triangular(
+                jnp.eye(64) + n, r, lower=True, unit_diagonal=True))
+        (_, got_x), got = through(kda._unit_lower_solve)
+    _close(got_x, want_x, 1e-5)
+    for g, w in zip(got, want):
+        _close(g, w, 1e-5)

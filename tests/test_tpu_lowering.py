@@ -416,10 +416,12 @@ def test_the_chunked_scan_compiles_at_64_heads_of_64_on_state_128_by_8192(
 def test_the_delta_rule_walk_compiles_at_32_heads_of_128_by_8192(one_chip):
     """The delta rule with a decay a channel, value and gradient, at
     Kimi-Linear's widths: 128 chunks of 64 walked eight at a time, the
-    chunk's triangular solve and the pairwise decays' ``(16, 16, 128)``
-    sub-blocks among what the TPU's compiler has to take; the program's
-    temporaries stay under what the pairwise decays of the whole sequence
-    would take alone (2.1 GB in float32 on the diagonal sub-blocks)."""
+    chunk's solve (float32 products: the program holds no triangular
+    solve, as lowered or as compiled) and the pairwise decays'
+    ``(16, 16, 128)`` sub-blocks among what the TPU's compiler has to
+    take; the program's temporaries stay under what the pairwise decays
+    of the whole sequence would take alone (2.1 GB in float32 on the
+    diagonal sub-blocks)."""
     from fmda_tpu.ops.kda import kda_scan
 
     t, h, k = 8192, 32, 128
@@ -430,9 +432,14 @@ def test_the_delta_rule_walk_compiles_at_32_heads_of_128_by_8192(one_chip):
             tuple(range(5)))(q, key, v, g, b)
 
     wide = _shape(one_chip, (1, t, h, k), BF16)
-    compiled = jax.jit(step).lower(
+    lowered = jax.jit(step).lower(
         wide, wide, wide, _shape(one_chip, (1, t, h, k), jnp.float32),
-        _shape(one_chip, (1, t, h), jnp.float32)).compile()
+        _shape(one_chip, (1, t, h), jnp.float32))
+    compiled = lowered.compile()
+    for text in (lowered.as_text(), compiled.as_text()):
+        for solve in ("triangular_solve", "triangular-solve",
+                      "TriangularSolve"):
+            assert solve not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1_200_000_000
 
 
