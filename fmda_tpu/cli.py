@@ -2091,14 +2091,39 @@ def _print_perf_report(doc: dict, profile_text, *, top: int) -> None:
         programs.sort(key=lambda p: -float(p.get("compile_seconds", 0.0)))
         print(f"  top {min(top, len(programs))} programs "
               f"by compile time:")
+        # trace / lower / backend / cache / held: schema 2 (a bundle
+        # written before it prints zeros and dashes)
         print(f"    {'program':<32} {'signature':<18} {'compiles':>8} "
-              f"{'calls':>8} {'compile_s':>10} {'gflops':>9}")
+              f"{'calls':>8} {'compile_s':>10} {'trace_s':>8} "
+              f"{'lower_s':>8} {'backend_s':>9} {'cache':>5} "
+              f"{'gflops':>9} {'held':>10}")
         for p in programs[:top]:
+            held = (p.get("memory") or {}).get("reserved_bytes")
             print(f"    {str(p.get('program', '')):<32} "
-                  f"{str(p.get('signature', '')):<18} "
+                  f"{str(p.get('signature', ''))[:18]:<18} "
                   f"{p.get('compiles', 0):>8} {p.get('calls', 0):>8} "
                   f"{float(p.get('compile_seconds', 0.0)):>10.3f} "
-                  f"{float(p.get('flops', 0.0)) / 1e9:>9.3f}")
+                  f"{float(p.get('trace_s', 0.0)):>8.3f} "
+                  f"{float(p.get('lower_s', 0.0)):>8.3f} "
+                  f"{float(p.get('backend_compile_s', 0.0)):>9.3f} "
+                  f"{str(p.get('cache') or '-'):>5} "
+                  f"{float(p.get('flops', 0.0)) / 1e9:>9.3f} "
+                  f"{_fmt_bytes(held) if held is not None else '-':>10}")
+    untracked = ledger.get("untracked") or {}
+    if untracked.get("by_name"):
+        rows = sorted(
+            untracked["by_name"].items(),
+            key=lambda kv: -(kv[1]["trace_s"] + kv[1]["lower_s"]
+                             + kv[1]["backend_compile_s"]))
+        print(f"  compiled outside any tracked program: trace "
+              f"{untracked['trace_s']:.3f}s | lower "
+              f"{untracked['lower_s']:.3f}s | backend "
+              f"{untracked['backend_compile_s']:.3f}s; by name:")
+        for name, row in rows[:top]:
+            print(f"    {name:<32} trace {row['trace_s']:>8.3f} "
+                  f"lower {row['lower_s']:>8.3f} "
+                  f"backend {row['backend_compile_s']:>8.3f} "
+                  f"events {row['events']:>5}")
     memory = doc.get("memory") or {}
     if memory.get("samples"):
         leak = " | LEAK SUSPECTED" if memory.get("leak_suspected") else ""

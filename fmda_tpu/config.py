@@ -1073,13 +1073,15 @@ class ProfilingConfig:
     telemetry").
 
     The compile ledger itself is on by default everywhere — a tracked
-    jit call with the ledger enabled costs two cache-size reads and one
-    short lock window (part of ``train_dispatch_us`` in the training
-    cells, ``PERF.md`` §5).  ``cost_analysis`` re-lowers each
-    program once per compile to read FLOPs/bytes, so it is a
-    *deployment* default (serving hosts want MFU; unit tests do not
-    want doubled compile time — the module-level default is off and
-    ``configure_device_obs`` applies this section at serve time).
+    jit call with the ledger enabled costs two cache-size reads, one
+    short lock window and one thread-local set and reset (part of
+    ``train_dispatch_us`` in the training cells, ``PERF.md`` §5).
+    ``cost_analysis`` asks each program once per compile for its
+    FLOPs/bytes and its memory, from the call's own kept signature, which
+    finds jax's cached lowering and executable (no second compile since
+    PR 51); it stays a *deployment* default (serving hosts want MFU —
+    the module-level default is off and ``configure_device_obs``
+    applies this section at serve time).
     """
 
     #: Master switch for the ledger + memory monitor.

@@ -20,9 +20,15 @@ the module must be importable on router-role analysis hosts):
   alertable property (``[slo]`` ``recompile`` objective; the chaos and
   elastic soaks hard-gate ``recompiles_after_warmup == 0``).
 - :class:`CompileLedger` — the process-wide record of every tracked
-  program: compiles, calls, compile seconds, and (where the installed
-  jax supports ``cost_analysis``, probed through
-  :mod:`fmda_tpu.compat`) per-program FLOPs/bytes-accessed.  Scrape
+  program: compiles, calls, compile seconds **and what each compile was
+  made of** (``trace_s``, ``lower_s``, ``backend_compile_s``, the
+  persistent cache's ``hit`` / ``miss`` and retrieval time, from jax's
+  own ``jax.monitoring`` events, by program name), what jax compiled
+  outside any tracked call (the ``(untracked)`` table), and — when
+  asked, never on a call — what the compiled program holds on the
+  device (:meth:`TrackedFunction.memory`) and costs
+  (FLOPs/bytes-accessed), both from one ``lower().compile()`` through
+  :mod:`fmda_tpu.compat`.  Scrape
   time derives the arithmetic-intensity gauge and, for a device kind
   with a published peak (:data:`DEVICE_PEAKS`), ``device_mfu``; any
   other device gets no MFU gauge at all rather than an estimated one.
@@ -36,12 +42,23 @@ the module must be importable on router-role analysis hosts):
 Cost discipline: a :class:`TrackedFunction` whose ledger is disabled
 is one attribute check + the underlying jit call — no allocation, no
 lock.  The enabled steady-state path (no compile) is two cache-size
-reads and one small lock window (in the trainer's step loop it is part
+reads, one small lock window and one thread-local set and reset (which
+tells jax's compile events inside a tracked call from those outside;
+in the trainer's step loop it is part
 of ``train_dispatch_us``, ``PERF.md`` §5; tests/test_device_obs.py holds
-that the plane changes no output).  ``cost_analysis`` probing re-lowers the program once
-per compile, so it defaults OFF at module level and ON in
-``[profiling]`` config (serving hosts want the numbers; unit tests do
-not want doubled compile time).
+that the plane changes no output).  The ``jax.monitoring`` listeners
+(registered once a process, at the first ``tracked_jit`` of an enabled
+ledger) do constant work an event: tracing a decoder step fires one
+event for every ``jnp`` call in it, and inside a tracked call every one
+of those is a thread-local read and a string compare.  The analysis
+probe (``cost_analysis`` in ``[profiling]``, and ``memory()``) lowers
+from the call's own abstract signature, kept at the compile event — a
+sharding on the committed leaves and only there — so after the call has
+run it finds jax's cached lowering and executable and compiles nothing
+(any other choice of shardings is a second lowering and a second
+compile of the program, which is what the probe cost before PR 51).  It
+still defaults OFF at module level and ON in ``[profiling]`` config: a
+probe per compile is a tree-map and two cache lookups a program.
 
 The ledger dump (:meth:`CompileLedger.dump`) has a pinned schema
 (``LEDGER_SCHEMA`` / ``PROGRAM_SCHEMA``, ``LEDGER_SCHEMA_VERSION``)
@@ -58,20 +75,58 @@ from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
 #: bump when LEDGER_SCHEMA / PROGRAM_SCHEMA change shape
-LEDGER_SCHEMA_VERSION = 1
+LEDGER_SCHEMA_VERSION = 2
 
 #: exact key set of CompileLedger.dump() (pinned; bundle member)
 LEDGER_SCHEMA = (
     "schema_version", "backend", "compiles_total",
     "compile_seconds_total", "unexpected_recompiles_total",
-    "cost_probe_failures", "programs",
+    "cost_probe_failures", "programs", "untracked",
 )
 
-#: exact key set of each dump()["programs"] entry (pinned)
+#: exact key set of each dump()["programs"] entry (pinned).
+#: ``compile_seconds`` is first-call wall time; ``trace_s`` + ``lower_s``
+#: + ``backend_compile_s`` + ``rest_s`` sum to it (``rest_s``: the first
+#: execution and the dispatch); ``cache`` is the persistent cache's
+#: answer at the last compile (``"hit"`` / ``"miss"``, None where none
+#: is configured); ``memory`` is :meth:`TrackedFunction.memory`'s dict
+#: where someone has asked, else None.
 PROGRAM_SCHEMA = (
     "program", "signature", "compiles", "calls", "compile_seconds",
     "unexpected", "flops", "bytes_accessed",
+    "trace_s", "lower_s", "backend_compile_s", "rest_s", "cache",
+    "cache_retrieval_s", "compile_time_saved_s", "memory",
 )
+
+#: what a compile is made of, in the order every parts vector here keeps
+#: them (a tracked program's open compile, the ``(untracked)`` total,
+#: the ledger's running totals)
+COMPILE_PARTS = (
+    "trace_s", "lower_s", "backend_compile_s", "cache_hits",
+    "cache_misses", "cache_retrieval_s", "compile_time_saved_s",
+)
+_TRACE, _LOWER, _BACKEND, _HITS = 0, 1, 2, 3
+
+#: jax.monitoring duration events -> index into a parts vector
+_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": _TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": _LOWER,
+    "/jax/core/compile/backend_compile_duration": _BACKEND,
+    "/jax/compilation_cache/cache_retrieval_time_sec": 5,
+    "/jax/compilation_cache/compile_time_saved_sec": 6,
+}
+#: jax.monitoring plain events -> index into a parts vector
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": 3,
+    "/jax/compilation_cache/cache_misses": 4,
+}
+
+#: the ``(untracked)`` table keeps a name whose event took this long
+UNTRACKED_NAME_MIN_S = 1e-3
+#: ... and this many names; the rest fold into ``(other)``
+UNTRACKED_NAMES = 32
+#: compile records the ledger keeps (newest)
+COMPILE_RECORDS = 256
 
 #: Published per-chip peaks, keyed by jax ``device_kind``: (dense bf16
 #: FLOP/s, HBM bytes/s).  Source: Google Cloud TPU documentation, "TPU
@@ -113,11 +168,174 @@ def _leaf_signature(args: tuple, kwargs: dict) -> Tuple:
     return tuple(sig)
 
 
+# -- jax's own compile events ---------------------------------------------------
+#
+# jax 0.9.0 tells a ``jax.monitoring`` listener what each compile was made
+# of, by name and on the compiling thread: ``jaxpr_trace_duration`` with
+# ``fun_name="train_step"``, ``jaxpr_to_mlir_module_duration`` and
+# ``backend_compile_duration`` with ``fun_name="jit(train_step)"``, and
+# between the last two the persistent cache's ``cache_hits`` |
+# ``cache_misses`` | ``cache_retrieval_time_sec`` |
+# ``compile_time_saved_sec``.  The process has one pair of listeners,
+# whatever its ledgers, registered at the first ``tracked_jit`` of an
+# enabled one; they hand an event inside a tracked call to that function
+# and every other event to the armed ledgers' ``(untracked)``.
+
+
+class _Open(threading.local):
+    """This thread's place in the compile events' stream."""
+
+    #: the tracked function whose call is open on this thread
+    fn: Optional["TrackedFunction"] = None
+    #: the persistent cache's events since the last backend compile
+    #: closed here: [hits, misses, retrieval_s, saved_s]
+    cache: Optional[List[float]] = None
+    #: ends and durations of the untracked trace events no later event
+    #: has yet contained (see :func:`_uncounted`)
+    ends: Optional[List[float]] = None
+    durations: Optional[List[float]] = None
+
+
+_OPEN = _Open()
+_ARM_LOCK = threading.Lock()
+_LISTENING = False
+#: the ledgers that take the events outside tracked calls, weakly (a
+#: tuple, replaced whole: the listener iterates it without a lock)
+_ARMED: Tuple["weakref.ref[CompileLedger]", ...] = ()
+
+
+def _armed() -> List["CompileLedger"]:
+    return [ledger for ref in _ARMED if (ledger := ref()) is not None]
+
+
+def _arm(ledger: "CompileLedger") -> None:
+    """Give ``ledger`` the events outside tracked calls, and register the
+    process's two listeners if this is the first ledger to ask."""
+    global _LISTENING, _ARMED
+    with _ARM_LOCK:
+        live = _armed()
+        if ledger not in live:
+            live.append(ledger)
+        _ARMED = tuple(weakref.ref(led) for led in live)
+        if _LISTENING:
+            return
+        import jax
+
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _LISTENING = True
+
+
+def _on_event(event: str, **_kw) -> None:
+    part = _CACHE_EVENTS.get(event)
+    if part is None:
+        return
+    cache = _OPEN.cache
+    if cache is None:
+        cache = _OPEN.cache = [0, 0, 0.0, 0.0]
+    cache[part - _HITS] += 1
+
+
+def _on_duration(event: str, duration: float, fun_name: Optional[str] = None,
+                 **_kw) -> None:
+    part = _DURATION_EVENTS.get(event)
+    if part is None:
+        return
+    if part > _BACKEND:  # the cache's two durations: pending, as its events
+        cache = _OPEN.cache
+        if cache is None:
+            cache = _OPEN.cache = [0, 0, 0.0, 0.0]
+        cache[part - _HITS] += duration
+        return
+    fn = _OPEN.fn
+    if fn is not None:
+        # inside a tracked call: the program's own three events are its
+        # compile's parts; every other event is a ``jax.jit`` it calls,
+        # whose time is in the program's own trace already
+        if fun_name == (fn.name if part == _TRACE else fn._jit_name):
+            cache = None
+            if part == _BACKEND:
+                cache, _OPEN.cache = _OPEN.cache, None
+            fn._compile_part(part, duration, cache)
+        elif part == _BACKEND:
+            _OPEN.cache = None
+        return
+    cache = None
+    if part == _BACKEND:
+        cache, _OPEN.cache = _OPEN.cache, None
+    counted = _uncounted(duration) if part == _TRACE else duration
+    for ref in _ARMED:
+        ledger = ref()
+        if ledger is not None and ledger.enabled:
+            ledger._untracked_part(part, duration, counted, fun_name, cache)
+
+
+def _uncounted(duration: float) -> float:
+    """The part of an untracked trace event that no earlier event on this
+    thread has counted.  A traced function's event holds the events of
+    the ``jax.jit``s it calls, and only ends are announced: an event that
+    ends now and lasted ``duration`` contains every earlier one that
+    ended after it began, so those are taken off it.  What is kept is the
+    events nothing has contained yet, newest last (bounded: the oldest
+    are final)."""
+    now = time.perf_counter()
+    began = now - duration
+    ends, durations = _OPEN.ends, _OPEN.durations
+    if ends is None:
+        ends, durations = _OPEN.ends, _OPEN.durations = [], []
+    inside = 0.0
+    while ends and ends[-1] > began:
+        ends.pop()
+        inside += durations.pop()
+    ends.append(now)
+    durations.append(duration)
+    if len(ends) > 512:
+        del ends[:256], durations[:256]
+    return max(0.0, duration - inside)
+
+
+def _program_name(fun_name: Optional[str]) -> str:
+    """``train_step`` of ``jit(train_step)``: one row a function in the
+    ``(untracked)`` table, whichever of its three events names it."""
+    if not fun_name:
+        return "(unnamed)"
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+def _no_parts() -> List[float]:
+    """A parts vector (:data:`COMPILE_PARTS`) that holds nothing yet."""
+    return [0.0, 0.0, 0.0, 0, 0, 0.0, 0.0]
+
+
+def _add_cache(parts: List[float], cache: Optional[List[float]]) -> None:
+    """The persistent cache's pending ``[hits, misses, retrieval_s,
+    saved_s]`` into a parts vector."""
+    if cache is not None:
+        for i, v in enumerate(cache):
+            parts[_HITS + i] += v
+
+
+def _split(parts: List[float], wall_s: float) -> Dict[str, object]:
+    """What a program's record and a compile's record say of a parts
+    vector beside its first-call wall time."""
+    return {
+        "trace_s": round(parts[_TRACE], 6),
+        "lower_s": round(parts[_LOWER], 6),
+        "backend_compile_s": round(parts[_BACKEND], 6),
+        "rest_s": round(wall_s - sum(parts[:_HITS]), 6),
+        "cache_retrieval_s": round(parts[5], 6),
+        "compile_time_saved_s": round(parts[6], 6),
+    }
+
+
 class ProgramRecord:
     """Per-(program, signature) accounting inside a TrackedFunction."""
 
     __slots__ = ("signature", "compiles", "calls", "compile_s",
-                 "unexpected", "flops", "bytes_accessed")
+                 "unexpected", "flops", "bytes_accessed", "parts", "cache",
+                 "abstract", "asked", "memory", "last_compile")
 
     def __init__(self, signature: object) -> None:
         self.signature = signature
@@ -127,6 +345,25 @@ class ProgramRecord:
         self.unexpected = 0
         self.flops = 0.0
         self.bytes_accessed = 0.0
+        #: COMPILE_PARTS, summed over this record's compiles
+        self.parts = _no_parts()
+        #: the persistent cache's answer at the last compile
+        self.cache: Optional[str] = None
+        #: the compiling call's abstract ``(args, kwargs)``
+        #: (``compat.abstract_signature``), kept for ``memory()``
+        self.abstract: Optional[Tuple] = None
+        #: whether anyone has asked what the program holds, and the
+        #: answer (``memory()``)
+        self.asked = False
+        self.memory: Optional[Dict[str, object]] = None
+        #: this record's newest entry in the ledger's compile ring
+        self.last_compile: Optional[Dict[str, object]] = None
+
+
+def _cache_answer(parts: List[float]) -> Optional[str]:
+    if parts[_HITS]:
+        return "hit"
+    return "miss" if parts[_HITS + 1] else None
 
 
 class TrackedFunction:
@@ -141,9 +378,18 @@ class TrackedFunction:
     without the hook, distinct-signature counting is the fallback
     (the same degradation the pools' ``compile_count`` always had).
 
-    The recorded "compile seconds" are first-call wall time (trace +
-    compile + first execution) — the operationally useful number for
-    a serving host deciding whether precompile covered its buckets.
+    The recorded "compile seconds" are first-call wall time — the
+    operationally useful number for a serving host deciding whether
+    precompile covered its buckets — and jax's own events for this
+    program, fired on the calling thread while the call is open, split
+    it: ``trace_s`` (the program's own trace event; the ``jax.jit``s it
+    calls are in it, not added to it), ``lower_s``,
+    ``backend_compile_s`` (a fetch from the persistent cache where
+    ``cache`` reads ``"hit"``), and ``rest_s``, what is left: the first
+    execution and the dispatch.
+
+    At a compile event, never on a call, the function keeps the call's
+    abstract signature; :meth:`memory` lowers from it when asked.
     """
 
     def __init__(
@@ -157,6 +403,7 @@ class TrackedFunction:
         self.name = name
         self.ledger = ledger
         self._jit = jitted
+        self._jit_name = f"jit({name})"
         self._signature_of = signature_of
         self._lock = threading.Lock()
         self._records: Dict[object, ProgramRecord] = {}
@@ -164,6 +411,9 @@ class TrackedFunction:
         self._fallback_sigs: set = set()
         self._warm = False
         self._unexpected = 0
+        self._calls = 0
+        #: COMPILE_PARTS of the compile open on each calling thread
+        self._open_parts: Dict[int, List[float]] = {}
 
     # -- cache probe ---------------------------------------------------------
 
@@ -186,8 +436,9 @@ class TrackedFunction:
 
     def _absorb_cache_size(self) -> None:
         """Fold the current cache size into the seen watermark without
-        recording a compile — the cost probe's re-lower can grow the
-        cache, and that growth must not read as a phantom compile."""
+        recording a compile — should the analysis probe's lowering miss
+        jax's caches and grow this one, that growth must not read as a
+        phantom compile."""
         raw = self._raw_cache_size()
         if raw is None:
             return
@@ -213,7 +464,27 @@ class TrackedFunction:
         with self._lock:
             return self._unexpected
 
+    @property
+    def calls(self) -> int:
+        """Calls through an enabled ledger, compiling ones included."""
+        with self._lock:
+            return self._calls
+
     # -- the call path -------------------------------------------------------
+
+    def _compile_part(self, part: int, duration: float,
+                      cache: Optional[List[float]]) -> None:
+        """One of jax's three events for this program, fired on the
+        thread whose call is open (``_on_duration``); a backend compile
+        brings the persistent cache's events that came before it."""
+        ident = threading.get_ident()
+        with self._lock:
+            parts = self._open_parts.get(ident)
+            if parts is None:
+                parts = self._open_parts[ident] = _no_parts()
+            parts[part] += duration
+            _add_cache(parts, cache)
+        self.ledger._add_totals(part, duration, cache)
 
     def __call__(self, *args, **kwargs):
         ledger = self.ledger
@@ -223,13 +494,22 @@ class TrackedFunction:
                if self._signature_of is not None else None)
         with self._lock:
             before = self._seen_cache_size
+        outer = _OPEN.fn
+        _OPEN.fn = self
         t0 = time.perf_counter()
-        out = self._jit(*args, **kwargs)
+        try:
+            out = self._jit(*args, **kwargs)
+        finally:
+            _OPEN.fn = outer
         dt = time.perf_counter() - t0
         after = self._raw_cache_size()
         compiled = False
         unexpected = False
+        parts = None
         with self._lock:
+            self._calls += 1
+            if self._open_parts:
+                parts = self._open_parts.pop(threading.get_ident(), None)
             if after is not None:
                 if after > self._seen_cache_size:
                     compiled = True
@@ -257,19 +537,65 @@ class TrackedFunction:
                     rec.compile_s += dt
                     if unexpected:
                         rec.unexpected += 1
+                    if parts is not None:
+                        for i, v in enumerate(parts):
+                            rec.parts[i] += v
+                        rec.cache = _cache_answer(parts)
         if compiled:
-            ledger._on_compile(self, sig, dt, unexpected, args, kwargs,
-                               cache_size_before=before)
+            ledger._on_compile(self, rec, dt, parts, unexpected, args,
+                               kwargs, cache_size_before=before)
         return out
+
+    # -- what the program holds ----------------------------------------------
+
+    def _record(self, signature: object = None) -> Optional[ProgramRecord]:
+        """The record of ``signature``; without one, of the program with
+        the most calls (the newest of equals: a function without a
+        ``signature_of`` counts a record's compiling calls alone)."""
+        with self._lock:
+            if signature is not None:
+                return self._records.get(signature)
+            best = None
+            for rec in self._records.values():
+                if best is None or rec.calls >= best.calls:
+                    best = rec
+            return best
+
+    def memory(self, signature: object = None
+               ) -> Optional[Dict[str, object]]:
+        """What the compiled program reserves on the device, by the
+        compiler's own analysis: ``argument_bytes``, ``output_bytes``,
+        ``alias_bytes``, ``temp_bytes``, ``code_bytes``, ``peak_bytes``
+        where the backend gives one, ``reserved_bytes`` (argument +
+        output - alias + temp + code), and ``asked`` (what asking took:
+        seconds, and the lowerings and backend compiles jax announced
+        meanwhile, which are 0 where the kept signature found the call's
+        own executable).  None where the program has not compiled or the
+        backend has no analysis.
+
+        Lowers and compiles from the signature kept at the compile event,
+        **when asked and once**: the answer is memoised on the record
+        (the same object every time) and in the ledger's compile record.
+        Never asked on a call; who may ask is in docs/observability.md
+        "Compile ledger"."""
+        rec = self._record(signature)
+        if rec is None:
+            return None
+        if not rec.asked:
+            self.ledger._analyse(self, rec)
+        return rec.memory
 
     # -- export --------------------------------------------------------------
 
-    def snapshot(self) -> List[Dict[str, object]]:
+    def snapshot(self, *, ask_memory: bool = False
+                 ) -> List[Dict[str, object]]:
         """Per-signature program records (PROGRAM_SCHEMA keys)."""
         with self._lock:
             records = list(self._records.items())
         out = []
         for sig, rec in records:
+            if ask_memory and not rec.asked:
+                self.ledger._analyse(self, rec)
             out.append({
                 "program": self.name,
                 "signature": repr(sig),
@@ -279,6 +605,9 @@ class TrackedFunction:
                 "unexpected": rec.unexpected,
                 "flops": rec.flops,
                 "bytes_accessed": rec.bytes_accessed,
+                **_split(rec.parts, rec.compile_s),
+                "cache": rec.cache,
+                "memory": rec.memory,
             })
         return out
 
@@ -300,11 +629,16 @@ class CompileLedger:
     Thread-safe; zero-cost when ``enabled`` is False (tracked calls
     skip straight to the jit).  Registration is *weak*: the owning
     pool/trainer holds the strong reference, and programs whose owner
-    has been dropped leave the ledger with it.  ``events`` is an
-    optional
-    :class:`fmda_tpu.obs.events.EventLog` attached by the
+    has been dropped leave the ledger with it — but not its compile
+    records: the ledger keeps the newest :data:`COMPILE_RECORDS` of
+    them itself (:meth:`compile_records`), with the program's
+    ``memory`` where someone asked while the owner lived.  ``events`` is
+    an optional :class:`fmda_tpu.obs.events.EventLog` attached by the
     Observability plane (latest instance wins, the chaos-hook
-    discipline)."""
+    discipline); each compile record is mirrored there as
+    ``device.compile``.  Never into ``obs.events.default_epoch_log()``:
+    that ring is the epoch account's alone, and its readers refuse a
+    ring that holds anything else."""
 
     def __init__(self, *, enabled: bool = True,
                  cost_analysis: bool = False) -> None:
@@ -323,12 +657,28 @@ class CompileLedger:
         self._mfu_prev: Optional[Tuple[float, float, float]] = None
         self._mfu: Optional[float] = None
         self._intensity = 0.0
+        self._reset_compile_account()
+
+    def _reset_compile_account(self) -> None:
+        with self._lock:
+            #: COMPILE_PARTS, tracked and untracked together, ever
+            self._totals = _no_parts()
+            #: jax's trace / lowering / backend-compile events seen, ever
+            self._events_seen = [0, 0, 0]
+            #: COMPILE_PARTS of what compiled outside any tracked call
+            self._untracked = _no_parts()
+            #: name -> [trace_s, lower_s, backend_compile_s, events] of
+            #: the untracked events of UNTRACKED_NAME_MIN_S or more
+            self._untracked_names: Dict[str, List[float]] = {}
+            self._compile_ring: deque = deque(maxlen=COMPILE_RECORDS)
 
     # -- registration --------------------------------------------------------
 
     def track(self, fn: TrackedFunction) -> None:
         with self._lock:
             self._functions.append(weakref.ref(fn))
+        if self.enabled:
+            _arm(self)
 
     def functions(self) -> List[TrackedFunction]:
         with self._lock:
@@ -353,6 +703,7 @@ class CompileLedger:
             self._mfu_prev = None
             self._mfu = None
             self._intensity = 0.0
+        self._reset_compile_account()
 
     # -- compile events ------------------------------------------------------
 
@@ -378,54 +729,170 @@ class CompileLedger:
             self._backend, self._device_kind = name, kind
         return name, kind
 
-    def _on_compile(self, fn: TrackedFunction, sig: object, dt: float,
+    def _add_totals(self, part: int, duration: float,
+                    cache: Optional[List[float]]) -> None:
+        with self._lock:
+            self._totals[part] += duration
+            self._events_seen[part] += 1
+            _add_cache(self._totals, cache)
+
+    def _untracked_part(self, part: int, duration: float, counted: float,
+                        fun_name: Optional[str],
+                        cache: Optional[List[float]]) -> None:
+        """One of jax's events outside any tracked call (``_on_duration``):
+        ``counted`` of its ``duration`` is new to this thread's total (a
+        trace event holds the events of what it calls); the table's rows
+        keep each name's own events whole."""
+        with self._lock:
+            self._totals[part] += counted
+            self._events_seen[part] += 1
+            self._untracked[part] += counted
+            _add_cache(self._totals, cache)
+            _add_cache(self._untracked, cache)
+            if duration < UNTRACKED_NAME_MIN_S:
+                return
+            names = self._untracked_names
+            name = _program_name(fun_name)
+            row = names.get(name)
+            if row is None:
+                if len(names) >= UNTRACKED_NAMES:
+                    name = "(other)"
+                    row = names.get(name)
+                if row is None:
+                    row = names[name] = [0.0, 0.0, 0.0, 0]
+            row[part] += duration
+            row[3] += 1
+
+    def compile_parts_total(self) -> Tuple[float, float, float, int, int,
+                                           float]:
+        """``(trace_s, lower_s, backend_compile_s, cache_hits,
+        cache_misses, cache_retrieval_s)`` of everything jax compiled in
+        this process since the ledger was armed, tracked and untracked
+        together: six reads, for a caller that accounts an interval by
+        difference (``Trainer._end_epoch``)."""
+        with self._lock:
+            t = self._totals
+            return t[0], t[1], t[2], t[3], t[4], t[5]
+
+    def _compile_events_seen(self) -> List[int]:
+        """jax's trace / lowering / backend-compile events so far."""
+        with self._lock:
+            return list(self._events_seen)
+
+    def compile_records(self) -> List[Dict[str, object]]:
+        """The newest :data:`COMPILE_RECORDS` compiles of tracked
+        programs, oldest first: ``program``, ``signature``, ``compile_s``
+        and its ``trace_s`` / ``lower_s`` / ``backend_compile_s`` /
+        ``rest_s``, ``cache``, ``cache_retrieval_s``,
+        ``compile_time_saved_s``, ``backend``, ``unexpected``,
+        ``cache_size_before``, and ``memory`` (None until someone asks
+        the program).  They outlive the function they describe."""
+        with self._lock:
+            return list(self._compile_ring)
+
+    def untracked(self) -> Dict[str, object]:
+        """What jax compiled outside any tracked call: COMPILE_PARTS in
+        total (every instant once) and, by name, the events of 1 ms or
+        more (each name's own events whole, so a row holds the rows of
+        what it calls; :data:`UNTRACKED_NAMES` names, the rest under
+        ``(other)``)."""
+        with self._lock:
+            total = dict(zip(COMPILE_PARTS, self._untracked))
+            rows = {name: list(row)
+                    for name, row in self._untracked_names.items()}
+        for key in ("trace_s", "lower_s", "backend_compile_s",
+                    "cache_retrieval_s", "compile_time_saved_s"):
+            total[key] = round(total[key], 6)
+        total["by_name"] = {
+            name: {"trace_s": round(row[0], 6), "lower_s": round(row[1], 6),
+                   "backend_compile_s": round(row[2], 6),
+                   "events": int(row[3])}
+            for name, row in sorted(rows.items())}
+        return total
+
+    def _on_compile(self, fn: TrackedFunction, rec: ProgramRecord,
+                    dt: float, parts: Optional[List[float]],
                     unexpected: bool, args: tuple, kwargs: dict, *,
                     cache_size_before: int) -> None:
         backend = self.backend()
+        try:
+            from fmda_tpu import compat
+
+            rec.abstract = compat.abstract_signature(args, kwargs)
+        except Exception:  # noqa: BLE001 — loss-free: a call whose
+            # leaves cannot be described leaves ``memory()`` None
+            rec.abstract = None
+        # what was asked of the program before this compile is not this
+        # one's answer
+        rec.asked, rec.memory = False, None
         if self.cost_analysis:
-            self._probe_cost(fn, sig, args, kwargs)
+            self._analyse(fn, rec)
+        parts = parts or _no_parts()
+        record: Dict[str, object] = {
+            "program": fn.name,
+            "signature": repr(rec.signature),
+            "compile_s": round(dt, 6),
+            **_split(parts, dt),
+            "cache": _cache_answer(parts),
+            "backend": backend,
+            "unexpected": bool(unexpected),
+            "cache_size_before": cache_size_before,
+        }
         events = self.events
         if events is not None:
-            events.emit(
-                "device.compile",
-                program=fn.name,
-                signature=repr(sig),
-                compile_s=round(dt, 6),
-                backend=backend,
-                unexpected=bool(unexpected),
-                cache_size_before=cache_size_before,
-            )
+            events.emit("device.compile", **record)
             if unexpected:
                 events.emit(
                     "device.unexpected_recompile",
                     program=fn.name,
-                    signature=repr(sig),
+                    signature=repr(rec.signature),
                     backend=backend,
                 )
+        record["ts"] = time.time()
+        record["memory"] = rec.memory
+        rec.last_compile = record
+        with self._lock:
+            self._compile_ring.append(record)
 
-    def _probe_cost(self, fn: TrackedFunction, sig: object,
-                    args: tuple, kwargs: dict) -> None:
-        try:
-            from fmda_tpu import compat
+    def _analyse(self, fn: TrackedFunction, rec: ProgramRecord) -> None:
+        """One ``lower().compile()`` from the record's kept signature:
+        the program's memory, and its cost where ``cost_analysis`` is
+        on.  After the call has run it finds jax's own lowering and
+        executable, so it compiles nothing; what it took is in the
+        answer's ``asked``."""
+        memory = cost = None
+        if rec.abstract is not None:
+            seen = self._compile_events_seen()
+            t0 = time.perf_counter()
+            try:
+                from fmda_tpu import compat
 
-            cost = compat.cost_analysis(fn._jit, args, kwargs)
-        except Exception:  # noqa: BLE001 — loss-free: the probe is
-            # best-effort telemetry over private-ish jax surface; a
-            # failure is counted below, never raised into serving
-            cost = None
-        if cost is None:
+                got = compat.program_analysis(fn._jit, *rec.abstract)
+                memory, cost = got["memory"], got["cost"]
+            except Exception:  # noqa: BLE001 — loss-free: the probe is
+                # best-effort telemetry over private-ish jax surface; a
+                # failure is counted below, never raised into serving
+                pass
+            if memory is not None:
+                now = self._compile_events_seen()
+                memory["asked"] = {
+                    "s": round(time.perf_counter() - t0, 6),
+                    "lowerings": now[_LOWER] - seen[_LOWER],
+                    "backend_compiles": now[_BACKEND] - seen[_BACKEND]}
+        if memory is None and cost is None:
             with self._lock:
                 self._cost_probe_failures += 1
-            return
-        flops = float(cost.get("flops", 0.0) or 0.0)
-        nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
         with fn._lock:
-            rec = fn._records.get(sig)
-            if rec is not None:
-                rec.flops = flops
-                rec.bytes_accessed = nbytes
-        # the re-lower can grow the jit cache; absorb so the next call
-        # does not read it as a phantom compile
+            if not rec.asked:
+                rec.asked, rec.memory = True, memory
+                if rec.last_compile is not None:
+                    rec.last_compile["memory"] = memory
+            if cost is not None and self.cost_analysis:
+                rec.flops = float(cost.get("flops", 0.0) or 0.0)
+                rec.bytes_accessed = float(
+                    cost.get("bytes accessed", 0.0) or 0.0)
+        # a lowering that missed jax's caches can grow the jit cache;
+        # absorb so the next call does not read it as a phantom compile
         fn._absorb_cache_size()
 
     # -- derived totals ------------------------------------------------------
@@ -453,13 +920,15 @@ class CompileLedger:
 
     # -- export --------------------------------------------------------------
 
-    def dump(self) -> Dict[str, object]:
+    def dump(self, *, ask_memory: bool = False) -> Dict[str, object]:
         """The pinned-schema ledger document (LEDGER_SCHEMA keys;
-        ``/device`` + flight-recorder bundle member)."""
+        ``/device`` + flight-recorder bundle member).  With
+        ``ask_memory`` every live program that has not been asked what
+        it holds is asked now (:meth:`TrackedFunction.memory`)."""
         functions = self.functions()
         programs: List[Dict[str, object]] = []
         for fn in functions:
-            programs.extend(fn.snapshot())
+            programs.extend(fn.snapshot(ask_memory=ask_memory))
         programs.sort(key=lambda p: (p["program"], p["signature"]))
         compiles = sum(p["compiles"] for p in programs)
         compile_s = sum(p["compile_seconds"] for p in programs)
@@ -475,6 +944,7 @@ class CompileLedger:
             "unexpected_recompiles_total": unexpected,
             "cost_probe_failures": failures,
             "programs": programs,
+            "untracked": self.untracked(),
         }
 
     def families(self) -> Dict[str, List[Dict[str, object]]]:
@@ -796,6 +1266,8 @@ def configure_device_obs(cfg) -> None:
     led = default_ledger()
     led.enabled = bool(cfg.enabled)
     led.cost_analysis = bool(cfg.cost_analysis)
+    if led.enabled:
+        _arm(led)
     mon = default_memory_monitor()
     mon.enabled = bool(cfg.enabled)
     mon.interval_s = float(cfg.memory_interval_s)
@@ -822,7 +1294,8 @@ def device_report(*, ledger: Optional[CompileLedger] = None,
                   memory: Optional[DeviceMemoryMonitor] = None
                   ) -> Dict[str, object]:
     """The ``/device`` endpoint / flight-recorder ``device.json``
-    document: ledger dump + memory doc + raw kernel-fallback map."""
+    document: ledger dump (each live program asked what it holds, once)
+    + memory doc + raw kernel-fallback map."""
     ledger = ledger if ledger is not None else default_ledger()
     memory = memory if memory is not None else default_memory_monitor()
     try:
@@ -833,7 +1306,7 @@ def device_report(*, ledger: Optional[CompileLedger] = None,
         # families(); an import failure reads as an empty map
         fallbacks = {}
     return {
-        "ledger": ledger.dump(),
+        "ledger": ledger.dump(ask_memory=True),
         "memory": memory.doc(),
         "kernel_fallbacks": fallbacks,
         "recompiles_after_warmup": ledger.recompiles_after_warmup,

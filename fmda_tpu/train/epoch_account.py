@@ -33,7 +33,15 @@ Record fields (kind ``train.epoch`` in
 - ``epoch_end_s``: history, ``train_epoch_seconds``, the log line;
 - ``warm``: ``mark_warm`` had been called when the epoch ended;
 - ``compiles``: programs the trainer's tracked steps compiled during
-  the epoch (None where jax's cache probe is unavailable).
+  the epoch (None where jax's cache probe is unavailable);
+- ``compile_parts``: what jax compiled in the process during the epoch,
+  tracked programs and everything else together, by difference of the
+  compile ledger's running totals (``obs/device.py``
+  ``CompileLedger.compile_parts_total``): ``trace_s``, ``lower_s``,
+  ``backend_compile_s``, ``cache_hits``, ``cache_misses``,
+  ``cache_retrieval_s``.  All zero in an epoch that compiled nothing.
+  The three times lie inside ``total_s`` (on the calling thread: inside
+  ``fit_setup_s`` and the passes' ``run_s``).
 """
 
 from __future__ import annotations
@@ -43,6 +51,11 @@ from typing import Dict, Optional
 from fmda_tpu.obs.events import default_epoch_log
 
 EVENT_KIND = "train.epoch"
+
+#: the keys of a record's ``compile_parts``, in the order of
+#: ``CompileLedger.compile_parts_total()``
+COMPILE_PARTS = ("trace_s", "lower_s", "backend_compile_s", "cache_hits",
+                 "cache_misses", "cache_retrieval_s")
 
 
 def emit_epoch(
@@ -54,6 +67,7 @@ def emit_epoch(
     *,
     warm: bool,
     compiles: Optional[int],
+    compile_parts: Dict[str, float],
 ) -> Dict[str, object]:
     """Assemble one epoch's record from its clock reads and append it to
     the process's epoch ring (through the ring's ``mirror``, to an
@@ -80,6 +94,6 @@ def emit_epoch(
         }
         t = account["t_published"]
     record.update(epoch_end_s=t_end - t, total_s=t_end - t_start,
-                  warm=warm, compiles=compiles)
+                  warm=warm, compiles=compiles, compile_parts=compile_parts)
     default_epoch_log().emit(EVENT_KIND, **record)
     return record

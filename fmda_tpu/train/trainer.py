@@ -409,9 +409,32 @@ class Trainer:
     def mark_warm(self) -> None:
         """Declare step warm-up over: any compile after this is counted
         as *unexpected* on the compile ledger (the contract
-        tests/test_step_totals.py and the continuous loop's tests pin)."""
+        tests/test_step_totals.py and the continuous loop's tests pin).
+
+        It is also where the warm programs are asked what they hold
+        (:meth:`step_memory`): the answer goes into the ledger's compile
+        records, which outlive this trainer, and whoever reads them after
+        the owner is gone (the benchmark's readers do) has no function
+        left to ask.  Two cached lowerings, about a millisecond; nothing
+        is compiled (tests/test_epoch_record.py)."""
         for step in self._steps():
             step.mark_warm()
+        self.step_memory()
+
+    def step_memory(self) -> Dict[str, Optional[Dict[str, object]]]:
+        """What the compiled steps reserve on the device, by the
+        compiler's own analysis of the programs ``fit`` ran — of each
+        kind the single or the grouped one, whichever has the calls:
+        ``{"train_step": ..., "eval_step": ...}``, each
+        :meth:`fmda_tpu.obs.device.TrackedFunction.memory`'s answer
+        (None for a kind that has not compiled, or on a backend without
+        the analysis).  Asked once a program and memoised; never a
+        compile."""
+        def ran(single, group):
+            return (group if group.calls > single.calls else single).memory()
+
+        return {"train_step": ran(self._train_step, self._train_group),
+                "eval_step": ran(self._eval_step, self._eval_group)}
 
     @property
     def unexpected_recompiles(self) -> int:
@@ -718,6 +741,7 @@ class Trainer:
         # the one before it; a call's first epoch begins here.
         clock = _time.perf_counter
         t_start = clock()
+        compiled = self._compile_marks()
         with span("fit_setup", epoch=self._epochs_run):
             tc = self.train_cfg
             rng = jax.random.PRNGKey(tc.seed) if rng is None else rng
@@ -737,7 +761,6 @@ class Trainer:
             reg = default_registry()
             epoch_hist = reg.histogram("train_epoch_seconds")
             epoch_counter = reg.counter("train_epochs_total")
-            compiled = self._compiled_programs()
         t_epoch = clock()
         for epoch in range(epochs if epochs is not None else tc.epochs):
             passes: Dict[str, Dict[str, Any]] = {"train": {}}
@@ -773,32 +796,41 @@ class Trainer:
             t_start = t_epoch = t_end
         return state, history, dataset
 
-    def _compiled_programs(self) -> Optional[int]:
-        """Programs the tracked steps have compiled, all kinds together
-        (None without jax's cache probe: ``compile_counts``)."""
+    def _compile_marks(self) -> Tuple[Optional[int], Tuple]:
+        """Where the compile accounts stand, for an epoch to be accounted
+        by difference: the programs the tracked steps have compiled, all
+        kinds together (None without jax's cache probe:
+        ``compile_counts``), and the ledger's running totals of what jax
+        compiled in the process (``CompileLedger.compile_parts_total``:
+        six reads)."""
         counts = list(self.compile_counts.values())
-        return None if None in counts else sum(counts)
+        return (None if None in counts else sum(counts),
+                self._train_step.ledger.compile_parts_total())
 
     def _end_epoch(self, t_start: float, t_epoch: float,
                    passes: Dict[str, Dict[str, Any]],
-                   compiled_before: Optional[int]):
+                   before: Tuple[Optional[int], Tuple]):
         """The epoch's last clock read and its ``train.epoch`` record
         (fmda_tpu.train.epoch_account); the next epoch's index.  Returns
-        the read and the compiled-program count, for the next epoch to
-        begin from."""
+        the read and the compile marks, for the next epoch to begin
+        from."""
         import time as _time
 
-        from fmda_tpu.train.epoch_account import emit_epoch
+        from fmda_tpu.train.epoch_account import COMPILE_PARTS, emit_epoch
 
-        compiled = self._compiled_programs()
+        marks = self._compile_marks()
         t_end = _time.perf_counter()
+        (compiled_before, parts_before), (compiled, parts) = before, marks
         emit_epoch(
             self._epochs_run, t_start, t_epoch, passes, t_end,
             warm=self._train_step.warm,
             compiles=(None if None in (compiled, compiled_before)
-                      else compiled - compiled_before))
+                      else compiled - compiled_before),
+            compile_parts={
+                key: now - was for key, now, was
+                in zip(COMPILE_PARTS, parts, parts_before)})
         self._epochs_run += 1
-        return t_end, compiled
+        return t_end, marks
 
     def fit_multi(
         self,
@@ -827,6 +859,7 @@ class Trainer:
 
         clock = _time.perf_counter  # the epoch's account, as in fit
         t_start = clock()
+        compiled = self._compile_marks()
         with span("fit_setup", epoch=self._epochs_run):
             tc = self.train_cfg
             rng = jax.random.PRNGKey(tc.seed) if rng is None else rng
@@ -861,7 +894,6 @@ class Trainer:
 
             state = self.init_state(init_rng)
             history: Dict[str, List[EpochMetrics]] = {"train": [], "val": []}
-            compiled = self._compiled_programs()
         t_epoch = clock()
         for epoch in range(epochs if epochs is not None else tc.epochs):
             passes: Dict[str, Dict[str, Any]] = {"train": {}, "eval": {}}

@@ -84,7 +84,7 @@ def test_ledger_dump_schema_is_pinned():
     f(jnp.ones((2,)))
     dump = led.dump()
     assert tuple(sorted(dump)) == tuple(sorted(LEDGER_SCHEMA))
-    assert dump["schema_version"] == 1
+    assert dump["schema_version"] == 2
     assert len(dump["programs"]) == 1
     for prog in dump["programs"]:
         assert tuple(sorted(prog)) == tuple(sorted(PROGRAM_SCHEMA))
@@ -721,3 +721,326 @@ def test_bucket_change_recompile_alerts_and_bundles_end_to_end(tmp_path):
     telemetry.close()
     led.reset()
     led.events = None
+
+
+# ---------------------------------------------------------------------------
+# what a compile was made of, and what a program holds (PR 51)
+# ---------------------------------------------------------------------------
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture
+def compile_events():
+    """Every duration event jax fires while the test runs, as
+    ``(event, fun_name, seconds)``: a listener of the test's own beside
+    the ledger's."""
+    seen = []
+
+    def listen(event, duration, fun_name=None, **_kw):
+        seen.append((event, fun_name, duration))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """jax's persistent compilation cache in ``tmp_path``, holding every
+    program whatever its size (the suite runs with it off: conftest
+    pins the CPU)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # (the flag's name in two pieces: tests/test_env_utils.py holds that
+    # only utils/env.py carries it whole, which is about the program)
+    keys = {"jax_compilation_" "cache_dir": str(tmp_path / "jax_cache"),
+            "jax_enable_compilation_cache": True,
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": -1}
+    was = {k: getattr(jax.config, k) for k in keys}
+    for k, v in keys.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+def _slow_to_trace(x):
+    """A function whose trace takes milliseconds: were its events to
+    leak out of a tracked call, the ``(untracked)`` table would keep
+    them by name."""
+    for k in range(60):
+        x = jnp.tanh(x) * (1.0 + k) + jnp.sin(x)
+    return x
+
+
+def test_a_tracked_programs_trace_holds_its_nested_jits_and_untracked_none(
+        compile_events):
+    led = CompileLedger(enabled=True)
+    nested_probe = jax.jit(_slow_to_trace)
+    nested_probe.__wrapped__.__name__ = "nested_probe"
+
+    def outer(x):
+        for _ in range(20):
+            x = nested_probe(x) + 1.0
+        return x
+
+    f = tracked_jit(outer, name="nesting_prog", ledger=led)
+    f(jnp.ones((4,)))
+    nested = [s for e, name, s in compile_events
+              if e == TRACE_EVENT and name == "nested_probe"]
+    own = [s for e, name, s in compile_events
+           if e == TRACE_EVENT and name == "nesting_prog"]
+    assert len(nested) == 20 and len(own) == 1
+    (prog,) = led.dump()["programs"]
+    assert prog["trace_s"] == pytest.approx(own[0], abs=1e-5)
+    assert prog["trace_s"] >= sum(nested) > 1e-3
+    lower = [s for e, name, s in compile_events
+             if e == LOWER_EVENT and name == "jit(nesting_prog)"]
+    backend = [s for e, name, s in compile_events
+               if e == BACKEND_EVENT and name == "jit(nesting_prog)"]
+    assert prog["lower_s"] == pytest.approx(sum(lower), abs=1e-5)
+    assert prog["backend_compile_s"] == pytest.approx(sum(backend), abs=1e-5)
+    assert prog["rest_s"] >= 0.0
+    assert (prog["trace_s"] + prog["lower_s"] + prog["backend_compile_s"]
+            + prog["rest_s"]) == pytest.approx(prog["compile_seconds"],
+                                               abs=1e-5)
+    # nothing of the tracked call is outside it: the only untracked
+    # events are the eager ``jnp.ones`` above
+    untracked = led.untracked()
+    assert "nested_probe" not in untracked["by_name"]
+    assert "nesting_prog" not in untracked["by_name"]
+    assert untracked["trace_s"] < nested[0]
+    # the same function traced outside any tracked call is kept by name,
+    # once: its own event holds what it calls
+    nested_probe(jnp.ones((5,)))
+    row = led.untracked()["by_name"]["nested_probe"]
+    assert row["events"] == 3 and row["trace_s"] > 1e-3
+    total = led.untracked()["trace_s"] - untracked["trace_s"]
+    assert total == pytest.approx(row["trace_s"], rel=0.05)
+
+
+def test_the_persistent_caches_answer_is_on_the_compile_it_served(
+        persistent_cache):
+    led = CompileLedger(enabled=True)
+    led.events = EventLog()
+
+    def body(x):
+        return jnp.tanh(x) @ x.T
+
+    first = tracked_jit(body, name="cached_prog", ledger=led)
+    first(jnp.ones((8, 8)))
+    (miss,) = led.compile_records()
+    assert miss["cache"] == "miss" and miss["cache_retrieval_s"] == 0.0
+    jax.clear_caches()
+    second = tracked_jit(body, name="cached_prog", ledger=led)
+    second(jnp.ones((8, 8)))
+    _, hit = led.compile_records()
+    assert hit["cache"] == "hit" and hit["cache_retrieval_s"] > 0.0
+    assert hit["backend_compile_s"] >= hit["cache_retrieval_s"]
+    by_cache = {p["cache"]: p for p in led.dump()["programs"]}
+    assert set(by_cache) == {"hit", "miss"}
+    # the mirror on /events carries the same fields
+    mirrored = [e for e in led.events.tail() if e["kind"] == "device.compile"]
+    assert [e["cache"] for e in mirrored] == ["miss", "hit"]
+    assert {"trace_s", "lower_s", "backend_compile_s", "rest_s",
+            "cache_retrieval_s", "compile_time_saved_s"} <= set(mirrored[0])
+    # the running totals hold the tracked program's two answers and
+    # those of the eager ``jnp.ones`` beside it
+    hits, misses = led.compile_parts_total()[3:5]
+    eager = led.untracked()
+    assert (hits, misses) == (eager["cache_hits"] + 1,
+                              eager["cache_misses"] + 1)
+
+
+def test_a_compile_with_no_persistent_cache_reads_no_answer():
+    led = CompileLedger(enabled=True)
+    f = tracked_jit(lambda x: x - 1.0, name="prog", ledger=led)
+    f(jnp.ones((3,)))
+    (rec,) = led.compile_records()
+    assert rec["cache"] is None
+    assert led.dump()["programs"][0]["cache"] is None
+
+
+def _no_compile_happened(events):
+    return not [e for e, _, _ in events if e in (LOWER_EVENT, BACKEND_EVENT)]
+
+
+def test_memory_is_asked_once_and_compiles_nothing(compile_events):
+    led = CompileLedger(enabled=True)
+    f = tracked_jit(lambda s, b: (s @ b + 1.0, b.sum()), name="holds",
+                    ledger=led, donate_argnums=(0,))
+    state = jax.device_put(jnp.ones((16, 16)), jax.devices()[0])
+    state, _ = f(state, jnp.ones((16, 16)))
+    f.mark_warm()
+    assert led.compile_records()[0]["memory"] is None  # nobody asked yet
+    del compile_events[:]
+    size = f.cache_size()
+    got = f.memory()
+    assert _no_compile_happened(compile_events)
+    assert got is f.memory()
+    assert (f.cache_size(), f.unexpected_recompiles) == (size, 0)
+    assert got["argument_bytes"] == 2 * 16 * 16 * 4
+    assert got["alias_bytes"] == 16 * 16 * 4  # the donated state
+    assert got["reserved_bytes"] == (
+        got["argument_bytes"] + got["output_bytes"] - got["alias_bytes"]
+        + got["temp_bytes"] + got["code_bytes"])
+    assert got["asked"]["lowerings"] == got["asked"]["backend_compiles"] == 0
+    # the ledger's own record of the compile holds the answer: it
+    # outlives the function
+    assert led.compile_records()[0]["memory"] is got
+    assert led.dump()["programs"][0]["memory"] is got
+    del f
+    assert led.compile_records()[0]["memory"] is got
+    # the step goes on as it was
+    assert led.dump()["unexpected_recompiles_total"] == 0
+
+
+def test_memory_of_a_donated_sharded_step_on_a_one_device_mesh(
+        compile_events):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    replicated = NamedSharding(mesh, P())
+    batched = NamedSharding(mesh, P("dp"))
+    led = CompileLedger(enabled=True)
+
+    def step(state, batch, rng):
+        noise = jax.random.normal(rng, batch.shape)
+        return {"w": state["w"] + (batch + noise).mean(0)}
+
+    f = tracked_jit(step, name="sharded_step", ledger=led,
+                    donate_argnums=(0,),
+                    in_shardings=(replicated, batched, replicated),
+                    out_shardings=replicated)
+    state = {"w": jax.device_put(jnp.zeros((8,)), replicated)}
+    rng = jax.random.PRNGKey(0)
+    for _ in range(2):
+        state = f(state, np.ones((4, 8), np.float32), rng)
+    f.mark_warm()
+    del compile_events[:]
+    size = f.cache_size()
+    got = f.memory()
+    assert got is not None and got is f.memory()
+    assert _no_compile_happened(compile_events)
+    assert (f.cache_size(), f.unexpected_recompiles) == (size, 0)
+    state = f(state, np.ones((4, 8), np.float32), rng)
+    assert (f.cache_size(), f.unexpected_recompiles) == (size, 0)
+
+
+def test_the_cost_probe_rides_the_calls_own_lowering(compile_events):
+    """``[profiling] cost_analysis``: cost and memory from one
+    ``lower().compile()`` at the compile event, which finds the call's
+    own executable (it was a second lowering and a second compile of
+    every program before the signature carried the committed leaves'
+    shardings)."""
+    led = CompileLedger(enabled=True, cost_analysis=True)
+    f = tracked_jit(lambda s, b: s @ b, name="probed", ledger=led,
+                    donate_argnums=(0,))
+    state = jax.device_put(jnp.ones((8, 8)), jax.devices()[0])
+    f(state, jnp.ones((8, 8)))
+    lowerings = [n for e, n, _ in compile_events
+                 if e == LOWER_EVENT and n == "jit(probed)"]
+    backends = [n for e, n, _ in compile_events
+                if e == BACKEND_EVENT and n == "jit(probed)"]
+    assert (len(lowerings), len(backends)) == (1, 1)
+    (prog,) = led.dump()["programs"]
+    assert prog["flops"] > 0.0 and prog["memory"]["argument_bytes"] == 512
+    assert f.cache_size() == 1
+
+
+def test_a_disabled_ledger_registers_no_listener_and_records_nothing(
+        monkeypatch):
+    from fmda_tpu.obs import device
+
+    registered = []
+    monkeypatch.setattr(device, "_LISTENING", False)
+    monkeypatch.setattr(jax.monitoring, "register_event_listener",
+                        registered.append)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        registered.append)
+    led = CompileLedger(enabled=False)
+    f = tracked_jit(lambda x: x * 5.0, name="prog", ledger=led)
+    f(jnp.ones((2,)))
+    assert registered == [] and led not in device._armed()
+    assert led.compile_records() == []
+    assert led.compile_parts_total() == (0.0, 0.0, 0.0, 0, 0, 0.0)
+    assert f.memory() is None
+    assert led.dump()["untracked"]["by_name"] == {}
+    # an enabled one registers the pair, once a process
+    on = CompileLedger(enabled=True)
+    g = tracked_jit(lambda x: x * 6.0, name="prog", ledger=on)
+    h = tracked_jit(lambda x: x * 7.0, name="prog", ledger=on)
+    assert registered == [device._on_event, device._on_duration]
+    assert on in device._armed() and g is not h
+
+
+def test_the_untracked_table_is_bounded(monkeypatch):
+    from fmda_tpu.obs import device
+
+    led = CompileLedger(enabled=True)
+    for k in range(device.UNTRACKED_NAMES + 8):
+        led._untracked_part(0, 0.002, 0.002, f"fn_{k}", None)
+    led._untracked_part(0, 0.0005, 0.0005, "too_short_to_name", None)
+    table = led.untracked()
+    assert len(table["by_name"]) == device.UNTRACKED_NAMES + 1
+    assert table["by_name"]["(other)"]["events"] == 8
+    assert "too_short_to_name" not in table["by_name"]
+    assert table["trace_s"] == pytest.approx(
+        0.002 * (device.UNTRACKED_NAMES + 8) + 0.0005)
+
+
+def test_the_compile_ring_keeps_the_newest_records():
+    from fmda_tpu.obs import device
+
+    led = CompileLedger(enabled=True)
+    f = tracked_jit(lambda x: x.sum(), name="prog", ledger=led,
+                    signature_of=lambda x: int(x.shape[0]))
+    for n in range(1, 6):
+        f(jnp.ones((n,)))
+    assert [r["signature"] for r in led.compile_records()] == list("12345")
+    assert device.COMPILE_RECORDS == 256
+    assert led._compile_ring.maxlen == device.COMPILE_RECORDS
+    led.reset()
+    assert led.compile_records() == []
+    assert led.compile_parts_total() == (0.0, 0.0, 0.0, 0, 0, 0.0)
+
+
+def test_the_schemas_pin_at_version_two():
+    from fmda_tpu.obs.device import LEDGER_SCHEMA_VERSION
+
+    assert LEDGER_SCHEMA_VERSION == 2
+    assert "untracked" in LEDGER_SCHEMA
+    assert {"trace_s", "lower_s", "backend_compile_s", "rest_s", "cache",
+            "cache_retrieval_s", "compile_time_saved_s",
+            "memory"} <= set(PROGRAM_SCHEMA)
+    led = CompileLedger(enabled=True)
+    doc = device_report(ledger=led, memory=DeviceMemoryMonitor())
+    assert set(doc["ledger"]["untracked"]) == {
+        "trace_s", "lower_s", "backend_compile_s", "cache_hits",
+        "cache_misses", "cache_retrieval_s", "compile_time_saved_s",
+        "by_name"}
+
+
+def test_device_report_asks_every_live_program_what_it_holds():
+    led = CompileLedger(enabled=True)
+    f = tracked_jit(lambda x: x * 2.0, name="prog", ledger=led,
+                    signature_of=lambda x: int(x.shape[0]))
+    f(jnp.ones((2,)))
+    f(jnp.ones((3,)))
+    assert [p["memory"] for p in led.dump()["programs"]] == [None, None]
+    doc = device_report(ledger=led, memory=DeviceMemoryMonitor())
+    held = [p["memory"] for p in doc["ledger"]["programs"]]
+    assert [m["argument_bytes"] for m in held] == [8, 12]
+    assert f.cache_size() == 2 and f.memory(3) is held[1]
+    json.dumps(doc)

@@ -289,3 +289,125 @@ def test_an_applications_events_carry_the_epoch_records():
     finally:
         obs.close()
         ring.mirror = previous
+
+
+# ---------------------------------------------------------------------------
+# what the epoch's compiles were made of, and what its steps hold (PR 51)
+# ---------------------------------------------------------------------------
+
+COMPILE_PARTS = ("trace_s", "lower_s", "backend_compile_s", "cache_hits",
+                 "cache_misses", "cache_retrieval_s")
+
+
+@pytest.mark.parametrize("cell,how", [
+    ("gru", "fit"), ("decoder", "fit"), ("gru", "fit_multi")])
+def test_compile_parts_are_the_first_epochs_and_warm_epochs_read_zero(
+        cell, how):
+    trainer = _trainer(cell)
+    source = _token_source() if cell == "decoder" else _source()
+    out = []
+    first, second = _records_of(
+        lambda: out.extend(_run(trainer, how, 2) if how == "fit_multi"
+                           else trainer.fit(source, epochs=2)))
+    trainer.mark_warm()
+    # the warm epochs resume, as a benchmark's window does (a fresh
+    # ``fit`` initialises a state, and that compiles: the account says so)
+    warm = [] if how == "fit_multi" else _records_of(
+        lambda: trainer.fit(source, epochs=2, initial_state=out[0],
+                            dataset=out[2]))
+    assert tuple(first["compile_parts"]) == COMPILE_PARTS
+    parts = first["compile_parts"]
+    assert parts["trace_s"] > 0 and parts["lower_s"] > 0
+    assert parts["backend_compile_s"] > 0
+    # the three times lie inside the epoch, on the thread that ran it
+    assert (parts["trace_s"] + parts["lower_s"]
+            + parts["backend_compile_s"]) <= first["total_s"]
+    # no persistent cache under the pinned CPU: neither hit nor miss
+    assert (parts["cache_hits"], parts["cache_misses"]) == (0, 0)
+    zeros = dict.fromkeys(COMPILE_PARTS, 0)
+    for quiet in [second] + warm:
+        assert quiet["compiles"] == 0
+        assert quiet["compile_parts"] == zeros
+
+
+def test_a_compile_leaves_nothing_foreign_in_the_epoch_ring():
+    """The guard for ``train_pass_ms_per_step``, ``eval_pass_ms_per_step``,
+    ``eval_pass_share`` and ``epoch_turnaround_share``: their reader
+    refuses a ring whose ``emitted`` exceeds its count of ``train.epoch``
+    records, so the ledger's compile records must never land there —
+    not even with an event log attached to the ledger."""
+    from fmda_tpu.obs.device import default_ledger
+
+    ring, led = default_epoch_log(), default_ledger()
+    was_events, led.events = led.events, EventLog()
+    emitted, held = ring.emitted, len(ring.tail())
+    try:
+        trainer = _trainer()
+        trainer.fit(_source(), epochs=2)
+        trainer.mark_warm()
+        compiled = [e for e in led.events.tail()
+                    if e["kind"] == "device.compile"]
+    finally:
+        led.events = was_events
+    assert {e["program"] for e in compiled} == {"train_step", "eval_step"}
+    new = ring.tail()[held:]
+    assert ring.emitted - emitted == len(new) == 2
+    assert {e["kind"] for e in ring.tail()} == {"train.epoch"}
+    assert ring.emitted == len(ring.tail())
+
+
+@pytest.mark.parametrize("cell", ["gru", "decoder"])
+def test_step_memory_is_asked_at_mark_warm_and_compiles_nothing(cell):
+    """``mark_warm`` asks the programs ``fit`` ran what they hold; the
+    answer is in the ledger's compile records, which outlive the
+    trainer, and asking is never a compile, expected or not."""
+    import gc
+
+    from fmda_tpu.obs.device import default_ledger
+
+    seen = []
+
+    def listen(event, duration, fun_name=None, **_kw):
+        if event.endswith(("jaxpr_to_mlir_module_duration",
+                           "backend_compile_duration")):
+            seen.append((event, fun_name))
+
+    trainer = _trainer(cell)
+    _run(trainer, "fit", 2)
+    counts = trainer.compile_counts
+    assert counts == {"train_step": 1, "eval_step": 1}
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        trainer.mark_warm()
+        held = trainer.step_memory()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert seen == []
+    assert trainer.compile_counts == counts
+    assert trainer.unexpected_recompiles == 0
+    again = trainer.step_memory()
+    for kind in ("train_step", "eval_step"):
+        assert held[kind] is again[kind]
+        assert held[kind]["temp_bytes"] > 0
+        assert held[kind]["reserved_bytes"] >= held[kind]["argument_bytes"]
+        assert held[kind]["asked"]["backend_compiles"] == 0
+    # the train step donates its state and totals; the eval step its
+    # totals alone
+    assert held["train_step"]["alias_bytes"] > held["eval_step"]["alias_bytes"]
+    _run(trainer, "fit", 1)
+    assert trainer.compile_counts == counts
+    assert trainer.unexpected_recompiles == 0
+    ring = [r for r in default_ledger().compile_records()
+            if r["memory"] is held["train_step"]]
+    assert [r["program"] for r in ring] == ["train_step"]
+    del trainer
+    gc.collect()
+    assert default_ledger().compile_records()[-2:] != []
+    assert any(r["memory"] is held["eval_step"]
+               for r in default_ledger().compile_records())
+
+
+def test_step_memory_before_any_step_is_none_and_costs_nothing():
+    trainer = _trainer()
+    trainer.mark_warm()
+    assert trainer.step_memory() == {"train_step": None, "eval_step": None}
