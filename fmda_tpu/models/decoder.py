@@ -72,9 +72,9 @@ Then a final RMSNorm and a head ``(hidden, vocab_size)``: a leaf of its
 own, or with ``cfg.tie_embeddings`` the embedding transposed.  The
 expert layer computes the experts this chip holds
 (``cfg.experts_held``; :mod:`fmda_tpu.ops.moe`), attention runs through
-:func:`fmda_tpu.ops.attention.mha` (the fused kernel where
-``cfg.use_pallas`` and the backend allow), and ``cfg.remat`` recomputes
-each block in backward but for what :data:`REPLAY_KEEPS` names.
+:func:`fmda_tpu.ops.attention.mha` (the fused kernels, and the delta-rule
+walk's ``kda_intra``, where ``cfg.use_pallas`` and the backend allow), and
+``cfg.remat`` recomputes each block but for what :data:`REPLAY_KEEPS` names.
 Parameters are float32; products run in
 ``cfg.dtype``; norms, softmaxes, rotary angles and the router's
 probabilities are float32.
@@ -549,8 +549,8 @@ def _kda_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
         )[:, None] * step
         beta = jax.nn.sigmoid(beta)
     with jax.named_scope("kda_scan"):
-        o, _, absmax = kda_scan(q, k, v, log_decay, beta,
-                                chunk=cfg.kda_chunk, dtype=dt)
+        o, _, absmax = kda_scan(q, k, v, log_decay, beta, chunk=cfg.kda_chunk,
+                                dtype=dt, impl=kernel_impl(cfg.use_pallas))
     with jax.named_scope("kda_out_norm"):
         o = (rms_norm(o, module.param("o_norm", nn.initializers.ones, (hd,)),
                       cfg.rms_norm_eps)

@@ -51,7 +51,20 @@ sub-blocks of :data:`SUB_ROWS` rows: for ``i`` in a sub-block whose first
 row is ``r`` and ``j`` before ``r``, ``exp(G_i - G_j) = exp(G_i - G_r)
 exp(G_r - G_j)``, both factors at most one, so the off-diagonal
 sub-blocks are plain products on the MXU and the ``(SUB_ROWS, SUB_ROWS,
-K)`` tensor exists on the diagonal sub-blocks alone.
+K)`` tensor exists on the diagonal sub-blocks alone.  **Where it exists
+is** ``kda_scan``**'s** ``impl``: as ``"jnp"`` (:func:`_pairwise`: the
+CPU's path, the tests' yardstick, and what runs where the kernels refuse
+a shape) it is an array of a group of chunks, as are its masked exponent
+and its products before their sums over ``K``; as ``"pallas"`` or
+``"interpret"`` (:mod:`fmda_tpu.ops.pallas_kda`) a chunk-head's ``q``,
+``k`` and ``G`` enter VMEM once, ``A`` and ``B`` leave it, and the
+tensor is a kernel's values, forward and, made again, in a backward
+written by hand.  The kernels take ``K`` a multiple of 128 lanes and
+chunks of whole sub-blocks (``pallas_kda.fits``); a refusal is counted
+(``decoder:kda_shape``).  :data:`SUB_ROWS` is the same on both paths:
+inside a sub-block the sums are float32 on unrounded operands, outside
+it products of operands rounded to ``dtype``, so another sub-block is
+another rounding.
 
 The chunks are walked once, in order, as ``ops/ssd.py`` walks its own: a
 ``lax.scan`` over groups of :data:`CHUNK_GROUP` chunks carries the state,
@@ -69,6 +82,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from fmda_tpu.ops.dispatch import count_kernel_fallback
 
 #: Chunks a turn of the walk takes (512 positions at a chunk of 64: what
 #: exists a group at a time is then 8 MB a float32 array of 32 heads of
@@ -240,14 +255,18 @@ def _unit_lower_solve_bwd(kept, dx):
 _unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
 
 
-def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None
-             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None,
+             impl: str = "jnp") -> Tuple[jax.Array, jax.Array, jax.Array]:
     """:func:`kda_stepwise` in chunks of ``chunk`` positions (module
     docstring): ``(o (B, T, H, V) float32, the state after the last
     position (B, H, K, V) float32, the largest |G| inside a chunk ()
     float32)``.  A length that is no multiple of ``chunk`` is padded with
     positions that neither decay nor correct the state (``g`` and ``b``
-    zero)."""
+    zero).  ``impl`` says where ``kda_intra``'s pairwise decays live:
+    ``"jnp"`` in arrays (:func:`_pairwise`), ``"pallas"`` or
+    ``"interpret"`` in the kernels of :mod:`fmda_tpu.ops.pallas_kda`,
+    where those take the shapes; a refusal is counted
+    (``decoder:kda_shape``) and runs the ``jnp`` form."""
     f32 = jnp.float32
     batch, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -260,6 +279,12 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None
     n_chunks = (t + pad) // chunk
     group = _largest_divisor(n_chunks, CHUNK_GROUP)
     sub = _largest_divisor(chunk, SUB_ROWS)
+    if impl != "jnp":
+        from fmda_tpu.ops import pallas_kda  # Pallas: where it is asked for
+
+        if not pallas_kda.fits(chunk, sub, dk):
+            count_kernel_fallback("decoder", "kda_shape")
+            impl = "jnp"
 
     def walk(state, at):
         """A group of chunks in order from the ``state`` (B, H, K, V)
@@ -270,8 +295,12 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None
         b = b.astype(f32)[..., None]
         gc = jnp.cumsum(g.astype(f32), axis=-2)           # never positive
         from_start = jnp.exp(gc)
-        with jax.named_scope("kda_intra"):
-            a_kk, a_qk = _pairwise(q32, k32, gc, sub, dtype)
+        if impl == "jnp":
+            with jax.named_scope("kda_intra"):
+                a_kk, a_qk = _pairwise(q32, k32, gc, sub, dtype)
+        else:  # the scope is opened inside the rule's two directions
+            a_kk, a_qk = pallas_kda.pairwise(
+                sub, dtype, impl == "interpret", q32, k32, gc)
         with jax.named_scope("kda_solve"):
             # what each position writes, but for the entering state's part
             solved = _unit_lower_solve(

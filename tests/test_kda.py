@@ -12,16 +12,18 @@ from fmda_tpu.ops.kda import kda_scan, kda_stepwise
 B, H, K, V = 2, 3, 8, 6
 
 
-def _inputs(t, seed=0, decay=1.0, beta=None, key_noise=None):
+def _inputs(t, seed=0, decay=1.0, beta=None, key_noise=None,
+            sizes=(B, H, K, V)):
+    batch, h, dk, dv = sizes
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q, k = (jax.random.normal(key, (B, t, H, K)) for key in keys[:2])
+    q, k = (jax.random.normal(key, (batch, t, h, dk)) for key in keys[:2])
     if key_noise is not None:  # every position's key near one key a head
         k = jax.random.normal(jax.random.fold_in(keys[1], 1),
-                              (B, 1, H, K)) + key_noise * k
+                              (batch, 1, h, dk)) + key_noise * k
     q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
-    v = jax.random.normal(keys[2], (B, t, H, V))
-    g = -jax.nn.softplus(jax.random.normal(keys[3], (B, t, H, K))) * decay
-    b = jax.nn.sigmoid(jax.random.normal(keys[4], (B, t, H)))
+    v = jax.random.normal(keys[2], (batch, t, h, dv))
+    g = -jax.nn.softplus(jax.random.normal(keys[3], (batch, t, h, dk))) * decay
+    b = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, t, h)))
     return q, k, v, g, b if beta is None else jnp.full_like(b, beta)
 
 
@@ -50,11 +52,10 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_the_chunked_walk_is_the_recurrence_as_written(case):
-    t, chunk, kw = CASES[case]
-    args = _inputs(t, seed=t, **kw)
-
+def _walk_against_the_recurrence(args, chunk, **walk):
+    """Outputs, final state and the five gradients of ``kda_scan`` against
+    ``kda_stepwise`` on ``args``, at the tolerances every path of the
+    walk is held to: ``(o, state, the largest |G|)`` of the walk."""
     def through(fn):
         def value(*a):
             o, state, *absmax = fn(*a)
@@ -66,14 +67,23 @@ def test_the_chunked_walk_is_the_recurrence_as_written(case):
     with jax.default_matmul_precision("highest"):
         (_, (want_o, want_s, _)), want = through(kda_stepwise)
         (_, (got_o, got_s, (absmax,))), got = through(
-            lambda *a: kda_scan(*a, chunk=chunk))
-    assert got_o.shape == want_o.shape == (B, t, H, V)
-    assert got_s.shape == want_s.shape == (B, H, K, V)
+            lambda *a: kda_scan(*a, chunk=chunk, **walk))
+    assert got_o.shape == want_o.shape == args[2].shape
+    assert got_s.shape == want_s.shape
     _close(got_o, want_o, 2e-5)
     _close(got_s, want_s, 2e-5)
     for g, w in zip(got, want):
         assert bool(jnp.isfinite(g).all())  # every value finite
         _close(g, w, 1e-4)
+    return got_o, got_s, absmax
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_chunked_walk_is_the_recurrence_as_written(case):
+    t, chunk, kw = CASES[case]
+    args = _inputs(t, seed=t, **kw)
+    got_o, got_s, absmax = _walk_against_the_recurrence(args, chunk)
+    assert got_o.shape == (B, t, H, V) and got_s.shape == (B, H, K, V)
     # the largest |G| inside a chunk: the sum of a chunk's log-decays
     g = args[3]
     pad = -t % chunk
@@ -220,3 +230,120 @@ def test_the_solves_gradients_are_the_triangular_solves(kind):
     _close(got_x, want_x, 1e-5)
     for g, w in zip(got, want):
         _close(g, w, 1e-5)
+
+
+# the kernels of ops/pallas_kda.py under the Pallas interpreter, at a
+# width they take (128 key channels a head)
+WIDE = (1, 2, 128, 128)
+# what a group's (q, k, gc) and the cotangents of (A, B) are made with:
+# the decay's scale, the keys' noise about one key a head, and whether
+# the cotangents arrive lower triangular (the walk's do: the solve's
+# rule masks dA) or full (the kernel masks for itself)
+INTRA_CASES = {
+    "plain": dict(),
+    "underflow": dict(decay=60.0),
+    "no_decay": dict(decay=0.0),
+    "keys_alike": dict(decay=0.0, key_noise=0.05),
+    "full_cotangent": dict(lower=False),
+}
+
+
+@pytest.mark.parametrize("chunk", [64, 32])
+@pytest.mark.parametrize("case", sorted(INTRA_CASES))
+def test_the_kernels_are_the_pairwise_products_and_their_cotangents(
+        case, chunk):
+    from fmda_tpu.ops import pallas_kda
+
+    kw = dict(INTRA_CASES[case])
+    lower = kw.pop("lower", True)
+    q, k, _, g, _ = _inputs(3 * chunk, seed=chunk, sizes=WIDE, **kw)
+    # (B, T, H, K) -> a group of three chunks, (B, G, H, C, K)
+    q, k, g = (jnp.swapaxes(x.reshape(1, 3, chunk, 2, 128), 2, 3)
+               for x in (q, k, g))
+    q, gc = q * 128 ** -0.5, jnp.cumsum(g, -2)
+    da, db = (jax.random.normal(key, (1, 3, 2, chunk, chunk))
+              for key in jax.random.split(jax.random.PRNGKey(chunk + 1)))
+    if lower:
+        da, db = jnp.tril(da, -1), jnp.tril(db)
+
+    def through(fn):
+        out, vjp = jax.vjp(fn, q, k, gc)
+        return out, vjp((da, db))
+
+    with jax.default_matmul_precision("highest"):
+        want, want_ct = jax.jit(lambda: through(
+            lambda *a: kda._pairwise(*a, kda.SUB_ROWS, jnp.float32)))()
+        got, got_ct = jax.jit(lambda: through(
+            lambda *a: pallas_kda.pairwise(
+                kda.SUB_ROWS, jnp.float32, True, *a)))()
+    for g_, w in zip(got, want):
+        assert g_.shape == w.shape == (1, 3, 2, chunk, chunk)
+        _close(g_, w, 1e-6)
+    assert not bool(jnp.any(jnp.triu(got[0])))      # A strictly lower
+    assert not bool(jnp.any(jnp.triu(got[1], 1)))   # B lower
+    for g_, w in zip(got_ct, want_ct):
+        assert bool(jnp.isfinite(g_).all())
+        _close(g_, w, 2e-6)
+
+
+# the walk's cases at that width and at chunks the kernels take (the
+# six groups are of eight chunks of 16 here, not of 8)
+WIDE_CASES = {
+    "ragged": (37, 32, dict()),
+    "six_groups": (768, 16, dict(decay=0.1)),
+    "underflow": (64, 16, dict(decay=60.0)),
+    "no_correction": (48, 16, dict(beta=0.0)),
+    "keys_alike": (64, 64, dict(decay=0.0, beta=1.0, key_noise=0.05)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+def test_the_walk_through_the_kernels_is_the_recurrence_as_written(case):
+    from fmda_tpu.ops.dispatch import kernel_fallbacks, reset_kernel_fallbacks
+
+    t, chunk, kw = WIDE_CASES[case]
+    args = _inputs(t, seed=t, sizes=WIDE, **kw)
+    reset_kernel_fallbacks()
+    got_o, got_s, absmax = _walk_against_the_recurrence(
+        args, chunk, impl="interpret")
+    assert "decoder:kda_shape" not in kernel_fallbacks()
+    if case == "underflow":
+        assert float(absmax) > 200.0
+    if case == "no_correction":
+        assert not bool(jnp.any(got_s)) and not bool(jnp.any(got_o))
+
+
+@pytest.mark.parametrize("chunk,sub,k,takes", [
+    (64, 16, 128, True), (32, 16, 128, True), (64, 16, 256, True),
+    (64, 16, 8, False), (64, 16, 192, False), (24, 12, 128, False),
+    (8, 8, 128, False)])
+def test_the_kernels_take_whole_sub_blocks_of_whole_lanes(chunk, sub, k,
+                                                          takes):
+    from fmda_tpu.ops import pallas_kda
+
+    assert pallas_kda.fits(chunk, sub, k) is takes
+
+
+@pytest.mark.parametrize("t,chunk,sizes", [
+    (37, 32, (B, H, K, V)),      # 8 key channels a head
+    (48, 24, WIDE),              # sub-blocks of 12 rows
+])
+def test_a_shape_the_kernels_refuse_runs_as_arrays_and_is_counted(
+        t, chunk, sizes):
+    from fmda_tpu.ops.dispatch import kernel_fallbacks, reset_kernel_fallbacks
+
+    args = _inputs(t, seed=t, sizes=sizes)
+
+    def through(impl):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(jnp.sin(kda_scan(
+                *a, chunk=chunk, impl=impl)[0])), tuple(range(5))))(*args)
+
+    reset_kernel_fallbacks()
+    want, want_grads = through("jnp")
+    assert kernel_fallbacks() == {}                 # nothing was refused
+    got, got_grads = through("interpret")
+    assert kernel_fallbacks() == {"decoder:kda_shape": 1}
+    assert float(got) == float(want)
+    for g, w in zip(got_grads, want_grads):         # bit for bit
+        assert bool(jnp.all(g == w))

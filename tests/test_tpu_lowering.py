@@ -413,6 +413,35 @@ def test_the_chunked_scan_compiles_at_64_heads_of_64_on_state_128_by_8192(
     assert compiled.memory_analysis().temp_size_in_bytes < 1_200_000_000
 
 
+def _delta_rule_walk(one_chip, impl, walks=1, compiled=False, kept={}):
+    """Value and gradient of ``walks`` delta-rule walks at Kimi-Linear's
+    widths (32 heads of 128 by 8,192, chunks of 64), one after another
+    as a model's layers are, lowered for the described chip, or
+    ``compiled`` for it; a form is lowered and compiled once a test run."""
+    from fmda_tpu.ops.kda import kda_scan
+
+    if compiled:
+        if (impl, walks, "compiled") not in kept:
+            kept[impl, walks, "compiled"] = _delta_rule_walk(
+                one_chip, impl, walks).compile()
+        return kept[impl, walks, "compiled"]
+    if (impl, walks) not in kept:
+        t, h, k = 8192, 32, 128
+
+        def value(q, key, v, g, b):
+            for _ in range(walks):
+                v = kda_scan(q, key, v, g, b, chunk=64, dtype=BF16,
+                             impl=impl)[0].astype(BF16)
+            return v.astype(jnp.float32).sum()
+
+        wide = _shape(one_chip, (1, t, h, k), BF16)
+        kept[impl, walks] = jax.jit(
+            jax.value_and_grad(value, tuple(range(5)))).lower(
+            wide, wide, wide, _shape(one_chip, (1, t, h, k), jnp.float32),
+            _shape(one_chip, (1, t, h), jnp.float32))
+    return kept[impl, walks]
+
+
 def test_the_delta_rule_walk_compiles_at_32_heads_of_128_by_8192(one_chip):
     """The delta rule with a decay a channel, value and gradient, at
     Kimi-Linear's widths: 128 chunks of 64 walked eight at a time, the
@@ -422,20 +451,8 @@ def test_the_delta_rule_walk_compiles_at_32_heads_of_128_by_8192(one_chip):
     take; the program's temporaries stay under what the pairwise decays
     of the whole sequence would take alone (2.1 GB in float32 on the
     diagonal sub-blocks)."""
-    from fmda_tpu.ops.kda import kda_scan
-
-    t, h, k = 8192, 32, 128
-
-    def step(q, key, v, g, b):
-        return jax.value_and_grad(
-            lambda *args: kda_scan(*args, chunk=64, dtype=BF16)[0].sum(),
-            tuple(range(5)))(q, key, v, g, b)
-
-    wide = _shape(one_chip, (1, t, h, k), BF16)
-    lowered = jax.jit(step).lower(
-        wide, wide, wide, _shape(one_chip, (1, t, h, k), jnp.float32),
-        _shape(one_chip, (1, t, h), jnp.float32))
-    compiled = lowered.compile()
+    lowered = _delta_rule_walk(one_chip, "jnp")
+    compiled = _delta_rule_walk(one_chip, "jnp", compiled=True)
     for text in (lowered.as_text(), compiled.as_text()):
         for solve in ("triangular_solve", "triangular-solve",
                       "TriangularSolve"):
@@ -453,6 +470,44 @@ def _hbm_instructions(text):
         elif not fused and " = " in line:
             out.append(line.split(" = ", 1)[1])
     return out
+
+
+def test_the_walks_pairwise_decays_stay_in_the_kernels(one_chip):
+    """The same walk with ``kda_intra`` as the two kernels of
+    ops/pallas_kda.py: value and gradient compile; no float32 array of
+    the pairwise decays' ``(16, 16, 128)`` sub-blocks and none of
+    ``k_right``'s shape exists outside a fusion (the ``jnp`` form holds
+    the second, so the pattern can tell), and the program's temporaries
+    are under the ``jnp`` form's.  The compiled walk runs the forward
+    kernel twice (a turn, and the turn made again under backward) and
+    the backward's once; as lowered, each kernel is a ``jax.jit`` whose
+    body the module holds once a path (jax makes the jit's program again
+    where it splits a turn for backward, so the forward's twice), and
+    two walks, as two layers, hold no more bodies than one."""
+    import re
+
+    def decays(compiled):
+        return [a[:60] for a in _hbm_instructions(compiled.as_text())
+                if re.match(r"\(?(f32\[[0-9,]*16,16,128\]"
+                            r"|(bf16|f32)\[(1,)?8,32,4,64,128\])", a)]
+
+    def bodies(lowered, name):
+        return len(re.findall(
+            r"stablehlo.custom_call @tpu_custom_call.*kernel_name = "
+            rf'"{name}"', lowered.as_text()))
+
+    lowered = _delta_rule_walk(one_chip, "pallas")
+    compiled = _delta_rule_walk(one_chip, "pallas", compiled=True)
+    arrays = _delta_rule_walk(one_chip, "jnp", compiled=True)
+    assert decays(arrays) and not decays(compiled)
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < arrays.memory_analysis().temp_size_in_bytes)
+    twice = _delta_rule_walk(one_chip, "pallas", walks=2)
+    for name, runs in (("kda_intra_fwd", 2), ("kda_intra_bwd", 1)):
+        assert len(re.findall(
+            rf"(?m)^\s*%{name}(?:\.\d+)? = .*custom-call\(",
+            compiled.as_text())) == runs, name
+        assert bodies(lowered, name) == bodies(twice, name) == runs, name
 
 
 def test_the_convolution_compiles_at_4352_channels_by_8192(one_chip):
