@@ -455,9 +455,10 @@ class ModelConfig:
     #: normalised input; 3 = a state-space layer: no attention, a scan
     #: over matrix-valued state (the ``ssm_*`` group below); 4 = a latent-
     #: attention layer (the ``q_lora_rank`` group below); 5 = a delta-rule
-    #: layer with a decay a channel (the ``kda_*`` group below).  Kinds 4
-    #: and 5 state their heads' widths themselves and may share a model,
-    #: in any order; they do not mix with kinds 0..3.
+    #: layer with a decay a channel (the ``kda_*`` group below); 6 = a
+    #: gated delta rule, one decay a head (the ``gdn_*`` group below).
+    #: Kinds 4 and 5 state their heads' widths themselves and may share a
+    #: model, in any order; they do not mix with kinds 0..3 and 6.
     layer_layout: Tuple[int, ...] = ()
     sliding_window: int = 4096
     rope_theta: float = 10000.0
@@ -511,6 +512,34 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_conv: int = 0
     kda_chunk: int = 0
+    #: ``layer_layout`` 6, a gated delta rule (ops/kda.py with ONE decay
+    #: a head): ``gdn_heads`` heads, each carrying a ``(gdn_key_dim,
+    #: gdn_value_dim)`` float32 state that every position decays by one
+    #: factor and corrects by a rank-one step ``b`` times the miss, ``b =
+    #: gdn_beta_scale * sigmoid`` (2 where the model lets the correction
+    #: overshoot: the state's transition then has eigenvalues down to
+    #: -1); queries and keys ``gdn_key_dim`` wide and values
+    #: ``gdn_value_dim`` behind causal depthwise convolutions of
+    #: ``gdn_conv`` taps; decay and step straight from the stream, a head;
+    #: a full-rank output gate under ``silu``; the walk taken
+    #: ``gdn_chunk`` positions at a time.  No positional encoding.  Such
+    #: a layer shares a model with kinds 0..3.
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 0
+    gdn_chunk: int = 0
+    gdn_beta_scale: float = 1.0
+    #: Where a block's two norms sit: False, on each sublayer's input
+    #: (``x + f(RMSNorm(x))``, every other configuration's); True, on its
+    #: output (``x + RMSNorm(f(x))``: mixer and feed-forward read the
+    #: stream as it is).  A plain residual's (``hc_streams`` 1).
+    post_norm: bool = False
+    #: A layer of kind 0 or 1 takes an RMSNorm over the WHOLE width of its
+    #: query projection and of its key projection (``n_heads * head_dim``,
+    #: ``n_kv_heads * head_dim``: one mean of squares a position, a scale
+    #: a channel) before the heads are split (kind 2 has one a head).
+    qk_norm_whole: bool = False
     #: ``moe_experts == 0``: every layer's feed-forward is one dense gated
     #: MLP ``hidden -> ffn_size -> hidden`` (``hidden_act`` on the gate),
     #: with no router.

@@ -164,6 +164,37 @@ stream ``(T, 2304)``)::
                  g_e = 2.446 * sc_e / sum_{e' in S} sc_e'
                  x2 = x1 + shared(u) + sum_{e in S, held} g_e expert_e(u)    shared and experts 1024 wide
 
+A gated-delta-rule layer (``layout`` 6; :mod:`fmda_tpu.ops.kda` with
+``g`` a head) has ONE decay and one step size a head where kind 5 has
+them a channel, a correction that may overshoot, keys and values of
+different widths and a full-rank output gate (``H`` = ``gdn_heads``,
+``dk`` = ``gdn_key_dim``, ``dv`` = ``gdn_value_dim``, the convolutions
+``gdn_conv`` taps, causal, depthwise, no bias)::
+
+    q  = L2norm_head(conv_silu(h @ wq)) ;  k = L2norm_head(conv_silu(h @ wk))       (T, H, dk)
+    v  = conv_silu(h @ wv)                                                            (T, H, dv)
+    g  = -exp(a_log)[head] * softplus(h @ wa + dt_bias)      (T, H) float32, <= 0: ONE log-decay a head
+    b  = gdn_beta_scale * sigmoid(h @ wb)                    (T, H) in 0..gdn_beta_scale (2: ``I - b k k^T`` may reflect)
+    S_t = exp(g_t) S_{t-1} ;  S_t += b_t k_t (v_t - S_t^T k_t)^T          S: (dk, dv) a head, float32, S_{-1} = 0
+    o_t = S_t^T q_t * dk^-1/2
+    y  = (RMSNorm_head(o) * silu(h @ wg)) @ wo               wg: hidden -> H x dv; the norm over a head's dv
+
+It shares a model with kinds 0..3.  Two more facts a configuration may
+state: ``cfg.post_norm`` puts a block's two norms on the sublayers'
+OUTPUTS, ``x1 = x + RMSNorm(mixer(x))``, ``x2 = x1 + RMSNorm(mlp(x1))``,
+mixer and feed-forward reading the stream as it is (``h`` above is then
+``x`` itself); ``cfg.qk_norm_whole`` gives a layer of kind 0 or 1 an
+RMSNorm over the whole width of ``h @ W_q`` and of ``h @ W_k`` before the
+heads are split.  With both, three gated-delta-rule layers to one layer
+of kind 0 and a dense MLP the block is Olmo-Hybrid-7B's (``x`` the
+stream ``(T, 3840)``; 30 heads published, a chip of the two that share a
+layer holds 15 of both mixers)::
+
+    layers 0, 1, 2, 4, ... (of four, the first three):  the gated-delta-rule mixer, heads of 96 | 192, 4 taps, b in 0..2
+    layers 3, 7, ...:  q = RMSNorm(x @ wq) ;  k = RMSNorm(x @ wk) ;  v = x @ wv     heads of 128 on as many kv heads
+                 NO rotary ;  a = causal softmax(q k^T * 128^-1/2) v ;  mixer = a @ wo
+    x1 = x + RMSNorm(mixer(x)) ;  x2 = x1 + RMSNorm((silu(x1 Wg) * (x1 Wu)) Wd)     11008 wide; a final RMSNorm, an untied head
+
 A latent-attention model's residual
 may run in ``n`` = ``cfg.hc_streams`` lanes: at one lane each sublayer
 ``F`` is the plain pre-norm residual ``x + r * F(RMSNorm(x))`` every
@@ -179,7 +210,10 @@ the cores' and ``mla_proj`` (latent attention's products, norms and
 rotary); ``ssm_mixer`` a state-space mixer's five; ``kda_mixer`` a
 delta-rule mixer's ``kda_proj``, ``kda_conv``, ``kda_gates``,
 ``kda_scan`` (the walk's ``kda_intra``, ``kda_solve``, ``kda_carry``,
-``kda_out``) and ``kda_out_norm``; ``hyper_conn`` the
+``kda_out``) and ``kda_out_norm``; ``gdn_mixer`` a gated-delta-rule
+mixer's ``gdn_proj``, ``gdn_conv``, ``gdn_gates``, ``gdn_scan`` (the same
+walk's four ``kda_*`` scopes) and ``gdn_out_norm``, and with
+``post_norm`` the block's norm of its output; ``hyper_conn`` the
 lanes' ``hc_coeff``, ``hc_pre``, ``hc_post_res``; ``moe_shared``;
 ``moe_seq_aux`` the balance term; the expert layer's and the dense MLP's
 own.
@@ -238,6 +272,9 @@ SSM_LAYOUT = 3
 LATENT_LAYOUT = 4
 #: ... and for a delta-rule layer with a decay a channel (ops/kda.py).
 KDA_LAYOUT = 5
+#: ... and for a delta-rule layer with one decay a head (a gated delta
+#: rule; ops/kda.py with ``g`` (B, T, H)).
+GDN_LAYOUT = 6
 #: What is added to a head's sum of squares before the root, where a
 #: delta-rule layer takes its queries and keys to unit length.
 L2_NORM_EPS = 1e-6
@@ -297,6 +334,22 @@ def _uniform(low: float, high: float, then=lambda v: v):
 
 def _inverse_softplus(step: jax.Array) -> jax.Array:
     return step + jnp.log(-jnp.expm1(-step))
+
+
+#: Where the recurrent mixers' published code starts them: rates
+#: ``exp(a_log)`` uniform in 1..16, step sizes log-uniform in 1e-3..1e-1
+#: at a zero input (the bias is the step's inverse softplus).
+_RATE_INIT = _uniform(1.0, 16.0, jnp.log)
+_STEP_BIAS_INIT = _uniform(math.log(1e-3), math.log(1e-1),
+                           lambda u: _inverse_softplus(jnp.exp(u)))
+
+
+def _unit_length(x: jax.Array) -> jax.Array:
+    """``x`` over its last axis' length (a delta-rule layer's queries and
+    keys a head): float32, in ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.sum(
+        jnp.square(x32), -1, keepdims=True) + L2_NORM_EPS)).astype(x.dtype)
 
 
 class Count(NamedTuple):
@@ -401,15 +454,32 @@ def _merged(module: nn.Module, a: jax.Array, d: int) -> jax.Array:
     return jnp.dot(a, _weight(module, "wo", (n * width, d)).astype(a.dtype))
 
 
+def _whole_width_norm(module: nn.Module, name: str, x: jax.Array,
+                      eps: float) -> jax.Array:
+    """RMSNorm over ALL the heads' channels of ``x`` (B, heads, T, width)
+    at once (one mean of squares a position over ``heads * width``), the
+    scale ``(heads * width,)``: float32, in ``x``'s dtype."""
+    _, n, _, width = x.shape
+    scale = module.param(name, nn.initializers.ones, (n * width,))
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=(1, 3), keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)
+            * scale.reshape(n, 1, width)).astype(x.dtype)
+
+
 def _attention_mixer(window: bool):
     """Full attention (no positional encoding, every key ``j <= i``), or
     with ``window`` rotary on q, k and keys ``0 <= i - j <
-    cfg.sliding_window``."""
+    cfg.sliding_window``; where ``cfg.qk_norm_whole`` an RMSNorm over the
+    whole width of the query and of the key projection first."""
     def mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
         n, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         q, k, v = (_heads(module, h, "wq", n, hd),
                    _heads(module, h, "wk", g, hd),
                    _heads(module, h, "wv", g, hd))
+        if cfg.qk_norm_whole:
+            q, k = (_whole_width_norm(module, name, y, cfg.rms_norm_eps)
+                    for name, y in (("q_norm", q), ("k_norm", k)))
         if window:
             with jax.named_scope("rope"):
                 q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
@@ -480,11 +550,8 @@ def _ssm_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
     xs, b_in, c_out = jnp.split(xbc, [inner, inner + n], axis=-1)
     with jax.named_scope("ssd_scan"):
         step = jax.nn.softplus(step.astype(f32) + module.param(
-            "dt_bias", _uniform(math.log(1e-3), math.log(1e-1),
-                                lambda v: _inverse_softplus(jnp.exp(v))),
-            (heads,), f32))
-        rate = -jnp.exp(module.param(
-            "a_log", _uniform(1.0, 16.0, jnp.log), (heads,), f32))
+            "dt_bias", _STEP_BIAS_INIT, (heads,), f32))
+        rate = -jnp.exp(module.param("a_log", _RATE_INIT, (heads,), f32))
         y, states = ssd_scan(
             xs.reshape(b, t, heads, p), step, rate, b_in, c_out,
             module.param("d_skip", nn.initializers.ones, (heads,), f32),
@@ -520,11 +587,6 @@ def _kda_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
     def by_head(x):
         return x.reshape(b, t, heads, hd)
 
-    def unit_length(x):
-        x32 = x.astype(f32)
-        return (x32 * jax.lax.rsqrt(jnp.sum(
-            jnp.square(x32), -1, keepdims=True) + L2_NORM_EPS)).astype(dt)
-
     with jax.named_scope("kda_proj"):
         q, k, v = (product(h, name, inner) for name in ("wq", "wk", "wv"))
         decay = product(product(h, "wf_a", hd), "wf_b", inner,
@@ -539,14 +601,11 @@ def _kda_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
                             f32), jnp.zeros((inner,), f32), dtype=dt))
             for x, name in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
     with jax.named_scope("kda_gates"):
-        q, k = unit_length(q), unit_length(k)
+        q, k = _unit_length(q), _unit_length(k)
         step = jax.nn.softplus(by_head(decay + module.param(
-            "dt_bias", _uniform(math.log(1e-3), math.log(1e-1),
-                                lambda u: _inverse_softplus(jnp.exp(u))),
-            (inner,), f32)))
+            "dt_bias", _STEP_BIAS_INIT, (inner,), f32)))
         log_decay = -jnp.exp(module.param(
-            "a_log", _uniform(1.0, 16.0, jnp.log), (heads,), f32)
-        )[:, None] * step
+            "a_log", _RATE_INIT, (heads,), f32))[:, None] * step
         beta = jax.nn.sigmoid(beta)
     with jax.named_scope("kda_scan"):
         o, _, absmax = kda_scan(q, k, v, log_decay, beta, chunk=cfg.kda_chunk,
@@ -560,6 +619,58 @@ def _kda_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
     return out, {"kda_chunks": jnp.int32(b * -(-t // cfg.kda_chunk)),
                  "kda_positions": jnp.int32(b * t),
                  "kda_log_decay_absmax": absmax}
+
+
+def _gdn_mixer(module: nn.Module, cfg: ModelConfig, h: jax.Array):
+    """A gated-delta-rule layer's mixer (module docstring) on the stream
+    ``h`` (B, T, hidden): its output (B, T, hidden), the chunks and
+    positions its walk took, the largest cumulative log-decay inside a
+    chunk and the largest ``b``.  One decay and one step size a HEAD,
+    keys ``gdn_key_dim`` and values ``gdn_value_dim`` wide, a full-rank
+    output gate; parameters start as :func:`_kda_mixer`'s do."""
+    b, t, d = h.shape
+    heads, dk, dv, taps = (cfg.gdn_heads, cfg.gdn_key_dim,
+                           cfg.gdn_value_dim, cfg.gdn_conv)
+    dt, f32 = h.dtype, jnp.float32
+
+    def product(name, width, **kw):
+        return jnp.dot(h, _weight(module, name, (d, width)).astype(dt), **kw)
+
+    with jax.named_scope("gdn_proj"):
+        q, k = product("wq", heads * dk), product("wk", heads * dk)
+        v = product("wv", heads * dv)
+        step = product("wa", heads, preferred_element_type=f32)
+        beta = product("wb", heads, preferred_element_type=f32)
+        gate = product("wg", heads * dv, preferred_element_type=f32)
+    with jax.named_scope("gdn_conv"):
+        bound = taps ** -0.5
+        q, k, v = (conv_silu(
+            x, module.param(name, _uniform(-bound, bound),
+                            (x.shape[-1], taps), f32),
+            jnp.zeros((x.shape[-1],), f32), dtype=dt
+        ).reshape(b, t, heads, -1)
+            for x, name in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+    with jax.named_scope("gdn_gates"):
+        q, k = _unit_length(q), _unit_length(k)
+        step = jax.nn.softplus(step + module.param(
+            "dt_bias", _STEP_BIAS_INIT, (heads,), f32))
+        log_decay = -jnp.exp(module.param(
+            "a_log", _RATE_INIT, (heads,), f32)) * step
+        beta = cfg.gdn_beta_scale * jax.nn.sigmoid(beta)
+    with jax.named_scope("gdn_scan"):
+        o, _, absmax = kda_scan(q, k, v, log_decay, beta,
+                                chunk=cfg.gdn_chunk, dtype=dt)
+    with jax.named_scope("gdn_out_norm"):
+        o = (rms_norm(o, module.param("o_norm", nn.initializers.ones, (dv,)),
+                      cfg.rms_norm_eps)
+             * jax.nn.silu(gate.reshape(b, t, heads, dv))).astype(dt)
+    with jax.named_scope("gdn_proj"):
+        out = jnp.dot(o.reshape(b, t, heads * dv),
+                      _weight(module, "wo", (heads * dv, d)).astype(dt))
+    return out, {"gdn_chunks": jnp.int32(b * -(-t // cfg.gdn_chunk)),
+                 "gdn_positions": jnp.int32(b * t),
+                 "gdn_log_decay_absmax": absmax,
+                 "gdn_beta_max": jax.lax.stop_gradient(jnp.max(beta))}
 
 
 def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
@@ -715,6 +826,13 @@ def _kda_rules(cfg: ModelConfig) -> list:
                          "kda_chunk")]
 
 
+def _gdn_rules(cfg: ModelConfig) -> list:
+    return [(f"{name} (layer_layout has a gated-delta-rule layer)",
+             getattr(cfg, name) > 0)
+            for name in ("gdn_heads", "gdn_key_dim", "gdn_value_dim",
+                         "gdn_conv", "gdn_chunk", "gdn_beta_scale")]
+
+
 KINDS: Dict[int, Kind] = {
     0: Kind(_attention_mixer(window=False), "attention", "mixer"),
     1: Kind(_attention_mixer(window=True), "attention", "mixer"),
@@ -744,6 +862,13 @@ KINDS: Dict[int, Kind] = {
         "kda_positions": Count(jnp.int32),
         "kda_log_decay_absmax": Count(jnp.float32, fold="max")},
         _kda_rules),
+    GDN_LAYOUT: Kind(_gdn_mixer, "gdn_mixer", None, {
+        # as kind 5's, and the largest ``b`` a pass saw: over 1 where the
+        # correction overshoots
+        "gdn_chunks": Count(jnp.int32),
+        "gdn_positions": Count(jnp.int32),
+        "gdn_log_decay_absmax": Count(jnp.float32, fold="max"),
+        "gdn_beta_max": Count(jnp.float32, fold="max")}, _gdn_rules),
 }
 
 #: Every declared count by name, whatever the configuration.
@@ -935,10 +1060,16 @@ class DecoderBlock(nn.Module):
         def sublayer(x, name, ln, fn, scope=None):
             """``x`` after the sublayer ``fn`` (normalised stream ->
             output, what it counted), and those counts: ``x + r * fn(
-            RMSNorm(x))`` at one lane, inside the lanes' mixing at more."""
+            RMSNorm(x))`` at one lane, inside the lanes' mixing at more;
+            where ``cfg.post_norm`` the norm sits on the sublayer's
+            output, ``x + r * RMSNorm(fn(x))``."""
             scale = self.param(ln, nn.initializers.ones, (d,))
 
             def normed(u):
+                if cfg.post_norm:
+                    y, counts = fn(u)
+                    with jax.named_scope(scope) if scope else nullcontext():
+                        return rms_norm(y, scale, cfg.rms_norm_eps), counts
                 return fn(rms_norm(u, scale, cfg.rms_norm_eps))
 
             if lanes:
@@ -1133,7 +1264,8 @@ def check_decoder_config(cfg: ModelConfig) -> None:
         ("head_dim", self_sized or cfg.head_dim > 0),
         ("n_kv_heads (must divide n_heads)", self_sized or (
             cfg.n_kv_heads > 0 and cfg.n_heads % cfg.n_kv_heads == 0)),
-        ("layer_layout (one of 0/1/2/3 per layer, or of 4/5 in every layer)",
+        ("layer_layout (one of 0/1/2/3/6 per layer, or of 4/5 in every "
+         "layer)",
          len(cfg.layer_layout) > 0
          and all(v in KINDS for v in cfg.layer_layout)
          and (not self_sized or set(cfg.layer_layout) <= own_widths)),
@@ -1152,6 +1284,10 @@ def check_decoder_config(cfg: ModelConfig) -> None:
          not lanes or cfg.hc_sinkhorn_iters > 0),
         ("hc_eps / hc_res_clamp (positive; hc_streams is more than 1)",
          not lanes or (cfg.hc_eps > 0 and cfg.hc_res_clamp > 0)),
+        ("post_norm (a plain residual's: hc_streams is 1)",
+         not (cfg.post_norm and lanes)),
+        ("qk_norm_whole (read by layers of kinds 0 and 1)",
+         not cfg.qk_norm_whole or bool({0, 1} & set(cfg.layer_layout))),
         ("embedding_multiplier / residual_multiplier / logits_scaling "
          "(not zero)",
          cfg.embedding_multiplier != 0 and cfg.residual_multiplier != 0

@@ -1,11 +1,15 @@
-"""The delta rule with a decay a channel, in chunks (Kimi Delta
-Attention): the sequence mixer of a ``decoder`` layer of ``layer_layout``
-5 (models/decoder.py).
+"""The delta rule with a decay, in chunks: the sequence mixer of a
+``decoder`` layer of ``layer_layout`` 5 (a decay a channel, Kimi Delta
+Attention) and 6 (one decay a head, a gated delta rule; models/decoder.py).
 
 One head carries a ``(K, V)`` state (``q``, ``k``: ``(T, H, K)``; ``v``:
-``(T, H, V)``; ``g``: ``(T, H, K)`` float32 log-decays, never positive;
-``b``: ``(T, H)`` in 0..1).  Every position decays the state by a vector,
-a factor a key channel, and then corrects it by a rank-one step towards
+``(T, H, V)``, ``V`` its own width; ``g``: float32 log-decays, never
+positive, ``(T, H, K)`` or ``(T, H)``; ``b``: ``(T, H)``, 0..1 or, where
+the correction may overshoot, 0..2).  **The shape of** ``g`` **says which
+rule**, a shape the code observes and no option: ``(T, H, K)`` decays the
+state by a vector, a factor a key channel (below as written); ``(T, H)``
+by one factor a head, ``Diag(exp(g_t))`` then ``exp(g_t) I``.  Every
+position decays the state and then corrects it by a rank-one step towards
 ``v_t`` along ``k_t``::
 
     S_t = Diag(exp(g_t)) S_{t-1} ;  S_t += b_t k_t (v_t - S_t^T k_t)^T      S_{-1} = 0
@@ -66,6 +70,20 @@ inside a sub-block the sums are float32 on unrounded operands, outside
 it products of operands rounded to ``dtype``, so another sub-block is
 another rounding.
 
+**With one decay a head the decays leave the sums over** ``K``: ``A_ij
+= (k_i . k_j) exp(G_i - G_j)``, ``B_ij = (q_i . k_j) exp(G_i - G_j)``,
+so ``kda_intra`` is two plain ``(C, K) x (K, C)`` products in ``dtype``
+with float32 accumulation and one masked ``(C, C)`` float32 exponent a
+head (:func:`_pairwise_a_head`; the factor is ``ops/ssd.py``'s
+:func:`~fmda_tpu.ops.ssd.pairwise_decays`, the matrix a state-space
+layer's scan weighs its pairs by), whatever ``impl`` and whatever ``K``
+(there is no tensor for a kernel to keep out of HBM).  The solve, the
+carry and the output are the same code: ``exp(G)``, ``exp(G_last - G)``
+and ``exp(G_last)`` are then scalars a row.  ``b`` up to 2 changes
+nothing in the algebra: ``I + Diag(b) A`` stays unit lower triangular,
+its entries double, and substitution solves it as before (the case
+``keys_alike_beta_two`` of tests/test_kda.py).
+
 The chunks are walked once, in order, as ``ops/ssd.py`` walks its own: a
 ``lax.scan`` over groups of :data:`CHUNK_GROUP` chunks carries the state,
 a turn does all four parts for its group (the carry unrolled over the
@@ -84,6 +102,7 @@ import jax
 import jax.numpy as jnp
 
 from fmda_tpu.ops.dispatch import count_kernel_fallback
+from fmda_tpu.ops.ssd import pairwise_decays
 
 #: Chunks a turn of the walk takes (512 positions at a chunk of 64: what
 #: exists a group at a time is then 8 MB a float32 array of 32 heads of
@@ -107,11 +126,13 @@ SOLVE_ROWS = 4
 def kda_stepwise(q, k, v, g, b, *, scale=None
                  ) -> Tuple[jax.Array, jax.Array]:
     """The recurrence as written, one position at a time, float32:
-    ``q`` / ``k`` / ``g`` (B, T, H, K), ``v`` (B, T, H, V), ``b``
-    (B, T, H) -> ``(o (B, T, H, V), the state after the last position
-    (B, H, K, V))``."""
+    ``q`` / ``k`` (B, T, H, K), ``g`` (B, T, H, K) or, one decay a head,
+    (B, T, H), ``v`` (B, T, H, V), ``b`` (B, T, H) -> ``(o (B, T, H, V),
+    the state after the last position (B, H, K, V))``."""
     f32 = jnp.float32
     q, k, v, g, b = (x.astype(f32) for x in (q, k, v, g, b))
+    if g.ndim == 3:  # one decay a head: every channel's
+        g = g[..., None]
     batch, _, h, dk = q.shape
     scale = dk ** -0.5 if scale is None else scale
 
@@ -176,6 +197,22 @@ def _pairwise(q, k, gc, sub: int, dtype):
         jnp.where(lower[..., None], span, -jnp.inf))
     a = a + _block_diagonal(jnp.sum(ks[..., :, None, :] * decayed, -1))
     b = b + _block_diagonal(jnp.sum(qs[..., :, None, :] * decayed, -1))
+    return a * jnp.tril(jnp.ones((chunk, chunk), f32), -1), b
+
+
+def _pairwise_a_head(q, k, gc, dtype):
+    """``(A, B)`` where a head has ONE decay: ``q`` (already scaled),
+    ``k`` (B, G, H, C, K), ``gc`` (B, G, H, C) float32 -> two (B, G, H,
+    C, C) float32.  The decays leave the sums over ``K``: two plain
+    products in ``dtype`` and one masked ``(C, C)`` exponent a head
+    (``ops/ssd.py``'s)."""
+    f32 = jnp.float32
+    chunk = q.shape[-2]
+    decays = pairwise_decays(gc, jnp.tril(jnp.ones((chunk, chunk), bool)))
+    k_n = k.astype(dtype)
+    a, b = (jnp.einsum("...ik,...jk->...ij", x, k_n,
+                       preferred_element_type=f32) * decays
+            for x in (k_n, q.astype(dtype)))
     return a * jnp.tril(jnp.ones((chunk, chunk), f32), -1), b
 
 
@@ -260,7 +297,10 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None,
     """:func:`kda_stepwise` in chunks of ``chunk`` positions (module
     docstring): ``(o (B, T, H, V) float32, the state after the last
     position (B, H, K, V) float32, the largest |G| inside a chunk ()
-    float32)``.  A length that is no multiple of ``chunk`` is padded with
+    float32)``.  ``g`` is (B, T, H, K), a decay a channel, or (B, T, H),
+    one a head: ``kda_intra`` is then :func:`_pairwise_a_head` whatever
+    ``impl``, and the decays of the other three parts scalars a row.  A
+    length that is no multiple of ``chunk`` is padded with
     positions that neither decay nor correct the state (``g`` and ``b``
     zero).  ``impl`` says where ``kda_intra``'s pairwise decays live:
     ``"jnp"`` in arrays (:func:`_pairwise`), ``"pallas"`` or
@@ -270,6 +310,7 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None,
     f32 = jnp.float32
     batch, t, h, dk = q.shape
     dv = v.shape[-1]
+    a_head = g.ndim == 3
     scale = dk ** -0.5 if scale is None else scale
     pad = -t % chunk
     if pad:
@@ -279,7 +320,7 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None,
     n_chunks = (t + pad) // chunk
     group = _largest_divisor(n_chunks, CHUNK_GROUP)
     sub = _largest_divisor(chunk, SUB_ROWS)
-    if impl != "jnp":
+    if impl != "jnp" and not a_head:
         from fmda_tpu.ops import pallas_kda  # Pallas: where it is asked for
 
         if not pallas_kda.fits(chunk, sub, dk):
@@ -293,9 +334,15 @@ def kda_scan(q, k, v, g, b, *, chunk: int, dtype=jnp.float32, scale=None,
         q, k, v, g, b = at                                # (B, G, H, C, .)
         q32, k32 = q.astype(f32) * scale, k.astype(f32)
         b = b.astype(f32)[..., None]
-        gc = jnp.cumsum(g.astype(f32), axis=-2)           # never positive
+        # over the chunk's positions; never positive
+        gc = jnp.cumsum(g.astype(f32), axis=-1 if a_head else -2)
+        if a_head:  # (B, G, H, C, 1): a scalar a row from here on
+            gc = gc[..., None]
         from_start = jnp.exp(gc)
-        if impl == "jnp":
+        if a_head:
+            with jax.named_scope("kda_intra"):
+                a_kk, a_qk = _pairwise_a_head(q32, k32, gc[..., 0], dtype)
+        elif impl == "jnp":
             with jax.named_scope("kda_intra"):
                 a_kk, a_qk = _pairwise(q32, k32, gc, sub, dtype)
         else:  # the scope is opened inside the rule's two directions

@@ -160,6 +160,16 @@ def ssd_scan_stepwise(xs, d, a, b, c, skip) -> jax.Array:
     return jnp.moveaxis(y, 0, 1) + skip[:, None] * xs
 
 
+def pairwise_decays(l: jax.Array, causal: jax.Array) -> jax.Array:
+    """``exp(l_i - l_j)`` where ``causal`` (``j <= i``) and 0 elsewhere:
+    cumulative log-decays ``l`` (..., Q) float32, never rising -> (..., Q,
+    Q).  The exponent is masked, not the result, so that no masked slot
+    overflows.  One decay a head makes the pairwise factor this matrix,
+    here and in ops/kda.py."""
+    span = l[..., :, None] - l[..., None, :]
+    return jnp.exp(jnp.where(causal, span, -jnp.inf))
+
+
 def _by_chunk(v: jax.Array, chunk: int) -> jax.Array:
     """(B, T, ...) -> (B, T / chunk, chunk, ...)."""
     return v.reshape(v.shape[:1] + (-1, chunk) + v.shape[2:])
@@ -220,11 +230,9 @@ def ssd_scan(xs, d, a, b, c, skip, *, chunk: int, dtype=jnp.float32
         with jax.named_scope("ssd_intra"):
             scores = jnp.einsum("bgin,bgjn->bgij", c_c, b_c,
                                 preferred_element_type=f32)
-            # exp(l_i - l_j) d_j for j <= i; the exponent is masked, not
-            # the result, so that no masked slot overflows
+            # exp(l_i - l_j) d_j for j <= i
             by_head = jnp.swapaxes(decay, 2, 3)                  # (B, G, H, Q)
-            span = by_head[..., :, None] - by_head[..., None, :]
-            weights = (jnp.exp(jnp.where(causal, span, -jnp.inf))
+            weights = (pairwise_decays(by_head, causal)
                        * jnp.swapaxes(d, 2, 3)[..., None, :]
                        * scores[:, :, None])                    # (B, G, H, i, j)
             y = jnp.einsum("bghij,bgjhp->bgihp", weights.astype(dtype),

@@ -81,7 +81,7 @@ class TokenTotals(NamedTuple):
     """A token pass's running totals: each step's mean loss, the tokens
     that counted and those predicted right, and what the layers counted,
     an attribute a name of :data:`fmda_tpu.models.decoder.COUNTS` (which
-    says what each is; three are folded by ``max``: :data:`FOLDED_BY_MAX`),
+    says what each is; some are folded by ``max``: :data:`FOLDED_BY_MAX`),
     None where the model does not count it.  Last, the loss terms the
     layers declare (:func:`fmda_tpu.models.decoder.model_terms`): each
     step's value a layer, the mean over the step's sequences, as the
@@ -105,6 +105,10 @@ class TokenTotals(NamedTuple):
     kda_chunks: Optional[jax.Array] = None     # (layers,) int32
     kda_positions: Optional[jax.Array] = None  # (layers,) int32
     kda_log_decay_absmax: Optional[jax.Array] = None  # (layers,) float32
+    gdn_chunks: Optional[jax.Array] = None     # (layers,) int32
+    gdn_positions: Optional[jax.Array] = None  # (layers,) int32
+    gdn_log_decay_absmax: Optional[jax.Array] = None  # (layers,) float32
+    gdn_beta_max: Optional[jax.Array] = None   # (layers,) float32
     seq_aux_loss: Optional[jax.Array] = None        # (layers,) float32
 
 
@@ -128,6 +132,10 @@ PUBLISHED = {
     "kda_chunks": "kda_chunks_total",
     "kda_positions": "kda_positions_total",
     "kda_log_decay_absmax": "kda_log_decay_absmax",
+    "gdn_chunks": "gdn_chunks_total",
+    "gdn_positions": "gdn_positions_total",
+    "gdn_log_decay_absmax": "gdn_log_decay_absmax",
+    "gdn_beta_max": "gdn_beta_max",
 }
 
 #: A declared loss term's gauge: its mean a step over the pass, a layer.

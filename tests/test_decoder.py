@@ -424,7 +424,8 @@ def test_a_count_folds_as_it_is_declared(through):
     task = NextToken(cfg, TrainConfig(batch_size=2, window=32))
     declared = model_counts(cfg)
     assert set(FOLDED_BY_MAX) == {"router_bias_absmax", "hc_sum_error",
-                                  "kda_log_decay_absmax"}
+                                  "kda_log_decay_absmax",
+                                  "gdn_log_decay_absmax", "gdn_beta_max"}
     rng = np.random.default_rng(0)
     a, b = ({name: jnp.asarray(rng.integers(1, 9, c.shape), c.count.dtype)
              for name, c in declared.items()} for _ in range(2))
@@ -459,7 +460,7 @@ def test_a_count_folds_as_it_is_declared(through):
     ("routed", dict(hc_streams=2),
      "hc_streams (1, or more lanes (a latent-attention model's))"),
     ("latent", dict(layer_layout=(4, 4, 0)),
-     "layer_layout (one of 0/1/2/3 per layer, or of 4/5 in every layer)"),
+     "layer_layout (one of 0/1/2/3/6 per layer, or of 4/5 in every layer)"),
     ("latent_direct", dict(hc_streams=4),
      "moe_seq_aux_alpha (0, or positive with experts under a plain "
      "residual (a model of layers of kinds 4 and 5))"),

@@ -1,6 +1,7 @@
-"""The chunked delta rule with a decay a channel against the recurrence as
-written (ops/kda.py): outputs, the final state and every gradient, at
-small sizes and seeded inputs, float32 on the CPU."""
+"""The chunked delta rule, with a decay a channel and with one a head,
+against the recurrence as written (ops/kda.py): outputs, the final state
+and every gradient, at small sizes and seeded inputs, float32 on the
+CPU."""
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,9 @@ B, H, K, V = 2, 3, 8, 6
 
 
 def _inputs(t, seed=0, decay=1.0, beta=None, key_noise=None,
-            sizes=(B, H, K, V)):
+            sizes=(B, H, K, V), a_head=False, beta_scale=1.0):
+    """``a_head``: one log-decay a head, ``g`` (B, T, H); ``beta_scale``
+    2: ``b`` drawn over 0..2, a correction that may overshoot."""
     batch, h, dk, dv = sizes
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     q, k = (jax.random.normal(key, (batch, t, h, dk)) for key in keys[:2])
@@ -22,8 +25,10 @@ def _inputs(t, seed=0, decay=1.0, beta=None, key_noise=None,
                               (batch, 1, h, dk)) + key_noise * k
     q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
     v = jax.random.normal(keys[2], (batch, t, h, dv))
-    g = -jax.nn.softplus(jax.random.normal(keys[3], (batch, t, h, dk))) * decay
-    b = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, t, h)))
+    g = -jax.nn.softplus(jax.random.normal(
+        keys[3], (batch, t, h) if a_head else (batch, t, h, dk))) * decay
+    b = beta_scale * jax.nn.sigmoid(
+        beta_scale * jax.random.normal(keys[4], (batch, t, h)))
     return q, k, v, g, b if beta is None else jnp.full_like(b, beta)
 
 
@@ -49,6 +54,20 @@ CASES = {
     "no_decay": (48, 16, dict(decay=0.0)),
     "no_correction": (48, 16, dict(beta=0.0)),
     "keys_alike": (64, 64, dict(decay=0.0, beta=1.0, key_noise=0.05)),
+    # ... where the correction may overshoot (``b`` up to 2: the unit-lower
+    # matrix's entries double, and ``I - 2 k k^T`` reflects the state)
+    "keys_alike_beta_two": (64, 64, dict(decay=0.0, beta=2.0,
+                                         key_noise=0.05)),
+    # one decay a head (``g`` (B, T, H)), ``b`` drawn over 0..2, ``K`` !=
+    # ``V`` as everywhere in this file
+    "a_head_ragged": (37, 32, dict(a_head=True, beta_scale=2.0)),
+    "a_head_six_groups": (384, 8, dict(a_head=True, decay=0.1,
+                                       beta_scale=2.0)),
+    "a_head_underflow": (64, 16, dict(a_head=True, decay=60.0,
+                                      beta_scale=2.0)),
+    "a_head_beta_two": (48, 16, dict(a_head=True, beta=2.0)),
+    "a_head_keys_alike_beta_two": (64, 64, dict(
+        a_head=True, decay=0.0, beta=2.0, key_noise=0.05)),
 }
 
 
@@ -87,17 +106,38 @@ def test_the_chunked_walk_is_the_recurrence_as_written(case):
     # the largest |G| inside a chunk: the sum of a chunk's log-decays
     g = args[3]
     pad = -t % chunk
-    by_chunk = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
-        B, -1, chunk, H, K)
+    by_chunk = jnp.pad(g, ((0, 0), (0, pad)) + ((0, 0),) * (g.ndim - 2)
+                       ).reshape((B, -1, chunk) + g.shape[2:])
     assert float(absmax) == pytest.approx(
         float(jnp.abs(by_chunk.sum(2)).max()), rel=1e-5)
-    if case == "underflow":  # past float32's 87: exp(-G) would be inf
+    if kw.get("beta_scale") == 2.0:  # the overshoot is exercised
+        assert float(args[4].max()) > 1.5
+    if case.endswith("underflow"):  # past float32's 87: exp(-G) would be inf
         assert float(absmax) > 200.0
         assert float(jnp.exp(-absmax)) == 0.0
     if case == "no_decay":
         assert float(absmax) == 0.0
     if case == "no_correction":  # nothing was ever written
         assert not bool(jnp.any(got_s)) and not bool(jnp.any(got_o))
+
+
+@pytest.mark.parametrize("chunk", [16, 32])
+def test_one_decay_a_head_is_that_decay_on_every_channel(chunk):
+    """``g`` (B, T, H) and the same ``g`` broadcast over the key
+    channels agree, through the walk and through the recurrence as
+    written; with one decay a head no (rows, rows, K) tensor is in the
+    program and its pairwise factor is a chunk's (C, C) matrix."""
+    q, k, v, g, b = _inputs(80, seed=5, a_head=True, beta_scale=2.0)
+    wide = jnp.broadcast_to(g[..., None], q.shape)
+    with jax.default_matmul_precision("highest"):
+        for fn in (kda_stepwise, lambda *a: kda_scan(*a, chunk=chunk)):
+            one, many = (jax.jit(fn)(q, k, v, x, b) for x in (g, wide))
+            for got, want in zip(one, many):
+                _close(got, want, 2e-6)
+    text = jax.jit(lambda *a: kda_scan(*a, chunk=chunk)[0]).lower(
+        q, k, v, g, b).as_text()
+    assert f"x{K}xf32>" in text and f"x{chunk}x{chunk}xf32>" in text
+    assert f"x{kda.SUB_ROWS}x{kda.SUB_ROWS}x{K}xf32>" not in text
 
 
 def test_the_pairwise_decays_exist_a_group_of_chunks_at_a_time(monkeypatch):

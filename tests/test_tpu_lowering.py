@@ -460,6 +460,39 @@ def test_the_delta_rule_walk_compiles_at_32_heads_of_128_by_8192(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1_200_000_000
 
 
+def test_the_walk_with_one_decay_a_head_compiles_at_15_heads_of_96_by_192(
+        one_chip):
+    """The delta rule with ONE decay a head, value and gradient, at the
+    share of Olmo-Hybrid's layer a chip holds (15 heads, keys 96 wide,
+    no multiple of 128 lanes, values 192, 8,192 positions in chunks of
+    64): the TPU's compiler takes it; the pairwise factor is a chunk's
+    ``(64, 64)`` matrix a head, so no ``(16, 16, 96)`` tensor of a
+    decay a channel is in the program as lowered, and no triangular
+    solve as lowered or as compiled."""
+    from fmda_tpu.ops.kda import kda_scan
+
+    t, h, k, v = 8192, 15, 96, 192
+
+    def value(q, key, val, g, b):
+        return kda_scan(q, key, val, g, b, chunk=64, dtype=BF16,
+                        impl="pallas")[0].sum()
+
+    a_head = _shape(one_chip, (1, t, h), jnp.float32)
+    lowered = jax.jit(jax.value_and_grad(value, tuple(range(5)))).lower(
+        _shape(one_chip, (1, t, h, k), BF16),
+        _shape(one_chip, (1, t, h, k), BF16),
+        _shape(one_chip, (1, t, h, v), BF16), a_head, a_head)
+    compiled = lowered.compile()
+    assert "x16x16x96xf32>" not in lowered.as_text()
+    assert "x64x64xf32>" in lowered.as_text()
+    for text in (lowered.as_text(), compiled.as_text()):
+        assert "tpu_custom_call" not in text  # no kernel: nothing to keep
+        for solve in ("triangular_solve", "triangular-solve",
+                      "TriangularSolve"):
+            assert solve not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 600_000_000
+
+
 def _hbm_instructions(text):
     """A compiled module's instructions outside its fused computations,
     from the result type on: what exists as an array of its own."""
