@@ -248,13 +248,21 @@ PINNED_KINDS = {
 #: less the ``block_1._method`` components flax writes for a module's
 #: method, which name no scope the code opens and no reader asks for);
 #: the parameter tree at ``PRNGKey(0)`` (paths, shapes, dtypes, bytes).
-#: ``hybrid``'s hashes and all six ``params`` are still those; the five
+#: All six ``params`` and ``eval_scopes`` are still those; the five
 #: kinds with an expert layer have PR 48's own text and scopes (the
 #: layer's rounds in a ``while`` under one ``custom_vjp``, each direction
 #: a ``jax.jit`` of its own: its scopes stand under ``jit(...)/while/
 #: body``, the backward's under ``jvp(moe_*)`` and ``transpose(jvp(
 #: moe_*))`` there; the output named for the replay; a fourth count,
-#: ``layout_rounds``).
+#: ``layout_rounds``).  The four kinds that call ``_dense_mlp``
+#: (``hybrid`` and the three latent ones) have PR 55's train text and
+#: scopes: the replay keeps the MLP's two pre-activation products, so
+#: six ``dot_general`` fewer a program, ``.../rematted_computation/
+#: block_*/{dense_mlp,moe_shared}/dot_general`` gone and the kept
+#: values' ``reduce_precision`` under ``jvp(forward)`` in their place;
+#: their eval text is the parent's operation for operation under other
+#: numbers for jax's private functions (``@silu_119`` for ``@silu_118``:
+#: a name is an equation to the counter and nothing to the program).
 #: A pin that moves means the change altered the program:
 #: regenerate (``_program_pins(kind)``) only after a deliberate change to
 #: these layers, their task or the step function.
@@ -269,20 +277,20 @@ PINNED = {
         eval_text="e4a838a7f34577e5", eval_scopes="81ebb71f65af29da"),
     "hybrid": dict(
         params="7170fa193506754b",
-        train_text="c9f228243c080be9", train_scopes="627737dbb0728fbd",
-        eval_text="c949e67b3ed09b92", eval_scopes="65a0fce208c4d6ab"),
+        train_text="2a5132dd5304c6de", train_scopes="002b8d80e8eb4415",
+        eval_text="31bbb51a12a8988a", eval_scopes="65a0fce208c4d6ab"),
     "latent": dict(
         params="43dbdb7823a39232",
-        train_text="48fd7a8239d36c6a", train_scopes="c0bbc76b37228339",
-        eval_text="2e98a77a3d84e2bc", eval_scopes="73af4a412538784b"),
+        train_text="5963b4d0494d2ee7", train_scopes="54f49539ea312fca",
+        eval_text="de2b65e31064ab7b", eval_scopes="73af4a412538784b"),
     "latent_plain": dict(
         params="881b57f6db59149d",
-        train_text="b20fd15e2abb0aac", train_scopes="beec7de9265d76cf",
-        eval_text="8e8ad003a11364ce", eval_scopes="c55711daca10cd61"),
+        train_text="c9d5b53ef001be63", train_scopes="6a183920ecf89faa",
+        eval_text="b04d9606cf175032", eval_scopes="c55711daca10cd61"),
     "latent_direct": dict(
         params="5f93cbac51dae9f8",
-        train_text="4a4bfdc2b411fb48", train_scopes="01019bca8d4c5459",
-        eval_text="d2fc6ab77e156cfa", eval_scopes="c14b381fe91fc917"),
+        train_text="d2e78248ad81c5dc", train_scopes="e7f819bb57cb1025",
+        eval_text="509f3361f7080458", eval_scopes="c14b381fe91fc917"),
 }
 
 
@@ -340,6 +348,64 @@ def test_the_accepted_configurations_steps_are_the_parents(
 
     monkeypatch.setattr(trainer_module, "SOLO_STEP_BYTES", 1)
     assert _program_pins(kind) == PINNED[kind]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_replay_keeps_the_mlps_two_products_and_moves_no_bit(
+        monkeypatch, capsys, dtype):
+    """A recomputed dense block and a recomputed expert block with a
+    shared expert: with the gated MLP's two pre-activation products under
+    ``REPLAY_KEEPS`` the forward hands backward two arrays a
+    ``_dense_mlp`` call, tokens x width in the compute dtype, and none
+    with the two names taken out; loss and every gradient leaf are the
+    same either way (the kept values are the ones the replay would have
+    made: bit for bit in float32; XLA on a CPU may fuse two bfloat16
+    programs' roundings differently, so a bfloat16 leaf is held to 2 % of
+    its largest entry)."""
+    from fmda_tpu.models import decoder
+
+    cfg = _tiny("latent_plain", layer_layout=(4, 4), dtype=dtype)
+    params = build_model(cfg).init(
+        {"params": jax.random.PRNGKey(0)},
+        jnp.zeros((1, 8), jnp.int32))["params"]
+    x, y = _ids(n=33)
+    x, y = x % cfg.vocab_size, y % cfg.vocab_size
+    batch = Batch(x, y, jnp.ones(x.shape, jnp.float32).at[1, 20:].set(0.0))
+    task = NextToken(cfg, TrainConfig(batch_size=2, window=32))
+    short = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    # the dense layer's MLP on (batch, tokens, hidden), ffn_size wide; the
+    # shared expert's on the flat rows, moe_shared_experts * moe_ffn_size
+    kept_shapes = (f"{short}[2,32,{cfg.ffn_size}]",
+                   f"{short}[64,{cfg.moe_shared_experts * cfg.moe_ffn_size}]")
+
+    def run():
+        model = build_model(cfg)
+
+        def loss(p):
+            return task.loss(p, task.forward(model, p, batch, None), batch)[0]
+
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(loss, params)
+        kept = [line.split(" ")[0]
+                for line in capsys.readouterr().out.splitlines()
+                if "(_dense_mlp)" in line]
+        return jax.jit(jax.value_and_grad(loss))(params), kept
+
+    (got, got_grads), kept = run()
+    assert sorted(kept) == sorted(2 * kept_shapes), kept
+    monkeypatch.setattr(decoder, "REPLAY_KEEPS", tuple(
+        name for name in decoder.REPLAY_KEEPS
+        if name not in (decoder.MLP_GATE, decoder.MLP_UP)))
+    (want, want_grads), kept = run()
+    assert kept == []
+    for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path((got, got_grads)),
+            jax.tree.leaves((want, want_grads))):
+        if dtype == "float32":
+            np.testing.assert_array_equal(g, w, err_msg=str(path))
+        else:
+            assert float(jnp.abs(g - w).max()) <= 2e-2 * float(
+                jnp.abs(w).max()), path
 
 
 # -- the seam: one declaration of what the layers count ----------------------

@@ -287,6 +287,34 @@ def test_expert_layer_row_passes_compile_bounded_and_in_place(one_chip):
                 and re.search(rf"= \w+\[{t},{d}\]", line)]
 
 
+def _loss_step_text(one_chip, cfg, t):
+    """The compiled text of the next-token loss's value and gradient
+    through ``cfg``'s model on one sequence of ``t`` tokens, from shapes,
+    for the described chip."""
+    from fmda_tpu.config import TrainConfig
+    from fmda_tpu.data.pipeline import Batch
+    from fmda_tpu.models import build_model
+    from fmda_tpu.train.tasks import NextToken
+
+    model = build_model(cfg)
+    task = NextToken(cfg, TrainConfig(batch_size=1, window=t))
+    params = jax.eval_shape(
+        lambda key: model.init({"params": key},
+                               jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+
+    def step(p, x, y, mask):
+        batch = Batch(x, y, mask)
+        return jax.value_and_grad(lambda p: task.loss(
+            p, task.forward(model, p, batch, None), batch)[0])(p)
+
+    return jax.jit(step).lower(
+        jax.tree.map(lambda l: _shape(one_chip, l.shape, l.dtype), params),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.int32),
+        _shape(one_chip, (1, t), jnp.float32)).compile().as_text()
+
+
 def _kernel_runs(text):
     """The grouped-product kernels' custom calls in a compiled program."""
     import re
@@ -311,11 +339,9 @@ def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
     keeping nothing (the policy the family had before) it holds two."""
     import re
 
-    from fmda_tpu.config import ModelConfig, TrainConfig
-    from fmda_tpu.data.pipeline import Batch
-    from fmda_tpu.models import build_model, decoder
+    from fmda_tpu.config import ModelConfig
+    from fmda_tpu.models import decoder
     from fmda_tpu.ops import attention
-    from fmda_tpu.train.tasks import NextToken
 
     monkeypatch.setattr(attention, "flash_available", lambda: True)
     monkeypatch.setattr(decoder, "kernel_impl", lambda use: "pallas")
@@ -331,23 +357,7 @@ def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
         experts_held=(0, 4), hidden_act="silu", indexer_heads=4,
         indexer_head_dim=64, indexer_topk=128, loss_chunk=256,
         dtype="bfloat16", use_pallas=True, remat=True)
-    model = build_model(cfg)
-    task = NextToken(cfg, TrainConfig(batch_size=1, window=t))
-    params = jax.eval_shape(
-        lambda key: model.init({"params": key},
-                               jnp.zeros((1, 8), jnp.int32))["params"],
-        jax.random.PRNGKey(0))
-
-    def step(p, x, y, mask):
-        batch = Batch(x, y, mask)
-        return jax.value_and_grad(lambda p: task.loss(
-            p, task.forward(model, p, batch, None), batch)[0])(p)
-
-    text = jax.jit(step).lower(
-        jax.tree.map(lambda l: _shape(one_chip, l.shape, l.dtype), params),
-        _shape(one_chip, (1, t), jnp.int32),
-        _shape(one_chip, (1, t), jnp.int32),
-        _shape(one_chip, (1, t), jnp.float32)).compile().as_text()
+    text = _loss_step_text(one_chip, cfg, t)
     runs = 1 if keeps == "names" else 2
     for name in kernels:
         calls = re.findall(
@@ -364,6 +374,59 @@ def test_a_recomputed_decoder_step_runs_each_attention_kernel_once_a_layer(
     # run forward, once more in backward, and transposed, whatever is kept
     assert _kernel_runs(text) == {"moe_gmm": 9 * len(layout),
                                   "moe_tgmm": 3 * len(layout)}
+
+
+@pytest.mark.parametrize("over,width", [
+    # a dense gated MLP a layer, as the hybrid configurations have
+    pytest.param(dict(moe_experts=0, ffn_size=640), 640, id="dense"),
+    # an expert layer with three shared experts: their MLP is one call
+    pytest.param(dict(moe_experts=8, moe_top_k=2, moe_ffn_size=128,
+                      experts_held=(0, 4), moe_shared_experts=3,
+                      moe_scoring="sigmoid", moe_routed_scaling=2.0), 384,
+                 id="shared"),
+])
+@pytest.mark.parametrize("keeps", ["names", "without_the_mlps"])
+def test_a_recomputed_decoder_step_makes_the_mlps_two_products_once(
+        one_chip, monkeypatch, over, width, keeps):
+    """The next-token loss's value and gradient through two recomputed
+    blocks, compiled for the chip: with the gated MLP's two
+    pre-activation products kept by name (``decoder.MLP_GATE``,
+    ``decoder.MLP_UP`` in ``decoder.REPLAY_KEEPS``) no product of the
+    MLP's ``(T, d) x (d, f)`` shape stands under ``rematted_computation``;
+    with the two names taken out the replay makes both again, two a
+    ``_dense_mlp`` call.  Behind a pre-norm block's plain residual the
+    third product's output feeds the block's output alone and is dead in
+    the replay either way (a block that reads it in backward, ``post_norm``
+    or the lanes, makes it again: PERF.md section 6, PR 55)."""
+    import re
+
+    from fmda_tpu.config import ModelConfig
+    from fmda_tpu.models import decoder
+    from fmda_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "flash_available", lambda: True)
+    monkeypatch.setattr(decoder, "kernel_impl", lambda use: "pallas")
+    if keeps == "without_the_mlps":
+        monkeypatch.setattr(decoder, "REPLAY_KEEPS", tuple(
+            name for name in decoder.REPLAY_KEEPS
+            if name not in (decoder.MLP_GATE, decoder.MLP_UP)))
+    t = 1024
+    cfg = ModelConfig(**{**dict(
+        cell="decoder", hidden_size=256, n_heads=4, vocab_size=512,
+        layer_layout=(4, 4), q_lora_rank=128, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_factor=64.0, rope_original_max=4096, hidden_act="silu",
+        loss_chunk=256, dtype="bfloat16", use_pallas=True, remat=True),
+        **over})
+    text = _loss_step_text(one_chip, cfg, t)
+    products = re.findall(
+        rf"(?m)^.* = bf16\[(?:1,)?{t},{width}\]\S* (?:convolution|dot)\(.*$",
+        text)
+    replayed = [p for p in products if "rematted_computation" in p]
+    # forward: gate and up; backward: the down product's transpose
+    assert len(products) - len(replayed) == 3 * len(cfg.layer_layout)
+    assert len(replayed) == (
+        0 if keeps == "names" else 2 * len(cfg.layer_layout)), len(replayed)
 
 
 def test_flash_kernels_compile_at_32_on_8_heads_of_64_with_a_stated_scale(
@@ -590,10 +653,7 @@ def test_a_hybrid_decoder_step_keeps_one_float32_array_a_convolution(
     and one a layer in backward (``dpre``)."""
     import re
 
-    from fmda_tpu.config import ModelConfig, TrainConfig
-    from fmda_tpu.data.pipeline import Batch
-    from fmda_tpu.models import build_model
-    from fmda_tpu.train.tasks import NextToken
+    from fmda_tpu.config import ModelConfig
 
     t, layout = 2048, (3, 3)
     cfg = ModelConfig(
@@ -602,23 +662,7 @@ def test_a_hybrid_decoder_step_keeps_one_float32_array_a_convolution(
         ffn_size=512, hidden_act="silu", ssm_heads=8, ssm_head_dim=64,
         ssm_state=128, ssm_conv=4, ssm_chunk=256, tie_embeddings=True,
         loss_chunk=256, dtype="bfloat16", use_pallas=True, remat=True)
-    model = build_model(cfg)
-    task = NextToken(cfg, TrainConfig(batch_size=1, window=t))
-    params = jax.eval_shape(
-        lambda key: model.init({"params": key},
-                               jnp.zeros((1, 8), jnp.int32))["params"],
-        jax.random.PRNGKey(0))
-
-    def step(p, x, y, mask):
-        batch = Batch(x, y, mask)
-        return jax.value_and_grad(lambda p: task.loss(
-            p, task.forward(model, p, batch, None), batch)[0])(p)
-
-    text = jax.jit(step).lower(
-        jax.tree.map(lambda l: _shape(one_chip, l.shape, l.dtype), params),
-        _shape(one_chip, (1, t), jnp.int32),
-        _shape(one_chip, (1, t), jnp.int32),
-        _shape(one_chip, (1, t), jnp.float32)).compile().as_text()
+    text = _loss_step_text(one_chip, cfg, t)
     # inner 512 + 2 x 128 channels
     wide = [a for a in _hbm_instructions(text) if "ssm_conv" in a and re.match(
         r"\(?f32\[1,(20(48|51),768|768,20(48|51))\]", a)]
@@ -659,11 +703,9 @@ def test_a_recomputed_latent_decoder_step_runs_the_core_once_a_layer(
     every layout uses, so the program holds one ``flash_fwd`` a layer."""
     import re
 
-    from fmda_tpu.config import ModelConfig, TrainConfig
-    from fmda_tpu.data.pipeline import Batch
-    from fmda_tpu.models import build_model, decoder
+    from fmda_tpu.config import ModelConfig
+    from fmda_tpu.models import decoder
     from fmda_tpu.ops import attention
-    from fmda_tpu.train.tasks import NextToken
 
     monkeypatch.setattr(attention, "flash_available", lambda: True)
     monkeypatch.setattr(decoder, "kernel_impl", lambda use: "pallas")
@@ -680,23 +722,7 @@ def test_a_recomputed_latent_decoder_step_runs_the_core_once_a_layer(
         moe_shared_experts=1, moe_scoring="sigmoid", moe_routed_scaling=2.0,
         moe_bias_rate=1e-3, hc_streams=4, loss_chunk=256, dtype="bfloat16",
         use_pallas=True, remat=True)
-    model = build_model(cfg)
-    task = NextToken(cfg, TrainConfig(batch_size=1, window=t))
-    params = jax.eval_shape(
-        lambda key: model.init({"params": key},
-                               jnp.zeros((1, 8), jnp.int32))["params"],
-        jax.random.PRNGKey(0))
-
-    def step(p, x, y, mask):
-        batch = Batch(x, y, mask)
-        return jax.value_and_grad(lambda p: task.loss(
-            p, task.forward(model, p, batch, None), batch)[0])(p)
-
-    text = jax.jit(step).lower(
-        jax.tree.map(lambda l: _shape(one_chip, l.shape, l.dtype), params),
-        _shape(one_chip, (1, t), jnp.int32),
-        _shape(one_chip, (1, t), jnp.int32),
-        _shape(one_chip, (1, t), jnp.float32)).compile().as_text()
+    text = _loss_step_text(one_chip, cfg, t)
     runs = 1 if keeps == "names" else 2
     calls = re.findall(
         r"(?m)^\s*%flash_fwd(?:\.\d+)? = .*custom-call\(.*$", text)
